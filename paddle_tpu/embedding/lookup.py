@@ -139,7 +139,7 @@ def sharded_lookup(w, ids, mesh, axis, padding_idx=None):
     scales down with the mesh exactly like the table's rows do. V must be
     a multiple of the axis size (pad_vocab; statically checked by
     fluid.analysis.sharding for annotated programs)."""
-    from ..parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     ws = mesh.shape[axis]

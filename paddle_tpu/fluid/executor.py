@@ -17,8 +17,9 @@ in-place in HBM.
 Pipelined hot loop (docs/perf.md): `run_bundle` scans K steps inside ONE
 compiled module (one dispatch + one host round-trip per K steps),
 `run(sync='async')` returns lazy FetchHandles so the host runs ahead of
-the device, and PADDLE_TPU_COMPILE_CACHE reuses XLA executables across
-processes (zero cold compiles on restart).
+the device, and the persistent compilation cache (utils/compile_cache.py,
+JAX_COMPILATION_CACHE_DIR) reuses XLA executables across processes (zero
+cold compiles on restart).
 """
 import collections
 import os
@@ -31,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import obs
+from ..utils import compile_cache
 from . import core
 from . import lowering
 from . import ops_impl  # noqa: F401  (registers all rules)
@@ -45,13 +47,6 @@ _ZERO_MIN_SIZE = 1024
 
 __all__ = ['Executor', 'FetchHandle', 'global_scope', 'scope_guard',
            '_switch_scope', 'Scope', 'anomaly_guard']
-
-# Persistent XLA compilation cache (docs/perf.md): point this env var at a
-# directory and every Executor in the process wires
-# jax_compilation_cache_dir at construction, so a RESTARTED process
-# (Trainer resume after preemption, serving warmup) deserializes compiled
-# modules instead of re-compiling them.
-ENV_COMPILE_CACHE = 'PADDLE_TPU_COMPILE_CACHE'
 
 # Compile-time stderr capture for XLA partitioner diagnostics
 # (docs/parallel.md): the SPMD partitioner reports "Involuntary full
@@ -298,6 +293,17 @@ def scope_guard(scope):
         yield
     finally:
         _switch_scope(prev)
+
+
+def _spec_key(spec):
+    """A PartitionSpec's identity for the compiled-step cache key, with
+    trailing Nones dropped: a step's OUTPUT shardings come back
+    canonicalized (P('tp', None) -> P('tp',)), and keying on the spelling
+    would recompile the whole step on its second run."""
+    entries = tuple(spec)
+    while entries and entries[-1] is None:
+        entries = entries[:-1]
+    return str(entries)
 
 
 def _as_fetch_name(f):
@@ -593,8 +599,11 @@ class Executor(object):
 
     def __init__(self, place=None):
         if place is None:
-            place = core.TPUPlace(0) if core.is_compiled_with_tpu() else core.CPUPlace()
+            place = core.default_place()
         self.place = place
+        # resolve now: an explicit place this process cannot honour is
+        # refused here (core.DeviceUnavailableError), not at the first run
+        self._jax_device = place.jax_device()
         self._cache = {}
         self._run_counter = 0
         # anomaly-guard observability (see anomaly_guard()): health of the
@@ -627,60 +636,19 @@ class Executor(object):
         # executor's compiles (see _scan_remat); tests assert 0 on the
         # pipeline compositions that used to warn (MULTICHIP_r05 tail)
         self.remat_detected = 0
-        # Persistent XLA compilation cache: PADDLE_TPU_COMPILE_CACHE=<dir>
-        # wires jax's on-disk executable cache at construction, so a
-        # restarted process (Trainer resume, serving warmup) deserializes
-        # already-built modules — zero cold compiles on the second run.
-        # The min-compile-time/min-entry-size floors are zeroed so EVERY
-        # executable persists; the hit/miss probe below relies on a miss
-        # always writing a new cache entry.
-        self._compile_cache_dir = None
+        # Persistent XLA compilation cache (utils/compile_cache.py): with
+        # JAX_COMPILATION_CACHE_DIR set, or after an entry point called
+        # compile_cache.enable(), a restarted process (Trainer resume,
+        # serving warmup) deserializes already-built modules — zero cold
+        # compiles on the second run. None = not wired, no probe.
+        self._compile_cache_dir = compile_cache.wired()
         # cache entries THIS executor's first calls wrote (names):
         # export_warm_signatures ships exactly these when it can, instead
         # of whatever else accumulated in a shared long-lived cache dir
         self._warm_entries = set()
-        cc = os.environ.get(ENV_COMPILE_CACHE)
-        if cc:
-            try:
-                self._wire_compile_cache(cc)
-            except Exception as e:
-                import warnings
-                warnings.warn(
-                    '%s=%r: persistent compilation cache unavailable in '
-                    'this jax (%s: %s) — compiles stay per-process'
-                    % (ENV_COMPILE_CACHE, cc, type(e).__name__, e),
-                    RuntimeWarning)
-
-    def _wire_compile_cache(self, cc, reset=False):
-        """The ONE wiring point for the persistent XLA compilation cache
-        (construction from PADDLE_TPU_COMPILE_CACHE, and
-        load_warm_signatures for a cold replica). The min-compile-time /
-        min-entry-size floors are zeroed so EVERY executable persists
-        (the hit/miss probe relies on a miss always writing an entry),
-        and jax's path-embedding XLA-autotune-cache option is disabled —
-        by default the cache dir's ABSOLUTE PATH lands inside the hashed
-        compile options, so two processes (or machines) with different
-        cache paths would never share an entry, which would break the
-        AOT warm-signature export (docs/perf.md#aot; GPU-only feature,
-        CPU/TPU lose nothing). reset=True additionally resets jax's
-        lazily-initialized cache object — required when wiring AFTER any
-        jit already ran in the process (cold-replica import), or the new
-        dir is never consulted. Raises on an incompatible jax."""
-        jax.config.update('jax_compilation_cache_dir', cc)
-        jax.config.update('jax_persistent_cache_min_compile_time_secs',
-                          0.0)
-        jax.config.update('jax_persistent_cache_min_entry_size_bytes', 0)
-        jax.config.update('jax_persistent_cache_enable_xla_caches', '')
-        if reset:
-            try:
-                from jax._src import compilation_cache as _jcc
-                _jcc.reset_cache()
-            except Exception:
-                pass   # private API drift: degrade to pre-reset behavior
-        self._compile_cache_dir = cc
 
     def _device(self):
-        return self.place.jax_device()
+        return self._jax_device
 
     def _to_device(self, val, var=None):
         if isinstance(val, jax.Array):
@@ -1071,7 +1039,7 @@ class Executor(object):
             if isinstance(v, jax.Array) and isinstance(v.sharding,
                                                        NamedSharding):
                 persist_shardings[n] = v.sharding
-        shard_sig = tuple(sorted((n, str(s.spec), s.mesh)
+        shard_sig = tuple(sorted((n, _spec_key(s.spec), s.mesh)
                                  for n, s in persist_shardings.items()))
         # GSPMD annotation path: jit sharding trees from the ACTUAL
         # placements (persist values were just mesh-placed by
@@ -1113,10 +1081,12 @@ class Executor(object):
         if compiled is None:
             self._cache_misses += 1
             _C_MISSES.inc()
-            # place is None under ParallelExecutor (mesh placement via
-            # shardings); the mesh devices set the platform then
-            plat = (self._device().platform if self.place is not None
-                    else jax.devices()[0].platform)
+            # under a mesh the arrays live on the MESH's devices, whatever
+            # the place says: kernel choice (ctx.platform) must follow
+            # where the data is, or a CPUPlace executor over a TPU mesh
+            # lowers flash_attention to the reference chain
+            plat = (dist_mesh.devices.flat[0].platform
+                    if dist_mesh is not None else self._device().platform)
             # Ahead-of-lowering optimization (docs/passes.md):
             # PADDLE_TPU_OPT={off,default,aggressive}, applied ONCE per
             # compiled-step cache key exactly like verify — the steady
@@ -1270,8 +1240,7 @@ class Executor(object):
         # arrays) would re-specialize the executable on call two — the
         # old run_bundle "warm twice" wart. Mesh-placed programs and
         # place-less executors own their placement and skip this.
-        pin_dev = (self._device() if self.place is not None
-                   and dist_mesh is None else None)
+        pin_dev = self._device() if dist_mesh is None else None
         for n in compiled.pin_state(persist, pin_dev):
             scope._chain_set(n, persist[n])
         return compiled, feed_vals, persist
@@ -1739,9 +1708,7 @@ class Executor(object):
                     arr = np.stack(vals)
                     if arr.dtype != v0.dtype:
                         arr = arr.astype(v0.dtype)
-                    stacked[name] = jax.device_put(
-                        arr, self._device() if self.place is not None
-                        else None)
+                    stacked[name] = jax.device_put(arr, self._device())
                 else:
                     slow_names.append(name)
             if slow_names:
@@ -2074,8 +2041,9 @@ class Executor(object):
         calls `load_warm_signatures(dirname)` before its own warmup and
         reaches first step / first token with ZERO online compiles —
         the PR 4 per-machine persistent cache, extended across machines
-        through the artifact. Requires PADDLE_TPU_COMPILE_CACHE to have
-        been set when this executor was constructed. Returns the
+        through the artifact. Requires the persistent compilation cache
+        to have been wired when this executor was constructed
+        (utils/compile_cache.py). Returns the
         manifest path; `tools/program_lint.py --aot DIR` lints the
         exported signature set against a saved program artifact."""
         from . import step_artifact
@@ -2090,40 +2058,19 @@ class Executor(object):
         cache with the blob's serialized executables and arm the stable-
         signature set, so every matching first call classifies as an
         `aot_hit` (cache_stats / executor.compile.aot_hit) instead of a
-        cold compile. When no PADDLE_TPU_COMPILE_CACHE is wired yet, a
-        fresh cache dir is created next to nothing — the import NEVER
-        writes into the artifact itself, so the blob stays pristine.
-        Returns the number of imported signatures."""
+        cold compile. The blob's entries are COPIED into the process's
+        compilation cache directory (compile_cache.enable(), wired here
+        if it was not yet) — the import never writes into the artifact
+        itself, so the blob stays pristine, and never points jax at a
+        directory of its own. Returns the number of imported signatures."""
         import shutil
-        import tempfile
         from . import step_artifact
         man = step_artifact.read_aot(dirname)
         src = os.path.join(dirname, step_artifact.AOT_CACHE_DIR)
         if self._compile_cache_dir is None:
-            # wire a private cache dir now (the constructor's wiring,
-            # via the shared helper, plus the cache-object reset that
-            # late wiring needs — in a cold replica something always
-            # jitted already) — the artifact dir itself stays read-only
-            cc = tempfile.mkdtemp(prefix='paddle_tpu_aot_cc_')
-            # the private dir holds a copy of the blob's executables:
-            # reclaim it at interpreter exit, or repeated cold-replica
-            # imports on one host would grow /tmp without bound
-            import atexit
-            import shutil
-            atexit.register(shutil.rmtree, cc, ignore_errors=True)
-            try:
-                self._wire_compile_cache(cc, reset=True)
-            except Exception as e:
-                import warnings
-                warnings.warn(
-                    'load_warm_signatures(%r): persistent compilation '
-                    'cache unavailable in this jax (%s: %s) — the AOT '
-                    'executables cannot deserialize; first calls will '
-                    'compile online' % (dirname, type(e).__name__, e),
-                    RuntimeWarning)
+            self._compile_cache_dir = compile_cache.enable()
         imported = 0
-        if os.path.isdir(src) and self._compile_cache_dir is not None:
-            os.makedirs(self._compile_cache_dir, exist_ok=True)
+        if os.path.isdir(src):
             for name in os.listdir(src):
                 dst = os.path.join(self._compile_cache_dir, name)
                 if not os.path.exists(dst):

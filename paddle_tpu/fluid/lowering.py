@@ -94,6 +94,14 @@ class Ctx(object):
     def rng(self):
         return jax.random.fold_in(self.key, self.op_index)
 
+    @property
+    def pallas_interpret(self):
+        """The `interpret=` every pallas dispatch site passes: Mosaic where
+        the step's arrays live on TPUs, the pallas interpreter elsewhere.
+        Decided from the Executor's place (or mesh), never from the
+        process's default backend."""
+        return self.platform != 'tpu'
+
 
 def amp_cast(ctx, *xs):
     """Under AMP, cast fp32 matmul/conv operands to bf16 for the MXU."""

@@ -93,26 +93,21 @@ def _adagrad(ins, attrs, ctx):
     eps = attrs.get('epsilon', 1e-6)
     lr = _lr(ins)
     if isinstance(g, SparseRows):
-        # touched-rows-only update on merged duplicates (reference
-        # adagrad_op.h SelectedRows branch: MergeAdd then per-row update).
-        # Deltas (not absolute values) are scattered so the zero-padded
-        # invalid merge slots are exact no-ops under duplicate indices.
+        # touched-rows-only update on merged duplicates
+        from ...ops import kernels
         uids, gm, valid = _merge_sparse(g, ctx)
         # fused pallas path: gather + moment math + scatter in ONE call,
         # tables aliased in place (per-shard-local — sharded steps keep
-        # the XLA branch below, whose scatter partitions under the mesh)
+        # the XLA path, whose scatter partitions under the mesh)
         if getattr(ctx, 'mesh', None) is None and \
                 use_kernel(ctx, 'sparse_adagrad'):
-            from ...ops.kernels import fused_sparse_adagrad
-            p_out, m_out = fused_sparse_adagrad(p, m, uids, gm, valid,
-                                                lr, eps)
-            return {'ParamOut': p_out, 'MomentOut': m_out}
-        vm = valid[:, None].astype(gm.dtype)
-        m_rows = m[uids]
-        m_new = m_rows + gm * gm
-        p_delta = -lr * gm / (jnp.sqrt(m_new) + eps) * vm
-        return {'ParamOut': p.at[uids].add(p_delta),
-                'MomentOut': m.at[uids].add((m_new - m_rows) * vm)}
+            p_out, m_out = kernels.fused_sparse_adagrad(
+                p, m, uids, gm, valid, lr, eps,
+                interpret=ctx.pallas_interpret)
+        else:
+            p_out, m_out = kernels.sparse_adagrad_reference(
+                p, m, uids, gm, valid, lr, eps)
+        return {'ParamOut': p_out, 'MomentOut': m_out}
     g = data_of(g)
     m_out = m + g * g
     p_out = p - lr * g / (jnp.sqrt(m_out) + eps)
@@ -132,29 +127,21 @@ def _adam(ins, attrs, ctx):
     eps = attrs.get('epsilon', 1e-8)
     lr = _lr(ins) * jnp.sqrt(1 - b2p) / (1 - b1p)
     if isinstance(g, SparseRows):
-        # lazy SelectedRows semantics (reference adam_op.h sparse branch):
-        # only touched rows' moments decay/update; duplicates are merged
-        # first so the nonlinear moment math sees each row's summed grad
-        # once. Scattered as deltas — padding slots from the merge are
-        # exact no-ops.
+        # duplicates are merged first so the nonlinear moment math sees
+        # each row's summed grad once; lr is already bias-corrected,
+        # exactly what both paths apply per row (see adagrad above)
+        from ...ops import kernels
         uids, gm, valid = _merge_sparse(g, ctx)
-        # fused pallas path (see adagrad above); lr is already
-        # bias-corrected, exactly what the kernel applies per row
         if getattr(ctx, 'mesh', None) is None and \
                 use_kernel(ctx, 'sparse_adam'):
-            from ...ops.kernels import fused_sparse_adam
-            p_out, m1_out, m2_out = fused_sparse_adam(
+            p_out, m1_out, m2_out = kernels.fused_sparse_adam(
+                p, m1, m2, uids, gm, valid, lr, b1, b2, eps,
+                interpret=ctx.pallas_interpret)
+        else:
+            p_out, m1_out, m2_out = kernels.sparse_adam_reference(
                 p, m1, m2, uids, gm, valid, lr, b1, b2, eps)
-            return {'ParamOut': p_out, 'Moment1Out': m1_out,
-                    'Moment2Out': m2_out}
-        vm = valid[:, None].astype(gm.dtype)
-        m1_rows, m2_rows = m1[uids], m2[uids]
-        m1_new = b1 * m1_rows + (1 - b1) * gm
-        m2_new = b2 * m2_rows + (1 - b2) * gm * gm
-        p_delta = -lr * m1_new / (jnp.sqrt(m2_new) + eps) * vm
-        return {'ParamOut': p.at[uids].add(p_delta),
-                'Moment1Out': m1.at[uids].add((m1_new - m1_rows) * vm),
-                'Moment2Out': m2.at[uids].add((m2_new - m2_rows) * vm)}
+        return {'ParamOut': p_out, 'Moment1Out': m1_out,
+                'Moment2Out': m2_out}
     g = data_of(g)
     m1_out = b1 * m1 + (1 - b1) * g
     m2_out = b2 * m2 + (1 - b2) * g * g

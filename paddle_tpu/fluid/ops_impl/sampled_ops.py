@@ -441,8 +441,9 @@ def _attention_lstm_beam_paged_step(ins, attrs, ctx):
         enc_pages = data_of(ins['EncPages'][0])
         mask_pages = data_of(ins['MaskPages'][0])
         enc_t = mask_t = None
-        attend = lambda q: paged_attention(q, enc_pages, mask_pages,
-                                           pt_enc, src_cap)
+        attend = lambda q: paged_attention(
+            q, enc_pages, mask_pages, pt_enc, src_cap,
+            interpret=ctx.pallas_interpret)
     else:
         attend = None
         enc, mask = _gather_paged_enc(ins, src_cap)
@@ -563,8 +564,9 @@ def _attention_lstm_spec_decode_step(ins, attrs, ctx):
         enc_pages = data_of(ins['EncPages'][0])
         mask_pages = data_of(ins['MaskPages'][0])
         enc = mask = None
-        attend = lambda q: paged_attention(q, enc_pages, mask_pages,
-                                           pt_enc, src_cap)
+        attend = lambda q: paged_attention(
+            q, enc_pages, mask_pages, pt_enc, src_cap,
+            interpret=ctx.pallas_interpret)
     else:
         attend = None
         enc, mask = _gather_paged_enc(ins, src_cap)  # [C, S, D]

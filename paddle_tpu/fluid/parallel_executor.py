@@ -22,7 +22,6 @@ import numpy as np
 import jax
 from jax.sharding import Mesh
 
-from . import core
 from .executor import Executor, global_scope
 from .framework import default_main_program
 
@@ -107,8 +106,7 @@ class ParallelExecutor(object):
         self._mesh = Mesh(np.asarray(devs), ('dp',))
         self._ndev = len(devs)
         self._axes = (('dp', self._ndev),)
-        self._exe = Executor(core.TPUPlace(0) if core.is_compiled_with_tpu()
-                             else core.CPUPlace())
+        self._exe = Executor()
         if share_vars_from is not None:
             self._scope = share_vars_from._scope
 

@@ -41,7 +41,7 @@ Wiring: `PADDLE_TPU_OPT={off,default,aggressive}` gates the Executor
 Telemetry: every pass runs under a `passes.<name>` span and bumps
 `passes.<name>.ops_removed` / `.ops_inserted` counters, and the whole
 pipeline records `passes.optimize` with the total op-count delta, so
-`obs_report` and `bench_sentinel` can attribute wins to passes.
+`obs_report` can attribute wins to passes.
 """
 import functools
 import inspect

@@ -867,7 +867,7 @@ class StepArtifact(object):
 # AOT warm signatures (docs/perf.md#aot): serialize the compiled-signature
 # set of a WARMED executor so a cold replica / elastic restart reaches its
 # first step (first token) with ZERO online compiles. The executable bytes
-# are the persistent XLA compilation cache's (PADDLE_TPU_COMPILE_CACHE) —
+# are the persistent XLA compilation cache's (utils/compile_cache.py) —
 # this packages them WITH a typed manifest of every warm signature (feed
 # names/shapes/dtypes, fetches, donation plan, program fingerprint), so the
 # blob travels across machines and `tools/program_lint.py --aot` can detect
@@ -976,7 +976,7 @@ def write_aot(dirname, executor):
     """Export the executor's warm signature set: the manifest plus the
     persistent-compile-cache entries (the serialized XLA executables)
     under `dirname/xla_cache/`. Requires the executor to have been
-    constructed with PADDLE_TPU_COMPILE_CACHE wired — the on-disk
+    constructed with the compilation cache wired — the on-disk
     executable IS the AOT payload; without it there is nothing
     transportable to export. Returns (manifest_path, manifest)."""
     import json
@@ -985,9 +985,10 @@ def write_aot(dirname, executor):
     if not src or not os.path.isdir(src):
         raise RuntimeError(
             'export_warm_signatures needs the persistent compilation '
-            'cache: construct the Executor with PADDLE_TPU_COMPILE_CACHE='
-            '<dir> set, warm the signature set, then export — the cached '
-            'XLA executables are the AOT payload (docs/perf.md#aot)')
+            'cache: construct the Executor with JAX_COMPILATION_CACHE_DIR='
+            '<dir> set (or after utils.compile_cache.enable()), warm the '
+            'signature set, then export — the cached XLA executables are '
+            'the AOT payload (docs/perf.md#aot)')
     man = aot_manifest(executor)
     if not man['signatures']:
         raise RuntimeError(

@@ -113,10 +113,7 @@ class CheckpointConfig(object):
 
 def check_and_get_place(place):
     """reference trainer.py:143 — default to the TPU when present."""
-    if place is None:
-        return (core.TPUPlace(0) if core.is_compiled_with_tpu()
-                else core.CPUPlace())
-    return place
+    return core.default_place() if place is None else place
 
 
 def build_feed_var_list(program, feed_order=None):

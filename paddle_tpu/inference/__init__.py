@@ -47,8 +47,7 @@ class Predictor(object):
             from ..ops import kernels as kernels_mod
             kernels_mod.configure(kernels)
         self._scope = Scope()
-        self._place = place or (core.TPUPlace(0) if core.is_compiled_with_tpu()
-                                else core.CPUPlace())
+        self._place = place or core.default_place()
         self._exe = Executor(self._place)
         prog, feeds, fetches = io.load_inference_model(dirname, self._exe,
                                                        scope=self._scope)
@@ -102,8 +101,6 @@ def export_compiled(dirname, feed_example, target_vars, executor,
 
     import jax
     import jax.numpy as jnp
-    # jax>=0.4.30 ships export as a real submodule that must be imported
-    # explicitly (the bare `jax.export` attribute was removed)
     from jax import export as jax_export
 
     from ..fluid import framework

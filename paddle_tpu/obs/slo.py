@@ -21,8 +21,8 @@ one of three TYPED outcomes:
                 otherwise every budget file would need a per-workload
                 variant; pass `strict_missing=True` to make it one.
 
-Consumed by tools/serve_bench.py --slo (exit nonzero on violation),
-tools/slo_report.py, and tools/bench_sentinel.sh (hard gate).
+Consumed by tools/serve_bench.py --slo (exit nonzero on violation) and
+tools/slo_report.py.
 stdlib-only (see metrics.py for why).
 """
 import json

@@ -8,8 +8,8 @@ optimizers) that the lowering rules dispatch into behind the
 per-kernel `PADDLE_TPU_KERNELS` knob (docs/perf.md#kernel-layer).
 """
 from .flash_attention import flash_attention, flash_attention_lse, \
-    reference_attention
+    flash_attention_sharded, reference_attention
 from . import kernels
 
-__all__ = ['flash_attention', 'flash_attention_lse', 'reference_attention',
-           'kernels']
+__all__ = ['flash_attention', 'flash_attention_lse',
+           'flash_attention_sharded', 'reference_attention', 'kernels']

@@ -4,9 +4,12 @@
 kernel registers under a NAME, ships alongside the pure-XLA lowering it
 replaces (the fallback contract — with the kernel disabled the op's
 lowering is byte-identical to the pre-kernel code path, because the
-dispatch sites keep the original jnp code as the `else` branch), and
-runs under the pallas interpreter off-TPU so tier-1 drills the real
-kernel bodies on `JAX_PLATFORMS=cpu`.
+dispatch sites keep the original jnp code as the `else` branch). Each
+kernel takes a required `interpret=`: the dispatch sites pass
+`ctx.pallas_interpret` (Mosaic where the step's arrays live on TPUs,
+the pallas interpreter elsewhere, so tier-1 drills the real kernel
+bodies on `JAX_PLATFORMS=cpu`); nothing reads the process's default
+backend.
 
 Enablement is per-kernel, resolved at TRACE time (the decision is baked
 into the compiled module; the Executor keys its step cache on
@@ -35,10 +38,11 @@ import os
 from ... import obs
 
 __all__ = ['register_kernel', 'available', 'enabled', 'configure',
-           'signature', 'note_dispatch', 'interpret_default',
+           'signature', 'note_dispatch',
            'ENV_KERNELS',
            'paged_attention', 'paged_attention_reference',
-           'fused_sparse_adagrad', 'fused_sparse_adam']
+           'fused_sparse_adagrad', 'fused_sparse_adam',
+           'sparse_adagrad_reference', 'sparse_adam_reference']
 
 ENV_KERNELS = 'PADDLE_TPU_KERNELS'
 
@@ -131,16 +135,7 @@ def note_dispatch(name, used):
               mode='kernel' if used else 'fallback')
 
 
-def interpret_default():
-    """Pallas interpret mode default: real Mosaic lowering only on a TPU
-    backend, the (slow, exact) interpreter everywhere else — the
-    ops/flash_attention.py convention that keeps tier-1 green on
-    JAX_PLATFORMS=cpu while still executing the kernel bodies."""
-    import jax
-    return jax.default_backend() != 'tpu'
-
-
 from .paged_attention import paged_attention, \
     paged_attention_reference  # noqa: E402
-from .sparse_optim import fused_sparse_adagrad, \
-    fused_sparse_adam  # noqa: E402
+from .sparse_optim import fused_sparse_adagrad, fused_sparse_adam, \
+    sparse_adagrad_reference, sparse_adam_reference  # noqa: E402

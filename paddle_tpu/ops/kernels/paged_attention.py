@@ -23,8 +23,14 @@ vs `paged_attention_reference` is tolerance-bounded, not bitwise:
 |kernel - oracle| <= 1e-5 + 1e-5*|oracle| on fp32 (tests/test_kernels.py
 drills it; docs/perf.md carries the table).
 
-On-chip alignment: D and page_size should be multiples of the (8, 128)
-fp32 tile for Mosaic; the interpreter (CPU tier-1) takes any shape.
+Mosaic shape rules this file is written around (jax 0.9 / libtpu 0.0.34,
+tests/test_kernels.py export-lowering test + chip_smoke.py): the last two
+dims of every block must be (8, 128)-divisible or equal the array's, so
+the [Pe, ps] mask pool rides as [Pe, 1, ps] with a leading blocked dim;
+and the online-softmax statistics stay 2-D ([beam, 1] slices of the
+lane-broadcast scratch) — Mosaic has no layout for the 1-D vectors a
+`m_s[:, 0]` read would make. `interpret` is the caller's decision (the
+dispatch sites pass `ctx.pallas_interpret`; tests pass True).
 """
 import functools
 
@@ -34,7 +40,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import register_kernel, interpret_default
+from . import register_kernel
 
 PAGED_ATTENTION = register_kernel(
     'paged_attention',
@@ -82,10 +88,11 @@ def _kernel(pt_ref, q_ref, page_ref, mask_ref, o_ref, m_s, l_s, acc_s, *,
 
     q = q_ref[0].astype(jnp.float32)                    # [beam, D]
     kpage = page_ref[0].astype(jnp.float32)             # [ps, D]
-    mrow = mask_ref[0].astype(jnp.float32)              # [ps]
+    mrow = mask_ref[0].astype(jnp.float32)              # [1, ps]
     beam = q.shape[0]
-    s = jnp.dot(q, kpage.T, preferred_element_type=jnp.float32)
-    s = jnp.where(mrow[None, :] > 0, s, NEG_MASKED)
+    s = lax.dot_general(q, kpage, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+    s = jnp.where(mrow > 0, s, NEG_MASKED)
     # positions >= src_cap are SLICED off by the oracle; -inf contributes
     # exp(-inf)=0 to the online sum (every page starts below src_cap, so
     # the running max never stays -inf)
@@ -93,36 +100,38 @@ def _kernel(pt_ref, q_ref, page_ref, mask_ref, o_ref, m_s, l_s, acc_s, *,
         jnp.int32, (beam, page_size), 1)
     s = jnp.where(pos < src_cap, s, -jnp.inf)
 
-    m_prev = m_s[:, 0]
-    l_prev = l_s[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_s[:, :1]                                 # [beam, 1]
+    l_prev = l_s[:, :1]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + p.sum(axis=-1)
-    acc_s[:] = acc_s[:] * alpha[:, None] + jnp.dot(
+    l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+    acc_s[:] = acc_s[:] * alpha + jnp.dot(
         p, kpage, preferred_element_type=jnp.float32)
-    m_s[:] = jnp.broadcast_to(m_new[:, None], m_s.shape)
-    l_s[:] = jnp.broadcast_to(l_new[:, None], l_s.shape)
+    m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
+    l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
 
     @pl.when(j == npe - 1)
     def _finish():
-        o_ref[0] = (acc_s[:] / jnp.maximum(l_new, 1e-30)[:, None]
+        o_ref[0] = (acc_s[:] / jnp.maximum(l_new, 1e-30)
                     ).astype(o_ref.dtype)
 
 
-def paged_attention(q, enc_pages, mask_pages, pt_enc, src_cap,
-                    interpret=None):
+def paged_attention(q, enc_pages, mask_pages, pt_enc, src_cap, *,
+                    interpret):
     """Fused page-gather attention: ctx [B, D] from q [B, D] against the
     paged encoder pool, one pallas call. Same contract as
-    `paged_attention_reference` (the dispatch sites' fallback)."""
-    if interpret is None:
-        interpret = interpret_default()
+    `paged_attention_reference` (the dispatch sites' fallback).
+    interpret=False compiles through Mosaic (TPU only); True runs the
+    body under the pallas interpreter."""
     pt = pt_enc.astype(jnp.int32)
     C, NPE = pt.shape
     ps, D = enc_pages.shape[1], enc_pages.shape[2]
     B = q.shape[0]
     beam = B // C
     qs = q.astype(jnp.float32).reshape(C, beam, D)
+    # [Pe, 1, ps]: the (1, 1, ps) block's last two dims equal the array's
+    masks = mask_pages.reshape(mask_pages.shape[0], 1, ps)
     kern = functools.partial(_kernel, page_size=ps, src_cap=int(src_cap))
     out = pl.pallas_call(
         kern,
@@ -132,7 +141,7 @@ def paged_attention(q, enc_pages, mask_pages, pt_enc, src_cap,
             in_specs=[
                 pl.BlockSpec((1, beam, D), lambda c, j, pt: (c, 0, 0)),
                 pl.BlockSpec((1, ps, D), lambda c, j, pt: (pt[c, j], 0, 0)),
-                pl.BlockSpec((1, ps), lambda c, j, pt: (pt[c, j], 0)),
+                pl.BlockSpec((1, 1, ps), lambda c, j, pt: (pt[c, j], 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, beam, D), lambda c, j, pt: (c, 0, 0)),
             scratch_shapes=[
@@ -142,6 +151,6 @@ def paged_attention(q, enc_pages, mask_pages, pt_enc, src_cap,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((C, beam, D), jnp.float32),
-        interpret=interpret,
-    )(pt, qs, enc_pages, mask_pages)
+        interpret=bool(interpret),
+    )(pt, qs, enc_pages, masks)
     return out.reshape(B, D)

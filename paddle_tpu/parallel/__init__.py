@@ -68,44 +68,12 @@ def init_distributed(coordinator_address=None, num_processes=None,
             'and process_id for a %r-process cluster (got %r, %r, %r)'
             % (num_processes, coordinator_address, num_processes,
                process_id))
-    _arm_cpu_collectives()
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=int(num_processes), process_id=int(process_id),
         local_device_ids=local_device_ids)
     return {'num_processes': int(num_processes),
             'process_id': int(process_id), 'initialized': True}
-
-
-def _arm_cpu_collectives():
-    """On the CPU platform, default the cross-process collectives
-    implementation to gloo BEFORE the backend initializes — without it
-    the old XLA CPU runtime raises "Multiprocess computations aren't
-    implemented" at the first cross-host dispatch. Only the 'none'
-    default is replaced (an explicit mpi/gloo choice wins); newer jax
-    without the flag, or a non-CPU platform, is a no-op."""
-    try:
-        plats = jax.config.jax_platforms
-    except AttributeError:
-        plats = None
-    # unset platform config means jax will AUTO-SELECT — which on a
-    # chipless host IS the CPU backend, exactly where the flag matters;
-    # only an explicit non-cpu platform choice skips the arming (the
-    # flag is inert on TPU/GPU backends anyway)
-    if plats and 'cpu' not in str(plats):
-        return
-    try:
-        cur = getattr(jax.config, 'jax_cpu_collectives_implementation',
-                      None)
-        if cur is None:
-            # jax<0.5 exposes it as a Flag holder, not a config attr
-            from jax._src.config import config as _jc
-            cur = _jc._value_holders[
-                'jax_cpu_collectives_implementation'].value
-        if cur in (None, 'none'):
-            jax.config.update('jax_cpu_collectives_implementation', 'gloo')
-    except Exception:
-        pass  # flag absent/renamed in this jax: leave the default
 
 
 def process_count():
@@ -183,7 +151,6 @@ def init_multihost(coordinator_address=None, num_processes=None,
     if (coordinator_address is None or process_id is None
             or num_processes in (None, 0, 1)):
         return False  # incomplete cluster description: single-host no-op
-    _arm_cpu_collectives()
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes, process_id=process_id,

@@ -13,7 +13,7 @@ def sp_shard_map(body, mesh, q, k, v, axis, key_bias, check_vma=True):
     also carries 'dp', the batch dim stays dp-sharded — each dp replica
     runs its own sequence ring/all_to_all over its batch slice instead of
     re-computing the global batch."""
-    from ._compat import shard_map
+    from jax import shard_map
 
     bdim = 'dp' if ('dp' in mesh.shape and axis != 'dp') else None
     if bdim is not None and q.shape[0] % mesh.shape['dp']:

@@ -148,7 +148,7 @@ def moe_apply(expert_fn, stacked_params, x, gate_logits, mesh, axis='ep',
         raise ValueError(
             'gate_logits last dim %d must equal the stacked expert count %d'
             % (gate_logits.shape[-1], n_exp))
-    from ._compat import shard_map
+    from jax import shard_map
 
     def body(params, xs, logits):
         # params leaves [epd, ...]: this device's expert block — expert e

@@ -98,7 +98,7 @@ def pipeline_apply(stage_fn, stacked_params, microbatches, mesh, axis='pp',
         raise ValueError(
             'circular pipeline (n_virtual=%d) injects microbatches in '
             'rounds of S=%d; n_micro=%d is not a multiple' % (v, S, n_micro))
-    from ._compat import shard_map
+    from jax import shard_map
     n_stream = len(extras_streamed)
 
     # [v*S, ...] sequential chunk order -> [v, S, ...]: row p column d is
@@ -135,20 +135,13 @@ def pipeline_apply(stage_fn, stacked_params, microbatches, mesh, axis='pp',
         # the context-mesh check when jax transposes the constraint in the
         # backward pass, and a bare PartitionSpec is too weak to stop the
         # partitioner's replicate-then-repartition on the matmul cotangent
-        try:
-            from jax.sharding import AxisType, Mesh as _Mesh, NamedSharding
-        except ImportError:
-            # jax<0.6 has no AxisType; skip the pin — a PERFORMANCE hint
-            # (stops replicate-then-repartition on the cotangent), never
-            # a correctness requirement
-            _tp_replicated = lambda t: t
-        else:
-            pin_mesh = _Mesh(
-                mesh.devices, mesh.axis_names,
-                axis_types=tuple(AxisType.Manual if n in manual_set
-                                 else AxisType.Auto for n in mesh.axis_names))
-            _tp_replicated = lambda t: lax.with_sharding_constraint(
-                t, NamedSharding(pin_mesh, P()))
+        from jax.sharding import AxisType, Mesh as _Mesh, NamedSharding
+        pin_mesh = _Mesh(
+            mesh.devices, mesh.axis_names,
+            axis_types=tuple(AxisType.Manual if n in manual_set
+                             else AxisType.Auto for n in mesh.axis_names))
+        _tp_replicated = lambda t: lax.with_sharding_constraint(
+            t, NamedSharding(pin_mesh, P()))
     else:
         _tp_replicated = lambda t: t
 

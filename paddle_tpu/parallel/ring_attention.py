@@ -11,7 +11,6 @@ concurrently with the local attention block (compute hides comm).
 Use inside shard_map (ring_attention) or via the pjit-level wrapper
 (ring_self_attention) which sets up the shard_map over a Mesh axis.
 """
-import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -19,14 +18,15 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 NEG_BIG = -1e9
 
 
-def _resolve_impl(impl):
-    """None -> env override or backend default; typos raise rather than
-    silently running the O(Tl^2) dense body."""
+def _resolve_impl(impl, interpret):
+    """None -> env override, else the kernel where it compiles (flash when
+    the caller says Mosaic, dense when it says interpreter — the
+    interpreted kernel is a test vehicle, not a path); typos raise rather
+    than silently running the O(Tl^2) dense body."""
     if impl is None:
         import os
-        impl = os.environ.get(
-            'PADDLE_TPU_RING_IMPL',
-            'flash' if jax.default_backend() == 'tpu' else 'dense')
+        impl = os.environ.get('PADDLE_TPU_RING_IMPL',
+                              'dense' if interpret else 'flash')
     if impl not in ('flash', 'dense'):
         raise ValueError(
             "ring attention impl must be 'flash' or 'dense', got %r" % impl)
@@ -34,7 +34,7 @@ def _resolve_impl(impl):
 
 
 def ring_attention(q, k, v, axis_name, key_bias=None, causal=False,
-                   sm_scale=None, impl=None):
+                   sm_scale=None, impl=None, *, interpret):
     """Per-shard body (call inside shard_map).
 
     q, k, v: [B, H, T_local, D] — the sequence axis sharded over axis_name.
@@ -42,13 +42,16 @@ def ring_attention(q, k, v, axis_name, key_bias=None, causal=False,
     impl: 'flash' runs each local block through the pallas flash kernel
         (no [Tl, Tl] score matrix ever materializes — the long-context MXU
         path) and merges ring steps with logsumexp statistics; 'dense' is
-        the plain-XLA einsum body. None auto-selects flash on TPU
-        (overridable with PADDLE_TPU_RING_IMPL).
+        the plain-XLA einsum body. None selects flash when the kernel
+        compiles (interpret=False), dense otherwise (overridable with
+        PADDLE_TPU_RING_IMPL).
+    interpret: the caller's pallas-mode decision for the flash body
+        (ops/flash_attention.py): False on a TPU mesh, True off it.
     """
-    impl = _resolve_impl(impl)
+    impl = _resolve_impl(impl, interpret)
     if impl == 'flash':
         return _ring_attention_flash(q, k, v, axis_name, key_bias, causal,
-                                     sm_scale)
+                                     sm_scale, interpret)
     n = lax.psum(1, axis_name)
     idx = lax.axis_index(axis_name)
     B, H, Tl, D = q.shape
@@ -95,7 +98,8 @@ def ring_attention(q, k, v, axis_name, key_bias=None, causal=False,
     return (acc / l[..., None]).astype(q.dtype)
 
 
-def _ring_attention_flash(q, k, v, axis_name, key_bias, causal, sm_scale):
+def _ring_attention_flash(q, k, v, axis_name, key_bias, causal, sm_scale,
+                          interpret):
     """Ring schedule with the pallas flash kernel as the per-step block.
 
     Each ring step computes (o_s, lse_s) = flash(q_local, kv_shard); steps
@@ -134,11 +138,13 @@ def _ring_attention_flash(q, k, v, axis_name, key_bias, causal, sm_scale):
         if causal:
             def visible(kc=kc, vc=vc, kbc=kbc):
                 return flash_attention_lse(q, kc, vc, key_bias=kbc,
-                                           causal=False, sm_scale=sm_scale)
+                                           causal=False, sm_scale=sm_scale,
+                                           interpret=interpret)
 
             def diagonal(kc=kc, vc=vc, kbc=kbc):
                 return flash_attention_lse(q, kc, vc, key_bias=kbc,
-                                           causal=True, sm_scale=sm_scale)
+                                           causal=True, sm_scale=sm_scale,
+                                           interpret=interpret)
 
             def masked():
                 return (jnp.zeros((B, H, Tl, D), q.dtype),
@@ -149,7 +155,8 @@ def _ring_attention_flash(q, k, v, axis_name, key_bias, causal, sm_scale):
                 lambda: lax.cond(src == idx, diagonal, visible))
         else:
             o_s, lse_s = flash_attention_lse(q, kc, vc, key_bias=kbc,
-                                             causal=False, sm_scale=sm_scale)
+                                             causal=False, sm_scale=sm_scale,
+                                             interpret=interpret)
         o, lse = merge(o, lse, o_s, lse_s)
         if s != n - 1:   # the last shard needs no further rotation
             kc = lax.ppermute(kc, axis_name, perm)
@@ -159,14 +166,17 @@ def _ring_attention_flash(q, k, v, axis_name, key_bias, causal, sm_scale):
 
 
 def ring_self_attention(mesh, q, k, v, axis='sp', key_bias=None,
-                        causal=False, sm_scale=None, impl=None):
+                        causal=False, sm_scale=None, impl=None, *,
+                        interpret):
     """pjit-level entry: q/k/v [B, H, T, D] with T sharded over mesh axis."""
     from ._sp import sp_shard_map
-    impl = _resolve_impl(impl)  # resolve HERE so check_vma is exact
+    # resolve HERE so check_vma is exact
+    impl = _resolve_impl(impl, interpret)
 
     def body(q, k, v, kb):
         return ring_attention(q, k, v, axis, key_bias=kb, causal=causal,
-                              sm_scale=sm_scale, impl=impl)
+                              sm_scale=sm_scale, impl=impl,
+                              interpret=interpret)
 
     # pallas ShapeDtypeStructs carry no varying-mesh-axes info, so the vma
     # check must be off when the flash body runs

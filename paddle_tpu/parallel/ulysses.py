@@ -23,12 +23,14 @@ __all__ = ['ulysses_attention', 'ulysses_self_attention']
 
 
 def ulysses_attention(q, k, v, axis_name, key_bias=None, causal=False,
-                      sm_scale=None):
+                      sm_scale=None, *, interpret):
     """Per-shard body (call inside shard_map).
 
     q, k, v: [B, H, T_local, D] with the sequence axis sharded over
     axis_name; H must be divisible by the axis size. key_bias is the
-    LOCAL [B, T_local] additive key bias (or None).
+    LOCAL [B, T_local] additive key bias (or None). interpret is the
+    caller's pallas-mode decision for the local flash kernel
+    (ops/flash_attention.py): False on a TPU mesh, True off it.
     """
     # seq-sharded -> head-sharded: each device now owns H/n heads, full T
     qg = lax.all_to_all(q, axis_name, split_axis=1, concat_axis=2,
@@ -41,14 +43,14 @@ def ulysses_attention(q, k, v, axis_name, key_bias=None, causal=False,
     if key_bias is not None:
         kb = lax.all_gather(key_bias, axis_name, axis=1, tiled=True)
     out = flash_attention(qg, kg, vg, key_bias=kb, causal=causal,
-                          sm_scale=sm_scale)
+                          sm_scale=sm_scale, interpret=interpret)
     # head-sharded -> seq-sharded
     return lax.all_to_all(out, axis_name, split_axis=2, concat_axis=1,
                           tiled=True)
 
 
 def ulysses_self_attention(mesh, q, k, v, axis='sp', key_bias=None,
-                           causal=False, sm_scale=None):
+                           causal=False, sm_scale=None, *, interpret):
     """pjit-level entry: q/k/v [B, H, T, D] with T sharded over mesh
     axis `axis` (same contract as ring_self_attention)."""
     n = mesh.shape[axis]
@@ -60,7 +62,7 @@ def ulysses_self_attention(mesh, q, k, v, axis='sp', key_bias=None,
 
     def body(q, k, v, kb):
         return ulysses_attention(q, k, v, axis, key_bias=kb, causal=causal,
-                                 sm_scale=sm_scale)
+                                 sm_scale=sm_scale, interpret=interpret)
 
     return sp_shard_map(body, mesh, q, k, v, axis, key_bias,
                         check_vma=False)  # pallas flash kernel inside

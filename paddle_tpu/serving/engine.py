@@ -406,7 +406,7 @@ class ServingEngine(object):
         (any row count >= 1) — or, when the model publishes a fully
         static `input_spec`, a zeros feed. Returns the bucket list.
 
-        With PADDLE_TPU_COMPILE_CACHE set (docs/perf.md), a RESTARTED
+        With JAX_COMPILATION_CACHE_DIR set (docs/perf.md), a RESTARTED
         server's warmup deserializes every bucket's executable from the
         persistent cache instead of re-compiling: each serving.warmup
         span then carries cache='persistent_hit' and the run log shows

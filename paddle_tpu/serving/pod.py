@@ -159,9 +159,7 @@ class ShardedPredictor(object):
                 % (model_dir,))
         prog.set_mesh(dict(axes), data_axis=data_axis)
         self._scope = Scope()
-        self._place = place or (core.TPUPlace(0)
-                                if core.is_compiled_with_tpu()
-                                else core.CPUPlace())
+        self._place = place or core.default_place()
         self._exe = Executor(self._place)
         self._program = prog
         self.feed_names = list(meta['feed_names'])

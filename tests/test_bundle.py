@@ -1,7 +1,7 @@
 """Pipelined training hot loop (ISSUE 4): K-step bundling via
 Executor.run_bundle / Trainer(bundle_steps=K), the async fetch window
 (run(sync='async') FetchHandles + Trainer in-flight window), and the
-persistent XLA compilation cache (PADDLE_TPU_COMPILE_CACHE).
+persistent XLA compilation cache (JAX_COMPILATION_CACHE_DIR).
 
 Equivalence contract proved here:
   - K=1 vs K=4 bundles reach BIT-IDENTICAL parameters (the scan body
@@ -10,7 +10,7 @@ Equivalence contract proved here:
     run() calls and one K-bundle (same seed integers, same keys);
   - the anomaly guard skips/rolls back PER INNER STEP inside a bundle
     exactly as it does unbundled, and escalation still fires;
-  - a second process over the same PADDLE_TPU_COMPILE_CACHE dir records
+  - a second process over the same JAX_COMPILATION_CACHE_DIR records
     ZERO executor.compile spans for already-cached keys.
 """
 import gc
@@ -498,7 +498,7 @@ def test_persistent_cache_second_process_zero_compiles(tmp_path):
     def run_child(obs_dir):
         env = dict(os.environ,
                    JAX_PLATFORMS='cpu',
-                   PADDLE_TPU_COMPILE_CACHE=str(cache),
+                   JAX_COMPILATION_CACHE_DIR=str(cache),
                    PADDLE_TPU_OBS_DIR=str(obs_dir))
         env.pop('PADDLE_TPU_OBS_RUN_FILE', None)
         r = subprocess.run([sys.executable, '-c', _CHILD],

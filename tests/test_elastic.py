@@ -354,14 +354,7 @@ _TORN_CHILD = r"""
 import os, sys, time
 import jax
 jax.config.update('jax_platforms', 'cpu')
-try:
-    jax.config.update('jax_num_cpu_devices', 2)
-except AttributeError:
-    # jax<0.5: the XLA flag is the fallback spelling — ONLY then (newer
-    # jax rejects having both mechanisms set); the backend has not
-    # initialized yet, so setting it post-import still applies
-    os.environ['XLA_FLAGS'] = (os.environ.get('XLA_FLAGS', '')
-                               + ' --xla_force_host_platform_device_count=2')
+jax.config.update('jax_num_cpu_devices', 2)
 import numpy as np
 from paddle_tpu.utils import checkpoint as ck
 
@@ -733,12 +726,7 @@ _MP_CHILD = r"""
 import os, sys, time, signal, json
 import jax
 jax.config.update('jax_platforms', 'cpu')
-try:
-    jax.config.update('jax_num_cpu_devices', 4)
-except AttributeError:
-    # jax<0.5 fallback; never set BOTH (newer jax rejects the combo)
-    os.environ['XLA_FLAGS'] = (os.environ.get('XLA_FLAGS', '')
-                               + ' --xla_force_host_platform_device_count=4')
+jax.config.update('jax_num_cpu_devices', 4)
 import numpy as np
 from paddle_tpu import parallel
 import paddle_tpu.fluid as fluid

@@ -97,7 +97,8 @@ def test_ring_attention_matches_full():
     for causal in (False, True):
         got = ring_self_attention(mesh, jnp.asarray(q), jnp.asarray(k),
                                   jnp.asarray(v), axis='sp',
-                                  key_bias=jnp.asarray(kb), causal=causal)
+                                  key_bias=jnp.asarray(kb), causal=causal,
+                                  interpret=True)
         want = ops.reference_attention(q, k, v, key_bias=kb, causal=causal)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5,
@@ -119,14 +120,16 @@ def test_ulysses_attention_matches_full_and_ring():
     for causal in (False, True):
         got = ulysses_self_attention(mesh, jnp.asarray(q), jnp.asarray(k),
                                      jnp.asarray(v), axis='sp',
-                                     key_bias=jnp.asarray(kb), causal=causal)
+                                     key_bias=jnp.asarray(kb), causal=causal,
+                                  interpret=True)
         want = ops.reference_attention(q, k, v, key_bias=kb, causal=causal)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5,
                                    err_msg='causal=%s' % causal)
         ring = ring_self_attention(mesh, jnp.asarray(q), jnp.asarray(k),
                                    jnp.asarray(v), axis='sp',
-                                   key_bias=jnp.asarray(kb), causal=causal)
+                                   key_bias=jnp.asarray(kb), causal=causal,
+                                  interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ring),
                                    rtol=2e-5, atol=2e-5)
 
@@ -138,7 +141,7 @@ def test_ulysses_rejects_indivisible_heads():
     mesh = parallel.make_mesh({'sp': 8})
     q = jnp.zeros((1, 3, 16, 4), jnp.float32)   # 3 heads, sp=8
     with pytest.raises(ValueError, match='ring_self_attention'):
-        ulysses_self_attention(mesh, q, q, q, axis='sp')
+        ulysses_self_attention(mesh, q, q, q, axis='sp', interpret=True)
 
 
 def test_forward_multiblock_grids():
@@ -233,7 +236,8 @@ def test_ring_attention_flash_impl_matches_dense_and_full():
     kb = jnp.asarray(kbn)
     for causal in (False, True):
         got = ring_self_attention(mesh, q, k, v, axis='sp', key_bias=kb,
-                                  causal=causal, impl='flash')
+                                  causal=causal, impl='flash',
+                                  interpret=True)
         want = ops.reference_attention(q, k, v, key_bias=kb, causal=causal)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=3e-5, atol=3e-5,
@@ -241,7 +245,8 @@ def test_ring_attention_flash_impl_matches_dense_and_full():
 
         def loss_ring(q, k, v, _c=causal):
             o = ring_self_attention(mesh, q, k, v, axis='sp', key_bias=kb,
-                                    causal=_c, impl='flash')
+                                    causal=_c, impl='flash',
+                                    interpret=True)
             return jnp.sum(o * jnp.cos(o))
 
         def loss_full(q, k, v, _c=causal):
@@ -301,3 +306,38 @@ def test_causal_triangular_grid_3x3_forward_and_grads():
     for a, b, name in zip(g1, g2, 'qkv'):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-3, atol=1e-4, err_msg=name)
+
+
+def test_flash_under_a_mesh_lowers_only_per_shard():
+    """ISSUE 21, found on four chips: a bare Mosaic call inside a
+    GSPMD-partitioned jit does not get all-gathered operands — jax refuses
+    to lower it. The lowering rule therefore routes through
+    flash_attention_sharded under a mesh; jax.export for the TPU platform
+    shows both outcomes without a chip."""
+    from jax import export
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu import parallel
+    mesh = parallel.make_mesh({"dp": 2, "tp": 2})
+    sh = NamedSharding(mesh, P('dp', 'tp', None, None))
+    x = jax.ShapeDtypeStruct((4, 4, 256, 64), jnp.bfloat16, sharding=sh)
+
+    def lower(fn):
+        return export.export(jax.jit(fn, in_shardings=(sh, sh, sh),
+                                     out_shardings=sh),
+                             platforms=['tpu'])(x, x, x).mlir_module()
+
+    with pytest.raises(NotImplementedError, match='shard_map'):
+        lower(lambda q, k, v: ops.flash_attention(
+            q, k, v, causal=True, interpret=False))
+    assert 'tpu_custom_call' in lower(
+        lambda q, k, v: ops.flash_attention_sharded(
+            mesh, q, k, v, causal=True, interpret=False))
+    # and the per-shard call computes what the reference computes
+    r = np.random.RandomState(9)
+    q, k, v = [jnp.asarray(r.randn(4, 4, 256, 64).astype('float32'))
+               for _ in range(3)]
+    got = ops.flash_attention_sharded(mesh, q, k, v, causal=True,
+                                      interpret=True)
+    want = ops.reference_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=3e-5, atol=3e-5)

@@ -501,3 +501,75 @@ def test_dispatch_events_and_report_section(tmp_path):
         assert 'paged_attention: 2 kernel trace(s)' in text
     finally:
         obs._reset()
+
+
+# ---------------------------------------------------------------------------
+# TPU lowering without a chip: jax.export for platforms=['tpu'] runs the
+# Pallas -> Mosaic lowering (block-shape rules, memory spaces) on the host.
+# It is how the (1, ps) mask block and the (1, d) row blocks were found
+# refused; what Mosaic itself then accepts is chip_smoke.py's kernel leg.
+# ---------------------------------------------------------------------------
+
+def _export_cases():
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import ops
+    S = jax.ShapeDtypeStruct
+    f32, i32 = jnp.float32, jnp.int32
+    qkv = [S((8, 8, 1024, 64), jnp.bfloat16)] * 3
+    tables = [S((4096, 128), f32)] * 3
+    rows = [S((256,), i32), S((256, 128), f32), S((256,), i32), S((), f32)]
+
+    def flash(causal):
+        return lambda q, k, v: ops.flash_attention(
+            q, k, v, causal=causal, interpret=False)
+
+    def flash_grad(causal):
+        return jax.grad(lambda q, k, v: flash(causal)(q, k, v).astype(
+            f32).sum(), argnums=(0, 1, 2))
+
+    return {
+        'flash_fwd': (flash(False), qkv),
+        'flash_fwd_causal': (flash(True), qkv),
+        'flash_bwd': (flash_grad(False), qkv),
+        'flash_bwd_causal': (flash_grad(True), qkv),
+        'paged_attention': (
+            functools.partial(kernels.paged_attention, src_cap=507,
+                              interpret=False),
+            [S((64, 128), f32), S((64, 128, 128), f32), S((64, 128), f32),
+             S((8, 4), i32)]),
+        'sparse_adagrad': (
+            lambda p, m, *r: kernels.fused_sparse_adagrad(
+                p, m, *r, 1e-6, interpret=False), tables[:2] + rows),
+        'sparse_adam': (
+            lambda p, m1, m2, *r: kernels.fused_sparse_adam(
+                p, m1, m2, *r, 0.9, 0.999, 1e-8, interpret=False),
+            tables + rows),
+    }
+
+
+def test_every_kernel_lowers_for_tpu():
+    import jax
+    from jax import export
+    cases = _export_cases()
+    assert set(kernels.available()) <= set(cases), \
+        'a registered kernel has no TPU lowering case'
+    for name, (fn, args) in cases.items():
+        exported = export.export(jax.jit(fn), platforms=['tpu'])(*args)
+        assert 'tpu_custom_call' in exported.mlir_module(), name
+
+
+def test_kernels_take_interpret_from_the_caller():
+    """No kernel reads the process's default backend: leaving interpret
+    out is an error, not a quiet trip through the interpreter."""
+    import jax.numpy as jnp
+    from paddle_tpu import ops
+    q = jnp.zeros((1, 1, 8, 4), jnp.float32)
+    with pytest.raises(TypeError):
+        ops.flash_attention(q, q, q)
+    with pytest.raises(TypeError):
+        ops.flash_attention(q, q, q, interpret=None)
+    with pytest.raises(TypeError):
+        kernels.paged_attention(q, q, q, q, 1)
+    assert not hasattr(kernels, 'interpret_default')

@@ -19,14 +19,7 @@ _CHILD = r"""
 import os
 import jax
 jax.config.update('jax_platforms', 'cpu')
-try:
-    jax.config.update('jax_num_cpu_devices', 2)
-except AttributeError:
-    # jax<0.5 fallback spelling — only then (newer jax rejects having
-    # both the config and the XLA flag); backend not yet initialized,
-    # so the env var still applies post-import
-    os.environ['XLA_FLAGS'] = (os.environ.get('XLA_FLAGS', '')
-                               + ' --xla_force_host_platform_device_count=2')
+jax.config.update('jax_num_cpu_devices', 2)
 import numpy as np
 from paddle_tpu import parallel
 

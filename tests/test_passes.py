@@ -653,7 +653,7 @@ def test_book_model_off_vs_default_equivalent(name):
 def test_book_model_op_count_reduction_reported():
     """At least one real model must show an op-count REDUCTION, reported
     through the passes.* obs counters (the attribution contract for
-    obs_report / bench_sentinel): label_semantic_roles builds a CRF
+    obs_report): label_semantic_roles builds a CRF
     decode path the training fetch never uses — dead for the cost-only
     fetch set the trainer runs."""
     from paddle_tpu import models

@@ -264,17 +264,6 @@ def test_pipeline_clone_and_inference_model_roundtrip(tmp_path):
         exe.run(main, feed={'x': xs, 'y': ys}, fetch_list=[cost])
 
 
-@pytest.mark.xfail(
-    not hasattr(__import__('jax'), 'shard_map'),
-    reason='pipeline x dp composition diverges numerically (~13% on the '
-           'first loss) under the pre-0.6 shard_map compat shim '
-           '(parallel/_compat.py maps axis_names/check_vma onto '
-           'experimental auto=/check_rep, whose partial-manual handling '
-           'mis-reduces the dp gradient all-reduce inside the GPipe '
-           'ring). Pre-existing at the seed (PR 3 notes); needs the real '
-           'jax>=0.6 shard_map or a dedicated dp-aware pipeline body to '
-           'fix — tracked, not worth forking the ring collectives for a '
-           'legacy jax.', strict=False)
 @pytest.mark.parametrize('order', ['dp_first', 'pp_first'])
 def test_pipeline_composes_with_dp(order):
     """dp x pp: DistributeTranspiler + PipelineTranspiler in either

@@ -1385,11 +1385,7 @@ _POD_CHILD = r"""
 import os, sys, time
 import jax
 jax.config.update('jax_platforms', 'cpu')
-try:
-    jax.config.update('jax_num_cpu_devices', 8)
-except AttributeError:
-    os.environ['XLA_FLAGS'] = (os.environ.get('XLA_FLAGS', '')
-                               + ' --xla_force_host_platform_device_count=8')
+jax.config.update('jax_num_cpu_devices', 8)
 import numpy as np
 from paddle_tpu import serving
 

@@ -515,10 +515,7 @@ def _run_aot_child(mode, aot_dir, cache_dir, obs_dir):
     env = dict(os.environ, JAX_PLATFORMS='cpu',
                PADDLE_TPU_OBS_DIR=str(obs_dir))
     env.pop('PADDLE_TPU_OBS_RUN_FILE', None)
-    if cache_dir is not None:
-        env['PADDLE_TPU_COMPILE_CACHE'] = str(cache_dir)
-    else:
-        env.pop('PADDLE_TPU_COMPILE_CACHE', None)
+    env['JAX_COMPILATION_CACHE_DIR'] = str(cache_dir)
     r = subprocess.run(
         [sys.executable, '-c', _AOT_CHILD % {'repo': _REPO}, mode,
          str(aot_dir)],
@@ -535,10 +532,11 @@ def _run_aot_child(mode, aot_dir, cache_dir, obs_dir):
 
 
 def test_aot_export_warms_cold_process_to_zero_compiles(tmp_path):
-    """The cold-replica contract: a fresh process (no pre-wired compile
-    cache at all) that loads the exported blob reaches its first step
-    AND first bundle with ZERO executor.compile spans — every first call
-    classifies aot_hit."""
+    """The cold-replica contract: a fresh process whose compile cache
+    directory is EMPTY (the environment places it; load_warm_signatures
+    copies the blob's entries in and never points jax anywhere else)
+    reaches its first step AND first bundle with ZERO executor.compile
+    spans — every first call classifies aot_hit."""
     aot = tmp_path / 'aot'
     stats1, ev1 = _run_aot_child('export', aot, tmp_path / 'cc',
                                  tmp_path / 'obs1')
@@ -549,7 +547,9 @@ def test_aot_export_warms_cold_process_to_zero_compiles(tmp_path):
     # startup + train artifacts, the train one with its K=2 bundle
     assert any(s['bundles'] == [2] for s in man['signatures'])
 
-    stats2, ev2 = _run_aot_child('import', aot, None, tmp_path / 'obs2')
+    stats2, ev2 = _run_aot_child('import', aot, tmp_path / 'cc_cold',
+                                 tmp_path / 'obs2')
+    assert stats2['compile_cache_dir'] == str(tmp_path / 'cc_cold')
     compiles2 = [e for e in ev2 if e['name'] == 'executor.compile']
     # the ONLY online compile is the deliberately un-warmed K=3 bundle —
     # and it classifies as an ordinary compile, never as a stale blob

@@ -33,8 +33,8 @@ at t=0 by default, `--mode open --qps R` for fixed-rate arrivals),
 reporting TTFT and per-token latency p50/p99
 plus tokens/sec for both (acceptance: >= 1.5x tokens/sec with zero
 steady-state compiles; `--check-speedup 1.5 --check-compiles` enforces
-it). Every record is stamped with the resolved platform + fallback flag,
-the PR 6 bench.py convention.
+it). Every record is stamped with the device jax reported (platform,
+device_kind, device_count), the bench.py convention.
 
 `--workload decode-paged` is the PAGED-CAPACITY A/B (dense-slot vs
 paged-memory engine at EQUAL state-buffer bytes: peak concurrent
@@ -62,30 +62,27 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 
 
-# Resolved platform context, stamped into EVERY emitted record (the PR 6
-# bench.py convention): `platform` is what the run actually executed on,
-# `fallback` is True when an accelerator was wanted (BENCH_PLATFORM) but
-# jax fell back to CPU — a CPU-fallback tokens/sec must never read as an
-# accelerator regression (tools/bench_sentinel.sh refuses the compare).
-_PLATFORM = [None]
-_FALLBACK = [None]
+# What jax reports for the device the workload runs on — platform,
+# device_kind, device_count — stamped into EVERY emitted record (the
+# bench.py convention). Taken in-process by _resolve_device(), which
+# initializes the backend and therefore HOLDS THE CHIP: a workload whose
+# children need the chip (aot-cold) must not call it, and takes the stamp
+# from its children's output instead.
+_DEVICE = {}
 
 
-def _resolve_platform():
-    if _PLATFORM[0] is None:
+def _resolve_device():
+    if not _DEVICE:
         import jax
-        plat = jax.devices()[0].platform
-        want = os.environ.get('BENCH_PLATFORM')
-        _PLATFORM[0] = plat
-        _FALLBACK[0] = (os.environ.get('BENCH_FALLBACK') == '1'
-                        or bool(want) and want != 'cpu' and plat == 'cpu')
-    return _PLATFORM[0], _FALLBACK[0]
+        d0 = jax.devices()[0]
+        _DEVICE.update(platform=d0.platform, device_kind=d0.device_kind,
+                       device_count=len(jax.devices()))
+    return _DEVICE
 
 
 def _emit(obj):
-    if _PLATFORM[0] is not None:
-        obj.setdefault('platform', _PLATFORM[0])
-        obj.setdefault('fallback', _FALLBACK[0])
+    for k, v in _DEVICE.items():
+        obj.setdefault(k, v)
     print(json.dumps(obj))
     sys.stdout.flush()
     if os.environ.get('PADDLE_TPU_OBS_DIR'):
@@ -701,11 +698,7 @@ _POD_PREP = r"""
 import os, sys
 import jax
 jax.config.update('jax_platforms', 'cpu')
-try:
-    jax.config.update('jax_num_cpu_devices', 8)
-except AttributeError:
-    os.environ['XLA_FLAGS'] = (os.environ.get('XLA_FLAGS', '')
-                               + ' --xla_force_host_platform_device_count=8')
+jax.config.update('jax_num_cpu_devices', 8)
 import numpy as np
 sys.path.insert(0, os.environ['PADDLE_TPU_REPO'])
 import paddle_tpu.fluid as fluid
@@ -761,11 +754,7 @@ _POD_WORKER = r"""
 import os, sys, time
 import jax
 jax.config.update('jax_platforms', 'cpu')
-try:
-    jax.config.update('jax_num_cpu_devices', 8)
-except AttributeError:
-    os.environ['XLA_FLAGS'] = (os.environ.get('XLA_FLAGS', '')
-                               + ' --xla_force_host_platform_device_count=8')
+jax.config.update('jax_num_cpu_devices', 8)
 sys.path.insert(0, os.environ['PADDLE_TPU_REPO'])
 from paddle_tpu import serving
 
@@ -798,8 +787,8 @@ def run_pod_sharded(args):
     set_mesh-annotated Program (row-sharded embedding table restored
     from a sharded checkpoint — never materialized dense) behind one
     PodRouter; mid-run one host is SIGKILLed. Reports: host-loss detect
-    + RECOVERY time (`serve.pod.recovery_s`, lower-is-better in
-    bench_sentinel), dropped-future count (must be 0), rows/sec before
+    + RECOVERY time (`serve.pod.recovery_s`, lower is better),
+    dropped-future count (must be 0), rows/sec before
     vs after recovery, and post-recovery steady-state compiles
     (--check-compiles enforces 0)."""
     import shutil
@@ -1070,8 +1059,8 @@ def run_decode_failover(args):
     the rpc wire loses its host mid-generation (simulate_death — the
     SIGKILL posture) and resumes on a survivor from the slot
     checkpoint. Reports end-to-end TTFT, the RESUME GAP (kill -> next
-    new token at the consumer, `*_resume_s`, lower-is-better in
-    bench_sentinel), tokens replayed past the checkpoint
+    new token at the consumer, `*_resume_s`, lower is better),
+    tokens replayed past the checkpoint
     (`*_replayed_tokens`), dropped futures (must be 0) and whether the
     final beams were TOKEN-EXACT vs an uninterrupted reference
     (exit 1 if not)."""
@@ -1182,6 +1171,13 @@ def run_decode_failover(args):
 # an imported AOT warm-signature blob (docs/perf.md#aot)
 # ---------------------------------------------------------------------------
 
+_AOT_BUILD_CHILD = r"""
+import os, sys
+sys.path.insert(0, os.path.join(os.environ['PADDLE_TPU_REPO'], 'tools'))
+import serve_bench
+serve_bench.build_model(sys.argv[1], sys.argv[2])
+"""
+
 _AOT_CHILD = r"""
 import json, os, sys, time
 sys.path.insert(0, os.environ['PADDLE_TPU_REPO'])
@@ -1209,58 +1205,74 @@ t_first = time.perf_counter() - t0
 if mode == 'export':
     exe.export_warm_signatures(aot_dir)
 eng.shutdown()
-stats = {k: v for k, v in exe.cache_stats.items()
-         if k != 'compile_cache_dir'}
-stats['first_response_s'] = t_first
+import jax
+stats = dict(exe.cache_stats, first_response_s=t_first,
+             platform=jax.devices()[0].platform,
+             device_kind=jax.devices()[0].device_kind,
+             device_count=len(jax.devices()))
 print('AOT_STATS=' + json.dumps(stats))
 """
 
 
 def run_aot_cold(args):
     """Cold-replica AOT drill: process A cold-compiles the serving
-    warmup signature set (with the persistent cache wired) and exports
-    the step-artifact AOT blob; process B — a genuinely cold replica
-    with NO pre-wired compile cache — imports the blob before warmup.
-    Metrics: time-to-first-response per leg, the cold replica's
-    online-compile count (the zero-compile contract) and its AOT-hit
-    count."""
+    warmup signature set (persistent cache wired) and exports the
+    step-artifact AOT blob; process B — a cold replica whose cache
+    directory starts EMPTY — imports the blob before warmup. Metrics:
+    time-to-first-response per leg, the cold replica's online-compile
+    count (the zero-compile contract) and its AOT-hit count.
+
+    Every leg that touches jax — building the model too — is a child, run
+    one after another: this parent never initializes a backend, so it
+    never holds the chip its children need, and it takes the device stamp
+    from their output. Both cache directories are fixed paths from the
+    one resolver (utils/compile_cache.py): A uses the resolved directory
+    itself, B the drill-owned `aot_cold_replica/` inside it, emptied
+    first. On a machine whose resolved cache is already warm, leg A
+    deserializes instead of compiling and its time is not a cold one
+    (its online_compiles says which)."""
     import shutil
     import subprocess
+    from paddle_tpu.utils import compile_cache   # import only: no backend
 
     save_dir = tempfile.mkdtemp(prefix='serve_bench_aot_')
-    feed_name, example = build_model(args.model, save_dir)
     aot_dir = os.path.join(save_dir, 'aot')
-    cache_dir = os.path.join(save_dir, 'cc')
+    warm_dir = compile_cache.resolve()
+    cold_dir = os.path.join(warm_dir, 'aot_cold_replica')
+    shutil.rmtree(cold_dir, ignore_errors=True)
     bucket = int(args.max_batch)
-    _emit({'metric': 'serve.aot.workload', 'value': args.model,
-           'bucket': bucket})
 
-    def child(mode, wire_cache):
+    def child(code, cache_dir, *argv):
         env = dict(os.environ, PADDLE_TPU_REPO=_REPO)
         env.pop('PADDLE_TPU_OBS_RUN_FILE', None)
-        if wire_cache:
-            env['PADDLE_TPU_COMPILE_CACHE'] = cache_dir
-        else:
-            # the cold replica brings NO cache of its own:
-            # load_warm_signatures wires a fresh one seeded from the blob
-            env.pop('PADDLE_TPU_COMPILE_CACHE', None)
-        r = subprocess.run(
-            [sys.executable, '-c', _AOT_CHILD, mode, save_dir, aot_dir,
-             str(bucket)],
-            capture_output=True, text=True, timeout=900, env=env)
+        env[compile_cache.ENV] = cache_dir
+        r = subprocess.run([sys.executable, '-c', code] + list(argv),
+                           capture_output=True, text=True, timeout=900,
+                           env=env)
         if r.returncode != 0:
-            raise RuntimeError('aot-cold %s leg failed:\n%s'
-                               % (mode, r.stderr[-2000:]))
-        line = [ln for ln in r.stdout.splitlines()
+            raise RuntimeError('aot-cold child %r failed:\n%s'
+                               % (argv, r.stderr[-2000:]))
+        return r.stdout
+
+    def leg(mode, cache_dir):
+        out = child(_AOT_CHILD, cache_dir, mode, save_dir, aot_dir,
+                    str(bucket))
+        line = [ln for ln in out.splitlines()
                 if ln.startswith('AOT_STATS=')]
         return json.loads(line[0][len('AOT_STATS='):])
 
     try:
-        base = child('export', wire_cache=True)
-        cold = child('import', wire_cache=False)
+        child(_AOT_BUILD_CHILD, warm_dir, args.model, save_dir)
+        base = leg('export', warm_dir)
+        cold = leg('import', cold_dir)
     finally:
         shutil.rmtree(save_dir, ignore_errors=True)
+        shutil.rmtree(cold_dir, ignore_errors=True)
 
+    _DEVICE.update({k: cold[k] for k in ('platform', 'device_kind',
+                                         'device_count')})
+    _emit({'metric': 'serve.aot.workload', 'value': args.model,
+           'bucket': bucket})
     _emit({'metric': 'serve.aot.baseline_first_response_ms',
            'value': round(1e3 * base['first_response_s'], 1),
            'unit': 'ms', 'online_compiles': base['online_compiles']})
@@ -1415,7 +1427,8 @@ def main(argv=None):
         if getattr(args, k) == ap.get_default(k):
             setattr(args, k, v)
 
-    _resolve_platform()
+    if args.workload != 'aot-cold':     # its children need the chip
+        _resolve_device()
     special = {'pod-rpc': run_pod_rpc,
                'decode-failover': run_decode_failover,
                'pod-sharded': run_pod_sharded,
