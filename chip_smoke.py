@@ -8,7 +8,10 @@ compiled by Mosaic and checked against its own XLA reference; a toy step
 is checked against the same Program on the host; and on a host with four
 or more chips the same Program trains over a dp=2 x tp=2 mesh. Any failure
 in any leg is an exception and a non-zero exit: nothing here turns a leg
-into a "skipped" line. The last line of stdout is one JSON object.
+into a "skipped" line. What was measured is printed as one `summary {...}`
+line; the last line of stdout is the verdict the driver reads,
+`{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`, with
+exactly those keys and the device as jax reports it.
 
     python3 chip_smoke.py          # on the machine with the chip
 
@@ -420,15 +423,16 @@ def main():
     train = train_leg(place)
     reference = reference_leg()
     kernels = kernel_leg()
-    result = {'ok': True, 'device': device,
-              'backend_init_seconds': round(init_s, 2),
-              'train': train, 'reference': reference,
-              'kernels_compiled': sorted(kernels),
-              'native_available': bool(native.available())}
+    summary = {'backend_init_seconds': round(init_s, 2),
+               'train': train, 'reference': reference,
+               'kernels_compiled': sorted(kernels),
+               'native_available': bool(native.available())}
     if device['count'] >= 4:
-        result['mesh'] = mesh_leg(place, train['first_loss'])
-    result['seconds'] = round(time.perf_counter() - t_start, 1)
-    print(json.dumps(result), flush=True)
+        summary['mesh'] = mesh_leg(place, train['first_loss'])
+    summary['seconds'] = round(time.perf_counter() - t_start, 1)
+    log('summary ' + json.dumps(summary))
+    # every leg raised on failure, so reaching this line is the verdict
+    print(json.dumps({'ok': True, 'device': device}), flush=True)
 
 
 if __name__ == '__main__':

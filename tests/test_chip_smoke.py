@@ -10,8 +10,11 @@ on-chip path rely on, checked where there is no chip.
   - `python chip_smoke.py` on a CPU host exits non-zero naming `cpu`, and
     alone in a directory it cannot even import the program;
   - the training leg itself, called at a toy width on CPUPlace, runs and
-    its loss falls.
+    its loss falls;
+  - the last line main() prints is the driver's verdict object and nothing
+    more (the first on-chip check was refused for a fuller last line).
 """
+import json
 import os
 import re
 import shutil
@@ -111,3 +114,20 @@ def test_training_leg_runs_at_toy_width_on_the_host():
     assert out['last_loss'] < out['first_loss']
     assert out['tpu_custom_calls'] == 0       # the host takes the XLA chain
     assert out['steps'] == 6
+
+
+def test_last_line_is_the_verdict_object_and_nothing_more(monkeypatch, capsys):
+    import chip_smoke
+    device = {'platform': 'tpu', 'kind': 'TPU v5 lite', 'count': 1}
+    monkeypatch.setattr(chip_smoke, 'device_report', lambda: device)
+    monkeypatch.setattr(chip_smoke, 'train_leg',
+                        lambda place: {'first_loss': 10.0, 'last_loss': 9.0})
+    monkeypatch.setattr(chip_smoke, 'reference_leg', lambda: {})
+    monkeypatch.setattr(chip_smoke, 'kernel_leg', lambda: {'k': 0.0})
+    monkeypatch.setattr(compile_cache, 'enable', lambda: '/nowhere')
+    monkeypatch.setenv('JAX_PLATFORMS', 'cpu')      # main() only setdefaults
+    assert not chip_smoke.main()
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert json.loads(lines[-1]) == {'ok': True, 'device': device}
+    assert lines[-2].startswith('summary {')
+    assert json.loads(lines[-2][len('summary '):])['train']['last_loss'] == 9.0
