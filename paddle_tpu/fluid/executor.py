@@ -331,17 +331,9 @@ _CompiledStep = StepArtifact
 # UNLABELED instruments: per-executor labels would grow the registry
 # without bound under executor churn (tests, notebooks); the
 # per-instance view lives in plain ints behind exe.cache_stats.
-_C_HITS = obs.counter('executor.cache.hits')
 _C_MISSES = obs.counter('executor.cache.misses')
-_C_EVICTIONS = obs.counter('executor.cache.evictions')
-_C_PERSISTENT_HITS = obs.counter('executor.cache.persistent_hits')
-# AOT warm-signature deserializations (docs/perf.md#aot): persistent hits
-# whose executable was imported from an exported step-artifact blob
-_C_AOT_HITS = obs.counter('executor.cache.aot_hits')
 _C_FEED_BYTES = obs.counter('executor.feed.bytes')
-_G_LAST_COMPILE = obs.gauge('executor.last_compile.seconds')
 _C_SKIPPED = obs.counter('anomaly.skipped_steps')
-_G_GRAD_NORM = obs.gauge('anomaly.grad_norm')
 # async-fetch pipeline (docs/perf.md): how many run(sync='async') fetch
 # handles are outstanding (dispatched, not yet host-synced), and the
 # executor.host_stall.seconds histogram (recorded via obs.span in
@@ -360,6 +352,71 @@ _C_REMAT = obs.counter('executor.remat_detected')
 # shrink it). The per-key geometry lives in the embedding.lookup /
 # embedding.update_rows run-log events; this counter carries the volume.
 _C_EMBED_ROWS = obs.counter('embedding.rows_touched')
+
+# The parts of a first call (docs/observability.md): jax reports how long
+# its own stages took through jax.monitoring duration events. While an
+# `executor.first_call` span is open on this thread the listener keeps
+# each event's interval, so the span can say how much of it was Python
+# tracing and lowering and how much the backend (XLA's compile, or the
+# read from the persistent cache). Intervals, not sums: a jitted function
+# traced inside another reports inside its caller's interval.
+_JAX_TRACE_EVENTS = ('/jax/core/compile/jaxpr_trace_duration',
+                     '/jax/core/compile/jaxpr_to_mlir_module_duration')
+_JAX_BACKEND_EVENT = '/jax/core/compile/backend_compile_duration'
+_JAX_RETRIEVAL_EVENT = '/jax/compilation_cache/cache_retrieval_time_sec'
+_first_call_open = threading.local()
+_first_call_listening = []      # non-empty once the listener is registered
+
+
+def _on_jax_duration(event, duration, **_):
+    parts = getattr(_first_call_open, 'parts', None)
+    if parts is not None:
+        t1 = time.perf_counter()
+        parts.append((event, t1 - duration, t1))
+
+
+def _listen_first_call():
+    """Opens this thread's collection of jax's duration events (the
+    listener is registered on first use, once per process) and returns
+    the list it fills until `_first_call_open.parts` is set to None."""
+    if not _first_call_listening:
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
+        _first_call_listening.append(True)
+    parts = _first_call_open.parts = []
+    return parts
+
+
+def _covered(intervals, lo, hi):
+    """(start of the first, total length of the union) of `intervals`
+    clipped to [lo, hi]; (lo, 0.0) when there are none."""
+    total, start, end = 0.0, None, lo
+    for a, b in sorted(intervals):
+        a, b = max(a, end), min(b, hi)
+        if b > a:
+            start = a if start is None else start
+            total += b - a
+            end = b
+    return (lo if start is None else start), total
+
+
+def _record_first_call_parts(parts, lo, hi, key_id):
+    """Post-hoc children of the open `executor.first_call` span from
+    jax's own duration events between `lo` and `hi`: `.trace` (jaxpr
+    trace plus lowering to MLIR) and `.backend` (XLA's compile, or
+    the read from the persistent cache: `cached` when every backend
+    compile of the call was served by a retrieval). What is left of
+    the span is its self time: dispatch and the step's run."""
+    trace = [(a, b) for e, a, b in parts if e in _JAX_TRACE_EVENTS]
+    backend = [(a, b) for e, a, b in parts if e == _JAX_BACKEND_EVENT]
+    retrieved = sum(e == _JAX_RETRIEVAL_EVENT for e, _, _ in parts)
+    t0, seconds = _covered(trace, lo, hi)
+    obs.span_record('executor.first_call.trace', seconds, t0=t0,
+                    key=key_id)
+    t0, seconds = _covered(backend, lo, hi)
+    obs.span_record('executor.first_call.backend', seconds, t0=t0,
+                    key=key_id, cached=0 < retrieved == len(backend))
+
 
 # RLock: FetchHandle.__del__ may run from a GC pass triggered INSIDE an
 # _inflight_delta call on the same thread (allocation under the lock);
@@ -491,7 +548,7 @@ class StepHandle(object):
         (executor.compile spans, cache_stats) is identical to run()'s.
         Steady-state calls record NO per-step run-log events (a decode
         loop would write thousands of span records per second); the
-        `executor.handle.steps` counter carries the volume instead.
+        handle's own `steps` carries the volume instead.
 
     Programs that CREATE persistables (startup-style) are rejected at
     acquire: the donated pytree structure must be stable across calls.
@@ -500,8 +557,6 @@ class StepHandle(object):
 
     __slots__ = ('_exe', '_compiled', '_scope', '_program', '_donated',
                  '_readonly', '_key', '_first', 'steps', 'key_id')
-
-    _C_STEPS = None   # registry counter, created lazily on first handle
 
     def __init__(self, exe, compiled, scope, program, persist, key_id):
         self._exe = exe
@@ -517,8 +572,6 @@ class StepHandle(object):
         self._first = not getattr(compiled, '_obs_compiled', False)
         self.steps = 0
         self.key_id = key_id
-        if StepHandle._C_STEPS is None:
-            StepHandle._C_STEPS = obs.counter('executor.handle.steps')
 
     @property
     def state(self):
@@ -590,7 +643,6 @@ class StepHandle(object):
         if health is not None:
             self._exe._observe_health(self._program, health)
         self.steps += 1
-        StepHandle._C_STEPS.inc()
         return fetches
 
 
@@ -1013,12 +1065,31 @@ class Executor(object):
         return feed_vals
 
     def _prepare(self, program, feed, fetch_list, scope,
-                 use_program_cache=True, verify_bundle=False):
+                 use_program_cache=True, verify_bundle=False, spans=False):
         """Shared front half of run()/lowered_hlo(): device-place the feed,
         resolve the (program, feed-sig, fetch) cache key, and build or fetch
-        the _CompiledStep. Returns (compiled, feed_vals, persist)."""
-        dist_mesh = self._ensure_dist_placement(program, scope)
-        feed_vals = self._place_feed(program, feed, dist_mesh)
+        the _CompiledStep. Returns (compiled, feed_vals, persist). `spans`
+        (run() and run_bundle() pass obs.enabled()) times the placement
+        and the feed as child spans of the caller's `executor.prepare`."""
+        with obs.span_if(spans, 'executor.placement') as sp:
+            dist_mesh = self._ensure_dist_placement(program, scope)
+            if sp is not None:
+                sp.fields['mesh'] = dist_mesh is not None
+        with obs.span_if(spans, 'executor.feed') as sp:
+            feed_vals = self._place_feed(program, feed, dist_mesh)
+            # feed-transfer accounting: nbytes is metadata only (no device
+            # sync); SeqValues carry their dense payload + length vectors
+            fb = 0
+            for dv in feed_vals.values():
+                if isinstance(dv, SeqValue):
+                    fb += int(getattr(dv.data, 'nbytes', 0))
+                    fb += int(getattr(dv.lengths, 'nbytes', 0))
+                else:
+                    fb += int(getattr(dv, 'nbytes', 0))
+            _C_FEED_BYTES.inc(fb)
+            self._last_feed_bytes = fb
+            if sp is not None:
+                sp.fields['bytes'] = fb
         block = program.global_block()
 
         fetch_names = [_as_fetch_name(f) for f in fetch_list]
@@ -1203,7 +1274,6 @@ class Executor(object):
             outcome = 'miss'
         else:
             self._cache_hits += 1
-            _C_HITS.inc()
             outcome = 'hit'
         self._last_cache_lookup = {'outcome': outcome, 'key': key_id,
                                    'entries': len(self._cache)}
@@ -1221,18 +1291,6 @@ class Executor(object):
             initialized=set(persist_in) | set(feed_vals),
             donates=compiled.mutates_persist, bundle=verify_bundle,
             dead_ops=False)
-        # feed-transfer accounting: nbytes is metadata only (no device
-        # sync); SeqValues carry their dense payload + length vectors
-        fb = 0
-        for dv in feed_vals.values():
-            if isinstance(dv, SeqValue):
-                fb += int(getattr(dv.data, 'nbytes', 0))
-                fb += int(getattr(dv.lengths, 'nbytes', 0))
-            else:
-                fb += int(getattr(dv, 'nbytes', 0))
-        _C_FEED_BYTES.inc(fb)
-        self._last_feed_bytes = fb
-
         persist = {n: scope._chain_get(n) for n in compiled.persist_in}
         # pin the donated state's placement ONCE (the artifact's donate-
         # exactly-once contract, fluid/step_artifact.py#pin_state): an
@@ -1346,55 +1404,61 @@ class Executor(object):
         window also tees fd-2 stderr to catch the SPMD partitioner's
         involuntary-rematerialization diagnostic (_scan_remat) — only on
         first calls, never in the steady-state loop."""
-        pre = self._cc_entry_names()
-        captured = []
-        t0 = time.perf_counter()
-        if _remat_capture_enabled():
-            with _capture_fd2(captured):
-                out = fn(*args)
-        else:
-            out = fn(*args)
-        dt = time.perf_counter() - t0
-        self._scan_remat(captured, key_id)
-        post = self._cc_entry_names()
-        hit = bool(pre) and post == pre
-        if pre is not None and post:
-            # the entries this first call wrote are THIS executor's warm
-            # set — what an AOT export ships
-            self._warm_entries.update(post - pre)
-        warmed = self._aot_warmed(aot_sig, aot_entry)
-        if hit:
-            self._persistent_hits += 1
-            _C_PERSISTENT_HITS.inc()
-            outcome = 'aot_hit' if warmed else 'persistent_hit'
-            if warmed:
-                self._aot_hits += 1
-                _C_AOT_HITS.inc()
-            if self._last_cache_lookup is not None:
-                self._last_cache_lookup['outcome'] = outcome
-            obs.event('executor.compile.%s' % outcome, key=key_id,
-                      seconds=round(dt, 6), **fields)
-        else:
-            outcome = 'compile'
-            self._online_compiles += 1
-            obs.span_record('executor.compile', dt, key=key_id, **fields)
-            self._last_compile_s = dt
-            _G_LAST_COMPILE.set(dt)
-            if warmed:
-                # the manifest PROMISED this signature was serialized but
-                # the first call compiled online anyway (cache entry
-                # missing/invalidated, jax/backend drift): a stale blob —
-                # the exact silent failure program_lint --aot types
-                self._aot_stale += 1
-                obs.event('executor.aot.stale', key=key_id, sig=aot_sig,
-                          seconds=round(dt, 6))
-                import warnings
-                warnings.warn(
-                    'AOT warm signature %s (key %s) COMPILED online '
-                    'despite the loaded warm-signature manifest claiming '
-                    'it — the AOT blob is stale (re-export it; '
-                    'program_lint --aot checks this statically)'
-                    % (aot_sig, key_id), RuntimeWarning)
+        with obs.span_if(obs.enabled(), 'executor.first_call',
+                         key=key_id) as sp:
+            parts = _listen_first_call() if sp is not None else None
+            pre = self._cc_entry_names()
+            captured = []
+            t0 = time.perf_counter()
+            try:
+                if _remat_capture_enabled():
+                    with _capture_fd2(captured):
+                        out = fn(*args)
+                else:
+                    out = fn(*args)
+            finally:
+                _first_call_open.parts = None
+            dt = time.perf_counter() - t0
+            self._scan_remat(captured, key_id)
+            post = self._cc_entry_names()
+            hit = bool(pre) and post == pre
+            if pre is not None and post:
+                # the entries this first call wrote are THIS executor's warm
+                # set — what an AOT export ships
+                self._warm_entries.update(post - pre)
+            warmed = self._aot_warmed(aot_sig, aot_entry)
+            if hit:
+                self._persistent_hits += 1
+                outcome = 'aot_hit' if warmed else 'persistent_hit'
+                if warmed:
+                    self._aot_hits += 1
+                if self._last_cache_lookup is not None:
+                    self._last_cache_lookup['outcome'] = outcome
+                obs.event('executor.compile.%s' % outcome, key=key_id,
+                          seconds=round(dt, 6), **fields)
+            else:
+                outcome = 'compile'
+                self._online_compiles += 1
+                obs.span_record('executor.compile', dt, key=key_id, **fields)
+                self._last_compile_s = dt
+                if warmed:
+                    # the manifest PROMISED this signature was serialized but
+                    # the first call compiled online anyway (cache entry
+                    # missing/invalidated, jax/backend drift): a stale blob —
+                    # the exact silent failure program_lint --aot types
+                    self._aot_stale += 1
+                    obs.event('executor.aot.stale', key=key_id, sig=aot_sig,
+                              seconds=round(dt, 6))
+                    import warnings
+                    warnings.warn(
+                        'AOT warm signature %s (key %s) COMPILED online '
+                        'despite the loaded warm-signature manifest claiming '
+                        'it — the AOT blob is stale (re-export it; '
+                        'program_lint --aot checks this statically)'
+                        % (aot_sig, key_id), RuntimeWarning)
+            if sp is not None:
+                sp.fields['outcome'] = outcome
+                _record_first_call_parts(parts, t0, t0 + dt, key_id)
         return out, outcome
 
     def _scan_remat(self, captured, key_id):
@@ -1463,20 +1527,28 @@ class Executor(object):
         # Telemetry (docs/observability.md): the step span covers the
         # whole run — prepare, device dispatch, fetch sync. When
         # observability is off this is two perf_counter calls and an
-        # in-memory histogram record; no file IO, no device syncs.
+        # in-memory histogram record; no file IO, no device syncs. Its
+        # child spans (prepare, placement, feed, rng, dispatch,
+        # first_call) say where a step's host time goes and exist only
+        # while observability is on: `on` is the step's one check.
+        on = obs.enabled()
         with obs.span('executor.step') as step_sp:
-            compiled, feed_vals, persist = self._prepare(
-                program, feed, fetch_list, scope,
-                use_program_cache=use_program_cache)
+            with obs.span_if(on, 'executor.prepare') as sp:
+                compiled, feed_vals, persist = self._prepare(
+                    program, feed, fetch_list, scope,
+                    use_program_cache=use_program_cache, spans=on)
+                look = self._last_cache_lookup or {}
+                if sp is not None:
+                    sp.fields['cache'] = look.get('outcome')
             self._run_counter += 1
-            look = self._last_cache_lookup or {}
             step_sp.fields.update(run=self._run_counter,
                                   cache=look.get('outcome'),
                                   key=look.get('key'),
                                   feed_bytes=self._last_feed_bytes)
-            rng = jax.random.key(np.uint32(
-                ((program.random_seed or 0) * 2654435761 + self._run_counter)
-                % (1 << 32)))
+            with obs.span_if(on, 'executor.rng'):
+                rng = jax.random.key(np.uint32(
+                    ((program.random_seed or 0) * 2654435761
+                     + self._run_counter) % (1 << 32)))
             from . import debugger as _dbg
             from . import profiler as _prof
             check = _dbg.nan_inf_check_active()
@@ -1502,8 +1574,9 @@ class Executor(object):
                 if outcome != 'compile':
                     step_sp.fields['cache'] = outcome
             else:
-                fetches, new_persist, health = compiled(
-                    persist, feed_vals, rng)
+                with obs.span_if(on, 'executor.dispatch'):
+                    fetches, new_persist, health = compiled(
+                        persist, feed_vals, rng)
             if compiled.sparse_plan:
                 _C_EMBED_ROWS.inc(getattr(compiled, '_embed_rows_step', 0))
             for n, v in new_persist.items():
@@ -1662,11 +1735,16 @@ class Executor(object):
         if steps is not None and int(steps) != K:
             raise ValueError('steps=%d but %d feed dicts were given'
                              % (steps, K))
+        on = obs.enabled()
         with obs.span('executor.bundle', steps=K) as bsp:
-            compiled, feed0, persist = self._prepare(
-                program, feeds[0], fetch_list, scope,
-                use_program_cache=use_program_cache, verify_bundle=True)
-            look = self._last_cache_lookup or {}
+            with obs.span_if(on, 'executor.prepare') as sp:
+                compiled, feed0, persist = self._prepare(
+                    program, feeds[0], fetch_list, scope,
+                    use_program_cache=use_program_cache,
+                    verify_bundle=True, spans=on)
+                look = self._last_cache_lookup or {}
+                if sp is not None:
+                    sp.fields['cache'] = look.get('outcome')
             bsp.fields.update(cache=look.get('outcome'),
                               key=look.get('key'))
             extras = compiled.plan.uninitialized(compiled.persist_in)
@@ -1826,9 +1904,6 @@ class Executor(object):
         self.last_step_health = h
         if run_id is None:
             run_id = self._run_counter
-        # telemetry from the health vector ALREADY on the host — reusing
-        # it costs no extra device sync (the guard's design invariant)
-        _G_GRAD_NORM.set(float(h['grad_norm']))
         if bool(h['healthy']):
             self._consecutive_skips = 0
             return
@@ -2103,7 +2178,6 @@ class Executor(object):
         (reference executor.py:close tears down the C++ scope/comm; here
         the compiled-step cache holds the device buffers XLA pinned)."""
         self._cache_evictions += len(self._cache)
-        _C_EVICTIONS.inc(len(self._cache))
         for step in self._cache.values():
             for fn in [getattr(step, '_jitted', None)] + \
                     list(getattr(step, '_bundles', {}).values()):

@@ -14,6 +14,12 @@ Three pieces (docs/observability.md has the full catalog):
     `<name>.seconds`, appends a span record to the run log, and forwards
     to jax.profiler.TraceAnnotation (StepTraceAnnotation when step_num is
     given) so the same names appear in Perfetto/XLA traces.
+  * a COMPLETED-SPAN BUFFER: while observability is on, every span record
+    (the run log's, with `t0`/`t1` on time.perf_counter's clock) is also
+    kept in a bounded in-memory buffer that obs.completed_spans()
+    returns, so a reader after the run computes self times over a window
+    without parsing a file. Generation-2 garbage collections land there
+    too, as `host.gc` spans.
 
 Disabled-mode contract (the default): spans still time into the in-memory
 registry, but no file is written, no event is recorded, and jax is never
@@ -21,6 +27,10 @@ imported — this module is stdlib-only and only *reuses* jax.profiler when
 the host program already imported jax AND observability is on. Tests load
 the package standalone (importlib, no paddle_tpu parent) to enforce that.
 """
+import atexit
+import collections
+import contextlib
+import gc
 import itertools
 import os
 import sys
@@ -36,7 +46,8 @@ from .metrics import REGISTRY, counter, gauge, histogram  # noqa: F401
 
 __all__ = ['metrics', 'report', 'slo', 'trace', 'REGISTRY', 'counter',
            'gauge', 'histogram', 'enabled', 'obs_dir', 'enable', 'disable',
-           'event', 'span', 'span_record', 'run_log_path', 'ENV_DIR']
+           'event', 'span', 'span_if', 'span_record', 'completed_spans',
+           'run_log_path', 'ENV_DIR']
 
 ENV_DIR = 'PADDLE_TPU_OBS_DIR'
 # Optional: pin the run-log to an EXACT file path instead of a fresh
@@ -48,6 +59,9 @@ ENV_RUN_FILE = 'PADDLE_TPU_OBS_RUN_FILE'
 # default because compaction would drop other writers' appends.
 ENV_MAX_EVENTS = 'PADDLE_TPU_OBS_MAX_EVENTS'
 DEFAULT_MAX_EVENTS = 500000
+# Bound of the completed-span buffer: a 20 s benchmark window is some 140
+# steps of 8 records, a whole run with its set-up a few thousand.
+SPAN_BUFFER_MAX = 16384
 
 _state = {
     'override': None,      # None = follow env; (True, dir) / (False, None)
@@ -55,11 +69,22 @@ _state = {
     'runlog_dir': None,
     'failed_dir': None,    # dir whose run-log creation failed (warn once)
     'lock': threading.RLock(),
+    # deque of completed span records; made with the first record kept,
+    # and _gc_callback is in gc.callbacks exactly while it exists
+    'spans': None,
+    'spans_dropped': 0,    # records the bound pushed out of 'spans'
+    'gc_t0': None,         # start of the generation-2 collection under way
 }
+# generation-2 collections seen by _gc_callback and not yet recorded:
+# (t0, t1, collected). The callback runs wherever an allocation triggers
+# a collection, possibly inside the run log's write, so it only appends
+# here; the next record (or completed_spans()) turns them into spans.
+_gc_pending = []
 _span_ids = itertools.count(1)
 _local = threading.local()
 # span-name -> registry histogram, so the per-span fast path skips the
-# registry's label-normalizing lookup (hot: 3 spans per executor step)
+# registry's label-normalizing lookup (hot: 3 spans per executor step,
+# 8 while observability is on)
 _span_hists = {}
 
 
@@ -99,7 +124,20 @@ def _reset():
         _close_runlog_locked()
         _state['override'] = None
         _span_hists.clear()   # drop handles detached by REGISTRY.reset()
+        if _state['spans'] is not None:
+            gc.callbacks.remove(_gc_callback)
+        _state['spans'] = _state['gc_t0'] = None
+        _state['spans_dropped'] = 0
+        del _gc_pending[:]
     trace._reset()
+
+
+@atexit.register
+def _close_at_exit():
+    # span records wait in the run log for their batch (runlog.SPAN_BATCH):
+    # a process that ends in the ordinary way writes them out
+    with _state['lock']:
+        _close_runlog_locked()
 
 
 def _close_runlog_locked():
@@ -159,10 +197,15 @@ def _run_log():
 
 def run_log_path():
     """Path of the current run's JSONL file (None when disabled or when
-    nothing has been recorded yet — the file is created lazily)."""
+    nothing has been recorded yet — the file is created lazily). Span
+    records go out in batches (runlog.SPAN_BATCH); asking for the path
+    writes out the ones held back, so whoever reads the file next reads
+    every record so far."""
     rl = _state['runlog']
-    return rl.path if rl is not None and _state['runlog_dir'] == obs_dir() \
-        else None
+    if rl is None or _state['runlog_dir'] != obs_dir():
+        return None
+    rl.flush()
+    return rl.path
 
 
 def _span_stack():
@@ -175,6 +218,98 @@ def _span_stack():
 def current_span_id():
     st = getattr(_local, 'stack', None)
     return st[-1].id if st else None
+
+
+def _span_hist(name):
+    h = _span_hists.get(name)
+    if h is None:
+        h = REGISTRY.histogram(name + '.seconds')
+        _span_hists[name] = h
+    return h
+
+
+def _span_rec(name, span_id, parent, t0, seconds, fields, tids=None):
+    """THE span record: what the run log writes and completed_spans()
+    returns. `t0`/`t1` are on time.perf_counter's clock (monotonic, the
+    one every span is timed on), so records of one process compare;
+    `ts` stays the run log's time.monotonic() stamp of the write."""
+    rec = {'ts': time.monotonic(), 'kind': 'span', 'name': name,
+           'span': span_id, 'parent': parent, 't0': t0, 't1': t0 + seconds,
+           'dur_s': seconds, 'fields': fields}
+    if tids:
+        rec.update(tids)
+    return rec
+
+
+def _put(rec):
+    """One completed span record into the buffer and the run log."""
+    buf = _state['spans']
+    if buf is None:
+        with _state['lock']:
+            buf = _state['spans']
+            if buf is None:
+                buf = _state['spans'] = collections.deque(
+                    maxlen=SPAN_BUFFER_MAX)
+                gc.callbacks.append(_gc_callback)
+    if len(buf) == buf.maxlen:
+        # the deque pushes its oldest record out; never silently
+        with _state['lock']:
+            _state['spans_dropped'] += 1
+        REGISTRY.counter('obs.spans.dropped').inc()
+    buf.append(rec)
+    rl = _run_log()
+    if rl is not None:
+        rl.write(rec, flush=False)      # in batches: runlog.SPAN_BATCH
+
+
+def _keep(rec):
+    if _gc_pending:
+        _drain_gc()
+    _put(rec)
+
+
+def _gc_callback(phase, info):
+    """gc.callbacks hook, installed with the first kept record: times the
+    generation-2 (full) collections, the ones long enough to stall a
+    step. Only stashes; see _gc_pending."""
+    if info['generation'] != 2:
+        return
+    if phase == 'start':
+        _state['gc_t0'] = time.perf_counter()
+        return
+    t0, _state['gc_t0'] = _state['gc_t0'], None
+    if t0 is not None and enabled():
+        _gc_pending.append((t0, time.perf_counter(), info['collected']))
+
+
+def _drain_gc():
+    n = len(_gc_pending)
+    pending, _gc_pending[:n] = _gc_pending[:n], []
+    for t0, t1, collected in pending:
+        _span_hist('host.gc').observe(t1 - t0)
+        _put(_span_rec('host.gc', next(_span_ids), None, t0, t1 - t0,
+                       {'generation': 2, 'collected': collected}))
+
+
+def completed_spans():
+    """The span records completed while observability was on, oldest
+    first (a list of the run log's span dicts; see _span_rec), at most
+    SPAN_BUFFER_MAX of them. When the bound has pushed records out, the
+    list LEADS with a `spans.dropped` meta record (`fields.dropped`), as
+    a compacted run log does, and the `obs.spans.dropped` counter holds
+    the count: a reader that needs every record of a window checks the
+    head. The buffer outlives disable() and an environment flip; only
+    _reset() empties it."""
+    if _gc_pending:
+        _drain_gc()
+    out = list(_state['spans'] or ())
+    dropped = _state['spans_dropped']
+    if dropped:
+        out.insert(0, {'ts': time.monotonic(), 'kind': 'meta',
+                       'name': 'spans.dropped', 'span': None,
+                       'fields': {'dropped': dropped,
+                                  'max_spans': SPAN_BUFFER_MAX}})
+    return out
 
 
 def event(name, **fields):
@@ -257,11 +392,7 @@ class Span(object):
         elif self._entered and self in st:   # mis-nested exit; stay sane
             st.remove(self)
         self._entered = False
-        h = _span_hists.get(self.name)
-        if h is None:
-            h = REGISTRY.histogram(self.name + '.seconds')
-            _span_hists[self.name] = h
-        h.observe(self.seconds)
+        _span_hist(self.name).observe(self.seconds)
         err = '%s: %s' % (exc_type.__name__, exc) if exc_type is not None \
             else None
         tids = None
@@ -272,20 +403,14 @@ class Span(object):
             tids = {'trace': trec['trace'], 'tspan': trec['span']}
             if trec.get('parent') is not None:
                 tids['tparent'] = trec['parent']
-        rl = _run_log()
-        if rl is not None:
+        if enabled():
             fields = dict(self.fields)
             if err is not None:
                 fields['error'] = err
             if self.step_num is not None:
                 fields.setdefault('step_num', self.step_num)
-            rec = {'ts': time.monotonic(), 'kind': 'span',
-                   'name': self.name, 'span': self.id,
-                   'parent': self.parent,
-                   'dur_s': self.seconds, 'fields': fields}
-            if tids:
-                rec.update(tids)
-            rl.write(rec)
+            _keep(_span_rec(self.name, self.id, self.parent, self.t0,
+                            self.seconds, fields, tids))
         return False
 
 
@@ -298,30 +423,36 @@ def span(name, step_num=None, **fields):
     return Span(name, step_num=step_num, **fields)
 
 
-def span_record(name, seconds, **fields):
+_NO_SPAN = contextlib.nullcontext()
+
+
+def span_if(on, name, **fields):
+    """obs.span(name, **fields) when `on`, else a shared no-op context
+    whose `as` target is None: no Span, no clock, no histogram. For the
+    child spans of a hot path that should exist only while observability
+    is on — the caller reads enabled() ONCE and passes it down."""
+    return Span(name, **fields) if on else _NO_SPAN
+
+
+def span_record(name, seconds, t0=None, **fields):
     """Record a span POST-HOC: the caller timed the region itself and only
     afterwards knows whether (and under which name) it should be recorded.
     The executor needs this for `executor.compile` — a first jitted call
     is timed, then classified as a real cold compile (span recorded) or a
     persistent-cache hit (an `executor.compile.persistent_hit` event
     instead), so a warm-cache restart shows ZERO compile spans. Feeds the
-    same registry histogram and run-log span schema as span(); no trace
-    annotation (the region is already over). Returns the record dict when
-    written to the run log, else None."""
+    same registry histogram, run-log span schema and completed-span
+    buffer as span(); no trace annotation (the region is already over).
+    `t0` is the region's start on time.perf_counter's clock; left out,
+    the region is taken to end now. Returns the record dict when
+    observability is on, else None."""
     seconds = float(seconds)
-    h = _span_hists.get(name)
-    if h is None:
-        h = REGISTRY.histogram(name + '.seconds')
-        _span_hists[name] = h
-    h.observe(seconds)
-    rl = _run_log()
-    if rl is None:
+    _span_hist(name).observe(seconds)
+    if not enabled():
         return None
-    rec = {'ts': time.monotonic(), 'kind': 'span', 'name': name,
-           'span': next(_span_ids), 'parent': current_span_id(),
-           'dur_s': seconds, 'fields': dict(fields)}
-    tids = trace._ids()
-    if tids:
-        rec.update(tids)
-    rl.write(rec)
+    if t0 is None:
+        t0 = time.perf_counter() - seconds
+    rec = _span_rec(name, next(_span_ids), current_span_id(), t0, seconds,
+                    dict(fields), trace._ids())
+    _keep(rec)
     return rec

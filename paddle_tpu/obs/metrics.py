@@ -2,12 +2,13 @@
 
 Prometheus-shaped, host-side, stdlib-only. Instruments are identified by
 (name, labels) — labels are how one logical series fans out per call site
-(`retry.attempts{site=...}`) or per executor (`executor.cache.hits{exe=...}`)
-while reports aggregate across them by name. Everything is thread-safe and
-cheap enough to stay armed unconditionally: an increment is one lock plus
-one add, so the registry keeps counting even when the run-log side of the
-observability layer (PADDLE_TPU_OBS_DIR) is disabled. File IO and trace
-forwarding — the costly parts — live in paddle_tpu.obs and are gated there.
+(`retry.attempts{site=...}`) or per verdict
+(`checkpoint.crc_verify{outcome=...}`) while reports aggregate across
+them by name. Everything is thread-safe and cheap enough to stay armed
+unconditionally: an increment is one lock plus one add, so the registry
+keeps counting even when the run-log side of the observability layer
+(PADDLE_TPU_OBS_DIR) is disabled. File IO and trace forwarding — the
+costly parts — live in paddle_tpu.obs and are gated there.
 
 This module must not import jax (or anything outside the stdlib): the
 disabled-mode contract of the obs layer is "no file, no jax import", and

@@ -69,6 +69,8 @@ def test_predictor_concurrent_threads_no_global_scope_race(tmp_path):
         dirs.append(str(d))
         wants.append(want)
     base_scope = global_scope()
+    # what earlier tests of this worker left there is not this test's
+    before = set(base_scope.vars)
     preds = [inference.Predictor(d, place=fluid.CPUPlace()) for d in dirs]
     errors = []
 
@@ -89,8 +91,8 @@ def test_predictor_concurrent_threads_no_global_scope_race(tmp_path):
     assert errors == []
     # the predictors' private vars never leaked into the global scope
     assert global_scope() is base_scope
-    assert all(n not in base_scope.vars for p in preds
-               for n in p._scope.vars)
+    assert set(base_scope.vars) == before
+    assert all(p._scope is not base_scope and p._scope.vars for p in preds)
 
 
 def test_compiled_artifact_round_trip(tmp_path):

@@ -688,3 +688,198 @@ def test_bench_emit_mirrors_into_run_log(tmp_path, monkeypatch, capsys):
     f = bench_evs[0]['fields']
     assert f['metric'] == 'unit.test.metric' and f['value'] == 12.5
     assert 'metrics' not in f           # the nested trajectory stays out
+
+
+# ---------------------------------------------------------------------------
+# completed spans kept in memory, and the child spans of executor.step
+# ---------------------------------------------------------------------------
+
+def test_completed_spans_keep_clock_parents_and_fields(obs_dir):
+    with obs.span('t.keep.outer', tag='x') as outer:
+        with obs.span('t.keep.inner'):
+            pass
+        post = obs.span_record('t.keep.posthoc', 0.25, t0=outer.t0, n=3)
+    kept = {r['name']: r for r in obs.completed_spans()}
+    out, inn, ph = (kept['t.keep.' + k] for k in ('outer', 'inner',
+                                                  'posthoc'))
+    assert inn['parent'] == out['span'] == ph['parent']
+    assert out['parent'] is None and out['fields'] == {'tag': 'x'}
+    assert out['t0'] <= inn['t0'] <= inn['t1'] <= out['t1']
+    assert out['dur_s'] == pytest.approx(out['t1'] - out['t0'])
+    assert ph is post and ph['fields'] == {'n': 3}
+    assert (ph['t0'], ph['t1']) == (outer.t0, outer.t0 + 0.25)
+    # ONE record: the run log holds what the buffer holds
+    events, errors = obs_report_mod.load_events(obs.run_log_path())
+    assert errors == []
+    logged = {e['name']: e for e in events if e['kind'] == 'span'}
+    assert logged['t.keep.inner'] == json.loads(json.dumps(inn))
+    # the buffer outlives the switch; only _reset() empties it
+    obs.disable()
+    assert len(obs.completed_spans()) == 3
+    obs._reset()
+    assert obs.completed_spans() == []
+
+
+def test_completed_spans_bound_themselves_and_count_drops(obs_dir,
+                                                          monkeypatch):
+    monkeypatch.setattr(obs, 'SPAN_BUFFER_MAX', 8)
+    before = obs.REGISTRY.total('obs.spans.dropped') or 0
+    for i in range(20):
+        with obs.span('t.flood', i=i):
+            pass
+    kept = obs.completed_spans()
+    assert kept[0]['kind'] == 'meta' and kept[0]['name'] == 'spans.dropped'
+    assert kept[0]['fields']['dropped'] == 12
+    assert [r['fields']['i'] for r in kept[1:]] == list(range(12, 20))
+    assert (obs.REGISTRY.total('obs.spans.dropped') or 0) - before == 12
+
+
+def test_disabled_mode_buffers_nothing_and_installs_no_gc_hook():
+    import gc
+    obs.disable()
+    with obs.span('t.off'):
+        assert obs.span_record('t.off.posthoc', 0.1) is None
+    with obs.span_if(False, 't.off.child') as sp:
+        assert sp is None
+    gc.collect()
+    assert obs.completed_spans() == []
+    assert obs._state['spans'] is None          # nothing was allocated
+    assert obs._gc_callback not in gc.callbacks
+    assert obs.histogram('t.off.child.seconds').count == 0
+
+
+def test_full_garbage_collections_become_host_gc_spans(obs_dir):
+    import gc
+    with obs.span('t.gc.before'):
+        pass                       # the first kept record installs the hook
+    assert obs._gc_callback in gc.callbacks
+    t0 = time.perf_counter()
+    gc.collect(1)                  # a young collection is not recorded
+    gc.collect()
+    t1 = time.perf_counter()
+    pauses = [r for r in obs.completed_spans() if r['name'] == 'host.gc']
+    assert len(pauses) == 1
+    assert pauses[0]['fields']['generation'] == 2
+    assert 'collected' in pauses[0]['fields']
+    assert t0 <= pauses[0]['t0'] <= pauses[0]['t1'] <= t1
+    obs._reset()
+    assert obs._gc_callback not in gc.callbacks
+
+
+def _span_counts():
+    """{span name: completed spans so far}, from the registry's
+    `<name>.seconds` histograms, which every span feeds on or off."""
+    return {s['name'][:-len('.seconds')]: s['count']
+            for s in obs.REGISTRY.snapshot()
+            if s['kind'] == 'histogram' and s['name'].endswith('.seconds')}
+
+
+def _opened(before):
+    return {k: v - before.get(k, 0) for k, v in _span_counts().items()
+            if v - before.get(k, 0)}
+
+
+STEP_CHILDREN = {'executor.prepare': 'executor.step',
+                 'executor.placement': 'executor.prepare',
+                 'executor.feed': 'executor.prepare',
+                 'executor.rng': 'executor.step',
+                 'executor.dispatch': 'executor.step',
+                 'executor.fetch': 'executor.step'}
+
+
+@pytest.mark.parametrize('on', [False, True], ids=['obs_off', 'obs_on'])
+def test_executor_step_child_spans_exist_only_with_observability_on(
+        on, tmp_path):
+    """Off, Executor.run opens exactly the spans it opened before this
+    split (executor.step and executor.fetch, executor.lowering and
+    executor.compile on a first call) and keeps nothing. On, a first step
+    has executor.first_call with its .trace and .backend parts, and a
+    steady step exactly the child spans of docs/observability.md's
+    table, nested under executor.step."""
+    if on:
+        obs.enable(str(tmp_path / 'obs'))
+    else:
+        obs.disable()
+    with fresh_program() as (main, startup):
+        loss = _fit_a_line_graph()
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        xb, yb = _housing_batch()
+        before = _span_counts()
+        exe.run(main, feed={'x': xb, 'y': yb}, fetch_list=[loss])
+        first = _opened(before)
+        before = _span_counts()
+        exe.run(main, feed={'x': xb, 'y': yb}, fetch_list=[loss])
+        steady = _opened(before)
+        exe.close()
+    always = {'executor.step': 1, 'executor.fetch': 1}
+    first_call = {'executor.lowering': 1, 'executor.compile': 1}
+    if not on:
+        assert steady == always
+        assert first == dict(always, **first_call)
+        assert obs.completed_spans() == []
+        return
+    assert steady == dict(always, **{k: 1 for k in STEP_CHILDREN})
+    want = dict(steady, **first_call)
+    del want['executor.dispatch']       # the first call is not a dispatch
+    want.update({'executor.first_call': 1, 'executor.first_call.trace': 1,
+                 'executor.first_call.backend': 1})
+    assert first == want
+    spans = obs.completed_spans()
+    by_id = {r['span']: r for r in spans}
+    last = [r for r in spans if r['name'] == 'executor.step'][-1]
+    under = [r for r in spans if r['t0'] >= last['t0'] and r is not last
+             and r['name'].startswith('executor.')]
+    assert sorted(r['name'] for r in under) == sorted(STEP_CHILDREN)
+    for r in under:
+        assert by_id[r['parent']]['name'] == STEP_CHILDREN[r['name']]
+        assert last['t0'] <= r['t0'] <= r['t1'] <= last['t1']
+    kept = {r['name']: r for r in spans}
+    assert kept['executor.prepare']['fields'] == {'cache': 'hit'}
+    assert kept['executor.placement']['fields'] == {'mesh': False}
+    assert kept['executor.feed']['fields']['bytes'] == xb.nbytes + yb.nbytes
+    call, trace_, backend = (kept['executor.first_call' + k]
+                             for k in ('', '.trace', '.backend'))
+    assert by_id[call['parent']]['name'] == 'executor.step'
+    assert trace_['parent'] == backend['parent'] == call['span']
+    assert call['fields']['outcome'] == 'compile'
+    assert call['fields']['key'] == trace_['fields']['key'] == \
+        by_id[call['parent']]['fields']['key']
+    assert backend['fields']['cached'] is False     # no persistent cache
+    assert trace_['dur_s'] > 0 and backend['dur_s'] > 0
+    assert trace_['dur_s'] + backend['dur_s'] < call['dur_s']
+    assert call['t0'] <= trace_['t0'] and backend['t1'] <= call['t1']
+
+
+def test_span_records_reach_the_run_log_in_batches(obs_dir):
+    """A span record waits in the run log for its batch (a write and a
+    flush for each cost a training step 0.7 ms on the chip's host, PR 23);
+    an event, the SPAN_BATCH-th span, run_log_path() and the closing of
+    the log each write out everything held back, in order."""
+    from paddle_tpu.obs import runlog
+
+    def on_disk():
+        with open(path) as f:
+            return [json.loads(line)['name'] for line in f]
+
+    obs.event('t.batch.first')                 # creates the file
+    path = obs.run_log_path()
+    assert on_disk() == ['run_start', 't.batch.first']
+    for i in range(3):
+        with obs.span('t.batch.span'):
+            pass
+    assert on_disk() == ['run_start', 't.batch.first']      # held back
+    obs.event('t.batch.event')      # an event goes out at once, in order
+    assert on_disk()[2:] == ['t.batch.span'] * 3 + ['t.batch.event']
+    for i in range(runlog.SPAN_BATCH + 1):
+        with obs.span('t.batch.more'):
+            pass
+    assert on_disk().count('t.batch.more') == runlog.SPAN_BATCH
+    assert obs.run_log_path() == path                       # flushes
+    assert on_disk().count('t.batch.more') == runlog.SPAN_BATCH + 1
+    with obs.span('t.batch.last'):
+        pass
+    obs._reset()                                            # closes
+    assert on_disk()[-1] == 't.batch.last'
+    events, errors = obs_report_mod.load_events(path)
+    assert errors == []
