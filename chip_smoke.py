@@ -127,13 +127,22 @@ def train_leg(place, cfg=BASE, steps=20, expect_kernel=True):
     """Leg 1: build, compile, step on one repeated seeded host batch.
     Loss finite every step and lower at the end than at the start; with
     expect_kernel the step's HLO must carry Mosaic custom calls for the
-    flash forward and both backward kernels of every attention op."""
+    flash forward and both backward kernels of every attention op, and
+    the AMP step must have lowered them with bf16 operands."""
     import paddle_tpu.fluid as fluid
+    from paddle_tpu import obs
     main, startup, avg_cost, feeds = _build(cfg, dropout=0.1)
     exe = fluid.Executor(place)
     exe.run(startup)
     feed = _batch(cfg, feeds)
+
+    def lowered():
+        return {d: obs.counter('flash.lowered', operands=d).value
+                for d in ('bfloat16', 'float32')}
+
+    before = lowered()
     losses, first_s, later_s = _run_steps(exe, main, feed, avg_cost, steps)
+    flash_lowered = {d: int(n - before[d]) for d, n in lowered().items()}
     if not losses[-1] < losses[0]:
         raise AssertionError('loss did not fall: %r' % (losses,))
     hlo = exe.lowered_hlo(main, feed, [avg_cost])
@@ -144,17 +153,21 @@ def train_leg(place, cfg=BASE, steps=20, expect_kernel=True):
             raise AssertionError(
                 'step HLO has %d tpu_custom_call(s), expected >= %d: the '
                 'flash kernels did not lower through Mosaic' % (n_calls, want))
+        if flash_lowered['float32'] or not flash_lowered['bfloat16']:
+            raise AssertionError(
+                'the AMP step did not hand its flash kernels bf16 tiles: '
+                'flash.lowered counted %r' % (flash_lowered,))
     stats = exe.cache_stats
     exe.close()
     n_params = _params(main)
     log('train: params %.1fM, batch %dx%d, first step %.1fs, then %.3fs/step'
-        ', loss %.4f -> %.4f, tpu_custom_call x%d'
+        ', loss %.4f -> %.4f, tpu_custom_call x%d, flash.lowered %r'
         % (n_params / 1e6, cfg['batch'], cfg['seq'], first_s, later_s,
-           losses[0], losses[-1], n_calls))
+           losses[0], losses[-1], n_calls, flash_lowered))
     return {'params': n_params, 'steps': steps,
             'first_step_seconds': round(first_s, 2),
             'first_loss': losses[0], 'last_loss': losses[-1],
-            'tpu_custom_calls': n_calls,
+            'tpu_custom_calls': n_calls, 'flash_lowered': flash_lowered,
             'online_compiles': stats['online_compiles'],
             'persistent_hits': stats['persistent_hits'],
             'cache_dir': stats['compile_cache_dir']}

@@ -24,6 +24,22 @@ never computed — causal forward+backward costs ~half the rectangular
 FLOPs. See the strategy note above _tri_maps for why this (and not
 compute predication) is the safe way to skip blocks under Mosaic.
 
+What is float32 and what follows the input. The q, k, v and do tiles go
+into the MXU in the dtype of their refs (bf16 under AMP, float32 in a
+Program without it), and p and ds are cast to that dtype only as operands
+of their dots; every dot accumulates in float32. Float32 whatever comes
+in: the scores s, the bias add, the causal mask, exp, the running max m
+and sum l, the accumulators (acc, dq, dk, dv scratch), lse and delta.
+Outputs and gradients leave in the input's dtype. So float32 in computes
+what it always did, and bf16 in rounds p and ds once more than the
+float32 reference does, as every matmul under AMP rounds its operands
+(tests/test_flash_attention.py has the arithmetic of the tolerance).
+Row statistics never become 1-D vectors inside a body: they stay
+lane-broadcast [rows, LANES] tiles from scratch or HBM to the score tile
+(_lanes). On the v5e that, not the operand dtype, was what a forward
+block step waited for (PERF.md, PR 24). The counter
+`flash.lowered{operands=<dtype>}` counts attention calls per lowering.
+
 `interpret` is the CALLER's decision, never read off the process's
 default backend: the op lowering passes interpret=False on a TPU place
 (and takes the XLA reference chain elsewhere), tests pass interpret=True
@@ -45,12 +61,47 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import obs
+
 NEG_BIG = -1e9   # finite mask value: keeps fully-masked rows NaN-free
 LANES = 128      # stats scratch is lane-broadcast to keep stores tiled
 
 
 def _round_up(x, m):
     return (x + m - 1) // m * m
+
+
+# The nine dots of a forward and backward, by their contracting dimensions:
+# a @ b and a @ b^T. Operands go in as they are (the tiles in their refs'
+# dtype, p and ds cast to it by the caller); the result is float32.
+_NN = (((1,), (0,)), ((), ()))
+_NT = (((1,), (1,)), ((), ()))
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _mask_causal(s, q0, k0, q_axis):
+    """NEG_BIG where a key lies after its query, for a score tile whose
+    first query is q0 and first key k0, queries running along q_axis.
+    The positions' difference within the tile does not depend on the grid
+    step; only the scalar it is compared with does."""
+    rel = (lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+           - lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
+    return jnp.where(rel >= k0 - q0, s, NEG_BIG)
+
+
+def _lanes(x, n):
+    """A lane-broadcast [rows, LANES] statistic (every lane of a row holds
+    the row's value) as [rows, n]: the same registers n / LANES times
+    over, so a row statistic meets a [rows, n] tile without ever becoming
+    a 1-D vector and being laid out again."""
+    if n <= LANES:
+        return x[:, :n]
+    if n % LANES:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return pltpu.repeat(x, n // LANES, axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -110,36 +161,30 @@ def _fwd_body(q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref,
         acc_s[:] = jnp.zeros_like(acc_s)
 
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale            # [bq, D]
-        kb = k_ref[0, 0].astype(jnp.float32)                   # [bk, D]
-        vb = v_ref[0, 0].astype(jnp.float32)
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32)
-        s = s + kb_ref[0, 0][None, :]
+        q = q_ref[0, 0]                                        # [bq, D]
+        kb = k_ref[0, 0]                                       # [bk, D]
+        vb = v_ref[0, 0]
+        s = _dot(q, kb, _NT) * scale
+        s = s + kb_ref[0]
         if causal:
-            qpos = i * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = j * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_BIG)
-        m_prev = m_s[:, 0]
-        l_prev = l_s[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+            s = _mask_causal(s, i * block_q, j * block_k, q_axis=0)
+        m_prev = m_s[:]                                        # [bq, LANES]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, block_k))
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + p.sum(axis=-1)
-        acc_s[:] = acc_s[:] * alpha[:, None] + jnp.dot(
-            p, vb, preferred_element_type=jnp.float32)
-        m_s[:] = jnp.broadcast_to(m_new[:, None], m_s.shape)
-        l_s[:] = jnp.broadcast_to(l_new[:, None], l_s.shape)
+        l_s[:] = l_s[:] * alpha + p.sum(axis=-1, keepdims=True)
+        m_s[:] = m_new
+        acc_s[:] = acc_s[:] * _lanes(alpha, acc_s.shape[1]) + _dot(
+            p.astype(vb.dtype), vb, _NN)
 
     _compute()
 
     @pl.when(is_last)
     def _finish():
-        m, l = m_s[:, 0], jnp.maximum(l_s[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_s[:] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.broadcast_to((m + jnp.log(l))[:, None],
-                                         lse_ref.shape[2:])
+        l = jnp.maximum(l_s[:], 1e-30)
+        o_ref[0, 0] = (acc_s[:] / _lanes(l, acc_s.shape[1])).astype(
+            o_ref.dtype)
+        lse_ref[0, 0] = m_s[:] + jnp.log(l)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref,
@@ -174,25 +219,20 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
         dq_s[:] = jnp.zeros_like(dq_s)
 
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        kb = k_ref[0, 0].astype(jnp.float32)
-        vb = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, 0]
-        delta = delta_ref[0, 0][:, 0]
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
-        s = s + kb_ref[0, 0][None, :]
+        q = q_ref[0, 0]
+        kb = k_ref[0, 0]
+        vb = v_ref[0, 0]
+        do = do_ref[0, 0]
+        lse = _lanes(lse_ref[0, 0], block_k)                   # [bq, bk]
+        delta = _lanes(delta_ref[0, 0], block_k)
+        s = _dot(q, kb, _NT) * scale
+        s = s + kb_ref[0]
         if causal:
-            qpos = i * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = j * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_BIG)
-        p = jnp.exp(s - lse[:, None])
-        dp = jnp.dot(do, vb.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        dq_s[:] = dq_s[:] + jnp.dot(ds, kb,
-                                    preferred_element_type=jnp.float32)
+            s = _mask_causal(s, i * block_q, j * block_k, q_axis=0)
+        p = jnp.exp(s - lse)
+        dp = _dot(do, vb, _NT)
+        ds = p * (dp - delta) * scale
+        dq_s[:] = dq_s[:] + _dot(ds.astype(kb.dtype), kb, _NN)
 
     _compute()
 
@@ -229,27 +269,24 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
         dv_s[:] = jnp.zeros_like(dv_s)
 
     def _compute():
-        k = k_ref[0, 0].astype(jnp.float32)                    # [bk, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        qb = q_ref[0, 0].astype(jnp.float32)                   # [bq, D]
-        dob = do_ref[0, 0].astype(jnp.float32)
-        lse_b = lse_ref[0, 0][:, 0]
-        delta_b = delta_ref[0, 0][:, 0]
-        s = jnp.dot(qb, k.T, preferred_element_type=jnp.float32) * scale
-        s = s + kb_ref[0, 0][None, :]
+        # the scores TRANSPOSED, [bk, bq]: both accumulators then take
+        # their p^T and ds^T as computed, and no [bq, bk] tile is turned
+        # round. What it costs is the three small vectors below.
+        k = k_ref[0, 0]                                        # [bk, D]
+        v = v_ref[0, 0]
+        qb = q_ref[0, 0]                                       # [bq, D]
+        dob = do_ref[0, 0]
+        lse_b = lse_ref[0, 0].T[:1]                            # [1, bq]
+        delta_b = delta_ref[0, 0].T[:1]
+        kb = jnp.broadcast_to(kb_ref[0], (LANES, block_k)).T[:, :1]
+        st = _dot(k, qb, _NT) * scale + kb                     # [bk, bq]
         if causal:
-            qpos = i * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = j * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_BIG)
-        p = jnp.exp(s - lse_b[:, None])                        # [bq, bk]
-        dv_s[:] = dv_s[:] + jnp.dot(p.T, dob,
-                                    preferred_element_type=jnp.float32)
-        dp = jnp.dot(dob, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_b[:, None]) * scale
-        dk_s[:] = dk_s[:] + jnp.dot(ds.T, qb,
-                                    preferred_element_type=jnp.float32)
+            st = _mask_causal(st, i * block_q, j * block_k, q_axis=1)
+        pt = jnp.exp(st - lse_b)
+        dv_s[:] = dv_s[:] + _dot(pt.astype(dob.dtype), dob, _NN)
+        dpt = _dot(v, dob, _NT)
+        dst = pt * (dpt - delta_b) * scale
+        dk_s[:] = dk_s[:] + _dot(dst.astype(qb.dtype), qb, _NN)
 
     _compute()
 
@@ -492,25 +529,36 @@ def _flash_lse_bwd(causal, scale, bq, bk, interpret, res, cot):
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-# Tile defaults from a tools/tune_flash.py sweep taken before round 3
-# (bf16, D in {64, 128}, T in {256, 1024}, full fwd+bwd): 512x512 won at
-# every swept shape. The driver has no on-chip row for it yet (ROADMAP
-# Speed item 5), so treat the choice as a starting point, not a result.
-# Equal bq == bk keeps the causal triangular block-skipping grid eligible
-# (_use_tri). Shorter sequences clip the tiles in _prep automatically.
+# Tile defaults from the tools/tune_flash.py sweep of PR 24 on a v5e (bf16,
+# D = 64, 16 x 8 x 1024 and 64 x 8 x 256, forward plus backward, square and
+# oblong tiles; docs/perf.md has every row and the commands). Not causal:
+# 1024 x 1024 beats 512 x 512 by 12% at T = 1024. Causal: 512 x 512 on the
+# triangular grid beats one masked 1024 x 1024 block by 1.5%. Equal
+# bq == bk keeps the triangular grid eligible (_use_tri). Shorter sequences
+# clip the tiles in _prep, which is all that T = 256 ever sees.
 # PADDLE_TPU_FLASH_BQ/BK override.
-_TUNED_BQ_BK = {True: (512, 512), False: (512, 512)}
+_TUNED_BQ_BK = {True: (512, 512), False: (1024, 1024)}
+# Beside 1024 x 1024 float32 score tiles Mosaic's VMEM budget holds operand
+# rows of up to this many bytes (compiled for a described v5e, PR 24:
+# float32 at D = 128 and bf16 at D = 256 fit, float32 at D = 256 is
+# refused).
+_WIDE_ROW_BYTES = 512
+
+
+def _default_tile(tuned, T, row_bytes):
+    """The table's tile for a sequence of T, or the 512 it was before PR 24
+    where the larger one cannot be had for nothing: operand rows too wide
+    for VMEM, or a length it would pad further than 512 does (1536 keys
+    in 1024-tiles are 2048)."""
+    if row_bytes > _WIDE_ROW_BYTES or (T > tuned and T % tuned):
+        return min(tuned, 512)
+    return tuned
 
 
 def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
           causal=False):
     """Shared block-size/padding/bias plumbing for the public wrappers."""
     import os
-    tuned_bq, tuned_bk = _TUNED_BQ_BK[bool(causal)]
-    if block_q is None:
-        block_q = int(os.environ.get('PADDLE_TPU_FLASH_BQ', tuned_bq))
-    if block_k is None:
-        block_k = int(os.environ.get('PADDLE_TPU_FLASH_BK', tuned_bk))
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     if sm_scale is None:
@@ -520,6 +568,20 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
             'flash attention needs interpret=True (pallas interpreter) or '
             'interpret=False (Mosaic, TPU only) from its caller, got %r'
             % (interpret,))
+    # the dots take their operands as given, so the three agree on a dtype
+    operands = jnp.result_type(q, k, v)
+    q, k, v = (x.astype(operands) for x in (q, k, v))
+    # trace time: once per attention call per lowering (a forward and two
+    # backward kernels each), never per step
+    obs.counter('flash.lowered', operands=operands.name).inc()
+    tuned_bq, tuned_bk = _TUNED_BQ_BK[bool(causal)]
+    row_bytes = D * operands.itemsize
+    if block_q is None:
+        block_q = int(os.environ.get(
+            'PADDLE_TPU_FLASH_BQ', _default_tile(tuned_bq, Tq, row_bytes)))
+    if block_k is None:
+        block_k = int(os.environ.get(
+            'PADDLE_TPU_FLASH_BK', _default_tile(tuned_bk, Tk, row_bytes)))
     if key_bias is None:
         key_bias = jnp.zeros((B, Tk), jnp.float32)
     else:

@@ -10,35 +10,42 @@ depends on the final result: one dispatch, one sync, `iters` kernels.
 """
 import time
 
-__all__ = ['time_fwd_bwd_chained']
+__all__ = ['time_chained', 'time_fwd_bwd_chained']
+
+
+def time_chained(step, x, iters, warmup=1):
+    """Seconds per call of step(x) -> x' (x a tuple of [B, H, T, D]
+    arrays), measured as `iters` calls chained inside one jit with a
+    single scalar, which depends on every final array, pulled to the host
+    at the end."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    @jax.jit
+    def run(x):
+        x = jax.lax.fori_loop(0, iters, lambda _, x: step(x), x)
+        return sum(jnp.sum(a[0, 0, 0, :8].astype(jnp.float32)) for a in x)
+
+    for _ in range(warmup):
+        s = float(run(x))           # compile + warm; host sync
+        assert np.isfinite(s), s
+    t0 = time.time()
+    s = float(run(x))               # host round-trip = completion
+    assert np.isfinite(s), s
+    return (time.time() - t0) / iters
 
 
 def time_fwd_bwd_chained(loss_fn, q, k, v, iters, warmup=1):
-    """Seconds per fwd+bwd step of loss_fn(q, k, v) -> scalar, measured as
-    `iters` chained steps inside one jit with a single scalar pulled to
-    the host at the end. ALL THREE inputs advance by their gradients —
+    """Seconds per fwd+bwd step of loss_fn(q, k, v) -> scalar, chained as
+    time_chained does. ALL THREE inputs advance by their gradients —
     dq and (dk, dv) come from separate pallas calls in the flash backward,
     so a chain that consumed only dq would let XLA dead-code-eliminate
     the dk/dv kernel and time half a backward."""
     import jax
-    import jax.numpy as jnp
-    import numpy as np
     grad = jax.grad(loss_fn, argnums=(0, 1, 2))
 
-    @jax.jit
-    def run(q, k, v):
-        def body(_, qkv):
-            qq, kk, vv = qkv
-            dq, dk, dv = grad(qq, kk, vv)
-            return (qq + 1e-6 * dq, kk + 1e-6 * dk, vv + 1e-6 * dv)
-        qn, kn, vn = jax.lax.fori_loop(0, iters, body, (q, k, v))
-        return jnp.sum((qn[0, 0, 0, :8] + kn[0, 0, 0, :8]
-                        + vn[0, 0, 0, :8]).astype(jnp.float32))
+    def step(qkv):
+        return tuple(x + 1e-6 * dx for x, dx in zip(qkv, grad(*qkv)))
 
-    for _ in range(warmup):
-        s = float(run(q, k, v))     # compile + warm; host sync
-        assert np.isfinite(s), s
-    t0 = time.time()
-    s = float(run(q, k, v))         # host round-trip = completion
-    assert np.isfinite(s), s
-    return (time.time() - t0) / iters
+    return time_chained(step, (q, k, v), iters, warmup)

@@ -1,0 +1,52 @@
+"""The flash kernels at their default tiles, compiled by Mosaic for a
+DESCRIBED v5e (no chip, nothing runs): what the interpreter and
+jax.export cannot refuse — VMEM the kernel may not have, slices Mosaic
+will not tile — is refused here. The cells' shapes, and the shapes on
+either side of the default-tile rule (_default_tile). The topology is
+described inside a fixture and in this file only: one process at a time
+may load the TPU's library."""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu import ops
+
+
+@pytest.fixture(scope='module')
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:
+        pytest.skip('no v5e:2x2 topology can be described here: %s' % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize('causal', [False, True], ids=['full', 'causal'])
+@pytest.mark.parametrize('dtype,shape', [
+    ('bfloat16', (16, 8, 1024, 64)),    # tfm_s1024: 1024 tiles, 512 causal
+    ('bfloat16', (64, 8, 256, 64)),     # tfm_s256: one 256 tile
+    ('bfloat16', (2, 8, 1536, 64)),     # 1024 would pad to 2048: 512
+    ('bfloat16', (4, 8, 1024, 256)),    # the widest rows 1024 tiles take
+    ('float32', (2, 8, 2048, 128)),     # the same 512 bytes a row
+    ('float32', (4, 8, 1024, 256)),     # wider: refused at 1024, so 512
+], ids=lambda x: x if isinstance(x, str) else 'x'.join(map(str, x)))
+def test_default_tiles_compile_for_v5e(one_chip, dtype, shape, causal):
+    x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    kb = jax.ShapeDtypeStruct((shape[0], shape[2]), jnp.float32,
+                              sharding=one_chip)
+
+    def loss(q, k, v, kb):
+        o = ops.flash_attention(q, k, v, key_bias=kb, causal=causal,
+                                interpret=False)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x, kb).compile()
+    assert compiled.as_text().count('tpu_custom_call') == 3

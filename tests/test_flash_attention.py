@@ -341,3 +341,177 @@ def test_flash_under_a_mesh_lowers_only_per_shard():
     want = ops.reference_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=3e-5, atol=3e-5)
+
+
+# ---------------------------------------------------------------------------
+# bf16 in: the tiles reach the dots as bf16, everything else stays float32
+# ---------------------------------------------------------------------------
+
+# One bf16 rounding moves a value by at most 2^-8 of itself. Against the
+# float32 reference ON THE SAME bf16 INPUTS, in relative norm, the kernel
+# adds: forward, p rounded before p @ v (1) and o rounded on its way out
+# (1) = 2 eps. Gradients, with a cotangent that is exact in bf16 (the loss
+# is linear in o): dv has p rounded (1) and its own rounding out (1); dq
+# and dk have ds rounded (1), delta = sum(do * o) read off the ROUNDED o
+# (1) and their own rounding out (1), and dp - delta cancels, which is
+# given the one spacing dv leaves spare: 4 eps. Products of bf16 tiles are
+# exact in the float32 accumulator and s, m, l, lse, delta never leave
+# float32, so lse holds the float32 tolerance. Not fitted: the interpreter
+# reads 0.5 eps forward and 0.6 to 0.8 eps on the gradients.
+BF16_EPS = 2.0 ** -8
+
+_BF16_CASES = {
+    'plain': dict(Tq=128, Tk=128),
+    'key_bias': dict(Tq=128, Tk=128, bias=True),
+    # Tq != Tk keeps the causal mask on the rectangular grid
+    'causal_rectangular': dict(Tq=128, Tk=256, bias=True, causal=True,
+                               block=128),
+    'causal_triangular_3x3': dict(Tq=384, Tk=384, bias=True, causal=True,
+                                  block=128),
+    'uneven_lengths': dict(Tq=9, Tk=33, bias=True),
+    'lse_cotangent': dict(Tq=128, Tk=128, bias=True, causal=True, lse=True),
+}
+
+
+def _rel_norm(got, want):
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+@pytest.mark.parametrize('case', sorted(_BF16_CASES))
+def test_bf16_inputs_match_float32_reference(case):
+    c = _BF16_CASES[case]
+    causal, with_lse = c.get('causal', False), c.get('lse', False)
+    q, k, v, kb = _rand_qkv(B=1, H=2, Tq=c['Tq'], Tk=c['Tk'], D=32, seed=21)
+    q, k, v = [jnp.asarray(x, jnp.bfloat16) for x in (q, k, v)]
+    bias = jnp.asarray(kb) if c.get('bias') else None
+    r = np.random.RandomState(22)
+    # cotangents that bf16 holds exactly, so both sides see the same ones
+    w = jnp.asarray(r.randn(1, 2, c['Tq'], 32), jnp.bfloat16)
+    u = jnp.asarray(r.randn(1, 2, c['Tq']), jnp.bfloat16).astype(jnp.float32)
+
+    def flash(q, k, v):
+        return ops.flash_attention_lse(
+            q, k, v, key_bias=bias, causal=causal, block_q=c.get('block'),
+            block_k=c.get('block'), interpret=True)
+
+    def ref(q, k, v):
+        s = jnp.einsum('bhqd,bhkd->bhqk', q, k) * q.shape[-1] ** -0.5
+        if bias is not None:
+            s = s + bias[:, None, None, :]
+        if causal:
+            s = jnp.where(jnp.arange(c['Tq'])[:, None]
+                          >= jnp.arange(c['Tk'])[None, :], s, -1e9)
+        return (ops.reference_attention(q, k, v, key_bias=bias,
+                                        causal=causal),
+                jax.scipy.special.logsumexp(s, axis=-1))
+
+    def loss(fn):
+        def f(q, k, v):
+            o, lse = fn(q, k, v)
+            val = jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32))
+            if with_lse:
+                val = val + jnp.sum(lse * u)
+            return val, (o, lse)
+        return jax.grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    g_k, (o_k, lse_k) = loss(flash)(q, k, v)
+    with jax.default_matmul_precision('highest'):
+        g_r, (o_r, lse_r) = loss(ref)(*[x.astype(jnp.float32)
+                                        for x in (q, k, v)])
+    assert o_k.dtype == jnp.bfloat16 and lse_k.dtype == jnp.float32
+    assert all(g.dtype == jnp.bfloat16 for g in g_k)
+    assert _rel_norm(o_k, o_r) <= 2 * BF16_EPS
+    np.testing.assert_allclose(np.asarray(lse_k), np.asarray(lse_r),
+                               rtol=2e-5, atol=2e-5)
+    for got, want, name in zip(g_k, g_r, ('dq', 'dk', 'dv')):
+        assert _rel_norm(got, want) <= 4 * BF16_EPS, name
+
+
+def _kernel_bodies(dtype, causal):
+    """The traced bodies of the three kernels of one forward and backward
+    (rectangular grid, or triangular when causal), as (name, [eqns])."""
+    x = jax.ShapeDtypeStruct((2, 2, 256, 64), dtype)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: ops.flash_attention(
+            q, k, v, causal=causal, block_q=128, block_k=128,
+            interpret=True).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))(x, x, x)
+
+    def walk(jp, out):
+        for e in jp.eqns:
+            out.append(e)
+            for p in e.params.values():
+                for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                    sub = getattr(sub, 'jaxpr', sub)
+                    if hasattr(sub, 'eqns'):
+                        walk(sub, out)
+        return out
+
+    calls = [e for e in walk(jaxpr.jaxpr, [])
+             if e.primitive.name == 'pallas_call']
+    return [(e.params['jaxpr'].debug_info.func_name,
+             walk(e.params['jaxpr'], [])) for e in calls]
+
+
+@pytest.mark.parametrize('causal', [False, True],
+                         ids=['rectangular', 'triangular'])
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+def test_kernel_dots_take_the_inputs_dtype(dtype, causal):
+    """The mechanism, pinned off the chip: all nine dots of the three
+    bodies (2 + 3 + 4) take operands of the refs' dtype and give float32;
+    with bf16 in nothing is cast up to float32 anywhere in a body (q, k, v
+    and do feed dots alone), with float32 in nothing is cast at all. No
+    score-sized or bf16 tile is transposed: the dk/dv body turns round its
+    lane-broadcast float32 row statistics and nothing else."""
+    bodies = _kernel_bodies(jnp.dtype(dtype), causal)
+    assert len(bodies) == 3, [n for n, _ in bodies]
+    assert all(('_tri' in name) == causal for name, _ in bodies)
+    n_dots = []
+    for name, eqns in bodies:
+        dots = [e for e in eqns if e.primitive.name == 'dot_general']
+        n_dots.append(len(dots))
+        for e in dots:
+            assert [str(a.aval.dtype) for a in e.invars] == [dtype] * 2, name
+            assert e.outvars[0].aval.dtype == jnp.float32, name
+        for e in eqns:
+            if e.primitive.name == 'transpose':
+                aval = e.invars[0].aval
+                assert aval.dtype == jnp.float32 and 128 in aval.shape, name
+        casts = [(str(e.invars[0].aval.dtype), str(e.params['new_dtype']))
+                 for e in eqns if e.primitive.name == 'convert_element_type'
+                 and e.invars[0].aval.dtype != e.params['new_dtype']]
+        floats = [c for c in casts if 'float' in c[0] and 'float' in c[1]]
+        if dtype == 'bfloat16':
+            assert floats and set(floats) == {('float32', 'bfloat16')}, name
+        else:
+            assert not floats, (name, floats)
+    assert sorted(n_dots) == [2, 3, 4]
+
+
+def test_flash_lowered_counts_once_per_call_per_lowering_by_dtype():
+    from paddle_tpu import obs
+
+    def count():
+        return {d: obs.counter('flash.lowered', operands=d).value
+                for d in ('bfloat16', 'float32')}
+
+    def two_calls(q, k, v):
+        o = ops.flash_attention(q, k, v, interpret=True)
+        o = ops.flash_attention(o, k, v, causal=True, interpret=True)
+        return o.astype(jnp.float32).sum()
+
+    step = jax.jit(jax.grad(two_calls, argnums=(0, 1, 2)))
+    x16 = jnp.ones((1, 1, 8, 8), jnp.bfloat16)
+    before = count()
+    for _ in range(3):          # three steps, one lowering
+        step(x16, x16, x16)
+    after = count()
+    assert after['bfloat16'] - before['bfloat16'] == 2
+    assert after['float32'] == before['float32']
+    x32 = x16.astype(jnp.float32)
+    step(x32, x32, x32)         # another dtype is another lowering
+    step(x32, x32, x32)
+    assert count()['float32'] - before['float32'] == 2
+    assert count()['bfloat16'] == after['bfloat16']

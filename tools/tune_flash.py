@@ -6,6 +6,11 @@ the winning env setting (PADDLE_TPU_FLASH_BQ/BK consumed by
 paddle_tpu.ops.flash_attention). Run on TPU:
 
     python tools/tune_flash.py [--seq 256] [--batch 64] [--heads 8] [--dim 64]
+
+--parts also times the forward, dq and dk/dv kernels ALONE at the table's
+default tiles (one `part ...` line each, with the time per grid step): what
+to read before and after a change to a kernel body. docs/perf.md has the
+last sweep's rows and the commands that gave them.
 """
 import argparse
 import itertools
@@ -19,6 +24,49 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
+def time_parts(q, k, v, causal, iters):
+    """[(kernel, seconds per call, grid steps per call)] of the forward,
+    dq and dk/dv kernels alone at the default tiles. Each chain consumes
+    one kernel's outputs only, so XLA removes the other backward call."""
+    import importlib
+    import jax.numpy as jnp
+    from paddle_tpu.utils.timing import time_chained
+    # the package's attribute of that name is the function
+    fa = importlib.import_module('paddle_tpu.ops.flash_attention')
+    q, k, v, kb, scale, bq, bk, interp, _, _ = fa._prep(
+        q, k, v, None, None, None, None, False, causal=causal)
+    o, lse = fa._fwd_call(q, k, v, kb, causal, scale, bq, bk, interp)
+    delta = jnp.sum(o.astype(jnp.float32) ** 2, axis=-1)
+    delta = jnp.broadcast_to(delta[..., None], delta.shape + (fa.LANES,))
+
+    def nudge(x, dx):
+        return x + (1e-6 * dx).astype(x.dtype)
+
+    def bwd(q, k, v):       # the cotangent is o itself: bf16, full rank
+        return fa._bwd_call(q, k, v, kb, o, lse, delta, causal, scale,
+                            bq, bk, interp)
+
+    def fwd_step(x):
+        return (nudge(x[0], fa._fwd_call(x[0], k, v, kb, causal, scale,
+                                         bq, bk, interp)[0]),)
+
+    def dq_step(x):
+        return (nudge(x[0], bwd(x[0], k, v)[0]),)
+
+    def dkv_step(x):
+        _, dk, dv = bwd(q, x[0], x[1])
+        return nudge(x[0], dk), nudge(x[1], dv)
+
+    B, H, T, _ = q.shape
+    nq = T // bq
+    blocks = nq * (nq + 1) // 2 if fa._use_tri(causal, T, T, bq, bk) \
+        else nq * (T // bk)
+    return [(name, time_chained(step, x, iters), B * H * blocks)
+            for name, step, x in (('fwd', fwd_step, (q,)),
+                                  ('dq', dq_step, (q,)),
+                                  ('dkv', dkv_step, (k, v)))]
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument('--seq', type=int, default=256)
@@ -29,6 +77,8 @@ def main():
     ap.add_argument('--iters', type=int, default=20)
     ap.add_argument('--blocks', type=str, default='128,256,512',
                     help='comma-separated candidate tile sizes')
+    ap.add_argument('--parts', action='store_true',
+                    help='also time fwd, dq and dkv alone at the default tiles')
     args = ap.parse_args()
 
     import jax
@@ -47,6 +97,11 @@ def main():
                     dtype=jnp.bfloat16)
     v = jnp.asarray(rng.randn(B, H, T, D).astype('float32'),
                     dtype=jnp.bfloat16)
+
+    if args.parts:
+        for name, dt, steps in time_parts(q, k, v, args.causal, args.iters):
+            print('part %-3s %.3f ms/call, %d grid steps, %.3f us/grid step'
+                  % (name, dt * 1e3, steps, dt * 1e6 / steps))
 
     cands = sorted({min(int(b), T) for b in args.blocks.split(',')})
     results = []
