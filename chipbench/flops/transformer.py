@@ -34,16 +34,17 @@ def train_step_flops(config, traffic):
 
 
 def kernel_cost(config, traffic, chips=1):
-    """What one chip's flash kernels of one step require: (FLOPs, bytes).
-    Forward reads q, k, v and writes the output; backward reads q, k, v,
-    the output and its gradient and writes three gradients: 12 tensors of
-    batch x seq x d_model in bf16, plus the f32 log-sum-exp rows once
-    written and once read. The required FLOPs are the forward's two
-    matmuls and the backward's four."""
+    """What one chip's Pallas kernels of one step require, by the Fluid op
+    type whose scope they run in: {op type: (FLOPs, bytes)}. The flash
+    kernels are the only ones here. Forward reads q, k, v and writes the
+    output; backward reads q, k, v, the output and its gradient and writes
+    three gradients: 12 tensors of batch x seq x d_model in bf16, plus the
+    f32 log-sum-exp rows once written and once read. The required FLOPs
+    are the forward's two matmuls and the backward's four."""
     m = config['model']
     batch, seq = traffic['batch'] // chips, traffic['seq']
     f = forward_flops(m, batch, seq)['attention']
     calls = sum(c for _, c in attention_calls(m))
     tensor = batch * seq * m['d_model'] * 2
     lse = batch * m['n_head'] * seq * 4
-    return 3.0 * f, calls * (12 * tensor + 2 * lse)
+    return {'flash_attention': (3.0 * f, calls * (12 * tensor + 2 * lse))}

@@ -258,8 +258,11 @@ def run_cell(cell, seed, seconds, traced, place, t_start, work_dir,
         # window's step time (layers/device_idle_pct.py says why)
         summary['traced_idle_pct'] = 100.0 * (
             1.0 - red['busy0_s'] / red['window_s'])
+        summary['kernel_s'] = red['kernel_s']
+        summary['kernel_by_op_s'] = red['kernel_by_op_s']
     if kernel_cost and peak:
-        summary['kernel_bound'] = peaks.roofline(kernel_cost, peak)[1]
+        summary['kernel_bound'] = {op: peaks.roofline(cost, peak)[1]
+                                   for op, cost in kernel_cost.items()}
     exe.close()
     return {'line': line, 'summary': summary}
 

@@ -116,12 +116,19 @@ def reduce(raw, instr_scopes, steps):
 
     by_op = collections.Counter()         # Fluid op type -> ns
     by_scope = collections.Counter()      # '<op type>_<index>' -> ns
+    kernel_by_op = collections.Counter()  # Fluid op type -> Mosaic ns
     for name, self_ns in iv.self_times([(s, e, n) for s, e, n, _ in events0]):
         scope = scopes.scope_of(instr_scopes.get(name, ''))
         by_op[scope[0] if scope else 'unattributed'] += self_ns
         if scope:
             by_scope['%s_%d' % scope] += self_ns
-    kernel_ns = iv.total(iv.union((s, e) for s, e, _, k in events0 if k))
+    kernels = [(s, e, n) for s, e, n, k in events0 if k]
+    kernel_ns = iv.total(iv.union((s, e) for s, e, _ in kernels))
+    # the Mosaic events alone, by the Fluid op type whose scope they lie
+    # in: self times among themselves, so the parts add up to kernel_ns
+    for name, self_ns in iv.self_times(kernels):
+        scope = scopes.scope_of(instr_scopes.get(name, ''))
+        kernel_by_op[scope[0] if scope else 'unattributed'] += self_ns
 
     # a collective is busy from its start to its done (the async span);
     # what runs meanwhile on the op queue, other than its own two ends,
@@ -151,6 +158,7 @@ def reduce(raw, instr_scopes, steps):
         'fluid_op_s': {k: v * ns for k, v in by_op.items()},
         'fluid_scope_s': {k: v * ns for k, v in by_scope.items()},
         'kernel_s': kernel_ns * ns,
+        'kernel_by_op_s': {k: v * ns for k, v in kernel_by_op.items()},
         'collective_s': iv.total(iv.clip(coll, lo, hi)) * ns,
         'collective_exposed_s': exposed * ns,
         'idle_by_span_s': {k: v * ns for k, v in gap_ns.items()},

@@ -1,8 +1,7 @@
-"""Device time per step of the Mosaic (Pallas) kernel events."""
+"""Device time per step of the flash kernels: the Mosaic (Pallas) events
+that lie in `flash_attention` scopes."""
+from chipbench.harness import kernels
 
 
 def read(reading):
-    red = reading['trace']
-    if red is None or not red['kernel_s']:
-        return None
-    return 1e3 * red['kernel_s'] / red['steps']
+    return kernels.ms(reading, 'flash_attention')

@@ -2,9 +2,7 @@
 functions and the Transformer's reference: everything that needs no
 compiled training step.
 """
-import json
 import os
-import re
 import sys
 
 import numpy as np
@@ -15,107 +13,136 @@ import chipbench_toy as toy  # noqa: E402
 
 sys.path.insert(0, toy.REPO)
 
-NAME = re.compile(r'^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$')
-UNIT = re.compile(r'^[A-Za-z0-9_/%.\-]{1,16}$')
-SOURCES = {'device_trace', 'program_span', 'program_counter', 'host_clock'}
-CELLS = ['tfm_s1024', 'tfm_s256', 'resnet50_b256', 'tfm_s1024_dp4']
-
 
 @pytest.fixture(scope='module')
 def spec():
-    with open(os.path.join(toy.REPO, 'BENCHMARK.json')) as f:
-        return json.load(f)
+    return toy.repo_spec()
 
 
 def test_benchmark_json_meets_the_contract(spec):
-    assert set(spec) == {'command', 'paths', 'run_seconds', 'configs',
-                         'workloads', 'end_to_end', 'per_layer'}
+    from chipbench.harness import contract
     assert os.path.getsize(os.path.join(toy.REPO, 'BENCHMARK.json')) < 65536
-    assert spec['paths'] == ['chipbench', 'tests/test_chipbench']
-    assert spec['command'][:2] == ['python3', 'chipbench/run.py']
-    n = len(spec['workloads'])
-    assert 2 <= n <= 24
-    assert isinstance(spec['run_seconds'], int)
-    assert 1 <= spec['run_seconds'] <= 51
-    # the full check, with all 24 cells a later PR may add, fits
-    full = (2 + 14 * 24) * (spec['run_seconds'] + 60) + 24 * 2 * 90 + 1200
-    assert full <= 43200
-    names = set()
-    configs = {c['name']: c for c in spec['configs']}
-    for c in spec['configs']:
-        assert set(c) == {'name', 'source', 'file', 'reduced', 'why'}
-        assert NAME.match(c['name']) and c['file'].startswith('chipbench/')
-        with open(os.path.join(toy.REPO, c['file'])) as f:
-            held = json.load(f)
-        assert held['source'] == c['source']
-        assert held['reduced'] == c['reduced'] == []
-    pairs = set()
-    for w in spec['workloads']:
-        assert set(w) == {'name', 'config', 'traffic', 'chips', 'why'}
-        assert NAME.match(w['name']) and NAME.match(w['traffic'])
-        assert w['config'] in configs and w['chips'] in (1, 4)
-        assert 1 <= len(w['why']) <= 200 and '\n' not in w['why']
-        pairs.add((w['config'], w['traffic']))
-        names.add(w['name'])
-    assert len(pairs) == n and len(names) == n
-    assert {w['config'] for w in spec['workloads']} == set(configs)
-    four = sum(w['chips'] == 4 for w in spec['workloads'])
-    assert four <= max(1, n // 4)
-    e2e = {m['name']: m for m in spec['end_to_end']}
-    assert 'setup_s' in e2e and e2e['setup_s']['bound'] <= 0.1
-    metric_names = [m['name'] for m in spec['end_to_end'] + spec['per_layer']]
-    assert len(metric_names) == len(set(metric_names))
-    for m in spec['end_to_end']:
-        assert set(m) - {'workloads'} == {'name', 'unit', 'better', 'bound',
-                                          'source'}
-        assert 0.01 <= m['bound'] <= 0.1
-        assert m['source'] in ('host_clock', 'device_trace')
-    for m in spec['per_layer']:
-        assert set(m) - {'workloads'} == {'name', 'unit', 'better', 'source',
-                                          'layer', 'moves'}
-        assert m['moves'] in e2e and m['source'] in SOURCES
-        assert 1 <= len(m['layer']) <= 200
-        # reported only where the metric it moves is
-        where = set(m.get('workloads', names))
-        assert where <= set(e2e[m['moves']].get('workloads', names)), m
-    for m in spec['end_to_end'] + spec['per_layer']:
-        assert NAME.match(m['name']) and UNIT.match(m['unit'])
-        assert m['better'] in ('lower', 'higher')
-        assert set(m.get('workloads', [])) <= names
-    for w in names:
-        has = [m['name'] for m in spec['end_to_end']
-               if w in m.get('workloads', names)]
-        assert 'setup_s' in has and len(has) >= 2
-        assert any(w in m.get('workloads', names) for m in spec['per_layer'])
+    contract.check_spec(spec)
 
 
 def test_every_named_thing_has_its_file(spec):
-    """Cells, configurations, traffic mixes and per-layer metrics are found
-    by the names BENCHMARK.json gives; files are named from a name's
-    characters."""
-    from chipbench.harness import catalog
-    assert [w['name'] for w in spec['workloads']] == CELLS
-    for w in spec['workloads']:
-        cell = catalog.load_cell(w['name'])
-        assert cell['cell']['config'] == w['config']
-        assert cell['cell']['traffic'] == w['traffic']
-        assert cell['cell']['chips'] == w['chips']
-        assert cell['cell']['why'] == w['why']
-        # the rate is named by the generator's unit of work
-        assert {m['name'] for m in catalog.metrics_of(
-            w['name'], 'end_to_end')} == {
-                cell['generator'].UNIT + '_per_s', 'setup_s'}
-    for m in spec['per_layer']:
-        assert callable(catalog.load_reader(m['name']))
-    for path in spec['paths']:
-        for d, _, files in os.walk(os.path.join(toy.REPO, path)):
-            if '__pycache__' in d:
-                continue
-            for f in files:
-                assert re.match(r'^[A-Za-z0-9_.\-]+$', f), f
+    """Cells, configurations with their toy widths, traffic mixes and
+    per-layer metrics are found by the names BENCHMARK.json gives; files
+    are named from a name's characters."""
+    from chipbench.harness import catalog, contract
+    contract.check_files(spec, toy.REPO, catalog.ROOT)
 
 
-@pytest.mark.parametrize('name', CELLS)
+def _cut(**changes):
+    """A configuration cut in depth, as BENCHMARK.json's entry and as its
+    file: what a model larger than one chip looks like."""
+    held = {'model': {'n_layer': 1, 'd_model': 2048, 'num_experts': 64},
+            'reduced': ['n_layer'], 'reduced_from': {'n_layer': 16},
+            'deployment': 'one of 16 layers on one chip, all 64 experts'}
+    listed = changes.pop('listed', None)
+    held.update(changes)
+    return {'name': 'cut', 'reduced': held['reduced'] if listed is None
+            else listed}, held
+
+
+def test_a_configuration_cut_in_depth_is_legal():
+    from chipbench.harness import contract
+    contract.check_reduced(*_cut())
+    contract.check_reduced(*_cut(
+        reduced=['n_layer', 'num_experts'],
+        reduced_from={'n_layer': 16, 'num_experts': 256}))
+    # a key of the source's config that the model here leaves out
+    contract.check_reduced(*_cut(reduced=['mtp_layers'],
+                                 reduced_from={'mtp_layers': 1}))
+
+
+BAD_REDUCED = {
+    'not_a_key': (dict(reduced=['n_layer 16 -> 1'],
+                       reduced_from={'n_layer 16 -> 1': 16}), 'a name'),
+    'file_differs': (dict(listed=[]), "not BENCHMARK.json's"),
+    'no_source_value': (dict(reduced_from=None), 'reduced_from'),
+    'source_value_of_another_key': (
+        dict(reduced_from={'n_layer': 16, 'num_experts': 256}),
+        'reduced_from'),
+    'not_changed': (dict(reduced_from={'n_layer': 1}), "equals the source's"),
+    'no_deployment': (dict(deployment=' '), 'deployment'),
+    'listed_twice': (dict(reduced=['n_layer', 'n_layer']), 'distinct'),
+}
+
+
+@pytest.mark.parametrize('case', sorted(BAD_REDUCED))
+def test_a_bad_reduced_entry_is_refused(case):
+    from chipbench.harness import contract
+    changes, message = BAD_REDUCED[case]
+    with pytest.raises(contract.ContractError, match=message):
+        contract.check_reduced(*_cut(**changes))
+
+
+@pytest.mark.parametrize('key', [
+    'd_model', 'hidden_size', 'moe_intermediate_size', 'head_dim',
+    'kv_lora_rank', 'num_experts_per_tok', 'sliding_window',
+    'ssm_state_size', 'expand', 'stage_width', 'q_proj_size'])
+def test_a_width_in_reduced_is_refused(key):
+    from chipbench.harness import contract
+    assert contract.names_a_width(key)
+    with pytest.raises(contract.ContractError, match='width'):
+        contract.check_reduced(*_cut(reduced=[key], reduced_from={key: 1}))
+
+
+@pytest.mark.parametrize('key', [
+    'n_layer', 'num_hidden_layers', 'num_attention_heads',
+    'num_key_value_heads', 'num_experts', 'n_routed_experts', 'vocab_size',
+    'depth'])
+def test_depth_and_counts_may_be_cut(key):
+    from chipbench.harness import contract
+    assert not contract.names_a_width(key)
+
+
+def test_an_accepted_cell_may_not_go_and_additions_keep_the_chip_share(spec):
+    import copy
+    from chipbench.harness import contract
+    gone = copy.deepcopy(spec)
+    gone['workloads'] = [w for w in gone['workloads']
+                         if w['name'] != 'tfm_s256']
+    for m in gone['end_to_end'] + gone['per_layer']:
+        if 'workloads' in m:
+            m['workloads'] = [w for w in m['workloads'] if w != 'tfm_s256']
+    with pytest.raises(contract.ContractError, match='accepted cell'):
+        contract.check_spec(gone)
+    # a fifth cell is legal; a second one on four chips is not yet
+    more = copy.deepcopy(spec)
+    more['workloads'].append(dict(more['workloads'][0], name='fifth',
+                                  traffic='another'))
+    for m in more['end_to_end'] + more['per_layer']:
+        if 'workloads' in m and more['workloads'][0]['name'] in m['workloads']:
+            m['workloads'].append('fifth')
+    contract.check_spec(more)
+    more['workloads'][-1]['chips'] = 4
+    with pytest.raises(contract.ContractError, match='four chips'):
+        contract.check_spec(more)
+
+
+def test_a_configuration_without_its_toy_file_is_refused(spec, tmp_path):
+    """The contract names the missing file, and so does the toy run of a
+    cell of that configuration."""
+    import shutil
+    from chipbench.harness import catalog, contract
+    os.symlink(catalog.ROOT, tmp_path / 'chipbench')
+    toys = tmp_path / 'tests' / 'test_chipbench' / 'toy'
+    shutil.copytree(toy.toy_dir(), toys)
+    root = str(tmp_path / 'chipbench')
+    contract.check_files(spec, str(tmp_path), root)
+    config = spec['configs'][-1]['name']
+    cell = [w['name'] for w in spec['workloads'] if w['config'] == config][0]
+    os.remove(toys / (config + '.json'))
+    missing = os.path.join('toy', config + '.json')
+    with pytest.raises(contract.ContractError, match=missing):
+        contract.check_files(spec, str(tmp_path), root)
+    with pytest.raises(FileNotFoundError, match=missing):
+        toy.load_toy_cell(cell, root=root)
+
+
+@pytest.mark.parametrize('name', toy.CELLS)
 def test_traffic_is_a_function_of_the_seed(name):
     cell = toy.load_toy_cell(name)
     gen, traffic, config = cell['generator'], cell['traffic'], cell['config']
@@ -173,7 +200,9 @@ def test_transformer_flops_meet_the_cross_checks():
         assert abs(3 * f['attention'] - attn) < 0.01e12
         step = cell['flops'].train_step_flops(cell['config'], t)
         assert abs(step - total) < 0.05e12
-        flops, nbytes = cell['flops'].kernel_cost(cell['config'], t)
+        cost = cell['flops'].kernel_cost(cell['config'], t)
+        assert list(cost) == ['flash_attention']
+        flops, nbytes = cost['flash_attention']
         assert flops == 3 * f['attention'] and nbytes > 0
     dp4 = catalog.load_cell('tfm_s1024_dp4')
     one = catalog.load_cell('tfm_s1024')

@@ -70,6 +70,8 @@ def test_reduce_busy_idle_scopes_and_gap_attribution():
     assert red['fluid_op_s']['adam'] == pytest.approx(100e-9)
     assert red['fluid_op_s']['fused_attention'] == pytest.approx(100e-9)
     assert red['kernel_s'] == pytest.approx(100e-9)
+    assert red['kernel_by_op_s'] == {
+        'fused_attention': pytest.approx(100e-9)}
     assert red['fluid_scope_s']['mul_3'] == pytest.approx(
         red['fluid_op_s']['mul'])
     # idle 640 ns: 100 before the first op under the lowering span and
@@ -85,6 +87,45 @@ def test_reduce_busy_idle_scopes_and_gap_attribution():
     assert set(out) == {'device_ops', 'idle_gaps'}
     assert out['device_ops'][0][0] == 'mul'
     assert len(out['device_ops']) <= 10 and len(out['idle_gaps']) <= 10
+
+
+def test_reduce_tells_the_kernels_of_two_op_types_apart():
+    """Mosaic events under scopes of two op types, and one outside any
+    scope: each under its own key, their union under kernel_s; an event
+    that is no kernel is in neither."""
+    instr = {'custom.1': 'jit(step)/jvp(flash_attention_4)/pallas_call',
+             'custom.2': 'jit(step)/transpose(jvp(flash_attention_4))/'
+                         'pallas_call',
+             'custom.3': 'jit(step)/moe_mlp_9/pallas_call',
+             'custom.4': 'jit(step)/pallas_call',
+             'fusion.5': 'jit(step)/moe_mlp_9/dot_general'}
+    events = [(0, 100, 'custom.1', True), (100, 250, 'custom.2', True),
+              (300, 340, 'custom.3', True), (400, 407, 'custom.4', True),
+              (500, 900, 'fusion.5', False)]
+    red = trace.reduce(_raw(events), instr, steps=1)
+    assert red['kernel_by_op_s'] == {
+        'flash_attention': pytest.approx(250e-9),
+        'moe_mlp': pytest.approx(40e-9),
+        'unattributed': pytest.approx(7e-9)}
+    assert red['kernel_s'] == pytest.approx(297e-9)
+    assert red['fluid_op_s']['moe_mlp'] == pytest.approx(440e-9)
+    # the readers of one kernel take its entry and its cost, and return
+    # nothing for a kernel the trace or the FLOP file does not have
+    from chipbench.harness import catalog, kernels, peaks
+    v5e = peaks.PEAKS['TPU v5 lite']
+    reading = {'trace': red, 'peaks': v5e, 'kernel_cost': {
+        'flash_attention': (197e12 * 50e-9, 1.0),      # flops-bound, 50 ns
+        'moe_mlp': (1.0, 819e9 * 10e-9)}}              # bytes-bound, 10 ns
+    assert catalog.load_reader('flash_ms')(reading) == pytest.approx(250e-6)
+    assert catalog.load_reader('flash_roofline')(reading) \
+        == pytest.approx(20.0)
+    assert kernels.ms(reading, 'moe_mlp') == pytest.approx(40e-6)
+    assert kernels.roofline_pct(reading, 'moe_mlp') == pytest.approx(25.0)
+    assert kernels.ms(reading, 'sparse_adam') is None
+    assert kernels.roofline_pct(reading, 'sparse_adam') is None
+    assert kernels.roofline_pct(dict(reading, kernel_cost=None),
+                                'moe_mlp') is None
+    assert kernels.ms(dict(reading, trace=None), 'moe_mlp') is None
 
 
 def test_reduce_exposed_collective_time_half_under_compute():
@@ -151,6 +192,24 @@ def _recorded(name):
     raw = trace.read_xplane(os.path.join(TESTDATA, name + '.xplane.pb'))
     with open(os.path.join(TESTDATA, name + '_instr.json')) as f:
         return raw, json.load(f)
+
+
+# flash_ms over the recorded traces as the reader of PR 24 returned it
+# from the one kernel_s, computed once on that commit
+PARENT_FLASH_MS = {'toy_tfm': 0.1401352, 'toy_dp4': 0.035394}
+
+
+@pytest.mark.parametrize('name', sorted(PARENT_FLASH_MS))
+def test_kernels_of_the_recorded_traces_all_lie_in_flash_scopes(name):
+    from chipbench.harness import catalog
+    raw, instr = _recorded(name)
+    red = trace.reduce(raw, instr, steps=5)
+    by_op = red['kernel_by_op_s']
+    assert list(by_op) == ['flash_attention']
+    # to the nanosecond
+    assert round(sum(by_op.values()) * 1e9) == round(red['kernel_s'] * 1e9)
+    assert catalog.load_reader('flash_ms')({'trace': red}) \
+        == PARENT_FLASH_MS[name]
 
 
 def test_reduction_of_the_trace_recorded_on_four_chips():
@@ -223,7 +282,8 @@ def test_reduction_of_the_trace_recorded_on_one_chip():
     # the per-layer readers on this reduction
     from chipbench.harness import catalog, peaks
     reading = {'trace': red, 'chips': 1, 'peaks': peaks.PEAKS['TPU v5 lite'],
-               'kernel_cost': (3 * 4 * 8 * 256 * 256 * 128 * 2.5, 1e6),
+               'kernel_cost': {'flash_attention': (
+                   3 * 4 * 8 * 256 * 256 * 128 * 2.5, 1e6)},
                'step_flops': 1e10,
                'window': {'step_s': [0.0051, 0.0049, 0.0050, 0.0080]}}
     assert catalog.load_reader('flash_ms')(reading) == pytest.approx(
