@@ -3,9 +3,9 @@
 Which ops lower to collectives is statically knowable from the lowering
 rules plus the program's annotations: a `lookup_table` with
 `is_distributed` and a `dist_axis` the mesh declares takes the
-all_to_all wire (ops_impl/embedding_ops.dist_lookup_applies), `moe_mlp`
-rides two all_to_alls when the dp axis divides num_experts
-(ops_impl/moe_ops), `flash_attention` ppermutes K/V around the ring
+all_to_all wire (ops_impl/embedding_ops.dist_lookup_applies), a
+fixed-capacity `moe_mlp` rides two all_to_alls when the dp axis divides
+num_experts and a dropless one moves nothing (ops_impl/moe_ops), `flash_attention` ppermutes K/V around the ring
 when an 'sp' axis exists (ops_impl/nn_ops), and `autodiff` under a mesh
 with a data axis implies the GSPMD gradient all-reduce. This pass
 derives each block's collective sequence from exactly those conditions
@@ -78,6 +78,10 @@ def op_collectives(op, program, axes):
         try:
             n_exp = int(op.attrs.get('num_experts', 0))
         except (TypeError, ValueError):
+            return []
+        if op.attrs.get('dropless'):
+            # one device only: nothing over the wire (on a mesh that
+            # would shard the experts the rule refuses to lower)
             return []
         if 'dp' in axes and n_exp and n_exp % axes['dp'] == 0:
             # dispatch + combine

@@ -2,6 +2,8 @@
 (all 76 public functions + relu/log). Each appends op symbols lowered by
 ops_impl/ into the single fused XLA step.
 """
+import copy
+
 import numpy as np
 
 from ..layer_helper import LayerHelper
@@ -28,7 +30,8 @@ __all__ = [
     'label_smooth', 'roi_pool', 'dice_loss', 'image_resize',
     'image_resize_short', 'resize_bilinear', 'gather', 'scatter', 'expand',
     'random_crop', 'mean_iou', 'relu', 'log', 'crop', 'rank_loss', 'prelu',
-    'flatten', 'sequence_mask', 'stack', 'fused_attention',
+    'flatten', 'sequence_mask', 'stack', 'fused_attention', 'rms_norm',
+    'rotary_embedding',
 ]
 
 
@@ -652,6 +655,39 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
                  "Variance": [variance_out]},
         attrs={"epsilon": epsilon, "begin_norm_axis": begin_norm_axis})
     return helper.append_activation(layer_norm_out)
+
+
+def rms_norm(input, epsilon=1e-05, param_attr=None, name=None):
+    """RMS norm over the last axis: ``scale * x * rsqrt(mean(x^2) +
+    epsilon)`` with a learned `scale` of the last axis' width (initialised
+    to 1), no mean subtraction and no shift. One Program op; statistics in
+    float32 under AMP too. TPU extension (the reference predates it)."""
+    helper = LayerHelper('rms_norm', **locals())
+    dtype = helper.input_dtype()
+    scale = helper.create_parameter(attr=helper.param_attr,
+                                    shape=[int(input.shape[-1])],
+                                    dtype=dtype,
+                                    default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(dtype)
+    helper.append_op(type='rms_norm', inputs={'X': [input],
+                                              'Scale': [scale]},
+                     outputs={'Y': [out]}, attrs={'epsilon': float(epsilon)})
+    return out
+
+
+def rotary_embedding(x, base=10000.0, name=None):
+    """Rotary position embedding of heads ``x`` [B, H, T, D] at positions
+    0..T-1: element i of a head is rotated with element i + D/2 (the
+    rotate-half pairing) by the angle ``t * base**(-2i/D)``. No parameter.
+    One Program op. TPU extension (the reference predates it)."""
+    if int(x.shape[-1]) % 2:
+        raise ValueError('rotary_embedding needs an even head width, got %r'
+                         % (x.shape[-1],))
+    helper = LayerHelper('rotary_embedding', **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type='rotary_embedding', inputs={'X': [x]},
+                     outputs={'Out': [out]}, attrs={'base': float(base)})
+    return out
 
 
 def conv2d_transpose(input, num_filters, output_size=None, filter_size=None,
@@ -1398,30 +1434,45 @@ def beam_search_decode(ids, scores, beam_size=None, end_id=0, parents=None,
 
 def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
             capacity_factor=2.0, gate_param_attr=None, param_attr=None,
-            bias_attr=None, name=None, top_k=1, return_aux_loss=False):
+            bias_attr=None, name=None, top_k=1, return_aux_loss=False,
+            gated=False, norm_topk_prob=True, return_expert_count=False):
     """Top-k gated mixture-of-experts FFN (TPU extension; the reference
     predates MoE — its conditional-computation ancestor is layers.Switch).
 
     Each of `num_experts` experts is a two-layer MLP
-    ``act(x @ w1 + b1) @ w2 + b2`` with hidden width `hidden_size`; tokens
-    are routed top-k by a learned linear gate with fixed capacity
-    (capacity_factor * top_k * tokens / experts; overflow dropped, all
-    first choices claiming slots before any second choice). top_k=1 uses
-    Switch-style raw-probability gates; top_k>=2 renormalizes the selected
-    gates per token (GShard). Under ParallelExecutor or a
+    ``act(x @ w1 + b1) @ w2 + b2`` with hidden width `hidden_size`, or with
+    ``gated=True`` the three-matrix form ``(act(x @ w1) * (x @ w3)) @ w2``
+    (SwiGLU with ``act='swish'``); ``bias_attr=False`` gives experts
+    without biases. Tokens are routed top-k by a learned linear gate whose
+    logits and softmax stay float32 under AMP. top_k=1 uses Switch-style
+    raw-probability gates; top_k>=2 renormalizes the selected gates per
+    token (GShard) unless ``norm_topk_prob=False`` (OLMoE: raw
+    probabilities).
+
+    `capacity_factor` a number: fixed capacity (capacity_factor * top_k *
+    tokens / experts slots an expert; overflow DROPPED, all first choices
+    claiming slots before any second choice). Under ParallelExecutor or a
     DistributeTranspiler mesh whose dp size divides num_experts, experts
     are sharded num_experts/dp-per-device and dispatch rides two
     all_to_alls (paddle_tpu.parallel.moe); otherwise experts run locally
     with identical semantics.
 
+    ``capacity_factor=None``: dropless. Every assignment is computed,
+    however uneven the router: the tokens x top_k assignments are sorted
+    by expert and run as grouped matmuls. One device only; on a mesh that
+    would shard the experts the step raises
+    ``paddle_tpu.parallel.moe.DroplessOnMeshError``.
+
     With return_aux_loss=True, also returns the scalar Switch/GShard
     load-balancing auxiliary loss (E * sum_e f_e * P_e, minimized at 1.0
     by a uniform router) to add to the training objective with a small
-    weight, e.g. ``cost = cost + 0.01 * aux``.
+    weight, e.g. ``cost = cost + 0.01 * aux``. With
+    return_expert_count=True, also the step's assignments per expert
+    ([num_experts] int32, summing to tokens x top_k where nothing drops).
 
     input: [N, d] tokens or [B, T, d] sequence activations.
-    Returns the same shape with the last dim `size` (default d), or
-    (out, aux_loss) when return_aux_loss=True.
+    Returns the same shape with the last dim `size` (default d); with the
+    return_* flags a tuple (out[, aux_loss][, expert_count]).
     """
     from ..ops_impl.moe_ops import supported_acts
     if (act or None) is not None and act not in supported_acts():
@@ -1439,29 +1490,46 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
     gate_w = helper.create_parameter(attr=ParamAttr.to_attr(gate_param_attr),
                                      shape=[d, num_experts], dtype=dtype,
                                      is_bias=False)
-    w1 = helper.create_parameter(attr=ParamAttr.to_attr(param_attr),
-                                 shape=[num_experts, d, hidden_size],
-                                 dtype=dtype, is_bias=False)
-    b1 = helper.create_parameter(attr=ParamAttr.to_attr(bias_attr),
-                                 shape=[num_experts, hidden_size],
-                                 dtype=dtype, is_bias=True)
-    w2 = helper.create_parameter(attr=ParamAttr.to_attr(param_attr),
-                                 shape=[num_experts, hidden_size, out_d],
-                                 dtype=dtype, is_bias=False)
-    b2 = helper.create_parameter(attr=ParamAttr.to_attr(bias_attr),
-                                 shape=[num_experts, out_d], dtype=dtype,
-                                 is_bias=True)
-    out = helper.create_variable_for_type_inference(dtype)
-    aux = helper.create_variable_for_type_inference('float32')
+
+    def weight(shape):
+        # a copy a weight: one ParamAttr object names one parameter
+        return helper.create_parameter(
+            attr=copy.deepcopy(ParamAttr.to_attr(param_attr)), shape=shape,
+            dtype=dtype, is_bias=False)
+
+    inputs = {'X': [input], 'GateW': [gate_w],
+              'W1': [weight([num_experts, d, hidden_size])]}
+    if gated:
+        inputs['W3'] = [weight([num_experts, d, hidden_size])]
+    inputs['W2'] = [weight([num_experts, hidden_size, out_d])]
+    if bias_attr is not False:
+        if gated:
+            raise ValueError('moe_mlp: gated experts have no biases; pass '
+                             'bias_attr=False')
+        for slot, width in (('B1', hidden_size), ('B2', out_d)):
+            inputs[slot] = [helper.create_parameter(
+                attr=copy.deepcopy(ParamAttr.to_attr(bias_attr)),
+                shape=[num_experts, width], dtype=dtype, is_bias=True)]
+    # shapes declared here: inference stands the dynamic batch in by a
+    # large prime, and at real widths batch x seq x top_k assignments pass
+    # what the dropless path's int32 sort indices hold
+    out = helper.create_variable_for_type_inference(
+        dtype, shape=list(input.shape[:-1]) + [out_d])
+    aux = helper.create_variable_for_type_inference('float32', shape=[])
+    outputs = {'Out': [out], 'AuxLoss': [aux]}
+    count = None
+    if return_expert_count:
+        count = helper.create_variable_for_type_inference(
+            'int32', shape=[int(num_experts)], stop_gradient=True)
+        outputs['ExpertCount'] = [count]
     helper.append_op(
-        type='moe_mlp',
-        inputs={'X': [input], 'GateW': [gate_w], 'W1': [w1], 'B1': [b1],
-                'W2': [w2], 'B2': [b2]},
-        outputs={'Out': [out], 'AuxLoss': [aux]},
+        type='moe_mlp', inputs=inputs, outputs=outputs,
         attrs={'num_experts': int(num_experts),
-               'capacity_factor': float(capacity_factor),
+               'dropless': capacity_factor is None,
+               'capacity_factor': float(capacity_factor or 0.0),
                'top_k': int(top_k),
+               'norm_topk_prob': bool(norm_topk_prob),
                'act': act or ''})
-    if return_aux_loss:
-        return out, aux
-    return out
+    got = (out,) + ((aux,) if return_aux_loss else ()) \
+        + ((count,) if return_expert_count else ())
+    return got if len(got) > 1 else out

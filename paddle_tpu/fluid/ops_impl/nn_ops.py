@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ... import obs
 from ..lowering import register, data_of, like, amp_cast
 
 
@@ -235,6 +236,47 @@ def _layer_norm(ins, attrs, ctx):
     return {'Y': like(ins['X'][0], y.astype(x.dtype)),
             'Mean': mean.reshape(x.shape[:axis]),
             'Variance': var.reshape(x.shape[:axis])}
+
+
+@register('rms_norm')
+def _rms_norm(ins, attrs, ctx):
+    """y = scale * x * rsqrt(mean(x^2) + epsilon) over the last axis (Zhang
+    and Sennrich 2019; no reference counterpart). The statistics are taken
+    in float32 whatever the input's dtype, under AMP too; the result has
+    the input's dtype."""
+    x = data_of(ins['X'][0])
+    obs.counter('rms_norm.lowered').inc()          # trace time
+    xf = x.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                    + attrs.get('epsilon', 1e-5))
+    y = xf * inv * data_of(ins['Scale'][0]).astype(jnp.float32)
+    return {'Y': like(ins['X'][0], y.astype(x.dtype))}
+
+
+@register('rotary_embedding')
+def _rotary_embedding(ins, attrs, ctx):
+    """Rotary position embedding (Su et al. 2021) of X [B, H, T, D] at
+    positions 0..T-1, rotate-half pairing: element i of a head turns with
+    element i + D/2 by the angle t * base^(-2i/D). The tables of sines and
+    cosines are constants of the step, computed on the host in float64 and
+    rounded once: at position 4095 one float32 rounding of a frequency
+    turns the angle by 2e-4 rad, which moved OLMoE's attention output by
+    1e-4 between two float32 programs on the chip (PR 26) and with it a
+    few tokens' choice of experts. The product is float32; the result has
+    the input's dtype."""
+    x = data_of(ins['X'][0])
+    obs.counter('rotary.lowered').inc()            # trace time
+    t, d = x.shape[-2], x.shape[-1]
+    half = d // 2
+    inv_freq = float(attrs.get('base', 10000.0)) ** (
+        -np.arange(half, dtype=np.float64) * 2.0 / d)
+    angle = np.arange(t, dtype=np.float64)[:, None] * inv_freq[None, :]
+    cos = jnp.asarray(np.cos(angle), jnp.float32)  # [T, D/2]
+    sin = jnp.asarray(np.sin(angle), jnp.float32)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    y = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return {'Out': y.astype(x.dtype)}
 
 
 @register('dropout')

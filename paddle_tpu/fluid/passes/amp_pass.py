@@ -36,14 +36,17 @@ __all__ = ['run', 'AMP_SLOTS']
 _C_CASTS = obs.counter('passes.amp.casts_inserted')
 _C_REWRITTEN = obs.counter('passes.amp.ops_rewritten')
 
-# op type -> input slots runtime amp_cast covers (None = every slot, the
-# moe rule casts its whole param bundle)
+# op type -> input slots runtime amp_cast covers. The moe rule casts its
+# experts' weights and, inside the rule, the rows it gives them; X stays as
+# it comes because the router reads it in float32, and GateW is the
+# router's. rms_norm and rotary_embedding are not here: their statistics
+# and angles are float32 whatever they are fed.
 AMP_SLOTS = {
     'mul': ('X', 'Y'),
     'matmul': ('X', 'Y'),
     'conv2d': ('Input', 'Filter'),
     'flash_attention': ('Q', 'K', 'V'),
-    'moe_mlp': None,
+    'moe_mlp': ('W1', 'B1', 'W2', 'B2', 'W3'),
 }
 
 
@@ -94,8 +97,7 @@ def run(program, report):
                 if s is None:
                     specs_ok = False
                 row.append(s)
-                if (v.dtype == 'float32'
-                        and (slots is None or slot in slots)):
+                if v.dtype == 'float32' and slot in slots:
                     targets.append((slot, j, v))
             in_specs[slot] = row
         if not targets:

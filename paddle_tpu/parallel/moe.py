@@ -14,6 +14,14 @@ before any second choice), sent to their expert with ONE all_to_all,
 transformed, and returned with a second all_to_all; dropped tokens pass
 through gate-weighted as zeros.
 
+Which path drops. Everything in this module is the FIXED-CAPACITY form:
+`pack_topk` gives each expert `capacity` slots and DROPS the assignments
+that overflow them, on one device and under `moe_apply` alike. The
+dropless form (every assignment is computed, whatever the router's
+imbalance) exists on one device only, as the `moe_mlp` rule's grouped path
+(fluid/ops_impl/moe_ops.py); under a mesh it is refused with
+`DroplessOnMeshError` until `moe_apply` can exchange ragged groups.
+
 `load_balancing_loss` is the Switch/GShard auxiliary objective
 E * sum_e f_e * P_e — differentiable through P_e, minimized at 1.0 by a
 uniform router — to be added to the model loss with a small weight.
@@ -27,23 +35,31 @@ from ._sp import stack_unit_params
 
 __all__ = ['moe_apply', 'stack_expert_params', 'router_topk', 'pack_topk',
            'combine_topk', 'pack_top1', 'combine_top1',
-           'load_balancing_loss']
+           'load_balancing_loss', 'DroplessOnMeshError']
+
+
+class DroplessOnMeshError(NotImplementedError):
+    """A dropless expert layer (`capacity_factor=None`) was lowered against
+    a mesh that would shard its experts. `moe_apply` exchanges fixed
+    `[experts, capacity, d]` buffers and would have to drop; it is refused
+    instead of dropping silently."""
 
 # [{param pytree} per expert] -> pytree with leading [n_experts, ...] axis
 stack_expert_params = stack_unit_params
 
 
-def router_topk(logits, top_k):
-    """Routing decisions shared by the dense and sharded paths.
+def router_topk(logits, top_k, norm_topk_prob=True):
+    """Routing decisions shared by every path.
 
     Returns (expert [k, nt] int, gate [k, nt] f32). k=1 keeps the Switch
     semantics (gate = raw softmax probability of the chosen expert); k>1
-    renormalizes the selected probabilities to sum to 1 per token (GShard).
+    renormalizes the selected probabilities to sum to 1 per token (GShard)
+    unless `norm_topk_prob` is false (OLMoE: the raw probabilities).
     """
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)  # [nt, E]
     _, idx = lax.top_k(logits, top_k)                            # [nt, k]
     gate = jnp.take_along_axis(probs, idx, axis=-1)              # [nt, k]
-    if top_k > 1:
+    if top_k > 1 and norm_topk_prob:
         gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
     return idx.T, gate.T
 
@@ -63,10 +79,11 @@ def load_balancing_loss(logits, top_k=1):
     return n_exp * jnp.sum(f * p)
 
 
-def pack_topk(xs, logits, n_exp, cap, top_k=1):
+def pack_topk(xs, logits, n_exp, cap, top_k=1, norm_topk_prob=True):
     """Top-k routing + fixed-capacity packing (shared by the sharded
     all_to_all path below and ops_impl/moe_ops.py's dense fallback, so the
-    two stay numerically identical).
+    two stay numerically identical). Assignments beyond an expert's `cap`
+    slots are DROPPED.
 
     Capacity slots are claimed in choice-major order — every token's first
     choice before any token's second choice (GShard priority), then token
@@ -75,7 +92,7 @@ def pack_topk(xs, logits, n_exp, cap, top_k=1):
     Returns (send [n_exp, cap, d], route) where route carries the
     (expert, slot, keep, gate) [k, nt] arrays needed to combine."""
     nt, d = xs.shape
-    expert, gate = router_topk(logits, top_k)                # [k, nt]
+    expert, gate = router_topk(logits, top_k, norm_topk_prob)   # [k, nt]
     onehot = jax.nn.one_hot(expert.reshape(-1), n_exp,
                             dtype=jnp.int32)                 # [k*nt, E]
     pos = jnp.cumsum(onehot, axis=0) * onehot                # 1-based
@@ -130,8 +147,9 @@ def _n_experts_of(stacked, mesh, axis):
 
 
 def moe_apply(expert_fn, stacked_params, x, gate_logits, mesh, axis='ep',
-              capacity_factor=2.0, top_k=1):
-    """Dispatch tokens to experts and combine.
+              capacity_factor=2.0, top_k=1, norm_topk_prob=True):
+    """Dispatch tokens to experts and combine (fixed capacity: overflow is
+    dropped).
 
     expert_fn(params, x) -> y        applied per expert on [cap, d]
     stacked_params: leaves [n_experts, ...], sharded over `axis`
@@ -158,7 +176,8 @@ def moe_apply(expert_fn, stacked_params, x, gate_logits, mesh, axis='ep',
         cap = int(max(1, capacity_factor * top_k * nt / n_exp))
 
         # pack: [E, cap, d] send buffer (local tokens destined per expert)
-        send, route = pack_topk(xs, logits, n_exp, cap, top_k)
+        send, route = pack_topk(xs, logits, n_exp, cap, top_k,
+                                norm_topk_prob)
 
         # exchange: device j receives every shard's buffers for its block
         # of experts [j*epd, (j+1)*epd)

@@ -1,4 +1,5 @@
-"""The flash kernels at their default tiles, compiled by Mosaic for a
+"""The flash kernels at their default tiles, and the grouped-matmul
+kernels at theirs, compiled by Mosaic for a
 DESCRIBED v5e (no chip, nothing runs): what the interpreter and
 jax.export cannot refuse — VMEM the kernel may not have, slices Mosaic
 will not tile — is refused here. The cells' shapes, and the shapes on
@@ -50,3 +51,28 @@ def test_default_tiles_compile_for_v5e(one_chip, dtype, shape, causal):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x, kb).compile()
     assert compiled.as_text().count('tpu_custom_call') == 3
+
+
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+@pytest.mark.parametrize('rows', [32768, 65536], ids=['b1', 'b2'])
+def test_grouped_matmul_tiles_compile_for_v5e(one_chip, dtype, rows):
+    """olmoe_s4096's expert matmuls (rows = batch x 4096 x 8 assignments,
+    64 experts of 2048 x 1024 and back), forward and both gradients, in the
+    cell's bf16 and in its float32 check's arithmetic: megablox's tgmm
+    runs out of VMEM at the tile its gmm takes (chip, PR 26), so each call
+    has its own (ops/kernels/grouped_matmul.py TILES)."""
+    from paddle_tpu.ops.kernels.grouped_matmul import grouped_matmul
+    dt = jnp.dtype(dtype)
+    x = jax.ShapeDtypeStruct((rows, 2048), dt, sharding=one_chip)
+    up = jax.ShapeDtypeStruct((64, 2048, 1024), dt, sharding=one_chip)
+    down = jax.ShapeDtypeStruct((64, 1024, 2048), dt, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)
+
+    def loss(x, up, down, sizes):
+        h = grouped_matmul(x, up, sizes, False)
+        return jnp.sum(grouped_matmul(h, down, sizes, False)
+                       .astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, up, down, sizes).compile()
+    assert compiled.as_text().count('tpu_custom_call') == 6

@@ -609,6 +609,9 @@ _SWEEP = {
     'recommender_system': dict(
         kwargs=dict(batch_size=4, emb_dim=8, tower_dim=16),
         reader_idx=3, feeds_idx=5),
+    'olmoe': dict(
+        kwargs=dict(batch_size=2, seq_len=16, vocab_size=64, hidden=32,
+                    n_expert=4, expert_width=16), feeds_idx=4, stack=True),
 }
 
 

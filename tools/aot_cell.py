@@ -1,0 +1,145 @@
+"""Compiles a benchmark cell's training step for a described TPU v5e, with
+no chip, and prints what the compiler says of it: PERF.md's batch rule
+(section 4) reads `memory_analysis()` here.
+
+    JAX_PLATFORMS=cpu python tools/aot_cell.py --workload olmoe_s4096
+        [--batch N] [--hlo FILE] [--check NAME]
+
+Builds the cell's Program at its published widths, runs the start-up
+program on the host (the step's arguments need shapes, not values), lowers
+the Executor's own jitted step with the TPU's lowering rules (flash and
+grouped-matmul kernels, not their host fallbacks) for device 0 of a
+described `v5e:2x2`, and compiles. With `--check NAME` it compiles that
+entry of the configuration's `checks` instead: the check Program in its
+own arithmetic and the plain reference on the check's sample, the two
+programs that share the chip with the scope before the window. One chip
+only: a mesh cell builds its mesh from jax.devices(). A compile that
+passes is not a chip run.
+"""
+import argparse
+import json
+import os
+import sys
+
+os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+
+def main(argv=None):
+    p = argparse.ArgumentParser(description=__doc__.split('\n\n')[0])
+    p.add_argument('--workload', required=True)
+    p.add_argument('--batch', type=int)
+    p.add_argument('--hlo', help='write the optimized HLO text here')
+    p.add_argument('--check', help="compile this entry of `checks` instead")
+    args = p.parse_args(argv)
+
+    import contextlib
+    import jax
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import executor
+    from chipbench.harness import catalog
+
+    cell = catalog.load_cell(args.workload)
+    config, traffic = cell['config'], dict(cell['traffic'])
+    if args.batch:
+        traffic['batch'] = args.batch
+    precision = None
+    if args.check:
+        # as harness/check.py run_check builds it
+        entry = config['checks'][args.check]
+        config = dict(config, check=entry,
+                      amp=entry.get('amp', config['amp']))
+        traffic['batch'] = args.batch or entry['sample']
+        precision = entry.get('matmul_precision')
+    pool, _ = cell['generator'].make_pool(dict(traffic, pool=1), config, 1)
+    built = cell['builder'].build(config, traffic, train=not args.check)
+    exe = fluid.Executor(fluid.CPUPlace())
+    # the start-up program of the TRAINING Program: the check runs in a
+    # scope that holds the optimizer's state too
+    exe.run(cell['builder'].build(cell['config'], traffic)['startup'])
+
+    # the step as the TPU's place would lower it
+    step_class = executor._CompiledStep
+    executor._CompiledStep = lambda *a, **k: step_class(
+        *a, **dict(k, platform='tpu'))
+    try:
+        compiled, feed_vals, persist = exe._prepare(
+            built['main'], pool[0],
+            [built['loss']] + [built['grads'][n]
+                               for n in sorted(built['grads'])],
+            fluid.global_scope())
+    finally:
+        executor._CompiledStep = step_class
+    topo = topologies.get_topology_desc(platform='tpu',
+                                        topology_name='v5e:2x2')
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+
+    donated, readonly = compiled.plan.split(persist)
+    shapes = jax.tree_util.tree_map(spec, (donated, readonly, feed_vals))
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        done = compiled._jitted.lower(
+            *shapes, spec(jax.random.key(0))).compile()
+    text = done.as_text()
+    if args.hlo:
+        with open(args.hlo, 'w') as f:
+            f.write(text)
+
+    def report(what, done, **more):
+        mem = done.memory_analysis()
+        sizes = {k: getattr(mem, k) for k in (
+            'argument_size_in_bytes', 'output_size_in_bytes',
+            'alias_size_in_bytes', 'temp_size_in_bytes',
+            'generated_code_size_in_bytes')}
+        total = (sizes['argument_size_in_bytes']
+                 + sizes['output_size_in_bytes']
+                 - sizes['alias_size_in_bytes']
+                 + sizes['temp_size_in_bytes'])
+        print(json.dumps({
+            'workload': args.workload, 'program': what,
+            'batch': traffic['batch'], 'seq': traffic.get('seq'), **sizes,
+            'step_bytes': total, 'step_gib': total / 2.0 ** 30,
+            'compiled_for': str(topo.devices[0].device_kind), **more}),
+            flush=True)
+
+    report(args.check or 'train_step', done,
+           mosaic_calls=text.count('tpu_custom_call'))
+    if not args.check:
+        return 0
+
+    # the plain reference on the same sample, its parameters as arguments
+    # (a reference with forward_loss(params, model, *feeds), as the causal
+    # language models' have)
+    from chipbench.harness import check as check_mod
+    scope = fluid.global_scope()
+    params, tree = cell['builder'].reference_params(
+        config, built['main'],
+        lambda name: np.asarray(scope.find_var(name).get_tensor()))
+    paths = check_mod.grad_paths(tree, set(built['grads']))
+    wanted = sorted({path for path, _ in paths.values()})
+    reference = cell['reference']
+    with jax.default_matmul_precision('highest'):
+        ref = jax.jit(lambda p, ids, labels: jax.value_and_grad(
+            lambda w: reference.forward_loss({**p, **w}, config['model'],
+                                             ids, labels))(
+            {k: p[k] for k in wanted})).lower(
+            jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, np.float32,
+                                               sharding=chip), params),
+            *(jax.ShapeDtypeStruct(pool[0][k].shape, np.int32,
+                                   sharding=chip)
+              for k in built['feeds'])).compile()
+    report('reference', ref)
+    return 0
+
+
+if __name__ == '__main__':
+    sys.exit(main())
