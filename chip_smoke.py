@@ -127,8 +127,9 @@ def train_leg(place, cfg=BASE, steps=20, expect_kernel=True):
     """Leg 1: build, compile, step on one repeated seeded host batch.
     Loss finite every step and lower at the end than at the start; with
     expect_kernel the step's HLO must carry Mosaic custom calls for the
-    flash forward and both backward kernels of every attention op, and
-    the AMP step must have lowered them with bf16 operands."""
+    flash forward and the one or two backward kernels of every attention
+    op, the AMP step must have lowered them with bf16 operands, and some
+    backward must run in one pass (at Transformer-base's lengths all do)."""
     import paddle_tpu.fluid as fluid
     from paddle_tpu import obs
     main, startup, avg_cost, feeds = _build(cfg, dropout=0.1)
@@ -137,18 +138,28 @@ def train_leg(place, cfg=BASE, steps=20, expect_kernel=True):
     feed = _batch(cfg, feeds)
 
     def lowered():
-        return {d: obs.counter('flash.lowered', operands=d).value
-                for d in ('bfloat16', 'float32')}
+        return ({d: obs.counter('flash.lowered', operands=d).value
+                 for d in ('bfloat16', 'float32')},
+                {p: obs.counter('flash.backward', passes=p).value
+                 for p in ('one', 'two')})
 
     before = lowered()
     losses, first_s, later_s = _run_steps(exe, main, feed, avg_cost, steps)
-    flash_lowered = {d: int(n - before[d]) for d, n in lowered().items()}
+    flash_lowered, flash_backward = (
+        {key: int(n - was[key]) for key, n in now.items()}
+        for was, now in zip(before, lowered()))
     if not losses[-1] < losses[0]:
         raise AssertionError('loss did not fall: %r' % (losses,))
     hlo = exe.lowered_hlo(main, feed, [avg_cost])
     n_calls = hlo.count('tpu_custom_call')
     if expect_kernel:
-        want = 9 * cfg['n_layer']     # 3 attention ops/layer pair x 3 calls
+        # 3 attention ops a layer pair, each a forward and a backward of
+        # one call or two
+        want = 2 * flash_backward['one'] + 3 * flash_backward['two']
+        if not flash_backward['one']:
+            raise AssertionError(
+                'no attention op took the one-pass backward: flash.backward '
+                'counted %r' % (flash_backward,))
         if n_calls < want:
             raise AssertionError(
                 'step HLO has %d tpu_custom_call(s), expected >= %d: the '
@@ -162,12 +173,14 @@ def train_leg(place, cfg=BASE, steps=20, expect_kernel=True):
     n_params = _params(main)
     log('train: params %.1fM, batch %dx%d, first step %.1fs, then %.3fs/step'
         ', loss %.4f -> %.4f, tpu_custom_call x%d, flash.lowered %r'
+        ', flash.backward %r'
         % (n_params / 1e6, cfg['batch'], cfg['seq'], first_s, later_s,
-           losses[0], losses[-1], n_calls, flash_lowered))
+           losses[0], losses[-1], n_calls, flash_lowered, flash_backward))
     return {'params': n_params, 'steps': steps,
             'first_step_seconds': round(first_s, 2),
             'first_loss': losses[0], 'last_loss': losses[-1],
             'tpu_custom_calls': n_calls, 'flash_lowered': flash_lowered,
+            'flash_backward': flash_backward,
             'online_compiles': stats['online_compiles'],
             'persistent_hits': stats['persistent_hits'],
             'cache_dir': stats['compile_cache_dir']}
@@ -201,14 +214,16 @@ def _rel_err(got, want):
     return float(np.max(np.abs(got - want)) / (np.max(np.abs(want)) + 1e-12))
 
 
-def _check_flash(causal):
-    """Flash forward + gradients at (8, 8, 1024, 64) bf16 with a pad bias,
-    against reference_attention."""
+def _check_flash(causal, shape=(8, 8, 1024, 64)):
+    """Flash forward + gradients at (8, 8, 1024, 64) bf16 with a pad bias
+    (one tile a head: the one-pass backward) or at (2, 8, 2048, 64) (four
+    tiles, or the triangle's three: the two backward kernels), against
+    reference_attention."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu import ops
     r = np.random.RandomState(1)
-    B, H, T, D = 8, 8, 1024, 64
+    B, H, T, D = shape
     q, k, v, w = [jnp.asarray(r.randn(B, H, T, D), jnp.bfloat16)
                   for _ in range(4)]
     kb = np.zeros((B, T), np.float32)
@@ -326,6 +341,9 @@ def _check_grouped_matmul():
 KERNEL_CHECKS = {
     'flash_attention': lambda: _check_flash(False),
     'flash_attention_causal': lambda: _check_flash(True),
+    'flash_attention_2048': lambda: _check_flash(False, (2, 8, 2048, 64)),
+    'flash_attention_causal_2048': lambda: _check_flash(True,
+                                                        (2, 8, 2048, 64)),
     'paged_attention': _check_paged_attention,
     'sparse_adagrad': _check_sparse_adagrad,
     'sparse_adam': _check_sparse_adam,

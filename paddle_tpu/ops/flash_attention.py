@@ -11,6 +11,15 @@ length is bounded by HBM, not VMEM. The forward keeps a running
 probabilities from the saved logsumexp. HBM traffic drops from O(T^2) to
 O(T*D).
 
+The backward is one algorithm with two schedules. Where a head's whole
+score matrix is one tile (every call at T <= 1024 with rows of up to 512
+bytes, causal or not) nothing has to be accumulated across grid steps, and
+ONE kernel on the grid (B, H) gives dq, dk and dv from one s, p, dp and ds
+(_bwd_fused_kernel). Longer sequences, and tiles a caller forces below the
+length, take two kernels, dq over a q-row's k-blocks and dk/dv over a
+k-column's q-blocks, each recomputing s, p, dp and ds for itself. _prep
+decides from the shapes and counts `flash.backward{passes=one|two}`.
+
 Supports an additive per-key bias [B, Tk] (padding mask; treated as a
 constant — stop_gradient'd by the op lowering) and causal masking —
 together these cover every mask the Transformer model builds
@@ -37,8 +46,9 @@ float32 reference does, as every matmul under AMP rounds its operands
 Row statistics never become 1-D vectors inside a body: they stay
 lane-broadcast [rows, LANES] tiles from scratch or HBM to the score tile
 (_lanes). On the v5e that, not the operand dtype, was what a forward
-block step waited for (PERF.md, PR 24). The counter
-`flash.lowered{operands=<dtype>}` counts attention calls per lowering.
+block step waited for (PERF.md, PR 24). The counters
+`flash.lowered{operands=<dtype>}` and `flash.backward{passes=one|two}`
+count attention calls per lowering.
 
 `interpret` is the CALLER's decision, never read off the process's
 default backend: the op lowering passes interpret=False on a TPU place
@@ -321,6 +331,58 @@ def _bwd_dkv_kernel_tri(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
                   block_q=block_q, block_k=block_k)
 
 
+def _add_rows(acc, x):
+    """acc + x for an x that holds acc's first rows only (a causal query
+    sub-tile sees no key after its last query)."""
+    if acc is None:
+        return x
+    n = x.shape[0]
+    if n == acc.shape[0]:
+        return acc + x
+    return jnp.concatenate([acc[:n] + x, acc[n:]], axis=0)
+
+
+def _bwd_fused_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, *, scale, causal, sub_q):
+    """The whole backward of one (batch, head) in one grid step: nothing
+    crosses a grid step, so s^T, p^T, dp^T and ds^T are computed once and
+    feed all three gradients (5 dots and one exp where the two kernels
+    spend 7 and two), every operand is read once, and the results leave
+    in one store each. The arithmetic is _bwd_dkv_body's, scores
+    transposed [keys, queries]. The one new dot is dq = ds k, taken as
+    (k^T ds^T)^T: that turns round two [rows, D] tiles where ds k would
+    turn round the score tile (7 % of the kernel at 1024 x 1024 on the
+    v5e; docs/perf.md, PR 27). The query axis is walked in static
+    sub-tiles of sub_q (the forward's tile), last to first; a causal
+    self-attention sub-tile takes only the keys up to its last query, so
+    the blocks above the diagonal cost nothing here either."""
+    bq, bk = q_ref.shape[2], k_ref.shape[2]
+    kb = jnp.broadcast_to(kb_ref[0], (LANES, bk)).T[:, :1]     # [bk, 1]
+    dk = dv = None
+    for q0 in reversed(range(0, bq, sub_q)):
+        nk = min(bk, q0 + sub_q) if causal and bq == bk else bk
+        rows = pl.ds(q0, sub_q)
+        k = k_ref[0, 0, :nk]                                   # [nk, D]
+        v = v_ref[0, 0, :nk]
+        qb = q_ref[0, 0, rows]                                 # [sub_q, D]
+        dob = do_ref[0, 0, rows]
+        lse_b = lse_ref[0, 0, rows].T[:1]                      # [1, sub_q]
+        delta_b = delta_ref[0, 0, rows].T[:1]
+        st = _dot(k, qb, _NT) * scale + kb[:nk]                # [nk, sub_q]
+        if causal:
+            st = _mask_causal(st, q0, 0, q_axis=1)
+        pt = jnp.exp(st - lse_b)
+        dv = _add_rows(dv, _dot(pt.astype(dob.dtype), dob, _NN))
+        dpt = _dot(v, dob, _NT)
+        dst = pt * (dpt - delta_b) * scale
+        dsc = dst.astype(qb.dtype)
+        dk = _add_rows(dk, _dot(dsc, qb, _NN))
+        kt = k.astype(jnp.float32).T.astype(k.dtype)           # [D, nk]
+        dq_ref[0, 0, rows] = _dot(kt, dsc, _NN).T.astype(dq_ref.dtype)
+    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
+    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+
+
 # ---------------------------------------------------------------------------
 # pallas_call plumbing
 # ---------------------------------------------------------------------------
@@ -444,12 +506,55 @@ def _bwd_call_tri(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret):
     return dq, dk, dv
 
 
-def _bwd_call(q, k, v, kb, do, lse, delta, causal, scale, bq, bk, interpret):
+def _bwd_call_fused(q, k, v, kb, do, lse, delta, causal, scale, sub_q,
+                    interpret):
+    """One pass over a head's whole score matrix: grid (B, H), one kernel
+    for dq, dk and dv (_bwd_fused_kernel)."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
+    qrow = pl.BlockSpec((1, 1, Tq, D), lambda b, h: (b, h, 0, 0))
+    kcol = pl.BlockSpec((1, 1, Tk, D), lambda b, h: (b, h, 0, 0))
+    kbias = pl.BlockSpec((1, 1, Tk), lambda b, h: (b, 0, 0))
+    stats = pl.BlockSpec((1, 1, Tq, LANES), lambda b, h: (b, h, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
+                          sub_q=sub_q),
+        grid=(B, H),
+        in_specs=[qrow, kcol, kcol, kbias, qrow, stats, stats],
+        out_specs=[qrow, kcol, kcol],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        interpret=interpret,
+    )(q, k, v, kb, do, lse, delta)
+
+
+def _bwd_call(q, k, v, kb, do, lse, delta, causal, scale, bq, bk, one_pass,
+              interpret):
+    """One algorithm, scheduled by whether anything has to be accumulated
+    across grid steps: one pass where _prep found a head's scores to be
+    one tile, else dq and dk/dv in a pass each over the triangular or the
+    rectangular grid."""
+    B, H, Tq, D = q.shape
+    Tk = k.shape[2]
+    if one_pass:
+        return _bwd_call_fused(q, k, v, kb, do, lse, delta, causal, scale,
+                               bq, interpret)
     if _use_tri(causal, Tq, Tk, bq, bk):
         return _bwd_call_tri(q, k, v, kb, do, lse, delta, scale, bq, bk,
                              interpret)
+    return _bwd_call_rect(q, k, v, kb, do, lse, delta, causal, scale, bq, bk,
+                          interpret)
+
+
+def _bwd_call_rect(q, k, v, kb, do, lse, delta, causal, scale, bq, bk,
+                   interpret):
+    """Two passes over the rectangular grid: dq accumulates over a q-row's
+    k-blocks, dk/dv over a k-column's q-blocks."""
+    B, H, Tq, D = q.shape
+    Tk = k.shape[2]
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
@@ -498,30 +603,30 @@ def _bwd_call(q, k, v, kb, do, lse, delta, causal, scale, bq, bk, interpret):
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_lse(q, k, v, kb, causal, scale, bq, bk, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash_lse(q, k, v, kb, causal, scale, bq, bk, one_pass, interpret):
     o, lse = _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret)
     return o, lse[..., 0]
 
 
-def _flash_lse_fwd(q, k, v, kb, causal, scale, bq, bk, interpret):
+def _flash_lse_fwd(q, k, v, kb, causal, scale, bq, bk, one_pass, interpret):
     o, lse = _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret)
     return (o, lse[..., 0]), (q, k, v, kb, o, lse)
 
 
-def _flash_lse_bwd(causal, scale, bq, bk, interpret, res, cot):
+def _flash_lse_bwd(causal, scale, bq, bk, one_pass, interpret, res, cot):
     """Backward with an lse cotangent, sharing the kernels unchanged:
     lse = logsumexp(S) gives dS|lse = P * dlse, and the kernels compute
     dS = P * (dP - delta), so folding delta' = delta - dlse routes the lse
-    gradient through the same two pallas calls (the FlashAttention D-trick
-    extended one term)."""
+    gradient through the same pallas calls, one pass or two (the
+    FlashAttention D-trick extended one term)."""
     do, dlse = cot
     q, k, v, kb, o, lse = res
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = delta - dlse.astype(jnp.float32)
     delta = jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
     dq, dk, dv = _bwd_call(q, k, v, kb, do, lse, delta, causal, scale,
-                           bq, bk, interpret)
+                           bq, bk, one_pass, interpret)
     # kb is a mask constant (see module docstring): zero cotangent
     return dq, dk, dv, jnp.zeros_like(kb)
 
@@ -537,12 +642,26 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 # bq == bk keeps the triangular grid eligible (_use_tri). Shorter sequences
 # clip the tiles in _prep, which is all that T = 256 ever sees.
 # PADDLE_TPU_FLASH_BQ/BK override.
+# The backward (PR 27) runs in ONE pass wherever a head's scores fit the
+# table's largest tile, masked or not: the residual lse is per row, so
+# nothing ties the backward to the forward's tile, and a causal call walks
+# that tile in the forward's 512 sub-tiles (_bwd_fused_kernel; at
+# 16 x 8 x 1024 x 64 causal 0.87 ms a call against 1.59 ms for the two
+# kernels on the triangular grid). Tiles a caller or the environment
+# forces are the backward's too.
 _TUNED_BQ_BK = {True: (512, 512), False: (1024, 1024)}
 # Beside 1024 x 1024 float32 score tiles Mosaic's VMEM budget holds operand
 # rows of up to this many bytes (compiled for a described v5e, PR 24:
 # float32 at D = 128 and bf16 at D = 256 fit, float32 at D = 256 is
 # refused).
 _WIDE_ROW_BYTES = 512
+# The one-pass backward holds a head's whole score tile, its operands and
+# its three results in VMEM at once, and fits Mosaic's default 16 MiB at
+# every shape this rule lets into one tile (compiled for a described v5e,
+# PR 27, tests/test_flash_aot.py: 1024 x 1024 at bf16 D = 256 and float32
+# D = 128, 512 x 512 at float32 D = 512; what fills VMEM first is the
+# double-buffered q, k, v, do, dq, dk, dv blocks, 14 x T x row bytes,
+# where the two kernels hold 10 and 12).
 
 
 def _default_tile(tuned, T, row_bytes):
@@ -571,17 +690,19 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
     # the dots take their operands as given, so the three agree on a dtype
     operands = jnp.result_type(q, k, v)
     q, k, v = (x.astype(operands) for x in (q, k, v))
-    # trace time: once per attention call per lowering (a forward and two
-    # backward kernels each), never per step
+    # trace time: once per attention call per lowering (a forward and one
+    # or two backward kernels each), never per step
     obs.counter('flash.lowered', operands=operands.name).inc()
     tuned_bq, tuned_bk = _TUNED_BQ_BK[bool(causal)]
+    whole_q, whole_k = _TUNED_BQ_BK[False]
     row_bytes = D * operands.itemsize
+    env_q = os.environ.get('PADDLE_TPU_FLASH_BQ')
+    env_k = os.environ.get('PADDLE_TPU_FLASH_BK')
+    forced = any(x is not None for x in (block_q, block_k, env_q, env_k))
     if block_q is None:
-        block_q = int(os.environ.get(
-            'PADDLE_TPU_FLASH_BQ', _default_tile(tuned_bq, Tq, row_bytes)))
+        block_q = int(env_q or _default_tile(tuned_bq, Tq, row_bytes))
     if block_k is None:
-        block_k = int(os.environ.get(
-            'PADDLE_TPU_FLASH_BK', _default_tile(tuned_bk, Tk, row_bytes)))
+        block_k = int(env_k or _default_tile(tuned_bk, Tk, row_bytes))
     if key_bias is None:
         key_bias = jnp.zeros((B, Tk), jnp.float32)
     else:
@@ -591,6 +712,13 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
     bk = min(block_k, _round_up(Tk, 128))
     Tq_p = _round_up(Tq, bq)
     Tk_p = _round_up(Tk, bk)
+    # the backward's schedule, read off the shapes: one pass where a head's
+    # scores are one tile, the forward's or (tiles not forced) the table's
+    # largest for rows this wide
+    one_pass = (Tq_p, Tk_p) == (bq, bk) or (
+        not forced and Tq_p <= _default_tile(whole_q, Tq, row_bytes)
+        and Tk_p <= _default_tile(whole_k, Tk, row_bytes))
+    obs.counter('flash.backward', passes='one' if one_pass else 'two').inc()
     if Tq_p != Tq:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, Tq_p - Tq), (0, 0)))
     if Tk_p != Tk:
@@ -602,7 +730,7 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
     # array dim, so the bias carries an explicit singleton sublane
     key_bias = key_bias.reshape(B, 1, Tk_p)
     return (q, k, v, key_bias, float(sm_scale), int(bq), int(bk),
-            bool(interpret), Tq, Tq_p)
+            bool(one_pass), bool(interpret), Tq, Tq_p)
 
 
 def flash_attention_lse(q, k, v, key_bias=None, causal=False, sm_scale=None,
@@ -611,10 +739,11 @@ def flash_attention_lse(q, k, v, key_bias=None, causal=False, sm_scale=None,
     ([B, H, Tq], f32) — the combine statistic ring attention needs to merge
     partial attention over key shards. Differentiable in q/k/v through BOTH
     outputs (see _flash_lse_bwd)."""
-    (q, k, v, kb, scale, bq, bk, interp, Tq, Tq_p) = _prep(
+    (q, k, v, kb, scale, bq, bk, one_pass, interp, Tq, Tq_p) = _prep(
         q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
         causal=causal)
-    o, lse = _flash_lse(q, k, v, kb, bool(causal), scale, bq, bk, interp)
+    o, lse = _flash_lse(q, k, v, kb, bool(causal), scale, bq, bk, one_pass,
+                        interp)
     if Tq_p != Tq:
         o = o[:, :, :Tq, :]
         lse = lse[:, :, :Tq]

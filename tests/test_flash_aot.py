@@ -29,16 +29,7 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize('causal', [False, True], ids=['full', 'causal'])
-@pytest.mark.parametrize('dtype,shape', [
-    ('bfloat16', (16, 8, 1024, 64)),    # tfm_s1024: 1024 tiles, 512 causal
-    ('bfloat16', (64, 8, 256, 64)),     # tfm_s256: one 256 tile
-    ('bfloat16', (2, 8, 1536, 64)),     # 1024 would pad to 2048: 512
-    ('bfloat16', (4, 8, 1024, 256)),    # the widest rows 1024 tiles take
-    ('float32', (2, 8, 2048, 128)),     # the same 512 bytes a row
-    ('float32', (4, 8, 1024, 256)),     # wider: refused at 1024, so 512
-], ids=lambda x: x if isinstance(x, str) else 'x'.join(map(str, x)))
-def test_default_tiles_compile_for_v5e(one_chip, dtype, shape, causal):
+def _compile_grad(one_chip, dtype, shape, causal):
     x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
     kb = jax.ShapeDtypeStruct((shape[0], shape[2]), jnp.float32,
                               sharding=one_chip)
@@ -48,9 +39,31 @@ def test_default_tiles_compile_for_v5e(one_chip, dtype, shape, causal):
                                 interpret=False)
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x, kb).compile()
-    assert compiled.as_text().count('tpu_custom_call') == 3
+
+
+# Mosaic calls of a forward and backward: 2 where a head's scores are one
+# tile (the one-pass backward, PR 27), 3 where they are not.
+@pytest.mark.parametrize('causal', [False, True], ids=['full', 'causal'])
+@pytest.mark.parametrize('dtype,shape,calls', [
+    ('bfloat16', (16, 8, 1024, 64), 2),   # tfm_s1024: one 1024 tile; causal
+                                          # forward 512, backward in one pass
+    ('bfloat16', (64, 8, 256, 64), 2),    # tfm_s256: one 256 tile
+    ('bfloat16', (2, 8, 1536, 64), 3),    # 1024 would pad to 2048: 512
+    ('bfloat16', (4, 8, 1024, 256), 2),   # the widest rows 1024 tiles take
+    ('float32', (2, 8, 2048, 128), 3),    # the same 512 bytes a row
+    ('float32', (4, 8, 1024, 256), 3),    # wider: refused at 1024, so 512
+    ('float32', (16, 8, 1024, 64), 2),    # the cells' shapes in a Program
+    ('float32', (64, 8, 256, 64), 2),     # without AMP: one pass too
+    ('float32', (2, 8, 1024, 128), 2),    # 512 bytes a row in one pass
+    ('float32', (2, 8, 512, 512), 2),     # the widest one 512 tile holds
+    ('bfloat16', (2, 16, 4096, 128), 3),  # olmoe_s4096: 36 tile pairs
+], ids=lambda x: x if isinstance(x, str) else
+    'x'.join(map(str, x)) if isinstance(x, tuple) else None)
+def test_default_tiles_compile_for_v5e(one_chip, dtype, shape, calls, causal):
+    compiled = _compile_grad(one_chip, dtype, shape, causal)
+    assert compiled.as_text().count('tpu_custom_call') == calls
 
 
 @pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])

@@ -370,7 +370,25 @@ _BF16_CASES = {
                                   block=128),
     'uneven_lengths': dict(Tq=9, Tk=33, bias=True),
     'lse_cotangent': dict(Tq=128, Tk=128, bias=True, causal=True, lse=True),
+    # the one-pass backward (PR 27; the four single-tile cases above take
+    # it too): a length that pads to its tile, and a causal head walked in
+    # two 512 sub-tiles, the first of which sees half the keys
+    'one_pass_pads_200': dict(Tq=200, Tk=200, bias=True, causal=True),
+    'one_pass_causal_sub_tiles': dict(Tq=1024, Tk=1024, bias=True,
+                                      causal=True, lse=True),
 }
+
+
+def _ref_o_lse(q, k, v, bias, causal):
+    """reference_attention and the logsumexp of its scores."""
+    s = jnp.einsum('bhqd,bhkd->bhqk', q, k) * q.shape[-1] ** -0.5
+    if bias is not None:
+        s = s + bias[:, None, None, :]
+    if causal:
+        s = jnp.where(jnp.arange(q.shape[2])[:, None]
+                      >= jnp.arange(k.shape[2])[None, :], s, -1e9)
+    return (ops.reference_attention(q, k, v, key_bias=bias, causal=causal),
+            jax.scipy.special.logsumexp(s, axis=-1))
 
 
 def _rel_norm(got, want):
@@ -397,15 +415,7 @@ def test_bf16_inputs_match_float32_reference(case):
             block_k=c.get('block'), interpret=True)
 
     def ref(q, k, v):
-        s = jnp.einsum('bhqd,bhkd->bhqk', q, k) * q.shape[-1] ** -0.5
-        if bias is not None:
-            s = s + bias[:, None, None, :]
-        if causal:
-            s = jnp.where(jnp.arange(c['Tq'])[:, None]
-                          >= jnp.arange(c['Tk'])[None, :], s, -1e9)
-        return (ops.reference_attention(q, k, v, key_bias=bias,
-                                        causal=causal),
-                jax.scipy.special.logsumexp(s, axis=-1))
+        return _ref_o_lse(q, k, v, bias, causal)
 
     def loss(fn):
         def f(q, k, v):
@@ -429,47 +439,69 @@ def test_bf16_inputs_match_float32_reference(case):
         assert _rel_norm(got, want) <= 4 * BF16_EPS, name
 
 
-def _kernel_bodies(dtype, causal):
-    """The traced bodies of the three kernels of one forward and backward
-    (rectangular grid, or triangular when causal), as (name, [eqns])."""
-    x = jax.ShapeDtypeStruct((2, 2, 256, 64), dtype)
+def _walk(jp, out):
+    for e in jp.eqns:
+        out.append(e)
+        for p in e.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                sub = getattr(sub, 'jaxpr', sub)
+                if hasattr(sub, 'eqns'):
+                    _walk(sub, out)
+    return out
+
+
+def _kernel_bodies(dtype, causal, block_q=128, block_k=128, T=256, Tk=None):
+    """The traced bodies of the kernels of one forward and backward at
+    2 x 2 x T x 64 (blocks of 128: the rectangular grid, or the triangular
+    one when causal; None: the default tiles), as (name, [eqns])."""
+    q = jax.ShapeDtypeStruct((2, 2, T, 64), dtype)
+    k = jax.ShapeDtypeStruct((2, 2, Tk or T, 64), dtype)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda q, k, v: ops.flash_attention(
-            q, k, v, causal=causal, block_q=128, block_k=128,
+            q, k, v, causal=causal, block_q=block_q, block_k=block_k,
             interpret=True).astype(jnp.float32).sum(),
-        argnums=(0, 1, 2)))(x, x, x)
-
-    def walk(jp, out):
-        for e in jp.eqns:
-            out.append(e)
-            for p in e.params.values():
-                for sub in (p if isinstance(p, (list, tuple)) else [p]):
-                    sub = getattr(sub, 'jaxpr', sub)
-                    if hasattr(sub, 'eqns'):
-                        walk(sub, out)
-        return out
-
-    calls = [e for e in walk(jaxpr.jaxpr, [])
+        argnums=(0, 1, 2)))(q, k, k)
+    calls = [e for e in _walk(jaxpr.jaxpr, [])
              if e.primitive.name == 'pallas_call']
     return [(e.params['jaxpr'].debug_info.func_name,
-             walk(e.params['jaxpr'], [])) for e in calls]
+             _walk(e.params['jaxpr'], [])) for e in calls]
 
 
-@pytest.mark.parametrize('causal', [False, True],
-                         ids=['rectangular', 'triangular'])
+# path -> the forced block (None: the default tiles, one tile a head here),
+# the mask, the bodies' dots sorted. Two passes: 2 + 3 + 4 = 9 dots and
+# three exp passes over a score tile; one pass: 2 + 5 and two.
+_BODY_PATHS = {
+    'rectangular': dict(block=128, causal=False, dots=[2, 3, 4]),
+    'triangular': dict(block=128, causal=True, dots=[2, 3, 4]),
+    'one_pass': dict(block=None, causal=False, dots=[2, 5]),
+    'one_pass_causal': dict(block=None, causal=True, dots=[2, 5]),
+}
+
+
+@pytest.mark.parametrize('path', sorted(_BODY_PATHS))
 @pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
-def test_kernel_dots_take_the_inputs_dtype(dtype, causal):
-    """The mechanism, pinned off the chip: all nine dots of the three
-    bodies (2 + 3 + 4) take operands of the refs' dtype and give float32;
-    with bf16 in nothing is cast up to float32 anywhere in a body (q, k, v
-    and do feed dots alone), with float32 in nothing is cast at all. No
-    score-sized or bf16 tile is transposed: the dk/dv body turns round its
-    lane-broadcast float32 row statistics and nothing else."""
-    bodies = _kernel_bodies(jnp.dtype(dtype), causal)
-    assert len(bodies) == 3, [n for n, _ in bodies]
-    assert all(('_tri' in name) == causal for name, _ in bodies)
+def test_kernel_dots_take_the_inputs_dtype(dtype, path):
+    """The mechanism, pinned off the chip, on every path: all dots of the
+    bodies (2 + 3 + 4 with two backward kernels, 2 + 5 with one) take
+    operands of the refs' dtype and give float32; with float32 in nothing
+    is cast at all; with bf16 in p and ds are cast down as dot operands
+    and nothing is cast up, but for the one-pass body's k tile on its way
+    through the transposition that dq = (k^T ds^T)^T needs. No score-sized
+    tile is transposed on any path: the transposed-score bodies turn round
+    their lane-broadcast float32 row statistics, and the one-pass body k
+    and dq^T besides. Each backward body takes exp of one score tile: the
+    one-pass backward computes s, p, dp and ds once."""
+    c = _BODY_PATHS[path]
+    bodies = _kernel_bodies(jnp.dtype(dtype), c['causal'], c['block'],
+                            c['block'])
+    assert len(bodies) == len(c['dots']), [n for n, _ in bodies]
+    assert all(('_tri' in name) == (path == 'triangular')
+               for name, _ in bodies)
+    if path.startswith('one_pass'):
+        assert [n for n, _ in bodies][1:] == ['_bwd_fused_kernel']
     n_dots = []
     for name, eqns in bodies:
+        fused = name == '_bwd_fused_kernel'
         dots = [e for e in eqns if e.primitive.name == 'dot_general']
         n_dots.append(len(dots))
         for e in dots:
@@ -478,16 +510,27 @@ def test_kernel_dots_take_the_inputs_dtype(dtype, causal):
         for e in eqns:
             if e.primitive.name == 'transpose':
                 aval = e.invars[0].aval
-                assert aval.dtype == jnp.float32 and 128 in aval.shape, name
-        casts = [(str(e.invars[0].aval.dtype), str(e.params['new_dtype']))
+                assert aval.dtype == jnp.float32, name
+                # never a [256, 256] score tile: statistics [rows, 128],
+                # and in the one-pass body k [256, 64] and dq^T [64, 256]
+                assert 128 in aval.shape or (fused and 64 in aval.shape), (
+                    name, aval.shape)
+        if 'bwd' in name:
+            exps = [e for e in eqns if e.primitive.name == 'exp']
+            assert [e.invars[0].aval.ndim for e in exps] == [2], name
+        casts = [(str(e.invars[0].aval.dtype), str(e.params['new_dtype']),
+                  e.invars[0].aval.shape)
                  for e in eqns if e.primitive.name == 'convert_element_type'
                  and e.invars[0].aval.dtype != e.params['new_dtype']]
         floats = [c for c in casts if 'float' in c[0] and 'float' in c[1]]
         if dtype == 'bfloat16':
-            assert floats and set(floats) == {('float32', 'bfloat16')}, name
+            up = [c for c in floats if c[:2] == ('bfloat16', 'float32')]
+            assert floats and {c[:2] for c in floats} - {
+                ('bfloat16', 'float32')} == {('float32', 'bfloat16')}, name
+            assert [c[2] for c in up] == ([(256, 64)] if fused else []), name
         else:
             assert not floats, (name, floats)
-    assert sorted(n_dots) == [2, 3, 4]
+    assert sorted(n_dots) == c['dots']
 
 
 def test_flash_lowered_counts_once_per_call_per_lowering_by_dtype():
@@ -515,3 +558,139 @@ def test_flash_lowered_counts_once_per_call_per_lowering_by_dtype():
     step(x32, x32, x32)
     assert count()['float32'] - before['float32'] == 2
     assert count()['bfloat16'] == after['bfloat16']
+
+
+# ---------------------------------------------------------------------------
+# the one-pass backward (PR 27): one kernel where a head's scores are one tile
+# ---------------------------------------------------------------------------
+
+_ONE_PASS_CASES = {
+    'plain': dict(T=128),
+    'causal': dict(T=128, causal=True),
+    'pad_key_bias': dict(T=256, bias=True),
+    'pads_200_to_256': dict(T=200, bias=True, causal=True),
+    'lse_cotangent': dict(T=128, bias=True, lse=True),
+    'cross_lengths': dict(T=128, Tk=384, bias=True),
+    'causal_sub_tiles': dict(T=1024, bias=True, causal=True, lse=True),
+}
+
+
+def _count_passes():
+    from paddle_tpu import obs
+    return {p: obs.counter('flash.backward', passes=p).value
+            for p in ('one', 'two')}
+
+
+@pytest.mark.parametrize('case', sorted(_ONE_PASS_CASES))
+def test_one_pass_backward_matches_reference_float32(case):
+    """Float32 in: the float32 tolerances of the two-kernel tests above,
+    unedited (3e-4), on every shape of mask, padding and cotangent the
+    one-pass body sees."""
+    c = _ONE_PASS_CASES[case]
+    causal, T, Tk = c.get('causal', False), c['T'], c.get('Tk', c['T'])
+    q, k, v, kb = _rand_qkv(B=2, H=1, Tq=T, Tk=Tk, D=16, seed=31)
+    if c.get('bias'):
+        kb[:, Tk - Tk // 8:] = -1e9               # a padded tail as well
+    bias = jnp.asarray(kb) if c.get('bias') else None
+
+    def ref(q, k, v):
+        return _ref_o_lse(q, k, v, bias, causal)
+
+    def flash(q, k, v):
+        return ops.flash_attention_lse(q, k, v, key_bias=bias, causal=causal,
+                                       interpret=True)
+
+    def grads(fn):
+        def f(q, k, v):
+            o, lse = fn(q, k, v)
+            val = jnp.sum(o * jnp.cos(o))
+            return val + jnp.sum(jnp.sin(lse)) if c.get('lse') else val
+        return jax.grad(f, argnums=(0, 1, 2))(*map(jnp.asarray, (q, k, v)))
+
+    before = _count_passes()
+    got = grads(flash)
+    after = _count_passes()
+    assert (after['one'] - before['one'], after['two'] - before['two']) \
+        == (1, 0)
+    for a, b, name in zip(got, grads(ref), 'qkv'):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=3e-4, atol=3e-4, err_msg=name)
+
+
+@pytest.mark.parametrize('causal', [False, True], ids=['full', 'causal'])
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+def test_one_pass_equals_two_passes(dtype, causal):
+    """The same inputs through both schedules (512 x 512 scores: one pass
+    in sub-tiles of 256 against the triangular or rectangular grid of
+    256-tiles) agree to the dots' rounding: the arithmetic is the same,
+    the order of the sums over blocks is not."""
+    import importlib
+    fa = importlib.import_module('paddle_tpu.ops.flash_attention')
+    q, k, v, kb = _rand_qkv(B=1, H=2, Tq=512, Tk=512, D=32, seed=41)
+    q, k, v = [jnp.asarray(x, jnp.dtype(dtype)) for x in (q, k, v)]
+    q, k, v, kb, scale, bq, bk, one_pass, interp, _, _ = fa._prep(
+        q, k, v, jnp.asarray(kb), None, 256, 256, True, causal=causal)
+    assert not one_pass and (bq, bk) == (256, 256)
+    o, lse = fa._fwd_call(q, k, v, kb, causal, scale, bq, bk, interp)
+    r = np.random.RandomState(42)
+    do = jnp.asarray(r.randn(*o.shape), o.dtype)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = jnp.broadcast_to(delta[..., None], delta.shape + (fa.LANES,))
+    args = (q, k, v, kb, do, lse, delta, causal, scale, bq, bk)
+    one = fa._bwd_call(*args, True, interp)
+    two = fa._bwd_call(*args, False, interp)
+    for a, b, name in zip(one, two, ('dq', 'dk', 'dv')):
+        assert a.dtype == b.dtype == jnp.dtype(dtype)
+        assert _rel_norm(a, b) <= (BF16_EPS if dtype == 'bfloat16'
+                                   else 1e-6), name
+
+
+@pytest.mark.parametrize('case,kw,bwd_calls', [
+    ('one_tile', dict(T=256), 1),
+    ('one_tile_causal', dict(T=256, causal=True), 1),
+    ('causal_1024_in_sub_tiles', dict(T=1024, causal=True), 1),
+    ('two_tiles', dict(T=2048), 2),
+    ('causal_2048_triangular', dict(T=2048, causal=True), 2),
+    ('block_q_forced_below_T', dict(T=256, block_q=128), 2),
+    ('blocks_forced_causal_1024', dict(T=1024, causal=True, block_q=512,
+                                       block_k=512), 2),
+    ('cross_lengths_two_key_tiles', dict(T=256, Tk=2048), 2),
+], ids=lambda x: x if isinstance(x, str) else None)
+def test_backward_routing_reads_the_shapes(case, kw, bwd_calls, monkeypatch):
+    """One pallas_call in the backward where a head's scores are one tile
+    (the forward's, or the table's largest when nobody forced a tile), two
+    otherwise; the counter says the same, once per call per lowering."""
+    monkeypatch.delenv('PADDLE_TPU_FLASH_BQ', raising=False)
+    monkeypatch.delenv('PADDLE_TPU_FLASH_BK', raising=False)
+
+    def trace():
+        return [name for name, _ in _kernel_bodies(
+            jnp.bfloat16, kw.get('causal', False), kw.get('block_q'),
+            kw.get('block_k'), kw['T'], kw.get('Tk'))]
+
+    before = _count_passes()
+    names = trace()
+    after = _count_passes()
+    assert len(names) == 1 + bwd_calls, names
+    assert ('_bwd_fused_kernel' in names) == (bwd_calls == 1)
+    want = {'one': int(bwd_calls == 1), 'two': int(bwd_calls == 2)}
+    assert {p: after[p] - before[p] for p in want} == want
+    if case == 'one_tile':      # the environment's tiles are forced tiles
+        monkeypatch.setenv('PADDLE_TPU_FLASH_BQ', '128')
+        assert len(trace()) == 3
+
+
+def test_flash_backward_counts_once_per_call_per_lowering():
+    def two_calls(q, k, v):
+        o = ops.flash_attention(q, k, v, interpret=True)
+        o = ops.flash_attention(o, k, v, causal=True, block_q=128,
+                                block_k=128, interpret=True)
+        return o.astype(jnp.float32).sum()
+
+    step = jax.jit(jax.grad(two_calls, argnums=(0, 1, 2)))
+    x = jnp.ones((1, 1, 256, 8), jnp.bfloat16)
+    before = _count_passes()
+    for _ in range(3):          # three steps, one lowering
+        step(x, x, x)
+    after = _count_passes()
+    assert {p: after[p] - before[p] for p in after} == {'one': 1, 'two': 1}

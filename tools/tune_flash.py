@@ -7,10 +7,15 @@ paddle_tpu.ops.flash_attention). Run on TPU:
 
     python tools/tune_flash.py [--seq 256] [--batch 64] [--heads 8] [--dim 64]
 
---parts also times the forward, dq and dk/dv kernels ALONE at the table's
-default tiles (one `part ...` line each, with the time per grid step): what
-to read before and after a change to a kernel body. docs/perf.md has the
-last sweep's rows and the commands that gave them.
+--parts also times the kernels ALONE (one `part ...` line each, with the
+time per grid step): the forward, the one-pass backward (`bwd`) where the
+default tiles make a head's scores one tile, and the two kernels (`dq`,
+`dkv`) it replaced there at the same tiles; what to read before and after
+a change to a kernel body. --tile N forces the parts' tiles: with --causal
+--seq 1024, --tile 1024 is one masked tile in one pass and --tile 512 the
+triangular grid's two kernels, against the default's one pass in 512
+sub-tiles. docs/perf.md has the last sweep's rows and the commands that
+gave them.
 """
 import argparse
 import itertools
@@ -24,17 +29,19 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def time_parts(q, k, v, causal, iters):
+def time_parts(q, k, v, causal, iters, tile=None):
     """[(kernel, seconds per call, grid steps per call)] of the forward,
-    dq and dk/dv kernels alone at the default tiles. Each chain consumes
-    one kernel's outputs only, so XLA removes the other backward call."""
+    the one-pass backward where the tiles allow it, and the dq and dk/dv
+    kernels alone, at the default tiles or at `tile`. The dq and dk/dv
+    chains consume one kernel's outputs only, so XLA removes the other
+    call."""
     import importlib
     import jax.numpy as jnp
     from paddle_tpu.utils.timing import time_chained
     # the package's attribute of that name is the function
     fa = importlib.import_module('paddle_tpu.ops.flash_attention')
-    q, k, v, kb, scale, bq, bk, interp, _, _ = fa._prep(
-        q, k, v, None, None, None, None, False, causal=causal)
+    q, k, v, kb, scale, bq, bk, one_pass, interp, _, _ = fa._prep(
+        q, k, v, None, None, tile, tile, False, causal=causal)
     o, lse = fa._fwd_call(q, k, v, kb, causal, scale, bq, bk, interp)
     delta = jnp.sum(o.astype(jnp.float32) ** 2, axis=-1)
     delta = jnp.broadcast_to(delta[..., None], delta.shape + (fa.LANES,))
@@ -42,29 +49,37 @@ def time_parts(q, k, v, causal, iters):
     def nudge(x, dx):
         return x + (1e-6 * dx).astype(x.dtype)
 
-    def bwd(q, k, v):       # the cotangent is o itself: bf16, full rank
+    def bwd(q, k, v, one_pass):  # the cotangent is o itself: bf16, full rank
         return fa._bwd_call(q, k, v, kb, o, lse, delta, causal, scale,
-                            bq, bk, interp)
+                            bq, bk, one_pass, interp)
 
     def fwd_step(x):
         return (nudge(x[0], fa._fwd_call(x[0], k, v, kb, causal, scale,
                                          bq, bk, interp)[0]),)
 
+    def bwd_step(x):
+        return tuple(nudge(a, d) for a, d in zip(x, bwd(*x, True)))
+
     def dq_step(x):
-        return (nudge(x[0], bwd(x[0], k, v)[0]),)
+        return (nudge(x[0], bwd(x[0], k, v, False)[0]),)
 
     def dkv_step(x):
-        _, dk, dv = bwd(q, x[0], x[1])
+        _, dk, dv = bwd(q, x[0], x[1], False)
         return nudge(x[0], dk), nudge(x[1], dv)
 
     B, H, T, _ = q.shape
     nq = T // bq
     blocks = nq * (nq + 1) // 2 if fa._use_tri(causal, T, T, bq, bk) \
         else nq * (T // bk)
-    return [(name, time_chained(step, x, iters), B * H * blocks)
-            for name, step, x in (('fwd', fwd_step, (q,)),
-                                  ('dq', dq_step, (q,)),
-                                  ('dkv', dkv_step, (k, v)))]
+    parts = [('fwd', fwd_step, (q,), blocks)]
+    if one_pass:
+        parts.append(('bwd', bwd_step, (q, k, v), 1))
+    parts += [('dq', dq_step, (q,), blocks), ('dkv', dkv_step, (k, v), blocks)]
+    print('parts at tiles %d x %d, backward in %s' % (
+        bq, bk, 'one pass (sub-tiles of %d)' % bq if one_pass
+        else 'two passes'))
+    return [(name, time_chained(step, x, iters), B * H * n)
+            for name, step, x, n in parts]
 
 
 def main():
@@ -78,7 +93,11 @@ def main():
     ap.add_argument('--blocks', type=str, default='128,256,512',
                     help='comma-separated candidate tile sizes')
     ap.add_argument('--parts', action='store_true',
-                    help='also time fwd, dq and dkv alone at the default tiles')
+                    help='also time fwd, the one-pass bwd, dq and dkv alone')
+    ap.add_argument('--tile', type=int, default=None,
+                    help='tiles of --parts (default: the table\'s)')
+    ap.add_argument('--no-sweep', action='store_true',
+                    help='stop after --parts')
     args = ap.parse_args()
 
     import jax
@@ -99,9 +118,12 @@ def main():
                     dtype=jnp.bfloat16)
 
     if args.parts:
-        for name, dt, steps in time_parts(q, k, v, args.causal, args.iters):
+        for name, dt, steps in time_parts(q, k, v, args.causal, args.iters,
+                                          args.tile):
             print('part %-3s %.3f ms/call, %d grid steps, %.3f us/grid step'
                   % (name, dt * 1e3, steps, dt * 1e6 / steps))
+    if args.no_sweep:
+        return
 
     cands = sorted({min(int(b), T) for b in args.blocks.split(',')})
     results = []
