@@ -10,9 +10,9 @@ magnitudes span the whole schedule, so moments stay fp32 and only the
 VALUE bytes shrink. Note the HostArena (tiers.py) stores a slot's
 table+moment rows in one homogeneous block and therefore keeps fp32 —
 int8 rows pay off at the two boundaries where values travel ALONE: the
-delta push (wire bytes per row: 4*D -> D + 4, the bench.py
-`--phase quant` metric) and the quantized serving table
-(quant_lookup_table's HBM: docs/perf.md#quantized-inference).
+delta push (wire bytes per row: 4*D -> D + 4) and the quantized
+serving table (quant_lookup_table's HBM:
+docs/perf.md#quantized-inference).
 """
 import numpy as np
 

@@ -1187,10 +1187,6 @@ class Executor(object):
                     obs.event('passes.error', key=key_id,
                               error='%s: %s' % (type(e).__name__, e))
                     run_program, run_block = program, block
-            # the amp ctx flag dies for IR-rewritten programs: their
-            # casts are explicit ops now (passes.amp_pass), even when
-            # the global amp_guard armed the flag
-            step_amp = amp and not getattr(run_program, '_amp_ir', False)
             # the Program -> jittable-step build (op walk, sparse plan,
             # pipeline region checks); the XLA compile itself happens on
             # the first call and is timed as executor.compile in run().
@@ -1201,7 +1197,7 @@ class Executor(object):
                 try:
                     compiled = _CompiledStep(
                         run_program, run_block, list(feed_vals),
-                        fetch_names, persist_in, amp=step_amp,
+                        fetch_names, persist_in, amp=amp,
                         platform=plat,
                         persist_shardings=persist_shardings,
                         mesh=dist_mesh, guard=guard,
@@ -1969,7 +1965,7 @@ class Executor(object):
         output/temp byte sizes of the compiled module. The temp figure is
         the per-step scratch footprint the docs/perf.md and
         docs/embedding.md sparse-vs-dense claims are measured with
-        (`bench.py --phase embedding`). Costs one lowering + compile
+        (tests/test_embedding.py). Costs one lowering + compile
         (absorbed by the persistent compile cache when wired); the
         compiled-step cache itself is shared with run()."""
         _, lowered = self._lower_current_step(program, feed, fetch_list,

@@ -580,10 +580,9 @@ class Program(object):
         Program.clone + inference_optimize)."""
         p = Program()
         p.random_seed = self.random_seed
-        # execution flags travel with the program: amp mode (incl. the
-        # passes.amp_pass IR-rewrite marker), the Float16Transpiler
-        # fetch contract, rematerialisation
-        for flag in ('_amp', '_amp_ir', '_fetch_f32', '_use_remat',
+        # execution flags travel with the program: amp mode, the
+        # Float16Transpiler fetch contract, rematerialisation
+        for flag in ('_amp', '_fetch_f32', '_use_remat',
                      '_quant', '_quant_ir', '_quant_ops'):
             if hasattr(self, flag):
                 setattr(p, flag, getattr(self, flag))

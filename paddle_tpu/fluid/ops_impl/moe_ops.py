@@ -225,8 +225,8 @@ def _moe_mlp(ins, attrs, ctx):
         sizes = jnp.bincount(expert.reshape(-1), length=n_exp
                              ).astype(jnp.int32)
     params = dict(zip(params, amp_cast(ctx, *params.values())))
-    # the experts' rows in the experts' dtype (runtime AMP cast the
-    # weights just above, the AMP rewrite casts W* and not X)
+    # the experts' rows in the experts' dtype (AMP cast the weights just
+    # above; X stayed float32 for the router)
     x = x.astype(params['w1'].dtype)
 
     mesh = ctx.mesh

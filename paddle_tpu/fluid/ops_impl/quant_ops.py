@@ -5,9 +5,8 @@ Parity: the reference grew fake_quantize/fake_dequantize operators
 tooling — scales computed per tensor or per channel, int8 storage for
 inference. Here the same boundaries are three PURE rules the quant pass
 (fluid/passes/quant_pass.py) inserts, so `analysis`, provenance and
-`program_lint` see every precision change as a real op — the same
-visibility argument as the AMP IR rewrite — and constant folding can
-evaluate a `quantize` of a frozen weight at optimization time through
+`program_lint` see every precision change as a real op, and constant
+folding can evaluate a `quantize` of a frozen weight at optimization time through
 the rule itself (one definition of the rounding semantics).
 
 Scheme (docs/perf.md#quantized-inference carries the tolerance table):

@@ -11,11 +11,6 @@ inference_transpiler) pre-digest the graph before codegen.
 
 Passes (docs/passes.md has the catalog and the A/B guarantees):
 
-  amp   — AMP as an IR rewrite: explicit `cast` ops around the
-          matmul/conv/attention ops `lowering.amp_cast` used to cast at
-          trace time, so bf16 boundaries are visible to analysis,
-          provenance, and program_lint. `ctx.amp` stays only as the
-          compatibility flag for unoptimized programs.
   fold  — constant folding: ops whose inputs are all compile-time
           constants (`fill_constant`/`assign_value` chains) are evaluated
           through THEIR OWN lowering rules (one definition of op
@@ -27,12 +22,14 @@ Passes (docs/passes.md has the catalog and the A/B guarantees):
           own liveness) promoted to a pruning transform that respects
           fetch and persistable liveness.
 
-Equivalence contract: DCE/CSE/folding are BIT-EXACT against the
-unoptimized lowering (per-op RNG streams survive op removal via the
-`op_seq` stamp the executor consults); the AMP rewrite matches runtime
-AMP within one bf16 rounding of each rewritten op's output
-(docs/passes.md "A/B guarantees"). `tests/test_passes.py` drills both
-claims over the program-fuzz corpus and the book models.
+Equivalence contract: every pass is BIT-EXACT against the unoptimized
+lowering, AMP or not (per-op RNG streams survive op removal via the
+`op_seq` stamp the executor consults; the quant rewrite keeps its own
+documented tolerance). bf16 is not a pass: an AMP program keeps `_amp`
+and its optimized clone lowers through `ctx.amp` (`lowering.amp_cast`)
+like the original, so the rules that call `amp_cast` are never folded.
+`tests/test_passes.py` drills the claim over the program-fuzz corpus and
+the book models.
 
 Wiring: `PADDLE_TPU_OPT={off,default,aggressive}` gates the Executor
 (once per compiled-step cache key, like PADDLE_TPU_VERIFY);
@@ -59,8 +56,7 @@ __all__ = ['optimize', 'opt_mode', 'is_pure', 'is_foldable',
 # PADDLE_TPU_OPT wires optimize() into Executor._prepare, once per
 # compiled-step cache key:
 #   off        (default) — lower the program exactly as built;
-#   default    — amp rewrite, constant folding, CSE, DCE (bit-exact /
-#                documented-tolerance transforms only);
+#   default    — constant folding, CSE, DCE (bit-exact transforms only);
 #   aggressive — same passes with a larger constant-folding budget.
 ENV_OPT = 'PADDLE_TPU_OPT'
 LEVELS = ('off', 'default', 'aggressive')
@@ -96,7 +92,8 @@ def opt_mode():
 # stream — a rule that mentions ctx.rng is impure on every code path,
 # conservatively. Folding is stricter still: the rule must not branch on
 # the compilation context (platform/mesh), because folding evaluates it
-# OUTSIDE the compiled module.
+# OUTSIDE the compiled module — nor call `amp_cast`, because folding
+# evaluates it with amp off where the step may run it in bf16.
 
 _EFFECTFUL = frozenset(['print', 'autodiff', 'py_func'])
 
@@ -119,7 +116,8 @@ def _rule_uses_rng(op_type):
 def _rule_uses_context(op_type):
     src = _rule_source(op_type)
     return src is None or any(m in src for m in (
-        'ctx.platform', 'ctx.mesh', 'manual_axes', 'ctx.is_test'))
+        'ctx.platform', 'ctx.mesh', 'manual_axes', 'ctx.is_test',
+        'amp_cast'))
 
 
 def is_pure(op):
@@ -138,7 +136,7 @@ def is_pure(op):
 def is_foldable(op):
     """Pure AND context-free: the rule can be evaluated eagerly at
     optimization time with the same result the compiled module would
-    produce (no platform/mesh/is_test branching)."""
+    produce (no platform/mesh/is_test/amp branching)."""
     return is_pure(op) and not _rule_uses_context(op.type)
 
 
@@ -178,10 +176,9 @@ def write_counts(program):
 
 # the one number per pass the passes.optimize span (and obs_report's
 # attribution line) carries: actual WORK DONE, never a grab-bag sum that
-# would count amp's skipped ops as rewrites
+# would count quant's skipped ops as rewrites
 _PRIMARY_STAT = {'dce': 'ops_removed', 'fold': 'ops_folded',
-                 'cse': 'ops_merged', 'amp': 'ops_rewritten',
-                 'quant': 'ops_rewritten'}
+                 'cse': 'ops_merged', 'quant': 'ops_rewritten'}
 
 class PassReport(object):
     """What one optimize() run did: per-pass numbers + the total top-level
@@ -257,16 +254,12 @@ def optimize(program, feeds=None, fetches=None, level='default',
         report.skipped = 'pipeline-transpiled program'
         return program, report
 
-    from . import amp_pass, cse, dce, fold, quant_pass
-    from .. import amp as amp_mod
+    from . import cse, dce, fold, quant_pass
 
     with obs.span('passes.optimize', level=level,
                   where=where or 'api') as sp:
         p = _clone_for_opt(program)
         report.ops_before = len(p.global_block().ops)
-        if amp_mod.is_amp(program):
-            with obs.span('passes.amp'):
-                amp_pass.run(p, report)
         if quant_pass.is_quant(program):
             with obs.span('passes.quant'):
                 quant_pass.run(p, report)
@@ -275,7 +268,7 @@ def optimize(program, feeds=None, fetches=None, level='default',
         if fetches is not None:
             # CSE and DCE both ELIMINATE output names; without knowing
             # the fetch set, any terminal output may be fetched later —
-            # only the amp/fold rewrites (which preserve every name) are
+            # only the quant/fold rewrites (which preserve every name) are
             # safe to run blind
             with obs.span('passes.cse'):
                 cse.run(p, report, feeds=feeds, fetches=fetches)

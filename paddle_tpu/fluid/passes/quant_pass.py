@@ -2,9 +2,8 @@
 
 Two surfaces over the same three ops (ops_impl/quant_ops.py):
 
-* `run(program, report)` — the PASS-PIPELINE form, modeled on
-  amp_pass.run and gated the same way (mark the program with
-  `mark_quant`, let `optimize()` rewrite the clone). Every eligible op
+* `run(program, report)` — the PASS-PIPELINE form (mark the program
+  with `mark_quant`, let `optimize()` rewrite the clone). Every eligible op
   with a frozen float32 weight gets EXPLICIT quantize/dequantize ops:
   `mul`/`matmul` weights route through `quantize` -> `dequantize` (the
   reference's fake-quant form — the op still consumes f32, but every
@@ -44,8 +43,8 @@ _C_WEIGHTS = obs.counter('passes.quant.weights_quantized')
 
 # op type -> (weight input slot, per-channel axis of that weight).
 # Weight-only quantization: activations stay f32, so downstream dtypes
-# never change and no abstract-eval eligibility probe is needed (unlike
-# the amp rewrite). lookup_table's axis 0 is per-ROW (the embedding
+# never change and no abstract-eval eligibility probe is needed.
+# lookup_table's axis 0 is per-ROW (the embedding
 # row-store layout embedding/quant_rows.py shares); matmul weights
 # quantize per OUTPUT channel (axis 1 of [K, N]).
 QUANT_SLOTS = {
@@ -172,8 +171,7 @@ def run(program, report):
         program._bump_version()
         _C_REWRITTEN.inc(rewritten)
         _C_QDQ.inc(inserted)
-    # quant becomes an IR property of the rewritten clone, exactly the
-    # amp pass's flag protocol
+    # quant becomes an IR property of the rewritten clone
     program._quant = False
     program._quant_ir = True
     report.note('quant', ops_rewritten=rewritten, qdq_inserted=inserted)

@@ -197,7 +197,7 @@ class Trainer(object):
         self.double_buffer = bool(double_buffer)
         # input-overlap accounting (docs/perf.md#overlap): total seconds
         # the train loop actually WAITED for its next fed batch, and the
-        # batches counted — bench.py's overlap phase reads these
+        # batches counted
         self.input_stage_s = 0.0
         self.batches_fed = 0
         # in-flight async sharded checkpoint (CheckpointConfig

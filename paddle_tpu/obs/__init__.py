@@ -51,8 +51,8 @@ __all__ = ['metrics', 'report', 'slo', 'trace', 'REGISTRY', 'counter',
 
 ENV_DIR = 'PADDLE_TPU_OBS_DIR'
 # Optional: pin the run-log to an EXACT file path instead of a fresh
-# run-<stamp>-<pid>.jsonl — how tools/perf_sweep.sh collects one sweep's
-# events (its own + every child bench's) into a single run file.
+# run-<stamp>-<pid>.jsonl — how a driver script collects its own events
+# and every child process's into a single run file.
 ENV_RUN_FILE = 'PADDLE_TPU_OBS_RUN_FILE'
 # Ring-buffer bound of the run log (see runlog.RunLog); applies to fresh
 # per-run files. A pinned shared file (ENV_RUN_FILE) stays unbounded by

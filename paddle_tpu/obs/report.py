@@ -338,7 +338,7 @@ def summarize(events):
         lines.append('%d program(s) optimized: %d -> %d top-level op(s)'
                      % (len(opt_spans), before, after))
         per = {}
-        for name in ('dce', 'fold', 'cse', 'amp', 'quant'):
+        for name in ('dce', 'fold', 'cse', 'quant'):
             tot = sum(int(s.get('fields', {}).get(name, 0))
                       for s in opt_spans)
             if tot:

@@ -389,12 +389,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
 
 def _use_tri(causal, Tq, Tk, bq, bk):
     """Triangular (block-skipping) causal grid applies to the aligned
-    self-attention case; nq == 1 has no upper blocks to skip.
-    PADDLE_TPU_FLASH_TRI=0 forces the rectangular fallback (escape hatch
-    if a Mosaic version mishandles the scalar-prefetch grid on-chip)."""
-    import os
-    if os.environ.get('PADDLE_TPU_FLASH_TRI', '1') != '1':
-        return False
+    self-attention case; nq == 1 has no upper blocks to skip."""
     return causal and Tq == Tk and bq == bk and Tq // bq > 1
 
 
@@ -641,14 +636,13 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 # triangular grid beats one masked 1024 x 1024 block by 1.5%. Equal
 # bq == bk keeps the triangular grid eligible (_use_tri). Shorter sequences
 # clip the tiles in _prep, which is all that T = 256 ever sees.
-# PADDLE_TPU_FLASH_BQ/BK override.
 # The backward (PR 27) runs in ONE pass wherever a head's scores fit the
 # table's largest tile, masked or not: the residual lse is per row, so
 # nothing ties the backward to the forward's tile, and a causal call walks
 # that tile in the forward's 512 sub-tiles (_bwd_fused_kernel; at
 # 16 x 8 x 1024 x 64 causal 0.87 ms a call against 1.59 ms for the two
-# kernels on the triangular grid). Tiles a caller or the environment
-# forces are the backward's too.
+# kernels on the triangular grid). Tiles a caller forces are the
+# backward's too.
 _TUNED_BQ_BK = {True: (512, 512), False: (1024, 1024)}
 # Beside 1024 x 1024 float32 score tiles Mosaic's VMEM budget holds operand
 # rows of up to this many bytes (compiled for a described v5e, PR 24:
@@ -677,7 +671,6 @@ def _default_tile(tuned, T, row_bytes):
 def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
           causal=False):
     """Shared block-size/padding/bias plumbing for the public wrappers."""
-    import os
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     if sm_scale is None:
@@ -696,13 +689,11 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
     tuned_bq, tuned_bk = _TUNED_BQ_BK[bool(causal)]
     whole_q, whole_k = _TUNED_BQ_BK[False]
     row_bytes = D * operands.itemsize
-    env_q = os.environ.get('PADDLE_TPU_FLASH_BQ')
-    env_k = os.environ.get('PADDLE_TPU_FLASH_BK')
-    forced = any(x is not None for x in (block_q, block_k, env_q, env_k))
+    forced = block_q is not None or block_k is not None
     if block_q is None:
-        block_q = int(env_q or _default_tile(tuned_bq, Tq, row_bytes))
+        block_q = _default_tile(tuned_bq, Tq, row_bytes)
     if block_k is None:
-        block_k = int(env_k or _default_tile(tuned_bk, Tk, row_bytes))
+        block_k = _default_tile(tuned_bk, Tk, row_bytes)
     if key_bias is None:
         key_bias = jnp.zeros((B, Tk), jnp.float32)
     else:
@@ -758,8 +749,7 @@ def flash_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,
               treated as a non-differentiable mask.
     causal:   lower-triangular masking (decoder self-attention).
     block_q/block_k: kernel tile sizes (defaults from the _TUNED_BQ_BK
-              table, overridable with PADDLE_TPU_FLASH_BQ /
-              PADDLE_TPU_FLASH_BK; see tools/tune_flash.py for the sweep).
+              table; tools/tune_flash.py sweeps them).
     interpret: required. False compiles through Mosaic (TPU only); True
               runs the kernel bodies under the pallas interpreter.
     Returns [B, H, Tq, D] in q's dtype; differentiable w.r.t. q/k/v.

@@ -1,6 +1,6 @@
 """Where the persistent XLA compilation cache lives (docs/perf.md).
 
-One resolver for every caller (Executor, bench.py, tools/serve_bench.py,
+One resolver for every caller (Executor, tools/serve_bench.py,
 chip_smoke.py): the directory is `JAX_COMPILATION_CACHE_DIR` when the
 environment sets it, else `<checkout>/.jax_cache`. Never a temporary
 name, a pid or a timestamp — a second process can only hit what the

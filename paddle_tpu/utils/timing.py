@@ -1,4 +1,4 @@
-"""On-chip timing helper shared by bench.py and tools/tune_flash.py.
+"""On-chip timing helper of tools/tune_flash.py.
 
 `block_until_ready` is honest on the chip, but timing one kernel per
 dispatch still measures the host: each call pays a dispatch and a sync

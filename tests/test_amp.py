@@ -1,7 +1,11 @@
 """bf16 mixed precision: numerics stay close to fp32, dtype stays fp32."""
+import re
+
 import numpy as np
+import pytest
 
 import paddle_tpu.fluid as fluid
+import paddle_tpu.ops as tpu_ops
 
 from util import fresh_program
 
@@ -48,3 +52,61 @@ def test_amp_output_dtype_stays_fp32():
         res, = exe.run(main, feed={'x': np.ones((2, 8), 'float32')},
                        fetch_list=[out])
         assert res.dtype == np.float32
+
+
+def _attention(x):
+    q = fluid.layers.reshape(x, [-1, 2, 128, 64])
+    return fluid.layers.fused_attention(q, q, q, causal=True)
+
+
+# op type -> (feed shape, the layer that appends it to the program, the
+# dtype its output has under AMP): the five rules that call
+# lowering.amp_cast, each built the way a model does. Four give their
+# result back in the dtype they were fed; attention gives it in the dtype
+# of its operands, and the output projection that follows takes it so.
+_MXU_OPS = {
+    'mul': ((4, 8), lambda x: fluid.layers.fc(input=x, size=16), 'float32'),
+    'matmul': ((4, 8), lambda x: fluid.layers.matmul(x, x, transpose_y=True),
+               'float32'),
+    'conv2d': ((2, 3, 8, 8), lambda x: fluid.layers.conv2d(
+        x, num_filters=4, filter_size=3), 'float32'),
+    'flash_attention': ((1, 2 * 128 * 64), _attention, 'bfloat16'),
+    'moe_mlp': ((16, 8), lambda x: fluid.layers.moe_mlp(
+        x, num_experts=4, hidden_size=16, act='swish', gated=True, top_k=2,
+        capacity_factor=None, bias_attr=False), 'float32'),
+}
+
+
+@pytest.mark.parametrize('op_type', sorted(_MXU_OPS))
+def test_mxu_ops_take_bf16_operands_only_under_amp(op_type, monkeypatch):
+    """The list of MXU ops is the five amp_cast calls: under
+    decorate_program the lowered step's dot or convolution takes bf16
+    operands and the output has the dtype the table names; without it the
+    step holds no bf16 at all."""
+    # off the TPU the rule takes the dense XLA chain, which upcasts what it
+    # is handed; the kernel's own dots are what a cell runs, so this test
+    # gives the rule the interpreted kernel in the chain's place
+    monkeypatch.setattr(
+        tpu_ops, 'reference_attention',
+        lambda *a, **kw: tpu_ops.flash_attention(*a, interpret=True, **kw))
+    shape, layer, amp_dtype = _MXU_OPS[op_type]
+    xs = np.random.RandomState(5).rand(*shape).astype('float32')
+    hlo = {}
+    for amp in (False, True):
+        with fresh_program() as (main, startup):
+            x = fluid.layers.data(name='x', shape=list(shape[1:]),
+                                  dtype='float32')
+            out = layer(x)
+            assert op_type in [op.type for op in main.global_block().ops]
+            assert out.dtype == 'float32'
+            if amp:
+                fluid.amp.decorate_program(main)
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(startup)
+            res, = exe.run(main, feed={'x': xs}, fetch_list=[out])
+            assert res.dtype.name == (amp_dtype if amp else 'float32')
+            hlo[amp] = exe.lowered_hlo(main, {'x': xs}, [out])
+    mxu = re.compile(r'stablehlo\.(dot_general|convolution).*: '
+                     r'\(tensor<[^>]*xbf16>, tensor<[^>]*xbf16>\)')
+    assert 'bf16' not in hlo[False]
+    assert [l for l in hlo[True].splitlines() if mxu.search(l)], hlo[True]

@@ -85,7 +85,7 @@ def test_one_file_points_jax_at_a_cache_directory():
             setters += [os.path.join(d, f) for f in files
                         if f.endswith(('.py', '.sh'))]
     setters += [os.path.join(REPO, f)
-                for f in ('bench.py', 'chip_smoke.py', '__graft_entry__.py')]
+                for f in ('chip_smoke.py', '__graft_entry__.py')]
     hits = [os.path.relpath(p, REPO) for p in setters
             if re.search(r'jax_compilation_cache_dir|set_cache_dir',
                          open(p).read())]

@@ -179,6 +179,30 @@ def test_sharded_sparse_matches_replicated_dense_sgd():
     assert stats['hits'] == len(batches) - 1
 
 
+def test_sharded_sparse_step_temp_does_not_grow_with_the_vocab():
+    """The footprint side of the same A/B, from XLA's memory analysis of
+    the exact cached step: the dense-replicated step's temporaries carry
+    the [vocab, DIM] gradient, the sharded-sparse step's only the
+    [rows touched, DIM] blocks, so they are the same bytes at 48 rows and
+    at 4096; and the static rows-touched bound is the feed's 24 ids."""
+    sgd = lambda: fluid.optimizer.SGD(learning_rate=0.1)
+    feed = {'ids': _batches(n=1)[0]}
+    temp = {}
+    for vocab in (VOCAB, 4096):
+        for sharded in (False, True):
+            with fresh_program():
+                main, startup, loss = _build(sharded, sharded, sgd,
+                                             vocab=vocab)
+                exe = fluid.Executor(fluid.CPUPlace())
+                exe.run(startup)
+                temp[vocab, sharded] = exe.compiled_memory_stats(
+                    main, feed, [loss]).temp_size_in_bytes
+                assert exe.embed_rows_per_step(main, feed, [loss]) == (
+                    6 * 4 if sharded else 0)
+    assert temp[4096, False] >= 4096 * DIM * 4
+    assert temp[4096, True] == temp[VOCAB, True] < temp[VOCAB, False]
+
+
 def test_sharded_sparse_matches_unsharded_sparse_adagrad_and_adam():
     """Nonlinear updates see each touched row once (merged duplicates) —
     per shard — and trajectories match the single-device SPARSE path
@@ -257,8 +281,8 @@ def test_untileable_vocab_falls_back_dense_with_warning():
 def test_trained_deepfm_sharded_matches_unsharded():
     """2-step trained deepfm (both FM tables sharded-sparse, adam) vs the
     same model single-device sparse: the model the subsystem exists for.
-    Small config — the 1e6-vocab footprint proof lives in bench.py
-    --phase embedding."""
+    Small config; the footprint is held by
+    test_sharded_sparse_step_temp_does_not_grow_with_the_vocab."""
     from paddle_tpu.models.deepfm import deepfm
 
     def run(dist):

@@ -656,13 +656,10 @@ def test_one_pass_equals_two_passes(dtype, causal):
                                        block_k=512), 2),
     ('cross_lengths_two_key_tiles', dict(T=256, Tk=2048), 2),
 ], ids=lambda x: x if isinstance(x, str) else None)
-def test_backward_routing_reads_the_shapes(case, kw, bwd_calls, monkeypatch):
+def test_backward_routing_reads_the_shapes(case, kw, bwd_calls):
     """One pallas_call in the backward where a head's scores are one tile
     (the forward's, or the table's largest when nobody forced a tile), two
     otherwise; the counter says the same, once per call per lowering."""
-    monkeypatch.delenv('PADDLE_TPU_FLASH_BQ', raising=False)
-    monkeypatch.delenv('PADDLE_TPU_FLASH_BK', raising=False)
-
     def trace():
         return [name for name, _ in _kernel_bodies(
             jnp.bfloat16, kw.get('causal', False), kw.get('block_q'),
@@ -675,9 +672,8 @@ def test_backward_routing_reads_the_shapes(case, kw, bwd_calls, monkeypatch):
     assert ('_bwd_fused_kernel' in names) == (bwd_calls == 1)
     want = {'one': int(bwd_calls == 1), 'two': int(bwd_calls == 2)}
     assert {p: after[p] - before[p] for p in want} == want
-    if case == 'one_tile':      # the environment's tiles are forced tiles
-        monkeypatch.setenv('PADDLE_TPU_FLASH_BQ', '128')
-        assert len(trace()) == 3
+    if case == 'one_tile':      # a tile the caller passes is a forced tile
+        assert len(_kernel_bodies(jnp.bfloat16, False, 128, None, 256)) == 3
 
 
 def test_flash_backward_counts_once_per_call_per_lowering():

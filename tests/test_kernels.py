@@ -324,6 +324,50 @@ def test_signature_in_executor_cache_key():
         assert exe.cache_stats['misses'] == m0 + 1   # flip back: cache hit
 
 
+def test_decode_engine_paged_attention_kernel_on_parity():
+    """The third kernel through ITS caller: the paged continuous-batching
+    decoder over the same requests with `paged_attention` off and on, a
+    fresh engine a leg. The kernel dispatches at trace time, neither leg
+    compiles after warmup(), and the beams' scores agree to the kernel's
+    documented tolerance (token ids may flip only at a near-tie, and none
+    does at this seed)."""
+    from paddle_tpu import obs
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine
+    V, E, D, H, K, SRC, MAX_LEN, SLOTS, PAGE = 24, 8, 16, 8, 3, 6, 8, 2, 3
+    rng = np.random.RandomState(0)
+    shapes = {'w_dec': (E + D, 4 * H), 'u_dec': (H, 4 * H),
+              'b_dec': (1, 4 * H), 'w_q': (H, D), 'w_emb': (V, E),
+              'w_out': (H, V), 'b_out': (1, V)}
+    weights = {n: (rng.randn(*s) * 0.3).astype(np.float32)
+               for n, s in shapes.items()}
+    encs = [(rng.randn(rng.randint(2, SRC + 1), D) * 0.5).astype(np.float32)
+            for _ in range(4)]
+    pages = SLOTS * (-(-MAX_LEN // PAGE) + -(-SRC // PAGE))
+
+    def leg(spec):
+        kernels.configure(spec)
+        eng = DecodeEngine(weights, DecodeConfig(
+            slots=SLOTS, beam_size=K, max_len=MAX_LEN, src_cap=SRC,
+            page_size=PAGE, pages=pages))
+        try:
+            eng.warmup()
+            misses = eng.cache_stats()['misses']
+            out = [f.result(300) for f in
+                   [eng.submit({'enc': e}) for e in encs]]
+            return out, eng.cache_stats()['misses'] - misses
+        finally:
+            eng.shutdown()
+
+    off, steady_off = leg(False)
+    before = obs.counter('kernels.paged_attention.dispatch').value
+    on, steady_on = leg('paged_attention')
+    assert obs.counter('kernels.paged_attention.dispatch').value > before
+    assert steady_off == steady_on == 0
+    for (tok_on, sc_on), (tok_off, sc_off) in zip(on, off):
+        np.testing.assert_allclose(sc_on, sc_off, rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(tok_on, tok_off)
+
+
 # ---------------------------------------------------------------------------
 # quant IR pass: QDQ pipeline form + offline weight quantization
 # ---------------------------------------------------------------------------

@@ -14,8 +14,7 @@ Covers the observability PR's acceptance criteria:
   - profiler satellites: stop_profiler warns on an unwritable
     profile_path, cuda_profiler routes output_file, the context manager
     stops on exceptions;
-  - obs_report --check exits nonzero on malformed records;
-  - bench.py mirrors its metric lines into the same JSONL schema.
+  - obs_report --check exits nonzero on malformed records.
 """
 import json
 import os
@@ -187,8 +186,8 @@ def test_unwritable_obs_dir_warns_once_never_raises(tmp_path):
 
 
 def test_pinned_run_file_env(tmp_path, monkeypatch):
-    """PADDLE_TPU_OBS_RUN_FILE pins the exact run-log path (how
-    perf_sweep.sh collects a whole sweep into one file), and a second
+    """PADDLE_TPU_OBS_RUN_FILE pins the exact run-log path (how a driver
+    script collects its children's events into one file), and a second
     writer appends without re-stamping run_start."""
     pinned = tmp_path / 'obs' / 'run-pinned.jsonl'
     monkeypatch.setenv('PADDLE_TPU_OBS_DIR', str(tmp_path / 'obs'))
@@ -657,37 +656,6 @@ def test_runlog_ring_buffer_bounds_file_and_counts_drops(tmp_path,
     # the surviving tail still validates against the schema
     events, errors = obs_report_mod.load_events(path)
     assert errors == [], errors
-
-
-# ---------------------------------------------------------------------------
-# bench mirrors its metrics into the same schema
-# ---------------------------------------------------------------------------
-
-def test_bench_emit_mirrors_into_run_log(tmp_path, monkeypatch, capsys):
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        '_bench_under_test', os.path.join(REPO, 'bench.py'))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    d = str(tmp_path / 'obs')
-    monkeypatch.setenv('PADDLE_TPU_OBS_DIR', d)
-    obs._reset()            # follow the env again
-    try:
-        bench._emit({'metric': 'unit.test.metric', 'value': 12.5,
-                     'unit': 'widgets/sec', 'metrics': [{'nested': 1}]})
-        bench._emit({'metric': 'relayed', 'value': 1}, mirror=False)
-    finally:
-        capsys.readouterr()
-        obs._reset()
-    runs = [f for f in os.listdir(d) if f.endswith('.jsonl')]
-    assert len(runs) == 1
-    events, errors = obs_report_mod.load_events(os.path.join(d, runs[0]))
-    assert errors == []
-    bench_evs = [e for e in events if e['name'] == 'bench.metric']
-    assert len(bench_evs) == 1          # the relayed line is NOT re-logged
-    f = bench_evs[0]['fields']
-    assert f['metric'] == 'unit.test.metric' and f['value'] == 12.5
-    assert 'metrics' not in f           # the nested trajectory stays out
 
 
 # ---------------------------------------------------------------------------

@@ -1,13 +1,12 @@
 """Optimizer-pass tier (docs/passes.md).
 
-Per-pass unit drills (DCE, constant folding, CSE, the AMP IR rewrite,
-the donation/memory plan), the PADDLE_TPU_OPT executor wiring
-(once-per-cache-key, key separation, crash fallback), and the A/B
-equivalence contract: `PADDLE_TPU_OPT=default` must be FETCH-EQUIVALENT
-to `off` — bit-exact for DCE/CSE/folding (RNG streams included: op
-removal must not shift another op's dropout mask), within one bf16
-rounding per rewritten op for the AMP pass — across the program-fuzz
-generator and the book models.
+Per-pass unit drills (DCE, constant folding, CSE, the donation/memory
+plan), the PADDLE_TPU_OPT executor wiring (once-per-cache-key, key
+separation, crash fallback), and the A/B equivalence contract:
+`PADDLE_TPU_OPT=default` must be FETCH-EQUIVALENT to `off` — bit-exact,
+AMP or not (RNG streams included: op removal must not shift another
+op's dropout mask) — across the program-fuzz generator and the book
+models.
 """
 import contextlib
 import os
@@ -190,6 +189,29 @@ def test_fold_skips_rng_and_respects_cap():
         assert report2.passes['fold']['ops_folded'] == 1   # big one folds
 
 
+def test_fold_leaves_the_rules_that_cast_under_amp():
+    """Folding evaluates a rule with amp off, so a rule that calls
+    `amp_cast` is not folded: a product of two constants in an AMP program
+    stays an op of the step, which runs it in bf16 either way."""
+    with fresh_program() as (main, startup):
+        x = layers.data(name='x', shape=[4], dtype='float32')
+        c = layers.fill_constant(shape=[4, 4], dtype='float32', value=1.1)
+        cc = layers.matmul(c, layers.scale(c, scale=3.0))   # scale folds
+        out = layers.elementwise_add(x, cc)
+        fluid.amp.decorate_program(main)
+        opt, report = passes.optimize(main, fetches=[out.name])
+        assert report.passes['fold']['ops_folded'] == 1
+        types = [op.type for op in opt.global_block().ops]
+        assert 'matmul' in types and 'scale' not in types
+        assert opt._amp
+        feed = {'x': np.ones((4, 4), 'float32')}
+        a = _run_arm(main, startup, feed, [out], 'off', n=1)
+        b = _run_arm(main, startup, feed, [out], 'default', n=1)
+    np.testing.assert_array_equal(a, b)
+    # 1.1 is not a bf16 number: the product did run in bf16
+    assert not np.allclose(a[0], 1.0 + 4 * 1.1 * 3.3, rtol=1e-6)
+
+
 # ------------------------------------------------------------- unit: cse
 
 def test_cse_merges_duplicates_not_rng():
@@ -278,29 +300,6 @@ def test_cse_sees_undeclared_sub_block_writes():
         assert types.count('scale') == 2
 
 
-def test_amp_cast_cache_sees_undeclared_sub_block_writes():
-    """The AMP rewrite's cast cache has the same rule: an undeclared
-    sub-block write to an f32 operand between two rewritten ops must
-    invalidate the cached bf16 cast, so each matmul casts the value it
-    actually reads."""
-    with fresh_program() as (main, _):
-        x = layers.data(name='x', shape=[4], dtype='float32')
-        w = layers.create_global_var(shape=[4, 4], value=1.0,
-                                     dtype='float32', persistable=True,
-                                     name='amp_w@sbw')
-        a = layers.matmul(x, w)
-        _append_undeclared_write_loop(main, w)
-        b = layers.matmul(x, w)
-        out = layers.elementwise_add(a, b)
-        fluid.amp.decorate_program(main)
-        opt, report = passes.optimize(main, fetches=[out.name])
-        casts_of_w = [op for op in opt.global_block().ops
-                      if op.type == 'cast'
-                      and op.input_arg_names == ['amp_w@sbw']]
-        assert len(casts_of_w) == 2, \
-            'second matmul must re-cast w after the sub-block write'
-
-
 def test_cse_skips_fetched_and_persistable_outputs():
     with fresh_program() as (main, startup):
         x = layers.data(name='x', shape=[8], dtype='float32')
@@ -311,40 +310,6 @@ def test_cse_skips_fetched_and_persistable_outputs():
         assert report.passes['cse']['ops_merged'] == 0
         types = [op.type for op in opt.global_block().ops]
         assert types.count('tanh') == 2
-
-
-# ------------------------------------------------------------- unit: amp
-
-def test_amp_rewrite_inserts_visible_casts():
-    with fresh_program() as (main, startup):
-        x = layers.data(name='x', shape=[8], dtype='float32')
-        y = layers.data(name='y', shape=[1], dtype='float32')
-        h = layers.fc(input=x, size=16, act='relu')
-        pred = layers.fc(input=h, size=1)
-        cost = layers.mean(layers.square_error_cost(input=pred, label=y))
-        fluid.optimizer.SGD(learning_rate=0.05).minimize(cost)
-        fluid.amp.decorate_program(main)
-        opt, report = passes.optimize(main, fetches=[cost.name])
-        assert report.passes['amp']['ops_rewritten'] >= 2   # the two muls
-        assert report.passes['amp']['casts_inserted'] >= 4
-        assert getattr(opt, '_amp_ir', False) and not opt._amp
-        casts = [op for op in opt.global_block().ops if op.type == 'cast']
-        assert casts, 'bf16 boundaries must be visible cast ops'
-        # bf16 boundaries visible to ANALYSIS too: declared dtypes of the
-        # cast temps are bfloat16 and the optimized program still
-        # verifies (shape pass runs the same rules)
-        bf16 = [v for v in opt.list_vars() if v.dtype == 'bfloat16']
-        assert bf16
-        assert analysis.analyze(opt, fetches=[cost.name],
-                                dead_ops=False) == []
-
-        feed = {'x': np.random.RandomState(0).rand(4, 8).astype('float32'),
-                'y': np.random.RandomState(1).rand(4, 1).astype('float32')}
-        a = _run_arm(main, startup, feed, [cost], 'off')
-        b = _run_arm(main, startup, feed, [cost], 'default')
-        # documented tolerance: one extra bf16 rounding per rewritten op
-        np.testing.assert_allclose(np.asarray(a).ravel(),
-                                   np.asarray(b).ravel(), rtol=2e-2)
 
 
 # ----------------------------------------------------- donation/memory plan
@@ -618,21 +583,26 @@ _SWEEP = {
 def _sweep_params():
     from paddle_tpu import models
     assert set(_SWEEP) == set(models.model_list)
-    return [pytest.param(n, marks=pytest.mark.slow)
-            if _SWEEP[n].get('slow') else n for n in models.model_list]
+    return [pytest.param(n, False, id=n, marks=pytest.mark.slow
+                         if _SWEEP[n].get('slow') else ())
+            for n in models.model_list] + [
+        pytest.param('transformer', True, id='transformer-amp')]
 
 
-@pytest.mark.parametrize('name', _sweep_params())
-def test_book_model_off_vs_default_equivalent(name):
+@pytest.mark.parametrize('name,amp', _sweep_params())
+def test_book_model_off_vs_default_equivalent(name, amp):
     """Acceptance: PADDLE_TPU_OPT=default is fetch-equivalent to off on
-    every book model — bit-exact (none of them use AMP), across two
-    training steps including every dropout mask and optimizer update."""
+    every book model — bit-exact, the AMP-decorated Transformer included
+    (bf16 is cast by the rules on both arms), across two training steps
+    including every dropout mask and optimizer update."""
     from paddle_tpu import models
     mod = models.get_model_module(name)
     spec = _SWEEP[name]
     with fresh_program() as (main, startup):
         ret = mod.get_model(**spec.get('kwargs', {}))
         cost = ret[0]
+        if amp:
+            fluid.amp.decorate_program(main)
         reader = ret[spec.get('reader_idx', 2)]
         feeds = spec.get('feeds') or ret[spec['feeds_idx']]
         batch = next(iter(reader()))

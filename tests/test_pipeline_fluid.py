@@ -373,8 +373,8 @@ def test_pipeline_rejects_dtype_changing_region():
         blk = main.global_block()
         s0 = blk.create_var(name='s0_outb', shape=[-1, D], dtype='bfloat16')
         s1 = blk.create_var(name='s1_outb', shape=[-1, D], dtype='bfloat16')
-        # infer_shape=False keeps the declared bf16 outputs (the dtype
-        # mismatch an AMP pass would introduce at the region boundary)
+        # infer_shape=False keeps the declared bf16 outputs (a dtype
+        # mismatch at the region boundary)
         with fluid.device_guard('pipe:0'):
             blk.append_op(type='scale', inputs={'X': [h]},
                           outputs={'Out': [s0]}, attrs={'scale': 2.0},

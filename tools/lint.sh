@@ -5,8 +5,8 @@
 #      (undefined names / redefinitions are fatal; unused-import noise is
 #      filtered — the tree uses bare "# noqa" markers pyflakes ignores);
 #   3. exports the mnist inference artifact and runs tools/program_lint.py
-#      over it — the program verifier linting a real saved __model__, the
-#      way perf_sweep.sh benches a real model. Both artifact lints run
+#      over it — the program verifier linting a real saved __model__.
+#      Both artifact lints run
 #      with --cost --hbm-budget, so a per-device residency regression
 #      past the budget fails the script (HbmOverBudget exits 1).
 #
@@ -15,7 +15,7 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 echo "== lint: compileall =="
-python -m compileall -q paddle_tpu tools tests bench.py
+python -m compileall -q paddle_tpu tools tests chipbench chip_smoke.py
 
 echo "== lint: pyflakes =="
 if python -c 'import pyflakes' 2>/dev/null; then

@@ -6,7 +6,6 @@
     python tools/obs_report.py OBS_DIR --merge    # every run file in a dir
     python tools/obs_report.py RUN.jsonl --check  # validate; rc=2 on bad records
     python tools/obs_report.py --emit NAME k=v... # append one event record
-                                                  # (used by tools/perf_sweep.sh)
 
 Prints p50/p95/max step time, the compile-vs-step split per cache key, the
 compile-cache hit ratio, anomaly-guard skips, retry/reader-degrade events,

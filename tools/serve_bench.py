@@ -18,8 +18,8 @@ regression), saves it, then drives it two ways:
 
 Reports p50/p99 latency and throughput for both as JSON lines on stdout
 and — when PADDLE_TPU_OBS_DIR is set — as `bench.metric` events in the
-structured run log (one schema with bench.py; `tools/obs_report.py`
-summarizes a serving run, docs/serving.md). Also verifies the warmup
+structured run log (`tools/obs_report.py` summarizes a serving run,
+docs/serving.md). Also verifies the warmup
 contract: after `warmup()` the steady-state phase must perform ZERO XLA
 compiles (`serve.steady_compiles` in the output; rc=1 with
 --check-compiles if any happened).
@@ -34,7 +34,7 @@ reporting TTFT and per-token latency p50/p99
 plus tokens/sec for both (acceptance: >= 1.5x tokens/sec with zero
 steady-state compiles; `--check-speedup 1.5 --check-compiles` enforces
 it). Every record is stamped with the device jax reported (platform,
-device_kind, device_count), the bench.py convention.
+device_kind, device_count).
 
 `--workload decode-paged` is the PAGED-CAPACITY A/B (dense-slot vs
 paged-memory engine at EQUAL state-buffer bytes: peak concurrent
@@ -45,8 +45,7 @@ rate; `--check-speedup` enforces the win) — docs/serving.md "Paged +
 speculative benchmarking" has the design and the CPU-box numbers.
 
 CPU-safe: run under JAX_PLATFORMS=cpu for a functional check; numbers
-only mean something on the real accelerator (tools/perf_sweep.sh wires
-this in behind SERVE=1, the decode workload behind DECODE=1).
+only mean something on the real accelerator.
 """
 import argparse
 import json
@@ -63,8 +62,8 @@ sys.path.insert(0, _REPO)
 
 
 # What jax reports for the device the workload runs on — platform,
-# device_kind, device_count — stamped into EVERY emitted record (the
-# bench.py convention). Taken in-process by _resolve_device(), which
+# device_kind, device_count — stamped into EVERY emitted record. Taken
+# in-process by _resolve_device(), which
 # initializes the backend and therefore HOLDS THE CHIP: a workload whose
 # children need the chip (aot-cold) must not call it, and takes the stamp
 # from its children's output instead.

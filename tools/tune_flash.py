@@ -2,8 +2,8 @@
 
 Times forward+backward through the pallas kernel at Transformer-base-like
 shapes for each (block_q, block_k) candidate and prints a ranked table plus
-the winning env setting (PADDLE_TPU_FLASH_BQ/BK consumed by
-paddle_tpu.ops.flash_attention). Run on TPU:
+the winning tiles as the `_TUNED_BQ_BK` entry of
+paddle_tpu/ops/flash_attention.py to edit. Run on TPU:
 
     python tools/tune_flash.py [--seq 256] [--batch 64] [--heads 8] [--dim 64]
 
@@ -146,9 +146,9 @@ def main():
         raise SystemExit('no candidate compiled')
     results.sort()
     dt, bq, bk = results[0]
-    print('\nbest: PADDLE_TPU_FLASH_BQ=%d PADDLE_TPU_FLASH_BK=%d '
+    print('\nbest: _TUNED_BQ_BK[%r] = (%d, %d) '
           '(%.3f ms/step fwd+bwd @ B%d H%d T%d D%d)'
-          % (bq, bk, dt * 1e3, B, H, T, D))
+          % (bool(args.causal), bq, bk, dt * 1e3, B, H, T, D))
 
 
 if __name__ == '__main__':
