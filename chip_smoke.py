@@ -141,15 +141,22 @@ def train_leg(place, cfg=BASE, steps=20, expect_kernel=True):
         return ({d: obs.counter('flash.lowered', operands=d).value
                  for d in ('bfloat16', 'float32')},
                 {p: obs.counter('flash.backward', passes=p).value
-                 for p in ('one', 'two')})
+                 for p in ('one', 'two')},
+                {k: obs.counter('xent.lowered', label=k).value
+                 for k in ('hard', 'soft')})
 
     before = lowered()
     losses, first_s, later_s = _run_steps(exe, main, feed, avg_cost, steps)
-    flash_lowered, flash_backward = (
+    flash_lowered, flash_backward, xent_lowered = (
         {key: int(n - was[key]) for key, n in now.items()}
         for was, now in zip(before, lowered()))
     if not losses[-1] < losses[0]:
         raise AssertionError('loss did not fall: %r' % (losses,))
+    if xent_lowered != {'hard': 0, 'soft': 1}:
+        raise AssertionError(
+            'the label-smoothed head did not lower through the closed-form '
+            'cross-entropy rule once: xent.lowered counted %r'
+            % (xent_lowered,))
     hlo = exe.lowered_hlo(main, feed, [avg_cost])
     n_calls = hlo.count('tpu_custom_call')
     if expect_kernel:
@@ -173,14 +180,15 @@ def train_leg(place, cfg=BASE, steps=20, expect_kernel=True):
     n_params = _params(main)
     log('train: params %.1fM, batch %dx%d, first step %.1fs, then %.3fs/step'
         ', loss %.4f -> %.4f, tpu_custom_call x%d, flash.lowered %r'
-        ', flash.backward %r'
+        ', flash.backward %r, xent.lowered %r'
         % (n_params / 1e6, cfg['batch'], cfg['seq'], first_s, later_s,
-           losses[0], losses[-1], n_calls, flash_lowered, flash_backward))
+           losses[0], losses[-1], n_calls, flash_lowered, flash_backward,
+           xent_lowered))
     return {'params': n_params, 'steps': steps,
             'first_step_seconds': round(first_s, 2),
             'first_loss': losses[0], 'last_loss': losses[-1],
             'tpu_custom_calls': n_calls, 'flash_lowered': flash_lowered,
-            'flash_backward': flash_backward,
+            'flash_backward': flash_backward, 'xent_lowered': xent_lowered,
             'online_compiles': stats['online_compiles'],
             'persistent_hits': stats['persistent_hits'],
             'cache_dir': stats['compile_cache_dir']}

@@ -5,6 +5,8 @@ dropout,softmax,cross_entropy,accuracy,auc,lrn,prelu,interpolate,...}_op.* —
 cuDNN descriptors replaced by lax.conv_general_dilated / reduce_window, which
 XLA tiles directly onto the TPU MXU.
 """
+import functools
+
 import numpy as np
 
 import jax
@@ -315,20 +317,78 @@ def _cross_entropy(ins, attrs, ctx):
     return {'Y': like(ins['X'][0], loss)}
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _xent(x, label, soft):
+    """-sum_v label * log_softmax(x) in closed form, with its own backward:
+    the row maximum, then ONE pass over the logits whose sibling reductions
+    are sum exp(x - m) and, for soft labels, sum label * (x - m) and
+    sum label (a hard label's term is gathered from the logits). No
+    [N, V] array but the logits is a result of the forward, and the
+    backward holds no reduction: `log_softmax` followed by a pick writes
+    the whole log-probability array to pick one number a row, and its
+    gradient walks the logits once more for a sum that is the label row's
+    (PERF.md, PR 29)."""
+    return _xent_fwd(x, label, soft)[0]
+
+
+def _xent_fwd(x, label, soft):
+    m = jnp.max(x, axis=-1, keepdims=True)
+    z = x - m
+    s = jnp.sum(jnp.exp(z), axis=-1, keepdims=True)
+    if soft:
+        # computed, not assumed 1: rows that do not sum to 1 keep the loss
+        # and the gradient log_softmax gave them
+        w = jnp.sum(label, axis=-1, keepdims=True)
+        loss = w * jnp.log(s) - jnp.sum(label * z, axis=-1, keepdims=True)
+    else:
+        w = None
+        loss = jnp.log(s) - (
+            jnp.take_along_axis(x, label[..., None], axis=-1) - m)
+    return loss, (x, label, m, s, w)
+
+
+def _xent_bwd(soft, res, g):
+    """dx = g * (softmax(x) * w - label), what a row shares folded into
+    [N] vectors first. The projection's two backward matmuls take an
+    elementwise dx into their operand fusions and rebuild it there, exp
+    and all, once per output tile. Soft labels leave it so: that is one
+    walk over the logits fewer than `log_softmax` made, whatever the
+    projection's width. Hard labels write dx once, which moves no more
+    bytes than the old rule's log-probability array did: at OLMoE's width
+    (2048) the matmuls then run 7 ms a step faster than rebuilding it, at
+    Transformer-base's (512) they would not (chip, PR 29; PERF.md has
+    both sides, and why the width is not this rule's to see)."""
+    x, label, m, s, w = res
+    z = x - m
+    if soft:
+        dx = jnp.exp(z) * (g * w / s) - g * label
+        dlabel = -g * (z - jnp.log(s))
+        return dx.astype(x.dtype), dlabel.astype(label.dtype)
+    # the gather's own convention: a negative id counts from the end
+    y = jnp.where(label < 0, label + x.shape[-1], label)[..., None]
+    hit = lax.broadcasted_iota(y.dtype, x.shape, x.ndim - 1) == y
+    dx = jnp.exp(z) * (g / s) - jnp.where(hit, g, 0)
+    return lax.optimization_barrier(dx), None
+
+
+_xent.defvjp(_xent_fwd, _xent_bwd)
+
+
 @register('softmax_with_cross_entropy')
 def _softmax_with_cross_entropy(ins, attrs, ctx):
     logits = data_of(ins['Logits'][0])
     label = data_of(ins['Label'][0])
+    soft = bool(attrs.get('soft_label', False))
+    # trace time: once per op per lowering, never per step
+    obs.counter('xent.lowered', label='soft' if soft else 'hard').inc()
+    if not soft:
+        label = label.astype(jnp.int32)
+        if label.ndim == logits.ndim:
+            label = jnp.squeeze(label, -1)
+    # dead unless fetched: XLA drops it
     sm = jax.nn.softmax(logits, axis=-1)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    if attrs.get('soft_label', False):
-        loss = -jnp.sum(label * logp, axis=-1, keepdims=True)
-    else:
-        li = label.astype(jnp.int32)
-        if li.ndim == logits.ndim:
-            li = jnp.squeeze(li, -1)
-        loss = -jnp.take_along_axis(logp, li[..., None], axis=-1)
-    return {'Softmax': sm, 'Loss': like(ins['Logits'][0], loss)}
+    return {'Softmax': sm,
+            'Loss': like(ins['Logits'][0], _xent(logits, label, soft))}
 
 
 @register('sigmoid_cross_entropy_with_logits')
