@@ -81,7 +81,10 @@ def op_collectives(op, program, axes):
             return []
         if op.attrs.get('dropless'):
             # one device only: nothing over the wire (on a mesh that
-            # would shard the experts the rule refuses to lower)
+            # would shard the experts the rule refuses to lower). A held
+            # share (`experts_held`) is dropless too: it computes its own
+            # experts' part and runs WITHOUT the exchange that would sum
+            # the shares on a pod, so it moves nothing either
             return []
         if 'dp' in axes and n_exp and n_exp % axes['dp'] == 0:
             # dispatch + combine

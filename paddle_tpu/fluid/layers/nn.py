@@ -31,7 +31,8 @@ __all__ = [
     'image_resize_short', 'resize_bilinear', 'gather', 'scatter', 'expand',
     'random_crop', 'mean_iou', 'relu', 'log', 'crop', 'rank_loss', 'prelu',
     'flatten', 'sequence_mask', 'stack', 'fused_attention', 'rms_norm',
-    'rotary_embedding',
+    'rotary_embedding', 'gated_delta_rule', 'causal_conv1d',
+    'gated_rms_norm',
 ]
 
 
@@ -675,18 +676,106 @@ def rms_norm(input, epsilon=1e-05, param_attr=None, name=None):
     return out
 
 
-def rotary_embedding(x, base=10000.0, name=None):
+def gated_rms_norm(input, gate, epsilon=1e-05, param_attr=None, name=None):
+    """RMS norm over the last axis times a SiLU gate of the same shape:
+    ``scale * x * rsqrt(mean(x^2) + epsilon) * silu(gate)``, `scale` as
+    layers.rms_norm's. One Program op whose backward keeps `input` and
+    `gate` and recomputes the rest (layers.rms_norm, layers.swish and a
+    multiply keep three more arrays of the same size). TPU extension."""
+    helper = LayerHelper('gated_rms_norm', **locals())
+    dtype = helper.input_dtype()
+    scale = helper.create_parameter(attr=helper.param_attr,
+                                    shape=[int(input.shape[-1])],
+                                    dtype=dtype,
+                                    default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(dtype)
+    helper.append_op(type='gated_rms_norm',
+                     inputs={'X': [input], 'Gate': [gate],
+                             'Scale': [scale]},
+                     outputs={'Y': [out]},
+                     attrs={'epsilon': float(epsilon)})
+    return out
+
+
+def rotary_embedding(x, base=10000.0, rotary_dim=None, name=None):
     """Rotary position embedding of heads ``x`` [B, H, T, D] at positions
     0..T-1: element i of a head is rotated with element i + D/2 (the
-    rotate-half pairing) by the angle ``t * base**(-2i/D)``. No parameter.
-    One Program op. TPU extension (the reference predates it)."""
-    if int(x.shape[-1]) % 2:
-        raise ValueError('rotary_embedding needs an even head width, got %r'
-                         % (x.shape[-1],))
+    rotate-half pairing) by the angle ``t * base**(-2i/D)``. With
+    `rotary_dim` R < D (a partial rotary factor) only the FIRST R elements
+    of each head turn, paired (i, i + R/2) at ``t * base**(-2i/R)``; the
+    rest pass through. No parameter. One Program op. TPU extension (the
+    reference predates it)."""
+    width = int(x.shape[-1])
+    rotary_dim = width if rotary_dim is None else int(rotary_dim)
+    if rotary_dim % 2 or not 0 < rotary_dim <= width:
+        raise ValueError('rotary_embedding turns an even number of a '
+                         "head's %d elements, got %r" % (width, rotary_dim))
     helper = LayerHelper('rotary_embedding', **locals())
     out = helper.create_variable_for_type_inference(x.dtype)
+    attrs = {'base': float(base)}
+    if rotary_dim != width:
+        attrs['rotary_dim'] = rotary_dim
     helper.append_op(type='rotary_embedding', inputs={'X': [x]},
-                     outputs={'Out': [out]}, attrs={'base': float(base)})
+                     outputs={'Out': [out]}, attrs=attrs)
+    return out
+
+
+def gated_delta_rule(q, k, v, g, beta, chunk_size=64, scale=None,
+                     qk_l2norm=False, name=None):
+    """The gated delta rule, Gated DeltaNet's linear attention (Yang et
+    al. 2024, arXiv:2412.06464), in ONE op. Per head a [Dk, Dv] float32
+    state S, zero at a row's first token, and for each token t
+
+        S = exp(g_t) S;  S = S + k_t (beta_t (v_t - S^T k_t))^T;
+        o_t = S^T q_t.
+
+    q, k: [B, T, Hk, Dk]; v: [B, T, Hv, Dv] with Hk dividing Hv (key head
+    h serves value heads h * Hv/Hk and following); g (<= 0, the log of
+    the decay) and beta (the write strength): [B, T, Hv]. With
+    ``qk_l2norm`` q and k are first divided by their norm over Dk
+    (``x * rsqrt(sum x^2 + 1e-6)``); q is then multiplied by `scale`
+    (default ``Dk ** -0.5``). Returns o [B, T, Hv, Dv].
+
+    Computed in chunks of `chunk_size` tokens (a power of two times 16
+    solves its chunks blockwise; T need not be a multiple): matmuls inside
+    a chunk, a scan carrying S across chunks, its own backward that keeps
+    S at chunk boundaries only (ops_impl/linear_attention_ops.py). No
+    state enters or leaves the op: a row is one stream, with no reset
+    between packed documents. TPU extension (the reference predates it).
+    """
+    if int(v.shape[2]) % int(q.shape[2]) or q.shape[2] != k.shape[2]:
+        raise ValueError('gated_delta_rule: %r key heads do not divide %r '
+                         'value heads' % (q.shape[2], v.shape[2]))
+    helper = LayerHelper('gated_delta_rule', **locals())
+    out = helper.create_variable_for_type_inference(v.dtype)
+    helper.append_op(
+        type='gated_delta_rule',
+        inputs={'Q': [q], 'K': [k], 'V': [v], 'G': [g], 'Beta': [beta]},
+        outputs={'Out': [out]},
+        attrs={'chunk_size': int(chunk_size),
+               'scale': float(scale) if scale is not None else -1.0,
+               'qk_l2norm': bool(qk_l2norm)})
+    return out
+
+
+def causal_conv1d(input, kernel_size, act=None, param_attr=None, name=None):
+    """Depthwise causal convolution along the time axis of ``input``
+    [B, T, C]: ``y[t] = act(sum_j w[j] * x[t - (kernel_size - 1) + j])``
+    per channel, zeros before the first token (left padding), no bias.
+    The filter is a parameter [kernel_size, C]; `act` is None or 'silu'.
+    One Program op. TPU extension (the reference's sequence_conv mixes
+    channels and looks both ways)."""
+    if act not in (None, 'silu', 'swish'):
+        raise ValueError("causal_conv1d act=%r: None or 'silu'" % (act,))
+    helper = LayerHelper('causal_conv1d', **locals())
+    dtype = helper.input_dtype()
+    w = helper.create_parameter(
+        attr=helper.param_attr,
+        shape=[int(kernel_size), int(input.shape[-1])], dtype=dtype)
+    out = helper.create_variable_for_type_inference(dtype)
+    helper.append_op(type='causal_conv1d',
+                     inputs={'X': [input], 'Filter': [w]},
+                     outputs={'Out': [out]}, attrs={'act': act or ''})
     return out
 
 
@@ -897,13 +986,20 @@ def fused_attention(q, k, v, key_bias=None, causal=False, scale=None,
                     name=None):
     """Whole-attention fused op: softmax(q k^T * scale + bias) v in ONE op.
 
-    q/k/v: [B, H, T, D]. key_bias: optional [B, Tk] (or [B,1,1,Tk]) additive
+    q/k/v: [B, H, T, D]; k and v may have FEWER heads than q (grouped
+    key-value heads: H_q a multiple of H_kv, key-value head h serving query
+    heads h * H_q/H_kv and following; the rule repeats them, so the
+    kernels see equal counts). key_bias: optional [B, Tk] (or [B,1,1,Tk]) additive
     bias for padded keys; causal adds lower-triangular masking. On TPU this
     lowers to the pallas flash-attention kernel (paddle_tpu.ops), which
     never materializes the [B,H,Tq,Tk] score matrix in HBM; elsewhere it
     falls back to the XLA chain. Replaces the reference's matmul->softmax->
     matmul op sequence (nets.py scaled_dot_product_attention).
     """
+    h_q, h_kv = int(q.shape[1]), int(k.shape[1])
+    if h_kv != int(v.shape[1]) or h_kv <= 0 or h_q % h_kv:
+        raise ValueError('fused_attention: %d query heads over %d key and '
+                         '%d value heads' % (h_q, h_kv, int(v.shape[1])))
     helper = LayerHelper('fused_attention', **locals())
     out = helper.create_variable_for_type_inference(dtype=q.dtype)
     inputs = {'Q': [q], 'K': [k], 'V': [v]}
@@ -1435,7 +1531,8 @@ def beam_search_decode(ids, scores, beam_size=None, end_id=0, parents=None,
 def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
             capacity_factor=2.0, gate_param_attr=None, param_attr=None,
             bias_attr=None, name=None, top_k=1, return_aux_loss=False,
-            gated=False, norm_topk_prob=True, return_expert_count=False):
+            gated=False, norm_topk_prob=True, return_expert_count=False,
+            experts_held=None):
     """Top-k gated mixture-of-experts FFN (TPU extension; the reference
     predates MoE — its conditional-computation ancestor is layers.Switch).
 
@@ -1463,6 +1560,17 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
     would shard the experts the step raises
     ``paddle_tpu.parallel.moe.DroplessOnMeshError``.
 
+    ``experts_held=(first, count)`` (dropless only): this device's SHARE
+    of an expert-parallel layer. The router, the top-k, the gates'
+    renormalisation, the auxiliary loss and `expert_count` stay over all
+    `num_experts`; the weight stacks are ``[count, ...]`` and hold experts
+    ``first .. first + count - 1``; the output is the part of the layer's
+    sum that those experts give (what the absent experts would add is
+    left out: on the pod the shares are summed by the exchange, which one
+    chip runs without). No assignment to a held expert is dropped at any
+    imbalance, and assignments to absent experts cost no matmul tile.
+    ``None``: every expert is here.
+
     With return_aux_loss=True, also returns the scalar Switch/GShard
     load-balancing auxiliary loss (E * sum_e f_e * P_e, minimized at 1.0
     by a uniform router) to add to the training objective with a small
@@ -1482,6 +1590,16 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
     if not 1 <= int(top_k) <= int(num_experts):
         raise ValueError('moe_mlp top_k=%r must be in [1, num_experts=%d]'
                          % (top_k, num_experts))
+    n_held = int(num_experts)
+    if experts_held is not None:
+        first, n_held = (int(i) for i in experts_held)
+        if capacity_factor is not None:
+            raise ValueError('moe_mlp: experts_held is the dropless '
+                             "layer's; pass capacity_factor=None")
+        if not (0 <= first and 0 < n_held
+                and first + n_held <= int(num_experts)):
+            raise ValueError('moe_mlp experts_held=%r is not a range of '
+                             'the %d experts' % (experts_held, num_experts))
     helper = LayerHelper('moe_mlp', **locals())
     dtype = helper.input_dtype()
     d = int(input.shape[-1])
@@ -1498,10 +1616,10 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
             dtype=dtype, is_bias=False)
 
     inputs = {'X': [input], 'GateW': [gate_w],
-              'W1': [weight([num_experts, d, hidden_size])]}
+              'W1': [weight([n_held, d, hidden_size])]}
     if gated:
-        inputs['W3'] = [weight([num_experts, d, hidden_size])]
-    inputs['W2'] = [weight([num_experts, hidden_size, out_d])]
+        inputs['W3'] = [weight([n_held, d, hidden_size])]
+    inputs['W2'] = [weight([n_held, hidden_size, out_d])]
     if bias_attr is not False:
         if gated:
             raise ValueError('moe_mlp: gated experts have no biases; pass '
@@ -1509,7 +1627,7 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
         for slot, width in (('B1', hidden_size), ('B2', out_d)):
             inputs[slot] = [helper.create_parameter(
                 attr=copy.deepcopy(ParamAttr.to_attr(bias_attr)),
-                shape=[num_experts, width], dtype=dtype, is_bias=True)]
+                shape=[n_held, width], dtype=dtype, is_bias=True)]
     # shapes declared here: inference stands the dynamic batch in by a
     # large prime, and at real widths batch x seq x top_k assignments pass
     # what the dropless path's int32 sort indices hold
@@ -1522,14 +1640,16 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
         count = helper.create_variable_for_type_inference(
             'int32', shape=[int(num_experts)], stop_gradient=True)
         outputs['ExpertCount'] = [count]
-    helper.append_op(
-        type='moe_mlp', inputs=inputs, outputs=outputs,
-        attrs={'num_experts': int(num_experts),
-               'dropless': capacity_factor is None,
-               'capacity_factor': float(capacity_factor or 0.0),
-               'top_k': int(top_k),
-               'norm_topk_prob': bool(norm_topk_prob),
-               'act': act or ''})
+    attrs = {'num_experts': int(num_experts),
+             'dropless': capacity_factor is None,
+             'capacity_factor': float(capacity_factor or 0.0),
+             'top_k': int(top_k),
+             'norm_topk_prob': bool(norm_topk_prob),
+             'act': act or ''}
+    if experts_held is not None:
+        attrs['experts_held'] = [first, n_held]
+    helper.append_op(type='moe_mlp', inputs=inputs, outputs=outputs,
+                     attrs=attrs)
     got = (out,) + ((aux,) if return_aux_loss else ()) \
         + ((count,) if return_expert_count else ())
     return got if len(got) > 1 else out

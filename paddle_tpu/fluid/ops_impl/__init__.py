@@ -15,6 +15,7 @@ from . import detection_ops  # noqa: F401
 from . import crf_ctc_ops  # noqa: F401
 from . import sampled_ops  # noqa: F401
 from . import moe_ops  # noqa: F401
+from . import linear_attention_ops  # noqa: F401
 from . import embedding_ops  # noqa: F401
 from . import extra_ops  # noqa: F401
 from . import quant_ops  # noqa: F401

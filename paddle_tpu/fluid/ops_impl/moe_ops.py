@@ -45,12 +45,29 @@ the router is. One device only: on a mesh whose dp divides num_experts the
 rule raises `DroplessOnMeshError` rather than fall back to a path that
 drops.
 
+A SHARE (`experts_held` = (first, count), dropless only): this device
+holds `count` of the `num_experts` experts, as one of the devices of an
+expert-parallel layer does. The router's logits, the softmax, the top-k,
+the renormalisation of the gates, `AuxLoss` and `ExpertCount` are over all
+`num_experts`; the stacks are [count, ...]. What the op computes is the
+sum, over each token's chosen experts THAT ARE HELD, of gate x expert:
+the part of the layer's output that this device's experts give. What it
+leaves out: the absent experts' part (on the pod the other devices
+compute theirs and the exchange adds them up; one device runs without
+the exchange and nothing stands in for it), so the outputs of all
+num_experts / count shares add up to the whole layer's, and anything
+every device computes alike (a shared expert) is counted once. Still
+dropless: no assignment to a held expert is lost at any imbalance, and
+an assignment to an absent expert costs no matmul tile (`_held_moe`). One
+device, as the whole dropless layer: the same refusal on a mesh.
+
 Inside the op's `moe_mlp_<index>` scope the stages are named `moe_route`
 (logits, top-k, the sort and the gather of rows), `moe_experts` (the
 matmuls) and `moe_combine` (un-sort, gate weights, sum over k). Trace-time
 counters: `moe.lowered{path=grouped|capacity}` once per op per trace of
-the rule (a lowering, or build-time shape inference), `moe.assignments`
-the tokens x k of the traced shape.
+the rule (a lowering, or build-time shape inference; a share adds the
+label `held=<count>of<num_experts>`), `moe.assignments` the tokens x k of
+the traced shape.
 """
 import functools
 
@@ -160,33 +177,175 @@ def _grouped_matmul(rows, w, sizes, ctx):
                           ).astype(rows.dtype)
 
 
-def _dropless_moe(params, x, expert, gate, sizes, act, ctx):
+def _keep(live):
+    """Rows before `live` as they are, zeros after: a select, which stops
+    whatever an unwritten row holds, forward and backward. `live` None:
+    every row is live."""
+    if live is None:
+        return lambda rows: rows
+
+    def keep(rows):
+        row = lax.broadcasted_iota(jnp.int32, (rows.shape[0], 1), 0)
+        return jnp.where(row < live, rows, jnp.zeros((), rows.dtype))
+    return keep
+
+
+def _experts(params, rows, sizes, group, act, ctx, keep):
+    """The experts on their sorted rows: group e's rows by expert e's
+    matrices. `group` [rows] names each row's expert where there are
+    biases."""
+    h = keep(_grouped_matmul(rows, params['w1'], sizes, ctx))
+    if 'b1' in params:
+        h = h + _rows(params['b1'], group)
+    h = _ACTS[act](h.astype(jnp.float32))
+    if 'w3' in params:
+        h = h * keep(_grouped_matmul(rows, params['w3'], sizes,
+                                     ctx)).astype(jnp.float32)
+    out = _grouped_matmul(keep(h.astype(rows.dtype)), params['w2'],
+                          sizes, ctx)
+    if 'b2' in params:
+        out = out + _rows(params['b2'], group)
+    return keep(out)
+
+
+def _dropless_moe(params, x, expert, gate, sizes, act, ctx, live=None):
     """Every one of the nt x k assignments is computed. `expert`, `gate`
     are [nt, k]; `sizes` [E] counts the assignments per expert. `x` is in
-    the experts' dtype; returns float32 [nt, d_out]."""
+    the experts' dtype; returns float32 [nt, d_out].
+
+    A held share (`_held_moe`) passes `live`, the number of sorted rows
+    that belong to a held expert: `sizes` then sums to `live`, the rows
+    after them are the absent experts' tail group, which no matmul tile
+    visits and whose rows a kernel leaves unwritten, so every buffer of
+    rows is set to zero past `live` (`_keep`)."""
     nt, k = expert.shape
+    keep = _keep(live)
+    group = None
     with jax.named_scope('moe_route'):
         flat = expert.reshape(-1)                      # token-major
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
         inv = jnp.argsort(order).astype(jnp.int32)
-        rows = _dispatch(x, order, inv, k)             # [nt * k, d]
+        rows = keep(_dispatch(x, order, inv, k))       # [nt * k, d]
         if 'b1' in params:
             group = _rows(flat, order)
+            if live is not None:
+                # the tail group has no bias row: any row in bounds, masked
+                group = jnp.minimum(group, sizes.shape[0] - 1)
     with jax.named_scope('moe_experts'):
-        h = _grouped_matmul(rows, params['w1'], sizes, ctx)
-        if 'b1' in params:
-            h = h + _rows(params['b1'], group)
-        h = _ACTS[act](h.astype(jnp.float32))
-        if 'w3' in params:
-            h = h * _grouped_matmul(rows, params['w3'], sizes,
-                                    ctx).astype(jnp.float32)
-        out = _grouped_matmul(h.astype(rows.dtype), params['w2'], sizes,
-                              ctx)
-        if 'b2' in params:
-            out = out + _rows(params['b2'], group)
+        out = _experts(params, rows, sizes, group, act, ctx, keep)
     with jax.named_scope('moe_combine'):
         out = _unsort(out, order, inv).reshape(nt, k, out.shape[-1])
         return jnp.sum(out.astype(jnp.float32) * gate[..., None], axis=1)
+
+
+def _compact_moe(params, x, key, gate, cap, act, ctx):
+    """A block of a held share whose held assignments fit `cap` rows: they
+    alone are laid out, sorted by expert, and nothing of tokens x k rows
+    is built. No sort and no gather: each held assignment's row is its
+    expert's first row plus its rank among that expert's assignments
+    (a running count), and a 0/1 matrix `place` [cap, tokens] with a one
+    where a row is a token's carries the tokens to their rows and the
+    rows' weighted results back, as two matmuls (exact: one term a row).
+    `key` [nt, k] is the held expert's index or, for an absent one, the
+    number of held experts; `x` is in the experts' dtype."""
+    nt, k = key.shape
+    count = params['w1'].shape[0]
+    dtype = x.dtype
+    exact = lax.Precision.HIGHEST if dtype == jnp.float32 else None
+    with jax.named_scope('moe_route'):
+        flat = key.reshape(-1)
+        hot = flat[:, None] == jnp.arange(count, dtype=flat.dtype)
+        sizes = jnp.sum(hot, axis=0, dtype=jnp.int32)
+        first = jnp.cumsum(sizes) - sizes
+        rank = jnp.cumsum(hot.astype(jnp.int32), axis=0) - 1
+        row = jnp.sum(jnp.where(hot, first + rank, 0), axis=-1)
+        row = jnp.where(flat < count, row, cap)        # absent: no row
+        at = row[None, :] == jnp.arange(cap, dtype=row.dtype)[:, None]
+        row_gate = jnp.sum(jnp.where(at, gate.reshape(-1), 0.0), axis=-1)
+        place = jnp.any(at.reshape(cap, nt, k), axis=-1).astype(dtype)
+        live = jnp.sum(sizes)
+        keep = _keep(live)
+        # `keep`: the kernels' gradient of the rows is unwritten past `live`
+        rows = keep(jnp.matmul(place, x, precision=exact,
+                               preferred_element_type=jnp.float32
+                               ).astype(dtype))
+        group = None
+        if 'b1' in params:
+            group = jnp.minimum(jnp.searchsorted(
+                jnp.cumsum(sizes), jnp.arange(cap), side='right'), count - 1)
+    with jax.named_scope('moe_experts'):
+        out = _experts(params, rows, sizes, group, act, ctx, keep)
+    with jax.named_scope('moe_combine'):
+        out = out.astype(jnp.float32) * row_gate[:, None]
+        return jnp.matmul(place.T, out.astype(dtype), precision=exact,
+                          preferred_element_type=jnp.float32)
+
+
+# tokens a block of a held share: its buffers of rows are this x top_k
+_HELD_BLOCK = 2048
+# a block's compact path holds this many times its expected held rows
+_HELD_SLACK = 10
+
+
+def _held_moe(params, x, expert, gate, held, n_exp, act, ctx):
+    """This device's share of the layer: experts first .. first + count - 1
+    are here (the stacks are [count, ...]), the router chose among all
+    `n_exp`. The tokens go through in blocks of `_HELD_BLOCK`, one after
+    the other (a lax.scan), each block by itself and recomputed in the
+    backward pass (jax.checkpoint), so a buffer of rows is a block's, in
+    both passes, and nothing of tokens x k rows is kept a layer. A block
+    takes one of two paths, chosen on the device (lax.cond) from how many
+    of its assignments are held:
+
+    - `_compact_moe`, where they fit `_HELD_SLACK` times the expected
+      number (tokens x k x count / n_exp): only the held rows exist. The
+      slack is wide on purpose: a router in training leans towards or
+      away from the held experts within tens of steps (read on the chip,
+      PR 30: the fullest block at 1.2 times the expected rows at the
+      first step and 5.8 times at the 64th), and a step's time should not
+      follow it;
+    - `_dropless_moe` with `live`, at any imbalance beyond that: the
+      assignments are sorted held experts first, the absent experts'
+      after them in ONE tail group, which the grouped matmuls never visit
+      (their `sizes` are the held experts' alone), and the buffers keep
+      the static tokens x k rows, so no assignment to a held expert is
+      ever lost.
+
+    Either way an absent expert's assignment costs no matmul tile.
+    `params` are in the experts' dtype already (one cast a layer, not one
+    a block: at 16 experts of 2048 x 512 a cast moves 300 MB), so their
+    gradients add up over the blocks in that dtype."""
+    first, count = held
+    nt, k = expert.shape
+    with jax.named_scope('moe_route'):
+        local = expert - first
+        key = jnp.where((local >= 0) & (local < count), local, count)
+    blocks = nt // _HELD_BLOCK if nt % _HELD_BLOCK == 0 else 1
+    rows = nt // blocks * k
+    cap = -(-_HELD_SLACK * rows * count // n_exp // 256) * 256
+
+    def block(params, x, key, gate):
+        def full(x, key, gate):
+            sizes = jnp.bincount(key.reshape(-1), length=count + 1
+                                 )[:count].astype(jnp.int32)
+            return _dropless_moe(params, x, key, gate, sizes, act, ctx,
+                                 live=jnp.sum(sizes))
+
+        if 2 * cap > rows:                 # nothing to gain from compacting
+            return full(x, key, gate)
+        return lax.cond(
+            jnp.sum(key < count) <= cap,
+            lambda *a: _compact_moe(params, *a, cap, act, ctx), full,
+            x, key, gate)
+
+    if blocks == 1:
+        return jax.checkpoint(block)(params, x, key, gate)
+    x, key, gate = (t.reshape((blocks, nt // blocks) + t.shape[1:])
+                    for t in (x, key, gate))
+    _, y = lax.scan(
+        lambda _, b: (None, jax.checkpoint(block)(params, *b)), None,
+        (x, key, gate))
+    return y.reshape(nt, y.shape[-1])
 
 
 _SLOTS = {'W1': 'w1', 'B1': 'b1', 'W2': 'w2', 'B2': 'b2', 'W3': 'w3'}
@@ -209,8 +368,11 @@ def _moe_mlp(ins, attrs, ctx):
     if x.ndim > 2:
         x = x.reshape(-1, x.shape[-1])
     nt = x.shape[0]
-    obs.counter('moe.lowered',
-                path='grouped' if dropless else 'capacity').inc()
+    held = attrs.get('experts_held')
+    held = tuple(int(i) for i in held) if held else None
+    obs.counter('moe.lowered', path='grouped' if dropless else 'capacity',
+                **({'held': '%dof%d' % (held[1], n_exp)} if held else {})
+                ).inc()
     obs.counter('moe.assignments').inc(nt * top_k)
 
     from ...parallel.moe import (DroplessOnMeshError, load_balancing_loss,
@@ -242,7 +404,9 @@ def _moe_mlp(ins, attrs, ctx):
             'the four-chip cell of PERF.md section 7) is not built; give a '
             'capacity_factor, or run the layer on one device.'
             % (mesh.shape['dp'], n_exp))
-    if dropless:
+    if held:
+        y = _held_moe(params, x, expert.T, gate.T, held, n_exp, act, ctx)
+    elif dropless:
         y = _dropless_moe(params, x, expert.T, gate.T, sizes, act, ctx)
     elif shards:
         from jax.sharding import NamedSharding, PartitionSpec as P

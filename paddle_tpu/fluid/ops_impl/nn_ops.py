@@ -259,25 +259,31 @@ def _rms_norm(ins, attrs, ctx):
 def _rotary_embedding(ins, attrs, ctx):
     """Rotary position embedding (Su et al. 2021) of X [B, H, T, D] at
     positions 0..T-1, rotate-half pairing: element i of a head turns with
-    element i + D/2 by the angle t * base^(-2i/D). The tables of sines and
-    cosines are constants of the step, computed on the host in float64 and
-    rounded once: at position 4095 one float32 rounding of a frequency
-    turns the angle by 2e-4 rad, which moved OLMoE's attention output by
-    1e-4 between two float32 programs on the chip (PR 26) and with it a
-    few tokens' choice of experts. The product is float32; the result has
-    the input's dtype."""
+    element i + D/2 by the angle t * base^(-2i/D). With `rotary_dim` R < D
+    the first R elements turn among themselves (pairs (i, i + R/2), angle
+    t * base^(-2i/R)) and the other D - R pass through. The tables of
+    sines and cosines are constants of the step, computed on the host in
+    float64 and rounded once: at position 4095 one float32 rounding of a
+    frequency turns the angle by 2e-4 rad, which moved OLMoE's attention
+    output by 1e-4 between two float32 programs on the chip (PR 26) and
+    with it a few tokens' choice of experts. The product is float32; the
+    result has the input's dtype. Trace-time counter `rotary.lowered`,
+    labelled `rotary_dim=` where the rotation is partial."""
     x = data_of(ins['X'][0])
-    obs.counter('rotary.lowered').inc()            # trace time
     t, d = x.shape[-2], x.shape[-1]
-    half = d // 2
+    rd = int(attrs.get('rotary_dim') or d)
+    obs.counter('rotary.lowered',
+                **({'rotary_dim': rd} if rd != d else {})).inc()
+    half = rd // 2
     inv_freq = float(attrs.get('base', 10000.0)) ** (
-        -np.arange(half, dtype=np.float64) * 2.0 / d)
+        -np.arange(half, dtype=np.float64) * 2.0 / rd)
     angle = np.arange(t, dtype=np.float64)[:, None] * inv_freq[None, :]
-    cos = jnp.asarray(np.cos(angle), jnp.float32)  # [T, D/2]
+    cos = jnp.asarray(np.cos(angle), jnp.float32)  # [T, R/2]
     sin = jnp.asarray(np.sin(angle), jnp.float32)
     xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    y = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    x1, x2 = xf[..., :half], xf[..., half:rd]
+    y = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
+                         xf[..., rd:]], axis=-1)
     return {'Out': y.astype(x.dtype)}
 
 
@@ -644,6 +650,14 @@ def _flash_attention(ins, attrs, ctx):
     scale = attrs.get('scale', -1.0)
     scale = None if scale is None or scale < 0 else float(scale)
     causal = bool(attrs.get('causal', False))
+    if k.shape[1] != q.shape[1]:
+        # grouped key-value heads: key-value head h serves the query heads
+        # h * group and following. A repeat, whose transpose sums a group's
+        # gradients; the kernels see equal head counts, as before
+        group = q.shape[1] // k.shape[1]
+        obs.counter('flash.grouped', q_heads=q.shape[1],
+                    kv_heads=k.shape[1], head_dim=q.shape[3]).inc()
+        k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
     q, k, v = amp_cast(ctx, q, k, v)
     # off the TPU the sp bodies run their kernels interpreted and the
     # plain op takes the XLA chain

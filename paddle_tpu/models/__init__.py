@@ -8,7 +8,8 @@ __all__ = ['model_list', 'get_model_module']
 model_list = ['fit_a_line', 'mnist', 'vgg', 'resnet',
               'stacked_dynamic_lstm', 'machine_translation', 'transformer',
               'deepfm', 'word2vec', 'se_resnext', 'understand_sentiment',
-              'label_semantic_roles', 'recommender_system', 'olmoe']
+              'label_semantic_roles', 'recommender_system', 'olmoe',
+              'qwen3_next']
 
 
 def get_model_module(name):

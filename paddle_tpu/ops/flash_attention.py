@@ -663,6 +663,14 @@ def _default_tile(tuned, T, row_bytes):
     where the larger one cannot be had for nothing: operand rows too wide
     for VMEM, or a length it would pad further than 512 does (1536 keys
     in 1024-tiles are 2048)."""
+    if row_bytes > _WIDE_ROW_BYTES and T > 512:
+        # rows wider than the table was tuned for, over more than one
+        # tile: float32 operands at D = 256, which only a float32 check
+        # of a bf16 cell runs. Traced under jax's highest matmul precision
+        # (each float32 dot then splits its operands into bf16 parts in
+        # VMEM) the two-pass backward at 512 x 512 is refused at T = 8192
+        # (compiled for a described v5e, PR 30); 256 x 256 fits.
+        return min(tuned, 256)
     if row_bytes > _WIDE_ROW_BYTES or (T > tuned and T % tuned):
         return min(tuned, 512)
     return tuned

@@ -59,11 +59,21 @@ def _attention(x):
     return fluid.layers.fused_attention(q, q, q, causal=True)
 
 
+def _delta_rule(x):
+    q = fluid.layers.reshape(x, [-1, 16, 2, 8])
+    beta = fluid.layers.sigmoid(fluid.layers.reduce_mean(q, dim=-1))
+    return fluid.layers.gated_delta_rule(
+        q, q, q, fluid.layers.scale(beta, scale=-1.0), beta, chunk_size=16,
+        qk_l2norm=True)
+
+
 # op type -> (feed shape, the layer that appends it to the program, the
-# dtype its output has under AMP): the five rules that call
-# lowering.amp_cast, each built the way a model does. Four give their
-# result back in the dtype they were fed; attention gives it in the dtype
-# of its operands, and the output projection that follows takes it so.
+# dtype its output has under AMP): the rules whose matmuls take operands
+# that lowering.amp_cast cast, each built the way a model does. Five give
+# their result back in the dtype they were fed; attention gives it in the
+# dtype of its operands, and the output projection that follows takes it
+# so. (causal_conv1d and gated_rms_norm call amp_cast too, for what their
+# backward keeps, and multiply nothing on the MXU.)
 _MXU_OPS = {
     'mul': ((4, 8), lambda x: fluid.layers.fc(input=x, size=16), 'float32'),
     'matmul': ((4, 8), lambda x: fluid.layers.matmul(x, x, transpose_y=True),
@@ -74,12 +84,13 @@ _MXU_OPS = {
     'moe_mlp': ((16, 8), lambda x: fluid.layers.moe_mlp(
         x, num_experts=4, hidden_size=16, act='swish', gated=True, top_k=2,
         capacity_factor=None, bias_attr=False), 'float32'),
+    'gated_delta_rule': ((2, 16 * 2 * 8), _delta_rule, 'float32'),
 }
 
 
 @pytest.mark.parametrize('op_type', sorted(_MXU_OPS))
 def test_mxu_ops_take_bf16_operands_only_under_amp(op_type, monkeypatch):
-    """The list of MXU ops is the five amp_cast calls: under
+    """The list of MXU ops is the rules that amp_cast their operands: under
     decorate_program the lowered step's dot or convolution takes bf16
     operands and the output has the dtype the table names; without it the
     step holds no bf16 at all."""

@@ -577,6 +577,10 @@ _SWEEP = {
     'olmoe': dict(
         kwargs=dict(batch_size=2, seq_len=16, vocab_size=64, hidden=32,
                     n_expert=4, expert_width=16), feeds_idx=4, stack=True),
+    'qwen3_next': dict(
+        kwargs=dict(batch_size=2, seq_len=16, vocab_size=64, hidden=32,
+                    n_expert=8, expert_width=16, experts_held=(2, 4)),
+        feeds_idx=4, stack=True),
 }
 
 
