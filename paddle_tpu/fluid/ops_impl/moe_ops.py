@@ -58,7 +58,15 @@ the exchange and nothing stands in for it), so the outputs of all
 num_experts / count shares add up to the whole layer's, and anything
 every device computes alike (a shared expert) is counted once. Still
 dropless: no assignment to a held expert is lost at any imbalance, and
-an assignment to an absent expert costs no matmul tile (`_held_moe`). One
+an assignment to an absent expert costs no matmul tile. How the held
+experts get their rows (`_held_moe`): BY INDEX, once a layer. One sort of
+the tokens x k assignments puts the held ones first in expert order; a
+layout of `_HELD_SLACK` times the expected number of held rows is
+gathered from the tokens, goes through the grouped matmuls, and is added
+back to the tokens by a scatter-add (`_compact_moe`; the cost follows the
+rows laid out, not rows x tokens). A router that sends more than that to
+the held experts is answered, on the device (one `lax.cond` a layer), by
+the static tokens x k rows a block at a time (`_held_blocks`). One
 device, as the whole dropless layer: the same refusal on a mesh.
 
 Inside the op's `moe_mlp_<index>` scope the stages are named `moe_route`
@@ -66,8 +74,8 @@ Inside the op's `moe_mlp_<index>` scope the stages are named `moe_route`
 matmuls) and `moe_combine` (un-sort, gate weights, sum over k). Trace-time
 counters: `moe.lowered{path=grouped|capacity}` once per op per trace of
 the rule (a lowering, or build-time shape inference; a share adds the
-label `held=<count>of<num_experts>`), `moe.assignments` the tokens x k of
-the traced shape.
+labels `held=<count>of<num_experts>` and `dispatch=index`),
+`moe.assignments` the tokens x k of the traced shape.
 """
 import functools
 
@@ -238,114 +246,151 @@ def _dropless_moe(params, x, expert, gate, sizes, act, ctx, live=None):
         return jnp.sum(out.astype(jnp.float32) * gate[..., None], axis=1)
 
 
-def _compact_moe(params, x, key, gate, cap, act, ctx):
-    """A block of a held share whose held assignments fit `cap` rows: they
-    alone are laid out, sorted by expert, and nothing of tokens x k rows
-    is built. No sort and no gather: each held assignment's row is its
-    expert's first row plus its rank among that expert's assignments
-    (a running count), and a 0/1 matrix `place` [cap, tokens] with a one
-    where a row is a token's carries the tokens to their rows and the
-    rows' weighted results back, as two matmuls (exact: one term a row).
-    `key` [nt, k] is the held expert's index or, for an absent one, the
-    number of held experts; `x` is in the experts' dtype."""
+# The two row moves of a held share's compact path, each the other's
+# transpose, both by index: `at` [cap] is the row of x that a laid-out row
+# takes. Written as a pair so that the add is float32 whatever the rows
+# are (jax's own transpose of a bf16 gather adds in bf16) and neither way
+# clamps an index. The zero-size residuals carry a shape and a dtype.
+@jax.custom_vjp
+def _lay_out(x, at):
+    """x[at]: the laid-out rows [cap, d] of x [n, d]."""
+    return _rows(x, at)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _add_up(rows, at, n):
+    """The laid-out rows [cap, d] added to the rows of x they came from:
+    [n, d] float32. A row that no assignment fills must come as zeros."""
+    zero = jnp.zeros((n,) + rows.shape[1:], jnp.float32)
+    return zero.at[at].add(rows.astype(jnp.float32),
+                           mode='promise_in_bounds')
+
+
+_lay_out.defvjp(
+    lambda x, at: (_lay_out(x, at), (at, x[:, :0])),
+    lambda res, g: (_add_up(g, res[0], res[1].shape[0]).astype(res[1].dtype),
+                    None))
+_add_up.defvjp(
+    lambda rows, at, n: (_add_up(rows, at, n), (at, rows[:0])),
+    lambda n, res, g: (_lay_out(g, res[0]).astype(res[1].dtype), None))
+
+
+def _compact_moe(params, x, key, gate, sizes, cap, act, ctx):
+    """A held share whose held assignments fit `cap` rows: they alone are
+    laid out, sorted by expert, once for the whole layer, and nothing of
+    tokens x k rows is built. One stable sort of the tokens x k keys puts
+    the held assignments first, in expert order; its first `cap` entries
+    are the assignments that get a row. The rows are gathered by that
+    index (`_lay_out`), go through the experts, are weighted by their
+    gates and added to their tokens (`_add_up`). `key` [nt, k] is the held
+    expert's index or, for an absent one, the number of held experts;
+    `sizes` are the held experts' counts; `x` is in the experts' dtype."""
     nt, k = key.shape
-    count = params['w1'].shape[0]
-    dtype = x.dtype
-    exact = lax.Precision.HIGHEST if dtype == jnp.float32 else None
     with jax.named_scope('moe_route'):
-        flat = key.reshape(-1)
-        hot = flat[:, None] == jnp.arange(count, dtype=flat.dtype)
-        sizes = jnp.sum(hot, axis=0, dtype=jnp.int32)
-        first = jnp.cumsum(sizes) - sizes
-        rank = jnp.cumsum(hot.astype(jnp.int32), axis=0) - 1
-        row = jnp.sum(jnp.where(hot, first + rank, 0), axis=-1)
-        row = jnp.where(flat < count, row, cap)        # absent: no row
-        at = row[None, :] == jnp.arange(cap, dtype=row.dtype)[:, None]
-        row_gate = jnp.sum(jnp.where(at, gate.reshape(-1), 0.0), axis=-1)
-        place = jnp.any(at.reshape(cap, nt, k), axis=-1).astype(dtype)
-        live = jnp.sum(sizes)
-        keep = _keep(live)
-        # `keep`: the kernels' gradient of the rows is unwritten past `live`
-        rows = keep(jnp.matmul(place, x, precision=exact,
-                               preferred_element_type=jnp.float32
-                               ).astype(dtype))
+        flat = key.reshape(-1)                         # token-major
+        src = jnp.argsort(flat, stable=True).astype(jnp.int32)[:cap]
+        token = src // k
+        # `keep`: a row past `live` is some absent assignment's token, and
+        # the kernels' gradient of the rows is unwritten there
+        keep = _keep(jnp.sum(sizes))
         group = None
         if 'b1' in params:
-            group = jnp.minimum(jnp.searchsorted(
-                jnp.cumsum(sizes), jnp.arange(cap), side='right'), count - 1)
-    with jax.named_scope('moe_experts'):
-        out = _experts(params, rows, sizes, group, act, ctx, keep)
-    with jax.named_scope('moe_combine'):
-        out = out.astype(jnp.float32) * row_gate[:, None]
-        return jnp.matmul(place.T, out.astype(dtype), precision=exact,
-                          preferred_element_type=jnp.float32)
+            group = jnp.minimum(_rows(flat, src), sizes.shape[0] - 1)
+
+    # the index is kept for the backward pass, the rows are laid out again
+    # there: kept, a layer's rows and the experts' hidden rows are 670 MB
+    # at 25600 rows of 2048
+    @jax.checkpoint
+    def rows_of(params, x, gate):
+        with jax.named_scope('moe_route'):
+            rows = keep(_lay_out(x, token))
+            row_gate = _lay_out(gate.reshape(-1, 1), src)
+        with jax.named_scope('moe_experts'):
+            out = _experts(params, rows, sizes, group, act, ctx, keep)
+        with jax.named_scope('moe_combine'):
+            return _add_up(out.astype(jnp.float32) * row_gate, token, nt)
+
+    return rows_of(params, x, gate)
 
 
-# tokens a block of a held share: its buffers of rows are this x top_k
+# tokens a block of a held share that keeps all its rows: its buffers of
+# rows are this x top_k
 _HELD_BLOCK = 2048
-# a block's compact path holds this many times its expected held rows
+# the compact path holds this many times the layer's expected held rows
 _HELD_SLACK = 10
 
 
-def _held_moe(params, x, expert, gate, held, n_exp, act, ctx):
+def _held_cap(assignments, count, n_exp):
+    """Rows of the compact path's layout for a layer of `assignments`
+    (tokens x k) that holds `count` of `n_exp` experts: the slack times
+    the expected held rows, rounded up to the kernels' 256 rows."""
+    return -(-_HELD_SLACK * assignments * count // n_exp // 256) * 256
+
+
+def _held_blocks(params, x, key, gate, act, ctx):
+    """Every tokens x k row of a held share, whatever the router did:
+    `_dropless_moe(live=)` over blocks of `_HELD_BLOCK` tokens, one after
+    the other (a lax.scan), each block by itself and recomputed in the
+    backward pass (jax.checkpoint), so a buffer of rows is a block's in
+    both passes. A block's assignments are sorted held experts first, the
+    absent experts' after them in ONE tail group, which the grouped
+    matmuls never visit (their `sizes` are the held experts' alone)."""
+    nt = key.shape[0]
+    count = params['w1'].shape[0]
+
+    @jax.checkpoint
+    def block(params, x, key, gate):
+        sizes = jnp.bincount(key.reshape(-1), length=count + 1
+                             )[:count].astype(jnp.int32)
+        return _dropless_moe(params, x, key, gate, sizes, act, ctx,
+                             live=jnp.sum(sizes))
+
+    if nt % _HELD_BLOCK or nt == _HELD_BLOCK:
+        return block(params, x, key, gate)
+    x, key, gate = (t.reshape((-1, _HELD_BLOCK) + t.shape[1:])
+                    for t in (x, key, gate))
+    _, y = lax.scan(lambda _, b: (None, block(params, *b)), None,
+                    (x, key, gate))
+    return y.reshape(nt, y.shape[-1])
+
+
+def _held_moe(params, x, expert, gate, sizes, held, act, ctx):
     """This device's share of the layer: experts first .. first + count - 1
     are here (the stacks are [count, ...]), the router chose among all
-    `n_exp`. The tokens go through in blocks of `_HELD_BLOCK`, one after
-    the other (a lax.scan), each block by itself and recomputed in the
-    backward pass (jax.checkpoint), so a buffer of rows is a block's, in
-    both passes, and nothing of tokens x k rows is kept a layer. A block
-    takes one of two paths, chosen on the device (lax.cond) from how many
-    of its assignments are held:
+    num_experts and `sizes` [num_experts] counts what each got. The layer
+    takes one of two paths, chosen ONCE on the device (lax.cond) from how
+    many of its tokens x k assignments are held:
 
-    - `_compact_moe`, where they fit `_HELD_SLACK` times the expected
-      number (tokens x k x count / n_exp): only the held rows exist. The
-      slack is wide on purpose: a router in training leans towards or
-      away from the held experts within tens of steps (read on the chip,
-      PR 30: the fullest block at 1.2 times the expected rows at the
+    - `_compact_moe`, where they fit `_held_cap`, `_HELD_SLACK` times the
+      expected number (tokens x k x count / num_experts): only those rows
+      exist, reached by index. A row of
+      the capacity that no assignment fills costs a gathered row and a
+      tile the kernels skip. The slack is wide on purpose: a router in
+      training leans towards or away from the held experts within tens of
+      steps (read on the chip, PR 30: 1.2 times the expected rows at the
       first step and 5.8 times at the 64th), and a step's time should not
       follow it;
-    - `_dropless_moe` with `live`, at any imbalance beyond that: the
-      assignments are sorted held experts first, the absent experts'
-      after them in ONE tail group, which the grouped matmuls never visit
-      (their `sizes` are the held experts' alone), and the buffers keep
-      the static tokens x k rows, so no assignment to a held expert is
-      ever lost.
+    - `_held_blocks`, at any imbalance beyond that: the static tokens x k
+      rows a block at a time, so no assignment to a held expert is ever
+      lost.
 
     Either way an absent expert's assignment costs no matmul tile.
-    `params` are in the experts' dtype already (one cast a layer, not one
-    a block: at 16 experts of 2048 x 512 a cast moves 300 MB), so their
-    gradients add up over the blocks in that dtype."""
+    `params` are in the experts' dtype already (one cast a layer: at 16
+    experts of 2048 x 512 it moves 300 MB)."""
     first, count = held
     nt, k = expert.shape
     with jax.named_scope('moe_route'):
         local = expert - first
         key = jnp.where((local >= 0) & (local < count), local, count)
-    blocks = nt // _HELD_BLOCK if nt % _HELD_BLOCK == 0 else 1
-    rows = nt // blocks * k
-    cap = -(-_HELD_SLACK * rows * count // n_exp // 256) * 256
-
-    def block(params, x, key, gate):
-        def full(x, key, gate):
-            sizes = jnp.bincount(key.reshape(-1), length=count + 1
-                                 )[:count].astype(jnp.int32)
-            return _dropless_moe(params, x, key, gate, sizes, act, ctx,
-                                 live=jnp.sum(sizes))
-
-        if 2 * cap > rows:                 # nothing to gain from compacting
-            return full(x, key, gate)
-        return lax.cond(
-            jnp.sum(key < count) <= cap,
-            lambda *a: _compact_moe(params, *a, cap, act, ctx), full,
-            x, key, gate)
-
-    if blocks == 1:
-        return jax.checkpoint(block)(params, x, key, gate)
-    x, key, gate = (t.reshape((blocks, nt // blocks) + t.shape[1:])
-                    for t in (x, key, gate))
-    _, y = lax.scan(
-        lambda _, b: (None, jax.checkpoint(block)(params, *b)), None,
-        (x, key, gate))
-    return y.reshape(nt, y.shape[-1])
+    cap = _held_cap(nt * k, count, sizes.shape[0])
+    if 2 * cap > nt * k:                   # nothing to gain from compacting
+        return _held_blocks(params, x, key, gate, act, ctx)
+    sizes = sizes[first:first + count]
+    return lax.cond(
+        jnp.sum(sizes) <= cap,
+        lambda *a: _compact_moe(*a, sizes, cap, act, ctx),
+        lambda *a: _held_blocks(*a, act, ctx),
+        params, x, key, gate)
 
 
 _SLOTS = {'W1': 'w1', 'B1': 'b1', 'W2': 'w2', 'B2': 'b2', 'W3': 'w3'}
@@ -371,8 +416,8 @@ def _moe_mlp(ins, attrs, ctx):
     held = attrs.get('experts_held')
     held = tuple(int(i) for i in held) if held else None
     obs.counter('moe.lowered', path='grouped' if dropless else 'capacity',
-                **({'held': '%dof%d' % (held[1], n_exp)} if held else {})
-                ).inc()
+                **({'held': '%dof%d' % (held[1], n_exp), 'dispatch': 'index'}
+                   if held else {})).inc()
     obs.counter('moe.assignments').inc(nt * top_k)
 
     from ...parallel.moe import (DroplessOnMeshError, load_balancing_loss,
@@ -405,7 +450,7 @@ def _moe_mlp(ins, attrs, ctx):
             'capacity_factor, or run the layer on one device.'
             % (mesh.shape['dp'], n_exp))
     if held:
-        y = _held_moe(params, x, expert.T, gate.T, held, n_exp, act, ctx)
+        y = _held_moe(params, x, expert.T, gate.T, sizes, held, act, ctx)
     elif dropless:
         y = _dropless_moe(params, x, expert.T, gate.T, sizes, act, ctx)
     elif shards:

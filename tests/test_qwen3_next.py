@@ -2,7 +2,8 @@
 against the token-by-token recurrence, the causal convolution, partial
 rotary and grouped key-value heads against few-line formulas, an expert
 layer that holds a SHARE of its experts (the shares add up to the whole
-layer; absent rows cost nothing and poison nothing), the whole toy model
+layer; absent rows cost nothing and poison nothing; the held rows are
+reached by index, once a layer: ISSUE 31), the whole toy model
 against the benchmark's plain reference, the counters and scopes, and the
 configuration's file. Small sizes, on the CPU."""
 import json
@@ -392,53 +393,78 @@ def test_the_shares_and_the_shared_expert_once_are_the_uncut_layer(tokens):
                                rtol=2e-4, atol=2e-6)
 
 
-def test_a_block_compacts_its_held_rows_or_keeps_them_all(monkeypatch):
-    """Two experts of 32 held, 4096 tokens in two blocks: a block's
-    expected held rows are a sixteenth of its assignments, so the block
-    lays out only 4 x that many rows (the compact path: one-hot matmuls,
-    no sort, no gather); a router forced onto the held experts overflows
-    them and the same block keeps all its rows instead. Both are the
-    uncut layer's part, values and gradients."""
+def _nan_path(params, x, *_):
+    """In place of the path a test expects the device NOT to take."""
+    return jnp.full((x.shape[0], params['w2'].shape[-1]), jnp.nan,
+                    jnp.float32)
+
+
+def _forced_router(order):
+    """Router weights that send a token of x > 0 to `order`, in order."""
+    router = np.zeros((D, E), 'float32')
+    for j, e in enumerate(order):
+        router[:, e] = 4.0 - j
+    return router
+
+
+def test_a_layer_compacts_its_held_rows_or_keeps_them_all(monkeypatch):
+    """Two experts of 32 held, 4096 tokens: the expected held rows are a
+    sixteenth of the layer's 16384 assignments, so the layer lays out
+    only 4 x that many rows, once (the compact path: a sort and row
+    gathers); a router forced onto the held experts overflows them and
+    the same layer keeps all its rows instead, a block at a time. Both are
+    the uncut layer's part."""
     paths = []
     monkeypatch.setattr(moe_ops, '_HELD_SLACK', 4)    # a sixteenth is held
-    compact, full = moe_ops._compact_moe, moe_ops._dropless_moe
+    compact, blocks = moe_ops._compact_moe, moe_ops._held_blocks
     monkeypatch.setattr(moe_ops, '_compact_moe', lambda *a: (
-        paths.append(('compact', a[4])), compact(*a))[1])
-    monkeypatch.setattr(moe_ops, '_dropless_moe', lambda *a, **kw: (
-        paths.append(('full', a[2].shape)), full(*a, **kw))[1])
+        paths.append(('compact', a[5])), compact(*a))[1])
+    monkeypatch.setattr(moe_ops, '_held_blocks', lambda *a: (
+        paths.append(('blocks', a[2].shape)), blocks(*a))[1])
     rng = np.random.default_rng(4)
     xs = np.abs(rng.normal(size=(4096, D))).astype('float32') + 0.1
     (whole, _, count), weights = run_share(None, xs)
     parts = [run_share((first, 2), xs, weights)[0][0]
              for first in range(0, E, 2)]
     np.testing.assert_allclose(sum(parts), whole, rtol=2e-5, atol=2e-7)
-    # 2048 tokens x 4 = 8192 assignments a block, 512 expected, 2048 rows;
-    # both paths are traced (a lax.cond), the device takes one a block
-    assert ('compact', 2048) in paths and ('full', (2048, K)) in paths
+    # 4096 tokens x 4 = 16384 assignments, 1024 expected, 4096 rows; both
+    # paths are traced ONCE a layer (a lax.cond), the device takes one
+    assert ('compact', 4096) in paths and ('blocks', (4096, K)) in paths
+    del paths[:]
+    run_share((6, 2), xs, weights)
+    names = [name for name, _ in paths]    # each, once a trace of the rule
+    assert names.count('compact') == names.count('blocks') > 0
+    # ... and it took the compact one: the other gives NaN here
+    monkeypatch.setattr(moe_ops, '_held_blocks', _nan_path)
+    (part, _, _), _ = run_share((6, 2), xs, weights)
+    np.testing.assert_allclose(part, parts[3], rtol=1e-6, atol=1e-8)
+    monkeypatch.setattr(moe_ops, '_held_blocks', blocks)
     forced = [w.copy() for w in weights]
-    forced[0] = np.zeros((D, E), 'float32')
-    for j, e in enumerate((6, 7, 0, 1)):
-        forced[0][:, e] = 4.0 - j                   # x > 0: 6, 7, 0, 1
+    forced[0] = _forced_router((6, 7, 0, 1))
     (whole, _, count), _ = run_share(None, xs, forced)
-    (part, _, _), _ = run_share((6, 2), xs, forced)
-    # 4096 held rows a block, twice what the compact path lays out: had
-    # the block taken it, half of them would be missing from `part`
-    assert count[6] == count[7] == 4096
     (rest, _, _), _ = run_share((0, 2), xs, forced)
+    # 8192 held rows, twice what the compact path lays out: had the layer
+    # taken it (NaN here), half of them would be missing from `part`
+    monkeypatch.setattr(moe_ops, '_compact_moe', _nan_path)
+    (part, _, _), _ = run_share((6, 2), xs, forced)
+    assert count[6] == count[7] == 4096
     np.testing.assert_allclose(part + rest, whole, rtol=2e-5, atol=2e-7)
 
 
-@pytest.mark.parametrize('tokens,held', [(N, (8, HELD)), (4096, (6, 2))],
-                         ids=['all_rows', 'compact_rows'])
+@pytest.mark.parametrize(
+    'tokens,held,forced', [(N, (8, HELD), False), (4096, (6, 2), False),
+                           (4096, (6, 2), True)],
+    ids=['all_rows', 'compact_rows', 'overflow_rows'])
 def test_rows_of_absent_experts_cost_no_tile_and_poison_nothing(
-        tokens, held, monkeypatch):
+        tokens, held, forced, monkeypatch):
     """The grouped matmuls are given the held experts' group sizes alone,
     and whatever lies in the rows after them (a kernel leaves them
     unwritten, in its results and in the gradient of its rows: NaN here)
-    reaches neither the output nor a gradient; in a block that keeps all
-    its rows and in one that lays out the held rows only."""
+    reaches neither the output nor a gradient: in a layer that always
+    keeps all its rows, in one that lays out the held rows only, and in
+    one whose held rows overflow that layout."""
     rng = np.random.default_rng(1)
-    xs = rng.normal(size=(tokens, D)).astype('float32')
+    xs = np.abs(rng.normal(size=(tokens, D))).astype('float32') + 0.1
     seen = []
     plain = moe_ops._grouped_matmul
     monkeypatch.setattr(moe_ops, '_HELD_SLACK', 4)    # a sixteenth is held
@@ -456,7 +482,10 @@ def test_rows_of_absent_experts_cost_no_tile_and_poison_nothing(
         rows = poison(rows, live)         # the gradient of the rows
         return poison(plain(rows, w, sizes, ctx), live)
 
-    (want, _, count), weights = run_share(held, xs)
+    (_, _, _), weights = run_share(None, xs)
+    if forced:
+        weights[0] = _forced_router((6, 7, 0, 1))
+    (want, _, count), _ = run_share(held, xs, weights)
     monkeypatch.setattr(moe_ops, '_grouped_matmul', poisoned)
     main, startup, out, _, _ = build_share(held)
     with unique_name.guard(), framework.program_guard(main, startup):
@@ -464,22 +493,222 @@ def test_rows_of_absent_experts_cost_no_tile_and_poison_nothing(
     with fluid.scope_guard(fluid.Scope()):
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(startup)
-        _set_weights(fluid.global_scope(), weights, 0, held[1])  # held ones
+        _set_weights(fluid.global_scope(), weights, *held)    # held ones
         got = exe.run(main, feed={'x': xs},
                       fetch_list=[out] + [g for _, g in grads])
     assert all(np.isfinite(g).all() for g in got)
+    assert all(np.abs(g).max() > 0 for g in got)
     np.testing.assert_allclose(got[0], want, rtol=1e-5, atol=1e-7)
     # every call: the held experts' groups and no tail group; the rows are
-    # a block's tokens x K, or the compact path's few
+    # a block's tokens x K, or the compact path's few of the whole layer
     assert seen and all(groups == held[1] and sizes.shape == (held[1],)
                         for _, groups, sizes in seen)
     sizes = {rows for rows, _, _ in seen}
-    block = min(tokens, moe_ops._HELD_BLOCK) * K
-    assert block in sizes
+    assert min(tokens, moe_ops._HELD_BLOCK) * K in sizes
     if tokens > N:
-        assert min(sizes) == moe_ops._HELD_SLACK * block * held[1] // E \
-            < block
-    assert count[held[0]:held[0] + held[1]].sum() < tokens * K
+        assert min(sizes) == moe_ops._HELD_SLACK * tokens * K * held[1] \
+            // E < moe_ops._HELD_BLOCK * K
+    live = count[held[0]:held[0] + held[1]].sum()
+    assert live == tokens * 2 if forced else live < min(sizes)
+
+
+# kinds of token by the experts a forced router gives them, of 32 with
+# experts 4..7 or 6..7 held: how many of a token's K = 4 assignments are held
+KINDS = {'all_k': (4, 5, 6, 7), 'two': (6, 7, 0, 1), 'one': (6, 0, 1, 2),
+         'none': (0, 1, 2, 3)}
+#        held, slack -> cap, tokens of each kind, the path the device takes
+BOUNDARY = {
+    # 256 x 2 = 512 held rows = cap: the last row of the layout is used
+    'live_is_cap': ((6, 2), 2, {'two': 256, 'none': 768}, 'compact'),
+    # one more: no layout of `cap` rows holds them, every row is kept
+    'live_is_cap_plus_one': ((6, 2), 2, {'two': 256, 'one': 1, 'none': 767},
+                             'blocks'),
+    # tokens with K, two, one and no held assignment side by side: a
+    # token's rows are added, its gradient is the sum
+    'two_and_k_held_slots': ((4, 4), 2, {'all_k': 100, 'two': 200,
+                                         'one': 50, 'none': 674}, 'compact'),
+}
+
+
+def plain_part(x, router, w1, w3, w2, held):
+    """The held experts' part of the layer, every held expert on every
+    token: no sort, no gather, no ragged op. Stacks of all E experts."""
+    with jax.default_matmul_precision('highest'):
+        gate, index = jax.lax.top_k(jax.nn.softmax(x @ router, -1), K)
+        gate = gate / jnp.sum(gate, -1, keepdims=True)
+        y = 0.0
+        for e in range(held[0], held[0] + held[1]):
+            mine = jnp.sum(jnp.where(index == e, gate, 0.0), -1)
+            y = y + mine[:, None] * (
+                (jax.nn.silu(x @ w1[e]) * (x @ w3[e])) @ w2[e])
+    return y
+
+
+@pytest.mark.parametrize('case', list(BOUNDARY))
+def test_the_boundary_of_the_layout_and_tokens_with_many_held_slots(
+        case, monkeypatch):
+    """`live == cap` takes the compact path and `live == cap + 1` the
+    rows-kept one (the path NOT expected gives NaN here); a token with
+    two and with K held assignments has its rows added up. Each equals
+    the plain part of the uncut layer in value and in every gradient:
+    the input's, the router's, the three stacks'."""
+    held, slack, kinds, path = BOUNDARY[case]
+    tokens = sum(kinds.values())
+    monkeypatch.setattr(moe_ops, '_HELD_SLACK', slack)
+    cap = -(-slack * tokens * K * held[1] // E // 256) * 256
+    live = sum(n * len([e for e in KINDS[kind]
+                        if held[0] <= e < held[0] + held[1]])
+               for kind, n in kinds.items())
+    assert (live <= cap) == (path == 'compact') and 2 * cap <= tokens * K
+    if case.startswith('live_is_cap'):
+        assert live - cap == (path == 'blocks')
+    monkeypatch.setattr(moe_ops, '_held_blocks' if path == 'compact'
+                        else '_compact_moe', _nan_path)
+    rng = np.random.default_rng(7)
+    # a token's kind is one of its first features; the router reads those
+    kind_of = rng.permutation(np.repeat(np.arange(len(kinds)),
+                                        list(kinds.values())))
+    xs = rng.normal(size=(tokens, D)).astype('float32')
+    xs[:, :len(kinds)] = np.eye(len(kinds), dtype='float32')[kind_of]
+    router = np.zeros((D, E), 'float32')
+    for i, kind in enumerate(kinds):
+        router[i, list(KINDS[kind])] = 4.0 - np.arange(K)
+    stacks = [rng.normal(size=s).astype('float32') * 0.3
+              for s in ((E, D, H), (E, D, H), (E, H, D))]
+    weights = [router] + stacks
+    w = rng.normal(size=(tokens, D)).astype('float32')
+
+    main, startup = framework.Program(), framework.Program()
+    with unique_name.guard(), framework.program_guard(main, startup):
+        out = layers.moe_mlp(
+            _input('x', xs), num_experts=E, hidden_size=H, act='swish',
+            gated=True, top_k=K, norm_topk_prob=True, capacity_factor=None,
+            bias_attr=False, experts_held=held)
+        loss = layers.reduce_sum(layers.elementwise_mul(out, layers.data(
+            name='w', shape=[tokens, D], dtype='float32',
+            append_batch_size=False)))
+        grads = dict((p.name, g) for p, g in
+                     fluid.backward.append_backward(loss))
+    names = ['x'] + ['moe_mlp_0.w_%d' % i for i in range(4)]
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        _set_weights(fluid.global_scope(), weights, *held)
+        got = exe.run(main, feed={'w': w},
+                      fetch_list=[out] + [grads[n] for n in names])
+    want = plain_part(xs, *weights, held)
+    g_want = jax.grad(lambda *a: jnp.sum(plain_part(*a, held) * w),
+                      argnums=range(5))(xs, *weights)
+    np.testing.assert_allclose(got[0], want, rtol=1e-4, atol=1e-5)
+    assert np.abs(got[0]).max() > 0.1
+    for name, a, b in zip(names, got[1:], g_want):
+        if b.shape != a.shape:            # a stack: the held experts' slice
+            rest = np.delete(np.asarray(b), np.s_[held[0]:sum(held)], axis=0)
+            assert np.abs(rest).max() == 0
+            b = b[held[0]:held[0] + held[1]]
+        assert np.abs(b).max() > 0, name
+        np.testing.assert_allclose(a, b, rtol=1e-3,
+                                   atol=1e-4 * np.abs(b).max(), err_msg=name)
+
+
+def _loops_and_dots(jaxpr, found):
+    """Every loop and every dot_general's operand shapes of a jaxpr and of
+    what it calls, a `cond`'s branches apart (they are returned)."""
+    conds = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == 'cond':
+            conds.append(eqn)
+            continue
+        if name in ('scan', 'while'):
+            found['loops'].append(name)
+        if name == 'dot_general':
+            found['dots'].append(tuple(v.aval.shape for v in eqn.invars))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            conds += _loops_and_dots(sub, found)
+    return conds
+
+
+@pytest.mark.parametrize('way', ['forward', 'backward'])
+def test_the_compact_path_is_indices_once_a_layer(way, monkeypatch):
+    """From the jaxpr at toy widths: one `cond` a layer (a second in the
+    backward pass, its transpose) and no loop outside it; the branch
+    without a loop (the compact path) moves its rows with no dot_general
+    at all, so none by a 0/1 matrix [cap, tokens]; the other branch (all
+    the rows kept) is the one that walks blocks."""
+    import types
+    monkeypatch.setattr(moe_ops, '_HELD_SLACK', 2)
+    tokens, held = 4096, (6, 2)
+    cap = 2 * tokens * K * held[1] // E
+    rng = np.random.default_rng(5)
+    params = {k: jnp.asarray(rng.normal(size=s), jnp.float32) for k, s in
+              (('w1', (2, D, H)), ('w3', (2, D, H)), ('w2', (2, H, D)))}
+    x = jnp.asarray(rng.normal(size=(tokens, D)), jnp.float32)
+    expert = jnp.asarray(np.argsort(rng.normal(size=(tokens, E)))[:, :K],
+                         jnp.int32)
+    gate = jnp.full((tokens, K), 0.25, jnp.float32)
+    sizes = jnp.bincount(expert.reshape(-1), length=E).astype(jnp.int32)
+    ctx = types.SimpleNamespace(platform='cpu')
+
+    def part(params, x, gate):
+        return jnp.sum(moe_ops._held_moe(params, x, expert, gate, sizes,
+                                         held, 'swish', ctx))
+
+    fn = part if way == 'forward' else jax.grad(part, argnums=(0, 1, 2))
+    outside = {'loops': [], 'dots': []}
+    conds = _loops_and_dots(jax.make_jaxpr(fn)(params, x, gate).jaxpr,
+                            outside)
+    assert len(conds) == (1 if way == 'forward' else 2)
+    assert outside == {'loops': [], 'dots': []}
+    for cond in conds:
+        inside = []
+        for branch in cond.params['branches']:
+            found = {'loops': [], 'dots': []}
+            assert _loops_and_dots(branch.jaxpr, found) == []
+            inside.append(found)
+        blocks, compact = inside           # lax.cond: (false, true)
+        assert blocks['loops'] and not compact['loops']
+        assert compact['dots'] == []
+        assert not [shapes for shapes in blocks['dots']
+                    if any({cap, tokens} <= set(s) for s in shapes)]
+
+
+def test_biases_ride_the_compact_path_as_they_do_the_kept_rows(monkeypatch):
+    """The Fluid layer's biased, ungated form of the experts (no cell
+    runs it held): a laid-out row takes its expert's bias rows by the same
+    index, and a row that no assignment fills gives nothing. The compact
+    path against all rows kept, values and every gradient."""
+    import types
+    monkeypatch.setattr(moe_ops, '_HELD_SLACK', 2)
+    tokens, held = 4096, (6, 2)
+    rng = np.random.default_rng(9)
+    params = {k: jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32)
+              for k, s in (('w1', (2, D, H)), ('w2', (2, H, D)),
+                           ('b1', (2, H)), ('b2', (2, D)))}
+    x = jnp.asarray(rng.normal(size=(tokens, D)), jnp.float32)
+    expert = jnp.asarray(np.argsort(rng.normal(size=(tokens, E)))[:, :K],
+                         jnp.int32)
+    gate = jnp.asarray(rng.uniform(size=(tokens, K)), jnp.float32)
+    sizes = jnp.bincount(expert.reshape(-1), length=E).astype(jnp.int32)
+    w = jnp.asarray(rng.normal(size=(tokens, D)), jnp.float32)
+    ctx = types.SimpleNamespace(platform='cpu')
+
+    def part(params, x, gate):
+        return jnp.sum(w * moe_ops._held_moe(params, x, expert, gate, sizes,
+                                             held, 'relu', ctx))
+
+    both = []
+    for path in ('compact', 'blocks'):
+        both.append(jax.jit(jax.value_and_grad(part, argnums=(0, 1, 2)))(
+            params, x, gate))
+        blocks = moe_ops._held_blocks       # the second time: rows kept
+        monkeypatch.setattr(moe_ops, '_compact_moe',
+                            lambda p, x, key, gate, sizes, cap, act, ctx:
+                            blocks(p, x, key, gate, act, ctx))
+    for a, b in zip(*(jax.tree_util.tree_leaves(t) for t in both)):
+        assert np.abs(b).max() > 0
+        np.testing.assert_allclose(a, b, rtol=1e-4,
+                                   atol=1e-5 * np.abs(b).max())
 
 
 def test_a_router_forced_onto_the_held_experts_loses_nothing():
@@ -543,7 +772,8 @@ def test_a_share_is_dropless_only_and_a_range_of_the_experts():
 
 def test_a_share_counts_its_lowering_and_moves_nothing_over_the_wire():
     from paddle_tpu.fluid.analysis import collectives
-    label = {'path': 'grouped', 'held': '%dof%d' % (HELD, E)}
+    label = {'path': 'grouped', 'held': '%dof%d' % (HELD, E),
+             'dispatch': 'index'}
     before = obs.counter('moe.lowered', **label).value
     xs = np.ones((N, D), 'float32')
     main, startup, out, _, _ = build_share((0, HELD), amp=True)
@@ -560,6 +790,30 @@ def test_a_share_counts_its_lowering_and_moves_nothing_over_the_wire():
     dots = [l for l in text.splitlines() if 'dot_general' in l]
     assert [l for l in dots if 'HIGHEST' in l and 'bf16' not in l]
     assert [l for l in dots if 'xbf16>, tensor' in l]
+
+
+def test_a_held_lowering_counts_once_under_dispatch_index():
+    """`moe.lowered{path=grouped, held=8of32, dispatch=index}`: one count a
+    trace of the rule, so one for the step's lowering of a Program with
+    one held layer; a layer that holds every expert names no dispatch."""
+    def counts():
+        return [obs.counter('moe.lowered', path='grouped', **more).value
+                for more in ({'held': '%dof%d' % (HELD, E),
+                              'dispatch': 'index'},
+                             {'held': '%dof%d' % (HELD, E)}, {})]
+
+    xs = np.ones((N, D), 'float32')
+    for held, moved in (((8, HELD), [1, 0, 0]), (None, [0, 0, 1])):
+        main, startup, out, _, _ = build_share(held)
+        with fluid.scope_guard(fluid.Scope()):
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(startup)
+            before = counts()
+            exe.run(main, feed={'x': xs}, fetch_list=[out])
+            first = counts()
+            exe.run(main, feed={'x': xs}, fetch_list=[out])   # no new trace
+            assert counts() == first
+        assert [b - a for a, b in zip(before, first)] == moved
 
 
 # ----------------------------------------------------------------- the model

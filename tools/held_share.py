@@ -9,10 +9,17 @@ experts' work on however many of the tokens x top_k assignments the router
 sends to them: data, not a shape. chipbench's rooflines count the EXPECTED
 share, held / routed (flops/qwen3_next.py `held_rows`); this prints what
 the seeded traffic really sent, layer by layer, over a few training steps
-(Adam moves the router, so the share drifts from its first value). Runs
-wherever jax runs: on the chip the cell's own step, on the host the same
-Program on CPUPlace (slow at published widths; `--toy` takes the widths
-of tests/test_chipbench/toy/).
+(Adam moves the router, so the share drifts from its first value). The
+LAYER is the unit that chooses the path (ops_impl/moe_ops.py `_held_moe`):
+a layer whose held rows fit `_HELD_SLACK` times the expected number lays
+out those rows alone, by index (the compact path); beyond that it keeps
+every row. So the line also gives each layer-step's held rows over the
+expected number (`layer_over_expected_*`), how many layer-steps went over
+the slack (`layers_over_slack`) and the share that stayed on the compact
+path (`compact_share`): counted on the host from `ExpertCount`, with
+nothing added to the step. Runs wherever jax runs: on the chip the cell's
+own step, on the host the same Program on CPUPlace (slow at published
+widths; `--toy` takes the widths of tests/test_chipbench/toy/).
 """
 import argparse
 import json
@@ -52,36 +59,22 @@ def main(argv=None):
     built = cell['builder'].build(config, traffic, train=True)
     moes = [op for op in built['main'].global_block().ops
             if op.type == 'moe_mlp']
-    fetch = [built['loss']] + [op.output('ExpertCount')[0] for op in moes] \
-        + [op.input('X')[0] for op in moes]
+    fetch = [built['loss']] + [op.output('ExpertCount')[0] for op in moes]
     exe = fluid.Executor()
     exe.run(built['startup'])
-    scope = fluid.global_scope()
     from paddle_tpu.fluid.ops_impl import moe_ops
-    top_k = moes[0].attrs['top_k']
-    shares, blocks = [], []
+    held_rows, assignments = [], []
     for i in range(args.steps):
-        out = exe.run(built['main'], feed=pool[i % len(pool)],
-                      fetch_list=fetch)
-        counts, inputs = out[1:1 + len(moes)], out[1 + len(moes):]
-        shares.append([float(np.asarray(c)[first:first + count].sum())
-                       / float(np.asarray(c).sum()) for c in counts])
-        # the same routing block by block, on the host: which of a block's
-        # assignments are held decides the path the block takes
-        # (ops_impl/moe_ops.py `_held_moe`)
-        per_layer = []
-        for op, x in zip(moes, inputs):
-            x = np.asarray(x, np.float32)
-            x = x.reshape(-1, x.shape[-1])
-            w = np.asarray(scope.find_var(op.input('GateW')[0]).get_tensor())
-            top = np.argsort(-(x @ w), axis=-1)[:, :top_k]
-            held_rows = ((top >= first) & (top < first + count)).sum(-1)
-            size = moe_ops._HELD_BLOCK if len(x) % moe_ops._HELD_BLOCK == 0 \
-                else len(x)
-            per_layer.append((held_rows.reshape(-1, size).sum(-1)
-                              / (size * top_k * count / routed)).tolist())
-        blocks.append(per_layer)
-    shares, blocks = np.asarray(shares), np.asarray(blocks)
+        counts = np.asarray(exe.run(built['main'], feed=pool[i % len(pool)],
+                                    fetch_list=fetch)[1:])
+        held_rows.append(counts[:, first:first + count].sum(-1))
+        assignments.append(counts.sum(-1))
+    held_rows, assignments = np.asarray(held_rows), np.asarray(assignments)
+    shares = held_rows / assignments                   # [steps, layers]
+    # a layer's held rows over the expected number, and the rows that
+    # `_held_moe` compares them with
+    over = shares * routed / count
+    cap = moe_ops._held_cap(assignments, count, routed)
     print(json.dumps({
         'workload': args.workload, 'seed': args.seed, 'steps': args.steps,
         'held': [first, count], 'routed': routed,
@@ -91,14 +84,16 @@ def main(argv=None):
         'measured_share_max': float(shares.max()),
         'first_step_by_layer': shares[0].tolist(),
         'last_step_by_layer': shares[-1].tolist(),
-        # a block's held rows over the expected number: above
-        # moe_ops._HELD_SLACK the block keeps all its rows
-        'block_over_expected_max_by_layer': blocks.max(axis=(0, 2)).tolist(),
-        'block_over_expected_p50': float(np.median(blocks)),
-        'blocks_over_slack': int((blocks > moe_ops._HELD_SLACK).sum()),
-        'block_over_expected_max_by_step': [round(float(b), 2) for b in
-                                            blocks.max(axis=(1, 2))],
-        'blocks': int(blocks.size)}))
+        'layer_over_expected_max_by_layer': over.max(axis=0).tolist(),
+        'layer_over_expected_p50': float(np.median(over)),
+        'layer_over_expected_max_by_step': [round(float(b), 2)
+                                            for b in over.max(axis=1)],
+        'slack': moe_ops._HELD_SLACK,
+        'layers_over_slack': int((held_rows > cap).sum()),
+        # a layout of half the rows or more is not built at all
+        'compact_share': float(((held_rows <= cap)
+                                & (2 * cap <= assignments)).mean()),
+        'layer_steps': int(over.size)}))
     return 0
 
 
