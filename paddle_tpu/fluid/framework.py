@@ -14,6 +14,7 @@ lowering rules, so there is exactly one definition of every op's semantics.
 import collections
 import contextlib
 import copy
+import itertools
 import os
 import sys
 
@@ -25,7 +26,7 @@ from . import unique_name
 __all__ = [
     'Program', 'Operator', 'Parameter', 'Variable', 'Block',
     'default_startup_program', 'default_main_program', 'program_guard',
-    'name_scope', 'device_guard', 'get_var', 'grad_var_name',
+    'name_scope', 'recompute_guard', 'device_guard', 'get_var', 'grad_var_name',
     'strict_infer_shape', 'normalize_sharding',
 ]
 
@@ -296,6 +297,11 @@ class Operator(object):
         self.attrs.setdefault('op_role', ROLE_FORWARD)
         if _device_guard_stack and _device_guard_stack[-1] is not None:
             self.attrs.setdefault('op_device', _device_guard_stack[-1])
+        scope = '/'.join(s for s in _name_scope_stack if s)
+        if scope:
+            self.attrs.setdefault('name_scope', scope)
+        if _recompute_stack:
+            self.attrs.setdefault('recompute', _recompute_stack[-1])
         if inputs:
             for slot, vs in inputs.items():
                 if vs is None:
@@ -844,11 +850,44 @@ _name_scope_stack = []
 
 @contextlib.contextmanager
 def name_scope(prefix=None):
-    _name_scope_stack.append(prefix or '')
+    """Ops appended inside carry the path of the scopes they were built in
+    as the attribute `name_scope` ('mtp/latent_attention'), and
+    lowering.run_op enters it around the op's own `<type>_<index>` scope:
+    an HLO instruction's op_name then reads
+    `.../mtp/latent_attention/mul_17/...`, so a trace can tell which `mul`
+    belongs to which part of the model. Any prefix is taken, as the
+    reference takes it (`layer_1`, `block.0`); what the trace shows of it
+    is lowering.scope_label's to make safe."""
+    prefix = prefix or ''
+    _name_scope_stack.append(prefix)
     try:
         yield
     finally:
         _name_scope_stack.pop()
+
+
+_recompute_stack = []
+_recompute_serial = itertools.count(1)
+
+
+@contextlib.contextmanager
+def recompute_guard():
+    """The forward ops appended inside form ONE region that the training
+    step recomputes in its backward pass: the step keeps the region's
+    inputs (and the attention kernels' outputs and statistics) and not
+    what the region computes on the way, one `jax.checkpoint` a region
+    (step_artifact._run_ops). A model marks each decoder layer. A region
+    is the guard's ops in a row; a guard inside another belongs to the
+    outer one. The default main program is marked `_use_remat`, which
+    `fluid.memory_optimize` sets for a Program with no region of its own
+    (the whole forward is then the one region)."""
+    _recompute_stack.append(_recompute_stack[-1] if _recompute_stack
+                            else next(_recompute_serial))
+    default_main_program()._use_remat = True
+    try:
+        yield
+    finally:
+        _recompute_stack.pop()
 
 
 _device_guard_stack = []

@@ -7,6 +7,7 @@ activation epilogues.
 import copy
 
 from . import unique_name
+from .. import obs
 from .framework import Variable, Parameter, default_main_program, \
     default_startup_program
 from .initializer import Constant, Xavier
@@ -121,6 +122,9 @@ class LayerHelper(object):
         attr.initializer(sp_var, startup_blk)
         main_blk = self.main_program.global_block()
         if attr.name in main_blk.vars:
+            # one weight, one optimizer state, the gradient the sum of
+            # its uses: counted where a name is bound again
+            obs.counter('model.shared_param_uses').inc()
             return main_blk.vars[attr.name]
         return main_blk.create_parameter(
             name=attr.name, shape=shape, dtype=dtype,

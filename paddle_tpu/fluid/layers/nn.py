@@ -14,7 +14,8 @@ from .. import unique_name
 from . import tensor as tensor_mod
 
 __all__ = [
-    'fc', 'embedding', 'moe_mlp', 'dynamic_lstm', 'dynamic_lstmp', 'dynamic_gru',
+    'fc', 'embedding', 'moe_mlp', 'router_bias_update', 'latent_attention',
+    'dynamic_lstm', 'dynamic_lstmp', 'dynamic_gru',
     'gru_unit', 'linear_chain_crf', 'crf_decoding', 'cos_sim',
     'cross_entropy', 'square_error_cost', 'chunk_eval', 'sequence_conv',
     'conv2d', 'conv3d', 'sequence_pool', 'sequence_softmax', 'softmax',
@@ -1013,6 +1014,66 @@ def fused_attention(q, k, v, key_bias=None, causal=False, scale=None,
     return out
 
 
+def latent_attention(input, size, num_heads, q_lora_rank, kv_lora_rank,
+                     qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                     rope_theta=10000.0, epsilon=1e-05, param_attr=None,
+                     name=None):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434, section
+    2.1), causal, over ``input`` [B, T, d]: queries, keys and values are
+    made from low-rank latents, and the rotary part of a key is ONE head
+    that all query heads share.
+
+        cq           = rms_norm(input Wqa)              d -> q_lora_rank
+        q            = cq Wqb      per head [q_nope | q_rope]
+        [ckv | kr]   = input Wkva             d -> kv_lora_rank + rope dim
+        [k_nope | v] = rms_norm(ckv) Wkvb     per head
+        q_rope, kr   = rotary (rotate-half over the whole rope part)
+        q_h = [q_nope_h | q_rope_h];  k_h = [k_nope_h | kr]
+        out = concat_h(causal_softmax(q_h k_h^T / sqrt(width)) v_h) Wo
+
+    No biases. Built from fc, rms_norm, split, rotary_embedding, expand,
+    concat and fused_attention (the flash kernels on the TPU), so the
+    keys' and the values' head width must be one:
+    ``qk_nope_head_dim + qk_rope_head_dim == v_head_dim``. What the
+    training step keeps of it is the recompute region's to say
+    (fluid.recompute_guard). Parameters in creation order: Wqa, the query
+    latent's norm, Wqb, Wkva, the key-value latent's norm, Wkvb, Wo;
+    `param_attr` (its initializer) serves the five matrices. Returns
+    [B, T, size]. TPU extension (the reference predates it)."""
+    h, nope, rope = int(num_heads), int(qk_nope_head_dim), \
+        int(qk_rope_head_dim)
+    width = nope + rope
+    if width != int(v_head_dim):
+        raise ValueError('latent_attention: keys of %d + %d and values of '
+                         '%d: the attention kernels take one head width'
+                         % (nope, rope, v_head_dim))
+
+    def proj(x, n):
+        return fc(input=x, size=n, num_flatten_dims=2, bias_attr=False,
+                  param_attr=copy.deepcopy(ParamAttr.to_attr(param_attr)))
+
+    def heads(x, n, d):
+        return transpose(reshape(x, shape=[0, 0, n, d]), perm=[0, 2, 1, 3])
+
+    cq = rms_norm(proj(input, int(q_lora_rank)), epsilon=epsilon)
+    q_nope, q_rope = split(heads(proj(cq, h * width), h, width),
+                           [nope, rope], dim=-1)
+    ckv, kr = split(proj(input, int(kv_lora_rank) + rope),
+                    [int(kv_lora_rank), rope], dim=-1)
+    k_nope, v = split(
+        heads(proj(rms_norm(ckv, epsilon=epsilon), h * (nope + width)), h,
+              nope + width), [nope, width], dim=-1)
+    q_rope = rotary_embedding(q_rope, base=rope_theta)
+    kr = expand(rotary_embedding(heads(kr, 1, rope), base=rope_theta),
+                expand_times=[1, h, 1, 1])
+    ctx = fused_attention(
+        tensor_mod.concat([q_nope, q_rope], axis=-1),
+        tensor_mod.concat([k_nope, kr], axis=-1), v, causal=True,
+        scale=width ** -0.5)
+    ctx = reshape(transpose(ctx, perm=[0, 2, 1, 3]), shape=[0, 0, h * width])
+    return proj(ctx, int(size))
+
+
 def topk(input, k, name=None):
     helper = LayerHelper("top_k", **locals())
     values = helper.create_variable_for_type_inference(dtype=input.dtype)
@@ -1532,7 +1593,8 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
             capacity_factor=2.0, gate_param_attr=None, param_attr=None,
             bias_attr=None, name=None, top_k=1, return_aux_loss=False,
             gated=False, norm_topk_prob=True, return_expert_count=False,
-            experts_held=None):
+            experts_held=None, scoring='softmax', selection_bias=False,
+            gate_scale=1.0):
     """Top-k gated mixture-of-experts FFN (TPU extension; the reference
     predates MoE — its conditional-computation ancestor is layers.Switch).
 
@@ -1571,6 +1633,18 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
     imbalance, and assignments to absent experts cost no matmul tile.
     ``None``: every expert is here.
 
+    ``scoring='sigmoid'`` (dropless only) scores each expert by itself,
+    sigmoid(logit), in place of the softmax over all (DeepSeek-V3,
+    arXiv:2412.19437); the gates are the chosen scores, renormalised over
+    the chosen under `norm_topk_prob`. ``selection_bias=True`` creates a
+    float32 ``[num_experts]`` persistable that starts at 0, is no
+    trainable parameter (no gradient, no optimizer state) and is added to
+    the scores for the CHOICE of the top k alone: the gates are taken
+    from the scores without it. It is returned last, for the model to
+    move by the experts' load (layers.router_bias_update). `gate_scale`
+    multiplies the gates after the renormalisation. Both come with the
+    sigmoid router and are refused under ``scoring='softmax'``.
+
     With return_aux_loss=True, also returns the scalar Switch/GShard
     load-balancing auxiliary loss (E * sum_e f_e * P_e, minimized at 1.0
     by a uniform router) to add to the training objective with a small
@@ -1580,7 +1654,8 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
 
     input: [N, d] tokens or [B, T, d] sequence activations.
     Returns the same shape with the last dim `size` (default d); with the
-    return_* flags a tuple (out[, aux_loss][, expert_count]).
+    return_* flags or `selection_bias` a tuple (out[, aux_loss]
+    [, expert_count][, selection_bias]).
     """
     from ..ops_impl.moe_ops import supported_acts
     if (act or None) is not None and act not in supported_acts():
@@ -1590,6 +1665,18 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
     if not 1 <= int(top_k) <= int(num_experts):
         raise ValueError('moe_mlp top_k=%r must be in [1, num_experts=%d]'
                          % (top_k, num_experts))
+    if scoring not in ('softmax', 'sigmoid'):
+        raise ValueError("moe_mlp scoring=%r: 'softmax' or 'sigmoid'"
+                         % (scoring,))
+    if capacity_factor is not None and (
+            scoring != 'softmax' or selection_bias or gate_scale != 1.0):
+        raise ValueError('moe_mlp: scoring, selection_bias and gate_scale '
+                         "are the dropless layer's; pass "
+                         'capacity_factor=None')
+    if scoring == 'softmax' and (selection_bias or gate_scale != 1.0):
+        raise ValueError("moe_mlp: selection_bias and gate_scale belong to "
+                         "scoring='sigmoid' (no model here has them under "
+                         'a softmax router)')
     n_held = int(num_experts)
     if experts_held is not None:
         first, n_held = (int(i) for i in experts_held)
@@ -1628,6 +1715,13 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
             inputs[slot] = [helper.create_parameter(
                 attr=copy.deepcopy(ParamAttr.to_attr(bias_attr)),
                 shape=[n_held, width], dtype=dtype, is_bias=True)]
+    bias = None
+    if selection_bias:
+        bias = helper.create_parameter(
+            attr=ParamAttr(trainable=False), shape=[int(num_experts)],
+            dtype='float32', default_initializer=Constant(0.0))
+        bias.stop_gradient = True
+        inputs['SelectionBias'] = [bias]
     # shapes declared here: inference stands the dynamic batch in by a
     # large prime, and at real widths batch x seq x top_k assignments pass
     # what the dropless path's int32 sort indices hold
@@ -1648,8 +1742,34 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
              'act': act or ''}
     if experts_held is not None:
         attrs['experts_held'] = [first, n_held]
+    if scoring != 'softmax':
+        attrs['scoring'] = scoring
+    if gate_scale != 1.0:
+        attrs['gate_scale'] = float(gate_scale)
     helper.append_op(type='moe_mlp', inputs=inputs, outputs=outputs,
                      attrs=attrs)
     got = (out,) + ((aux,) if return_aux_loss else ()) \
-        + ((count,) if return_expert_count else ())
+        + ((count,) if return_expert_count else ()) \
+        + ((bias,) if selection_bias else ())
     return got if len(got) > 1 else out
+
+
+def router_bias_update(bias, expert_count, rate=0.001):
+    """Moves a router's selection bias by the step's load, the
+    auxiliary-loss-free balancing of DeepSeek-V3 (arXiv:2412.19437,
+    section 2.1.2): ``b_e <- b_e + rate * sign(mean(c) - c_e)`` with `c`
+    the step's assignments per expert (`moe_mlp`'s expert count): an
+    expert that got less than the mean is chosen a little more easily the
+    next step. Plain ops (cast, reduce_mean, elementwise_sub, sign, scale,
+    assign) written back into `bias`, so the update is part of the one
+    compiled step; build it after ``minimize``. No gradient and no
+    optimizer is involved. Build-time counter `moe.bias_updates`."""
+    from ... import obs
+    from . import ops as ops_mod
+    obs.counter('moe.bias_updates').inc()
+    load = tensor_mod.cast(expert_count, 'float32')
+    over = ops_mod.sign(ops_mod.elementwise_sub(
+        load, reduce_mean(load, keep_dim=True)))
+    return tensor_mod.assign(
+        ops_mod.elementwise_sub(bias, ops_mod.scale(over, scale=float(rate))),
+        output=bias)

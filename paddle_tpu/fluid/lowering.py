@@ -10,7 +10,9 @@ fuses across op boundaries (the reference pays a kernel launch per op).
 The same rules power build-time shape inference via jax.eval_shape
 (framework.Block.append_op), so op semantics are defined exactly once.
 """
+import contextlib
 import functools
+import re
 
 import numpy as np
 
@@ -258,6 +260,16 @@ def first_seq(*vals):
     return None
 
 
+def scope_label(name):
+    """A `fluid.name_scope` prefix as it is written into op_name: what is
+    not a letter, a digit or `_` becomes `_`, and a name that ends in
+    `_<digits>` gets one more `_`, because `<type>_<index>` is how an op's
+    own scope reads and whoever parses op_name takes the innermost such
+    for the op (`layer_1` is written `layer_1_`)."""
+    label = re.sub(r'[^A-Za-z0-9_]', '_', name)
+    return label + '_' if re.search(r'_[0-9]+$', label) else label
+
+
 def run_op(op, env, ctx):
     """Resolve an op's inputs from env, apply its rule, bind outputs.
 
@@ -266,8 +278,16 @@ def run_op(op, env, ctx):
     came from: profiler traces and HLO dumps of the COMPILED fused step map
     back to program ops (the reference's per-op C++ event tracer,
     profiler.py:81-130, attributes the real run the same way — here the
-    attribution survives fusion instead of requiring the eager path)."""
-    with jax.named_scope('%s_%d' % (op.type, ctx.op_index)):
+    attribution survives fusion instead of requiring the eager path). The
+    scopes the op was built in (`fluid.name_scope`, the attribute
+    `name_scope`) are entered around it, outermost first, each as
+    `scope_label` writes it: `.../mtp/latent_attention/mul_17/...`."""
+    with contextlib.ExitStack() as scopes:
+        for name in op.attrs.get('name_scope', '').split('/'):
+            if name:
+                scopes.enter_context(jax.named_scope(scope_label(name)))
+        scopes.enter_context(
+            jax.named_scope('%s_%d' % (op.type, ctx.op_index)))
         if op.type in _BLOCK_RULES:
             _BLOCK_RULES[op.type](op, env, ctx)
             return

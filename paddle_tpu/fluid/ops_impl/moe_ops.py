@@ -69,12 +69,24 @@ the held experts is answered, on the device (one `lax.cond` a layer), by
 the static tokens x k rows a block at a time (`_held_blocks`). One
 device, as the whole dropless layer: the same refusal on a mesh.
 
+ANOTHER ROUTER (dropless only; `scoring`, `SelectionBias`, `gate_scale`):
+`scoring` 'sigmoid' scores every expert by itself, sigmoid(logit), where
+the default takes a softmax over all; an input `SelectionBias` [E]
+(float32, a persistable that is no trainable parameter) is added to the
+scores for the CHOICE of the top k and for nothing else: the gates are
+the chosen experts' scores without it, renormalised over the chosen
+under `norm_topk_prob`, times `gate_scale`; no gradient reaches the bias
+(parallel/moe.py router_topk: one code path for both scorings). Its
+owner moves it after the step from `ExpertCount`
+(layers.router_bias_update). `AuxLoss` stays the softmax form.
+
 Inside the op's `moe_mlp_<index>` scope the stages are named `moe_route`
 (logits, top-k, the sort and the gather of rows), `moe_experts` (the
 matmuls) and `moe_combine` (un-sort, gate weights, sum over k). Trace-time
 counters: `moe.lowered{path=grouped|capacity}` once per op per trace of
 the rule (a lowering, or build-time shape inference; a share adds the
-labels `held=<count>of<num_experts>` and `dispatch=index`),
+labels `held=<count>of<num_experts>` and `dispatch=index`, a sigmoid
+router the label `scoring=sigmoid`),
 `moe.assignments` the tokens x k of the traced shape.
 """
 import functools
@@ -415,9 +427,15 @@ def _moe_mlp(ins, attrs, ctx):
     nt = x.shape[0]
     held = attrs.get('experts_held')
     held = tuple(int(i) for i in held) if held else None
+    scoring = attrs.get('scoring') or 'softmax'
+    bias = data_of(ins['SelectionBias'][0]) if ins.get('SelectionBias') \
+        else None
+    labels = {'held': '%dof%d' % (held[1], n_exp), 'dispatch': 'index'} \
+        if held else {}
+    if scoring != 'softmax':
+        labels['scoring'] = scoring
     obs.counter('moe.lowered', path='grouped' if dropless else 'capacity',
-                **({'held': '%dof%d' % (held[1], n_exp), 'dispatch': 'index'}
-                   if held else {})).inc()
+                **labels).inc()
     obs.counter('moe.assignments').inc(nt * top_k)
 
     from ...parallel.moe import (DroplessOnMeshError, load_balancing_loss,
@@ -428,7 +446,9 @@ def _moe_mlp(ins, attrs, ctx):
                             gate_w.astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
         aux = load_balancing_loss(logits, top_k)
-        expert, gate = router_topk(logits, top_k, norm)        # [k, nt]
+        expert, gate = router_topk(
+            logits, top_k, norm, scoring, bias,
+            float(attrs.get('gate_scale', 1.0)))               # [k, nt]
         sizes = jnp.bincount(expert.reshape(-1), length=n_exp
                              ).astype(jnp.int32)
     params = dict(zip(params, amp_cast(ctx, *params.values())))

@@ -135,6 +135,7 @@ class StepArtifact(object):
         ad_idxs = [i for i, op in enumerate(ops) if op.type == 'autodiff']
         assert len(ad_idxs) <= 1, "at most one append_backward per program"
         self.ad_idx = ad_idxs[0] if ad_idxs else None
+        self.regions = self._recompute_regions(program)
         for op in (o for blk in program.blocks for o in blk.ops):
             # loud inertness check (docs/embedding.md): a TRAINING step
             # whose lookup was built for the distributed wire (annotated
@@ -222,11 +223,6 @@ class StepArtifact(object):
                 pnames, gnames, trainable, base, taps = \
                     self._grad_setup(env, ad)
                 fwd = self._make_fwd(base, ad, key, taps=taps)
-                if self.use_remat:
-                    # memory_optimize(): recompute forward activations in
-                    # the backward pass instead of saving them (the TPU
-                    # lever matching the reference's liveness buffer reuse).
-                    fwd = jax.checkpoint(fwd)
                 grads, env = jax.grad(fwd, has_aux=True)(trainable)
                 self._apply_grads(grads, env, ad, pnames, gnames)
                 if self.guard:
@@ -448,6 +444,73 @@ class StepArtifact(object):
         base = {k: v for k, v in env.items() if k not in trainable}
         return pnames, gnames, trainable, base, taps
 
+    def _recompute_regions(self, program):
+        """{first op: (one past the last, names read, names handed on)} of
+        the forward's recompute regions. A region is a run of ops that
+        one `fluid.recompute_guard()` built; a Program flagged by
+        `fluid.memory_optimize` that marks none has the whole forward as
+        its one region (the reference traded buffer reuse there; here
+        the trade is FLOPs for HBM). The backward pass recomputes a
+        region from the values it read, which is all of it the step
+        keeps (_run_region)."""
+        if not self.use_remat or self.ad_idx is None:
+            return {}
+        n = self.ad_idx
+        marks = [op.attrs.get('recompute') for op in self.ops[:n]]
+        spans, i = [], 0
+        while i < n:
+            j = i + 1
+            if marks[i] is not None:
+                while j < n and marks[j] == marks[i]:
+                    j += 1
+                spans.append((i, j))
+            i = j
+        spans = spans or [(0, n)]
+
+        def reads(op):
+            names = set(op.input_arg_names)
+            subs = list(op.attrs.get('sub_blocks') or [])
+            if op.attrs.get('sub_block') is not None:
+                subs.append(op.attrs['sub_block'])
+            for b in subs:
+                for inner in program.block(b).ops:
+                    names |= reads(inner)
+            return names
+
+        read_at = [reads(op) for op in self.ops]
+        always = set(self.fetch_names) | {
+            v.name for v in program.list_vars() if v.persistable}
+        regions = {}
+        for lo, hi in spans:
+            inside = set().union(*read_at[lo:hi])
+            outside = always.union(*(read_at[:lo] + read_at[hi:]))
+            written = {name for op in self.ops[lo:hi]
+                       for name in op.output_arg_names}
+            regions[lo] = (hi, sorted(inside), sorted(written & outside))
+        return regions
+
+    def _run_region(self, env, lo, key, taps):
+        """Ops [lo, hi) of one recompute region as ONE jax.checkpoint: the
+        backward pass is handed the region's inputs and runs its ops
+        again. Kept besides: what ops/flash_attention.py names
+        `flash_out` and `flash_lse`, an attention call's output and row
+        statistics (a sixth of its forward's bytes, and its forward is a
+        third of its work)."""
+        hi, read, handed_on = self.regions[lo]
+        tap_names = [taps[i][0] for i in range(lo, hi) if taps and i in taps]
+        inputs = {n: env[n] for n in read + tap_names if n in env}
+
+        def region(inputs):
+            e = dict(inputs)
+            self._run_ops(e, lo, hi, key, grad_mode=True, taps=taps,
+                          in_region=True)
+            return {n: e[n] for n in handed_on if n in e}
+
+        policy = jax.checkpoint_policies.save_only_these_names(
+            'flash_out', 'flash_lse')
+        env.update(jax.checkpoint(region, policy=policy)(inputs))
+        return hi
+
     def _make_fwd(self, base, ad, key, taps=None):
         """The differentiable forward closure: trainable -> (loss, env)."""
         def fwd(tr):
@@ -547,14 +610,23 @@ class StepArtifact(object):
                 new, old)
 
     def _run_ops(self, env, lo, hi, key, grad_mode=False, on_op=None,
-                 taps=None):
+                 taps=None, in_region=False):
         """Execute ops [lo, hi); on_op(i, op, seconds, env) — when set, each
         op is synchronized and timed (debug/profiling path, eager only).
         taps: {op_index: (tap_name, out_var_name)} — after the op at
         op_index runs, the zero tap joins its output so jax.grad yields the
-        per-row gradient there (sparse embedding path)."""
+        per-row gradient there (sparse embedding path). Under grad_mode a
+        recompute region (self.regions) runs as one jax.checkpoint
+        (_run_region, which comes back here with `in_region`)."""
         pipe = self.pipe
+        done = lo
         for i in range(lo, hi):
+            if i < done:
+                continue            # ran inside a recompute region
+            if grad_mode and on_op is None and not in_region \
+                    and i in self.regions:
+                done = self._run_region(env, i, key, taps)
+                continue
             if pipe is not None and on_op is None \
                     and pipe['region'][0] <= i < pipe['region'][1]:
                 if i == pipe['region'][0]:

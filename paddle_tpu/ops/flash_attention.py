@@ -68,6 +68,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -606,6 +607,9 @@ def _flash_lse(q, k, v, kb, causal, scale, bq, bk, one_pass, interpret):
 
 def _flash_lse_fwd(q, k, v, kb, causal, scale, bq, bk, one_pass, interpret):
     o, lse = _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret)
+    # named for a recompute region's policy (step_artifact._run_region):
+    # kept, the backward pass rebuilds q, k and v and not this call
+    o, lse = checkpoint_name(o, 'flash_out'), checkpoint_name(lse, 'flash_lse')
     return (o, lse[..., 0]), (q, k, v, kb, o, lse)
 
 

@@ -48,19 +48,47 @@ class DroplessOnMeshError(NotImplementedError):
 stack_expert_params = stack_unit_params
 
 
-def router_topk(logits, top_k, norm_topk_prob=True):
+def router_topk(logits, top_k, norm_topk_prob=True, scoring='softmax',
+                bias=None, gate_scale=1.0):
     """Routing decisions shared by every path.
 
     Returns (expert [k, nt] int, gate [k, nt] f32). k=1 keeps the Switch
     semantics (gate = raw softmax probability of the chosen expert); k>1
     renormalizes the selected probabilities to sum to 1 per token (GShard)
     unless `norm_topk_prob` is false (OLMoE: the raw probabilities).
+
+    `scoring` 'sigmoid' scores each expert by itself, sigmoid(logit)
+    (DeepSeek-V3, arXiv:2412.19437 section 2.1.2), and the sum the chosen
+    scores are renormalised by carries that source's 1e-20. `bias` [E]
+    moves the CHOICE and nothing else: the top k are taken of score +
+    bias, the gates from the scores without it, and no gradient reaches
+    it (its owner moves it by the experts' load, not by the loss).
+    `gate_scale` multiplies the gates last. Both belong to the sigmoid
+    router: under 'softmax' they are refused until a model brings them.
     """
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)  # [nt, E]
-    _, idx = lax.top_k(logits, top_k)                            # [nt, k]
-    gate = jnp.take_along_axis(probs, idx, axis=-1)              # [nt, k]
+    x = logits.astype(jnp.float32)
+    if scoring == 'softmax':
+        if bias is not None or gate_scale != 1.0:
+            raise NotImplementedError(
+                "router_topk: a selection bias or a gate scale under "
+                "scoring='softmax' (no model here routes so; 'sigmoid' "
+                "takes both)")
+        # the logits choose what the probabilities would
+        scores, pick, eps = jax.nn.softmax(x, axis=-1), logits, 0.0
+    elif scoring == 'sigmoid':
+        scores = pick = jax.nn.sigmoid(x)
+        eps = 1e-20
+    else:
+        raise ValueError("router scoring %r: 'softmax' or 'sigmoid'"
+                         % (scoring,))
+    if bias is not None:
+        pick = scores + lax.stop_gradient(bias.astype(jnp.float32))
+    _, idx = lax.top_k(pick, top_k)                              # [nt, k]
+    gate = jnp.take_along_axis(scores, idx, axis=-1)             # [nt, k]
     if top_k > 1 and norm_topk_prob:
-        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + eps)
+    if gate_scale != 1.0:
+        gate = gate * gate_scale
     return idx.T, gate.T
 
 
