@@ -40,6 +40,16 @@ that the forward scans, so the two cannot drift) and pulls the chunks'
 cotangents back through the recomputed stage (the l2 norm of q and k and
 the repeat of the key heads included).
 
+On the TPU, for a chunk of 64 and heads of whole lane tiles (`usable` of
+ops/kernels/gated_delta_intra.py), stage `gdn_intra` is a Pallas kernel
+forward and one backward that keep everything C x C of a chunk in VMEM:
+the same arithmetic in the same precisions, W, Qg, Kd and P handed over in
+the matmuls' dtype (what the scan's matmuls round them to anyway), the
+backward's recomputed and transposed passes one grid step. Every other
+platform and shape takes `_intra` below, the composition the kernel is
+tested against. The rule chooses on what it can see (platform, shapes,
+dtype); nothing else selects it.
+
 Under AMP the rule is one of the MXU's: q, k, v are cast to bf16
 (`lowering.amp_cast`; they are what the backward keeps) and every matmul
 of the two stages takes bf16 operands with float32 accumulation; the
@@ -58,7 +68,8 @@ last axis, statistics in float32; its backward keeps x and the gate
 (bf16 under AMP) and computes the rest again.
 
 Trace-time counters: `gdn.lowered{chunk=}` once per op per trace,
-`gdn.tokens` the B x T of the traced shape, `conv1d.lowered`,
+`gdn.intra{way=kernel|composed}` beside it (which way stage `gdn_intra`
+went), `gdn.tokens` the B x T of the traced shape, `conv1d.lowered`,
 `gated_rms_norm.lowered`.
 """
 import functools
@@ -69,6 +80,7 @@ import numpy as np
 from jax import lax
 
 from ... import obs
+from ...ops.kernels import gated_delta_intra as intra_kernel
 from ..lowering import register, data_of, amp_cast
 
 _SOLVE_BLOCK = 16
@@ -198,10 +210,12 @@ def _from_chunks(x, t):
 
 def _stage_intra(q, k, v, g, beta, cfg):
     """From the op's inputs to what the scan reads: q and k normalised
-    (float32), q scaled, each key head repeated for its value heads, all
-    cut into chunks (the padding tokens change nothing: k = 0, beta = 0,
-    g = 0), then stage `gdn_intra`."""
-    chunk, scale, l2norm, eps = cfg
+    (float32), q scaled, each key head repeated for its value heads
+    (`_intra`; the kernel reads a key head for each of them), all cut
+    into chunks (the padding tokens change nothing: k = 0, beta = 0,
+    g = 0), then stage `gdn_intra`: the kernel where `cfg` says so (with G
+    summed here: a scan over 64 that XLA does in passing), else `_intra`."""
+    chunk, scale, l2norm, eps, kernel = cfg
     dtype = v.dtype
     qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
     if l2norm:
@@ -209,12 +223,16 @@ def _stage_intra(q, k, v, g, beta, cfg):
                   for x in (qf, kf))
     qf = qf * scale
     rep = v.shape[2] // q.shape[2]
-    if rep > 1:
+    if rep > 1 and not kernel:      # the kernel reads a key head in place
         qf, kf = (jnp.repeat(x, rep, axis=2) for x in (qf, kf))
-    return _intra(_to_chunks(qf.astype(dtype), chunk),
-                  _to_chunks(kf.astype(dtype), chunk), _to_chunks(v, chunk),
-                  _to_chunks(g.astype(jnp.float32), chunk),
-                  _to_chunks(beta.astype(jnp.float32), chunk))
+    q, k, v, g, beta = (
+        _to_chunks(x, chunk) for x in (
+            qf.astype(dtype), kf.astype(dtype), v, g.astype(jnp.float32),
+            beta.astype(jnp.float32)))
+    if kernel:
+        return intra_kernel.gated_delta_intra(
+            q, k, v, jnp.cumsum(g, axis=-1), beta, False)
+    return _intra(q, k, v, g, beta)
 
 
 def _zero_state(q, v):
@@ -270,19 +288,25 @@ def _chunked_bwd(cfg, res, do):
 _chunked.defvjp(_chunked_fwd, _chunked_bwd)
 
 
+def _chunk_of(chunk_size, t):
+    """`chunk_size`, or for a shorter row the power of two that holds it."""
+    return min(int(chunk_size), 1 << max(t - 1, 0).bit_length())
+
+
 def gated_delta_rule(q, k, v, g, beta, chunk_size=64, scale=None,
-                     qk_l2norm=False, l2norm_eps=1e-6):
+                     qk_l2norm=False, l2norm_eps=1e-6, kernel=False):
     """q, k [B, T, Hk, Dk], v [B, T, Hv, Dv] (float32, or bf16 for bf16
     matmuls), g, beta [B, T, Hv]; Hk divides Hv and key head h serves the
     value heads h * Hv / Hk and following. Returns o [B, T, Hv, Dv]
     float32. `qk_l2norm`: q and k are first divided by their norm over
     Dk, x * rsqrt(sum x^2 + eps), in float32; then q is scaled (`scale`,
-    default Dk^-0.5)."""
-    t, dk = q.shape[1], q.shape[3]
+    default Dk^-0.5). `kernel`: stage `gdn_intra` as the Pallas kernel
+    (the rule's choice; the caller has asked its `usable`)."""
+    dk = q.shape[3]
     scale = dk ** -0.5 if scale is None else float(scale)
-    chunk = min(int(chunk_size), 1 << max(t - 1, 0).bit_length())
     return _chunked(q, k, v, g, beta,
-                    (chunk, scale, bool(qk_l2norm), float(l2norm_eps)))
+                    (_chunk_of(chunk_size, q.shape[1]), scale,
+                     bool(qk_l2norm), float(l2norm_eps), bool(kernel)))
 
 
 @register('gated_delta_rule')
@@ -293,12 +317,19 @@ def _gated_delta_rule(ins, attrs, ctx):
     obs.counter('gdn.lowered', chunk=chunk).inc()            # trace time
     obs.counter('gdn.tokens').inc(int(v.shape[0]) * int(v.shape[1]))
     q, k, v = amp_cast(ctx, q, k, v)
+    # stage `gdn_intra`: the Pallas kernel on the TPU for a shape it takes,
+    # as the expert layer takes its grouped matmul there
+    kernel = ctx.platform == 'tpu' and intra_kernel.usable(
+        _chunk_of(chunk, q.shape[1]), q.shape[3], v.shape[3], v.dtype)
+    obs.counter('gdn.intra',                                 # trace time
+                way='kernel' if kernel else 'composed').inc()
     scale = attrs.get('scale', -1.0)
     o = gated_delta_rule(
         q, k, v, g, beta, chunk_size=chunk,
         scale=None if scale is None or scale < 0 else float(scale),
         qk_l2norm=bool(attrs.get('qk_l2norm', False)),
-        l2norm_eps=float(attrs.get('l2norm_eps', 1e-6)))
+        l2norm_eps=float(attrs.get('l2norm_eps', 1e-6)),
+        kernel=kernel)
     return {'Out': o}
 
 

@@ -1,0 +1,339 @@
+"""Stage `gdn_intra` of the gated delta rule as one Pallas kernel forward
+and one backward: everything C x C of a chunk (the decays D, K K^T, A, the
+unit lower triangular solve T = (I + A)^-1, Q K^T) lives in VMEM for the
+grid step that needs it, and only what the scan reads leaves.
+
+The mathematics, its precisions and the names are those of
+fluid/ops_impl/linear_attention_ops.py `_intra`, which stays as the
+composition this is tested against and as every other platform's path:
+
+    D   = exp(G_i - G_j) for i >= j, 0 above     G the in-chunk running sum
+    A   = strict_lower(diag(beta) (K K^T) * D)
+    T   = (I + A)^-1           float32: diagonal blocks of 16 by forward
+                               substitution, then two levels of merges
+    U   = T diag(beta) V       W = T diag(beta exp G) K
+    P   = (Q K^T) * D          Qg = diag(exp G) Q
+    Kd  = diag(exp(G_C - G)) K
+
+Operands of the matmuls in `dtype` (bf16 under AMP, float32 accumulation;
+float32 operands multiply at full precision, written in the body: a
+`jax.default_matmul_precision` context does not reach a kernel); decays,
+solve and every elementwise product in float32. W, Qg, Kd, P leave in
+`dtype`, which is what the scan's matmuls round them to anyway, U in
+float32.
+
+The solve in the form Mosaic lowers. A head's four diagonal blocks are
+eliminated side by side, column by column (row i of a block's inverse minus
+a[i, k] times row k, k = 0..15: the substitution's sums, accumulated in
+the order of k); a level of merges is two 64 x 64 products
+X - X M X over the block diagonal X so far, M the blocks of A one level
+off it (what `_inverse` computes a pair of blocks at a time: the terms
+added are exact zeros).
+
+The backward kernel computes the chunk again in VMEM, but for the solve,
+and pulls the five cotangents back in the same grid step; the solve's part
+is -T^T dT T^T on the strict lower triangle. T is the one C x C array of
+the chunk it reads (beside P's cotangent): the op's backward runs the forward kernel again for what its scans
+read, and that run writes T out (float32, 67 MB a layer at 4096
+chunk-heads, alive from there to the backward kernel of the same layer):
+a third of a step's solves for 0.16 ms of traffic a layer.
+
+`interpret` as every kernel here: True for the Pallas interpreter, False
+for Mosaic. Not under the PADDLE_TPU_KERNELS knob: like the flash kernels
+and the grouped matmul it is what the op lowers to on the TPU.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ['gated_delta_intra', 'usable', 'HEADS']
+
+# heads a grid step at 2-byte operands (tools/bench_gated_delta_intra.py
+# --sweep; docs/perf.md has the rows); 4-byte operands take half
+HEADS = 8
+_CHUNK = 64
+_BLOCK = 16          # linear_attention_ops._SOLVE_BLOCK
+
+
+def usable(chunk, dk, dv, dtype):
+    """A chunk of 64 (the solve's four blocks of 16 and two merges are
+    written out), heads of whole lane tiles, bf16 or float32 operands."""
+    return (chunk == _CHUNK and dk % 128 == 0 and dv % 128 == 0
+            and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
+                                     jnp.dtype(jnp.float32)))
+
+
+def _dot(a, b, dims, dtype):
+    """a x b on the MXU, operands in `dtype`, float32 out. `dims`: 'nn'
+    a b, 'nt' a b^T, 'tn' a^T b."""
+    contract = {'nn': ((1,), (0,)), 'nt': ((1,), (1,)),
+                'tn': ((0,), (0,))}[dims]
+    precision = lax.Precision.HIGHEST if dtype == jnp.float32 else None
+    return lax.dot_general(a.astype(dtype), b.astype(dtype),
+                           (contract, ((), ())), precision=precision,
+                           preferred_element_type=jnp.float32)
+
+
+def _iotas(c, rows=None):
+    """Row and column numbers of a [rows, c] array (rows: c). Each made at
+    its own shape: Mosaic does not slice an iota."""
+    shape = (c if rows is None else rows, c)
+    return (lax.broadcasted_iota(jnp.int32, shape, 0),
+            lax.broadcasted_iota(jnp.int32, shape, 1))
+
+
+def _column(x_row, eye):
+    """[1, c] -> [c, 1]: the diagonal of the row spread over c rows."""
+    return jnp.sum(jnp.where(eye, x_row, 0.0), axis=1, keepdims=True)
+
+
+def _as_row(x_col, eye):
+    """[c, 1] -> [1, c]"""
+    return jnp.sum(jnp.where(eye, x_col, 0.0), axis=0, keepdims=True)
+
+
+def _solve(mats):
+    """(I + a)^-1 of each strictly lower triangular a [64, 64] float32 of
+    the list: the heads of a grid step side by side, step by step, so that
+    one head's chain of dependent steps runs between the others'."""
+    c = mats[0].shape[0]
+    nb, half = c // _BLOCK, _BLOCK // 2
+    # the diagonal blocks, each as its 16 rows of the whole width, in two
+    # halves of eight rows: the upper is final after its eight columns
+    row8, col8 = _iotas(c, half)
+    eyes = [[(row8 + (b * _BLOCK + r) == col8).astype(jnp.float32)
+             for r in (0, half)] for b in range(nb)]
+    xs = [[list(e) for e in eyes] for _ in mats]
+    for k in range(_BLOCK - 1):
+        for a, x in zip(mats, xs):
+            for b in range(nb):
+                lo = b * _BLOCK
+                up, low = x[b]
+                pivot = up[k:k + 1] if k < half else low[k - half:k - half + 1]
+                coef = a[lo:lo + _BLOCK, lo + k:lo + k + 1]       # [16, 1]
+                if k < half:
+                    up = up - coef[:half] * pivot
+                x[b] = [up, low - coef[half:] * pivot]
+    xs = [jnp.concatenate([r for blk in x for r in blk], axis=0) for x in xs]
+    row, col = _iotas(c)
+    size = _BLOCK
+    while size < c:
+        # the blocks one level off the diagonal: odd block row, the even
+        # block column before it
+        br, bc = row // size, col // size
+        off = (br % 2 == 1) & (bc == br - 1)
+        ps = [_dot(x, jnp.where(off, a, 0.0), 'nn', jnp.float32)
+              for x, a in zip(xs, mats)]
+        xs = [x - _dot(p, x, 'nn', jnp.float32) for x, p in zip(xs, ps)]
+        size *= 2
+    return xs
+
+
+def _chunks(q_ref, k_ref, v_ref, gb_ref, dtype):
+    """What forward and backward both need of a grid step's chunk-heads:
+    a dict a value head. q, k [C, Dk] (its key head's), v [C, Dv] in
+    `dtype`, gb [2, C] float32 (G, beta)."""
+    c = q_ref.shape[2]
+    row, col = _iotas(c)
+    eye, lower = row == col, row >= col
+    rep = v_ref.shape[1] // q_ref.shape[1]    # value heads a key head
+    heads = []
+    for h in range(v_ref.shape[1]):
+        if h % rep == 0:            # a key head's products, once
+            q, k = q_ref[0, h // rep], k_ref[0, h // rep]
+            kk, qk = _dot(k, k, 'nt', dtype), _dot(q, k, 'nt', dtype)
+            qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
+        g_row = gb_ref[0, h, 0:1, :]
+        g_col = _column(g_row, eye)
+        beta = _column(gb_ref[0, h, 1:2, :], eye)
+        decay = jnp.where(
+            lower, jnp.exp(jnp.where(lower, g_col - g_row, 0.0)), 0.0)
+        a0 = jnp.where(row > col, kk * decay, 0.0)
+        last = jnp.sum(jnp.where(col == c - 1, g_row, 0.0), axis=1,
+                       keepdims=True)                 # G_C in every row
+        heads.append(dict(
+            q=q, k=k, beta=beta, decay=decay, kk=kk, qk=qk, a0=a0,
+            e_g=jnp.exp(g_col), e_last=jnp.exp(last - g_col), qf=qf,
+            kf=kf, vf=v_ref[0, h].astype(jnp.float32)))
+    return heads
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, gb_ref, w_ref, u_ref, qg_ref, kd_ref,
+                p_ref, *rest, dtype):
+    heads = _chunks(q_ref, k_ref, v_ref, gb_ref, dtype)
+    solved = _solve([x['a0'] * x['beta'] for x in heads])
+    for h, (x, t) in enumerate(zip(heads, solved)):
+        kf = x['kf']
+        if rest:                 # the backward's residual
+            rest[0][0, h] = t
+        u_ref[0, h] = _dot(t, x['vf'] * x['beta'], 'nn', dtype)
+        w_ref[0, h] = _dot(t, kf * (x['beta'] * x['e_g']), 'nn',
+                           dtype).astype(dtype)
+        p_ref[0, h] = (x['qk'] * x['decay']).astype(dtype)
+        qg_ref[0, h] = (x['qf'] * x['e_g']).astype(dtype)
+        kd_ref[0, h] = (kf * x['e_last']).astype(dtype)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, gb_ref, t_ref, dw_ref, du_ref, dqg_ref,
+                dkd_ref, dp_ref, dq_ref, dk_ref, dv_ref, dgb_ref, *, dtype):
+    f32 = jnp.float32
+    c = q_ref.shape[2]
+    row, col = _iotas(c)
+    eye, strict, last = row == col, row > col, _iotas(c, 1)[1] == c - 1
+    heads = _chunks(q_ref, k_ref, v_ref, gb_ref, dtype)
+    # stage by stage over the heads, as `_solve`: the chains of dependent
+    # matmuls of one head between the others'
+    for h, x in enumerate(heads):
+        x['t'] = t_ref[0, h]
+        x['g_w'], x['g_u'] = dw_ref[0, h], du_ref[0, h]
+        x['scale_k'] = x['beta'] * x['e_g']
+        # U = T (beta V), W = T (beta exp(G) K)
+        x['d_t'] = _dot(x['g_u'], x['vf'] * x['beta'], 'nt', dtype) \
+            + _dot(x['g_w'], x['kf'] * x['scale_k'], 'nt', dtype)
+    # T = (I + A)^-1: dA = -T^T dT T^T on the strict lower triangle
+    for x in heads:
+        x['tt_dt'] = _dot(x['t'], x['d_t'], 'tn', f32)
+    for x in heads:
+        x['d_a'] = jnp.where(strict, -_dot(x['tt_dt'], x['t'], 'nt', f32),
+                             0.0)
+    for h, x in enumerate(heads):
+        q, k, beta, decay, kk, qk, a0, t, d_a, scale_k = (x[n] for n in (
+            'q', 'k', 'beta', 'decay', 'kk', 'qk', 'a0', 't', 'd_a',
+            'scale_k'))
+        e_g, e_last, qf, kf, vf = (x[n] for n in
+                                   ('e_g', 'e_last', 'qf', 'kf', 'vf'))
+        g_qg, g_kd, g_p = (r[0, h].astype(f32) for r in (
+            dqg_ref, dkd_ref, dp_ref))
+        d_vb = _dot(t, x['g_u'], 'tn', dtype)
+        d_kb = _dot(t, x['g_w'], 'tn', dtype)
+        d_a0 = d_a * beta
+        d_kk = d_a0 * decay
+        d_qk = g_p * decay
+        x['dq'] = _dot(d_qk, k, 'nn', dtype) + g_qg * e_g
+        x['dk'] = _dot(d_qk, q, 'tn', dtype) + _dot(d_kk, k, 'nn', dtype) \
+            + _dot(d_kk, k, 'tn', dtype) + d_kb * scale_k + g_kd * e_last
+        dv_ref[0, h] = (d_vb * beta).astype(dtype)
+        kb_sum = jnp.sum(d_kb * kf, axis=1, keepdims=True)
+        d_beta = jnp.sum(d_a * a0, axis=1, keepdims=True) \
+            + jnp.sum(d_vb * vf, axis=1, keepdims=True) + kb_sum * e_g
+        d_eg = kb_sum * beta + jnp.sum(g_qg * qf, axis=1, keepdims=True)
+        d_last = jnp.sum(g_kd * kf, axis=1, keepdims=True) * e_last
+        d_diff = (d_a0 * kk + g_p * qk) * decay
+        d_g = d_eg * e_g - d_last + jnp.sum(d_diff, axis=1, keepdims=True)
+        d_g = _as_row(d_g, eye) - jnp.sum(d_diff, axis=0, keepdims=True) \
+            + jnp.where(last, jnp.sum(d_last, axis=0, keepdims=True), 0.0)
+        dgb_ref[0, h, 0:1, :] = d_g
+        dgb_ref[0, h, 1:2, :] = _as_row(d_beta, eye)
+    # a key head's gradient: the sum over the value heads it serves
+    rep = v_ref.shape[1] // q_ref.shape[1]
+    for kh in range(q_ref.shape[1]):
+        served = heads[kh * rep:(kh + 1) * rep]
+        dq_ref[0, kh] = sum(x['dq'] for x in served).astype(dtype)
+        dk_ref[0, kh] = sum(x['dk'] for x in served).astype(dtype)
+
+
+def _heads(hv, rep, dtype):
+    """Value heads a grid step: the largest divisor of hv within HEADS
+    (half of it at 4-byte operands, whose blocks are twice the bytes)
+    that holds whole groups of the `rep` value heads a key head serves."""
+    n = max(rep, HEADS * 2 // jnp.dtype(dtype).itemsize)
+    while hv % n or n % rep:
+        n -= 1
+    return n
+
+
+def _call(kernel, ins, outs, heads, interpret):
+    """One grid step a row of the chunks and `heads` value heads; of the
+    arrays over key heads (q, k and their gradients) the heads those take
+    their keys from."""
+    rows, hv = ins[2].shape[:2]
+
+    def specs(shapes):
+        return [pl.BlockSpec(
+            (1, heads * s[1] // hv) + s[2:],
+            lambda i, j, n=len(s): (i, j) + (0,) * (n - 2)) for s in shapes]
+
+    return pl.pallas_call(
+        kernel, grid=(rows, hv // heads),
+        in_specs=specs([a.shape for a in ins]),
+        out_specs=specs([o.shape for o in outs]),
+        out_shape=outs, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'parallel')))(*ins)
+
+
+def _flat(x):
+    """[N, B, H, ...] -> [N x B, H, ...]"""
+    return x.reshape((-1,) + x.shape[2:])
+
+
+# Both calls are jitted functions of their own: an op is traced several
+# times a Program (custom_vjp traces the primal and its forward rule, the
+# backward runs the forward again) and a model has several such ops, and a
+# kernel body is thousands of operations to trace and to lower. jit keeps
+# one trace a shape and emits one function a module, called from each
+# place under that place's scopes.
+@functools.partial(jax.jit, static_argnames=('interpret', 'heads', 'solved'))
+def _forward(q, k, v, gb, *, interpret, heads, solved):
+    """The forward kernel; `solved` adds T [rows, H, C, C] float32 to what
+    it writes (what the backward keeps of the chunk it computed again)."""
+    dtype = v.dtype
+    like = jax.ShapeDtypeStruct
+    keys = v.shape[:3] + q.shape[3:]              # a row a value head
+    scores = v.shape[:3] + (v.shape[2],)
+    outs = [like(keys, dtype), like(v.shape, jnp.float32),
+            like(keys, dtype), like(keys, dtype), like(scores, dtype)]
+    if solved:
+        outs.append(like(scores, jnp.float32))
+    return tuple(_call(functools.partial(_fwd_kernel, dtype=dtype),
+                       (q, k, v, gb), outs, heads, interpret))
+
+
+@functools.partial(jax.jit, static_argnames=('interpret', 'heads'))
+def _backward(res, cts, *, interpret, heads):
+    outs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in res[:4]]
+    return tuple(_call(functools.partial(_bwd_kernel, dtype=res[2].dtype),
+                       tuple(res) + tuple(cts), outs, heads, interpret))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _intra(q, k, v, gb, interpret, heads):
+    return _forward(q, k, v, gb, interpret=interpret, heads=heads,
+                    solved=False)
+
+
+def _intra_fwd(q, k, v, gb, interpret, heads):
+    outs = _forward(q, k, v, gb, interpret=interpret, heads=heads,
+                    solved=True)
+    return outs[:5], (q, k, v, gb, outs[5])
+
+
+def _intra_bwd(interpret, heads, res, g):
+    return _backward(res, g, interpret=interpret, heads=heads)
+
+
+_intra.defvjp(_intra_fwd, _intra_bwd)
+
+
+def gated_delta_intra(q, k, v, g_sum, beta, interpret, heads=None):
+    """q, k [N, B, Hk, C, Dk] (normalised, q scaled), v [N, B, Hv, C, Dv]
+    in the matmuls' dtype, g_sum, beta [N, B, Hv, C] float32, g_sum the
+    running sum of g inside each chunk. Hk divides Hv and key head h
+    serves the value heads h * Hv / Hk and following: a grid step reads a
+    key head once for the value heads it serves, and the backward adds
+    their gradients up in VMEM. Returns (W, U, Qg, Kd, P, decay of the
+    chunk) a VALUE head, as `_intra` of linear_attention_ops does on
+    repeated key heads, W, Qg, Kd, P in the matmuls' dtype and U in
+    float32. Differentiable in all five. `heads` overrides the value
+    heads a grid step takes (the sweep's door: a multiple of Hv / Hk that
+    divides Hv)."""
+    lead, hv = q.shape[:2], v.shape[2]
+    heads = heads or _heads(hv, hv // q.shape[2], v.dtype)
+    gb = jnp.stack([g_sum, beta], axis=-2).astype(jnp.float32)
+    outs = _intra(_flat(q), _flat(k), _flat(v), _flat(gb), interpret, heads)
+    return tuple(o.reshape(lead + o.shape[1:]) for o in outs) \
+        + (jnp.exp(g_sum[..., -1]),)

@@ -1,5 +1,5 @@
-"""The flash kernels at their default tiles, and the grouped-matmul
-kernels at theirs, compiled by Mosaic for a
+"""The flash kernels at their default tiles, the grouped-matmul kernels at
+theirs and the delta rule's chunk kernels, compiled by Mosaic for a
 DESCRIBED v5e (no chip, nothing runs): what the interpreter and
 jax.export cannot refuse — VMEM the kernel may not have, slices Mosaic
 will not tile — is refused here. The cells' shapes, and the shapes on
@@ -89,3 +89,28 @@ def test_grouped_matmul_tiles_compile_for_v5e(one_chip, dtype, rows):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, up, down, sizes).compile()
     assert compiled.as_text().count('tpu_custom_call') == 6
+
+
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+def test_gated_delta_intra_compiles_for_v5e(one_chip, dtype):
+    """qwen3next_s8192's stage `gdn_intra` (128 chunks of 64 tokens, 16
+    key heads serving 32 value heads of 128), forward and backward, in the cell's bf16 and in its
+    float32 check's arithmetic, at the heads a grid step each takes:
+    Mosaic slices no iota and broadcasts no [1, 1] both ways, which the
+    interpreter lets pass (AOT, PR 34)."""
+    from paddle_tpu.ops.kernels.gated_delta_intra import gated_delta_intra
+    dt = jnp.dtype(dtype)
+    keys = jax.ShapeDtypeStruct((128, 1, 16, 64, 128), dt,
+                                sharding=one_chip)
+    x = jax.ShapeDtypeStruct((128, 1, 32, 64, 128), dt, sharding=one_chip)
+    gate = jax.ShapeDtypeStruct((128, 1, 32, 64), jnp.float32,
+                                sharding=one_chip)
+
+    def loss(q, k, v, g_sum, beta):
+        return sum(jnp.sum(o.astype(jnp.float32)) for o in
+                   gated_delta_intra(q, k, v, g_sum, beta, False))
+
+    compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        keys, keys, x, gate, gate).compile()
+    # the forward (it writes T for the backward) and the backward
+    assert compiled.as_text().count('tpu_custom_call') == 2

@@ -1,0 +1,211 @@
+"""Stage `gdn_intra` of the gated delta rule as a Pallas kernel (ISSUE 34):
+the kernel bodies in the interpreter against the composition they replace
+on the TPU (`linear_attention_ops._intra` and `jax.vjp` of it), the whole
+op through the kernel against the token-by-token recurrence, and the
+rule's choice between the two. Heads of 128, which the kernel asks for;
+short rows and few heads keep the interpreter cheap. On the CPU."""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu import obs
+from paddle_tpu.fluid import layers, lowering
+from paddle_tpu.fluid.ops_impl import linear_attention_ops as la
+from paddle_tpu.ops.kernels import gated_delta_intra as gdi
+
+from test_qwen3_next import _grads_of, _input, plain_delta_net
+
+BF16_ULP = 2.0 ** -8
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """The rule hands the kernel `interpret=False` (Mosaic); here its
+    bodies run in the Pallas interpreter."""
+    real = gdi.gated_delta_intra
+    monkeypatch.setattr(
+        gdi, 'gated_delta_intra',
+        lambda q, k, v, g_sum, beta, interpret, heads=None: real(
+            q, k, v, g_sum, beta, True, heads))
+
+
+def op_inputs(seed, t, hk, hv, gates, dtype=jnp.float32):
+    """As test_qwen3_next.delta_inputs draws them, at heads of 128."""
+    rng = np.random.default_rng(seed)
+    b, d = 1, 128
+    q, k = (jnp.asarray(rng.normal(size=(b, t, hk, d)), dtype)
+            for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(b, t, hv, d)), dtype)
+    lo, hi = (5, 12) if gates == 'strong' else (0, 0.3)
+    g = -jnp.asarray(rng.uniform(lo, hi, size=(b, t, hv)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0, 1, size=(b, t, hv)), jnp.float32)
+    return q, k, v, g, beta
+
+
+def _stage(kernel, dtype):
+    """Stage `gdn_intra` from the op's inputs (norm, scale, the key heads'
+    repeat, the chunks and their padding included), its six outputs in
+    the dtypes the kernel hands the scan."""
+    cfg = (64, 128 ** -0.5, True, 1e-6, kernel)
+
+    def stage(*args):
+        w, u, qg, kd, p, decay = la._stage_intra(*args, cfg)
+        return (w.astype(dtype), u, qg.astype(dtype), kd.astype(dtype),
+                p.astype(dtype), decay)
+    return stage
+
+
+# (T, key heads, value heads): a row whose last chunk is 36 tokens and 28
+# of padding, one key head serving two value heads; whole chunks, a key
+# head a value head
+ROWS = {'padded_shared_keys': (100, 1, 2), 'whole_chunks': (128, 2, 2)}
+
+
+@pytest.mark.parametrize('gates', ['mild', 'strong'])
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize('rows', list(ROWS))
+def test_kernel_is_the_composition(rows, dtype, gates, interpreted):
+    """The six outputs and the gradients pulled back to the op's five
+    inputs: float32 to 1e-5, bf16 to 2 ulp of bf16."""
+    dtype = jnp.dtype(dtype)
+    args = op_inputs(len(rows) + len(gates), *ROWS[rows], gates, dtype)
+    names = ('w', 'u', 'qg', 'kd', 'p', 'decay')
+    with jax.default_matmul_precision('highest'):
+        want, pull_want = jax.vjp(_stage(False, dtype), *args)
+        got, pull_got = jax.vjp(_stage(True, dtype), *args)
+        cts = tuple(jnp.asarray(np.random.default_rng(i).normal(
+            size=o.shape), o.dtype) for i, o in enumerate(want))
+        g_want, g_got = pull_want(cts), pull_got(cts)
+    tol = 1e-5 if dtype == jnp.float32 else 2 * BF16_ULP
+    for name, a, b in zip(names, got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        # an element against its own size, or where sums cancel against
+        # the float32 rounding of the row's largest
+        slack = 1e-6 * np.abs(b).max(-1, keepdims=True) + 1e-30
+        assert np.all(np.abs(a - b) <= tol * np.abs(b) + slack), name
+    for name, a, b in zip(('q', 'k', 'v', 'g', 'beta'), g_got, g_want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        # 'strong' gates leave g's gradient to what rounding leaves of
+        # decays of e^-5 and less: against the largest gradient there
+        scale = np.linalg.norm(b) if name != 'g' else max(
+            np.linalg.norm(b), 1e-3 * np.linalg.norm(np.asarray(
+                g_want[4], np.float32)))
+        assert np.linalg.norm(a - b) <= tol * scale, (
+            name, np.linalg.norm(a - b) / scale)
+
+
+@pytest.mark.parametrize('gates', ['mild', 'strong'])
+def test_the_op_through_the_kernel_is_the_recurrence(gates, interpreted):
+    """Values and all five gradients against the token-by-token
+    definition, float32; the row ends in a padded chunk and each key head
+    serves two value heads."""
+    args = op_inputs(7, 100, 1, 2, gates)
+    weight = jnp.asarray(np.random.default_rng(1).normal(
+        size=args[2].shape), jnp.float32)
+    with jax.default_matmul_precision('highest'):
+        def through_kernel(*a):
+            return la.gated_delta_rule(*a, chunk_size=64, qk_l2norm=True,
+                                       kernel=True)
+
+        got, want = through_kernel(*args), plain_delta_net(*args)
+        g_got = jax.grad(lambda *a: jnp.sum(through_kernel(*a) * weight),
+                         argnums=range(5))(*args)
+        g_want = jax.grad(lambda *a: jnp.sum(plain_delta_net(*a) * weight),
+                          argnums=range(5))(*args)
+    assert float(jnp.abs(got - want).max()) < 2e-5 * float(
+        jnp.abs(want).max())
+    for name, a, b in zip('q k v g beta'.split(), g_got, g_want):
+        err = float(jnp.linalg.norm(a - b))
+        assert err < 3e-4 * float(jnp.linalg.norm(b)) + 1e-7, (name, err)
+
+
+def test_usable_at_its_boundaries():
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert gdi.usable(64, 128, 128, bf16) and gdi.usable(64, 128, 128, f32)
+    assert gdi.usable(64, 256, 128, np.dtype('float32'))
+    assert not gdi.usable(16, 128, 128, bf16)       # the toy cells' chunk
+    assert not gdi.usable(128, 128, 128, bf16)
+    assert not gdi.usable(64, 96, 128, bf16)
+    assert not gdi.usable(64, 128, 64, f32)
+    assert not gdi.usable(64, 128, 128, jnp.float16)
+    # a row shorter than a chunk is cut to the power of two that holds it
+    assert la._chunk_of(64, 8192) == 64 and la._chunk_of(64, 20) == 32
+
+
+def _ways():
+    return {w: obs.counter('gdn.intra', way=w).value
+            for w in ('kernel', 'composed')}
+
+
+@pytest.mark.parametrize('platform', ['cpu', 'tpu'])
+def test_the_rule_chooses_on_platform_and_shape(platform, monkeypatch,
+                                                interpreted):
+    """Through the Executor: on the CPU the composition, with the platform
+    reported as `tpu` the kernel (here in the interpreter), counted once
+    per op per trace; a shape outside `usable` keeps the composition on
+    either; both stages' scopes are in the compiled module's metadata and
+    the values are the recurrence's both ways."""
+    init = lowering.Ctx.__init__
+    monkeypatch.setattr(
+        lowering.Ctx, '__init__',
+        lambda self, *a, **kw: init(self, *a, **dict(kw, platform=platform)))
+    args = op_inputs(11, 100, 1, 2, 'mild')
+    names = ['q', 'k', 'v', 'g', 'beta']
+    w = np.random.default_rng(3).normal(size=args[2].shape).astype('float32')
+
+    def build(chunk):
+        return lambda: layers.gated_delta_rule(
+            *(_input(n, a) for n, a in zip(names, args)), chunk_size=chunk,
+            qk_l2norm=True)
+
+    before = _ways()
+    got, grads, text = _grads_of(build(64), {'w': w}, names, optimized=True)
+    after = _ways()
+    took, other = (('kernel', 'composed') if platform == 'tpu'
+                   else ('composed', 'kernel'))
+    # one op: the forward and the backward programs are one trace each
+    assert 1 <= after[took] - before[took] <= 2
+    assert after[other] == before[other]
+    want = plain_delta_net(*args)
+    g_want = jax.grad(lambda *a: jnp.sum(plain_delta_net(*a) * w),
+                      argnums=range(5))(*args)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    for a, b in zip(grads, g_want):
+        np.testing.assert_allclose(a, b, rtol=1e-3, atol=2e-5)
+    scoped = [l for l in text.splitlines() if 'gated_delta_rule_' in l]
+    assert any('gdn_intra' in l for l in scoped)
+    assert any('gdn_scan' in l for l in scoped)
+    assert any('transpose' in l and 'gdn_intra' in l for l in scoped)
+    # a chunk of 16 is not the kernel's, whatever the platform
+    before = _ways()
+    _grads_of(build(16), {'w': w}, names)
+    after = _ways()
+    assert after['kernel'] == before['kernel']
+    assert after['composed'] > before['composed']
+
+
+def test_every_heads_a_grid_step_gives_the_same_chunks():
+    """`heads` only groups chunk-heads into grid steps: whole groups of
+    the value heads that share a key head."""
+    rng = np.random.default_rng(5)
+    shape = (2, 1, 4, 64)
+    q, k = (jnp.asarray(rng.normal(size=(2, 1, 2, 64, 128)) * 0.1,
+                        jnp.bfloat16) for _ in range(2))
+    v = jnp.asarray(rng.normal(size=shape + (128,)), jnp.bfloat16)
+    g_sum = jnp.cumsum(-jnp.asarray(rng.uniform(0, 0.3, size=shape),
+                                    jnp.float32), axis=-1)
+    beta = jnp.asarray(rng.uniform(0, 1, size=shape), jnp.float32)
+    two = gdi.gated_delta_intra(q, k, v, g_sum, beta, True, 2)
+    four = gdi.gated_delta_intra(q, k, v, g_sum, beta, True, 4)
+    for a, b in zip(two, four):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert gdi._heads(32, 2, bf16) == gdi.HEADS
+    assert gdi._heads(32, 2, f32) == gdi.HEADS // 2
+    assert gdi._heads(6, 1, bf16) == 6 and gdi._heads(7, 1, bf16) == 7
+    assert gdi._heads(12, 3, bf16) == 6 and gdi._heads(32, 16, f32) == 16
