@@ -627,8 +627,11 @@ class StepHandle(object):
         key = self._key if seed is None else jax.random.key(
             np.uint32(int(seed) % (1 << 32)))
         args = (self._donated, self._readonly, feed or {}, key)
+        # the step's device counters stay unread: a handle's caller decides
+        # when to pay a host sync, and its steps record no spans to carry
+        # them (Executor._read_device is run()'s and run_bundle()'s)
         if self._first:
-            (fetches, new_persist, health), _ = \
+            (fetches, new_persist, health, _), _ = \
                 self._exe._timed_first_call(
                     self._compiled._jitted, args, self.key_id, handle=True,
                     aot_sig=self._exe._aot_sig_of(self._compiled),
@@ -636,7 +639,7 @@ class StepHandle(object):
             self._compiled._obs_compiled = True
             self._first = False
         else:
-            fetches, new_persist, health = self._compiled._jitted(*args)
+            fetches, new_persist, health, _ = self._compiled._jitted(*args)
         for n, v in new_persist.items():
             self._donated[n] = v
             self._scope._chain_set(n, v)
@@ -1550,7 +1553,7 @@ class Executor(object):
             check = _dbg.nan_inf_check_active()
             op_hook = _prof.op_event_hook()
             if check or op_hook is not None:
-                fetches, new_persist, health = compiled.debug_step(
+                fetches, new_persist, health, counters = compiled.debug_step(
                     persist, feed_vals, rng, check_nan_inf=check,
                     on_op=op_hook)
             elif not getattr(compiled, '_obs_compiled', False):
@@ -1559,7 +1562,7 @@ class Executor(object):
                 # synchronously inside it; _timed_first_call measures it
                 # and records executor.compile ONLY for real cold
                 # compiles (plus one step's dispatch either way)
-                (fetches, new_persist, health), outcome = \
+                (fetches, new_persist, health, counters), outcome = \
                     self._timed_first_call(
                         compiled, (persist, feed_vals, rng),
                         look.get('key'),
@@ -1571,7 +1574,7 @@ class Executor(object):
                     step_sp.fields['cache'] = outcome
             else:
                 with obs.span_if(on, 'executor.dispatch'):
-                    fetches, new_persist, health = compiled(
+                    fetches, new_persist, health, counters = compiled(
                         persist, feed_vals, rng)
             if compiled.sparse_plan:
                 _C_EMBED_ROWS.inc(getattr(compiled, '_embed_rows_step', 0))
@@ -1601,6 +1604,9 @@ class Executor(object):
                 out = [self._convert_fetch(v, fetch_f32, return_numpy,
                                            sync == 'async')
                        for v in fetches]
+                if on and counters is not None and sync != 'async':
+                    step_sp.fields['device'] = self._read_device(
+                        compiled, counters)
         return out
 
     def acquire_step(self, program=None, feed=None, fetch_list=None,
@@ -1829,7 +1835,7 @@ class Executor(object):
             donated, readonly = compiled.plan.split(persist)
             obs_key = ('bundle', K)
             if obs_key not in getattr(compiled, '_obs_bundles', set()):
-                (new_persist, (fetches, healths)), outcome = \
+                (new_persist, (fetches, healths, counters)), outcome = \
                     self._timed_first_call(
                         bundle_fn, (donated, readonly, stacked, seeds),
                         look.get('key'), bundle_steps=K,
@@ -1842,7 +1848,7 @@ class Executor(object):
                 if outcome != 'compile':
                     bsp.fields['cache'] = outcome
             else:
-                new_persist, (fetches, healths) = bundle_fn(
+                new_persist, (fetches, healths, counters) = bundle_fn(
                     donated, readonly, stacked, seeds)
             if compiled.sparse_plan:
                 _C_EMBED_ROWS.inc(
@@ -1890,7 +1896,26 @@ class Executor(object):
                     else:
                         out.append(self._convert_fetch(
                             v, fetch_f32, return_numpy, sync == 'async'))
+                if on and counters is not None and sync != 'async':
+                    bsp.fields['device'] = self._read_device(
+                        compiled, counters)
         return out
+
+    def _read_device(self, compiled, counters):
+        """THE host read of a step's device counters (StepArtifact.
+        _device_counters), and their recording: what goes under
+        `fields['device']` of the step's own record, an entry a declared
+        op (a bundle's [K, counters]: a list a step). Called only while
+        observability is on and only where the caller has just blocked on
+        the step's fetches, inside its `executor.fetch` span: the step is
+        complete, so this waits for nothing, and a step's host time
+        outside the fetch is what it was. With observability off, or
+        under sync='async', nobody calls it and the vector is never
+        copied to the host."""
+        values = np.asarray(counters)
+        if values.ndim == 1:
+            return compiled.device_record(values)
+        return [compiled.device_record(v) for v in values]
 
     def _observe_health(self, program, health, run_id=None):
         """Host side of the anomaly guard: record the health vector, count

@@ -10,6 +10,7 @@ fuses across op boundaries (the reference pays a kernel launch per op).
 The same rules power build-time shape inference via jax.eval_shape
 (framework.Block.append_op), so op semantics are defined exactly once.
 """
+import collections
 import contextlib
 import functools
 import re
@@ -69,6 +70,36 @@ def has_rule(op_type):
     return op_type in _RULES
 
 
+# One int32 the compiled step hands back for an op, beside its fetches:
+# `source` names the variable it is reduced from (an output the op already
+# has, so it crosses the backward pass and a recompute region as any
+# output does), `reduce(value)` is traced inside the step, and
+# `record(label, value, facts)` runs on the host once the step is done,
+# only while observability is on: it counts into the registry and returns
+# the op's entry of the step record's `fields['device']`. `facts` is what
+# the op's rule told `Ctx.note` when it was traced.
+DeviceCounter = collections.namedtuple('DeviceCounter',
+                                       'source reduce record')
+_DEVICE_COUNTERS = {}
+
+
+def register_device_counter(op_type):
+    """Register `declare(op)` for an op type: the DeviceCounter the step
+    keeps for this op, or None where it counts nothing. What a step does
+    that depends on its DATA (a rule's lax.cond, the load of a share)
+    leaves the device this way; StepArtifact gathers the declared
+    counters into the step's one packed vector (docs/observability.md)."""
+    def deco(fn):
+        _DEVICE_COUNTERS[op_type] = fn
+        return fn
+    return deco
+
+
+def device_counter(op):
+    declare = _DEVICE_COUNTERS.get(op.type)
+    return declare(op) if declare is not None else None
+
+
 class Ctx(object):
     """Per-op lowering context: PRNG key, run mode, target platform
     (the Executor's Place decides this — jax.default_backend() lies when a
@@ -78,13 +109,16 @@ class Ctx(object):
     mesh axes the op is ALREADY manual over (inside a shard_map body, e.g.
     the pipeline region): rules that would otherwise open their own
     shard_map (sp attention) must instead use the per-shard collective
-    bodies on those axes."""
+    bodies on those axes. `facts` is the step's dict of what rules say
+    about their device counters (`note`); None where nothing gathers it
+    (shape inference, sub-blocks, a pipeline stage)."""
 
     __slots__ = ('key', 'op_index', 'is_test', 'amp', 'platform', 'mesh',
-                 'manual_axes')
+                 'manual_axes', 'facts')
 
     def __init__(self, key, op_index=0, is_test=False, amp=False,
-                 platform='cpu', mesh=None, manual_axes=frozenset()):
+                 platform='cpu', mesh=None, manual_axes=frozenset(),
+                 facts=None):
         self.key = key
         self.op_index = op_index
         self.is_test = is_test
@@ -92,9 +126,18 @@ class Ctx(object):
         self.platform = platform
         self.mesh = mesh
         self.manual_axes = manual_axes
+        self.facts = facts
 
     def rng(self):
         return jax.random.fold_in(self.key, self.op_index)
+
+    def note(self, **facts):
+        """What this op's rule fixed while it was traced and the host
+        needs to read the op's device counter by (`DeviceCounter.record`):
+        plain Python values, kept under the op's index. A rule traced
+        again (a recompute region, the bundle's scan) says the same."""
+        if self.facts is not None:
+            self.facts[self.op_index] = facts
 
     @property
     def pallas_interpret(self):

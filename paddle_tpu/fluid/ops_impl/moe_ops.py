@@ -86,8 +86,16 @@ matmuls) and `moe_combine` (un-sort, gate weights, sum over k). Trace-time
 counters: `moe.lowered{path=grouped|capacity}` once per op per trace of
 the rule (a lowering, or build-time shape inference; a share adds the
 labels `held=<count>of<num_experts>` and `dispatch=index`, a sigmoid
-router the label `scoring=sigmoid`),
-`moe.assignments` the tokens x k of the traced shape.
+router the label `scoring=sigmoid`).
+
+What a share's step did with its DATA leaves the device as the op's
+device counter (`_held_counter`, lowering.register_device_counter): the
+step's assignments to the held experts, one int32 an op, reduced from
+`ExpertCount` by the function whose result the `lax.cond` compares
+(`_held_rows`). On the host, while observability is on, it becomes the
+step record's `fields['device']` entry (`rows`, `expected`, `cap`, `way`)
+and the registry's `moe.held.rows{op=}` and
+`moe.held.layer_steps{op=, way=compact|blocks}` (`_held_record`).
 """
 import functools
 
@@ -96,7 +104,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ... import obs
-from ..lowering import register, data_of, amp_cast
+from ..lowering import (DeviceCounter, amp_cast, data_of, register,
+                        register_device_counter)
 
 _ACTS = {
     'relu': jax.nn.relu,
@@ -339,6 +348,14 @@ def _held_cap(assignments, count, n_exp):
     return -(-_HELD_SLACK * assignments * count // n_exp // 256) * 256
 
 
+def _held_layout(assignments, count, n_exp):
+    """`_held_cap`, or None where a layout of it holds half the layer's
+    rows or more: nothing to gain from compacting, and the layer keeps
+    every row whatever the router does."""
+    cap = _held_cap(assignments, count, n_exp)
+    return None if 2 * cap > assignments else cap
+
+
 def _held_blocks(params, x, key, gate, act, ctx):
     """Every tokens x k row of a held share, whatever the router did:
     `_dropless_moe(live=)` over blocks of `_HELD_BLOCK` tokens, one after
@@ -394,15 +411,48 @@ def _held_moe(params, x, expert, gate, sizes, held, act, ctx):
     with jax.named_scope('moe_route'):
         local = expert - first
         key = jnp.where((local >= 0) & (local < count), local, count)
-    cap = _held_cap(nt * k, count, sizes.shape[0])
-    if 2 * cap > nt * k:                   # nothing to gain from compacting
+    cap = _held_layout(nt * k, count, sizes.shape[0])
+    if cap is None:
         return _held_blocks(params, x, key, gate, act, ctx)
+    rows = _held_rows(sizes, held)
     sizes = sizes[first:first + count]
     return lax.cond(
-        jnp.sum(sizes) <= cap,
+        rows <= cap,
         lambda *a: _compact_moe(*a, sizes, cap, act, ctx),
         lambda *a: _held_blocks(*a, act, ctx),
         params, x, key, gate)
+
+
+def _held_rows(sizes, held):
+    """The step's assignments to the held experts, of `sizes`
+    [num_experts] (`ExpertCount`): what `_held_moe` compares with its
+    capacity, and the op's device counter."""
+    first, count = held
+    return jnp.sum(sizes[first:first + count])
+
+
+def _held_record(label, rows, facts):
+    """The host's side of a held op's device counter, a step: `rows` is
+    the integer the device compared, `facts` what the rule noted when it
+    was traced, so the way read here is the way the layer took."""
+    cap = facts['cap']
+    way = 'compact' if cap is not None and rows <= cap else 'blocks'
+    obs.counter('moe.held.rows', op=label).inc(rows)
+    obs.counter('moe.held.layer_steps', op=label, way=way).inc()
+    return {'op': label, 'rows': rows, 'expected': facts['expected'],
+            'cap': cap, 'way': way}
+
+
+@register_device_counter('moe_mlp')
+def _held_counter(op):
+    """A share's held rows, a step; where the op was built with its
+    expert count (`return_expert_count`), which is what it is read from."""
+    held = op.attrs.get('experts_held')
+    if not held or not op.output('ExpertCount'):
+        return None
+    held = tuple(int(i) for i in held)
+    return DeviceCounter(op.output('ExpertCount')[0],
+                         lambda sizes: _held_rows(sizes, held), _held_record)
 
 
 _SLOTS = {'W1': 'w1', 'B1': 'b1', 'W2': 'w2', 'B2': 'b2', 'W3': 'w3'}
@@ -424,7 +474,6 @@ def _moe_mlp(ins, attrs, ctx):
     shape_in, dtype_in = x.shape, x.dtype
     if x.ndim > 2:
         x = x.reshape(-1, x.shape[-1])
-    nt = x.shape[0]
     held = attrs.get('experts_held')
     held = tuple(int(i) for i in held) if held else None
     scoring = attrs.get('scoring') or 'softmax'
@@ -436,7 +485,6 @@ def _moe_mlp(ins, attrs, ctx):
         labels['scoring'] = scoring
     obs.counter('moe.lowered', path='grouped' if dropless else 'capacity',
                 **labels).inc()
-    obs.counter('moe.assignments').inc(nt * top_k)
 
     from ...parallel.moe import (DroplessOnMeshError, load_balancing_loss,
                                  moe_apply, router_topk)
@@ -470,6 +518,10 @@ def _moe_mlp(ins, attrs, ctx):
             'capacity_factor, or run the layer on one device.'
             % (mesh.shape['dp'], n_exp))
     if held:
+        # what the host reads the op's device counter by (`_held_record`)
+        rows = x.shape[0] * top_k
+        ctx.note(expected=rows * held[1] / n_exp,
+                 cap=_held_layout(rows, held[1], n_exp))
         y = _held_moe(params, x, expert.T, gate.T, sizes, held, act, ctx)
     elif dropless:
         y = _dropless_moe(params, x, expert.T, gate.T, sizes, act, ctx)

@@ -135,6 +135,20 @@ class StepArtifact(object):
         ad_idxs = [i for i, op in enumerate(ops) if op.type == 'autodiff']
         assert len(ad_idxs) <= 1, "at most one append_backward per program"
         self.ad_idx = ad_idxs[0] if ad_idxs else None
+        # the step's device counters (lowering.register_device_counter),
+        # in op order: (label, op index, DeviceCounter). The label is the
+        # op's own scope in the module's op_name, `<type>_<index>`.
+        # `counter_facts` is filled when the step is traced ({op index:
+        # what the op's rule told Ctx.note}). Ops of a pipeline region
+        # bind nothing in the step's env, so a pipelined step keeps none.
+        self.counters = []
+        self.counter_facts = {}
+        for i, op in enumerate(ops if self.pipe is None else ()):
+            found = lowering.device_counter(op)
+            if found is not None:
+                seq = op.attrs.get('op_seq', i)
+                self.counters.append(
+                    ('%s_%d' % (op.type, seq), seq, found))
         self.regions = self._recompute_regions(program)
         for op in (o for blk in program.blocks for o in blk.ops):
             # loud inertness check (docs/embedding.md): a TRAINING step
@@ -237,7 +251,7 @@ class StepArtifact(object):
                 if n in new_persist and not isinstance(new_persist[n], SeqValue):
                     new_persist[n] = jax.lax.with_sharding_constraint(
                         new_persist[n], sh)
-            return fetches, new_persist, health
+            return fetches, new_persist, health, self._device_counters(env)
 
         self._step_fn = step  # pure, un-jitted, split (donated, readonly)
         # the donation vector comes from the memory plan for BOTH paths
@@ -248,7 +262,7 @@ class StepArtifact(object):
             self._jitted = jax.jit(
                 step,
                 in_shardings=(don_sh, ro_sh, feed_sh, None),
-                out_shardings=(None, out_sh, None),
+                out_shardings=(None, out_sh, None, None),
                 donate_argnums=donate)
         else:
             self._jitted = jax.jit(step, donate_argnums=donate)
@@ -273,7 +287,8 @@ class StepArtifact(object):
         per-step randomness is bit-identical to K unbundled runs. ys are
         the per-step fetches (stacked on a leading K axis) and, when the
         anomaly guard is armed, the per-step health vectors (rollback
-        already applied in-graph by `step`, per inner step)."""
+        already applied in-graph by `step`, per inner step) and, where the
+        step keeps device counters, theirs ([K, counters])."""
         K = int(K)
         fn = self._bundles.get(K)
         if fn is None:
@@ -285,10 +300,10 @@ class StepArtifact(object):
                 # invariant across the scan
                 def body(carry, xs):
                     feed, seed = xs
-                    fetches, new_persist, health = step(
+                    fetches, new_persist, health, counters = step(
                         carry, readonly, feed, jax.random.key(seed))
                     nxt = {n: new_persist.get(n, carry[n]) for n in carry}
-                    return nxt, (fetches, health)
+                    return nxt, (fetches, health, counters)
 
                 return jax.lax.scan(body, donated, (feeds, seeds))
 
@@ -479,7 +494,8 @@ class StepArtifact(object):
 
         read_at = [reads(op) for op in self.ops]
         always = set(self.fetch_names) | {
-            v.name for v in program.list_vars() if v.persistable}
+            v.name for v in program.list_vars() if v.persistable} | {
+            found.source for _, _, found in self.counters}
         regions = {}
         for lo, hi in spans:
             inside = set().union(*read_at[lo:hi])
@@ -590,6 +606,29 @@ class StepArtifact(object):
                 'grads_finite': grads_finite,
                 'grad_norm': grad_norm}
 
+    def _device_counters(self, env):
+        """The step's device counters as ONE packed int32 vector, an entry
+        a declared op in op order, reduced INSIDE the compiled module
+        from variables the step already has (`_step_health` is the
+        precedent); None, and a module that returns nothing more, where
+        no op declares one. The module is the same whether anyone reads
+        the vector: observability is no part of it (`device_record`)."""
+        if not self.counters:
+            return None
+        return jnp.stack([
+            found.reduce(lowering.data_of(env[found.source]))
+            for _, _, found in self.counters]).astype(jnp.int32)
+
+    def device_record(self, values):
+        """One step's counters, read to the host (`values`, [counters]),
+        as the step record's `fields['device']` holds them: an entry an
+        op in op order, made by the op's own `DeviceCounter.record` from
+        the integer and from what its rule noted when it was traced. The
+        caller reads only while observability is on
+        (Executor._read_device)."""
+        return [found.record(label, int(v), self.counter_facts[seq])
+                for (label, seq, found), v in zip(self.counters, values)]
+
     def _select_healthy(self, healthy, new_persist, persist):
         """Step-skip policy (the AMP loss-scaling skip, generalized): when
         the step is unhealthy, every persistable output rolls back to its
@@ -608,6 +647,10 @@ class StepArtifact(object):
                 lambda a, b: a if getattr(a, 'shape', None) != getattr(
                     b, 'shape', None) else jnp.where(healthy, a, b),
                 new, old)
+
+    def _ctx(self, key, seq):
+        return Ctx(key, seq, amp=self.amp, platform=self.platform,
+                   mesh=self.mesh, facts=self.counter_facts)
 
     def _run_ops(self, env, lo, hi, key, grad_mode=False, on_op=None,
                  taps=None, in_region=False):
@@ -642,15 +685,11 @@ class StepArtifact(object):
             # behavior)
             seq = op.attrs.get('op_seq', i)
             if on_op is None:
-                lowering.run_op(op, env, Ctx(key, seq, amp=self.amp,
-                                             platform=self.platform,
-                                             mesh=self.mesh))
+                lowering.run_op(op, env, self._ctx(key, seq))
             else:
                 import time
                 t0 = time.perf_counter()
-                lowering.run_op(op, env, Ctx(key, seq, amp=self.amp,
-                                             platform=self.platform,
-                                             mesh=self.mesh))
+                lowering.run_op(op, env, self._ctx(key, seq))
                 outs = [env[v.name] for vs in op.outputs.values()
                         for v in vs if env.get(v.name) is not None]
                 jax.block_until_ready(outs)
@@ -826,7 +865,7 @@ class StepArtifact(object):
         new_persist = {n: env[n] for n in self.persist_out if n in env}
         if health is not None:
             self._select_healthy(health['healthy'], new_persist, persist)
-        return fetches, new_persist, health
+        return fetches, new_persist, health, self._device_counters(env)
 
     def __call__(self, persist, feed, key):
         donated, readonly = self.plan.split(persist)

@@ -144,7 +144,7 @@ def export_compiled(dirname, feed_example, target_vars, executor,
                 feed[n] = SeqValue(a, lens)
             else:
                 feed[n] = a
-        fetches, _, _ = compiled._step(persist, feed, jax.random.key(0))
+        fetches = compiled._step(persist, feed, jax.random.key(0))[0]
         return [f.data if isinstance(f, SeqValue) else f for f in fetches]
 
     args = [jnp.asarray(feed_example[n]) for n in feed_names]

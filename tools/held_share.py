@@ -1,5 +1,6 @@
 """The share of a step's expert assignments that lands on the experts THIS
-chip holds, measured from `ExpertCount`, beside the expected one.
+chip holds, as the program's own device counters have it, beside the
+expected one.
 
     python tools/held_share.py --workload qwen3next_s8192 --seed 7 [--steps 8]
 
@@ -16,15 +17,19 @@ out those rows alone, by index (the compact path); beyond that it keeps
 every row. So the line also gives each layer-step's held rows over the
 expected number (`layer_over_expected_*`), how many layer-steps went over
 the slack (`layers_over_slack`) and the share that stayed on the compact
-path (`compact_share`): counted on the host from `ExpertCount`, with
-nothing added to the step. Runs wherever jax runs: on the chip the cell's
-own step, on the host the same Program on CPUPlace (slow at published
-widths; `--toy` takes the widths of tests/test_chipbench/toy/).
+path (`compact_share`). All of it is read from `fields['device']` of the
+program's `executor.step` records (docs/observability.md): the rows the
+step counted on the device and the `expected`, `cap` and `way` its rule
+fixed, so "over the slack" has one definition, the rule's. Runs wherever
+jax runs: on the chip the cell's own step, on the host the same Program
+on CPUPlace (slow at published widths; `--toy` takes the widths of
+tests/test_chipbench/toy/).
 """
 import argparse
 import json
 import os
 import sys
+import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -57,24 +62,28 @@ def main(argv=None):
     first, count = held
     pool, _ = cell['generator'].make_pool(traffic, config, args.seed)
     built = cell['builder'].build(config, traffic, train=True)
-    moes = [op for op in built['main'].global_block().ops
-            if op.type == 'moe_mlp']
-    fetch = [built['loss']] + [op.output('ExpertCount')[0] for op in moes]
     exe = fluid.Executor()
     exe.run(built['startup'])
+    from paddle_tpu import obs
     from paddle_tpu.fluid.ops_impl import moe_ops
-    held_rows, assignments = [], []
+    # the counters are recorded while observability is on
+    obs.enable(tempfile.mkdtemp(prefix='held_share_obs_'))
     for i in range(args.steps):
-        counts = np.asarray(exe.run(built['main'], feed=pool[i % len(pool)],
-                                    fetch_list=fetch)[1:])
-        held_rows.append(counts[:, first:first + count].sum(-1))
-        assignments.append(counts.sum(-1))
-    held_rows, assignments = np.asarray(held_rows), np.asarray(assignments)
-    shares = held_rows / assignments                   # [steps, layers]
-    # a layer's held rows over the expected number, and the rows that
-    # `_held_moe` compares them with
-    over = shares * routed / count
-    cap = moe_ops._held_cap(assignments, count, routed)
+        exe.run(built['main'], feed=pool[i % len(pool)],
+                fetch_list=[built['loss']])
+    steps = [r['fields']['device'] for r in obs.completed_spans()
+             if r['name'] == 'executor.step' and r['fields'].get('device')]
+    steps = steps[-args.steps:]
+
+    def of(key):                                       # [steps, layers]
+        return np.asarray([[e[key] for e in entries] for entries in steps])
+
+    held_rows, expected = of('rows'), of('expected').astype(float)
+    over = held_rows / expected
+    shares = over * count / routed
+    compact = of('way') == 'compact'
+    static = np.asarray([[e['cap'] is None for e in entries]
+                         for entries in steps])
     print(json.dumps({
         'workload': args.workload, 'seed': args.seed, 'steps': args.steps,
         'held': [first, count], 'routed': routed,
@@ -89,10 +98,10 @@ def main(argv=None):
         'layer_over_expected_max_by_step': [round(float(b), 2)
                                             for b in over.max(axis=1)],
         'slack': moe_ops._HELD_SLACK,
-        'layers_over_slack': int((held_rows > cap).sum()),
-        # a layout of half the rows or more is not built at all
-        'compact_share': float(((held_rows <= cap)
-                                & (2 * cap <= assignments)).mean()),
+        # a layout of half the rows or more is not built at all: such a
+        # layer keeps every row whatever the router does, and is not over
+        'layers_over_slack': int((~compact & ~static).sum()),
+        'compact_share': float(compact.mean()),
         'layer_steps': int(over.size)}))
     return 0
 
