@@ -62,6 +62,15 @@ bias. Float32 elementwise work (K shifted multiply-adds), never the MXU.
 Under AMP it reads its input rounded to bf16 and gives its result in
 bf16, as attention does: the input is what its backward keeps, and a
 [B, T, C] float32 array twice a layer is what the cell cannot hold.
+On the TPU, for whole lane tiles of channels and whole tiles of tokens
+(`usable` of ops/kernels/causal_conv1d.py), it is one Pallas kernel
+forward and one backward that shift a tile's rows in VMEM and move each
+array once: `_conv`'s arithmetic in its order and precisions, the
+backward's recomputed sum, dx and dw one grid step. Every other platform
+and shape takes `_conv` below (a padded float32 copy and K slices of it,
+which on the TPU are K misaligned copies through HBM), the composition
+the kernel is tested against. The rule chooses as it does for the delta
+rule's stage.
 
 `gated_rms_norm`: y = w * x * rsqrt(mean(x^2) + eps) * silu(gate) over the
 last axis, statistics in float32; its backward keeps x and the gate
@@ -69,8 +78,8 @@ last axis, statistics in float32; its backward keeps x and the gate
 
 Trace-time counters: `gdn.lowered{chunk=}` once per op per trace,
 `gdn.intra{way=kernel|composed}` beside it (which way stage `gdn_intra`
-went), `gdn.tokens` the B x T of the traced shape, `conv1d.lowered`,
-`gated_rms_norm.lowered`.
+went), `gdn.tokens` the B x T of the traced shape, `conv1d.lowered` and
+`conv1d.way{way=kernel|composed}` beside it, `gated_rms_norm.lowered`.
 """
 import functools
 
@@ -80,6 +89,7 @@ import numpy as np
 from jax import lax
 
 from ... import obs
+from ...ops.kernels import causal_conv1d as conv_kernel
 from ...ops.kernels import gated_delta_intra as intra_kernel
 from ..lowering import register, data_of, amp_cast
 
@@ -344,20 +354,31 @@ def _conv(x, w, act):
     return _CONV_ACTS[act](y).astype(x.dtype)
 
 
-# The backward keeps the input and the filter and computes the sum again:
-# K shifted multiply-adds of a memory-bound op, against a second
-# [B, T, C] float32 array (the sum before its activation) kept a layer.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def causal_conv1d(x, w, act=''):
-    """x [B, T, C], w [K, C]: y[t] = act(sum_j w[j] x[t - (K - 1) + j])."""
+def _conv_forward(x, w, act, kernel):
+    if kernel:
+        return conv_kernel.causal_conv1d_fwd(x, w, act=act, interpret=False)
     return _conv(x, w, act)
 
 
-def _conv_fwd(x, w, act):
-    return _conv(x, w, act), (x, w)
+# The backward keeps the input and the filter and computes the sum again:
+# K shifted multiply-adds of a memory-bound op, against a second
+# [B, T, C] float32 array (the sum before its activation) kept a layer.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def causal_conv1d(x, w, act='', kernel=False):
+    """x [B, T, C], w [K, C]: y[t] = act(sum_j w[j] x[t - (K - 1) + j]).
+    `kernel`: the Pallas kernels, forward and backward (the rule's choice;
+    the caller has asked their `usable`), else `_conv`."""
+    return _conv_forward(x, w, act, kernel)
 
 
-def _conv_bwd(act, res, g):
+def _conv_fwd(x, w, act, kernel):
+    return _conv_forward(x, w, act, kernel), (x, w)
+
+
+def _conv_bwd(act, kernel, res, g):
+    if kernel:      # the sum again in VMEM: nothing XLA could keep instead
+        return conv_kernel.causal_conv1d_bwd(*res, g, act=act,
+                                             interpret=False)
     res, g = _recompute_after(res, g)
     return jax.vjp(lambda x, w: _conv(x, w, act), *res)[1](g)
 
@@ -368,9 +389,14 @@ causal_conv1d.defvjp(_conv_fwd, _conv_bwd)
 @register('causal_conv1d')
 def _causal_conv1d(ins, attrs, ctx):
     obs.counter('conv1d.lowered').inc()                      # trace time
-    return {'Out': causal_conv1d(amp_cast(ctx, data_of(ins['X'][0])),
-                                 data_of(ins['Filter'][0]),
-                                 attrs.get('act') or '')}
+    x = amp_cast(ctx, data_of(ins['X'][0]))
+    w = data_of(ins['Filter'][0])
+    # on the TPU, for a shape they take, one Pallas kernel each way
+    kernel = ctx.platform == 'tpu' and conv_kernel.usable(
+        x.shape[1], x.shape[2], w.shape[0], x.dtype)
+    obs.counter('conv1d.way',                                # trace time
+                way='kernel' if kernel else 'composed').inc()
+    return {'Out': causal_conv1d(x, w, attrs.get('act') or '', kernel)}
 
 
 def _gated_norm(x, gate, w, eps):

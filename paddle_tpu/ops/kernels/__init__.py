@@ -32,6 +32,20 @@ time, so the counters count COMPILED modules carrying the kernel, not
 steady-state steps (which re-trace nothing). Each dispatch also writes
 a `kernels.dispatch` event (once per trace, for the obs_report
 `-- kernels --` section).
+
+Not under the knob, and not in the registry: the kernels that ARE an op's
+lowering on the TPU, chosen by the op's rule from what it can see
+(`ctx.platform == 'tpu'`, the module's `usable(...)` of the shapes and
+the dtype), with the composition they are tested against as every other
+platform's and shape's path:
+
+  * `grouped_matmul` — the expert matmuls of `moe_mlp`'s dropless paths;
+  * `gated_delta_intra` — stage `gdn_intra` of `gated_delta_rule`, forward
+    and backward (else `linear_attention_ops._intra`);
+  * `causal_conv1d` — the op `causal_conv1d`, one kernel forward and one
+    backward that shift the K taps in VMEM (else
+    `linear_attention_ops._conv`); `conv1d.way{way=kernel|composed}`
+    counts the choice at trace time.
 """
 import os
 

@@ -1,11 +1,11 @@
 """The flash kernels at their default tiles, the grouped-matmul kernels at
-theirs and the delta rule's chunk kernels, compiled by Mosaic for a
-DESCRIBED v5e (no chip, nothing runs): what the interpreter and
-jax.export cannot refuse — VMEM the kernel may not have, slices Mosaic
-will not tile — is refused here. The cells' shapes, and the shapes on
-either side of the default-tile rule (_default_tile). The topology is
-described inside a fixture and in this file only: one process at a time
-may load the TPU's library."""
+theirs, the delta rule's chunk kernels and the causal convolution's,
+compiled by Mosaic for a DESCRIBED v5e (no chip, nothing runs): what the
+interpreter and jax.export cannot refuse — VMEM the kernel may not have,
+slices Mosaic will not tile — is refused here. The cells' shapes, and the
+shapes on either side of the default-tile rule (_default_tile). The
+topology is described inside a fixture and in this file only: one process
+at a time may load the TPU's library."""
 import os
 
 import pytest
@@ -113,4 +113,26 @@ def test_gated_delta_intra_compiles_for_v5e(one_chip, dtype):
     compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
         keys, keys, x, gate, gate).compile()
     # the forward (it writes T for the backward) and the backward
+    assert compiled.as_text().count('tpu_custom_call') == 2
+
+
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+def test_causal_conv1d_compiles_for_v5e(one_chip, dtype):
+    """qwen3next_s8192's depthwise convolution (one row of 8192 tokens,
+    8192 channels, four taps, silu) as the rule hands it to the op,
+    forward and backward, in the cell's bf16 and in its float32 check's
+    arithmetic, at the tile `tile_of` gives each: three blocks of a tile
+    twice over are what the backward asks of VMEM."""
+    from paddle_tpu.fluid.ops_impl.linear_attention_ops import causal_conv1d
+    from paddle_tpu.ops.kernels import causal_conv1d as kernel
+    dt = jnp.dtype(dtype)
+    assert kernel.usable(8192, 8192, 4, dt)
+    x = jax.ShapeDtypeStruct((1, 8192, 8192), dt, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((4, 8192), jnp.float32, sharding=one_chip)
+
+    def loss(x, w):
+        return jnp.sum(causal_conv1d(x, w, 'silu', True)
+                       .astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).compile()
     assert compiled.as_text().count('tpu_custom_call') == 2
