@@ -138,7 +138,9 @@ def train_leg(place, cfg=BASE, steps=20, expect_kernel=True):
     feed = _batch(cfg, feeds)
 
     def lowered():
-        return ({d: obs.counter('flash.lowered', operands=d).value
+        return ({d: sum(obs.counter('flash.lowered', operands=d,
+                                    grid=g).value
+                        for g in ('band', 'triangle', 'rect'))
                  for d in ('bfloat16', 'float32')},
                 {p: obs.counter('flash.backward', passes=p).value
                  for p in ('one', 'two')},

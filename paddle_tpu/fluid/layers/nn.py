@@ -984,19 +984,27 @@ def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None):
 
 
 def fused_attention(q, k, v, key_bias=None, causal=False, scale=None,
-                    name=None):
+                    name=None, window=None):
     """Whole-attention fused op: softmax(q k^T * scale + bias) v in ONE op.
 
     q/k/v: [B, H, T, D]; k and v may have FEWER heads than q (grouped
     key-value heads: H_q a multiple of H_kv, key-value head h serving query
     heads h * H_q/H_kv and following; the rule repeats them, so the
     kernels see equal counts). key_bias: optional [B, Tk] (or [B,1,1,Tk]) additive
-    bias for padded keys; causal adds lower-triangular masking. On TPU this
+    bias for padded keys; causal adds lower-triangular masking. ``window``
+    (causal only) is a sliding window: query i sees the keys
+    i - window + 1 .. i, its own position counting as one; None, or a
+    window as long as the sequence, is plain causal attention. On TPU this
     lowers to the pallas flash-attention kernel (paddle_tpu.ops), which
-    never materializes the [B,H,Tq,Tk] score matrix in HBM; elsewhere it
-    falls back to the XLA chain. Replaces the reference's matmul->softmax->
-    matmul op sequence (nets.py scaled_dot_product_attention).
+    never materializes the [B,H,Tq,Tk] score matrix in HBM and visits only
+    the band of blocks a window touches; elsewhere it falls back to the
+    XLA chain with the same mask. Replaces the reference's
+    matmul->softmax->matmul op sequence (nets.py
+    scaled_dot_product_attention).
     """
+    # refused here as the kernels would refuse it at the lowering
+    from ...ops.flash_attention import _window_of
+    _window_of(window, causal, int(q.shape[2]))
     h_q, h_kv = int(q.shape[1]), int(k.shape[1])
     if h_kv != int(v.shape[1]) or h_kv <= 0 or h_q % h_kv:
         raise ValueError('fused_attention: %d query heads over %d key and '
@@ -1006,11 +1014,12 @@ def fused_attention(q, k, v, key_bias=None, causal=False, scale=None,
     inputs = {'Q': [q], 'K': [k], 'V': [v]}
     if key_bias is not None:
         inputs['KeyBias'] = [key_bias]
+    attrs = {'causal': bool(causal),
+             'scale': float(scale) if scale is not None else -1.0}
+    if window is not None:
+        attrs['window'] = int(window)
     helper.append_op(type='flash_attention', inputs=inputs,
-                     outputs={'Out': [out]},
-                     attrs={'causal': bool(causal),
-                            'scale': (float(scale) if scale is not None
-                                      else -1.0)})
+                     outputs={'Out': [out]}, attrs=attrs)
     return out
 
 
@@ -1594,7 +1603,7 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
             bias_attr=None, name=None, top_k=1, return_aux_loss=False,
             gated=False, norm_topk_prob=True, return_expert_count=False,
             experts_held=None, scoring='softmax', selection_bias=False,
-            gate_scale=1.0):
+            gate_scale=1.0, router_input=None):
     """Top-k gated mixture-of-experts FFN (TPU extension; the reference
     predates MoE — its conditional-computation ancestor is layers.Switch).
 
@@ -1645,6 +1654,17 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
     multiplies the gates after the renormalisation. Both come with the
     sigmoid router and are refused under ``scoring='softmax'``.
 
+    ``router_input`` (dropless only): a tensor of `input`'s shape that the
+    ROUTER reads in place of `input`: the logits are ``router_input @
+    gate_w`` and `input` feeds the experts alone (SmallThinker,
+    arXiv:2507.20984: the router reads the layer's normed input BEFORE
+    attention, so that a deployment fetches the experts while attention
+    runs; the experts read the normed state after it). The router's
+    gradient then reaches `router_input` and the experts' reaches
+    `input`. The auxiliary loss, `expert_count`, a held share and either
+    scoring take the logits wherever they came from. ``None``: one tensor
+    serves both.
+
     With return_aux_loss=True, also returns the scalar Switch/GShard
     load-balancing auxiliary loss (E * sum_e f_e * P_e, minimized at 1.0
     by a uniform router) to add to the training objective with a small
@@ -1677,6 +1697,14 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
         raise ValueError("moe_mlp: selection_bias and gate_scale belong to "
                          "scoring='sigmoid' (no model here has them under "
                          'a softmax router)')
+    if router_input is not None:
+        if capacity_factor is not None:
+            raise ValueError("moe_mlp: router_input is the dropless "
+                             "layer's; pass capacity_factor=None")
+        if tuple(router_input.shape) != tuple(input.shape):
+            raise ValueError('moe_mlp: router_input %r has not the shape '
+                             'of input %r' % (tuple(router_input.shape),
+                                              tuple(input.shape)))
     n_held = int(num_experts)
     if experts_held is not None:
         first, n_held = (int(i) for i in experts_held)
@@ -1704,6 +1732,8 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
 
     inputs = {'X': [input], 'GateW': [gate_w],
               'W1': [weight([n_held, d, hidden_size])]}
+    if router_input is not None:
+        inputs['RouterX'] = [router_input]
     if gated:
         inputs['W3'] = [weight([n_held, d, hidden_size])]
     inputs['W2'] = [weight([n_held, hidden_size, out_d])]

@@ -78,7 +78,14 @@ the chosen experts' scores without it, renormalised over the chosen
 under `norm_topk_prob`, times `gate_scale`; no gradient reaches the bias
 (parallel/moe.py router_topk: one code path for both scorings). Its
 owner moves it after the step from `ExpertCount`
-(layers.router_bias_update). `AuxLoss` stays the softmax form.
+(layers.router_bias_update). `AuxLoss` stays the softmax form. An
+optional input `RouterX` of `X`'s shape is what the router reads in
+place of `X` (`layers.moe_mlp(router_input=)`): the logits are RouterX @
+GateW, float32 at full precision as ever, and `X` feeds the experts
+alone, so the router's gradient flows to one tensor and the experts' to
+another. Everything after the logits (either scoring, the top k,
+`AuxLoss`, `ExpertCount`, a held share and its device counter) is
+indifferent to where they came from. Without it the op is what it was.
 
 Inside the op's `moe_mlp_<index>` scope the stages are named `moe_route`
 (logits, top-k, the sort and the gather of rows), `moe_experts` (the
@@ -86,7 +93,8 @@ matmuls) and `moe_combine` (un-sort, gate weights, sum over k). Trace-time
 counters: `moe.lowered{path=grouped|capacity}` once per op per trace of
 the rule (a lowering, or build-time shape inference; a share adds the
 labels `held=<count>of<num_experts>` and `dispatch=index`, a sigmoid
-router the label `scoring=sigmoid`).
+router the label `scoring=sigmoid`, a router with an input of its own
+the label `router=own`).
 
 What a share's step did with its DATA leaves the device as the op's
 device counter (`_held_counter`, lowering.register_device_counter): the
@@ -483,6 +491,14 @@ def _moe_mlp(ins, attrs, ctx):
         if held else {}
     if scoring != 'softmax':
         labels['scoring'] = scoring
+    # the router's input: its own tensor where the op was given one
+    routed = x
+    if ins.get('RouterX'):
+        if not dropless:
+            raise ValueError("moe_mlp: RouterX is the dropless layer's "
+                             '(capacity_factor=None)')
+        routed = data_of(ins['RouterX'][0]).reshape(x.shape)
+        labels['router'] = 'own'
     obs.counter('moe.lowered', path='grouped' if dropless else 'capacity',
                 **labels).inc()
 
@@ -490,7 +506,7 @@ def _moe_mlp(ins, attrs, ctx):
                                  moe_apply, router_topk)
     with jax.named_scope('moe_route'):
         # the router is not the MXU's: float32 operands at full precision
-        logits = jnp.matmul(x.astype(jnp.float32),
+        logits = jnp.matmul(routed.astype(jnp.float32),
                             gate_w.astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
         aux = load_balancing_loss(logits, top_k)

@@ -650,6 +650,8 @@ def _flash_attention(ins, attrs, ctx):
     scale = attrs.get('scale', -1.0)
     scale = None if scale is None or scale < 0 else float(scale)
     causal = bool(attrs.get('causal', False))
+    window = attrs.get('window')
+    window = None if window is None else int(window)
     if k.shape[1] != q.shape[1]:
         # grouped key-value heads: key-value head h serves the query heads
         # h * group and following. A repeat, whose transpose sums a group's
@@ -669,6 +671,11 @@ def _flash_attention(ins, attrs, ctx):
         # device holds O(T/sp) keys (flash blocks on TPU, dense on CPU)
         sp = mesh.shape['sp']
         strategy = attrs.get('sp_strategy', 'ring')
+        if window is not None:
+            raise ValueError(
+                'flash_attention: a sliding window (window=%d) on a '
+                'sequence-parallel mesh is not built: the ring and ulysses '
+                'bodies know the causal edge alone' % window)
         if 'sp' in getattr(ctx, 'manual_axes', ()):
             # already INSIDE a shard_map manual over sp (the pipeline
             # region): q/k/v arrive sequence-LOCAL [B, H, T/sp, D]; call
@@ -706,12 +713,13 @@ def _flash_attention(ins, attrs, ctx):
             # GSPMD has no partitioning rule for the bare Mosaic call
             out = tpu_ops.flash_attention_sharded(
                 mesh, q, k, v, key_bias=kb, causal=causal, sm_scale=scale,
-                interpret=False)
+                window=window, interpret=False)
         else:
             out = tpu_ops.flash_attention(q, k, v, key_bias=kb,
                                           causal=causal, sm_scale=scale,
-                                          interpret=False)
+                                          window=window, interpret=False)
     else:
         out = tpu_ops.reference_attention(q, k, v, key_bias=kb,
-                                          causal=causal, sm_scale=scale)
+                                          causal=causal, sm_scale=scale,
+                                          window=window)
     return {'Out': out}

@@ -31,7 +31,12 @@ LOWER-TRIANGLE grid: scalar-prefetch index arrays enumerate only the
 (q-block, k-block) pairs on or below the diagonal, so blocks above it are
 never computed — causal forward+backward costs ~half the rectangular
 FLOPs. See the strategy note above _tri_maps for why this (and not
-compute predication) is the safe way to skip blocks under Mosaic.
+compute predication) is the safe way to skip blocks under Mosaic. A
+sliding `window` (causal only: query i sees keys i - window + 1 .. i)
+cuts the same enumeration on its other side: the forward, the dq and the
+dk/dv grids list only the BAND of blocks a window touches, and the mask
+gets its second edge. Where the triangular grid does not apply (or the
+backward runs in one pass) a window is the mask alone.
 
 What is float32 and what follows the input. The q, k, v and do tiles go
 into the MXU in the dtype of their refs (bf16 under AMP, float32 in a
@@ -47,8 +52,11 @@ Row statistics never become 1-D vectors inside a body: they stay
 lane-broadcast [rows, LANES] tiles from scratch or HBM to the score tile
 (_lanes). On the v5e that, not the operand dtype, was what a forward
 block step waited for (PERF.md, PR 24). The counters
-`flash.lowered{operands=<dtype>}` and `flash.backward{passes=one|two}`
-count attention calls per lowering.
+`flash.lowered{operands=<dtype>, grid=band|triangle|rect}` and
+`flash.backward{passes=one|two}` count attention calls per lowering;
+`flash.tiles{grid=}` adds up the (q-block, k-block) pairs a head that a
+call's grids visit (forward + dq + dk/dv), so a lowering says off the
+chip whether the band was taken and what it spared.
 
 `interpret` is the CALLER's decision, never read off the process's
 default backend: the op lowering passes interpret=False on a TPU place
@@ -93,14 +101,19 @@ def _dot(a, b, dims):
     return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
-def _mask_causal(s, q0, k0, q_axis):
-    """NEG_BIG where a key lies after its query, for a score tile whose
-    first query is q0 and first key k0, queries running along q_axis.
-    The positions' difference within the tile does not depend on the grid
-    step; only the scalar it is compared with does."""
+def _mask_causal(s, q0, k0, q_axis, window=None):
+    """NEG_BIG where a key lies after its query or, under a `window`, that
+    many positions or more before it (a query sees its own position and
+    the window - 1 before it), for a score tile whose first query is q0
+    and first key k0, queries running along q_axis. The positions'
+    difference within the tile does not depend on the grid step; only the
+    scalars it is compared with do."""
     rel = (lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
            - lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
-    return jnp.where(rel >= k0 - q0, s, NEG_BIG)
+    keep = rel >= k0 - q0
+    if window is not None:
+        keep = keep & (rel < k0 - q0 + window)
+    return jnp.where(keep, s, NEG_BIG)
 
 
 def _lanes(x, n):
@@ -132,28 +145,64 @@ def _lanes(x, n):
 #                  hazard above never arises. Used when Tq == Tk and
 #                  bq == bk (decoder self-attention); anything else falls
 #                  back to rectangular.
+#   band           — the triangular grid cut on its other side too: under a
+#                  sliding `window` a q-block i sees the k-blocks
+#                  i - nb .. i, nb = ceil((window - 1) / bk) (_band), and
+#                  the same index arrays list only those pairs. Nothing
+#                  else changes: still one visit a pair, still no
+#                  predication; the accumulators start at a row's (a
+#                  column's) first pair IN THE BAND and end at its last.
+#                  The pairs at the band's two edges hold positions the
+#                  mask removes (_mask_causal knows both edges). 16384
+#                  positions in 512-blocks under a window of 4096: 252 of
+#                  the triangle's 528 pairs a head. A windowed call
+#                  outside _use_tri's conditions takes the rectangular
+#                  grid with the same mask and skips nothing.
 # ---------------------------------------------------------------------------
 
 
-def _tri_maps(n):
+def _band(window, bk, n):
+    """How many k-blocks BELOW the diagonal a q-block still sees under
+    `window` (a query sees the keys i - window + 1 .. i): None where that
+    is every one of the n - 1 there are, the plain triangle."""
+    if window is None:
+        return None
+    nb = -(-(window - 1) // bk)
+    return None if nb >= n - 1 else nb
+
+
+def _tile_pairs(n, nb=None):
+    """(q-block, k-block) pairs of the triangle of n blocks, or of its
+    band of nb blocks below the diagonal."""
+    if nb is None:
+        return n * (n + 1) // 2
+    return (nb + 1) * (nb + 2) // 2 + (n - nb - 1) * (nb + 1)
+
+
+def _tri_maps(n, nb=None):
     """Row-major lower-triangle enumeration: (0,0),(1,0),(1,1),(2,0),...
-    Returns int32 (i_map, j_map) with j <= i, length n*(n+1)//2."""
+    Returns int32 (i_map, j_map) with j <= i, length n*(n+1)//2. With
+    `nb`, only the pairs of the band i - nb <= j <= i, in the same
+    order."""
     import numpy as np
-    i = np.repeat(np.arange(n), np.arange(1, n + 1))
-    j = np.concatenate([np.arange(r + 1) for r in range(n)])
+    rows = np.arange(n)
+    first = np.zeros(n, int) if nb is None else np.maximum(rows - nb, 0)
+    i = np.repeat(rows, rows - first + 1)
+    j = np.concatenate([np.arange(f, r + 1) for r, f in zip(rows, first)])
     return i.astype(np.int32), j.astype(np.int32)
 
 
-def _tri_maps_kv(n):
+def _tri_maps_kv(n, nb=None):
     """Lower-triangle enumeration ordered for the dk/dv kernel: k-block j
     outer (visited last-to-first), its contributing q-blocks i = j..n-1
-    inner, so the (dk, dv) accumulator runs over consecutive steps."""
+    (with `nb`: j..min(n - 1, j + nb)) inner, so the (dk, dv) accumulator
+    runs over consecutive steps."""
     import numpy as np
     ii, jj = [], []
-    for a in range(n):          # a = n-1-j
-        j = n - 1 - a
-        ii.append(np.arange(j, n))
-        jj.append(np.full(n - j, j))
+    for j in range(n - 1, -1, -1):
+        last = n - 1 if nb is None else min(n - 1, j + nb)
+        ii.append(np.arange(j, last + 1))
+        jj.append(np.full(last + 1 - j, j))
     return (np.concatenate(ii).astype(np.int32),
             np.concatenate(jj).astype(np.int32))
 
@@ -164,7 +213,7 @@ def _tri_maps_kv(n):
 
 def _fwd_body(q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref,
               m_s, l_s, acc_s, i, j, is_first, is_last, *,
-              scale, causal, block_q, block_k):
+              scale, causal, block_q, block_k, window=None):
     @pl.when(is_first)
     def _init():
         m_s[:] = jnp.full_like(m_s, -1e30)
@@ -178,7 +227,7 @@ def _fwd_body(q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref,
         s = _dot(q, kb, _NT) * scale
         s = s + kb_ref[0]
         if causal:
-            s = _mask_causal(s, i * block_q, j * block_k, q_axis=0)
+            s = _mask_causal(s, i * block_q, j * block_k, 0, window)
         m_prev = m_s[:]                                        # [bq, LANES]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - _lanes(m_new, block_k))
@@ -198,24 +247,34 @@ def _fwd_body(q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref,
         lse_ref[0, 0] = m_s[:] + jnp.log(l)
 
 
+def _row_start(i, j, nb):
+    """Is k-block j the first that q-block i visits (triangle: block 0;
+    band of nb: block i - nb, or 0 in the first rows)?"""
+    return j == 0 if nb is None else j == jnp.maximum(i - nb, 0)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref,
-                m_s, l_s, acc_s, *, scale, causal, block_q, block_k):
+                m_s, l_s, acc_s, *, scale, causal, block_q, block_k,
+                window=None):
     i, j = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
     _fwd_body(q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref, m_s, l_s, acc_s,
               i, j, j == 0, j == nk - 1,
-              scale=scale, causal=causal, block_q=block_q, block_k=block_k)
+              scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+              window=window)
 
 
 def _fwd_kernel_tri(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref,
                     o_ref, lse_ref, m_s, l_s, acc_s, *,
-                    scale, block_q, block_k):
+                    scale, block_q, block_k, window=None, nb=None):
     t = pl.program_id(2)
     i, j = im_ref[t], jm_ref[t]
-    # j == 0 starts row i; j == i is the diagonal block, last for row i
+    # j == 0 (in a band: the row's first block in it) starts row i;
+    # j == i is the diagonal block, last for row i
     _fwd_body(q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref, m_s, l_s, acc_s,
-              i, j, j == 0, j == i,
-              scale=scale, causal=True, block_q=block_q, block_k=block_k)
+              i, j, _row_start(i, j, nb), j == i,
+              scale=scale, causal=True, block_q=block_q, block_k=block_k,
+              window=window)
 
 
 # ---------------------------------------------------------------------------
@@ -224,7 +283,7 @@ def _fwd_kernel_tri(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref,
 
 def _bwd_dq_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
                  dq_ref, dq_s, i, j, is_first, is_last, *,
-                 scale, causal, block_q, block_k):
+                 scale, causal, block_q, block_k, window=None):
     @pl.when(is_first)
     def _init():
         dq_s[:] = jnp.zeros_like(dq_s)
@@ -239,7 +298,7 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
         s = _dot(q, kb, _NT) * scale
         s = s + kb_ref[0]
         if causal:
-            s = _mask_causal(s, i * block_q, j * block_k, q_axis=0)
+            s = _mask_causal(s, i * block_q, j * block_k, 0, window)
         p = jnp.exp(s - lse)
         dp = _dot(do, vb, _NT)
         ds = p * (dp - delta) * scale
@@ -253,27 +312,30 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_s, *, scale, causal, block_q, block_k):
+                   dq_ref, dq_s, *, scale, causal, block_q, block_k,
+                   window=None):
     i, j = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
     _bwd_dq_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
                  dq_ref, dq_s, i, j, j == 0, j == nk - 1,
-                 scale=scale, causal=causal, block_q=block_q, block_k=block_k)
+                 scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+                 window=window)
 
 
 def _bwd_dq_kernel_tri(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
                        lse_ref, delta_ref, dq_ref, dq_s, *,
-                       scale, block_q, block_k):
+                       scale, block_q, block_k, window=None, nb=None):
     t = pl.program_id(2)
     i, j = im_ref[t], jm_ref[t]
     _bwd_dq_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
-                 dq_ref, dq_s, i, j, j == 0, j == i,
-                 scale=scale, causal=True, block_q=block_q, block_k=block_k)
+                 dq_ref, dq_s, i, j, _row_start(i, j, nb), j == i,
+                 scale=scale, causal=True, block_q=block_q, block_k=block_k,
+                 window=window)
 
 
 def _bwd_dkv_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
                   dk_ref, dv_ref, dk_s, dv_s, i, j, is_first, is_last, *,
-                  scale, causal, block_q, block_k):
+                  scale, causal, block_q, block_k, window=None):
     @pl.when(is_first)
     def _init():
         dk_s[:] = jnp.zeros_like(dk_s)
@@ -292,7 +354,7 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
         kb = jnp.broadcast_to(kb_ref[0], (LANES, block_k)).T[:, :1]
         st = _dot(k, qb, _NT) * scale + kb                     # [bk, bq]
         if causal:
-            st = _mask_causal(st, i * block_q, j * block_k, q_axis=1)
+            st = _mask_causal(st, i * block_q, j * block_k, 1, window)
         pt = jnp.exp(st - lse_b)
         dv_s[:] = dv_s[:] + _dot(pt.astype(dob.dtype), dob, _NN)
         dpt = _dot(v, dob, _NT)
@@ -309,27 +371,28 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_s, dv_s, *, scale, causal, block_q,
-                    block_k):
+                    block_k, window=None):
     j, i = pl.program_id(2), pl.program_id(3)   # k block outer, q block inner
     nq = pl.num_programs(3)
     _bwd_dkv_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
                   dk_ref, dv_ref, dk_s, dv_s, i, j, i == 0, i == nq - 1,
                   scale=scale, causal=causal,
-                  block_q=block_q, block_k=block_k)
+                  block_q=block_q, block_k=block_k, window=window)
 
 
 def _bwd_dkv_kernel_tri(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
                         lse_ref, delta_ref, dk_ref, dv_ref, dk_s, dv_s, *,
-                        scale, block_q, block_k, nq):
+                        scale, block_q, block_k, nq, window=None, nb=None):
     t = pl.program_id(2)
     i, j = im_ref[t], jm_ref[t]
     # contributing q-blocks for k-block j run i = j..nq-1 (tri_maps_kv
     # order): the accumulator starts at the diagonal and ends at the last
-    # q-block
+    # q-block, in a band of nb at the last q-block that still sees j
+    last = nq - 1 if nb is None else jnp.minimum(j + nb, nq - 1)
     _bwd_dkv_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
-                  dk_ref, dv_ref, dk_s, dv_s, i, j, i == j, i == nq - 1,
+                  dk_ref, dv_ref, dk_s, dv_s, i, j, i == j, i == last,
                   scale=scale, causal=True,
-                  block_q=block_q, block_k=block_k)
+                  block_q=block_q, block_k=block_k, window=window)
 
 
 def _add_rows(acc, x):
@@ -344,7 +407,8 @@ def _add_rows(acc, x):
 
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, *, scale, causal, sub_q):
+                      dq_ref, dk_ref, dv_ref, *, scale, causal, sub_q,
+                      window=None):
     """The whole backward of one (batch, head) in one grid step: nothing
     crosses a grid step, so s^T, p^T, dp^T and ds^T are computed once and
     feed all three gradients (5 dots and one exp where the two kernels
@@ -356,7 +420,9 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
     v5e; docs/perf.md, PR 27). The query axis is walked in static
     sub-tiles of sub_q (the forward's tile), last to first; a causal
     self-attention sub-tile takes only the keys up to its last query, so
-    the blocks above the diagonal cost nothing here either."""
+    the blocks above the diagonal cost nothing here either. A `window`
+    is the mask's second edge here and skips nothing: a call short enough
+    for one pass is a few windows long at most."""
     bq, bk = q_ref.shape[2], k_ref.shape[2]
     kb = jnp.broadcast_to(kb_ref[0], (LANES, bk)).T[:, :1]     # [bk, 1]
     dk = dv = None
@@ -371,7 +437,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
         delta_b = delta_ref[0, 0, rows].T[:1]
         st = _dot(k, qb, _NT) * scale + kb[:nk]                # [nk, sub_q]
         if causal:
-            st = _mask_causal(st, q0, 0, q_axis=1)
+            st = _mask_causal(st, q0, 0, 1, window)
         pt = jnp.exp(st - lse_b)
         dv = _add_rows(dv, _dot(pt.astype(dob.dtype), dob, _NN))
         dpt = _dot(v, dob, _NT)
@@ -407,7 +473,7 @@ def _tri_specs(bq, bk, D):
     return qrow, kcol, kbias, stats
 
 
-def _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret):
+def _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret, window=None):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     out_shape = [
@@ -420,10 +486,12 @@ def _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret):
         pltpu.VMEM((bq, D), jnp.float32),
     ]
     if _use_tri(causal, Tq, Tk, bq, bk):
-        im, jm = _tri_maps(Tq // bq)
+        nb = _band(window, bk, Tq // bq)
+        im, jm = _tri_maps(Tq // bq, nb)
         qrow, kcol, kbias, stats = _tri_specs(bq, bk, D)
         kern = functools.partial(_fwd_kernel_tri, scale=scale,
-                                 block_q=bq, block_k=bk)
+                                 block_q=bq, block_k=bk, window=window,
+                                 nb=nb)
         return pl.pallas_call(
             kern,
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -437,7 +505,7 @@ def _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret):
             interpret=interpret,
         )(jnp.asarray(im), jnp.asarray(jm), q, k, v, kb)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                             block_q=bq, block_k=bk)
+                             block_q=bq, block_k=bk, window=window)
     return pl.pallas_call(
         kern,
         grid=(B, H, Tq // bq, Tk // bk),
@@ -457,18 +525,21 @@ def _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret):
     )(q, k, v, kb)
 
 
-def _bwd_call_tri(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret):
-    """Causal backward over the linearized lower-triangle grid (see the
-    strategy note at the top): dq accumulates over a q-row's k-blocks, then
-    dk/dv re-walk the triangle k-block-major (_tri_maps_kv order)."""
+def _bwd_call_tri(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret,
+                  window=None):
+    """Causal backward over the linearized lower-triangle grid or its band
+    (see the strategy note at the top): dq accumulates over a q-row's
+    k-blocks, then dk/dv re-walk the same pairs k-block-major
+    (_tri_maps_kv order)."""
     B, H, Tq, D = q.shape
     nq = Tq // bq
+    nb = _band(window, bk, nq)
     qrow, kcol, kbias, stats = _tri_specs(bq, bk, D)
     bwd_in_specs = [qrow, kcol, kcol, kbias, qrow, stats, stats]
-    im, jm = _tri_maps(nq)
+    im, jm = _tri_maps(nq, nb)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel_tri, scale=scale,
-                          block_q=bq, block_k=bk),
+                          block_q=bq, block_k=bk, window=window, nb=nb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, H, len(im)),
@@ -479,10 +550,11 @@ def _bwd_call_tri(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
     )(jnp.asarray(im), jnp.asarray(jm), q, k, v, kb, do, lse, delta)
-    im2, jm2 = _tri_maps_kv(nq)
+    im2, jm2 = _tri_maps_kv(nq, nb)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel_tri, scale=scale,
-                          block_q=bq, block_k=bk, nq=nq),
+                          block_q=bq, block_k=bk, nq=nq, window=window,
+                          nb=nb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, H, len(im2)),
@@ -503,7 +575,7 @@ def _bwd_call_tri(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret):
 
 
 def _bwd_call_fused(q, k, v, kb, do, lse, delta, causal, scale, sub_q,
-                    interpret):
+                    interpret, window=None):
     """One pass over a head's whole score matrix: grid (B, H), one kernel
     for dq, dk and dv (_bwd_fused_kernel)."""
     B, H, Tq, D = q.shape
@@ -514,7 +586,7 @@ def _bwd_call_fused(q, k, v, kb, do, lse, delta, causal, scale, sub_q,
     stats = pl.BlockSpec((1, 1, Tq, LANES), lambda b, h: (b, h, 0, 0))
     return pl.pallas_call(
         functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
-                          sub_q=sub_q),
+                          sub_q=sub_q, window=window),
         grid=(B, H),
         in_specs=[qrow, kcol, kcol, kbias, qrow, stats, stats],
         out_specs=[qrow, kcol, kcol],
@@ -528,7 +600,7 @@ def _bwd_call_fused(q, k, v, kb, do, lse, delta, causal, scale, sub_q,
 
 
 def _bwd_call(q, k, v, kb, do, lse, delta, causal, scale, bq, bk, one_pass,
-              interpret):
+              interpret, window=None):
     """One algorithm, scheduled by whether anything has to be accumulated
     across grid steps: one pass where _prep found a head's scores to be
     one tile, else dq and dk/dv in a pass each over the triangular or the
@@ -537,23 +609,23 @@ def _bwd_call(q, k, v, kb, do, lse, delta, causal, scale, bq, bk, one_pass,
     Tk = k.shape[2]
     if one_pass:
         return _bwd_call_fused(q, k, v, kb, do, lse, delta, causal, scale,
-                               bq, interpret)
+                               bq, interpret, window)
     if _use_tri(causal, Tq, Tk, bq, bk):
         return _bwd_call_tri(q, k, v, kb, do, lse, delta, scale, bq, bk,
-                             interpret)
+                             interpret, window)
     return _bwd_call_rect(q, k, v, kb, do, lse, delta, causal, scale, bq, bk,
-                          interpret)
+                          interpret, window)
 
 
 def _bwd_call_rect(q, k, v, kb, do, lse, delta, causal, scale, bq, bk,
-                   interpret):
+                   interpret, window=None):
     """Two passes over the rectangular grid: dq accumulates over a q-row's
     k-blocks, dk/dv over a k-column's q-blocks."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk),
+                          block_q=bq, block_k=bk, window=window),
         grid=(B, H, Tq // bq, Tk // bk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
@@ -571,7 +643,7 @@ def _bwd_call_rect(q, k, v, kb, do, lse, delta, causal, scale, bq, bk,
     )(q, k, v, kb, do, lse, delta)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk),
+                          block_q=bq, block_k=bk, window=window),
         grid=(B, H, Tk // bk, Tq // bq),
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, j, i: (b, h, i, 0)),
@@ -599,21 +671,24 @@ def _bwd_call_rect(q, k, v, kb, do, lse, delta, causal, scale, bq, bk,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash_lse(q, k, v, kb, causal, scale, bq, bk, one_pass, interpret):
-    o, lse = _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash_lse(q, k, v, kb, causal, window, scale, bq, bk, one_pass,
+               interpret):
+    o, lse = _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret, window)
     return o, lse[..., 0]
 
 
-def _flash_lse_fwd(q, k, v, kb, causal, scale, bq, bk, one_pass, interpret):
-    o, lse = _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret)
+def _flash_lse_fwd(q, k, v, kb, causal, window, scale, bq, bk, one_pass,
+                   interpret):
+    o, lse = _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret, window)
     # named for a recompute region's policy (step_artifact._run_region):
     # kept, the backward pass rebuilds q, k and v and not this call
     o, lse = checkpoint_name(o, 'flash_out'), checkpoint_name(lse, 'flash_lse')
     return (o, lse[..., 0]), (q, k, v, kb, o, lse)
 
 
-def _flash_lse_bwd(causal, scale, bq, bk, one_pass, interpret, res, cot):
+def _flash_lse_bwd(causal, window, scale, bq, bk, one_pass, interpret, res,
+                   cot):
     """Backward with an lse cotangent, sharing the kernels unchanged:
     lse = logsumexp(S) gives dS|lse = P * dlse, and the kernels compute
     dS = P * (dP - delta), so folding delta' = delta - dlse routes the lse
@@ -625,7 +700,7 @@ def _flash_lse_bwd(causal, scale, bq, bk, one_pass, interpret, res, cot):
     delta = delta - dlse.astype(jnp.float32)
     delta = jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
     dq, dk, dv = _bwd_call(q, k, v, kb, do, lse, delta, causal, scale,
-                           bq, bk, one_pass, interpret)
+                           bq, bk, one_pass, interpret, window)
     # kb is a mask constant (see module docstring): zero cotangent
     return dq, dk, dv, jnp.zeros_like(kb)
 
@@ -680,8 +755,22 @@ def _default_tile(tuned, T, row_bytes):
     return tuned
 
 
+def _window_of(window, causal, Tq):
+    """The window as the kernels take it: None where there is none or
+    where it reaches every earlier position (`window >= Tq` IS plain
+    causal attention, on the same grid)."""
+    if window is None:
+        return None
+    if not causal or int(window) != window or window < 1:
+        raise ValueError('flash attention: window=%r is a whole number of '
+                         'positions, at least 1, of a causal call (a query '
+                         'sees its own position and the window - 1 before '
+                         'it)' % (window,))
+    return None if window >= Tq else int(window)
+
+
 def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
-          causal=False):
+          causal=False, window=None):
     """Shared block-size/padding/bias plumbing for the public wrappers."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
@@ -695,9 +784,6 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
     # the dots take their operands as given, so the three agree on a dtype
     operands = jnp.result_type(q, k, v)
     q, k, v = (x.astype(operands) for x in (q, k, v))
-    # trace time: once per attention call per lowering (a forward and one
-    # or two backward kernels each), never per step
-    obs.counter('flash.lowered', operands=operands.name).inc()
     tuned_bq, tuned_bk = _TUNED_BQ_BK[bool(causal)]
     whole_q, whole_k = _TUNED_BQ_BK[False]
     row_bytes = D * operands.itemsize
@@ -721,7 +807,20 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
     one_pass = (Tq_p, Tk_p) == (bq, bk) or (
         not forced and Tq_p <= _default_tile(whole_q, Tq, row_bytes)
         and Tk_p <= _default_tile(whole_k, Tk, row_bytes))
+    # trace time: once per attention call per lowering (a forward and one
+    # or two backward kernels each), never per step. `grid` is the
+    # forward's; `flash.tiles` the (q-block, k-block) pairs a head that the
+    # call's grids visit, forward + dq + dk/dv (one pass: one).
+    nq, nk = Tq_p // bq, Tk_p // bk
+    if _use_tri(causal, Tq_p, Tk_p, bq, bk):
+        nb = _band(window, bk, nq)
+        grid, pairs = 'triangle' if nb is None else 'band', _tile_pairs(nq, nb)
+    else:
+        grid, pairs = 'rect', nq * nk
+    obs.counter('flash.lowered', operands=operands.name, grid=grid).inc()
     obs.counter('flash.backward', passes='one' if one_pass else 'two').inc()
+    obs.counter('flash.tiles', grid=grid).inc(
+        pairs + (1 if one_pass else 2 * pairs))
     if Tq_p != Tq:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, Tq_p - Tq), (0, 0)))
     if Tk_p != Tk:
@@ -737,16 +836,18 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
 
 
 def flash_attention_lse(q, k, v, key_bias=None, causal=False, sm_scale=None,
-                        block_q=None, block_k=None, *, interpret):
+                        block_q=None, block_k=None, window=None, *,
+                        interpret):
     """flash_attention that ALSO returns the per-query logsumexp
     ([B, H, Tq], f32) — the combine statistic ring attention needs to merge
     partial attention over key shards. Differentiable in q/k/v through BOTH
     outputs (see _flash_lse_bwd)."""
+    window = _window_of(window, causal, q.shape[2])
     (q, k, v, kb, scale, bq, bk, one_pass, interp, Tq, Tq_p) = _prep(
         q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
-        causal=causal)
-    o, lse = _flash_lse(q, k, v, kb, bool(causal), scale, bq, bk, one_pass,
-                        interp)
+        causal=causal, window=window)
+    o, lse = _flash_lse(q, k, v, kb, bool(causal), window, scale, bq, bk,
+                        one_pass, interp)
     if Tq_p != Tq:
         o = o[:, :, :Tq, :]
         lse = lse[:, :, :Tq]
@@ -754,12 +855,17 @@ def flash_attention_lse(q, k, v, key_bias=None, causal=False, sm_scale=None,
 
 
 def flash_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,
-                    block_q=None, block_k=None, *, interpret):
+                    block_q=None, block_k=None, window=None, *, interpret):
     """Flash attention over [B, H, T, D] tensors.
 
     key_bias: optional additive [B, Tk] bias (e.g. -1e9 on padded keys);
               treated as a non-differentiable mask.
     causal:   lower-triangular masking (decoder self-attention).
+    window:   with causal, a sliding window: query i sees the keys
+              i - window + 1 .. i (its own position counts). None, or a
+              window that reaches the whole sequence: plain causal. On
+              the triangular grid's conditions the kernels visit only the
+              band of blocks the window touches.
     block_q/block_k: kernel tile sizes (defaults from the _TUNED_BQ_BK
               table; tools/tune_flash.py sweeps them).
     interpret: required. False compiles through Mosaic (TPU only); True
@@ -770,12 +876,13 @@ def flash_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,
     # zero cotangent, making _flash_lse_bwd exactly the classic backward
     o, _ = flash_attention_lse(q, k, v, key_bias=key_bias, causal=causal,
                                sm_scale=sm_scale, block_q=block_q,
-                               block_k=block_k, interpret=interpret)
+                               block_k=block_k, window=window,
+                               interpret=interpret)
     return o
 
 
 def flash_attention_sharded(mesh, q, k, v, key_bias=None, causal=False,
-                            sm_scale=None, *, interpret):
+                            sm_scale=None, window=None, *, interpret):
     """flash_attention inside a GSPMD-partitioned step: the kernel runs
     PER SHARD, batch split over the mesh's 'dp' axis and heads over 'tp'
     (the layout DistributeTranspiler feeds and the Megatron column-parallel
@@ -797,7 +904,8 @@ def flash_attention_sharded(mesh, q, k, v, key_bias=None, causal=False,
     bdim, hdim = axis('dp', B), axis('tp', H)
     if bdim is None and hdim is None:
         return flash_attention(q, k, v, key_bias=key_bias, causal=causal,
-                               sm_scale=sm_scale, interpret=interpret)
+                               sm_scale=sm_scale, window=window,
+                               interpret=interpret)
     from jax.sharding import PartitionSpec as P
     if key_bias is None:
         key_bias = jnp.zeros((B, k.shape[2]), jnp.float32)
@@ -805,7 +913,8 @@ def flash_attention_sharded(mesh, q, k, v, key_bias=None, causal=False,
 
     def body(q, k, v, kb):
         return flash_attention(q, k, v, key_bias=kb, causal=causal,
-                               sm_scale=sm_scale, interpret=interpret)
+                               sm_scale=sm_scale, window=window,
+                               interpret=interpret)
 
     # check_vma off: pallas out_shapes carry no varying-mesh-axes info
     return jax.shard_map(body, mesh=mesh, in_specs=(qkv, qkv, qkv,
@@ -813,11 +922,13 @@ def flash_attention_sharded(mesh, q, k, v, key_bias=None, causal=False,
                          out_specs=qkv, check_vma=False)(q, k, v, key_bias)
 
 
-def reference_attention(q, k, v, key_bias=None, causal=False, sm_scale=None):
+def reference_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,
+                        window=None):
     """Plain-XLA attention with the same signature (fallback + test oracle).
     key_bias is stop_gradient'd to match the kernel's semantics."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
+    window = _window_of(window, causal, Tq)
     if sm_scale is None:
         sm_scale = D ** -0.5
     s = jnp.einsum('bhqd,bhkd->bhqk', q.astype(jnp.float32),
@@ -828,7 +939,10 @@ def reference_attention(q, k, v, key_bias=None, causal=False, sm_scale=None):
     if causal:
         qpos = jnp.arange(Tq)[:, None]
         kpos = jnp.arange(Tk)[None, :]
-        s = jnp.where(qpos >= kpos, s, NEG_BIG)
+        seen = qpos >= kpos
+        if window is not None:
+            seen = seen & (qpos - kpos < window)
+        s = jnp.where(seen, s, NEG_BIG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum('bhqk,bhkd->bhqd', p,
                       v.astype(jnp.float32)).astype(q.dtype)

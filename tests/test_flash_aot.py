@@ -136,3 +136,40 @@ def test_causal_conv1d_compiles_for_v5e(one_chip, dtype):
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).compile()
     assert compiled.as_text().count('tpu_custom_call') == 2
+
+
+@pytest.mark.parametrize('dtype,precision', [
+    ('bfloat16', None), ('float32', 'highest')],
+    ids=['bf16_the_cell', 'float32_the_check'])
+def test_windowed_band_compiles_for_v5e(one_chip, dtype, precision):
+    """smallthinker_s16384's windowed attention call (one row of 16384
+    positions, 28 heads of 128, a window of 4096) forward, dq and dk/dv on
+    the BAND grid, in the cell's bf16 and in its float32 check's
+    arithmetic (traced under jax's highest matmul precision, as
+    harness/check.py traces it), inside Mosaic's VMEM budget; the counter
+    says off the chip that the band was taken and what it spared: 252 of
+    the triangle's 528 tile pairs a head in each of the three grids."""
+    import contextlib
+    from paddle_tpu import obs
+    x = jax.ShapeDtypeStruct((1, 28, 16384, 128), jnp.dtype(dtype),
+                             sharding=one_chip)
+
+    def tiles():
+        return {g: obs.counter('flash.tiles', grid=g).value
+                for g in ('band', 'triangle', 'rect')}
+
+    def loss(q, k, v):
+        o = ops.flash_attention(q, k, v, causal=True, window=4096,
+                                interpret=False)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    before = tiles()
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).compile()
+    assert compiled.as_text().count('tpu_custom_call') == 3
+    after = tiles()
+    assert after['band'] - before['band'] == 3 * 252
+    assert after['triangle'] == before['triangle']
+    assert after['rect'] == before['rect']

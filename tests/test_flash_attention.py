@@ -537,7 +537,8 @@ def test_flash_lowered_counts_once_per_call_per_lowering_by_dtype():
     from paddle_tpu import obs
 
     def count():
-        return {d: obs.counter('flash.lowered', operands=d).value
+        return {d: sum(obs.counter('flash.lowered', operands=d, grid=g).value
+                       for g in ('band', 'triangle', 'rect'))
                 for d in ('bfloat16', 'float32')}
 
     def two_calls(q, k, v):
@@ -690,3 +691,240 @@ def test_flash_backward_counts_once_per_call_per_lowering():
         step(x, x, x)
     after = _count_passes()
     assert {p: after[p] - before[p] for p in after} == {'one': 1, 'two': 1}
+
+
+# ---------------------------------------------------------------------------
+# a sliding window (PR 37): the band of tiles, and the mask's second edge
+# ---------------------------------------------------------------------------
+
+def _window_inputs(T, H=2, Hkv=None, D=16, B=1, seed=51):
+    r = np.random.RandomState(seed)
+    q = jnp.asarray(r.randn(B, H, T, D), jnp.float32)
+    k, v = (jnp.asarray(r.randn(B, Hkv or H, T, D), jnp.float32)
+            for _ in range(2))
+    do = jnp.asarray(r.randn(B, H, T, D), jnp.float32)
+    return q, k, v, do
+
+
+def _window_pair(q, k, v, do, window, tile):
+    """((loss, (dq, dk, dv)) of the kernels, of the oracle): the kernels
+    under the interpreter in `tile`-blocks, key-value heads repeated over
+    their group as the op's rule repeats them."""
+    group = q.shape[1] // k.shape[1]
+
+    def wide(t):
+        return jnp.repeat(t, group, axis=1)
+
+    def flash(q, k, v):
+        o = ops.flash_attention(q, wide(k), wide(v), causal=True,
+                                window=window, block_q=tile, block_k=tile,
+                                interpret=True)
+        return jnp.sum(o * do)
+
+    def oracle(q, k, v):
+        o = ops.reference_attention(q, wide(k), wide(v), causal=True,
+                                    window=window)
+        return jnp.sum(o * do)
+
+    return (jax.value_and_grad(flash, (0, 1, 2))(q, k, v),
+            jax.value_and_grad(oracle, (0, 1, 2))(q, k, v))
+
+
+# T = 640 in 128-tiles is a grid of five tiles a side: a window of one tile
+# is a band of two (nb 1), a tile and a half a band of three
+_WINDOW_CASES = {
+    'one_key': dict(window=1),
+    'three_keys': dict(window=3),
+    'one_tile': dict(window=128),
+    'a_tile_and_a_half': dict(window=192),
+    'no_tile_multiple': dict(window=300),
+    'the_whole_row': dict(window=640),
+    'longer_than_the_row': dict(window=1000),
+    'one_pass': dict(window=100, T=384, tile=None),
+    'rectangular_grid': dict(window=100, T=384, tile=(128, 256)),
+}
+
+
+@pytest.mark.parametrize('case', sorted(_WINDOW_CASES))
+def test_window_forward_and_gradients_match_reference(case):
+    c = _WINDOW_CASES[case]
+    T, tile = c.get('T', 640), c.get('tile', 128)
+    q, k, v, do = _window_inputs(T)
+    if isinstance(tile, tuple):
+        def flash(q, k, v):
+            return jnp.sum(do * ops.flash_attention(
+                q, k, v, causal=True, window=c['window'], block_q=tile[0],
+                block_k=tile[1], interpret=True))
+        got = jax.value_and_grad(flash, (0, 1, 2))(q, k, v)
+        _, want = _window_pair(q, k, v, do, c['window'], None)
+    else:
+        got, want = _window_pair(q, k, v, do, c['window'], tile)
+    assert abs(float(got[0]) - float(want[0])) <= 3e-4 * T
+    # against the cotangent's norm where a gradient is zero (a window of
+    # one key: the softmax of one score is 1 whatever q and k are)
+    floor = float(jnp.linalg.norm(do))
+    for a, b, name in zip(got[1], want[1], ('dq', 'dk', 'dv')):
+        err = float(jnp.linalg.norm(a - b))
+        assert err <= 3e-6 * max(float(jnp.linalg.norm(b)), floor), name
+
+
+def test_window_is_not_the_window_one_key_off():
+    """The comparison above holds the window's edge: the oracle one key
+    wider or narrower is another function."""
+    q, k, v, do = _window_inputs(640)
+    got, _ = _window_pair(q, k, v, do, 192, 128)
+    for other in (191, 193):
+        _, off = _window_pair(q, k, v, do, other, 128)
+        assert _rel_norm(got[1][0], off[1][0]) > 1e-3
+
+
+def test_window_of_the_whole_row_is_plain_causal_on_the_same_grid():
+    from paddle_tpu import obs
+    q, k, v, do = _window_inputs(640)
+
+    def tiles():
+        return {g: obs.counter('flash.tiles', grid=g).value
+                for g in ('band', 'triangle', 'rect')}
+
+    before = tiles()
+    whole, _ = _window_pair(q, k, v, do, 640, 128)
+    after = tiles()
+    plain, _ = _window_pair(q, k, v, do, None, 128)
+    assert float(whole[0]) == float(plain[0])
+    for a, b in zip(whole[1], plain[1]):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    # the triangle of five tiles, forward + dq + dk/dv; no band
+    assert after['triangle'] - before['triangle'] == 3 * 15
+    assert after['band'] == before['band']
+
+
+def test_window_over_grouped_heads_28_over_4():
+    """28 query heads over 4 key-value heads at a toy width: a group of 7,
+    no power of two, through the repeat the op's rule makes."""
+    q, k, v, do = _window_inputs(256, H=28, Hkv=4, D=8)
+    got, want = _window_pair(q, k, v, do, 100, 128)
+    assert got[1][1].shape == (1, 4, 256, 8)
+    for a, b, name in zip(got[1], want[1], ('dq', 'dk', 'dv')):
+        assert _rel_norm(a, b) <= 3e-6, name
+
+
+def test_no_window_gives_the_maps_of_the_triangle():
+    """`window=None`: the enumeration a plain causal call always had,
+    as arrays."""
+    import importlib
+    fa = importlib.import_module('paddle_tpu.ops.flash_attention')
+    for n in (1, 2, 5, 32):
+        i = np.repeat(np.arange(n), np.arange(1, n + 1))
+        j = np.concatenate([np.arange(r + 1) for r in range(n)])
+        got = fa._tri_maps(n)
+        assert got[0].dtype == got[1].dtype == np.int32
+        assert np.array_equal(got[0], i) and np.array_equal(got[1], j)
+        ii = np.concatenate([np.arange(c, n) for c in range(n - 1, -1, -1)])
+        jj = np.concatenate([np.full(n - c, c)
+                             for c in range(n - 1, -1, -1)])
+        got = fa._tri_maps_kv(n)
+        assert np.array_equal(got[0], ii) and np.array_equal(got[1], jj)
+        assert fa._band(None, 512, n) is None
+        assert fa._tile_pairs(n) == len(i)
+
+
+@pytest.mark.parametrize('n,tile,window', [
+    (5, 128, 128), (5, 128, 192), (5, 128, 129), (5, 128, 1), (8, 16, 40),
+    (32, 512, 4096)])
+def test_band_maps_visit_each_admitted_tile_once(n, tile, window):
+    import importlib
+    fa = importlib.import_module('paddle_tpu.ops.flash_attention')
+    nb = fa._band(window, tile, n)
+    # the tiles that hold a pair the mask admits, from the positions
+    pos = np.arange(n * tile)
+    ahead = pos[:, None] - pos[None, :]
+    admitted = ((ahead >= 0) & (ahead < window)).reshape(
+        n, tile, n, tile).any(axis=(1, 3))
+    want = {(i, j) for i in range(n) for j in range(n) if admitted[i, j]}
+    for maps in (fa._tri_maps(n, nb), fa._tri_maps_kv(n, nb)):
+        pairs = list(zip(maps[0].tolist(), maps[1].tolist()))
+        assert len(pairs) == len(set(pairs)) == len(want)
+        assert set(pairs) == want
+        assert fa._tile_pairs(n, nb) == len(pairs)
+    # forward and dq: a q-tile's k-tiles consecutive, first to diagonal
+    i, j = fa._tri_maps(n, nb)
+    for row in range(n):
+        ks = j[i == row]
+        assert np.array_equal(np.flatnonzero(i == row),
+                              np.arange(ks.size) + np.flatnonzero(i == row)[0])
+        assert ks[-1] == row and np.array_equal(
+            ks, np.arange(max(0, row - (n if nb is None else nb)), row + 1))
+    # dk/dv: a k-tile's q-tiles consecutive steps, diagonal first
+    i, j = fa._tri_maps_kv(n, nb)
+    for col in range(n):
+        at = np.flatnonzero(j == col)
+        assert np.array_equal(at, np.arange(at.size) + at[0])
+        assert i[at][0] == col and np.array_equal(
+            i[at], np.arange(col, col + at.size))
+    if (n, tile, window) == (32, 512, 4096):
+        assert nb == 8 and fa._tile_pairs(n, nb) == 252 \
+            and fa._tile_pairs(n) == 528
+
+
+def test_flash_tiles_counts_the_band():
+    """A lowering says off the chip which grid a call took and how many
+    tile pairs a head its three grids visit."""
+    from paddle_tpu import obs
+
+    def read():
+        return ({g: obs.counter('flash.tiles', grid=g).value
+                 for g in ('band', 'triangle', 'rect')},
+                {g: obs.counter('flash.lowered', operands='float32',
+                                grid=g).value
+                 for g in ('band', 'triangle', 'rect')})
+
+    q, k, v, _ = _window_inputs(640)
+    tiles0, calls0 = read()
+    ops.flash_attention(q, k, v, causal=True, window=192, block_q=128,
+                        block_k=128, interpret=True)
+    tiles1, calls1 = read()
+    # rows of 1, 2, 3, 3, 3 tiles: 12 pairs, in each of three grids
+    assert tiles1['band'] - tiles0['band'] == 3 * 12
+    assert calls1['band'] - calls0['band'] == 1
+    assert tiles1['triangle'] == tiles0['triangle']
+    ops.flash_attention(q, k, v, causal=True, window=192, block_q=128,
+                        block_k=256, interpret=True)
+    tiles2, calls2 = read()
+    # oblong tiles: the rectangular grid, 5 x 3 pairs (keys pad to 768)
+    assert tiles2['rect'] - tiles1['rect'] == 3 * 15
+    assert calls2['rect'] - calls1['rect'] == 1 \
+        and tiles2['band'] == tiles1['band']
+
+
+def test_window_needs_causal_and_a_whole_number():
+    q, k, v, _ = _window_inputs(128)
+    for kw in (dict(causal=False, window=8), dict(causal=True, window=0),
+               dict(causal=True, window=2.5)):
+        with pytest.raises(ValueError, match='window'):
+            ops.flash_attention(q, k, v, interpret=True, **kw)
+        with pytest.raises(ValueError, match='window'):
+            ops.reference_attention(q, k, v, **kw)
+
+
+def test_fused_attention_window_op_matches_reference_and_refuses():
+    """The attribute through the op to the XLA chain a host takes, with
+    grouped heads; a window without causal is refused at construction."""
+    q, k, v, _ = _window_inputs(40, H=6, Hkv=2, D=8, B=2)
+    with fresh_program() as (main, startup):
+        qv, kv, vv = (layers.data(name=n, shape=list(t.shape[1:]),
+                                  dtype='float32')
+                      for n, t in (('q', q), ('k', k), ('v', v)))
+        out = layers.fused_attention(qv, kv, vv, causal=True, window=7)
+        with pytest.raises(ValueError, match='window'):
+            layers.fused_attention(qv, kv, vv, window=7)
+        op = [o for o in main.global_block().ops
+              if o.type == 'flash_attention'][0]
+        assert op.attrs['window'] == 7
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        got, = exe.run(main, feed={'q': np.asarray(q), 'k': np.asarray(k),
+                                   'v': np.asarray(v)}, fetch_list=[out])
+    want = ops.reference_attention(q, jnp.repeat(k, 3, axis=1),
+                                   jnp.repeat(v, 3, axis=1), causal=True,
+                                   window=7)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-5, atol=2e-5)

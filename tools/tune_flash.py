@@ -14,8 +14,11 @@ default tiles make a head's scores one tile, and the two kernels (`dq`,
 a change to a kernel body. --tile N forces the parts' tiles: with --causal
 --seq 1024, --tile 1024 is one masked tile in one pass and --tile 512 the
 triangular grid's two kernels, against the default's one pass in 512
-sub-tiles. docs/perf.md has the last sweep's rows and the commands that
-gave them.
+sub-tiles. --window N (with --causal --parts) times the same kernels on
+the BAND of tiles a sliding window touches (PR 37): `--batch 1 --heads 28
+--seq 16384 --dim 128 --causal --parts --no-sweep` with and without
+`--window 4096` is smallthinker_s16384's windowed and global call.
+docs/perf.md has the last sweep's rows and the commands that gave them.
 """
 import argparse
 import itertools
@@ -29,7 +32,7 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def time_parts(q, k, v, causal, iters, tile=None):
+def time_parts(q, k, v, causal, iters, tile=None, window=None):
     """[(kernel, seconds per call, grid steps per call)] of the forward,
     the one-pass backward where the tiles allow it, and the dq and dk/dv
     kernels alone, at the default tiles or at `tile`. The dq and dk/dv
@@ -40,9 +43,11 @@ def time_parts(q, k, v, causal, iters, tile=None):
     from paddle_tpu.utils.timing import time_chained
     # the package's attribute of that name is the function
     fa = importlib.import_module('paddle_tpu.ops.flash_attention')
+    window = fa._window_of(window, causal, q.shape[2])
     q, k, v, kb, scale, bq, bk, one_pass, interp, _, _ = fa._prep(
-        q, k, v, None, None, tile, tile, False, causal=causal)
-    o, lse = fa._fwd_call(q, k, v, kb, causal, scale, bq, bk, interp)
+        q, k, v, None, None, tile, tile, False, causal=causal,
+        window=window)
+    o, lse = fa._fwd_call(q, k, v, kb, causal, scale, bq, bk, interp, window)
     delta = jnp.sum(o.astype(jnp.float32) ** 2, axis=-1)
     delta = jnp.broadcast_to(delta[..., None], delta.shape + (fa.LANES,))
 
@@ -51,11 +56,11 @@ def time_parts(q, k, v, causal, iters, tile=None):
 
     def bwd(q, k, v, one_pass):  # the cotangent is o itself: bf16, full rank
         return fa._bwd_call(q, k, v, kb, o, lse, delta, causal, scale,
-                            bq, bk, one_pass, interp)
+                            bq, bk, one_pass, interp, window)
 
     def fwd_step(x):
         return (nudge(x[0], fa._fwd_call(x[0], k, v, kb, causal, scale,
-                                         bq, bk, interp)[0]),)
+                                         bq, bk, interp, window)[0]),)
 
     def bwd_step(x):
         return tuple(nudge(a, d) for a, d in zip(x, bwd(*x, True)))
@@ -69,8 +74,8 @@ def time_parts(q, k, v, causal, iters, tile=None):
 
     B, H, T, _ = q.shape
     nq = T // bq
-    blocks = nq * (nq + 1) // 2 if fa._use_tri(causal, T, T, bq, bk) \
-        else nq * (T // bk)
+    blocks = fa._tile_pairs(nq, fa._band(window, bk, nq)) \
+        if fa._use_tri(causal, T, T, bq, bk) else nq * (T // bk)
     parts = [('fwd', fwd_step, (q,), blocks)]
     if one_pass:
         parts.append(('bwd', bwd_step, (q, k, v), 1))
@@ -96,6 +101,8 @@ def main():
                     help='also time fwd, the one-pass bwd, dq and dkv alone')
     ap.add_argument('--tile', type=int, default=None,
                     help='tiles of --parts (default: the table\'s)')
+    ap.add_argument('--window', type=int, default=None,
+                    help='a sliding window for --parts (with --causal)')
     ap.add_argument('--no-sweep', action='store_true',
                     help='stop after --parts')
     args = ap.parse_args()
@@ -119,7 +126,7 @@ def main():
 
     if args.parts:
         for name, dt, steps in time_parts(q, k, v, args.causal, args.iters,
-                                          args.tile):
+                                          args.tile, args.window):
             print('part %-3s %.3f ms/call, %d grid steps, %.3f us/grid step'
                   % (name, dt * 1e3, steps, dt * 1e6 / steps))
     if args.no_sweep:
