@@ -111,14 +111,17 @@ class Ctx(object):
     shard_map (sp attention) must instead use the per-shard collective
     bodies on those axes. `facts` is the step's dict of what rules say
     about their device counters (`note`); None where nothing gathers it
-    (shape inference, sub-blocks, a pipeline stage)."""
+    (shape inference, sub-blocks, a pipeline stage). `bodies` is the
+    step's dict of the rule bodies its ops share (`traced_once`); None in
+    the same places. `scope` is the `fluid.name_scope` path the op was
+    built under (`run_op` writes it)."""
 
     __slots__ = ('key', 'op_index', 'is_test', 'amp', 'platform', 'mesh',
-                 'manual_axes', 'facts')
+                 'manual_axes', 'facts', 'bodies', 'scope')
 
     def __init__(self, key, op_index=0, is_test=False, amp=False,
                  platform='cpu', mesh=None, manual_axes=frozenset(),
-                 facts=None):
+                 facts=None, bodies=None):
         self.key = key
         self.op_index = op_index
         self.is_test = is_test
@@ -127,6 +130,8 @@ class Ctx(object):
         self.mesh = mesh
         self.manual_axes = manual_axes
         self.facts = facts
+        self.bodies = bodies
+        self.scope = ''
 
     def rng(self):
         return jax.random.fold_in(self.key, self.op_index)
@@ -146,6 +151,41 @@ class Ctx(object):
         Decided from the Executor's place (or mesh), never from the
         process's default backend."""
         return self.platform != 'tpu'
+
+
+def traced_once(ctx, fn, **static):
+    """`fn(ctx, *arrays, **static)` as ONE `jax.jit` function for every op
+    of the step that asks with the same `fn` and `static` from under the
+    same `fluid.name_scope`: a model's equal layers then run a rule's
+    Python body once a shape, autodiff works on one jaxpr, and the module
+    holds one function, called from each op under that op's scopes (XLA
+    writes the caller's op_name before the body's own, so a profile still
+    reads `<type>_<index>/jit(<fn>)/...`; `fn`'s name must not itself end
+    in `_<digits>`). Not across name scopes: the inlined calls share ONE
+    copy of the body's nested computations (a conditional's branches), and
+    that copy carries one caller's op_name, its op index (any op of the
+    type: what a reader by op type sums) and its name scope, which a
+    reader by name scope (`mtp_ms`) would be handed. The function lives in
+    the step's `bodies`, so another Program (a check beside the training
+    step, a build after a test patched the rule's module) traces its own.
+    The `ctx` the shared body sees says what the step's every op says
+    (run mode, AMP, platform, mesh) and has no PRNG key: a body that
+    draws from `rng()` is not one to share. Where no step gathers bodies
+    (shape inference, a sub-block, a rule called with a stand-in for
+    `ctx`) the plain function on the caller's `ctx`, traced where it is
+    called."""
+    bodies = getattr(ctx, 'bodies', None)
+    if bodies is None:
+        return functools.partial(fn, ctx, **static)
+    key = (fn, ctx.scope, tuple(sorted(static.items())))
+    if key not in bodies:
+        body = functools.partial(
+            fn, Ctx(None, is_test=ctx.is_test, amp=ctx.amp,
+                    platform=ctx.platform, mesh=ctx.mesh,
+                    manual_axes=ctx.manual_axes), **static)
+        body.__name__ = fn.__name__        # the function's name in the module
+        bodies[key] = jax.jit(body)
+    return bodies[key]
 
 
 def amp_cast(ctx, *xs):
@@ -325,8 +365,9 @@ def run_op(op, env, ctx):
     scopes the op was built in (`fluid.name_scope`, the attribute
     `name_scope`) are entered around it, outermost first, each as
     `scope_label` writes it: `.../mtp/latent_attention/mul_17/...`."""
+    ctx.scope = op.attrs.get('name_scope', '')
     with contextlib.ExitStack() as scopes:
-        for name in op.attrs.get('name_scope', '').split('/'):
+        for name in ctx.scope.split('/'):
             if name:
                 scopes.enter_context(jax.named_scope(scope_label(name)))
         scopes.enter_context(

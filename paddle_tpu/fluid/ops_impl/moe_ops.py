@@ -61,13 +61,19 @@ dropless: no assignment to a held expert is lost at any imbalance, and
 an assignment to an absent expert costs no matmul tile. How the held
 experts get their rows (`_held_moe`): BY INDEX, once a layer. One sort of
 the tokens x k assignments puts the held ones first in expert order; a
-layout of `_HELD_SLACK` times the expected number of held rows is
+layout of `_HELD_SLACK` times the expected number of held rows, and never
+more than half the layer's rows (`_held_layout`: a share of an eighth of
+the experts lays out 4 times its expected rows, one of a 32nd all 10), is
 gathered from the tokens, goes through the grouped matmuls, and is added
 back to the tokens by a scatter-add (`_compact_moe`; the cost follows the
 rows laid out, not rows x tokens). A router that sends more than that to
 the held experts is answered, on the device (one `lax.cond` a layer), by
-the static tokens x k rows a block at a time (`_held_blocks`). One
-device, as the whole dropless layer: the same refusal on a mesh.
+the static tokens x k rows a block at a time (`_held_blocks`), which is
+also all a layer has where half its rows are under one 256-row tile. The
+layer from its keys on (`_held_paths`: the conditional and both paths) is
+one `jax.jit` function a step, shared by the step's layers of equal
+shapes (`lowering.traced_once`). One device, as the whole dropless layer:
+the same refusal on a mesh.
 
 ANOTHER ROUTER (dropless only; `scoring`, `SelectionBias`, `gate_scale`):
 `scoring` 'sigmoid' scores every expert by itself, sigmoid(logit), where
@@ -113,7 +119,7 @@ from jax import lax
 
 from ... import obs
 from ..lowering import (DeviceCounter, amp_cast, data_of, register,
-                        register_device_counter)
+                        register_device_counter, traced_once)
 
 _ACTS = {
     'relu': jax.nn.relu,
@@ -275,24 +281,48 @@ def _dropless_moe(params, x, expert, gate, sizes, act, ctx, live=None):
         return jnp.sum(out.astype(jnp.float32) * gate[..., None], axis=1)
 
 
+def _argsort(keys, bound):
+    """jnp.argsort(keys, stable=True) of int32 keys in [0, bound): where
+    they fit, a key and its position are packed into ONE int32 and sorted
+    as one operand, unstably (the packed keys are distinct, so the order is
+    the stable one). The TPU's compiler takes 2 s over such a sort of
+    98304 keys and 14 s over the stable sort of two operands that argsort
+    lowers to (11 s from 20480 keys on; AOT, PR 39): a start's time, in the
+    step and in every check Program."""
+    n = keys.shape[0]
+    span = 1 << (n - 1).bit_length()
+    if bound * span > 1 << 31:
+        return jnp.argsort(keys, stable=True).astype(jnp.int32)
+    packed = lax.sort(keys * span + lax.iota(jnp.int32, n), is_stable=False)
+    return packed & (span - 1)
+
+
 # The two row moves of a held share's compact path, each the other's
-# transpose, both by index: `at` [cap] is the row of x that a laid-out row
-# takes. Written as a pair so that the add is float32 whatever the rows
-# are (jax's own transpose of a bf16 gather adds in bf16) and neither way
-# clamps an index. The zero-size residuals carry a shape and a dtype.
+# transpose, both by index: `at` is (token, order, token[order]): token
+# [cap] is the row of x that a laid-out row takes, order [cap] lists the
+# laid-out rows by token. Written as a pair so that the add is float32
+# whatever the rows are (jax's own transpose of a bf16 gather adds in
+# bf16), neither way clamps an index, and the add is given its rows in the
+# order of the rows they are added to: left to itself XLA sorts a
+# scatter's indices anew in every scatter, forward and backward, and its
+# compiler takes 11 s over each such sort of 49152 (2 s over the scatter
+# told its indices are sorted; AOT, PR 39). The zero-size residuals carry
+# a shape and a dtype.
 @jax.custom_vjp
 def _lay_out(x, at):
-    """x[at]: the laid-out rows [cap, d] of x [n, d]."""
-    return _rows(x, at)
+    """x[token]: the laid-out rows [cap, d] of x [n, d]."""
+    return _rows(x, at[0])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _add_up(rows, at, n):
     """The laid-out rows [cap, d] added to the rows of x they came from:
     [n, d] float32. A row that no assignment fills must come as zeros."""
+    _, order, by_token = at
     zero = jnp.zeros((n,) + rows.shape[1:], jnp.float32)
-    return zero.at[at].add(rows.astype(jnp.float32),
-                           mode='promise_in_bounds')
+    return zero.at[by_token].add(
+        _rows(rows, order).astype(jnp.float32), indices_are_sorted=True,
+        mode='promise_in_bounds')
 
 
 _lay_out.defvjp(
@@ -317,8 +347,10 @@ def _compact_moe(params, x, key, gate, sizes, cap, act, ctx):
     nt, k = key.shape
     with jax.named_scope('moe_route'):
         flat = key.reshape(-1)                         # token-major
-        src = jnp.argsort(flat, stable=True).astype(jnp.int32)[:cap]
+        src = _argsort(flat, sizes.shape[0] + 1)[:cap]
         token = src // k
+        order = _argsort(token, nt)
+        at = (token, order, _rows(token, order))
         # `keep`: a row past `live` is some absent assignment's token, and
         # the kernels' gradient of the rows is unwritten there
         keep = _keep(jnp.sum(sizes))
@@ -332,12 +364,12 @@ def _compact_moe(params, x, key, gate, sizes, cap, act, ctx):
     @jax.checkpoint
     def rows_of(params, x, gate):
         with jax.named_scope('moe_route'):
-            rows = keep(_lay_out(x, token))
-            row_gate = _lay_out(gate.reshape(-1, 1), src)
+            rows = keep(_lay_out(x, at))
+            row_gate = _rows(gate.reshape(-1, 1), src)
         with jax.named_scope('moe_experts'):
             out = _experts(params, rows, sizes, group, act, ctx, keep)
         with jax.named_scope('moe_combine'):
-            return _add_up(out.astype(jnp.float32) * row_gate, token, nt)
+            return _add_up(out.astype(jnp.float32) * row_gate, at, nt)
 
     return rows_of(params, x, gate)
 
@@ -357,11 +389,13 @@ def _held_cap(assignments, count, n_exp):
 
 
 def _held_layout(assignments, count, n_exp):
-    """`_held_cap`, or None where a layout of it holds half the layer's
-    rows or more: nothing to gain from compacting, and the layer keeps
-    every row whatever the router does."""
-    cap = _held_cap(assignments, count, n_exp)
-    return None if 2 * cap > assignments else cap
+    """Rows of the compact path's layout: `_held_cap`, or half the layer's
+    rows (rounded down to the kernels' 256) where the slack's layout would
+    hold more; a layout of more than half the rows gains too little on
+    keeping them all. None where half the rows are under one such tile:
+    the layer keeps every row whatever the router does."""
+    return min(_held_cap(assignments, count, n_exp),
+               assignments // 2 // 256 * 256) or None
 
 
 def _held_blocks(params, x, key, gate, act, ctx):
@@ -398,32 +432,41 @@ def _held_moe(params, x, expert, gate, sizes, held, act, ctx):
     takes one of two paths, chosen ONCE on the device (lax.cond) from how
     many of its tokens x k assignments are held:
 
-    - `_compact_moe`, where they fit `_held_cap`, `_HELD_SLACK` times the
-      expected number (tokens x k x count / num_experts): only those rows
-      exist, reached by index. A row of
-      the capacity that no assignment fills costs a gathered row and a
-      tile the kernels skip. The slack is wide on purpose: a router in
-      training leans towards or away from the held experts within tens of
-      steps (read on the chip, PR 30: 1.2 times the expected rows at the
-      first step and 5.8 times at the 64th), and a step's time should not
-      follow it;
+    - `_compact_moe`, where they fit the layout (`_held_layout`):
+      `_HELD_SLACK` times the expected number (tokens x k x count /
+      num_experts), and at most half the layer's rows. Only those rows
+      exist, reached by index. A row of the layout that no assignment
+      fills costs a gathered row and a tile the kernels skip. The slack
+      is wide on purpose: a router in training leans towards or away from
+      the held experts within tens of steps (read on the chip, PR 30: 1.2
+      times the expected rows at the first step and 5.8 times at the
+      64th), and a step's time should not follow it;
     - `_held_blocks`, at any imbalance beyond that: the static tokens x k
       rows a block at a time, so no assignment to a held expert is ever
-      lost.
+      lost. The only path where the layer has no layout (toy widths).
 
     Either way an absent expert's assignment costs no matmul tile.
-    `params` are in the experts' dtype already (one cast a layer: at 16
-    experts of 2048 x 512 it moves 300 MB)."""
+    Everything after the keys is `_held_paths`, one function a step for
+    all its layers of these shapes (`traced_once`). `params` are in the
+    experts' dtype already (one cast a layer: at 16 experts of 2048 x 512
+    it moves 300 MB)."""
     first, count = held
     nt, k = expert.shape
     with jax.named_scope('moe_route'):
         local = expert - first
         key = jnp.where((local >= 0) & (local < count), local, count)
     cap = _held_layout(nt * k, count, sizes.shape[0])
+    paths = traced_once(ctx, _held_paths, cap=cap, act=act)
+    return paths(params, x, key, gate, sizes[first:first + count],
+                 _held_rows(sizes, held))
+
+
+def _held_paths(ctx, params, x, key, gate, sizes, rows, *, cap, act):
+    """`_held_moe` from its keys on: `key` [nt, k] is the held expert's
+    index or, for an absent one, the number of held experts; `sizes` are
+    the held experts' counts and `rows` their sum (`_held_rows`)."""
     if cap is None:
         return _held_blocks(params, x, key, gate, act, ctx)
-    rows = _held_rows(sizes, held)
-    sizes = sizes[first:first + count]
     return lax.cond(
         rows <= cap,
         lambda *a: _compact_moe(*a, sizes, cap, act, ctx),

@@ -57,6 +57,15 @@ __all__ = ['StepArtifact', 'program_fingerprint', 'stable_signature',
            'AOT_MANIFEST', 'AOT_CACHE_DIR']
 
 
+# What a recompute region keeps besides its inputs (`_run_region`). ONE
+# object for every region: jax caches the split of a jitted body into what
+# is known and what is run again on the policy's identity, so regions that
+# call one shared body (lowering.traced_once, the kernels' own jits) share
+# its halves too; a policy made anew a region gave every layer its own.
+_REGION_KEEPS = jax.checkpoint_policies.save_only_these_names(
+    'flash_out', 'flash_lse')
+
+
 def _is_annotated(program):
     """True for a Program on the first-class GSPMD annotation path:
     a `set_mesh()` spec and no legacy transpiler `_dist_config` (the
@@ -143,6 +152,8 @@ class StepArtifact(object):
         # bind nothing in the step's env, so a pipelined step keeps none.
         self.counters = []
         self.counter_facts = {}
+        # the rule bodies this step's ops share (lowering.traced_once)
+        self.bodies = {}
         for i, op in enumerate(ops if self.pipe is None else ()):
             found = lowering.device_counter(op)
             if found is not None:
@@ -522,9 +533,7 @@ class StepArtifact(object):
                           in_region=True)
             return {n: e[n] for n in handed_on if n in e}
 
-        policy = jax.checkpoint_policies.save_only_these_names(
-            'flash_out', 'flash_lse')
-        env.update(jax.checkpoint(region, policy=policy)(inputs))
+        env.update(jax.checkpoint(region, policy=_REGION_KEEPS)(inputs))
         return hi
 
     def _make_fwd(self, base, ad, key, taps=None):
@@ -650,7 +659,8 @@ class StepArtifact(object):
 
     def _ctx(self, key, seq):
         return Ctx(key, seq, amp=self.amp, platform=self.platform,
-                   mesh=self.mesh, facts=self.counter_facts)
+                   mesh=self.mesh, facts=self.counter_facts,
+                   bodies=self.bodies)
 
     def _run_ops(self, env, lo, hi, key, grad_mode=False, on_op=None,
                  taps=None, in_region=False):

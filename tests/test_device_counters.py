@@ -96,8 +96,9 @@ def test_every_step_record_carries_the_rows_the_step_counted(name, tmp_path):
             k = cell['config']['model']['num_experts_per_tok']
             for e in device:
                 assert e['expected'] == tokens * k * count / routed
-                # at the toy widths a layout of the slack is no smaller
-                # than the rows: the layer keeps them all, statically
+                # at the toy widths half the layer's rows (480 / 2) are
+                # under one 256-row tile: no layout, the layer keeps them
+                # all, statically
                 assert e['cap'] is None and e['way'] == 'blocks'
         keys = {r['fields'].get('key') for r in _step_records()
                 if 'device' in r['fields']}
@@ -174,6 +175,59 @@ def test_a_layer_under_its_layout_reads_compact(tmp_path):
         # every token's first choice: 4096 rows, still a layout's worth
         assert counts[6] == N and entry['rows'] == N
         assert entry['way'] == 'compact' and ways() == [2, 0]
+
+
+def test_an_eighth_held_reads_the_layout_of_half_its_rows(tmp_path):
+    """2 of 16 held over 2048 tokens x 2: ten times the expected 512 rows
+    are more than all 4096, so the layout is half of them, 2048, chosen
+    on the device: the entry reads that `cap`, `way` follows `rows <=
+    cap` (the router as initialised: compact; every first choice and
+    every second to the two held experts: 4096 rows, blocks), and
+    tools/held_share.py counts such a layer among those that can go over
+    their layout (its `static` is False: `layers_over_slack` counts the
+    second step)."""
+    obs.enable(str(tmp_path))
+    tokens, experts, held = 2048, 16, (6, 2)
+    main, startup = framework.Program(), framework.Program()
+    main.random_seed = startup.random_seed = 3
+    with unique_name.guard(), framework.program_guard(main, startup):
+        x = layers.data(name='x', shape=[D], dtype='float32')
+        out, count = layers.moe_mlp(
+            x, num_experts=experts, hidden_size=H, act='swish', gated=True,
+            top_k=2, norm_topk_prob=True, capacity_factor=None,
+            bias_attr=False, return_expert_count=True, experts_held=held)
+        loss = layers.mean(out)
+        fluid.optimizer.SGD(learning_rate=0.0).minimize(loss)
+    assert moe_ops._held_cap(tokens * 2, 2, experts) == 5120
+    assert moe_ops._held_layout(tokens * 2, 2, experts) == 2048
+    xs = np.abs(np.random.default_rng(4).normal(size=(tokens, D))
+                ).astype('float32') + 0.1
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        entries = []
+        for skewed in (False, True):
+            if skewed:
+                router = np.zeros((D, experts), 'float32')
+                router[:, 6], router[:, 7] = 4.0, 3.0
+                fluid.global_scope().find_var('moe_mlp_0.w_0').get_tensor(
+                    ).set(router, fluid.CPUPlace())
+            got, counts = exe.run(main, feed={'x': xs},
+                                  fetch_list=[loss, count])
+            assert np.isfinite(got).all()
+            entry, = _step_records()[-1]['fields']['device']
+            assert entry['rows'] == counts[6] + counts[7]
+            assert entry['cap'] == 2048 and entry['expected'] == 512
+            entries.append(entry)
+    assert 0 < entries[0]['rows'] <= 2048 and entries[0]['way'] == 'compact'
+    assert entries[1]['rows'] == 4096 and entries[1]['way'] == 'blocks'
+    # the tool: no layer-step here is `static` (`cap` None), so the one
+    # over its layout is counted as over the slack
+    sys.path.insert(0, os.path.join(REPO, 'tools'))
+    import held_share
+    line = held_share.summary([[e] for e in entries], 2, experts)
+    assert line['layers_over_slack'] == 1 and line['compact_share'] == 0.5
+    assert line['layer_over_expected_max_by_step'][1] == 8.0
 
 
 def test_the_way_read_is_the_way_taken_beyond_the_slack(tmp_path,

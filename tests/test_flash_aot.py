@@ -173,3 +173,47 @@ def test_windowed_band_compiles_for_v5e(one_chip, dtype, precision):
     assert after['band'] - before['band'] == 3 * 252
     assert after['triangle'] == before['triangle']
     assert after['rect'] == before['rect']
+
+
+@pytest.mark.parametrize('cell,tokens,k,width,hidden,cap,dtype', [
+    ('smallthinker_s16384', 16384, 6, 2560, 768, 49152, 'bfloat16'),
+    ('glm47flash_s8192', 8192, 4, 2048, 1536, 16384, 'float32'),
+], ids=['smallthinker', 'glm47flash_float32'])
+def test_an_eighth_held_compiles_both_paths_for_v5e(
+        one_chip, cell, tokens, k, width, hidden, cap, dtype):
+    """A held expert layer of the two 8-of-64 cells from its keys on
+    (`_held_paths`: the conditional, the layout of half the rows and the
+    blocks behind it), forward and backward at the cell's shapes, in one
+    cell's bf16 and in the other's float32 check's arithmetic: the
+    layout's size is the rule's, both paths take the Mosaic grouped
+    matmuls (3 forward, 3 again, 3 + 3 backward, a path), and the
+    compact path sorts one operand (`_argsort`: the TPU's compiler takes
+    seven times as long over a stable sort of keys beside their
+    positions)."""
+    import re
+    import types
+    from paddle_tpu.fluid.ops_impl import moe_ops
+    assert moe_ops._held_layout(tokens * k, 8, 64) == cap
+    dt = jnp.dtype(dtype)
+
+    def like(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {'w1': like((8, width, hidden), dt),
+              'w3': like((8, width, hidden), dt),
+              'w2': like((8, hidden, width), dt)}
+    ctx = types.SimpleNamespace(platform='tpu')
+
+    def loss(params, x, gate, key, sizes):
+        y = moe_ops._held_paths(ctx, params, x, key, gate, sizes,
+                                jnp.sum(sizes), cap=cap, act='relu')
+        return jnp.sum(y * y)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        params, like((tokens, width), dt), like((tokens, k), jnp.float32),
+        like((tokens, k), jnp.int32), like((8,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 24
+    # the compact path sorts its tokens x k keys as ONE operand (a result
+    # that is an array, not argsort's pair)
+    assert re.search(r'= s32\[%d\]\S* sort\(' % (tokens * k), text)

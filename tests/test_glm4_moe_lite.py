@@ -21,6 +21,7 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu import obs
 from paddle_tpu.fluid import framework, layers, unique_name
 from paddle_tpu.parallel.moe import router_topk
+from util import held_way
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -203,6 +204,82 @@ def test_the_eight_shares_and_the_shared_expert_once_are_the_uncut_layer():
     np.testing.assert_allclose(parts[1] + once, part1, rtol=2e-4, atol=2e-5)
     # wrong rules are far away: no 1.8, gates renormalised over the held
     assert np.abs(sum(parts) / 1.8 + once - want).max() > 0.05
+
+
+@pytest.mark.parametrize('way', ['compact', 'blocks', 'overflow'])
+def test_an_eighth_held_under_the_sigmoid_router_on_either_path(
+        way, monkeypatch):
+    """8 of 64 held, top 4 over 192 tokens under the sigmoid router with
+    its bias and 1.8: 768 rows, 96 expected, a layout of 256 (half the
+    rows in whole tiles), chosen on the device. The router as drawn stays
+    under it and takes the compact path (`compact`: the other gives NaN);
+    the same rows through `_held_blocks` (`blocks`: the compact path is
+    made to call it); a bias that gives the held experts every choice,
+    768 rows, overflows the layout (`overflow`: the compact path gives
+    NaN). Each is the cut plain reference's routed part in value and in
+    every gradient: the input's, the router's, the three stacks'; none
+    reaches the bias."""
+    from paddle_tpu.fluid.ops_impl import moe_ops
+    tokens = 2 * N
+    assert moe_ops._held_layout(tokens * K, HELD, E) == 256
+    rng = np.random.default_rng(5)
+    xs, w = (rng.normal(size=(tokens, D)).astype('float32')
+             for _ in range(2))
+    weights = [rng.normal(size=(D, E)).astype('float32'),
+               rng.normal(size=(HELD, D, H)).astype('float32') * 0.3,
+               rng.normal(size=(HELD, D, H)).astype('float32') * 0.3,
+               rng.normal(size=(HELD, H, D)).astype('float32') * 0.3,
+               rng.normal(size=E).astype('float32') * 0.2]
+    if way == 'overflow':
+        weights[4][8:8 + K] += 4.0
+    held_way(monkeypatch, way)
+    main, startup = framework.Program(), framework.Program()
+    with unique_name.guard(), framework.program_guard(main, startup):
+        out, count, _ = layers.moe_mlp(
+            layers.create_parameter([tokens, D], 'float32', name='px'),
+            num_experts=E, hidden_size=H, act='swish', gated=True, top_k=K,
+            norm_topk_prob=True, capacity_factor=None, bias_attr=False,
+            return_expert_count=True, experts_held=(8, HELD),
+            scoring='sigmoid', selection_bias=True, gate_scale=1.8)
+        loss = layers.reduce_sum(layers.elementwise_mul(out, layers.data(
+            name='w', shape=[D], dtype='float32')))
+        grads = dict((p.name, g) for p, g in
+                     fluid.backward.append_backward(loss))
+    names = ['px'] + ['moe_mlp_0.w_%d' % i for i in range(4)]
+    assert sorted(grads) == sorted(names)         # none for the bias
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        scope, place = fluid.global_scope(), fluid.CPUPlace()
+        for name, value in zip(names + ['moe_mlp_0.w_4'], [xs] + weights):
+            scope.find_var(name).get_tensor().set(value, place)
+        got = exe.run(main, feed={'w': w},
+                      fetch_list=[out, count] + [grads[n] for n in names])
+    live = got[1][8:8 + HELD].sum()
+    assert live == tokens * K if way == 'overflow' else 0 < live <= 256
+
+    reference = reference_module()
+    model = {'num_experts_per_tok': K, 'norm_topk_prob': True,
+             'routed_scaling_factor': 1.8, 'first_expert_held': 8}
+    none = [np.zeros(s, 'float32') for s in ((D, H), (D, H), (H, D))]
+
+    def part(x, router, w_gate, w_up, w_down):
+        y = reference.experts(
+            {'router': router, 'experts_in': [w_gate, w_up],
+             'experts_down': w_down, 'bias': weights[4], 'shared': none},
+            x[None], model)[0]
+        return jnp.sum(y * w), y
+
+    with jax.default_matmul_precision('highest'):
+        want, y = jax.grad(part, argnums=range(5), has_aux=True)(
+            jnp.asarray(xs), *weights[:4])
+    np.testing.assert_allclose(got[0], y, rtol=2e-4, atol=2e-5)
+    assert np.abs(got[0]).max() > 0.1
+    for name, a, b in zip(names, got[2:], want):
+        assert np.abs(b).max() > 0, name
+        np.testing.assert_allclose(a, b, rtol=1e-3,
+                                   atol=1e-4 * np.abs(b).max(),
+                                   err_msg=name)
 
 
 # ---------------------------------------------------------- latent attention

@@ -21,6 +21,7 @@ from paddle_tpu import obs
 from paddle_tpu.fluid import framework, layers, unique_name
 from paddle_tpu.fluid.ops_impl import linear_attention_ops as la
 from paddle_tpu.fluid.ops_impl import moe_ops
+from util import nan_path
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -393,18 +394,54 @@ def test_the_shares_and_the_shared_expert_once_are_the_uncut_layer(tokens):
                                rtol=2e-4, atol=2e-6)
 
 
-def _nan_path(params, x, *_):
-    """In place of the path a test expects the device NOT to take."""
-    return jnp.full((x.shape[0], params['w2'].shape[-1]), jnp.nan,
-                    jnp.float32)
-
-
 def _forced_router(order):
     """Router weights that send a token of x > 0 to `order`, in order."""
     router = np.zeros((D, E), 'float32')
     for j, e in enumerate(order):
         router[:, e] = 4.0 - j
     return router
+
+
+# tokens x k, held, routed -> rows of the layout (`_held_layout`)
+LAYOUTS = {
+    # an eighth held: ten times the expected rows are more than all of
+    # them, so half the rows, 4 x the expected
+    'smallthinker_s16384': (16384 * 6, 8, 64, 49152),
+    'glm47flash_s8192': (8192 * 4, 8, 64, 16384),
+    # a 32nd held: the slack's ten times, under half the rows
+    'qwen3next_s8192': (8192 * 10, 16, 512, 25600),
+    # half the rows under one 256-row tile: no layout, every row is kept
+    'toy_cells': (160 * 3, 4, 16, None),
+    'one_row_short_of_a_tile': (511, 8, 64, None),
+    'one_tile': (512, 8, 64, 256),
+    # half the rows are rounded DOWN to whole tiles
+    'tiles_round_down': (1534, 8, 64, 512),
+}
+
+
+@pytest.mark.parametrize('case', list(LAYOUTS))
+def test_the_layout_is_the_slack_or_half_the_rows(case):
+    """From shapes alone: `_HELD_SLACK` times the expected held rows, at
+    most half the layer's rows in whole tiles, None under one tile."""
+    rows, count, routed, cap = LAYOUTS[case]
+    assert moe_ops._HELD_SLACK == 10
+    assert moe_ops._held_layout(rows, count, routed) == cap
+    if cap is not None:
+        assert cap % 256 == 0 and 2 * cap <= rows
+        assert cap <= moe_ops._held_cap(rows, count, routed)
+
+
+@pytest.mark.parametrize('bound', [9, 1 << 20], ids=['packed', 'pairs'])
+def test_the_one_operand_sort_is_the_stable_argsort(bound):
+    """`_argsort` packs a key and its position into one int32 where they
+    fit 31 bits and is `jnp.argsort(stable=True)` where they do not: the
+    same order either way, ties in the order of their positions."""
+    keys = np.random.default_rng(0).integers(0, 9, size=3000).astype('int32')
+    span = 1 << (len(keys) - 1).bit_length()
+    assert (bound * span <= 1 << 31) == (bound == 9)
+    np.testing.assert_array_equal(
+        moe_ops._argsort(jnp.asarray(keys), bound),
+        np.argsort(keys, kind='stable'))
 
 
 def test_a_layer_compacts_its_held_rows_or_keeps_them_all(monkeypatch):
@@ -435,7 +472,7 @@ def test_a_layer_compacts_its_held_rows_or_keeps_them_all(monkeypatch):
     names = [name for name, _ in paths]    # each, once a trace of the rule
     assert names.count('compact') == names.count('blocks') > 0
     # ... and it took the compact one: the other gives NaN here
-    monkeypatch.setattr(moe_ops, '_held_blocks', _nan_path)
+    monkeypatch.setattr(moe_ops, '_held_blocks', nan_path)
     (part, _, _), _ = run_share((6, 2), xs, weights)
     np.testing.assert_allclose(part, parts[3], rtol=1e-6, atol=1e-8)
     monkeypatch.setattr(moe_ops, '_held_blocks', blocks)
@@ -445,7 +482,7 @@ def test_a_layer_compacts_its_held_rows_or_keeps_them_all(monkeypatch):
     (rest, _, _), _ = run_share((0, 2), xs, forced)
     # 8192 held rows, twice what the compact path lays out: had the layer
     # taken it (NaN here), half of them would be missing from `part`
-    monkeypatch.setattr(moe_ops, '_compact_moe', _nan_path)
+    monkeypatch.setattr(moe_ops, '_compact_moe', nan_path)
     (part, _, _), _ = run_share((6, 2), xs, forced)
     assert count[6] == count[7] == 4096
     np.testing.assert_allclose(part + rest, whole, rtol=2e-5, atol=2e-7)
@@ -527,6 +564,16 @@ BOUNDARY = {
     # token's rows are added, its gradient is the sum
     'two_and_k_held_slots': ((4, 4), 2, {'all_k': 100, 'two': 200,
                                          'one': 50, 'none': 674}, 'compact'),
+    # an EIGHTH held at the slack the module has: the layout is half the
+    # 4096 rows, 4 x the expected 512. 512 x 4 = 2048 held rows = cap
+    'eighth_live_is_cap': ((4, 4), 10, {'all_k': 512, 'none': 512},
+                           'compact'),
+    'eighth_live_is_cap_plus_one': ((4, 4), 10, {'all_k': 512, 'one': 1,
+                                                 'none': 511}, 'blocks'),
+    # one row under: 511 x 4 + 2 + 1 = 2047
+    'eighth_live_is_cap_less_one': ((4, 4), 10, {'all_k': 511, 'two': 1,
+                                                 'one': 1, 'none': 511},
+                                    'compact'),
 }
 
 
@@ -555,15 +602,17 @@ def test_the_boundary_of_the_layout_and_tokens_with_many_held_slots(
     held, slack, kinds, path = BOUNDARY[case]
     tokens = sum(kinds.values())
     monkeypatch.setattr(moe_ops, '_HELD_SLACK', slack)
-    cap = -(-slack * tokens * K * held[1] // E // 256) * 256
+    cap = min(-(-slack * tokens * K * held[1] // E // 256) * 256,
+              tokens * K // 2 // 256 * 256)
     live = sum(n * len([e for e in KINDS[kind]
                         if held[0] <= e < held[0] + held[1]])
                for kind, n in kinds.items())
     assert (live <= cap) == (path == 'compact') and 2 * cap <= tokens * K
-    if case.startswith('live_is_cap'):
-        assert live - cap == (path == 'blocks')
+    if 'live_is_cap' in case:
+        assert live - cap == {'': 0, '_plus_one': 1, '_less_one': -1}[
+            case.split('live_is_cap')[1]]
     monkeypatch.setattr(moe_ops, '_held_blocks' if path == 'compact'
-                        else '_compact_moe', _nan_path)
+                        else '_compact_moe', nan_path)
     rng = np.random.default_rng(7)
     # a token's kind is one of its first features; the router reads those
     kind_of = rng.permutation(np.repeat(np.arange(len(kinds)),

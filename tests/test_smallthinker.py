@@ -8,6 +8,7 @@ configuration's file, its FLOPs and its readers. Small sizes, on the
 CPU."""
 import json
 import os
+import re
 import sys
 
 import numpy as np
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 import paddle_tpu.fluid as fluid
 from paddle_tpu import obs
 from paddle_tpu.fluid import framework, layers, unique_name
+from util import held_way
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -200,6 +202,132 @@ def test_the_eight_shares_are_the_uncut_references_layer():
     # the router on the experts' tensor is another layer
     assert np.abs(run_layer(None, False, feeds, weights)[0] - want).max() \
         > 0.05
+
+
+@pytest.mark.parametrize('way', ['compact', 'blocks', 'overflow'])
+def test_an_eighth_held_lays_out_half_its_rows_or_keeps_them_all(
+        way, monkeypatch):
+    """8 of 64 held, top 6 over 96 tokens: 576 rows, 72 expected, a layout
+    of 256 (half the rows in whole tiles), chosen on the device. The
+    router as drawn stays under it and takes the compact path (`compact`:
+    the other path gives NaN); the same rows through `_held_blocks`
+    (`blocks`: the compact path is made to call it) and a router forced
+    onto the held experts, 576 rows, which overflows the layout
+    (`overflow`: the compact path gives NaN) are each the cut plain
+    reference's part in value and in every gradient: the experts' tensor,
+    the router's own, the router's weight, the three stacks."""
+    from paddle_tpu.fluid.ops_impl import moe_ops
+    assert moe_ops._held_layout(N * K, HELD, E) == 256
+    feeds, weights = _layer_data(3)
+    if way == 'overflow':
+        feeds['r'] = np.abs(feeds['r']) + 0.1
+        weights[0] = np.zeros((D, E), 'float32')
+        weights[0][:, 8:8 + K] = 1.0 + np.arange(K)[::-1] / K
+    held_way(monkeypatch, way)
+    out, aux, count, grads = run_layer((8, HELD), True, feeds, weights)
+    live = count[8:8 + HELD].sum()
+    assert live == N * K if way == 'overflow' else 0 < live <= 256
+
+    reference = reference_module()
+    model = {'moe_num_active_primary_experts': K, 'first_expert_held': 8}
+
+    def loss(g, m, router, w_gate, w_up, w_down):
+        y, aux = reference.experts(
+            {'router': router, 'experts_in': [w_gate, w_up],
+             'experts_down': w_down}, g[None], m[None], model)
+        return jnp.sum(y[0] * feeds['w']) + aux, y[0]
+
+    cut = [weights[0]] + [w[8:8 + HELD] for w in weights[1:]]
+    with jax.default_matmul_precision('highest'):
+        want, y = jax.grad(loss, argnums=range(6), has_aux=True)(
+            jnp.asarray(feeds['r']), jnp.asarray(feeds['x']), *cut)
+    np.testing.assert_allclose(out, y, rtol=2e-4, atol=2e-5)
+    assert np.abs(out).max() > 0.1
+    names = ['pr', 'px'] + ['moe_mlp_0.w_%d' % i for i in range(4)]
+    for name, b in zip(names, want):
+        assert np.abs(b).max() > 0, name
+        np.testing.assert_allclose(grads[name], b, rtol=1e-3,
+                                   atol=1e-4 * np.abs(b).max(),
+                                   err_msg=name)
+
+
+def test_equal_expert_layers_trace_one_body_a_program(monkeypatch):
+    """Three held expert layers of equal shapes, one wider and one of the
+    first shape under a `fluid.name_scope`: a training Program runs the
+    layer's Python body (`_held_paths`: the conditional and both paths)
+    once a shape and name scope, not once a layer, the lowered module
+    holds one function for each and a pass (forward, backward) and calls
+    it from each layer; the next Program traces its own (a path patched
+    between two builds is never answered from the build before). The
+    name scope keeps its own: XLA gives the shared branches of a
+    conditional ONE caller's op_name, and a reader by name scope
+    (`mtp_ms`) must not be handed the other layers' time."""
+    from paddle_tpu.fluid.ops_impl import moe_ops
+    traced = []
+    paths = moe_ops._held_paths
+
+    def counting(ctx, params, *a, **static):
+        traced.append(params['w1'].shape[-1])
+        return paths(ctx, params, *a, **static)
+
+    counting.__name__ = '_held_paths'
+    monkeypatch.setattr(moe_ops, '_held_paths', counting)
+    main, startup = framework.Program(), framework.Program()
+    with unique_name.guard(), framework.program_guard(main, startup):
+        x = layers.create_parameter([N, D], 'float32', name='px')
+        def block(x, hidden):
+            return x + layers.moe_mlp(
+                x, num_experts=E, hidden_size=hidden, act='relu', gated=True,
+                top_k=K, norm_topk_prob=True, capacity_factor=None,
+                bias_attr=False, experts_held=(8, HELD))
+
+        for hidden in (H, H, 2 * H, H):
+            x = block(x, hidden)
+        with fluid.name_scope('mtp'):
+            x = block(x, H)
+        loss = layers.reduce_sum(x)
+        grad = dict((p.name, g) for p, g in
+                    fluid.backward.append_backward(loss))['px']
+    assert sorted(traced) == [H] * 4 + [2 * H]   # shape inference, a layer
+    for again in (1, 2):
+        del traced[:]
+        with fluid.scope_guard(fluid.Scope()):
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(startup)
+            got = exe.run(main, fetch_list=[loss, grad])
+            assert all(np.isfinite(g).all() for g in got)
+            assert sorted(traced) == [H, H, 2 * H]
+            text = exe.lowered_hlo(main, {}, [loss, grad])
+        assert sorted(traced) == [H, H, 2 * H]
+        bodies = re.findall(r'func\.func private @(_held_paths\w*)', text)
+        calls = re.findall(r'call @(_held_paths\w*)', text)
+        # a body's forward and its backward; each called once a layer
+        assert len(bodies) == len(set(bodies)) == 6
+        assert sorted(calls.count(b) for b in bodies) == [1, 1, 1, 1, 3, 3]
+
+
+def test_the_toy_cells_expert_layers_share_a_body_across_their_regions():
+    """The cell's training step at the toy widths: four expert layers,
+    each in a recompute region of its own, call the same two or three
+    functions (the body's forward, its backward, and the rows again where
+    they are not part of it), four times each. The
+    regions hand jax one policy object (`step_artifact._REGION_KEEPS`): a
+    policy made anew a region splits the body anew a region, and every
+    layer gets functions of its own."""
+    cell = _toy_cell()
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        built = cell['builder'].build(cell['config'], cell['traffic'])
+        exe.run(built['startup'])
+        pool, _ = cell['generator'].make_pool(cell['traffic'],
+                                              cell['config'], 3)
+        assert len(exe._prepare(built['main'], pool[0], [built['loss']],
+                                fluid.global_scope())[0].regions) == 4
+        text = exe.lowered_hlo(built['main'], pool[0], [built['loss']])
+    bodies = re.findall(r'func\.func private @(_held_paths\w*)', text)
+    calls = re.findall(r'call @(_held_paths\w*)', text)
+    assert 2 <= len(bodies) == len(set(bodies)) <= 3
+    assert [calls.count(b) for b in bodies] == [4] * len(bodies)
 
 
 # ------------------------------------------------------------------ the model

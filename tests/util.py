@@ -1,5 +1,8 @@
-"""Shared test helpers: fresh programs per test."""
+"""Shared test helpers: fresh programs per test, a held expert layer's
+paths forced or poisoned."""
 import contextlib
+
+import jax.numpy as jnp
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import framework, unique_name
@@ -19,3 +22,28 @@ def fresh_program():
                 yield main, startup
             finally:
                 _switch_scope(prev_scope)
+
+
+def nan_path(params, x, *_):
+    """In place of the path of a held expert layer (ops_impl/moe_ops.py
+    `_compact_moe`, `_held_blocks`) that a test expects the device NOT to
+    take."""
+    return jnp.full((x.shape[0], params['w2'].shape[-1]), jnp.nan,
+                    jnp.float32)
+
+
+def held_way(monkeypatch, way):
+    """A held expert layer under its layout takes the compact path with
+    the blocks poisoned (`compact`) or `_held_blocks` in the compact
+    path's place (`blocks`); one over its layout takes the blocks with
+    the compact path poisoned (`overflow`)."""
+    from paddle_tpu.fluid.ops_impl import moe_ops
+    blocks = moe_ops._held_blocks
+    if way == 'blocks':
+        monkeypatch.setattr(
+            moe_ops, '_compact_moe',
+            lambda p, x, key, gate, sizes, cap, act, ctx:
+            blocks(p, x, key, gate, act, ctx))
+    else:
+        monkeypatch.setattr(moe_ops, '_held_blocks' if way == 'compact'
+                            else '_compact_moe', nan_path)

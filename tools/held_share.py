@@ -12,12 +12,13 @@ share, held / routed (flops/qwen3_next.py `held_rows`); this prints what
 the seeded traffic really sent, layer by layer, over a few training steps
 (Adam moves the router, so the share drifts from its first value). The
 LAYER is the unit that chooses the path (ops_impl/moe_ops.py `_held_moe`):
-a layer whose held rows fit `_HELD_SLACK` times the expected number lays
-out those rows alone, by index (the compact path); beyond that it keeps
-every row. So the line also gives each layer-step's held rows over the
-expected number (`layer_over_expected_*`), how many layer-steps went over
-the slack (`layers_over_slack`) and the share that stayed on the compact
-path (`compact_share`). All of it is read from `fields['device']` of the
+a layer whose held rows fit its layout (`_HELD_SLACK` times the expected
+number, at most half the layer's rows: `_held_layout`) lays out those
+rows alone, by index (the compact path); beyond that it keeps every row.
+So the line also gives each layer-step's held rows over the expected
+number (`layer_over_expected_*`), how many layer-steps went over their
+layout (`layers_over_slack`) and the share that stayed on the compact path
+(`compact_share`). All of it is read from `fields['device']` of the
 program's `executor.step` records (docs/observability.md): the rows the
 step counted on the device and the `expected`, `cap` and `way` its rule
 fixed, so "over the slack" has one definition, the rule's. Runs wherever
@@ -31,8 +32,39 @@ import os
 import sys
 import tempfile
 
+import numpy as np
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
+
+
+def summary(steps, count, routed):
+    """What the line says of `steps`, a list a step of the `device`
+    entries of the held ops in op order."""
+    def of(key):                                       # [steps, layers]
+        return np.asarray([[e[key] for e in entries] for entries in steps])
+
+    over = of('rows') / of('expected').astype(float)
+    shares = over * count / routed
+    compact = of('way') == 'compact'
+    static = np.asarray([[e['cap'] is None for e in entries]
+                         for entries in steps])
+    return {
+        'expected_share': count / routed,
+        'measured_share_mean': float(shares.mean()),
+        'measured_share_min': float(shares.min()),
+        'measured_share_max': float(shares.max()),
+        'first_step_by_layer': shares[0].tolist(),
+        'last_step_by_layer': shares[-1].tolist(),
+        'layer_over_expected_max_by_layer': over.max(axis=0).tolist(),
+        'layer_over_expected_p50': float(np.median(over)),
+        'layer_over_expected_max_by_step': [round(float(b), 2)
+                                            for b in over.max(axis=1)],
+        # a layer under one tile of rows has no layout (`cap` None): it
+        # keeps every row whatever the router does, and is not over
+        'layers_over_slack': int((~compact & ~static).sum()),
+        'compact_share': float(compact.mean()),
+        'layer_steps': int(over.size)}
 
 
 def main(argv=None):
@@ -43,7 +75,6 @@ def main(argv=None):
     p.add_argument('--toy', action='store_true')
     args = p.parse_args(argv)
 
-    import numpy as np
     import paddle_tpu.fluid as fluid
     from chipbench.harness import catalog
     overrides = None
@@ -75,34 +106,10 @@ def main(argv=None):
              if r['name'] == 'executor.step' and r['fields'].get('device')]
     steps = steps[-args.steps:]
 
-    def of(key):                                       # [steps, layers]
-        return np.asarray([[e[key] for e in entries] for entries in steps])
-
-    held_rows, expected = of('rows'), of('expected').astype(float)
-    over = held_rows / expected
-    shares = over * count / routed
-    compact = of('way') == 'compact'
-    static = np.asarray([[e['cap'] is None for e in entries]
-                         for entries in steps])
     print(json.dumps({
         'workload': args.workload, 'seed': args.seed, 'steps': args.steps,
         'held': [first, count], 'routed': routed,
-        'expected_share': count / routed,
-        'measured_share_mean': float(shares.mean()),
-        'measured_share_min': float(shares.min()),
-        'measured_share_max': float(shares.max()),
-        'first_step_by_layer': shares[0].tolist(),
-        'last_step_by_layer': shares[-1].tolist(),
-        'layer_over_expected_max_by_layer': over.max(axis=0).tolist(),
-        'layer_over_expected_p50': float(np.median(over)),
-        'layer_over_expected_max_by_step': [round(float(b), 2)
-                                            for b in over.max(axis=1)],
-        'slack': moe_ops._HELD_SLACK,
-        # a layout of half the rows or more is not built at all: such a
-        # layer keeps every row whatever the router does, and is not over
-        'layers_over_slack': int((~compact & ~static).sum()),
-        'compact_share': float(compact.mean()),
-        'layer_steps': int(over.size)}))
+        **summary(steps, count, routed), 'slack': moe_ops._HELD_SLACK}))
     return 0
 
 
