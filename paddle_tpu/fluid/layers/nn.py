@@ -33,7 +33,7 @@ __all__ = [
     'random_crop', 'mean_iou', 'relu', 'log', 'crop', 'rank_loss', 'prelu',
     'flatten', 'sequence_mask', 'stack', 'fused_attention', 'rms_norm',
     'rotary_embedding', 'gated_delta_rule', 'causal_conv1d',
-    'gated_rms_norm',
+    'gated_rms_norm', 'ssd_scan',
 ]
 
 
@@ -677,12 +677,22 @@ def rms_norm(input, epsilon=1e-05, param_attr=None, name=None):
     return out
 
 
-def gated_rms_norm(input, gate, epsilon=1e-05, param_attr=None, name=None):
+def gated_rms_norm(input, gate, epsilon=1e-05, param_attr=None, name=None,
+                   norm_before_gate=True, groups=1):
     """RMS norm over the last axis times a SiLU gate of the same shape:
     ``scale * x * rsqrt(mean(x^2) + epsilon) * silu(gate)``, `scale` as
     layers.rms_norm's. One Program op whose backward keeps `input` and
     `gate` and recomputes the rest (layers.rms_norm, layers.swish and a
-    multiply keep three more arrays of the same size). TPU extension."""
+    multiply keep three more arrays of the same size). TPU extension.
+
+    ``norm_before_gate=False`` gates FIRST and normalises the product
+    (Mamba-2): ``scale * u * rsqrt(mean(u^2) + epsilon)`` with ``u = x *
+    silu(gate)``. ``groups`` G > 1 takes the mean of squares over each of
+    G equal parts of the last axis by itself (`scale` stays one weight an
+    element of the whole axis)."""
+    if int(groups) < 1 or int(input.shape[-1]) % int(groups):
+        raise ValueError('gated_rms_norm: %r groups do not divide the last '
+                         'axis of %r' % (groups, int(input.shape[-1])))
     helper = LayerHelper('gated_rms_norm', **locals())
     dtype = helper.input_dtype()
     scale = helper.create_parameter(attr=helper.param_attr,
@@ -690,11 +700,16 @@ def gated_rms_norm(input, gate, epsilon=1e-05, param_attr=None, name=None):
                                     dtype=dtype,
                                     default_initializer=Constant(1.0))
     out = helper.create_variable_for_type_inference(dtype)
+    # the mode is written only where it departs from the op as it was
+    attrs = {'epsilon': float(epsilon)}
+    if not norm_before_gate:
+        attrs['norm_before_gate'] = False
+    if int(groups) > 1:
+        attrs['groups'] = int(groups)
     helper.append_op(type='gated_rms_norm',
                      inputs={'X': [input], 'Gate': [gate],
                              'Scale': [scale]},
-                     outputs={'Y': [out]},
-                     attrs={'epsilon': float(epsilon)})
+                     outputs={'Y': [out]}, attrs=attrs)
     return out
 
 
@@ -759,12 +774,47 @@ def gated_delta_rule(q, k, v, g, beta, chunk_size=64, scale=None,
     return out
 
 
-def causal_conv1d(input, kernel_size, act=None, param_attr=None, name=None):
+def ssd_scan(x, dt, a, b, c, d=None, chunk_size=128, name=None):
+    """Mamba-2's selective state-space scan (SSD: Dao and Gu 2024,
+    arXiv:2405.21060) in ONE op. Per head h a [P, N] float32 state S, zero
+    at a row's first token, and for each token t
+
+        S = exp(dt_t a_h) S + dt_t x_t B_t^T;   y_t = S C_t + d_h x_t.
+
+    x: [B, T, H, P]; dt (the step, > 0: the model's softplus): [B, T, H];
+    a (< 0, a scalar decay a head) and the optional skip d: [H]; b, c:
+    [B, T, G, N] with G dividing H (group g serves heads g * H/G and
+    following). Returns y [B, T, H, P].
+
+    Computed in chunks of `chunk_size` tokens (T need not be a multiple):
+    matmuls inside a chunk, a scan carrying S in float32 across chunks,
+    its own backward that keeps the op's inputs alone
+    (ops_impl/linear_attention_ops.py). No state enters or leaves the op:
+    a row is one stream, with no reset between packed documents. TPU
+    extension (the reference predates it)."""
+    if int(x.shape[2]) % int(b.shape[2]) or tuple(b.shape) != tuple(c.shape):
+        raise ValueError('ssd_scan: %r groups of b %r and c %r do not '
+                         'divide %r heads' % (b.shape[2], tuple(b.shape),
+                                              tuple(c.shape), x.shape[2]))
+    helper = LayerHelper('ssd_scan', **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    inputs = {'X': [x], 'Dt': [dt], 'A': [a], 'B': [b], 'C': [c]}
+    if d is not None:
+        inputs['D'] = [d]
+    helper.append_op(type='ssd_scan', inputs=inputs, outputs={'Out': [out]},
+                     attrs={'chunk_size': int(chunk_size)})
+    return out
+
+
+def causal_conv1d(input, kernel_size, act=None, param_attr=None, name=None,
+                  bias_attr=False):
     """Depthwise causal convolution along the time axis of ``input``
-    [B, T, C]: ``y[t] = act(sum_j w[j] * x[t - (kernel_size - 1) + j])``
-    per channel, zeros before the first token (left padding), no bias.
-    The filter is a parameter [kernel_size, C]; `act` is None or 'silu'.
-    One Program op. TPU extension (the reference's sequence_conv mixes
+    [B, T, C]: ``y[t] = act(sum_j w[j] * x[t - (kernel_size - 1) + j] +
+    bias)`` per channel, zeros before the first token (left padding).
+    The filter is a parameter [kernel_size, C]; `act` is None or 'silu';
+    ``bias_attr`` False (the default) is no bias, anything else a
+    parameter [C] from 0 (a ParamAttr names or initialises it). One
+    Program op. TPU extension (the reference's sequence_conv mixes
     channels and looks both ways)."""
     if act not in (None, 'silu', 'swish'):
         raise ValueError("causal_conv1d act=%r: None or 'silu'" % (act,))
@@ -773,9 +823,13 @@ def causal_conv1d(input, kernel_size, act=None, param_attr=None, name=None):
     w = helper.create_parameter(
         attr=helper.param_attr,
         shape=[int(kernel_size), int(input.shape[-1])], dtype=dtype)
+    inputs = {'X': [input], 'Filter': [w]}
+    if bias_attr is not False:
+        inputs['Bias'] = [helper.create_parameter(
+            attr=helper.bias_attr, shape=[int(input.shape[-1])],
+            dtype=dtype, is_bias=True)]
     out = helper.create_variable_for_type_inference(dtype)
-    helper.append_op(type='causal_conv1d',
-                     inputs={'X': [input], 'Filter': [w]},
+    helper.append_op(type='causal_conv1d', inputs=inputs,
                      outputs={'Out': [out]}, attrs={'act': act or ''})
     return out
 

@@ -1,5 +1,6 @@
-"""Linear attention with a recurrent state: the gated delta rule, the
-depthwise causal convolution that feeds it and the gated norm after it.
+"""Token mixers with a recurrent state: the gated delta rule, Mamba-2's
+selective state-space scan, the depthwise causal convolution that feeds
+either and the gated norm after them.
 
 TPU-first extension (no reference counterpart: the reference's recurrent
 layers, operators/lstm_op.cc and gru_op.cc, carry a vector a step; this
@@ -56,9 +57,44 @@ of the two stages takes bf16 operands with float32 accumulation; the
 norm of q and k, g, beta, the decays, the solve and the state are
 float32. The output is float32.
 
-`causal_conv1d`: y[b, t, c] = act(sum_j w[j, c] x[b, t - (K - 1) + j, c]),
-x = 0 before the row's first token; depthwise (a filter a channel), no
-bias. Float32 elementwise work (K shifted multiply-adds), never the MXU.
+`ssd_scan` is Mamba-2's selective state-space scan (SSD: Dao and Gu 2024,
+arXiv:2405.21060). Per head h of group g = h // (H / G), with S a [P, N]
+float32 state, S_0 = 0, for t = 1..T:
+
+    S   = exp(dt_t A_h) S + dt_t x_t B_t^T        A_h < 0, dt_t > 0
+    y_t = S C_t + D_h x_t                         B_t, C_t the group's
+
+ONE implementation, whatever the platform: the chunked form. A row is cut
+into chunks of C (`chunk_size`, padded with tokens of dt = 0, which decay
+nothing and write nothing). With L the running sum of dt A inside a chunk
+(only differences L_i - L_j, i >= j, and L_C - L_j are exponentiated:
+every factor is at most 1):
+
+  stage `ssd_intra`, every chunk at once:
+    M_ij = (C_i . B_j) exp(L_i - L_j) dt_j for i >= j, 0 above   [C, C]
+    Y    = M X                                the chunk's own tokens
+    dS   = (diag(exp(L_C - L) dt) X)^T B      what the chunk writes
+  stage `ssd_scan`, a lax.scan over the chunks carrying S in float32:
+    S_start of the next chunk = exp(L_C) S_start + dS
+  stage `ssd_inter`, every chunk at once:
+    Y   += diag(exp L) C S_start              what the earlier chunks left
+
+C . B is a group's and is computed once for its H / G heads; the decay is
+a head's. The backward is the op's own (`jax.custom_vjp`) and keeps the
+op's inputs alone: it runs the three stages again behind one
+`optimization_barrier` and transposes them (jax.vjp of the function the
+forward runs, so the two cannot drift); S at the chunks' starts (a
+[T / C, B, H, P, N] float32 array) lives only inside a pass. Under AMP
+the rule is one of the MXU's: x, B, C are cast to bf16 (they are what the
+backward keeps) and the matmuls of `ssd_intra` and `ssd_inter` take bf16
+operands (M, the weighted X and S_start rounded once) with float32
+accumulation; dt, A, D, every decay and the carried state are float32.
+The output is float32.
+
+`causal_conv1d`: y[b, t, c] = act(sum_j w[j, c] x[b, t - (K - 1) + j, c]
++ bias[c]), x = 0 before the row's first token; depthwise (a filter a
+channel), the bias optional (input `Bias`). Float32 elementwise work (K
+shifted multiply-adds), never the MXU.
 Under AMP it reads its input rounded to bf16 and gives its result in
 bf16, as attention does: the input is what its backward keeps, and a
 [B, T, C] float32 array twice a layer is what the cell cannot hold.
@@ -74,11 +110,16 @@ rule's stage.
 
 `gated_rms_norm`: y = w * x * rsqrt(mean(x^2) + eps) * silu(gate) over the
 last axis, statistics in float32; its backward keeps x and the gate
-(bf16 under AMP) and computes the rest again.
+(bf16 under AMP) and computes the rest again. Two attributes give
+Mamba-2's form: `norm_before_gate` false gates FIRST,
+y = w * rmsnorm(x * silu(gate)), and `groups` G takes the mean over each
+of G equal parts of the last axis by itself.
 
 Trace-time counters: `gdn.lowered{chunk=}` once per op per trace,
 `gdn.intra{way=kernel|composed}` beside it (which way stage `gdn_intra`
-went), `gdn.tokens` the B x T of the traced shape, `conv1d.lowered` and
+went), `gdn.tokens` the B x T of the traced shape,
+`ssd.lowered{chunk=, heads=, groups=}` and `ssd.tokens` likewise,
+`conv1d.lowered` (with the label `bias=true` where the op has one) and
 `conv1d.way{way=kernel|composed}` beside it, `gated_rms_norm.lowered`.
 """
 import functools
@@ -343,44 +384,137 @@ def _gated_delta_rule(ins, attrs, ctx):
     return {'Out': o}
 
 
+def _ssd_stages(x, dt, a, b, c, chunk):
+    """The three stages on whole chunks. x [B, T, H, P], b, c [B, T, G, N]
+    in the matmuls' dtype, dt [B, T, H] and a [H] float32, T a multiple of
+    `chunk`. Returns y [B, T, H, P] float32 (without the skip D x)."""
+    dtype = x.dtype
+    bsz, t, h, p = x.shape
+    g, n = b.shape[2:]
+    r, z = h // g, t // chunk
+    x = x.reshape(bsz, z, chunk, g, r, p)
+    b, c = (v.reshape(bsz, z, chunk, g, n) for v in (b, c))
+    # a head's arrays with the chunk's tokens last: [B, Z, G, R, C]
+    dt = jnp.moveaxis(dt.reshape(bsz, z, chunk, g, r), 2, -1)
+    run = jnp.cumsum(dt * a.reshape(g, r, 1), axis=-1)        # L
+    with jax.named_scope('ssd_intra'):
+        lower = np.tril(np.ones((chunk, chunk), bool))
+        decay = jnp.exp(jnp.where(
+            lower, run[..., :, None] - run[..., None, :], -jnp.inf))
+        cb = _mm('bzign,bzjgn->bzgij', c, b, dtype)
+        m = cb[:, :, :, None] * decay * dt[..., None, :]
+        y = _mm('bzgrij,bzjgrp->bzigrp', m, x, dtype)
+        last = run[..., -1:]
+        write = jnp.moveaxis(jnp.exp(last - run) * dt, -1, 2)  # [B,Z,C,G,R]
+        ds = _mm('bzcgrp,bzcgn->zbgrpn',
+                 x.astype(jnp.float32) * write[..., None], b, dtype)
+        keep = jnp.moveaxis(jnp.exp(last[..., 0]), 1, 0)       # [Z,B,G,R]
+    with jax.named_scope('ssd_scan'):
+        # S at each chunk's start; elementwise, the state in float32
+        _, starts = lax.scan(
+            lambda s, inp: (s * inp[0][..., None, None] + inp[1], s),
+            jnp.zeros(ds.shape[1:], jnp.float32), (keep, ds))
+    with jax.named_scope('ssd_inter'):
+        read = jnp.moveaxis(jnp.exp(run), -1, 2)               # [B,Z,C,G,R]
+        y = y + read[..., None] * _mm('bzcgn,zbgrpn->bzcgrp', c, starts,
+                                      dtype)
+    return y.reshape(bsz, t, h, p)
+
+
+def _ssd(x, dt, a, b, c, d, chunk):
+    t = x.shape[1]
+    pad = [(0, 0), (0, -t % chunk), (0, 0)]
+    xp, bp, cp = (jnp.pad(v, pad + [(0, 0)]) for v in (x, b, c))
+    dt, a = dt.astype(jnp.float32), a.astype(jnp.float32)
+    y = _ssd_stages(xp, jnp.pad(dt, pad), a, bp, cp, chunk)[:, :t]
+    if d is not None:
+        y = y + d.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+    return y
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _ssd_chunked(x, dt, a, b, c, d, chunk):
+    return _ssd(x, dt, a, b, c, d, chunk)
+
+
+def _ssd_fwd(x, dt, a, b, c, d, chunk):
+    return _ssd(x, dt, a, b, c, d, chunk), (x, dt, a, b, c, d)
+
+
+def _ssd_bwd(chunk, res, dy):
+    res, dy = _recompute_after(res, dy)
+    return jax.vjp(lambda *v: _ssd(*v, chunk), *res)[1](dy)
+
+
+_ssd_chunked.defvjp(_ssd_fwd, _ssd_bwd)
+
+
+def ssd_scan(x, dt, a, b, c, d=None, chunk_size=128):
+    """x [B, T, H, P], b, c [B, T, G, N] (float32, or bf16 for bf16
+    matmuls), dt [B, T, H] (the step, > 0), a [H] (< 0), d [H] or None; G
+    divides H and group g serves heads g * H / G and following. Returns
+    y [B, T, H, P] float32."""
+    return _ssd_chunked(x, dt, a, b, c, d,
+                        _chunk_of(chunk_size, x.shape[1]))
+
+
+@register('ssd_scan')
+def _ssd_scan(ins, attrs, ctx):
+    x, dt, a, b, c = (data_of(ins[s][0]) for s in ('X', 'Dt', 'A', 'B', 'C'))
+    d = data_of(ins['D'][0]) if ins.get('D') else None
+    chunk = int(attrs.get('chunk_size', 128))
+    obs.counter('ssd.lowered', chunk=chunk, heads=int(x.shape[2]),
+                groups=int(b.shape[2])).inc()                # trace time
+    obs.counter('ssd.tokens').inc(int(x.shape[0]) * int(x.shape[1]))
+    x, b, c = amp_cast(ctx, x, b, c)
+    return {'Out': ssd_scan(x, dt, a, b, c, d, chunk_size=chunk)}
+
+
 _CONV_ACTS = {'': lambda x: x, 'silu': jax.nn.silu, 'swish': jax.nn.silu}
 
 
-def _conv(x, w, act):
+def _conv(x, w, act, b=None):
     taps, t = w.shape[0], x.shape[1]
     xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
     wf = w.astype(jnp.float32)
     y = sum(xf[:, j:j + t] * wf[j] for j in range(taps))
+    if b is not None:
+        y = y + b.astype(jnp.float32)
     return _CONV_ACTS[act](y).astype(x.dtype)
 
 
-def _conv_forward(x, w, act, kernel):
+def _conv_forward(x, w, act, kernel, b):
     if kernel:
-        return conv_kernel.causal_conv1d_fwd(x, w, act=act, interpret=False)
-    return _conv(x, w, act)
+        return conv_kernel.causal_conv1d_fwd(x, w, b, act=act,
+                                             interpret=False)
+    return _conv(x, w, act, b)
 
 
 # The backward keeps the input and the filter and computes the sum again:
 # K shifted multiply-adds of a memory-bound op, against a second
 # [B, T, C] float32 array (the sum before its activation) kept a layer.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def causal_conv1d(x, w, act='', kernel=False):
-    """x [B, T, C], w [K, C]: y[t] = act(sum_j w[j] x[t - (K - 1) + j]).
+def causal_conv1d(x, w, act='', kernel=False, b=None):
+    """x [B, T, C], w [K, C], b [C] or None:
+    y[t] = act(sum_j w[j] x[t - (K - 1) + j] + b).
     `kernel`: the Pallas kernels, forward and backward (the rule's choice;
     the caller has asked their `usable`), else `_conv`."""
-    return _conv_forward(x, w, act, kernel)
+    return _conv_forward(x, w, act, kernel, b)
 
 
-def _conv_fwd(x, w, act, kernel):
-    return _conv_forward(x, w, act, kernel), (x, w)
+def _conv_fwd(x, w, act, kernel, b):
+    return _conv_forward(x, w, act, kernel, b), (x, w, b)
 
 
 def _conv_bwd(act, kernel, res, g):
     if kernel:      # the sum again in VMEM: nothing XLA could keep instead
-        return conv_kernel.causal_conv1d_bwd(*res, g, act=act,
-                                             interpret=False)
+        x, w, b = res
+        # (dx, dw) and, with a bias, its gradient
+        return (conv_kernel.causal_conv1d_bwd(x, w, g, b, act=act,
+                                              interpret=False)
+                + (None,))[:3]
     res, g = _recompute_after(res, g)
-    return jax.vjp(lambda x, w: _conv(x, w, act), *res)[1](g)
+    return jax.vjp(lambda x, w, b: _conv(x, w, act, b), *res)[1](g)
 
 
 causal_conv1d.defvjp(_conv_fwd, _conv_bwd)
@@ -388,7 +522,9 @@ causal_conv1d.defvjp(_conv_fwd, _conv_bwd)
 
 @register('causal_conv1d')
 def _causal_conv1d(ins, attrs, ctx):
-    obs.counter('conv1d.lowered').inc()                      # trace time
+    b = data_of(ins['Bias'][0]) if ins.get('Bias') else None
+    obs.counter('conv1d.lowered',                            # trace time
+                **({} if b is None else {'bias': 'true'})).inc()
     x = amp_cast(ctx, data_of(ins['X'][0]))
     w = data_of(ins['Filter'][0])
     # on the TPU, for a shape they take, one Pallas kernel each way
@@ -396,29 +532,37 @@ def _causal_conv1d(ins, attrs, ctx):
         x.shape[1], x.shape[2], w.shape[0], x.dtype)
     obs.counter('conv1d.way',                                # trace time
                 way='kernel' if kernel else 'composed').inc()
-    return {'Out': causal_conv1d(x, w, attrs.get('act') or '', kernel)}
+    return {'Out': causal_conv1d(x, w, attrs.get('act') or '', kernel, b)}
 
 
-def _gated_norm(x, gate, w, eps):
+def _gated_norm(x, gate, w, cfg):
+    eps, norm_first, groups = cfg
     xf = x.astype(jnp.float32)
-    inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
-    return xf * inv * w.astype(jnp.float32) \
-        * jax.nn.silu(gate.astype(jnp.float32))
+    if not norm_first:
+        xf = xf * jax.nn.silu(gate.astype(jnp.float32))
+    parts = xf.reshape(xf.shape[:-1] + (groups, -1)) if groups > 1 else xf
+    inv = lax.rsqrt(jnp.mean(jnp.square(parts), axis=-1, keepdims=True) + eps)
+    y = (parts * inv).reshape(xf.shape) if groups > 1 else xf * inv
+    y = y * w.astype(jnp.float32)
+    return y * jax.nn.silu(gate.astype(jnp.float32)) if norm_first else y
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def gated_rms_norm(x, gate, w, eps):
-    """w * x * rsqrt(mean(x^2) + eps) * silu(gate), float32."""
-    return _gated_norm(x, gate, w, eps)
+def gated_rms_norm(x, gate, w, cfg):
+    """`cfg` = (eps, norm_before_gate, groups), float32:
+    w * x * rsqrt(mean(x^2) + eps) * silu(gate), or with
+    norm_before_gate false w * u * rsqrt(mean(u^2) + eps), u = x *
+    silu(gate); the mean over each of `groups` parts of the last axis."""
+    return _gated_norm(x, gate, w, cfg)
 
 
-def _gated_norm_fwd(x, gate, w, eps):
-    return _gated_norm(x, gate, w, eps), (x, gate, w)
+def _gated_norm_fwd(x, gate, w, cfg):
+    return _gated_norm(x, gate, w, cfg), (x, gate, w)
 
 
-def _gated_norm_bwd(eps, res, g):
+def _gated_norm_bwd(cfg, res, g):
     res, g = _recompute_after(res, g)
-    return jax.vjp(lambda *a: _gated_norm(*a, eps), *res)[1](g)
+    return jax.vjp(lambda *a: _gated_norm(*a, cfg), *res)[1](g)
 
 
 gated_rms_norm.defvjp(_gated_norm_fwd, _gated_norm_bwd)
@@ -430,5 +574,7 @@ def _gated_rms_norm(ins, attrs, ctx):
     obs.counter('gated_rms_norm.lowered').inc()              # trace time
     y = gated_rms_norm(x, amp_cast(ctx, data_of(ins['Gate'][0])),
                        data_of(ins['Scale'][0]),
-                       float(attrs.get('epsilon', 1e-5)))
+                       (float(attrs.get('epsilon', 1e-5)),
+                        bool(attrs.get('norm_before_gate', True)),
+                        int(attrs.get('groups', 1))))
     return {'Y': y.astype(x.dtype)}

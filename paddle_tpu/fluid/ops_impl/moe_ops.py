@@ -8,6 +8,7 @@ expert FFN:
   gate_logits = x @ gate_w                       [N, E], float32
   expert e:  y = act(x @ w1[e] + b1[e]) @ w2[e] + b2[e]
   gated (W3 given):  y = (act(x @ w1[e]) * (x @ w3[e])) @ w2[e]
+  act 'relu2' is relu(h)^2 (Nemotron-H's experts: two matrices, no W3)
 
 The biases are optional inputs. Gates are the softmax probabilities of the
 k chosen experts: raw for k=1 (Switch) and for `norm_topk_prob` false
@@ -100,7 +101,9 @@ counters: `moe.lowered{path=grouped|capacity}` once per op per trace of
 the rule (a lowering, or build-time shape inference; a share adds the
 labels `held=<count>of<num_experts>` and `dispatch=index`, a sigmoid
 router the label `scoring=sigmoid`, a router with an input of its own
-the label `router=own`).
+the label `router=own`, squared-ReLU experts the label `act=relu2`, and
+dropless experts of two matrices without biases the label `gated=false`:
+the form whose grouped matmuls are two a pass).
 
 What a share's step did with its DATA leaves the device as the op's
 device counter (`_held_counter`, lowering.register_device_counter): the
@@ -127,6 +130,7 @@ _ACTS = {
     'tanh': jnp.tanh,
     'sigmoid': jax.nn.sigmoid,
     'swish': jax.nn.silu,
+    'relu2': lambda x: jnp.square(jax.nn.relu(x)),
     None: lambda x: x,
     '': lambda x: x,
 }
@@ -542,6 +546,10 @@ def _moe_mlp(ins, attrs, ctx):
                              '(capacity_factor=None)')
         routed = data_of(ins['RouterX'][0]).reshape(x.shape)
         labels['router'] = 'own'
+    if act == 'relu2':
+        labels['act'] = act
+    if dropless and not set(params) - {'w1', 'w2'}:
+        labels['gated'] = 'false'
     obs.counter('moe.lowered', path='grouped' if dropless else 'capacity',
                 **labels).inc()
 

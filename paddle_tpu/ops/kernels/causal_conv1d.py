@@ -2,7 +2,13 @@
 backward: the K - 1 shifts along T happen in VMEM, on a tile that is
 already there, and each pass moves its arrays once.
 
-    y[b, t, c] = act(sum_j w[j, c] x[b, t - (K - 1) + j, c])    x = 0, t < 0
+    y[b, t, c] = act(sum_j w[j, c] x[b, t - (K - 1) + j, c] + bias[c])
+                                                              x = 0, t < 0
+
+The bias is optional (None: the calls, their operands and their bodies are
+what they were without it); with one, both bodies add it to the float32
+sum before the activation, and the backward sums dpre into a [1, tC] block
+beside dw's.
 
 The arithmetic, its order and its precisions are those of
 fluid/ops_impl/linear_attention_ops.py `_conv`, which stays as the
@@ -137,7 +143,14 @@ def _piece(i, rows):
 # The pieces of a tile are a `lax.fori_loop`, not a Python loop: unrolled,
 # a Program traced 64 pieces of some 200 operations for each dtype, which
 # no compile cache keeps (setup_s; docs/perf.md has both forms' times).
-def _fwd_kernel(x_ref, prev_ref, w_ref, y_ref, *, act, rows):
+def _pre(ws, xs, b_ref):
+    """The float32 sum before the activation."""
+    pre = _weighted(ws, xs)
+    return pre if b_ref is None else pre + b_ref[...]
+
+
+def _fwd_kernel(x_ref, prev_ref, w_ref, *rest, act, rows):
+    b_ref, y_ref = rest if len(rest) == 2 else (None,) + rest
     ws = _taps(w_ref)
     # the eight rows before the tile: the neighbouring tile's last, zeros
     # before a row's first token
@@ -146,7 +159,8 @@ def _fwd_kernel(x_ref, prev_ref, w_ref, y_ref, *, act, rows):
     def piece(i, before):
         x = x_ref[_piece(i, rows)].astype(_F32)
         y_ref[_piece(i, rows)] = _ACTS[act][0](
-            _weighted(ws, _windows(before, x, len(ws)))).astype(y_ref.dtype)
+            _pre(ws, _windows(before, x, len(ws)), b_ref)
+        ).astype(y_ref.dtype)
         return _last(x)
 
     lax.fori_loop(0, x_ref.shape[1] // rows, piece, first)
@@ -160,7 +174,9 @@ def _fold(x):
 
 
 def _bwd_kernel(x_ref, prev_ref, next_ref, g_ref, g_next_ref, w_ref,
-                dx_ref, dw_ref, *, act, rows):
+                *rest, act, rows):
+    b_ref, dx_ref, dw_ref, db_ref = rest if len(rest) == 4 \
+        else (None,) + rest + (None,)
     ws = _taps(w_ref)
     taps, tt = len(ws), x_ref.shape[1]
     edge = prev_ref.shape[1]
@@ -168,11 +184,13 @@ def _bwd_kernel(x_ref, prev_ref, next_ref, g_ref, g_next_ref, w_ref,
 
     def dpre_of(g, xs):
         g = g.astype(_F32)
-        return g if grad is None else g * grad(_weighted(ws, xs))
+        return g if grad is None else g * grad(_pre(ws, xs, b_ref))
 
     @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0))
     def _():
         dw_ref[...] = jnp.zeros_like(dw_ref)
+        if db_ref is not None:
+            db_ref[...] = jnp.zeros_like(db_ref)
 
     first = jnp.where(pl.program_id(2) > 0, _last(prev_ref[0]), 0.0)
     # dpre of the eight rows after the tile: the next tile's first, whose
@@ -195,7 +213,8 @@ def _bwd_kernel(x_ref, prev_ref, next_ref, g_ref, g_next_ref, w_ref,
         before = jnp.where(i > 0, _last(x_ref[0, pl.ds(lo, edge)]), first)
         xs = _windows(before, x, taps)
         dpre = dpre_of(g_ref[_piece(i, rows)], xs)
-        sums = tuple(s + _fold(dpre * xj) for s, xj in zip(sums, xs))
+        sums = tuple(s + _fold(dpre * xj) for s, xj in zip(sums, xs)) \
+            + ((sums[taps] + _fold(dpre),) if db_ref is not None else ())
         # dpre[t + s] over the piece's rows t: the piece over the eight
         # rows after it, rolled up by s
         ext = jnp.concatenate([dpre, head], axis=0)
@@ -205,9 +224,13 @@ def _bwd_kernel(x_ref, prev_ref, next_ref, g_ref, g_next_ref, w_ref,
         return dpre[:_F32_ROWS], sums
 
     zero = jnp.zeros((_F32_ROWS, x_ref.shape[2]), _F32)
-    _, sums = lax.fori_loop(0, pieces, piece, (head, (zero,) * taps))
+    _, sums = lax.fori_loop(
+        0, pieces, piece,
+        (head, (zero,) * (taps + (db_ref is not None))))
     for j in range(taps):
         dw_ref[j:j + 1, :] += jnp.sum(sums[j], axis=0, keepdims=True)
+    if db_ref is not None:
+        db_ref[...] += jnp.sum(sums[taps], axis=0, keepdims=True)
 
 
 def _geometry(x, tile):
@@ -222,12 +245,22 @@ def _geometry(x, tile):
 # kernels: a model has several such ops, each traced for the primal, for
 # its forward rule and in every check Program. jit keeps one trace a shape
 # and emits one function a module, called under each place's scopes.
+def _bias(bias, tc, index):
+    """([the bias as a [1, C] float32 operand], [its block's spec]), or
+    two empty lists where there is none."""
+    if bias is None:
+        return [], []
+    return ([bias.astype(_F32).reshape(1, -1)],
+            [pl.BlockSpec((1, tc), index)])
+
+
 @functools.partial(jax.jit, static_argnames=('act', 'interpret', 'tile'))
-def causal_conv1d_fwd(x, w, *, act, interpret, tile=None):
-    """x [B, T, C], w [K, C] -> y [B, T, C] in x's dtype. `tile`
-    overrides (tT, tC) (the sweep's and the tests' door)."""
+def causal_conv1d_fwd(x, w, bias=None, *, act, interpret, tile=None):
+    """x [B, T, C], w [K, C], bias [C] or None -> y [B, T, C] in x's
+    dtype. `tile` overrides (tT, tC) (the sweep's and the tests' door)."""
     b, _, c = x.shape
     tt, tc, edge, n_t, per, _ = _geometry(x, tile)
+    bias, bias_spec = _bias(bias, tc, lambda b, j, i: (0, j))
     return pl.pallas_call(
         functools.partial(_fwd_kernel, act=act,
                           rows=_rows_of(tt, tc, edge)),
@@ -236,20 +269,24 @@ def causal_conv1d_fwd(x, w, *, act, interpret, tile=None):
             pl.BlockSpec((1, tt, tc), lambda b, j, i: (b, i, j)),
             pl.BlockSpec((1, edge, tc), lambda b, j, i: (
                 b, jnp.maximum(i * per - 1, 0), j)),
-            pl.BlockSpec((w.shape[0], tc), lambda b, j, i: (0, j))],
+            pl.BlockSpec((w.shape[0], tc), lambda b, j, i: (0, j))]
+        + bias_spec,
         out_specs=pl.BlockSpec((1, tt, tc), lambda b, j, i: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret, name='causal_conv1d_fwd',
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'parallel')))(
-        x, x, w.astype(_F32))
+        x, x, w.astype(_F32), *bias)
 
 
 @functools.partial(jax.jit, static_argnames=('act', 'interpret', 'tile'))
-def causal_conv1d_bwd(x, w, g, *, act, interpret, tile=None):
-    """The cotangent g of y -> (dx in x's dtype, dw in w's)."""
+def causal_conv1d_bwd(x, w, g, bias=None, *, act, interpret, tile=None):
+    """The cotangent g of y -> (dx in x's dtype, dw in w's) and, with a
+    bias, its gradient in its dtype as a third."""
     b, _, c = x.shape
     tt, tc, edge, n_t, per, n_edge = _geometry(x, tile)
+    dtype = None if bias is None else bias.dtype
+    bias, bias_spec = _bias(bias, tc, lambda j, b, i: (0, j))
 
     def here(j, b, i):
         return b, i, j
@@ -260,7 +297,7 @@ def causal_conv1d_bwd(x, w, g, *, act, interpret, tile=None):
     def after(j, b, i):
         return b, jnp.minimum((i + 1) * per, n_edge - 1), j
 
-    dx, dw = pl.pallas_call(
+    dx, dw, *db = pl.pallas_call(
         functools.partial(_bwd_kernel, act=act,
                           rows=_rows_of(tt, tc, edge)),
         grid=(c // tc, b, n_t),
@@ -270,14 +307,18 @@ def causal_conv1d_bwd(x, w, g, *, act, interpret, tile=None):
             pl.BlockSpec((1, edge, tc), after),
             pl.BlockSpec((1, tt, tc), here),
             pl.BlockSpec((1, edge, tc), after),
-            pl.BlockSpec((w.shape[0], tc), lambda j, b, i: (0, j))],
+            pl.BlockSpec((w.shape[0], tc), lambda j, b, i: (0, j))]
+        + bias_spec,
         out_specs=[
             pl.BlockSpec((1, tt, tc), here),
-            pl.BlockSpec((w.shape[0], tc), lambda j, b, i: (0, j))],
+            pl.BlockSpec((w.shape[0], tc), lambda j, b, i: (0, j))]
+        + bias_spec,
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct(w.shape, _F32)],
+                   jax.ShapeDtypeStruct(w.shape, _F32)]
+        + [jax.ShapeDtypeStruct((1, c), _F32)] * len(bias),
         interpret=interpret, name='causal_conv1d_bwd',
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary', 'arbitrary')))(
-        x, x, x, g, g, w.astype(_F32))
-    return dx, dw.astype(w.dtype)
+        x, x, x, g, g, w.astype(_F32), *bias)
+    return (dx, dw.astype(w.dtype)) + tuple(
+        v.reshape(-1).astype(dtype) for v in db)

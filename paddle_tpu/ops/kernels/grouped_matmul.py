@@ -46,17 +46,24 @@ def _fit(tiles, m, k, n, itemsize, budget=4 << 20):
     output's in tgmm) halved along its longer side until it is within
     `budget` bytes at 2-byte operands, which is what double-buffered fits
     the 16 MiB of scoped VMEM beside the rows' tiles (4-byte operands: the
-    float32 check of chipbench's olmoe configuration)."""
+    float32 check of chipbench's olmoe configuration). A halved side stays
+    whole lane tiles (a width of 1856 halves to 896, not 928: Mosaic takes
+    a block's last dimension in 128s or whole); the kernels mask what the
+    last tile of a side overhangs."""
     tm, tk, tn = tiles
     while m % tm:
         tm //= 2
     tk, tn = min(tk, k), min(tn, n)
+
+    def half(side):
+        return max(128, side // 2 // 128 * 128)
+
     # 4-byte operands: half the block again (the rows' tiles double too)
     while tk * tn * itemsize > budget // max(1, itemsize // 2):
         if tk >= tn:
-            tk //= 2
+            tk = half(tk)
         else:
-            tn //= 2
+            tn = half(tn)
     return tm, tk, tn
 
 

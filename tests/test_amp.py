@@ -67,9 +67,17 @@ def _delta_rule(x):
         qk_l2norm=True)
 
 
+def _ssd(x):
+    v = fluid.layers.reshape(x, [-1, 16, 2, 8])
+    dt = fluid.layers.sigmoid(fluid.layers.reduce_mean(v, dim=-1))
+    a = fluid.layers.scale(fluid.layers.exp(fluid.layers.create_parameter(
+        [2], 'float32')), scale=-1.0)
+    return fluid.layers.ssd_scan(v, dt, a, v, v, chunk_size=8)
+
+
 # op type -> (feed shape, the layer that appends it to the program, the
 # dtype its output has under AMP): the rules whose matmuls take operands
-# that lowering.amp_cast cast, each built the way a model does. Five give
+# that lowering.amp_cast cast, each built the way a model does. Six give
 # their result back in the dtype they were fed; attention gives it in the
 # dtype of its operands, and the output projection that follows takes it
 # so. (causal_conv1d and gated_rms_norm call amp_cast too, for what their
@@ -85,6 +93,7 @@ _MXU_OPS = {
         x, num_experts=4, hidden_size=16, act='swish', gated=True, top_k=2,
         capacity_factor=None, bias_attr=False), 'float32'),
     'gated_delta_rule': ((2, 16 * 2 * 8), _delta_rule, 'float32'),
+    'ssd_scan': ((2, 16 * 2 * 8), _ssd, 'float32'),
 }
 
 

@@ -67,6 +67,44 @@ def test_kernel_is_the_composition(dtype, act, taps, rows):
         <= 1e-5 * np.linalg.norm(_f32(dw_want))
 
 
+@pytest.mark.parametrize('rows', list(ROWS))
+@pytest.mark.parametrize('act', ['', 'silu'])
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+def test_kernel_with_a_bias_is_the_composition_plus_the_bias(dtype, act,
+                                                             rows):
+    """The optional bias (ISSUE 40: Mamba-2's convolution has one): y, dx,
+    dw and db against `_conv(..., b)` and its `jax.vjp`, at the tolerances
+    of the test above; db, a float32 sum over B x T, to 1e-5 of its norm.
+    Without a bias the backward still gives two values."""
+    dtype = jnp.dtype(dtype)
+    b, t = ROWS[rows]
+    x, w, g = _operands(len(rows) + 7, b, t, 256, 4, dtype)
+    bias = jnp.asarray(np.random.default_rng(1).normal(size=256),
+                       jnp.float32)
+    want, pull = jax.vjp(lambda x, w, b: la._conv(x, w, act, b), x, w, bias)
+    dx_want, dw_want, db_want = pull(g)
+    got = cc.causal_conv1d_fwd(x, w, bias, act=act, interpret=True,
+                               tile=TILE)
+    dx, dw, db = cc.causal_conv1d_bwd(x, w, g, bias, act=act,
+                                      interpret=True, tile=TILE)
+    for name, a, ref in (('y', got, want), ('dx', dx, dx_want)):
+        assert a.dtype == ref.dtype and a.shape == ref.shape, name
+        a, ref = _f32(a), _f32(ref)
+        tol = (1e-5 * np.abs(ref).max() if dtype == jnp.float32
+               else 2 * BF16_ULP * np.abs(ref) + 1e-6)
+        assert np.all(np.abs(a - ref) <= tol), (
+            name, float(np.abs(a - ref).max()))
+    for a, ref in ((dw, dw_want), (db, db_want)):
+        assert a.dtype == ref.dtype and a.shape == ref.shape
+        assert np.linalg.norm(_f32(a) - _f32(ref)) \
+            <= 1e-5 * np.linalg.norm(_f32(ref))
+    # the bias moved the result, and no bias is the kernel it was
+    bare = cc.causal_conv1d_fwd(x, w, act=act, interpret=True, tile=TILE)
+    assert np.abs(_f32(bare) - _f32(got)).max() > 0.1
+    assert len(cc.causal_conv1d_bwd(x, w, g, act=act, interpret=True,
+                                    tile=TILE)) == 2
+
+
 def test_rows_of_a_batch_do_not_see_each_other():
     """Row 1 of a batch of two is what it is alone, and its first K - 1
     tokens are the last taps' terms only; the gradient of row 0's last
@@ -225,3 +263,52 @@ def test_the_rule_chooses_on_platform_and_shape(platform, monkeypatch,
     for shape in ((11, 128), (24, 6)):
         moved = run(*shape)
         assert moved['composed'] >= 1 and moved['kernel'] == 0
+
+
+@pytest.mark.parametrize('platform', ['cpu', 'tpu'])
+def test_the_layers_bias_reaches_either_way(platform, monkeypatch,
+                                            interpreted):
+    """`layers.causal_conv1d(bias_attr=...)` through the Executor: the
+    composition on the CPU, the kernels with the platform reported as
+    `tpu`; value and the three gradients are the formula's with the bias,
+    and the lowering counts `conv1d.lowered{bias=true}`. The default is no
+    bias and no `Bias` input."""
+    init = lowering.Ctx.__init__
+    monkeypatch.setattr(
+        lowering.Ctx, '__init__',
+        lambda self, *a, **kw: init(self, *a, **dict(kw, platform=platform)))
+    rng = np.random.default_rng(13)
+    x = rng.normal(size=(2, 24, 128)).astype('float32')
+    f = rng.normal(size=(4, 128)).astype('float32')
+    b = rng.normal(size=128).astype('float32')
+    w = rng.normal(size=(2, 24, 128)).astype('float32')
+
+    def attr(name, value):
+        return fluid.ParamAttr(
+            name=name,
+            initializer=fluid.initializer.NumpyArrayInitializer(value))
+
+    def build():
+        return layers.causal_conv1d(_input('x', x), 4, act='silu',
+                                    param_attr=attr('f', f),
+                                    bias_attr=attr('b', b))
+
+    before = obs.counter('conv1d.lowered', bias='true').value, _ways()
+    got, (gx, gf, gb), _ = _grads_of(build, {'w': w}, ['x', 'f', 'b'],
+                                     optimized=True)
+    assert obs.counter('conv1d.lowered', bias='true').value > before[0]
+    assert _ways()['kernel' if platform == 'tpu' else 'composed'] \
+        > before[1]['kernel' if platform == 'tpu' else 'composed']
+    want, pull = jax.vjp(lambda x, f, b: la._conv(x, f, 'silu', b),
+                         *map(jnp.asarray, (x, f, b)))
+    wx, wf, wb = pull(jnp.asarray(w))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    for a, ref in ((gx, wx), (gf, wf), (gb, wb)):
+        np.testing.assert_allclose(a, ref, rtol=1e-4, atol=1e-5)
+    assert np.abs(wb).max() > 0.1
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        layers.causal_conv1d(layers.data(name='x', shape=[24, 128],
+                                         dtype='float32'), 4)
+        op, = [o for o in fluid.default_main_program().global_block().ops
+               if o.type == 'causal_conv1d']
+        assert not op.input('Bias')

@@ -92,6 +92,35 @@ def test_grouped_matmul_tiles_compile_for_v5e(one_chip, dtype, rows):
 
 
 @pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+def test_grouped_matmul_takes_a_width_of_no_power_of_two(one_chip, dtype):
+    """nemotron3nano_s8192's expert matmuls (the compact layout's 24576
+    rows, 8 held experts of 2688 x 1856 and back, TWO matrices), forward
+    and both gradients, in the cell's bf16 and in its float32 check's
+    arithmetic: 1856 = 14.5 lane tiles, and a tile `_fit` halves must stay
+    whole lane tiles (928 was refused: AOT, PR 40)."""
+    from paddle_tpu.ops.kernels import grouped_matmul as gm
+    dt = jnp.dtype(dtype)
+    for tiles, k, n in ((gm.TILES[0], 2688, 1856), (gm.TILES[1], 1856, 2688),
+                        (gm.TILES[2], 2688, 1856)):
+        _, tk, tn = gm._fit(tiles, 24576, k, n, dt.itemsize)
+        assert (tk % 128 == 0 or tk == k) and (tn % 128 == 0 or tn == n)
+    x = jax.ShapeDtypeStruct((24576, 2688), dt, sharding=one_chip)
+    up = jax.ShapeDtypeStruct((8, 2688, 1856), dt, sharding=one_chip)
+    down = jax.ShapeDtypeStruct((8, 1856, 2688), dt, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+
+    def loss(x, up, down, sizes):
+        h = gm.grouped_matmul(x, up, sizes, False)
+        h = jnp.square(jax.nn.relu(h.astype(jnp.float32))).astype(dt)
+        return jnp.sum(gm.grouped_matmul(h, down, sizes, False)
+                       .astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, up, down, sizes).compile()
+    assert compiled.as_text().count('tpu_custom_call') == 6
+
+
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
 def test_gated_delta_intra_compiles_for_v5e(one_chip, dtype):
     """qwen3next_s8192's stage `gdn_intra` (128 chunks of 64 tokens, 16
     key heads serving 32 value heads of 128), forward and backward, in the cell's bf16 and in its
@@ -135,6 +164,29 @@ def test_causal_conv1d_compiles_for_v5e(one_chip, dtype):
                        .astype(jnp.float32) ** 2)
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).compile()
+    assert compiled.as_text().count('tpu_custom_call') == 2
+
+
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+def test_causal_conv1d_with_a_bias_compiles_for_v5e(one_chip, dtype):
+    """nemotron3nano_s8192's depthwise convolution (one row of 8192
+    tokens, 6144 channels = 4096 + 2 x 8 x 128, four taps, a BIAS, silu),
+    forward and backward: the bias is one more [1, tC] block each way and
+    its gradient a third output of the backward's one call."""
+    from paddle_tpu.fluid.ops_impl.linear_attention_ops import causal_conv1d
+    from paddle_tpu.ops.kernels import causal_conv1d as kernel
+    dt = jnp.dtype(dtype)
+    assert kernel.usable(8192, 6144, 4, dt)
+    x = jax.ShapeDtypeStruct((1, 8192, 6144), dt, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((4, 6144), jnp.float32, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((6144,), jnp.float32, sharding=one_chip)
+
+    def loss(x, w, b):
+        return jnp.sum(causal_conv1d(x, w, 'silu', True, b)
+                       .astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, w, b).compile()
     assert compiled.as_text().count('tpu_custom_call') == 2
 
 
