@@ -90,6 +90,16 @@ backward keeps) and the matmuls of `ssd_intra` and `ssd_inter` take bf16
 operands (M, the weighted X and S_start rounded once) with float32
 accumulation; dt, A, D, every decay and the carried state are float32.
 The output is float32.
+On the TPU, for chunks of 128, heads of 64 or 128 and states of whole lane
+tiles (`usable` of ops/kernels/ssd_scan.py), the whole scan is one Pallas
+kernel forward and one backward: the chunks are the grid's last,
+sequential axis, S a float32 VMEM scratch, nothing [C, C] reaches HBM,
+and the backward reads S_start of each chunk, which the rule's forward
+kept ([T / C, B, H, P, N] float32, alive from a block's recomputation to
+its backward), so it runs no stage again. The same arithmetic in the same
+precisions; every other platform and shape takes `_ssd_stages` below, the
+composition the kernels are tested against. The rule chooses as it does
+for the delta rule's stage.
 
 `causal_conv1d`: y[b, t, c] = act(sum_j w[j, c] x[b, t - (K - 1) + j, c]
 + bias[c]), x = 0 before the row's first token; depthwise (a filter a
@@ -118,8 +128,9 @@ of G equal parts of the last axis by itself.
 Trace-time counters: `gdn.lowered{chunk=}` once per op per trace,
 `gdn.intra{way=kernel|composed}` beside it (which way stage `gdn_intra`
 went), `gdn.tokens` the B x T of the traced shape,
-`ssd.lowered{chunk=, heads=, groups=}` and `ssd.tokens` likewise,
-`conv1d.lowered` (with the label `bias=true` where the op has one) and
+`ssd.lowered{chunk=, heads=, groups=}` and `ssd.tokens` likewise and
+`ssd.way{way=kernel|composed}` beside them, `conv1d.lowered` (with the
+label `bias=true` where the op has one) and
 `conv1d.way{way=kernel|composed}` beside it, `gated_rms_norm.lowered`.
 """
 import functools
@@ -132,6 +143,7 @@ from jax import lax
 from ... import obs
 from ...ops.kernels import causal_conv1d as conv_kernel
 from ...ops.kernels import gated_delta_intra as intra_kernel
+from ...ops.kernels import ssd_scan as ssd_kernel
 from ..lowering import register, data_of, amp_cast
 
 _SOLVE_BLOCK = 16
@@ -421,27 +433,56 @@ def _ssd_stages(x, dt, a, b, c, chunk):
     return y.reshape(bsz, t, h, p)
 
 
+def _ssd_padded(x, dt, b, c, chunk):
+    """x, dt, b, c with T padded to whole chunks: tokens of dt = 0, which
+    decay nothing and write nothing."""
+    pad = [(0, 0), (0, -x.shape[1] % chunk), (0, 0)]
+    xp, bp, cp = (jnp.pad(v, pad + [(0, 0)]) for v in (x, b, c))
+    return xp, jnp.pad(dt.astype(jnp.float32), pad), bp, cp
+
+
 def _ssd(x, dt, a, b, c, d, chunk):
     t = x.shape[1]
-    pad = [(0, 0), (0, -t % chunk), (0, 0)]
-    xp, bp, cp = (jnp.pad(v, pad + [(0, 0)]) for v in (x, b, c))
-    dt, a = dt.astype(jnp.float32), a.astype(jnp.float32)
-    y = _ssd_stages(xp, jnp.pad(dt, pad), a, bp, cp, chunk)[:, :t]
+    xp, dtp, bp, cp = _ssd_padded(x, dt, b, c, chunk)
+    y = _ssd_stages(xp, dtp, a.astype(jnp.float32), bp, cp, chunk)[:, :t]
     if d is not None:
         y = y + d.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
     return y
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _ssd_chunked(x, dt, a, b, c, d, chunk):
+def _ssd_kernel(x, dt, a, b, c, d, chunk):
+    """The Pallas forward: (y, S at each chunk's start, which only a
+    caller that reads it pays for)."""
+    xp, dtp, bp, cp = _ssd_padded(x, dt, b, c, chunk)
+    y, starts = ssd_kernel.ssd_scan_fwd(xp, dtp, a, bp, cp, d,
+                                        interpret=False)
+    return y[:, :x.shape[1]], starts
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _ssd_chunked(x, dt, a, b, c, d, chunk, kernel):
+    if kernel:
+        return _ssd_kernel(x, dt, a, b, c, d, chunk)[0]
     return _ssd(x, dt, a, b, c, d, chunk)
 
 
-def _ssd_fwd(x, dt, a, b, c, d, chunk):
+def _ssd_fwd(x, dt, a, b, c, d, chunk, kernel):
+    if kernel:      # S at the chunks' starts is all the backward asks of it
+        y, starts = _ssd_kernel(x, dt, a, b, c, d, chunk)
+        return y, (x, dt, a, b, c, d, starts)
     return _ssd(x, dt, a, b, c, d, chunk), (x, dt, a, b, c, d)
 
 
-def _ssd_bwd(chunk, res, dy):
+def _ssd_bwd(chunk, kernel, res, dy):
+    if kernel:      # one kernel, no forward of its own
+        x, dt, a, b, c, d, starts = res
+        t = x.shape[1]
+        xp, dtp, bp, cp = _ssd_padded(x, dt, b, c, chunk)
+        dyp = jnp.pad(dy, [(0, 0), (0, -t % chunk), (0, 0), (0, 0)])
+        dx, ddt, da, db, dc, dd = ssd_kernel.ssd_scan_bwd(
+            xp, dtp, a, bp, cp, d, starts, dyp, interpret=False)
+        return (dx[:, :t], ddt[:, :t].astype(dt.dtype), da, db[:, :t],
+                dc[:, :t], dd)
     res, dy = _recompute_after(res, dy)
     return jax.vjp(lambda *v: _ssd(*v, chunk), *res)[1](dy)
 
@@ -449,13 +490,15 @@ def _ssd_bwd(chunk, res, dy):
 _ssd_chunked.defvjp(_ssd_fwd, _ssd_bwd)
 
 
-def ssd_scan(x, dt, a, b, c, d=None, chunk_size=128):
+def ssd_scan(x, dt, a, b, c, d=None, chunk_size=128, kernel=False):
     """x [B, T, H, P], b, c [B, T, G, N] (float32, or bf16 for bf16
     matmuls), dt [B, T, H] (the step, > 0), a [H] (< 0), d [H] or None; G
     divides H and group g serves heads g * H / G and following. Returns
-    y [B, T, H, P] float32."""
+    y [B, T, H, P] float32. `kernel`: the Pallas kernels, forward and
+    backward (the rule's choice; the caller has asked their `usable`),
+    else `_ssd_stages`."""
     return _ssd_chunked(x, dt, a, b, c, d,
-                        _chunk_of(chunk_size, x.shape[1]))
+                        _chunk_of(chunk_size, x.shape[1]), bool(kernel))
 
 
 @register('ssd_scan')
@@ -467,7 +510,14 @@ def _ssd_scan(ins, attrs, ctx):
                 groups=int(b.shape[2])).inc()                # trace time
     obs.counter('ssd.tokens').inc(int(x.shape[0]) * int(x.shape[1]))
     x, b, c = amp_cast(ctx, x, b, c)
-    return {'Out': ssd_scan(x, dt, a, b, c, d, chunk_size=chunk)}
+    # on the TPU, for a shape they take, one Pallas kernel each way
+    kernel = ctx.platform == 'tpu' and ssd_kernel.usable(
+        _chunk_of(chunk, x.shape[1]), x.shape[3], b.shape[3],
+        x.shape[2] // b.shape[2], x.dtype)
+    obs.counter('ssd.way',                                   # trace time
+                way='kernel' if kernel else 'composed').inc()
+    return {'Out': ssd_scan(x, dt, a, b, c, d, chunk_size=chunk,
+                            kernel=kernel)}
 
 
 _CONV_ACTS = {'': lambda x: x, 'silu': jax.nn.silu, 'swish': jax.nn.silu}

@@ -1,6 +1,7 @@
 """The flash kernels at their default tiles, the grouped-matmul kernels at
-theirs, the delta rule's chunk kernels and the causal convolution's,
-compiled by Mosaic for a DESCRIBED v5e (no chip, nothing runs): what the
+theirs, the delta rule's chunk kernels, the causal convolution's and the
+state-space scan's, compiled by Mosaic for a DESCRIBED v5e (no chip,
+nothing runs): what the
 interpreter and jax.export cannot refuse — VMEM the kernel may not have,
 slices Mosaic will not tile — is refused here. The cells' shapes, and the
 shapes on either side of the default-tile rule (_default_tile). The
@@ -188,6 +189,35 @@ def test_causal_conv1d_with_a_bias_compiles_for_v5e(one_chip, dtype):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, w, b).compile()
     assert compiled.as_text().count('tpu_custom_call') == 2
+
+
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+def test_ssd_scan_compiles_for_v5e(one_chip, dtype):
+    """nemotron3nano_s8192's state-space scan (one row of 8192 tokens, 64
+    heads of 64 in 8 groups of state 128, chunks of 128, the skip) as the
+    rule hands it to the op, forward and backward, in the cell's bf16 and
+    in its float32 check's arithmetic, at the heads a grid step takes:
+    two Mosaic calls, the forward that keeps the starts and the backward,
+    and the starts are all the float32 the op keeps between them."""
+    from paddle_tpu.fluid.ops_impl.linear_attention_ops import ssd_scan
+    from paddle_tpu.ops.kernels import ssd_scan as kernel
+    dt = jnp.dtype(dtype)
+    assert kernel.usable(128, 64, 128, 8, dt) and kernel._heads(8, 64) == 8
+    x = jax.ShapeDtypeStruct((1, 8192, 64, 64), dt, sharding=one_chip)
+    bc = jax.ShapeDtypeStruct((1, 8192, 8, 128), dt, sharding=one_chip)
+    step = jax.ShapeDtypeStruct((1, 8192, 64), jnp.float32,
+                                sharding=one_chip)
+    head = jax.ShapeDtypeStruct((64,), jnp.float32, sharding=one_chip)
+
+    def loss(x, step, a, b, c, d):
+        return jnp.sum(ssd_scan(x, step, a, b, c, d, chunk_size=128,
+                                kernel=True) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=range(6))).lower(
+        x, step, head, bc, bc, head).compile()
+    assert compiled.as_text().count('tpu_custom_call') == 2
+    # y, its cotangent and the starts, 134 MB each, and no decays
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.2 * 2 ** 27
 
 
 @pytest.mark.parametrize('dtype,precision', [
