@@ -11,14 +11,23 @@ length is bounded by HBM, not VMEM. The forward keeps a running
 probabilities from the saved logsumexp. HBM traffic drops from O(T^2) to
 O(T*D).
 
-The backward is one algorithm with two schedules. Where a head's whole
+The backward is one algorithm with three schedules. Where a head's whole
 score matrix is one tile (every call at T <= 1024 with rows of up to 512
 bytes, causal or not) nothing has to be accumulated across grid steps, and
 ONE kernel on the grid (B, H) gives dq, dk and dv from one s, p, dp and ds
-(_bwd_fused_kernel). Longer sequences, and tiles a caller forces below the
-length, take two kernels, dq over a q-row's k-blocks and dk/dv over a
-k-column's q-blocks, each recomputing s, p, dp and ds for itself. _prep
-decides from the shapes and counts `flash.backward{passes=one|two}`.
+(_bwd_fused_kernel, 'tile'). A causal self-attention head of more tiles,
+on the triangular grid or its band, still takes one pass (_bwd_head_kernel,
+'head') where VMEM holds the head's float32 dq beside a grid step's blocks
+(_head_vmem_limit against _HEAD_VMEM_LIMIT_BYTES: 16384 x 128 and
+8192 x 256 are 8 MiB of dq; bf16 heads of D = 128 fit up to 32768
+positions): dk and dv accumulate over a k-block's q-blocks, dq into the
+head's accumulator, each pair's s, p, dp and ds computed once. Everything
+else (the rectangular grid: not causal, Tq != Tk, oblong tiles; a head
+too long or too wide for that budget) takes two kernels, dq over a
+q-row's k-blocks and dk/dv over a k-column's q-blocks, each recomputing
+s, p, dp and ds for itself: 7 dots and two exp a pair where one pass
+spends 5 and one. _prep decides from the shapes and counts
+`flash.backward{passes=one, span=tile|head}` or `{passes=two}`.
 
 Supports an additive per-key bias [B, Tk] (padding mask; treated as a
 constant — stop_gradient'd by the op lowering) and causal masking —
@@ -33,10 +42,11 @@ never computed — causal forward+backward costs ~half the rectangular
 FLOPs. See the strategy note above _tri_maps for why this (and not
 compute predication) is the safe way to skip blocks under Mosaic. A
 sliding `window` (causal only: query i sees keys i - window + 1 .. i)
-cuts the same enumeration on its other side: the forward, the dq and the
-dk/dv grids list only the BAND of blocks a window touches, and the mask
-gets its second edge. Where the triangular grid does not apply (or the
-backward runs in one pass) a window is the mask alone.
+cuts the same enumeration on its other side: the forward and the
+backward's grids (one pass over a head, or dq and dk/dv) list only the
+BAND of blocks a window touches, and the mask gets its second edge. Where
+the triangular grid does not apply (or the backward is one tile) a window
+is the mask alone.
 
 What is float32 and what follows the input. The q, k, v and do tiles go
 into the MXU in the dtype of their refs (bf16 under AMP, float32 in a
@@ -53,10 +63,11 @@ lane-broadcast [rows, LANES] tiles from scratch or HBM to the score tile
 (_lanes). On the v5e that, not the operand dtype, was what a forward
 block step waited for (PERF.md, PR 24). The counters
 `flash.lowered{operands=<dtype>, grid=band|triangle|rect}` and
-`flash.backward{passes=one|two}` count attention calls per lowering;
-`flash.tiles{grid=}` adds up the (q-block, k-block) pairs a head that a
-call's grids visit (forward + dq + dk/dv), so a lowering says off the
-chip whether the band was taken and what it spared.
+`flash.backward{passes=one, span=tile|head}` / `{passes=two}` count
+attention calls per lowering; `flash.tiles{grid=}` adds up the (q-block,
+k-block) pairs a head that a call's grids visit (forward + dq + dk/dv;
+forward + the one pass under 'head'), so a lowering says off the chip
+whether the band was taken, what it spared, and which backward ran.
 
 `interpret` is the CALLER's decision, never read off the process's
 default backend: the op lowering passes interpret=False on a TPU place
@@ -333,6 +344,33 @@ def _bwd_dq_kernel_tri(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
                  window=window)
 
 
+def _bwd_dkv_pair(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
+                  dk_s, dv_s, i, j, *, scale, causal, block_q, block_k,
+                  window=None):
+    """One (q-block i, k-block j) pair's s^T, p^T, dp^T and ds^T, added
+    into the dk and dv accumulators. Returns the k tile and ds^T as a
+    dot's operand, which is all that dq needs besides."""
+    # the scores TRANSPOSED, [bk, bq]: both accumulators then take
+    # their p^T and ds^T as computed, and no [bq, bk] tile is turned
+    # round. What it costs is the three small vectors below.
+    k = k_ref[0, 0]                                            # [bk, D]
+    v = v_ref[0, 0]
+    qb = q_ref[0, 0]                                           # [bq, D]
+    dob = do_ref[0, 0]
+    lse_b = lse_ref[0, 0].T[:1]                                # [1, bq]
+    delta_b = delta_ref[0, 0].T[:1]
+    kb = jnp.broadcast_to(kb_ref[0], (LANES, block_k)).T[:, :1]
+    st = _dot(k, qb, _NT) * scale + kb                         # [bk, bq]
+    if causal:
+        st = _mask_causal(st, i * block_q, j * block_k, 1, window)
+    pt = jnp.exp(st - lse_b)
+    dv_s[:] = dv_s[:] + _dot(pt.astype(dob.dtype), dob, _NN)
+    dpt = _dot(v, dob, _NT)
+    dsc = (pt * (dpt - delta_b) * scale).astype(qb.dtype)
+    dk_s[:] = dk_s[:] + _dot(dsc, qb, _NN)
+    return k, dsc
+
+
 def _bwd_dkv_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
                   dk_ref, dv_ref, dk_s, dv_s, i, j, is_first, is_last, *,
                   scale, causal, block_q, block_k, window=None):
@@ -341,27 +379,9 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
         dk_s[:] = jnp.zeros_like(dk_s)
         dv_s[:] = jnp.zeros_like(dv_s)
 
-    def _compute():
-        # the scores TRANSPOSED, [bk, bq]: both accumulators then take
-        # their p^T and ds^T as computed, and no [bq, bk] tile is turned
-        # round. What it costs is the three small vectors below.
-        k = k_ref[0, 0]                                        # [bk, D]
-        v = v_ref[0, 0]
-        qb = q_ref[0, 0]                                       # [bq, D]
-        dob = do_ref[0, 0]
-        lse_b = lse_ref[0, 0].T[:1]                            # [1, bq]
-        delta_b = delta_ref[0, 0].T[:1]
-        kb = jnp.broadcast_to(kb_ref[0], (LANES, block_k)).T[:, :1]
-        st = _dot(k, qb, _NT) * scale + kb                     # [bk, bq]
-        if causal:
-            st = _mask_causal(st, i * block_q, j * block_k, 1, window)
-        pt = jnp.exp(st - lse_b)
-        dv_s[:] = dv_s[:] + _dot(pt.astype(dob.dtype), dob, _NN)
-        dpt = _dot(v, dob, _NT)
-        dst = pt * (dpt - delta_b) * scale
-        dk_s[:] = dk_s[:] + _dot(dst.astype(qb.dtype), qb, _NN)
-
-    _compute()
+    _bwd_dkv_pair(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
+                  dk_s, dv_s, i, j, scale=scale, causal=causal,
+                  block_q=block_q, block_k=block_k, window=window)
 
     @pl.when(is_last)
     def _finish():
@@ -393,6 +413,53 @@ def _bwd_dkv_kernel_tri(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
                   dk_ref, dv_ref, dk_s, dv_s, i, j, i == j, i == last,
                   scale=scale, causal=True,
                   block_q=block_q, block_k=block_k, window=window)
+
+
+def _bwd_head_kernel(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
+                     lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                     dq_s, dk_s, dv_s, *, scale, block_q, block_k, nq,
+                     window=None, nb=None):
+    """The whole backward of a causal head of MORE than one tile in one
+    pass over its triangle or band, the pairs in _tri_maps_kv order: a
+    pair's s^T, p^T, dp^T and ds^T are computed once (_bwd_dkv_pair) and
+    feed all three gradients, 5 dots and one exp where the two kernels
+    spend 7 and two, and q, k, v, do, lse and delta are read once a pair.
+    dk and dv accumulate over a k-block's consecutive steps as in
+    _bwd_dkv_kernel_tri. dq cannot: a q-block's pairs lie a k-block's
+    whole run apart, so the HEAD's dq stays in VMEM, float32
+    [nq, bq, D], and pair (i, j) adds into block i, the dot taken as
+    _bwd_fused_kernel takes it, (k^T ds^T)^T. k-blocks run last to
+    first, so q-block i meets its diagonal pair first (its block is
+    zeroed there, with k-block i's dk and dv) and its row's first
+    k-block last: there block i leaves for the head's dq output block,
+    which stays in VMEM until the head's last pair. Only those inits and
+    stores are predicated on the grid position, never the compute (the
+    strategy note above _tri_maps)."""
+    t = pl.program_id(2)
+    i, j = im_ref[t], jm_ref[t]
+    last = nq - 1 if nb is None else jnp.minimum(j + nb, nq - 1)
+
+    @pl.when(i == j)
+    def _init():
+        dk_s[:] = jnp.zeros_like(dk_s)
+        dv_s[:] = jnp.zeros_like(dv_s)
+        dq_s[i] = jnp.zeros(dq_s.shape[1:], dq_s.dtype)
+
+    k, dsc = _bwd_dkv_pair(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
+                           delta_ref, dk_s, dv_s, i, j, scale=scale,
+                           causal=True, block_q=block_q, block_k=block_k,
+                           window=window)
+    kt = k.astype(jnp.float32).T.astype(k.dtype)               # [D, bk]
+    dq_s[i] = dq_s[i] + _dot(kt, dsc, _NN).T
+
+    @pl.when(i == last)
+    def _finish_kv():
+        dk_ref[0, 0] = dk_s[:].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_s[:].astype(dv_ref.dtype)
+
+    @pl.when(_row_start(i, j, nb))
+    def _finish_q():
+        dq_ref[0, 0, i] = dq_s[i].astype(dq_ref.dtype)
 
 
 def _add_rows(acc, x):
@@ -574,6 +641,59 @@ def _bwd_call_tri(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret,
     return dq, dk, dv
 
 
+def _head_vmem_limit(T, D, bq, bk, itemsize):
+    """The VMEM limit the one-pass head kernel's call states, in bytes:
+    what it holds (a head's dq in float32 and the two buffers of its
+    output block, a grid step's blocks twice over: q, do, k, v in, dk, dv
+    out, lse and delta, the bias in its sublane tile; the dk and dv
+    accumulators) plus Mosaic's default scope for the body's score tiles,
+    as the two kernels have it at the same tiles."""
+    dq = T * D * (4 + 2 * itemsize)
+    blocks = 2 * ((2 * bq + 4 * bk) * D * itemsize + 2 * bq * LANES * 4
+                  + 8 * bk * 4)
+    return dq + blocks + 2 * bk * D * 4 + _MOSAIC_SCOPE_BYTES
+
+
+def _bwd_call_head(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret,
+                   window=None):
+    """One pass over a head's triangle or band: grid (B, H, pairs), one
+    kernel for dq, dk and dv (_bwd_head_kernel). The call states the VMEM
+    it needs (_head_vmem_limit): Mosaic's default does not cover a head's
+    dq."""
+    B, H, Tq, D = q.shape
+    nq = Tq // bq
+    nb = _band(window, bk, nq)
+    qrow, kcol, kbias, stats = _tri_specs(bq, bk, D)
+    head = pl.BlockSpec((1, 1, nq, bq, D),
+                        lambda b, h, t, im, jm: (b, h, 0, 0, 0))
+    im, jm = _tri_maps_kv(nq, nb)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_head_kernel, scale=scale, block_q=bq,
+                          block_k=bk, nq=nq, window=window, nb=nb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, H, len(im)),
+            in_specs=[qrow, kcol, kcol, kbias, qrow, stats, stats],
+            out_specs=[head, kcol, kcol],
+            scratch_shapes=[
+                pltpu.VMEM((nq, bq, D), jnp.float32),
+                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, D), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, nq, bq, D), q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_head_vmem_limit(Tq, D, bq, bk,
+                                              q.dtype.itemsize)),
+        interpret=interpret,
+    )(jnp.asarray(im), jnp.asarray(jm), q, k, v, kb, do, lse, delta)
+    return dq.reshape(q.shape), dk, dv
+
+
 def _bwd_call_fused(q, k, v, kb, do, lse, delta, causal, scale, sub_q,
                     interpret, window=None):
     """One pass over a head's whole score matrix: grid (B, H), one kernel
@@ -599,17 +719,21 @@ def _bwd_call_fused(q, k, v, kb, do, lse, delta, causal, scale, sub_q,
     )(q, k, v, kb, do, lse, delta)
 
 
-def _bwd_call(q, k, v, kb, do, lse, delta, causal, scale, bq, bk, one_pass,
+def _bwd_call(q, k, v, kb, do, lse, delta, causal, scale, bq, bk, schedule,
               interpret, window=None):
-    """One algorithm, scheduled by whether anything has to be accumulated
-    across grid steps: one pass where _prep found a head's scores to be
-    one tile, else dq and dk/dv in a pass each over the triangular or the
-    rectangular grid."""
+    """One algorithm, scheduled by what has to be kept across grid steps
+    and whether VMEM holds it (_prep's rule): 'tile', one pass where a
+    head's scores are one tile; 'head', one pass over the triangle or
+    band with the head's dq in VMEM; None, dq and dk/dv in a pass each
+    over the triangular or the rectangular grid."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
-    if one_pass:
+    if schedule == 'tile':
         return _bwd_call_fused(q, k, v, kb, do, lse, delta, causal, scale,
                                bq, interpret, window)
+    if schedule == 'head':
+        return _bwd_call_head(q, k, v, kb, do, lse, delta, scale, bq, bk,
+                              interpret, window)
     if _use_tri(causal, Tq, Tk, bq, bk):
         return _bwd_call_tri(q, k, v, kb, do, lse, delta, scale, bq, bk,
                              interpret, window)
@@ -672,13 +796,13 @@ def _bwd_call_rect(q, k, v, kb, do, lse, delta, causal, scale, bq, bk,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
-def _flash_lse(q, k, v, kb, causal, window, scale, bq, bk, one_pass,
+def _flash_lse(q, k, v, kb, causal, window, scale, bq, bk, schedule,
                interpret):
     o, lse = _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret, window)
     return o, lse[..., 0]
 
 
-def _flash_lse_fwd(q, k, v, kb, causal, window, scale, bq, bk, one_pass,
+def _flash_lse_fwd(q, k, v, kb, causal, window, scale, bq, bk, schedule,
                    interpret):
     o, lse = _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret, window)
     # named for a recompute region's policy (step_artifact._run_region):
@@ -687,12 +811,12 @@ def _flash_lse_fwd(q, k, v, kb, causal, window, scale, bq, bk, one_pass,
     return (o, lse[..., 0]), (q, k, v, kb, o, lse)
 
 
-def _flash_lse_bwd(causal, window, scale, bq, bk, one_pass, interpret, res,
+def _flash_lse_bwd(causal, window, scale, bq, bk, schedule, interpret, res,
                    cot):
     """Backward with an lse cotangent, sharing the kernels unchanged:
     lse = logsumexp(S) gives dS|lse = P * dlse, and the kernels compute
     dS = P * (dP - delta), so folding delta' = delta - dlse routes the lse
-    gradient through the same pallas calls, one pass or two (the
+    gradient through the same pallas calls, whatever the schedule (the
     FlashAttention D-trick extended one term)."""
     do, dlse = cot
     q, k, v, kb, o, lse = res
@@ -700,7 +824,7 @@ def _flash_lse_bwd(causal, window, scale, bq, bk, one_pass, interpret, res,
     delta = delta - dlse.astype(jnp.float32)
     delta = jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
     dq, dk, dv = _bwd_call(q, k, v, kb, do, lse, delta, causal, scale,
-                           bq, bk, one_pass, interpret, window)
+                           bq, bk, schedule, interpret, window)
     # kb is a mask constant (see module docstring): zero cotangent
     return dq, dk, dv, jnp.zeros_like(kb)
 
@@ -715,13 +839,20 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 # triangular grid beats one masked 1024 x 1024 block by 1.5%. Equal
 # bq == bk keeps the triangular grid eligible (_use_tri). Shorter sequences
 # clip the tiles in _prep, which is all that T = 256 ever sees.
-# The backward (PR 27) runs in ONE pass wherever a head's scores fit the
-# table's largest tile, masked or not: the residual lse is per row, so
-# nothing ties the backward to the forward's tile, and a causal call walks
-# that tile in the forward's 512 sub-tiles (_bwd_fused_kernel; at
-# 16 x 8 x 1024 x 64 causal 0.87 ms a call against 1.59 ms for the two
-# kernels on the triangular grid). Tiles a caller forces are the
-# backward's too.
+# The backward has three schedules (_prep's rule). 'tile' (PR 27): ONE
+# pass wherever a head's scores fit the table's largest tile, masked or
+# not: the residual lse is per row, so nothing ties the backward to the
+# forward's tile, and a causal call walks that tile in the forward's 512
+# sub-tiles (_bwd_fused_kernel; at 16 x 8 x 1024 x 64 causal 0.87 ms a
+# call against 1.59 ms for the two kernels on the triangular grid): every
+# call of the Transformer cells. 'head' (PR 42): one pass over the
+# triangular grid or its band at the forward's tiles, the head's dq in
+# VMEM (_bwd_head_kernel): causal self-attention of more than one tile,
+# which is every call of the language-model cells (16384 x 128 with and
+# without a window, 8192 x 256, 8192 x 128, 4096 x 128, and their float32
+# checks at 512- and 256-tiles). Two passes: the rectangular grid (not
+# causal over 1024, Tq != Tk, oblong tiles) and a head whose dq VMEM does
+# not hold. Tiles a caller forces are the backward's too.
 _TUNED_BQ_BK = {True: (512, 512), False: (1024, 1024)}
 # Beside 1024 x 1024 float32 score tiles Mosaic's VMEM budget holds operand
 # rows of up to this many bytes (compiled for a described v5e, PR 24:
@@ -735,6 +866,13 @@ _WIDE_ROW_BYTES = 512
 # D = 128, 512 x 512 at float32 D = 512; what fills VMEM first is the
 # double-buffered q, k, v, do, dq, dk, dv blocks, 14 x T x row bytes,
 # where the two kernels hold 10 and 12).
+_MOSAIC_SCOPE_BYTES = 16 * 2 ** 20
+# The one-pass head kernel holds a head's dq besides, which that default
+# does not cover, so its call states a limit of its own
+# (_head_vmem_limit). The rule lets a head in where that limit is at most
+# half the 128 MiB of a v5e's VMEM (16384 x 128 bf16: 19.0 MiB held, 35.0
+# stated; float32: 28.5 and 44.5).
+_HEAD_VMEM_LIMIT_BYTES = 64 * 2 ** 20
 
 
 def _default_tile(tuned, T, row_bytes):
@@ -801,26 +939,37 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
     bk = min(block_k, _round_up(Tk, 128))
     Tq_p = _round_up(Tq, bq)
     Tk_p = _round_up(Tk, bk)
-    # the backward's schedule, read off the shapes: one pass where a head's
-    # scores are one tile, the forward's or (tiles not forced) the table's
-    # largest for rows this wide
-    one_pass = (Tq_p, Tk_p) == (bq, bk) or (
-        not forced and Tq_p <= _default_tile(whole_q, Tq, row_bytes)
-        and Tk_p <= _default_tile(whole_k, Tk, row_bytes))
     # trace time: once per attention call per lowering (a forward and one
     # or two backward kernels each), never per step. `grid` is the
     # forward's; `flash.tiles` the (q-block, k-block) pairs a head that the
-    # call's grids visit, forward + dq + dk/dv (one pass: one).
+    # call's grids visit: forward + dq + dk/dv, forward + one pass over
+    # the same pairs ('head'), or forward + one step ('tile').
     nq, nk = Tq_p // bq, Tk_p // bk
-    if _use_tri(causal, Tq_p, Tk_p, bq, bk):
+    tri = _use_tri(causal, Tq_p, Tk_p, bq, bk)
+    if tri:
         nb = _band(window, bk, nq)
         grid, pairs = 'triangle' if nb is None else 'band', _tile_pairs(nq, nb)
     else:
         grid, pairs = 'rect', nq * nk
+    # the backward's schedule, read off the shapes: one pass where a head's
+    # scores are one tile, the forward's or (tiles not forced) the table's
+    # largest for rows this wide; one pass over the triangle or band where
+    # VMEM holds the head's dq beside a step's blocks; else two passes
+    if (Tq_p, Tk_p) == (bq, bk) or (
+            not forced and Tq_p <= _default_tile(whole_q, Tq, row_bytes)
+            and Tk_p <= _default_tile(whole_k, Tk, row_bytes)):
+        schedule, bwd_pairs = 'tile', 1
+    elif tri and _head_vmem_limit(
+            Tq_p, D, bq, bk, operands.itemsize) <= _HEAD_VMEM_LIMIT_BYTES:
+        schedule, bwd_pairs = 'head', pairs
+    else:
+        schedule, bwd_pairs = None, 2 * pairs
     obs.counter('flash.lowered', operands=operands.name, grid=grid).inc()
-    obs.counter('flash.backward', passes='one' if one_pass else 'two').inc()
-    obs.counter('flash.tiles', grid=grid).inc(
-        pairs + (1 if one_pass else 2 * pairs))
+    if schedule:
+        obs.counter('flash.backward', passes='one', span=schedule).inc()
+    else:
+        obs.counter('flash.backward', passes='two').inc()
+    obs.counter('flash.tiles', grid=grid).inc(pairs + bwd_pairs)
     if Tq_p != Tq:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, Tq_p - Tq), (0, 0)))
     if Tk_p != Tk:
@@ -832,7 +981,7 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
     # array dim, so the bias carries an explicit singleton sublane
     key_bias = key_bias.reshape(B, 1, Tk_p)
     return (q, k, v, key_bias, float(sm_scale), int(bq), int(bk),
-            bool(one_pass), bool(interpret), Tq, Tq_p)
+            schedule, bool(interpret), Tq, Tq_p)
 
 
 def flash_attention_lse(q, k, v, key_bias=None, causal=False, sm_scale=None,
@@ -843,11 +992,11 @@ def flash_attention_lse(q, k, v, key_bias=None, causal=False, sm_scale=None,
     partial attention over key shards. Differentiable in q/k/v through BOTH
     outputs (see _flash_lse_bwd)."""
     window = _window_of(window, causal, q.shape[2])
-    (q, k, v, kb, scale, bq, bk, one_pass, interp, Tq, Tq_p) = _prep(
+    (q, k, v, kb, scale, bq, bk, schedule, interp, Tq, Tq_p) = _prep(
         q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
         causal=causal, window=window)
     o, lse = _flash_lse(q, k, v, kb, bool(causal), window, scale, bq, bk,
-                        one_pass, interp)
+                        schedule, interp)
     if Tq_p != Tq:
         o = o[:, :, :Tq, :]
         lse = lse[:, :, :Tq]

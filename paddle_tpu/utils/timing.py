@@ -13,25 +13,29 @@ import time
 __all__ = ['time_chained', 'time_fwd_bwd_chained']
 
 
-def time_chained(step, x, iters, warmup=1):
-    """Seconds per call of step(x) -> x' (x a tuple of [B, H, T, D]
-    arrays), measured as `iters` calls chained inside one jit with a
-    single scalar, which depends on every final array, pulled to the host
-    at the end."""
+def time_chained(step, x, iters, warmup=1, consts=None):
+    """Seconds per call of step(x, **consts) -> x' (x a tuple of
+    [B, H, T, D] arrays), measured as `iters` calls chained inside one
+    jit with a single scalar, which depends on every final array, pulled
+    to the host at the end. Arrays a step only reads go in the dict
+    `consts`: one it closes over is compiled into the program, a quarter
+    of a gigabyte of it at 16384 positions."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    consts = consts or {}
+
     @jax.jit
-    def run(x):
-        x = jax.lax.fori_loop(0, iters, lambda _, x: step(x), x)
+    def run(x, consts):
+        x = jax.lax.fori_loop(0, iters, lambda _, x: step(x, **consts), x)
         return sum(jnp.sum(a[0, 0, 0, :8].astype(jnp.float32)) for a in x)
 
     for _ in range(warmup):
-        s = float(run(x))           # compile + warm; host sync
+        s = float(run(x, consts))   # compile + warm; host sync
         assert np.isfinite(s), s
     t0 = time.time()
-    s = float(run(x))               # host round-trip = completion
+    s = float(run(x, consts))       # host round-trip = completion
     assert np.isfinite(s), s
     return (time.time() - t0) / iters
 

@@ -4,7 +4,8 @@ state-space scan's, compiled by Mosaic for a DESCRIBED v5e (no chip,
 nothing runs): what the
 interpreter and jax.export cannot refuse — VMEM the kernel may not have,
 slices Mosaic will not tile — is refused here. The cells' shapes, and the
-shapes on either side of the default-tile rule (_default_tile). The
+shapes on either side of the default-tile rule (_default_tile) and of the
+backward's schedule. The
 topology is described inside a fixture and in this file only: one process
 at a time may load the TPU's library."""
 import os
@@ -15,6 +16,8 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu import ops
+
+from util import flash_schedules as _schedules
 
 
 @pytest.fixture(scope='module')
@@ -44,27 +47,43 @@ def _compile_grad(one_chip, dtype, shape, causal):
         x, x, x, kb).compile()
 
 
-# Mosaic calls of a forward and backward: 2 where a head's scores are one
-# tile (the one-pass backward, PR 27), 3 where they are not.
+# The backward's schedule (not causal, causal) that the rule gives each
+# shape: 'tile' where a head's scores are one tile (PR 27), 'head' on the
+# triangular grid (PR 42), 'two' passes. Mosaic calls of a forward and
+# backward: 2 in one pass, 3 in two.
 @pytest.mark.parametrize('causal', [False, True], ids=['full', 'causal'])
-@pytest.mark.parametrize('dtype,shape,calls', [
-    ('bfloat16', (16, 8, 1024, 64), 2),   # tfm_s1024: one 1024 tile; causal
-                                          # forward 512, backward in one pass
-    ('bfloat16', (64, 8, 256, 64), 2),    # tfm_s256: one 256 tile
-    ('bfloat16', (2, 8, 1536, 64), 3),    # 1024 would pad to 2048: 512
-    ('bfloat16', (4, 8, 1024, 256), 2),   # the widest rows 1024 tiles take
-    ('float32', (2, 8, 2048, 128), 3),    # the same 512 bytes a row
-    ('float32', (4, 8, 1024, 256), 3),    # wider: refused at 1024, so 512
-    ('float32', (16, 8, 1024, 64), 2),    # the cells' shapes in a Program
-    ('float32', (64, 8, 256, 64), 2),     # without AMP: one pass too
-    ('float32', (2, 8, 1024, 128), 2),    # 512 bytes a row in one pass
-    ('float32', (2, 8, 512, 512), 2),     # the widest one 512 tile holds
-    ('bfloat16', (2, 16, 4096, 128), 3),  # olmoe_s4096: 36 tile pairs
+@pytest.mark.parametrize('dtype,shape,schedules', [
+    ('bfloat16', (16, 8, 1024, 64), ('tile', 'tile')),  # tfm_s1024: one 1024
+                                          # tile; causal forward 512,
+                                          # backward in one pass
+    ('bfloat16', (64, 8, 256, 64), ('tile', 'tile')),   # tfm_s256: one 256
+    ('bfloat16', (2, 8, 1536, 64), ('two', 'head')),    # 1024 would pad to
+                                                        # 2048: 512
+    ('bfloat16', (4, 8, 1024, 256), ('tile', 'tile')),  # the widest rows
+                                                        # 1024 tiles take
+    ('float32', (2, 8, 2048, 128), ('two', 'head')),    # the same 512 bytes
+    ('float32', (4, 8, 1024, 256), ('two', 'head')),    # wider: refused at
+                                                        # 1024, so 512 (256)
+    ('float32', (16, 8, 1024, 64), ('tile', 'tile')),   # the cells' shapes in
+    ('float32', (64, 8, 256, 64), ('tile', 'tile')),    # a Program without
+                                                        # AMP: one pass too
+    ('float32', (2, 8, 1024, 128), ('tile', 'tile')),   # 512 bytes a row
+    ('float32', (2, 8, 512, 512), ('tile', 'tile')),    # the widest one 512
+                                                        # tile holds
+    ('bfloat16', (2, 16, 4096, 128), ('two', 'head')),  # olmoe_s4096: 36
+                                                        # tile pairs
 ], ids=lambda x: x if isinstance(x, str) else
-    'x'.join(map(str, x)) if isinstance(x, tuple) else None)
-def test_default_tiles_compile_for_v5e(one_chip, dtype, shape, calls, causal):
+    'x'.join(map(str, x)) if isinstance(x[0], int) else None)
+def test_default_tiles_compile_for_v5e(one_chip, dtype, shape, schedules,
+                                       causal):
+    before = _schedules()
     compiled = _compile_grad(one_chip, dtype, shape, causal)
-    assert compiled.as_text().count('tpu_custom_call') == calls
+    after = _schedules()
+    schedule = schedules[causal]
+    assert {s: after[s] - before[s] for s in after} == {
+        s: int(s == schedule) for s in after}
+    assert compiled.as_text().count('tpu_custom_call') == (
+        3 if schedule == 'two' else 2)
 
 
 @pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
@@ -220,41 +239,62 @@ def test_ssd_scan_compiles_for_v5e(one_chip, dtype):
     assert compiled.memory_analysis().temp_size_in_bytes < 4.2 * 2 ** 27
 
 
+# the causal attention calls of the five language-model cells as their
+# builders make them (key-value heads repeated over their groups before the
+# call): shape, window, the tile pairs a head of the grid they take
+_CELL_CALLS = {
+    'smallthinker_window': ((1, 28, 16384, 128), 4096, 'band', 252),
+    'smallthinker_global': ((1, 28, 16384, 128), None, 'triangle', 528),
+    'glm47flash': ((1, 20, 8192, 256), None, 'triangle', 136),
+    'qwen3next': ((1, 16, 8192, 256), None, 'triangle', 136),
+    'nemotron3nano': ((1, 32, 8192, 128), None, 'triangle', 136),
+    'olmoe': ((2, 16, 4096, 128), None, 'triangle', 36),
+}
+
+
 @pytest.mark.parametrize('dtype,precision', [
     ('bfloat16', None), ('float32', 'highest')],
     ids=['bf16_the_cell', 'float32_the_check'])
-def test_windowed_band_compiles_for_v5e(one_chip, dtype, precision):
-    """smallthinker_s16384's windowed attention call (one row of 16384
-    positions, 28 heads of 128, a window of 4096) forward, dq and dk/dv on
-    the BAND grid, in the cell's bf16 and in its float32 check's
-    arithmetic (traced under jax's highest matmul precision, as
-    harness/check.py traces it), inside Mosaic's VMEM budget; the counter
-    says off the chip that the band was taken and what it spared: 252 of
-    the triangle's 528 tile pairs a head in each of the three grids."""
+@pytest.mark.parametrize('call', sorted(_CELL_CALLS))
+def test_head_backward_compiles_for_the_cells(one_chip, call, dtype,
+                                              precision):
+    """Every causal attention call of the five language-model cells, the
+    forward and the ONE-pass backward over the head (PR 42), in the cell's
+    bf16 and in its float32 check's arithmetic (traced under jax's highest
+    matmul precision, as harness/check.py traces it; rows of D = 256 in
+    256-tiles there, 528 pairs), inside the VMEM limit the call states.
+    The counters say off the chip which schedule the rule chose, that a
+    window took the band, and what it spared: 252 of the triangle's 528
+    tile pairs a head in each of the two grids. A shape that fell back to
+    two passes would fail here and not on the chip."""
     import contextlib
     from paddle_tpu import obs
-    x = jax.ShapeDtypeStruct((1, 28, 16384, 128), jnp.dtype(dtype),
-                             sharding=one_chip)
+    shape, window, grid, pairs = _CELL_CALLS[call]
+    dt = jnp.dtype(dtype)
+    if dt.itemsize * shape[3] > 512:
+        pairs = {136: 528}[pairs]
+    x = jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     def tiles():
         return {g: obs.counter('flash.tiles', grid=g).value
                 for g in ('band', 'triangle', 'rect')}
 
     def loss(q, k, v):
-        o = ops.flash_attention(q, k, v, causal=True, window=4096,
+        o = ops.flash_attention(q, k, v, causal=True, window=window,
                                 interpret=False)
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
-    before = tiles()
+    before, was = tiles(), _schedules()
     with (jax.default_matmul_precision(precision) if precision
           else contextlib.nullcontext()):
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             x, x, x).compile()
-    assert compiled.as_text().count('tpu_custom_call') == 3
-    after = tiles()
-    assert after['band'] - before['band'] == 3 * 252
-    assert after['triangle'] == before['triangle']
-    assert after['rect'] == before['rect']
+    assert compiled.as_text().count('tpu_custom_call') == 2
+    after, now = tiles(), _schedules()
+    assert {s: now[s] - was[s] for s in now} == {
+        'tile': 0, 'head': 1, 'two': 0}
+    assert {g: after[g] - before[g] for g in after} == {
+        g: 2 * pairs * (g == grid) for g in after}
 
 
 @pytest.mark.parametrize('cell,tokens,k,width,hidden,cap,dtype', [

@@ -10,7 +10,29 @@ from paddle_tpu import ops
 import paddle_tpu.fluid as fluid
 import paddle_tpu.fluid.layers as layers
 
-from util import fresh_program
+from util import flash_schedules as _count_passes, fresh_program
+
+
+def _fa():
+    # the package's attribute of that name is the function
+    import importlib
+    return importlib.import_module('paddle_tpu.ops.flash_attention')
+
+
+def _flash_as(schedule, q, k, v, key_bias=None, causal=False, window=None,
+              block_q=None, block_k=None):
+    """flash_attention_lse under the interpreter with the backward's
+    schedule PINNED ('tile', 'head' or None: two passes) through
+    _flash_lse's static argument, whatever _prep's rule chose (and
+    counted) for the shapes."""
+    fa = _fa()
+    window = fa._window_of(window, causal, q.shape[2])
+    q, k, v, kb, scale, bq, bk, _, interp, Tq, _ = fa._prep(
+        q, k, v, key_bias, None, block_q, block_k, True, causal=causal,
+        window=window)
+    o, lse = fa._flash_lse(q, k, v, kb, bool(causal), window, scale, bq, bk,
+                           schedule, interp)
+    return o[:, :, :Tq], lse[:, :, :Tq]
 
 
 def _rand_qkv(B=2, H=2, Tq=20, Tk=20, D=16, seed=0):
@@ -366,8 +388,17 @@ _BF16_CASES = {
     # Tq != Tk keeps the causal mask on the rectangular grid
     'causal_rectangular': dict(Tq=128, Tk=256, bias=True, causal=True,
                                block=128),
+    # three tiles a side: the rule gives one pass over the head (PR 42);
+    # the two kernels it replaced there, pinned
     'causal_triangular_3x3': dict(Tq=384, Tk=384, bias=True, causal=True,
                                   block=128),
+    'causal_triangular_3x3_two_passes': dict(Tq=384, Tk=384, bias=True,
+                                             causal=True, block=128,
+                                             pin=None),
+    'head_4x4_lse_cotangent': dict(Tq=512, Tk=512, bias=True, causal=True,
+                                   block=128, lse=True),
+    'head_band_window_200': dict(Tq=512, Tk=512, bias=True, causal=True,
+                                 block=128, window=200),
     'uneven_lengths': dict(Tq=9, Tk=33, bias=True),
     'lse_cotangent': dict(Tq=128, Tk=128, bias=True, causal=True, lse=True),
     # the one-pass backward (PR 27; the four single-tile cases above take
@@ -379,15 +410,19 @@ _BF16_CASES = {
 }
 
 
-def _ref_o_lse(q, k, v, bias, causal):
+def _ref_o_lse(q, k, v, bias, causal, window=None):
     """reference_attention and the logsumexp of its scores."""
     s = jnp.einsum('bhqd,bhkd->bhqk', q, k) * q.shape[-1] ** -0.5
     if bias is not None:
         s = s + bias[:, None, None, :]
     if causal:
-        s = jnp.where(jnp.arange(q.shape[2])[:, None]
-                      >= jnp.arange(k.shape[2])[None, :], s, -1e9)
-    return (ops.reference_attention(q, k, v, key_bias=bias, causal=causal),
+        ahead = (jnp.arange(q.shape[2])[:, None]
+                 - jnp.arange(k.shape[2])[None, :])
+        seen = ahead >= 0 if window is None else (ahead >= 0) & (
+            ahead < window)
+        s = jnp.where(seen, s, -1e9)
+    return (ops.reference_attention(q, k, v, key_bias=bias, causal=causal,
+                                    window=window),
             jax.scipy.special.logsumexp(s, axis=-1))
 
 
@@ -410,12 +445,14 @@ def test_bf16_inputs_match_float32_reference(case):
     u = jnp.asarray(r.randn(1, 2, c['Tq']), jnp.bfloat16).astype(jnp.float32)
 
     def flash(q, k, v):
-        return ops.flash_attention_lse(
-            q, k, v, key_bias=bias, causal=causal, block_q=c.get('block'),
-            block_k=c.get('block'), interpret=True)
+        kw = dict(key_bias=bias, causal=causal, window=c.get('window'),
+                  block_q=c.get('block'), block_k=c.get('block'))
+        if 'pin' in c:
+            return _flash_as(c['pin'], q, k, v, **kw)
+        return ops.flash_attention_lse(q, k, v, interpret=True, **kw)
 
     def ref(q, k, v):
-        return _ref_o_lse(q, k, v, bias, causal)
+        return _ref_o_lse(q, k, v, bias, causal, c.get('window'))
 
     def loss(fn):
         def f(q, k, v):
@@ -450,16 +487,23 @@ def _walk(jp, out):
     return out
 
 
-def _kernel_bodies(dtype, causal, block_q=128, block_k=128, T=256, Tk=None):
+def _kernel_bodies(dtype, causal, block_q=128, block_k=128, T=256, Tk=None,
+                   pin='rule'):
     """The traced bodies of the kernels of one forward and backward at
     2 x 2 x T x 64 (blocks of 128: the rectangular grid, or the triangular
-    one when causal; None: the default tiles), as (name, [eqns])."""
+    one when causal; None: the default tiles), as (name, [eqns]); with
+    `pin`, the backward's schedule whatever the rule gives the shapes."""
     q = jax.ShapeDtypeStruct((2, 2, T, 64), dtype)
     k = jax.ShapeDtypeStruct((2, 2, Tk or T, 64), dtype)
+
+    def flash(q, k, v):
+        kw = dict(causal=causal, block_q=block_q, block_k=block_k)
+        if pin != 'rule':
+            return _flash_as(pin, q, k, v, **kw)[0]
+        return ops.flash_attention(q, k, v, interpret=True, **kw)
+
     jaxpr = jax.make_jaxpr(jax.grad(
-        lambda q, k, v: ops.flash_attention(
-            q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-            interpret=True).astype(jnp.float32).sum(),
+        lambda q, k, v: flash(q, k, v).astype(jnp.float32).sum(),
         argnums=(0, 1, 2)))(q, k, k)
     calls = [e for e in _walk(jaxpr.jaxpr, [])
              if e.primitive.name == 'pallas_call']
@@ -469,12 +513,18 @@ def _kernel_bodies(dtype, causal, block_q=128, block_k=128, T=256, Tk=None):
 
 # path -> the forced block (None: the default tiles, one tile a head here),
 # the mask, the bodies' dots sorted. Two passes: 2 + 3 + 4 = 9 dots and
-# three exp passes over a score tile; one pass: 2 + 5 and two.
+# three exp passes over a score tile; one pass: 2 + 5 and two. Two tiles a
+# side on the triangular grid are one pass over the head by the rule (PR
+# 42), and the two kernels where the static argument pins them.
 _BODY_PATHS = {
     'rectangular': dict(block=128, causal=False, dots=[2, 3, 4]),
-    'triangular': dict(block=128, causal=True, dots=[2, 3, 4]),
-    'one_pass': dict(block=None, causal=False, dots=[2, 5]),
-    'one_pass_causal': dict(block=None, causal=True, dots=[2, 5]),
+    'triangular': dict(block=128, causal=True, dots=[2, 3, 4], pin=None),
+    'head': dict(block=128, causal=True, dots=[2, 5],
+                 one='_bwd_head_kernel'),
+    'one_pass': dict(block=None, causal=False, dots=[2, 5],
+                     one='_bwd_fused_kernel'),
+    'one_pass_causal': dict(block=None, causal=True, dots=[2, 5],
+                            one='_bwd_fused_kernel'),
 }
 
 
@@ -485,23 +535,26 @@ def test_kernel_dots_take_the_inputs_dtype(dtype, path):
     bodies (2 + 3 + 4 with two backward kernels, 2 + 5 with one) take
     operands of the refs' dtype and give float32; with float32 in nothing
     is cast at all; with bf16 in p and ds are cast down as dot operands
-    and nothing is cast up, but for the one-pass body's k tile on its way
+    and nothing is cast up, but for the one-pass bodies' k tile on its way
     through the transposition that dq = (k^T ds^T)^T needs. No score-sized
     tile is transposed on any path: the transposed-score bodies turn round
-    their lane-broadcast float32 row statistics, and the one-pass body k
+    their lane-broadcast float32 row statistics, and the one-pass bodies k
     and dq^T besides. Each backward body takes exp of one score tile: the
-    one-pass backward computes s, p, dp and ds once."""
+    one-pass backwards compute s, p, dp and ds once."""
     c = _BODY_PATHS[path]
     bodies = _kernel_bodies(jnp.dtype(dtype), c['causal'], c['block'],
-                            c['block'])
+                            c['block'], pin=c.get('pin', 'rule'))
     assert len(bodies) == len(c['dots']), [n for n, _ in bodies]
-    assert all(('_tri' in name) == (path == 'triangular')
-               for name, _ in bodies)
-    if path.startswith('one_pass'):
-        assert [n for n, _ in bodies][1:] == ['_bwd_fused_kernel']
+    assert all(('_tri' in name) == (path in ('triangular', 'head'))
+               for name, _ in bodies if name != '_bwd_head_kernel')
+    if 'one' in c:
+        assert [n for n, _ in bodies][1:] == [c['one']]
+    # the k tile a one-pass body turns round: a head's 256 rows, or a
+    # 128-tile of them
+    k_tile = {'_bwd_fused_kernel': (256, 64), '_bwd_head_kernel': (128, 64)}
     n_dots = []
     for name, eqns in bodies:
-        fused = name == '_bwd_fused_kernel'
+        fused = name in k_tile
         dots = [e for e in eqns if e.primitive.name == 'dot_general']
         n_dots.append(len(dots))
         for e in dots:
@@ -527,7 +580,7 @@ def test_kernel_dots_take_the_inputs_dtype(dtype, path):
             up = [c for c in floats if c[:2] == ('bfloat16', 'float32')]
             assert floats and {c[:2] for c in floats} - {
                 ('bfloat16', 'float32')} == {('float32', 'bfloat16')}, name
-            assert [c[2] for c in up] == ([(256, 64)] if fused else []), name
+            assert [c[2] for c in up] == ([k_tile[name]] if fused else []), name
         else:
             assert not floats, (name, floats)
     assert sorted(n_dots) == c['dots']
@@ -576,10 +629,14 @@ _ONE_PASS_CASES = {
 }
 
 
-def _count_passes():
+def _rose(before, after):
+    return {k: int(after[k] - before[k]) for k in after}
+
+
+def _count_tiles():
     from paddle_tpu import obs
-    return {p: obs.counter('flash.backward', passes=p).value
-            for p in ('one', 'two')}
+    return {g: obs.counter('flash.tiles', grid=g).value
+            for g in ('band', 'triangle', 'rect')}
 
 
 @pytest.mark.parametrize('case', sorted(_ONE_PASS_CASES))
@@ -611,56 +668,71 @@ def test_one_pass_backward_matches_reference_float32(case):
     before = _count_passes()
     got = grads(flash)
     after = _count_passes()
-    assert (after['one'] - before['one'], after['two'] - before['two']) \
-        == (1, 0)
+    assert _rose(before, after) == {'tile': 1, 'head': 0, 'two': 0}
     for a, b, name in zip(got, grads(ref), 'qkv'):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=3e-4, atol=3e-4, err_msg=name)
 
 
-@pytest.mark.parametrize('causal', [False, True], ids=['full', 'causal'])
+@pytest.mark.parametrize('schedule,causal', [
+    ('tile', False), ('tile', True), ('head', True)],
+    ids=['full', 'causal', 'head_causal'])
 @pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
-def test_one_pass_equals_two_passes(dtype, causal):
-    """The same inputs through both schedules (512 x 512 scores: one pass
-    in sub-tiles of 256 against the triangular or rectangular grid of
-    256-tiles) agree to the dots' rounding: the arithmetic is the same,
-    the order of the sums over blocks is not."""
-    import importlib
-    fa = importlib.import_module('paddle_tpu.ops.flash_attention')
+def test_one_pass_equals_two_passes(dtype, schedule, causal):
+    """The same inputs through the schedules (512 x 512 scores: one pass
+    in sub-tiles of 256, or one pass over the head's three tile pairs,
+    against the triangular or rectangular grid of 256-tiles) agree to the
+    dots' rounding: the arithmetic is the same, the order of the sums
+    over blocks is not."""
+    fa = _fa()
     q, k, v, kb = _rand_qkv(B=1, H=2, Tq=512, Tk=512, D=32, seed=41)
     q, k, v = [jnp.asarray(x, jnp.dtype(dtype)) for x in (q, k, v)]
-    q, k, v, kb, scale, bq, bk, one_pass, interp, _, _ = fa._prep(
+    q, k, v, kb, scale, bq, bk, chosen, interp, _, _ = fa._prep(
         q, k, v, jnp.asarray(kb), None, 256, 256, True, causal=causal)
-    assert not one_pass and (bq, bk) == (256, 256)
+    assert chosen == ('head' if causal else None) and (bq, bk) == (256, 256)
     o, lse = fa._fwd_call(q, k, v, kb, causal, scale, bq, bk, interp)
     r = np.random.RandomState(42)
     do = jnp.asarray(r.randn(*o.shape), o.dtype)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[..., None], delta.shape + (fa.LANES,))
     args = (q, k, v, kb, do, lse, delta, causal, scale, bq, bk)
-    one = fa._bwd_call(*args, True, interp)
-    two = fa._bwd_call(*args, False, interp)
+    one = fa._bwd_call(*args, schedule, interp)
+    two = fa._bwd_call(*args, None, interp)
     for a, b, name in zip(one, two, ('dq', 'dk', 'dv')):
         assert a.dtype == b.dtype == jnp.dtype(dtype)
         assert _rel_norm(a, b) <= (BF16_EPS if dtype == 'bfloat16'
                                    else 1e-6), name
+    if schedule == 'head':
+        # the pair's arithmetic is the dk/dv kernel's own, step for step
+        assert all(np.array_equal(np.asarray(a), np.asarray(b))
+                   for a, b in zip(one[1:], two[1:]))
 
 
-@pytest.mark.parametrize('case,kw,bwd_calls', [
-    ('one_tile', dict(T=256), 1),
-    ('one_tile_causal', dict(T=256, causal=True), 1),
-    ('causal_1024_in_sub_tiles', dict(T=1024, causal=True), 1),
-    ('two_tiles', dict(T=2048), 2),
-    ('causal_2048_triangular', dict(T=2048, causal=True), 2),
-    ('block_q_forced_below_T', dict(T=256, block_q=128), 2),
+# 2 x 2 x T x 64 bf16: a head's dq is 8 x 64 x T bytes of VMEM (float32 and
+# the two bf16 output buffers), 64 MiB at 131072 positions
+@pytest.mark.parametrize('case,kw,schedule', [
+    ('one_tile', dict(T=256), 'tile'),
+    ('one_tile_causal', dict(T=256, causal=True), 'tile'),
+    ('causal_1024_in_sub_tiles', dict(T=1024, causal=True), 'tile'),
+    ('two_tiles', dict(T=2048), None),
+    ('causal_2048_triangular', dict(T=2048, causal=True), 'head'),
+    ('block_q_forced_below_T', dict(T=256, block_q=128), None),
     ('blocks_forced_causal_1024', dict(T=1024, causal=True, block_q=512,
-                                       block_k=512), 2),
-    ('cross_lengths_two_key_tiles', dict(T=256, Tk=2048), 2),
+                                       block_k=512), 'head'),
+    ('oblong_tiles_causal_rectangular', dict(T=1024, causal=True,
+                                             block_q=256, block_k=512), None),
+    ('cross_lengths_two_key_tiles', dict(T=256, Tk=2048), None),
+    ('cross_lengths_causal', dict(T=1024, Tk=2048, causal=True), None),
+    ('causal_65536_fits_vmem', dict(T=65536, causal=True), 'head'),
+    ('causal_131072_over_the_vmem_budget', dict(T=131072, causal=True), None),
 ], ids=lambda x: x if isinstance(x, str) else None)
-def test_backward_routing_reads_the_shapes(case, kw, bwd_calls):
+def test_backward_routing_reads_the_shapes(case, kw, schedule):
     """One pallas_call in the backward where a head's scores are one tile
-    (the forward's, or the table's largest when nobody forced a tile), two
-    otherwise; the counter says the same, once per call per lowering."""
+    (the forward's, or the table's largest when nobody forced a tile) or
+    where the head is causal self-attention on the triangular grid and
+    its dq fits the VMEM budget, two otherwise (the rectangular grid; a
+    head too long); the counter says the same, once per call per
+    lowering."""
     def trace():
         return [name for name, _ in _kernel_bodies(
             jnp.bfloat16, kw.get('causal', False), kw.get('block_q'),
@@ -669,28 +741,90 @@ def test_backward_routing_reads_the_shapes(case, kw, bwd_calls):
     before = _count_passes()
     names = trace()
     after = _count_passes()
-    assert len(names) == 1 + bwd_calls, names
-    assert ('_bwd_fused_kernel' in names) == (bwd_calls == 1)
-    want = {'one': int(bwd_calls == 1), 'two': int(bwd_calls == 2)}
-    assert {p: after[p] - before[p] for p in want} == want
+    assert names[1:] == {
+        'tile': ['_bwd_fused_kernel'], 'head': ['_bwd_head_kernel']}.get(
+        schedule, names[1:]) and len(names) == (2 if schedule else 3), names
+    want = {'tile': 0, 'head': 0, 'two': 0}
+    want[schedule or 'two'] = 1
+    assert _rose(before, after) == want
     if case == 'one_tile':      # a tile the caller passes is a forced tile
         assert len(_kernel_bodies(jnp.bfloat16, False, 128, None, 256)) == 3
 
 
 def test_flash_backward_counts_once_per_call_per_lowering():
-    def two_calls(q, k, v):
+    def three_calls(q, k, v):
         o = ops.flash_attention(q, k, v, interpret=True)
         o = ops.flash_attention(o, k, v, causal=True, block_q=128,
                                 block_k=128, interpret=True)
+        o = ops.flash_attention(o, k, v, block_q=128, block_k=128,
+                                interpret=True)
         return o.astype(jnp.float32).sum()
 
-    step = jax.jit(jax.grad(two_calls, argnums=(0, 1, 2)))
+    step = jax.jit(jax.grad(three_calls, argnums=(0, 1, 2)))
     x = jnp.ones((1, 1, 256, 8), jnp.bfloat16)
     before = _count_passes()
     for _ in range(3):          # three steps, one lowering
         step(x, x, x)
-    after = _count_passes()
-    assert {p: after[p] - before[p] for p in after} == {'one': 1, 'two': 1}
+    assert _rose(before, _count_passes()) == {'tile': 1, 'head': 1, 'two': 1}
+
+
+# ---------------------------------------------------------------------------
+# one pass over a head of many tiles (PR 42): dq in VMEM across the key loop
+# ---------------------------------------------------------------------------
+
+# T = 512 in 128-tiles: a triangle of 4 x 4 tiles, 10 pairs. A window of
+# 129 ends on a tile's edge (128 keys back: a band of two tiles, 7 pairs),
+# one of 200 inside the next tile and one of 257 on its edge (a band of
+# three, 9 pairs); one of 400 reaches every tile, so the grid is the
+# triangle and the window the mask alone. Batch 2 with a key bias is the
+# stale-block hazard of the strategy note.
+_HEAD_CASES = {
+    'triangle_4x4': dict(pairs=10),
+    'triangle_4x4_lse_cotangent': dict(pairs=10, lse=True),
+    'triangle_pads_450_to_512': dict(pairs=10, T=450),
+    'band_ends_on_a_tile_edge': dict(pairs=7, window=129),
+    'band_ends_inside_a_tile': dict(pairs=9, window=200),
+    'band_of_three_lse_cotangent': dict(pairs=9, window=257, lse=True),
+    'window_over_every_tile': dict(pairs=10, window=400, grid='triangle'),
+}
+
+
+@pytest.mark.parametrize('case', sorted(_HEAD_CASES))
+def test_head_backward_matches_reference_float32(case):
+    """Float32 in: the float32 tolerances of the two-kernel tests,
+    unedited (3e-4), with batch 2, a key bias and a padded tail; the
+    counters say that the rule took one pass over the head and how many
+    tile pairs its two grids visit."""
+    c = _HEAD_CASES[case]
+    T, window = c.get('T', 512), c.get('window')
+    q, k, v, kb = _rand_qkv(B=2, H=2, Tq=T, Tk=T, D=16, seed=61)
+    kb[:, T - T // 8:] = -1e9
+    bias = jnp.asarray(kb)
+
+    def ref(q, k, v):
+        return _ref_o_lse(q, k, v, bias, True, window)
+
+    def flash(q, k, v):
+        return ops.flash_attention_lse(q, k, v, key_bias=bias, causal=True,
+                                       window=window, block_q=128,
+                                       block_k=128, interpret=True)
+
+    def grads(fn):
+        def f(q, k, v):
+            o, lse = fn(q, k, v)
+            val = jnp.sum(o * jnp.cos(o))
+            return val + jnp.sum(jnp.sin(lse)) if c.get('lse') else val
+        return jax.grad(f, argnums=(0, 1, 2))(*map(jnp.asarray, (q, k, v)))
+
+    passes, tiles = _count_passes(), _count_tiles()
+    got = grads(flash)
+    assert _rose(passes, _count_passes()) == {'tile': 0, 'head': 1, 'two': 0}
+    grid = c.get('grid', 'band' if window else 'triangle')
+    assert _rose(tiles, _count_tiles()) == {
+        g: 2 * c['pairs'] * (g == grid) for g in tiles}
+    for a, b, name in zip(got, grads(ref), 'qkv'):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=3e-4, atol=3e-4, err_msg=name)
 
 
 # ---------------------------------------------------------------------------
@@ -706,19 +840,22 @@ def _window_inputs(T, H=2, Hkv=None, D=16, B=1, seed=51):
     return q, k, v, do
 
 
-def _window_pair(q, k, v, do, window, tile):
+def _window_pair(q, k, v, do, window, tile, schedule='rule'):
     """((loss, (dq, dk, dv)) of the kernels, of the oracle): the kernels
     under the interpreter in `tile`-blocks, key-value heads repeated over
-    their group as the op's rule repeats them."""
+    their group as the op's rule repeats them; the backward as the rule
+    schedules it, or as `schedule` pins it."""
     group = q.shape[1] // k.shape[1]
 
     def wide(t):
         return jnp.repeat(t, group, axis=1)
 
     def flash(q, k, v):
-        o = ops.flash_attention(q, wide(k), wide(v), causal=True,
-                                window=window, block_q=tile, block_k=tile,
-                                interpret=True)
+        kw = dict(causal=True, window=window, block_q=tile, block_k=tile)
+        if schedule == 'rule':
+            o = ops.flash_attention(q, wide(k), wide(v), interpret=True, **kw)
+        else:
+            o = _flash_as(schedule, q, wide(k), wide(v), **kw)[0]
         return jnp.sum(o * do)
 
     def oracle(q, k, v):
@@ -745,8 +882,14 @@ _WINDOW_CASES = {
 }
 
 
-@pytest.mark.parametrize('case', sorted(_WINDOW_CASES))
-def test_window_forward_and_gradients_match_reference(case):
+# every case on the triangular grid under both of its backwards: the one
+# pass over the head that the rule gives it (PR 42), and the two kernels
+@pytest.mark.parametrize('case,schedule', [
+    (case, schedule) for case in sorted(_WINDOW_CASES)
+    for schedule in (('head', None) if 'tile' not in _WINDOW_CASES[case]
+                     else ('rule',))],
+    ids=lambda x: {None: 'two_passes'}.get(x, x))
+def test_window_forward_and_gradients_match_reference(case, schedule):
     c = _WINDOW_CASES[case]
     T, tile = c.get('T', 640), c.get('tile', 128)
     q, k, v, do = _window_inputs(T)
@@ -758,7 +901,7 @@ def test_window_forward_and_gradients_match_reference(case):
         got = jax.value_and_grad(flash, (0, 1, 2))(q, k, v)
         _, want = _window_pair(q, k, v, do, c['window'], None)
     else:
-        got, want = _window_pair(q, k, v, do, c['window'], tile)
+        got, want = _window_pair(q, k, v, do, c['window'], tile, schedule)
     assert abs(float(got[0]) - float(want[0])) <= 3e-4 * T
     # against the cotangent's norm where a gradient is zero (a window of
     # one key: the softmax of one score is 1 whatever q and k are)
@@ -779,22 +922,16 @@ def test_window_is_not_the_window_one_key_off():
 
 
 def test_window_of_the_whole_row_is_plain_causal_on_the_same_grid():
-    from paddle_tpu import obs
     q, k, v, do = _window_inputs(640)
-
-    def tiles():
-        return {g: obs.counter('flash.tiles', grid=g).value
-                for g in ('band', 'triangle', 'rect')}
-
-    before = tiles()
+    before = _count_tiles()
     whole, _ = _window_pair(q, k, v, do, 640, 128)
-    after = tiles()
+    after = _count_tiles()
     plain, _ = _window_pair(q, k, v, do, None, 128)
     assert float(whole[0]) == float(plain[0])
     for a, b in zip(whole[1], plain[1]):
         assert np.array_equal(np.asarray(a), np.asarray(b))
-    # the triangle of five tiles, forward + dq + dk/dv; no band
-    assert after['triangle'] - before['triangle'] == 3 * 15
+    # the triangle of five tiles, forward + the one pass; no band
+    assert after['triangle'] - before['triangle'] == 2 * 15
     assert after['band'] == before['band']
 
 
@@ -868,7 +1005,8 @@ def test_band_maps_visit_each_admitted_tile_once(n, tile, window):
 
 def test_flash_tiles_counts_the_band():
     """A lowering says off the chip which grid a call took and how many
-    tile pairs a head its three grids visit."""
+    tile pairs a head its grids visit (two where the backward is one pass
+    over the head, three where it is dq and dk/dv)."""
     from paddle_tpu import obs
 
     def read():
@@ -883,8 +1021,8 @@ def test_flash_tiles_counts_the_band():
     ops.flash_attention(q, k, v, causal=True, window=192, block_q=128,
                         block_k=128, interpret=True)
     tiles1, calls1 = read()
-    # rows of 1, 2, 3, 3, 3 tiles: 12 pairs, in each of three grids
-    assert tiles1['band'] - tiles0['band'] == 3 * 12
+    # rows of 1, 2, 3, 3, 3 tiles: 12 pairs, forward and the one pass
+    assert tiles1['band'] - tiles0['band'] == 2 * 12
     assert calls1['band'] - calls0['band'] == 1
     assert tiles1['triangle'] == tiles0['triangle']
     ops.flash_attention(q, k, v, causal=True, window=192, block_q=128,

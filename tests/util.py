@@ -1,5 +1,5 @@
 """Shared test helpers: fresh programs per test, a held expert layer's
-paths forced or poisoned."""
+paths forced or poisoned, the flash backward's schedule counters."""
 import contextlib
 
 import jax.numpy as jnp
@@ -47,3 +47,15 @@ def held_way(monkeypatch, way):
     else:
         monkeypatch.setattr(moe_ops, '_held_blocks' if way == 'compact'
                             else '_compact_moe', nan_path)
+
+
+def flash_schedules():
+    """Attention calls by the backward's schedule as the lowering counts
+    them (`flash.backward`): one pass over a 'tile' or over a 'head', or
+    'two' passes."""
+    from paddle_tpu import obs
+    return {'tile': obs.counter('flash.backward', passes='one',
+                                span='tile').value,
+            'head': obs.counter('flash.backward', passes='one',
+                                span='head').value,
+            'two': obs.counter('flash.backward', passes='two').value}

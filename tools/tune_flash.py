@@ -9,18 +9,23 @@ paddle_tpu/ops/flash_attention.py to edit. Run on TPU:
 
 --parts also times the kernels ALONE (one `part ...` line each, with the
 time per grid step): the forward, the one-pass backward (`bwd`) where the
-default tiles make a head's scores one tile, and the two kernels (`dq`,
-`dkv`) it replaced there at the same tiles; what to read before and after
-a change to a kernel body. --tile N forces the parts' tiles: with --causal
---seq 1024, --tile 1024 is one masked tile in one pass and --tile 512 the
-triangular grid's two kernels, against the default's one pass in 512
-sub-tiles. --window N (with --causal --parts) times the same kernels on
-the BAND of tiles a sliding window touches (PR 37): `--batch 1 --heads 28
---seq 16384 --dim 128 --causal --parts --no-sweep` with and without
-`--window 4096` is smallthinker_s16384's windowed and global call.
+rule gives one (a head's scores in one tile, or a causal head of many on
+the triangular grid or its band with its dq in VMEM: the line says which,
+and for the second the VMEM bytes the call holds and the limit it
+states), and the two kernels (`dq`, `dkv`) at the same tiles, with the
+largest difference between the two schedules' gradients; what to read
+before and after a change to a kernel body. --tile N forces the parts'
+tiles: with --causal --seq 1024, --tile 1024 is one masked tile in one
+pass and --tile 512 the triangular grid, against the default's one pass
+in 512 sub-tiles. --window N (with --causal --parts) times the same
+kernels on the BAND of tiles a sliding window touches (PR 37): `--batch 1
+--heads 28 --seq 16384 --dim 128 --causal --parts --no-sweep` with and
+without `--window 4096` is smallthinker_s16384's windowed and global call,
+`--batch 1 --heads 20 --seq 8192 --dim 256 --causal` glm47flash_s8192's.
 docs/perf.md has the last sweep's rows and the commands that gave them.
 """
 import argparse
+import functools
 import itertools
 import os
 import sys
@@ -34,17 +39,18 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def time_parts(q, k, v, causal, iters, tile=None, window=None):
     """[(kernel, seconds per call, grid steps per call)] of the forward,
-    the one-pass backward where the tiles allow it, and the dq and dk/dv
+    the one-pass backward where the rule gives one, and the dq and dk/dv
     kernels alone, at the default tiles or at `tile`. The dq and dk/dv
     chains consume one kernel's outputs only, so XLA removes the other
     call."""
     import importlib
+    import jax
     import jax.numpy as jnp
     from paddle_tpu.utils.timing import time_chained
     # the package's attribute of that name is the function
     fa = importlib.import_module('paddle_tpu.ops.flash_attention')
     window = fa._window_of(window, causal, q.shape[2])
-    q, k, v, kb, scale, bq, bk, one_pass, interp, _, _ = fa._prep(
+    q, k, v, kb, scale, bq, bk, schedule, interp, _, _ = fa._prep(
         q, k, v, None, None, tile, tile, False, causal=causal,
         window=window)
     o, lse = fa._fwd_call(q, k, v, kb, causal, scale, bq, bk, interp, window)
@@ -54,36 +60,56 @@ def time_parts(q, k, v, causal, iters, tile=None, window=None):
     def nudge(x, dx):
         return x + (1e-6 * dx).astype(x.dtype)
 
-    def bwd(q, k, v, one_pass):  # the cotangent is o itself: bf16, full rank
-        return fa._bwd_call(q, k, v, kb, o, lse, delta, causal, scale,
-                            bq, bk, one_pass, interp, window)
+    # what a step only reads is an argument of the timed program
+    # (time_chained's consts), not a constant compiled into it
+    consts = dict(q=q, k=k, v=v, kb=kb, o=o, lse=lse, delta=delta)
 
-    def fwd_step(x):
+    def bwd(schedule, q, k, v, kb, o, lse, delta):
+        # the cotangent is o itself: bf16, full rank
+        return fa._bwd_call(q, k, v, kb, o, lse, delta, causal, scale,
+                            bq, bk, schedule, interp, window)
+
+    def fwd_step(x, k, v, kb, **_):
         return (nudge(x[0], fa._fwd_call(x[0], k, v, kb, causal, scale,
                                          bq, bk, interp, window)[0]),)
 
-    def bwd_step(x):
-        return tuple(nudge(a, d) for a, d in zip(x, bwd(*x, True)))
+    def bwd_step(x, q, k, v, **rest):
+        return tuple(nudge(a, d)
+                     for a, d in zip(x, bwd(schedule, *x, **rest)))
 
-    def dq_step(x):
-        return (nudge(x[0], bwd(x[0], k, v, False)[0]),)
+    def dq_step(x, q, **rest):
+        return (nudge(x[0], bwd(None, x[0], **rest)[0]),)
 
-    def dkv_step(x):
-        _, dk, dv = bwd(q, x[0], x[1], False)
+    def dkv_step(x, k, v, **rest):
+        _, dk, dv = bwd(None, k=x[0], v=x[1], **rest)
         return nudge(x[0], dk), nudge(x[1], dv)
 
-    B, H, T, _ = q.shape
+    B, H, T, D = q.shape
     nq = T // bq
     blocks = fa._tile_pairs(nq, fa._band(window, bk, nq)) \
         if fa._use_tri(causal, T, T, bq, bk) else nq * (T // bk)
     parts = [('fwd', fwd_step, (q,), blocks)]
-    if one_pass:
-        parts.append(('bwd', bwd_step, (q, k, v), 1))
+    said = 'two passes'
+    if schedule:
+        parts.append(('bwd', bwd_step, (q, k, v),
+                      blocks if schedule == 'head' else 1))
+        said = 'one pass (sub-tiles of %d)' % bq
+        if schedule == 'head':
+            limit = fa._head_vmem_limit(T, D, bq, bk, q.dtype.itemsize)
+            said = ('one pass over the head: %.2f MiB of VMEM held, limit '
+                    '%.2f MiB stated' % (
+                        (limit - fa._MOSAIC_SCOPE_BYTES) / 2 ** 20,
+                        limit / 2 ** 20))
+        one, two = (jax.jit(functools.partial(bwd, s))(**consts)
+                    for s in (schedule, None))
+        print('one pass against two, largest difference: '
+              + ', '.join('%s %.3g (of %.3g)' % (
+                  n, abs(a.astype(jnp.float32) - b.astype(jnp.float32)).max(),
+                  abs(b.astype(jnp.float32)).max())
+                  for n, a, b in zip(('dq', 'dk', 'dv'), one, two)))
     parts += [('dq', dq_step, (q,), blocks), ('dkv', dkv_step, (k, v), blocks)]
-    print('parts at tiles %d x %d, backward in %s' % (
-        bq, bk, 'one pass (sub-tiles of %d)' % bq if one_pass
-        else 'two passes'))
-    return [(name, time_chained(step, x, iters), B * H * n)
+    print('parts at tiles %d x %d, backward in %s' % (bq, bk, said))
+    return [(name, time_chained(step, x, iters, consts=consts), B * H * n)
             for name, step, x, n in parts]
 
 
