@@ -2,8 +2,8 @@
 
 The executor donates every persistable input buffer to the jitted step
 when (and only when) the program's TOP-LEVEL ops write at least one
-persistable (executor._CompiledStep): a mutating step updates params in
-place in HBM and re-exposes every donated input as an output; a read-only
+persistable (StepArtifact): a mutating step updates params in place in
+HBM and re-exposes every donated input as an output; a read-only
 step donates nothing, because donation would invalidate the param buffers
 under concurrent runs over a shared scope (the PR-3 serving bug).
 
@@ -28,8 +28,8 @@ __all__ = ['run_pass', 'persistable_write_set', 'executor_write_set',
 
 def executor_write_set(program):
     """Persistable names the TOP-LEVEL block writes — byte-for-byte the
-    scan executor._CompiledStep bases its donation decision on (defined
-    here so the executor and the analyzer can never drift apart)."""
+    scan StepArtifact bases its donation decision on (defined here so
+    the executor and the analyzer can never drift apart)."""
     persistable = {v.name for v in program.list_vars() if v.persistable}
     produced = set()
     for op in program.global_block().ops:

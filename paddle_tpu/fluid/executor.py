@@ -22,22 +22,25 @@ JAX_COMPILATION_CACHE_DIR) reuses XLA executables across processes (zero
 cold compiles on restart).
 """
 import collections
+import contextlib
 import os
 import threading
 import time
+import warnings
+from typing import Any, NamedTuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
 from .. import obs
 from ..utils import compile_cache
 from . import core
-from . import lowering
 from . import ops_impl  # noqa: F401  (registers all rules)
-from .framework import default_main_program, Program
-from .lowering import SeqValue, Ctx
+from .framework import default_main_program
+from .lowering import SeqValue
 
 # ZeRO floor (elements): tensors smaller than this keep their tp-only
 # layout instead of ('tp','dp')-product sharding — mirrors
@@ -63,20 +66,15 @@ def _remat_capture_enabled():
         '0', 'off', 'false', 'no')
 
 
-import contextlib as _contextlib
-
-
-import threading as _threading
-
 # fd 2 is process-global state: two overlapping captures (two Executors
 # compiling on different threads) would interleave dup2 save/restore and
 # could leave stderr pointing at a deleted temp file forever. One capture
 # at a time; a contended compile simply runs uncaptured (missing one
 # remat detection beats corrupting fd 2).
-_CAPTURE_FD2_LOCK = _threading.Lock()
+_CAPTURE_FD2_LOCK = threading.Lock()
 
 
-@_contextlib.contextmanager
+@contextlib.contextmanager
 def _capture_fd2(sink):
     """Tee C++-level stderr (fd 2) into `sink` (a list of bytes) for the
     duration, re-emitting everything to the real stderr afterwards —
@@ -283,9 +281,6 @@ def _switch_scope(scope):
     return prev
 
 
-import contextlib
-
-
 @contextlib.contextmanager
 def scope_guard(scope):
     prev = _switch_scope(scope)
@@ -306,6 +301,13 @@ def _spec_key(spec):
     return str(entries)
 
 
+def _named_sharding(v):
+    """A mesh-placed array's NamedSharding, else None."""
+    if isinstance(v, jax.Array) and isinstance(v.sharding, NamedSharding):
+        return v.sharding
+    return None
+
+
 def _as_fetch_name(f):
     from .framework import Variable
     if isinstance(f, Variable):
@@ -313,18 +315,37 @@ def _as_fetch_name(f):
     return str(f)
 
 
-# The compiled step is a first-class artifact now (fluid/step_artifact.py):
-# one object per (program, feed-sig, fetch) owning the optimized program,
-# the memory/donation plan, the NamedSharding trees, the RNG-stream
-# policy, the feed/fetch signature, and the state_dict seam — with run /
-# run_bundle / StepHandle / the serving dispatch as thin drivers over it.
 from .step_artifact import (StepArtifact, _feed_signature, _is_annotated,
-                            _nan_inf_hook, stable_signature as _stable_sig)
+                            stable_signature as _stable_sig)
 
-# migration alias (docs/architecture.md#step-artifact): external code that
-# poked the executor internals via `_CompiledStep` keeps importing it here.
-_CompiledStep = StepArtifact
 
+class _StepKey(NamedTuple):
+    """What `Executor._place_and_key` derives every step: the compiled
+    step's cache key and everything it was derived from."""
+    key: tuple
+    key_id: str             # short id of `key` in telemetry
+    feed_vals: dict         # the placed feed
+    feed_bytes: int
+    feed_sig: tuple
+    fetch_names: list
+    persist_in: tuple       # scope-initialized persistables, sorted
+    persist_shardings: dict
+    jit_shardings: Any      # the annotation path's trees, else None
+    dist_mesh: Any
+    amp: bool
+    quant: bool
+    guard: bool
+    opt: str
+
+
+class _Prepared(NamedTuple):
+    """What `Executor._prepare` returns; `lookup` is {'outcome', 'key',
+    'entries'}."""
+    compiled: StepArtifact
+    feed_vals: dict
+    persist: dict
+    lookup: dict
+    feed_bytes: int
 
 
 # Process-wide executor telemetry (docs/observability.md). Shared,
@@ -556,9 +577,9 @@ class StepHandle(object):
     """
 
     __slots__ = ('_exe', '_compiled', '_scope', '_program', '_donated',
-                 '_readonly', '_key', '_first', 'steps', 'key_id')
+                 '_readonly', '_key', '_first', '_lookup', 'steps')
 
-    def __init__(self, exe, compiled, scope, program, persist, key_id):
+    def __init__(self, exe, compiled, scope, program, persist, lookup):
         self._exe = exe
         self._compiled = compiled
         self._scope = scope
@@ -569,9 +590,9 @@ class StepHandle(object):
         self._key = jax.random.key(0)
         # a compiled step already first-called via run() (warmup) needs
         # no compile-classification probe here
-        self._first = not getattr(compiled, '_obs_compiled', False)
+        self._first = not compiled._obs_compiled
+        self._lookup = lookup
         self.steps = 0
-        self.key_id = key_id
 
     @property
     def state(self):
@@ -631,22 +652,21 @@ class StepHandle(object):
         # when to pay a host sync, and its steps record no spans to carry
         # them (Executor._read_device is run()'s and run_bundle()'s)
         if self._first:
-            (fetches, new_persist, health, _), _ = \
-                self._exe._timed_first_call(
-                    self._compiled._jitted, args, self.key_id, handle=True,
-                    aot_sig=self._exe._aot_sig_of(self._compiled),
-                    aot_entry='step')
-            self._compiled._obs_compiled = True
+            res, outcome = self._exe._timed_first_call(
+                self._compiled, 'step', args, self._lookup['key'],
+                handle=True)
             self._first = False
+            if outcome != 'compile':
+                self._lookup['outcome'] = outcome
         else:
-            fetches, new_persist, health, _ = self._compiled._jitted(*args)
-        for n, v in new_persist.items():
+            res = self._compiled(*args)
+        for n, v in res.new_persist.items():
             self._donated[n] = v
             self._scope._chain_set(n, v)
-        if health is not None:
-            self._exe._observe_health(self._program, health)
+        if res.health is not None:
+            self._exe._observe_health(self._program, res.health)
         self.steps += 1
-        return fetches
+        return res.fetches
 
 
 class Executor(object):
@@ -763,7 +783,6 @@ class Executor(object):
             try:
                 placed = jax.device_put(val, NamedSharding(mesh, spec))
             except ValueError as e:
-                import warnings
                 warnings.warn(
                     'sharding annotation %r on %r does not fit the mesh '
                     '%r (%s); replicating instead — program_lint --mesh '
@@ -808,7 +827,6 @@ class Executor(object):
             # and the gradient all-reduce is part of the compiled step, so
             # the Program path stays synchronous. The supported async
             # analogue is local SGD (parallel/local_sgd.py).
-            import warnings
             warnings.warn(
                 "DistributeTranspiler sync_mode=False: the TPU Program path "
                 "runs SYNCHRONOUS data-parallel (GSPMD all-reduce each "
@@ -915,7 +933,6 @@ class Executor(object):
                     if both is not None:
                         spec = both
                     else:
-                        import warnings
                         warnings.warn(
                             '%r keeps a tp-only layout %r (no remaining '
                             'dim divides dp=%d); its dp ZeRO sharding is '
@@ -1058,22 +1075,46 @@ class Executor(object):
                 want = np.dtype(var.dtype) if var.dtype != 'bfloat16' else jnp.bfloat16
                 if dv.dtype != want:
                     dv = dv.astype(want)
-            if dist_mesh is not None:
-                if _is_annotated(program):
-                    dv = self._annot_shard_feed(name, dv, dist_mesh,
-                                                program)
-                else:
-                    dv = self._dist_shard_feed(name, dv, dist_mesh)
+            if annot:
+                dv = self._annot_shard_feed(name, dv, dist_mesh, program)
+            elif dist_mesh is not None:
+                dv = self._dist_shard_feed(name, dv, dist_mesh)
             feed_vals[name] = dv
         return feed_vals
 
     def _prepare(self, program, feed, fetch_list, scope,
                  use_program_cache=True, verify_bundle=False, spans=False):
-        """Shared front half of run()/lowered_hlo(): device-place the feed,
-        resolve the (program, feed-sig, fetch) cache key, and build or fetch
-        the _CompiledStep. Returns (compiled, feed_vals, persist). `spans`
-        (run() and run_bundle() pass obs.enabled()) times the placement
-        and the feed as child spans of the caller's `executor.prepare`."""
+        """Shared front half of every path to a compiled step, three
+        functions by lifetime: `_place_and_key` runs every step,
+        `_build_step` once a cache key, `_bind_state` binds this call.
+        The lookup it returns (`outcome` 'hit' or 'miss') is also left as
+        `_last_cache_lookup`, a diagnostic for readers outside this class
+        (fluid/profiler.py, the serving warmup): the caller that makes an
+        entry's first call refines its `outcome` in place. `spans` (run()
+        and run_bundle() pass obs.enabled()) times the placement and the
+        feed as child spans of the caller's `executor.prepare`."""
+        k = self._place_and_key(program, feed, fetch_list, scope, spans)
+        compiled = self._cache.get(k.key) if use_program_cache else None
+        if compiled is None:
+            compiled = self._build_step(program, scope, k)
+            if use_program_cache:
+                self._cache[k.key] = compiled
+            outcome = 'miss'
+        else:
+            self._cache_hits += 1
+            outcome = 'hit'
+        persist = self._bind_state(program, scope, k, compiled,
+                                   verify_bundle)
+        lookup = self._last_cache_lookup = {
+            'outcome': outcome, 'key': k.key_id, 'entries': len(self._cache)}
+        return _Prepared(compiled, k.feed_vals, persist, lookup,
+                         k.feed_bytes)
+
+    def _place_and_key(self, program, feed, fetch_list, scope, spans):
+        """EVERY STEP: place the state and the feed, and derive the
+        compiled step's cache key from what was placed. Returns a
+        `_StepKey`: the key with everything derived on the way to it, so
+        the build of a miss derives nothing again."""
         with obs.span_if(spans, 'executor.placement') as sp:
             dist_mesh = self._ensure_dist_placement(program, scope)
             if sp is not None:
@@ -1090,10 +1131,8 @@ class Executor(object):
                 else:
                     fb += int(getattr(dv, 'nbytes', 0))
             _C_FEED_BYTES.inc(fb)
-            self._last_feed_bytes = fb
             if sp is not None:
                 sp.fields['bytes'] = fb
-        block = program.global_block()
 
         fetch_names = [_as_fetch_name(f) for f in fetch_list]
         feed_sig = tuple(sorted(_feed_signature(n, v) for n, v in feed_vals.items()))
@@ -1106,32 +1145,26 @@ class Executor(object):
         amp = amp_mod.is_amp(program)
         quant = quant_mod.is_quant(program)
         guard = bool(getattr(program, '_anomaly_guard', False))
-        from jax.sharding import NamedSharding
         persist_shardings = {}
         for n in persist_in:
-            v = scope._chain_get(n)
-            if isinstance(v, jax.Array) and isinstance(v.sharding,
-                                                       NamedSharding):
-                persist_shardings[n] = v.sharding
+            sh = _named_sharding(scope._chain_get(n))
+            if sh is not None:
+                persist_shardings[n] = sh
         shard_sig = tuple(sorted((n, _spec_key(s.spec), s.mesh)
                                  for n, s in persist_shardings.items()))
         # GSPMD annotation path: jit sharding trees from the ACTUAL
         # placements (persist values were just mesh-placed by
         # _annot_placement; feed values by _annot_shard_feed), plus the
         # raw annotations for persistables the step creates. The
-        # _CompiledStep derives its in/out shardings + donation vector
+        # StepArtifact derives its in/out shardings + donation vector
         # from these through the memory plan.
         jit_shardings = None
         if _is_annotated(program) and dist_mesh is not None:
-            def _sh_of(v):
-                if isinstance(v, jax.Array) and isinstance(
-                        v.sharding, NamedSharding):
-                    return v.sharding
-                return None
             jit_shardings = {
-                'persist': {n: _sh_of(scope._chain_get(n))
+                'persist': {n: persist_shardings.get(n)
                             for n in persist_in},
-                'feed': {n: _sh_of(v) for n, v in feed_vals.items()},
+                'feed': {n: _named_sharding(v)
+                         for n, v in feed_vals.items()},
                 'specs': {v.name: v.sharding for v in program.list_vars()
                           if v.persistable and getattr(v, 'sharding',
                                                        None)},
@@ -1151,143 +1184,124 @@ class Executor(object):
         # short stable-within-process id naming this compiled module in
         # telemetry (step spans, compiled_op_table's header)
         key_id = '%08x' % (hash(key) & 0xFFFFFFFF)
-        compiled = self._cache.get(key) if use_program_cache else None
-        if compiled is None:
-            self._cache_misses += 1
-            _C_MISSES.inc()
-            # under a mesh the arrays live on the MESH's devices, whatever
-            # the place says: kernel choice (ctx.platform) must follow
-            # where the data is, or a CPUPlace executor over a TPU mesh
-            # lowers flash_attention to the reference chain
-            plat = (dist_mesh.devices.flat[0].platform
-                    if dist_mesh is not None else self._device().platform)
-            # Ahead-of-lowering optimization (docs/passes.md):
-            # PADDLE_TPU_OPT={off,default,aggressive}, applied ONCE per
-            # compiled-step cache key exactly like verify — the steady
-            # state re-optimizes nothing. The ORIGINAL program is never
-            # mutated; the _CompiledStep lowers the optimized clone. An
-            # optimizer failure must never take down a training run:
-            # fall back to the unoptimized lowering, loudly.
-            # a quant-marked program REQUIRES the pass pipeline: unlike
-            # amp there is no ctx-flag fallback in the lowering, so
-            # honoring the mark can't be conditional on PADDLE_TPU_OPT
-            run_program, run_block = program, block
-            if opt != 'off' or quant:
+        return _StepKey(key, key_id, feed_vals, fb, feed_sig, fetch_names,
+                        persist_in, persist_shardings, jit_shardings,
+                        dist_mesh, amp, quant, guard, opt)
+
+    def _lowering_platform(self, dist_mesh):
+        """The platform a step's rules lower for (ctx.platform). Under a
+        mesh the arrays live on the MESH's devices, whatever the place
+        says: kernel choice must follow where the data is, or a CPUPlace
+        executor over a TPU mesh lowers flash_attention to the reference
+        chain. tools/aot_cell.py overrides this on its own instance to
+        lower a described chip's step on a host."""
+        if dist_mesh is not None:
+            return dist_mesh.devices.flat[0].platform
+        return self._device().platform
+
+    def _build_step(self, program, scope, k):
+        """ONCE A KEY, on a miss: optimise, build, probe, fall back. The
+        ladder: build the optimised clone and probe it; on any failure
+        warn, record `passes.error`, and build the Program as handed."""
+        from . import passes as passes_mod
+        self._cache_misses += 1
+        _C_MISSES.inc()
+        plat = self._lowering_platform(k.dist_mesh)
+
+        def build(run_program):
+            return StepArtifact(
+                run_program, run_program.global_block(), list(k.feed_vals),
+                k.fetch_names, k.persist_in, k.feed_sig, k.key_id, program,
+                lambda n: k.feed_vals.get(n, scope._chain_get(n)),
+                amp=k.amp, platform=plat,
+                persist_shardings=k.persist_shardings, mesh=k.dist_mesh,
+                guard=k.guard, jit_shardings=k.jit_shardings)
+
+        def fell_back(what, e, **stage):
+            warnings.warn(
+                '%s=%s: %s failed (%s: %s) — lowering the unoptimized '
+                'program' % (passes_mod.ENV_OPT, k.opt, what,
+                             type(e).__name__, e), RuntimeWarning)
+            obs.event('passes.error', key=k.key_id, **stage,
+                      error='%s: %s' % (type(e).__name__, e))
+
+        # Ahead-of-lowering optimization (docs/passes.md):
+        # PADDLE_TPU_OPT={off,default,aggressive}, applied ONCE per
+        # compiled-step cache key exactly like verify — the steady state
+        # re-optimizes nothing. The ORIGINAL program is never mutated; the
+        # StepArtifact lowers the optimized clone. An optimizer failure
+        # must never take down a training run: fall back to the
+        # unoptimized lowering, loudly. A quant-marked program REQUIRES
+        # the pass pipeline: unlike amp there is no ctx-flag fallback in
+        # the lowering, so honoring the mark can't be conditional on
+        # PADDLE_TPU_OPT.
+        optimized = program
+        if k.opt != 'off' or k.quant:
+            try:
+                optimized, _opt_report = passes_mod.optimize(
+                    program, feeds=set(k.feed_vals), fetches=k.fetch_names,
+                    level=k.opt if k.opt != 'off' else 'default',
+                    where='executor')
+            except Exception as e:
+                fell_back('program optimization', e)
+        # the Program -> jittable-step build (op walk, sparse plan,
+        # pipeline region checks); the XLA compile itself happens on the
+        # first call and is timed as executor.compile in run().
+        with obs.span('executor.lowering', key=k.key_id):
+            compiled = None
+            if optimized is not program:
                 try:
-                    run_program, _opt_report = passes_mod.optimize(
-                        program, feeds=set(feed_vals),
-                        fetches=fetch_names,
-                        level=opt if opt != 'off' else 'default',
-                        where='executor')
-                    run_block = run_program.global_block()
+                    compiled = build(optimized)
+                    # PROBE the optimized step by tracing it now (.lower()
+                    # = trace to StableHLO, no XLA compile, no execution,
+                    # no donation): a pass bug that slipped the
+                    # optimizer's def-use self-check — e.g. a rule
+                    # resolving env by attr name — must surface HERE,
+                    # where the fallback catches it, not on the first
+                    # run() call where nothing does. Costs one extra trace
+                    # per optimized cache key, a small slice of the XLA
+                    # compile the key pays anyway.
+                    compiled._jitted.lower(
+                        *compiled.plan.split({n: scope._chain_get(n)
+                                              for n in compiled.persist_in}),
+                        k.feed_vals, jax.random.key(0))
                 except Exception as e:
-                    import warnings
-                    warnings.warn(
-                        '%s=%s: program optimization failed (%s: %s) — '
-                        'lowering the unoptimized program'
-                        % (passes_mod.ENV_OPT, opt, type(e).__name__, e),
-                        RuntimeWarning)
-                    obs.event('passes.error', key=key_id,
-                              error='%s: %s' % (type(e).__name__, e))
-                    run_program, run_block = program, block
-            # the Program -> jittable-step build (op walk, sparse plan,
-            # pipeline region checks); the XLA compile itself happens on
-            # the first call and is timed as executor.compile in run().
-            # When the OPTIMIZED clone fails to build (a pass bug the
-            # optimizer's own self-check missed), fall back to the
-            # unoptimized program rather than killing the run.
-            with obs.span('executor.lowering', key=key_id):
-                try:
-                    compiled = _CompiledStep(
-                        run_program, run_block, list(feed_vals),
-                        fetch_names, persist_in, amp=amp,
-                        platform=plat,
-                        persist_shardings=persist_shardings,
-                        mesh=dist_mesh, guard=guard,
-                        jit_shardings=jit_shardings)
-                    if run_program is not program:
-                        # PROBE the optimized step by tracing it now
-                        # (.lower() = trace to StableHLO, no XLA compile,
-                        # no execution, no donation): a pass bug that
-                        # slipped the optimizer's def-use self-check —
-                        # e.g. a rule resolving env by attr name — must
-                        # surface HERE, where the fallback below catches
-                        # it, not on the first run() call where nothing
-                        # does. Costs one extra trace per optimized
-                        # cache key, a small slice of the XLA compile
-                        # the key pays anyway.
-                        probe_persist = {
-                            n: scope._chain_get(n)
-                            for n in compiled.persist_in}
-                        compiled._jitted.lower(
-                            *compiled.plan.split(probe_persist),
-                            feed_vals, jax.random.key(0))
-                except Exception as e:
-                    if run_program is program:
-                        raise
-                    import warnings
-                    warnings.warn(
-                        '%s=%s: lowering the optimized program failed '
-                        '(%s: %s) — lowering the unoptimized program'
-                        % (passes_mod.ENV_OPT, opt, type(e).__name__, e),
-                        RuntimeWarning)
-                    obs.event('passes.error', key=key_id, stage='lowering',
-                              error='%s: %s' % (type(e).__name__, e))
-                    compiled = _CompiledStep(
-                        program, block, list(feed_vals),
-                        fetch_names, persist_in, amp=amp,
-                        platform=plat,
-                        persist_shardings=persist_shardings,
-                        mesh=dist_mesh, guard=guard,
-                        jit_shardings=jit_shardings)
-            # sparse-embedding accounting (docs/embedding.md): the
-            # rows-touched-per-step bound is static given the feed
-            # signature, so resolve it once per compiled key — run()'s
-            # hot loop only bumps a counter
-            embed_rows = self._embed_rows_per_step(
-                compiled, feed_vals, scope)
-            compiled._embed_rows_step = sum(embed_rows.values())
-            # report ONLY the tables whose sparse path actually arms —
-            # a planned table with unresolvable ids falls back dense in
-            # _grad_setup and must not be claimed sparse here
-            active = sorted(w for w, r in embed_rows.items() if r)
-            if active:
-                obs.event(
-                    'embedding.update_rows', key=key_id, tables=active,
-                    rows_per_step=compiled._embed_rows_step,
-                    sharded=dist_mesh is not None)
-            # artifact identity (fluid/step_artifact.py): the placed-feed
-            # signature + short key id + SOURCE program (compiled.program
-            # may be the optimized clone) — what stable_signature() and
-            # the AOT manifest are derived from
-            compiled._feed_sig = feed_sig
-            compiled._key_id = key_id
-            compiled._source_program = program
-            obs.event('executor.artifact', key=key_id,
-                      feeds=len(feed_vals), fetches=len(fetch_names),
-                      persistables=len(persist_in),
-                      donates=len(compiled.donate_names),
-                      mesh=dist_mesh is not None)
-            if use_program_cache:
-                self._cache[key] = compiled
-            outcome = 'miss'
-        else:
-            self._cache_hits += 1
-            outcome = 'hit'
-        self._last_cache_lookup = {'outcome': outcome, 'key': key_id,
-                                   'entries': len(self._cache)}
+                    fell_back('lowering the optimized program', e,
+                              stage='lowering')
+                    compiled = None
+            if compiled is None:
+                compiled = build(program)
+        # report ONLY the tables whose sparse path actually arms — a
+        # planned table with unresolvable ids falls back dense in
+        # _grad_setup and must not be claimed sparse here
+        active = sorted(w for w, r in compiled._embed_rows.items() if r)
+        if active:
+            obs.event(
+                'embedding.update_rows', key=k.key_id, tables=active,
+                rows_per_step=compiled._embed_rows_step,
+                sharded=k.dist_mesh is not None)
+        obs.event('executor.artifact', key=k.key_id,
+                  feeds=len(k.feed_vals), fetches=len(k.fetch_names),
+                  persistables=len(k.persist_in),
+                  donates=len(compiled.donate_names),
+                  mesh=k.dist_mesh is not None)
+        return compiled
+
+    def _bind_state(self, program, scope, k, compiled, verify_bundle):
+        """BIND this call: the verifier's lookup, the persist dict the
+        step is called with, and the pin of its donated state."""
         # Ahead-of-lowering program verification (docs/analysis.md):
         # PADDLE_TPU_VERIFY={off,warn,error}, ONE analysis per cache key —
         # the steady-state loop never re-analyzes, so verify overhead
         # amortizes to zero (the analysis.verify span is the proof). The
         # env model is exact for this step: the real feed names, the real
-        # scope-initialized persistables, and the _CompiledStep's actual
+        # scope-initialized persistables, and the StepArtifact's actual
         # donation decision to cross-check.
         from . import analysis
         analysis.maybe_verify(
-            program, key=('verify', verify_bundle) + key, where='executor',
-            feeds=set(feed_vals), fetches=fetch_names,
-            initialized=set(persist_in) | set(feed_vals),
+            program, key=('verify', verify_bundle) + k.key, where='executor',
+            feeds=set(k.feed_vals), fetches=k.fetch_names,
+            initialized=set(k.persist_in) | set(k.feed_vals),
             donates=compiled.mutates_persist, bundle=verify_bundle,
             dead_ops=False)
         persist = {n: scope._chain_get(n) for n in compiled.persist_in}
@@ -1297,43 +1311,10 @@ class Executor(object):
         # arrays) would re-specialize the executable on call two — the
         # old run_bundle "warm twice" wart. Mesh-placed programs and
         # place-less executors own their placement and skip this.
-        pin_dev = self._device() if dist_mesh is None else None
+        pin_dev = self._device() if k.dist_mesh is None else None
         for n in compiled.pin_state(persist, pin_dev):
             scope._chain_set(n, persist[n])
-        return compiled, feed_vals, persist
-
-    @staticmethod
-    def _embed_rows_per_step(compiled, feed_vals, scope=None):
-        """Static per-step bound on table rows the sparse-embedding plan
-        touches: the total id count of the plan's lookups resolved from
-        the feed shapes — or the scope, matching _grad_setup's own
-        resolution order, so persist-resident id tensors count too (on-
-        device merge collapses duplicates, so the true unique count is
-        <= this; the dense path would touch the full vocab instead — the
-        number docs/perf.md's 49x claim is about). Mirrors _grad_setup's
-        ALL-OR-NOTHING activation per table: a table with ANY
-        unresolvable ids tensor falls back to the dense path there, so
-        it must contribute zero here — otherwise the counter/event/bench
-        would claim touched-rows updates while the [vocab, dim] dense
-        grad actually materializes. Returns {table: rows} with 0 for
-        fallen-back tables."""
-        per_table = {}
-        for w, plan in compiled.sparse_plan.items():
-            table_rows = 0
-            for _, ids_name, _ in plan['lookups']:
-                v = feed_vals.get(ids_name)
-                if v is None and scope is not None:
-                    v = scope._chain_get(ids_name)
-                if v is None:
-                    table_rows = 0
-                    break   # dense fallback for this whole table
-                arr = v.data if isinstance(v, SeqValue) else v
-                shp = tuple(getattr(arr, 'shape', ()))
-                if shp and shp[-1] == 1:
-                    shp = shp[:-1]
-                table_rows += int(np.prod(shp)) if shp else 1
-            per_table[w] = table_rows
-        return per_table
+        return persist
 
     # -- persistent-compile-cache probe -----------------------------------
 
@@ -1364,14 +1345,6 @@ class Executor(object):
         except OSError:
             return set()
 
-    def _aot_sig_of(self, compiled):
-        """The artifact's stable signature when the AOT set is armed
-        (None otherwise — the hash is only worth computing when a loaded
-        manifest could match it)."""
-        if not self._aot_sigs:
-            return None
-        return _stable_sig(compiled)
-
     def _aot_warmed(self, aot_sig, entry):
         """Did the loaded AOT manifest warm THIS entry point of the
         signature? `entry` is 'step' or ('bundle', K) — a blob exported
@@ -1381,17 +1354,18 @@ class Executor(object):
         if aot_sig is None or aot_sig not in (self._aot_sigs or ()):
             return False
         rec = (self._aot_entries or {}).get(aot_sig)
-        if rec is None or entry is None:
+        if rec is None:
             return True   # pre-entry-index manifest: signature-level only
         if entry == 'step':
             return rec['step']
         return entry[1] in rec['bundles']
 
-    def _timed_first_call(self, fn, args, key_id, aot_sig=None,
-                          aot_entry=None, **fields):
-        """Run the first jitted call of a cache entry (trace + XLA compile
-        OR persistent-cache deserialize happen synchronously inside it),
-        classify which one happened, and record it: a real cold compile
+    def _timed_first_call(self, compiled, entry, args, key_id, **fields):
+        """Run the first call of an artifact's entry point, 'step' or
+        ('bundle', K), through its call seam (trace + XLA compile OR
+        persistent-cache deserialize happen synchronously inside it),
+        classify which one happened, record it, and mark the entry as
+        made. Returns (the StepResult, the outcome). A real cold compile
         emits the `executor.compile` span; a persistent hit emits an
         `executor.compile.persistent_hit` event instead — so a warm-cache
         restart's run log shows ZERO compile spans for already-cached
@@ -1403,6 +1377,10 @@ class Executor(object):
         window also tees fd-2 stderr to catch the SPMD partitioner's
         involuntary-rematerialization diagnostic (_scan_remat) — only on
         first calls, never in the steady-state loop."""
+        fn = compiled if entry == 'step' else compiled.bundle(entry[1])
+        # the stable signature is only worth hashing when a loaded
+        # manifest could match it
+        aot_sig = _stable_sig(compiled) if self._aot_sigs else None
         with obs.span_if(obs.enabled(), 'executor.first_call',
                          key=key_id) as sp:
             parts = _listen_first_call() if sp is not None else None
@@ -1425,14 +1403,12 @@ class Executor(object):
                 # the entries this first call wrote are THIS executor's warm
                 # set — what an AOT export ships
                 self._warm_entries.update(post - pre)
-            warmed = self._aot_warmed(aot_sig, aot_entry)
+            warmed = self._aot_warmed(aot_sig, entry)
             if hit:
                 self._persistent_hits += 1
                 outcome = 'aot_hit' if warmed else 'persistent_hit'
                 if warmed:
                     self._aot_hits += 1
-                if self._last_cache_lookup is not None:
-                    self._last_cache_lookup['outcome'] = outcome
                 obs.event('executor.compile.%s' % outcome, key=key_id,
                           seconds=round(dt, 6), **fields)
             else:
@@ -1448,7 +1424,6 @@ class Executor(object):
                     self._aot_stale += 1
                     obs.event('executor.aot.stale', key=key_id, sig=aot_sig,
                               seconds=round(dt, 6))
-                    import warnings
                     warnings.warn(
                         'AOT warm signature %s (key %s) COMPILED online '
                         'despite the loaded warm-signature manifest claiming '
@@ -1458,6 +1433,10 @@ class Executor(object):
             if sp is not None:
                 sp.fields['outcome'] = outcome
                 _record_first_call_parts(parts, t0, t0 + dt, key_id)
+        if entry == 'step':
+            compiled._obs_compiled = True
+        else:
+            compiled._obs_bundles.add(entry[1])
         return out, outcome
 
     def _scan_remat(self, captured, key_id):
@@ -1475,7 +1454,6 @@ class Executor(object):
         self.remat_detected += n
         _C_REMAT.inc(n)
         obs.event('executor.remat_detected', key=key_id, count=n)
-        import warnings
         warnings.warn(
             'XLA SPMD partitioner reported %d involuntary full '
             'rematerialization(s) while compiling key %s: a sharding '
@@ -1533,17 +1511,17 @@ class Executor(object):
         on = obs.enabled()
         with obs.span('executor.step') as step_sp:
             with obs.span_if(on, 'executor.prepare') as sp:
-                compiled, feed_vals, persist = self._prepare(
-                    program, feed, fetch_list, scope,
-                    use_program_cache=use_program_cache, spans=on)
-                look = self._last_cache_lookup or {}
+                compiled, feed_vals, persist, look, feed_bytes = \
+                    self._prepare(
+                        program, feed, fetch_list, scope,
+                        use_program_cache=use_program_cache, spans=on)
                 if sp is not None:
-                    sp.fields['cache'] = look.get('outcome')
+                    sp.fields['cache'] = look['outcome']
             self._run_counter += 1
             step_sp.fields.update(run=self._run_counter,
-                                  cache=look.get('outcome'),
-                                  key=look.get('key'),
-                                  feed_bytes=self._last_feed_bytes)
+                                  cache=look['outcome'],
+                                  key=look['key'],
+                                  feed_bytes=feed_bytes)
             with obs.span_if(on, 'executor.rng'):
                 rng = jax.random.key(np.uint32(
                     ((program.random_seed or 0) * 2654435761
@@ -1553,46 +1531,40 @@ class Executor(object):
             check = _dbg.nan_inf_check_active()
             op_hook = _prof.op_event_hook()
             if check or op_hook is not None:
-                fetches, new_persist, health, counters = compiled.debug_step(
+                res = compiled.debug_step(
                     persist, feed_vals, rng, check_nan_inf=check,
                     on_op=op_hook)
-            elif not getattr(compiled, '_obs_compiled', False):
+            elif not compiled._obs_compiled:
                 # first jitted call of this cache entry: jax traces and
                 # XLA-compiles (or persistent-cache-deserializes)
                 # synchronously inside it; _timed_first_call measures it
                 # and records executor.compile ONLY for real cold
                 # compiles (plus one step's dispatch either way)
-                (fetches, new_persist, health, counters), outcome = \
-                    self._timed_first_call(
-                        compiled, (persist, feed_vals, rng),
-                        look.get('key'),
-                        aot_sig=self._aot_sig_of(compiled),
-                        aot_entry='step')
-                compiled._obs_compiled = True
+                res, outcome = self._timed_first_call(
+                    compiled, 'step',
+                    compiled.plan.split(persist) + (feed_vals, rng),
+                    look['key'])
                 step_sp.fields['compiled'] = (outcome == 'compile')
                 if outcome != 'compile':
-                    step_sp.fields['cache'] = outcome
+                    look['outcome'] = step_sp.fields['cache'] = outcome
             else:
                 with obs.span_if(on, 'executor.dispatch'):
-                    fetches, new_persist, health, counters = compiled(
-                        persist, feed_vals, rng)
+                    res = compiled(*compiled.plan.split(persist),
+                                   feed_vals, rng)
             if compiled.sparse_plan:
-                _C_EMBED_ROWS.inc(getattr(compiled, '_embed_rows_step', 0))
-            for n, v in new_persist.items():
+                _C_EMBED_ROWS.inc(compiled._embed_rows_step)
+            for n, v in res.new_persist.items():
                 scope._chain_set(n, v)
-            if health is not None:
+            if res.health is not None:
                 # the guard's contract is a HOST decision per step, so
                 # this syncs on the (tiny) health vector — which waits
                 # for the step itself. Under sync='async' that wait is
                 # the step's real host stall: record it, or the overlap
                 # histogram would read ~0 and lie (the guard largely
                 # serializes the async window; docs/perf.md).
-                if sync == 'async':
-                    with obs.span('executor.host_stall',
-                                  cause='anomaly_guard'):
-                        self._observe_health(program, health)
-                else:
-                    self._observe_health(program, health)
+                with obs.span_if(sync == 'async', 'executor.host_stall',
+                                 cause='anomaly_guard'):
+                    self._observe_health(program, res.health)
 
             fetch_f32 = bool(getattr(program, '_fetch_f32', False))
 
@@ -1603,10 +1575,10 @@ class Executor(object):
             with obs.span('executor.fetch', sync=sync):
                 out = [self._convert_fetch(v, fetch_f32, return_numpy,
                                            sync == 'async')
-                       for v in fetches]
-                if on and counters is not None and sync != 'async':
+                       for v in res.fetches]
+                if on and res.counters is not None and sync != 'async':
                     step_sp.fields['device'] = self._read_device(
-                        compiled, counters)
+                        compiled, res.counters)
         return out
 
     def acquire_step(self, program=None, feed=None, fetch_list=None,
@@ -1628,17 +1600,16 @@ class Executor(object):
             scope = global_scope()
         feed = feed or {}
         fetch_list = fetch_list or []
-        compiled, feed_vals, persist = self._prepare(
-            program, feed, fetch_list, scope)
+        prep = self._prepare(program, feed, fetch_list, scope)
+        compiled = prep.compiled
         gap = compiled.plan.uninitialized(compiled.persist_in)
         if gap:
             raise ValueError(
                 'acquire_step: program writes persistable(s) %r that have '
                 'no scope value yet — a handle needs a stable donated '
                 'state structure; run the startup program first' % gap)
-        look = self._last_cache_lookup or {}
-        return StepHandle(self, compiled, scope, program, persist,
-                          look.get('key'))
+        return StepHandle(self, compiled, scope, program, prep.persist,
+                          prep.lookup)
 
     def step_artifact(self, program=None, feed=None, fetch_list=None,
                       scope=None):
@@ -1653,9 +1624,8 @@ class Executor(object):
             program = default_main_program()
         if scope is None:
             scope = global_scope()
-        compiled, _, _ = self._prepare(program, feed or {},
-                                       fetch_list or [], scope)
-        return compiled
+        return self._prepare(program, feed or {}, fetch_list or [],
+                             scope).compiled
 
     def _convert_fetch(self, v, fetch_f32, return_numpy, lazy):
         """One fetched value -> what run()/run_bundle() hand back: numpy /
@@ -1740,15 +1710,14 @@ class Executor(object):
         on = obs.enabled()
         with obs.span('executor.bundle', steps=K) as bsp:
             with obs.span_if(on, 'executor.prepare') as sp:
-                compiled, feed0, persist = self._prepare(
-                    program, feeds[0], fetch_list, scope,
-                    use_program_cache=use_program_cache,
-                    verify_bundle=True, spans=on)
-                look = self._last_cache_lookup or {}
+                compiled, feed0, persist, look, feed_bytes = \
+                    self._prepare(
+                        program, feeds[0], fetch_list, scope,
+                        use_program_cache=use_program_cache,
+                        verify_bundle=True, spans=on)
                 if sp is not None:
-                    sp.fields['cache'] = look.get('outcome')
-            bsp.fields.update(cache=look.get('outcome'),
-                              key=look.get('key'))
+                    sp.fields['cache'] = look['outcome']
+            bsp.fields.update(cache=look['outcome'], key=look['key'])
             extras = compiled.plan.uninitialized(compiled.persist_in)
             if extras:
                 raise ValueError(
@@ -1820,8 +1789,7 @@ class Executor(object):
             # executor.feed.bytes doesn't under-report bundles K-fold
             fb = sum(int(getattr(leaf, 'nbytes', 0))
                      for leaf in jax.tree_util.tree_leaves(stacked))
-            _C_FEED_BYTES.inc(max(0, fb - self._last_feed_bytes))
-            self._last_feed_bytes = fb
+            _C_FEED_BYTES.inc(max(0, fb - feed_bytes))
             # per-step RNG seeds: exactly the integers K successive run()
             # calls would derive from the shared counter
             base = (program.random_seed or 0) * 2654435761
@@ -1831,43 +1799,30 @@ class Executor(object):
             run_base = self._run_counter
             self._run_counter += K
             _C_BUNDLED_STEPS.inc(K)
-            bundle_fn = compiled.bundle(K)
-            donated, readonly = compiled.plan.split(persist)
-            obs_key = ('bundle', K)
-            if obs_key not in getattr(compiled, '_obs_bundles', set()):
-                (new_persist, (fetches, healths, counters)), outcome = \
-                    self._timed_first_call(
-                        bundle_fn, (donated, readonly, stacked, seeds),
-                        look.get('key'), bundle_steps=K,
-                        aot_sig=self._aot_sig_of(compiled),
-                        aot_entry=('bundle', K))
-                if not hasattr(compiled, '_obs_bundles'):
-                    compiled._obs_bundles = set()
-                compiled._obs_bundles.add(obs_key)
+            args = compiled.plan.split(persist) + (stacked, seeds)
+            if K not in compiled._obs_bundles:
+                res, outcome = self._timed_first_call(
+                    compiled, ('bundle', K), args, look['key'],
+                    bundle_steps=K)
                 bsp.fields['compiled'] = (outcome == 'compile')
                 if outcome != 'compile':
-                    bsp.fields['cache'] = outcome
+                    look['outcome'] = bsp.fields['cache'] = outcome
             else:
-                new_persist, (fetches, healths, counters) = bundle_fn(
-                    donated, readonly, stacked, seeds)
+                res = compiled.bundle(K)(*args)
             if compiled.sparse_plan:
-                _C_EMBED_ROWS.inc(
-                    K * getattr(compiled, '_embed_rows_step', 0))
-            for n, v in new_persist.items():
+                _C_EMBED_ROWS.inc(K * compiled._embed_rows_step)
+            for n, v in res.new_persist.items():
                 scope._chain_set(n, v)
-            if healths is not None:
+            if res.health is not None:
                 # ONE host sync of the tiny [K] health matrix; skips are
                 # then observed (and escalated) per inner step, exactly
                 # as K unbundled runs would have. Under sync='async' the
                 # wait on the bundle's outputs happens HERE — record it
                 # as the host stall it is.
-                if sync == 'async':
-                    with obs.span('executor.host_stall',
-                                  cause='anomaly_guard', steps=K):
-                        h_np = {k: np.asarray(v)
-                                for k, v in healths.items()}
-                else:
-                    h_np = {k: np.asarray(v) for k, v in healths.items()}
+                with obs.span_if(sync == 'async', 'executor.host_stall',
+                                 cause='anomaly_guard', steps=K):
+                    h_np = {k: np.asarray(v)
+                            for k, v in res.health.items()}
                 for j in range(K):
                     self._observe_health(
                         program, {k: v[j] for k, v in h_np.items()},
@@ -1876,7 +1831,7 @@ class Executor(object):
             fetch_f32 = bool(getattr(program, '_fetch_f32', False))
             with obs.span('executor.fetch', sync=sync, steps=K):
                 out = []
-                for v in fetches:
+                for v in res.fetches:
                     if isinstance(v, SeqValue):
                         # stacked [K, batch, ...] sequence fetch -> K
                         # per-step values (LoDTensor conversion is
@@ -1896,9 +1851,9 @@ class Executor(object):
                     else:
                         out.append(self._convert_fetch(
                             v, fetch_f32, return_numpy, sync == 'async'))
-                if on and counters is not None and sync != 'async':
+                if on and res.counters is not None and sync != 'async':
                     bsp.fields['device'] = self._read_device(
-                        compiled, counters)
+                        compiled, res.counters)
         return out
 
     def _read_device(self, compiled, counters):
@@ -1936,7 +1891,6 @@ class Executor(object):
                   loss_finite=bool(h['loss_finite']),
                   grads_finite=bool(h['grads_finite']),
                   consecutive=self._consecutive_skips)
-        import warnings
         warnings.warn(
             'anomaly guard: step %d skipped (loss_finite=%s '
             'grads_finite=%s grad_norm=%s) — parameters and optimizer '
@@ -1977,11 +1931,10 @@ class Executor(object):
             program = default_main_program()
         if scope is None:
             scope = global_scope()
-        compiled, feed_vals, persist = self._prepare(
-            program, feed or {}, fetch_list or [], scope)
-        donated, readonly = compiled.plan.split(persist)
-        return compiled, compiled._jitted.lower(
-            donated, readonly, feed_vals, jax.random.key(0))
+        prep = self._prepare(program, feed or {}, fetch_list or [], scope)
+        return prep.compiled, prep.compiled._jitted.lower(
+            *prep.compiled.plan.split(prep.persist), prep.feed_vals,
+            jax.random.key(0))
 
     def compiled_memory_stats(self, program=None, feed=None,
                               fetch_list=None, scope=None):
@@ -2005,13 +1958,8 @@ class Executor(object):
         step updates its tables densely (no plan, or every planned
         table fell back). Resolves through the same compiled-step cache
         as run()."""
-        if program is None:
-            program = default_main_program()
-        if scope is None:
-            scope = global_scope()
-        compiled, _, _ = self._prepare(
-            program, feed or {}, fetch_list or [], scope)
-        return getattr(compiled, '_embed_rows_step', 0)
+        return self.step_artifact(program, feed, fetch_list,
+                                  scope)._embed_rows_step
 
     # -- elastic checkpoint seam (docs/robustness.md#elastic) --------------
 
@@ -2036,7 +1984,6 @@ class Executor(object):
             if val is None:
                 continue
             if isinstance(val, SeqValue):
-                import warnings
                 warnings.warn(
                     'state_dict skips LoD persistable %r (SeqValue '
                     'state has no sharded-checkpoint representation)'
@@ -2075,7 +2022,6 @@ class Executor(object):
                 try:
                     val = jax.device_put(val, NamedSharding(mesh, spec))
                 except ValueError as e:
-                    import warnings
                     warnings.warn(
                         'load_state_dict: annotation %r on %r does not '
                         'fit the mesh (%s); replicating instead'
@@ -2095,7 +2041,6 @@ class Executor(object):
             scope._chain_set(name, val)
             restored.append(name)
         if unknown:
-            import warnings
             warnings.warn(
                 'load_state_dict: %d checkpoint entr(ies) are not '
                 'persistables of this program and were skipped: %s'
@@ -2181,7 +2126,6 @@ class Executor(object):
             for s in man['signatures']}
         self._aot_manifest = man
         if man.get('jax') != jax.__version__:
-            import warnings
             warnings.warn(
                 'AOT blob %r was exported under jax %s but this process '
                 'runs %s — serialized executables will not deserialize '
@@ -2200,11 +2144,9 @@ class Executor(object):
         the compiled-step cache holds the device buffers XLA pinned)."""
         self._cache_evictions += len(self._cache)
         for step in self._cache.values():
-            for fn in [getattr(step, '_jitted', None)] + \
-                    list(getattr(step, '_bundles', {}).values()):
-                if hasattr(fn, 'clear_cache'):
-                    fn.clear_cache()
-            step._bundles = {}
+            for fn in [step._jitted] + list(step._bundles.values()):
+                fn.clear_cache()
+            step._bundles.clear()
         self._cache.clear()
         import gc
         gc.collect()

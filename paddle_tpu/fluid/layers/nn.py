@@ -134,7 +134,7 @@ def embedding(input, size, is_sparse=False, is_distributed=False,
         else:
             # set_mesh() may legitimately come after the layer calls; a
             # program that still has no mesh (or no such axis) when it
-            # COMPILES is warned about there (executor._CompiledStep)
+            # COMPILES is warned about there (StepArtifact)
             dist_axis = row
     # static out shape (reference lookup_table_op InferShape): an id
     # column [..., 1] embeds to [..., emb_dim] — downstream layers (fc)

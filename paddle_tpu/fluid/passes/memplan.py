@@ -1,7 +1,7 @@
 """Per-program donation/memory plan.
 
 The executor used to make its buffer-donation decision inline
-(`_CompiledStep.__init__` scanned `analysis.executor_write_set` and, when
+(`StepArtifact.__init__` scanned `analysis.executor_write_set` and, when
 mutating, donated EVERY persistable input and re-exposed every one as an
 output). This module turns that ad-hoc decision into a first-class plan
 object computed from the same analysis facts:
@@ -22,7 +22,7 @@ object computed from the same analysis facts:
     the donated set is exactly the set XLA can alias in place, which is
     what keeps the update fusible with the compute that produced it.
 
-Consumers: `executor._CompiledStep` (jit donation + write-back),
+Consumers: `StepArtifact` (jit donation + write-back),
 `Executor.run_bundle` (the scan-carry gap check names the plan's
 uninitialized writes), and the serving engine's `warmup()` (records the
 plan in its spans and rejects donating models behind a concurrent

@@ -1,12 +1,7 @@
 """The compiled-step artifact: ONE first-class object per (program,
-feed-signature, fetch-set) owning everything the four step drivers need.
-
-The runtime used to assemble lower -> shard -> donate -> dispatch ->
-fetch four separate ways (`Executor.run`, `run_bundle`, `StepHandle.step`,
-the serving dispatch), with `state_dict` bolted on the side. This module
-is the convergence point (ROADMAP item 5; the SNIPPETS.md pjit exemplar —
-one donation_vector/in_shardings/out_shardings computation reused by
-every caller): a `StepArtifact` owns
+feed-signature, fetch-set) owning everything the four step drivers need
+(the SNIPPETS.md pjit exemplar: one donation_vector/in_shardings/
+out_shardings computation reused by every caller). A `StepArtifact` owns
 
   * the optimized program + lowered op walk (the jittable step body);
   * the memory/donation plan (fluid.passes.memplan) — which persistables
@@ -15,12 +10,14 @@ every caller): a `StepArtifact` owns
     in/out layout fixed point;
   * the RNG-stream policy (op_seq-stamped per-op streams; bundled scans
     re-derive per-step keys from scanned uint32 seeds);
-  * the feed/fetch signature (`feed_names`/`fetch_names` + the
-    feed-signature tuples cache keys and AOT manifests are built from);
-  * the `state_dict` seam (`state_names`/`state_dict` — the placement-
-    true persistable view sharded checkpointing consumes);
+  * its identity: `feed_names`/`fetch_names`, the feed-signature tuples,
+    the cache key's id and the source program, all constructor arguments
+    (cache keys and AOT manifests are built from them);
+  * the `state_dict` seam (the placement-true view of `persist_in` that
+    sharded checkpointing consumes);
   * every jitted entry point compiled from it: the unbundled step and
-    one K-scan per bundle length (`signatures()` enumerates them).
+    one K-scan per bundle length (`signatures()` enumerates them), each
+    behind a call seam that hands back a `StepResult`.
 
 The four drivers stay thin: `Executor.run` dispatches one step,
 `run_bundle` scans K steps over the SAME body, `StepHandle` pins a
@@ -34,15 +31,10 @@ shared entry and bit-identical fetches.
 committed to its device placement BEFORE the first jitted call, so the
 first call's argument signature (committed device arrays) is identical
 to every later call's (donated outputs come back committed) and each
-entry point compiles exactly once — the PR 4 "warm twice" run_bundle
-wart was precisely this committedness flip re-specializing the scan on
-its second call.
-
-Migration note (docs/architecture.md): this class was
-`fluid.executor._CompiledStep`; that name remains importable as an
-alias, but new code should reach it here.
+entry point compiles exactly once.
 """
 import os
+from typing import Any, NamedTuple
 
 import numpy as np
 
@@ -52,9 +44,9 @@ import jax.numpy as jnp
 from . import lowering
 from .lowering import SeqValue, Ctx
 
-__all__ = ['StepArtifact', 'program_fingerprint', 'stable_signature',
-           'aot_manifest', 'write_aot', 'read_aot', 'aot_check',
-           'AOT_MANIFEST', 'AOT_CACHE_DIR']
+__all__ = ['StepArtifact', 'StepResult', 'program_fingerprint',
+           'stable_signature', 'aot_manifest', 'write_aot', 'read_aot',
+           'aot_check', 'AOT_MANIFEST', 'AOT_CACHE_DIR']
 
 
 # What a recompute region keeps besides its inputs (`_run_region`). ONE
@@ -81,10 +73,23 @@ def _feed_signature(name, val):
     return (name, tuple(arr.shape), str(arr.dtype))
 
 
+class StepResult(NamedTuple):
+    """What one execution of a compiled step hands back, whichever driver
+    made it (the jitted step, `debug_step`, a `StepHandle`, a first call).
+    A bundle's K-scan returns the same record with `fetches`, `health`
+    and `counters` stacked on a leading K axis and `new_persist` as the
+    state after the last step."""
+    fetches: Any       # [value], in fetch_names order
+    new_persist: Any   # {name: value} of the persistables the step wrote
+    health: Any        # the anomaly guard's vector, None when unarmed
+    counters: Any      # the device counters' vector, None without any
+
+
 class StepArtifact(object):
     """One lowered+jitted (program, feed-sig, fetch) combination."""
 
     def __init__(self, program, block, feed_names, fetch_names, persist_in,
+                 feed_sig, key_id, source_program, input_of,
                  amp=False, platform='cpu', persist_shardings=None,
                  mesh=None, guard=False, jit_shardings=None):
         self.program = program
@@ -133,14 +138,18 @@ class StepArtifact(object):
         self.feed_names = list(feed_names)
         self.fetch_names = list(fetch_names)
         self.persist_in = list(persist_in)
-        # set by Executor._prepare after construction: the placed-feed
-        # signature tuples this artifact was keyed on, the short cache-key
-        # id it reports under, and the SOURCE program (self.program may be
-        # the optimized clone) — the inputs of stable_signature()
-        self._feed_sig = None
-        self._key_id = None
-        self._source_program = None
+        # identity: the placed-feed signature tuples this artifact was
+        # keyed on, the short cache-key id it reports under, and the SOURCE
+        # program (self.program may be the optimized clone) — the inputs
+        # of stable_signature()
+        self._feed_sig = feed_sig
+        self._key_id = key_id
+        self._source_program = source_program
         self._stable_sig = None
+        # which entry points a driver has first-called (the compile-vs-
+        # deserialize probe runs once each; the AOT manifest exports them)
+        self._obs_compiled = False
+        self._obs_bundles = set()
         ad_idxs = [i for i, op in enumerate(ops) if op.type == 'autodiff']
         assert len(ad_idxs) <= 1, "at most one append_backward per program"
         self.ad_idx = ad_idxs[0] if ad_idxs else None
@@ -189,6 +198,14 @@ class StepArtifact(object):
                        else 'mesh axes %r' % sorted(mesh.shape),
                        op.attrs['dist_axis']), UserWarning)
         self.sparse_plan = self._sparse_embedding_plan(program)
+        # the plan's tables that a trace found their ids for (_grad_setup)
+        self._sparse_active = {}
+        # sparse-embedding accounting (docs/embedding.md): the rows-
+        # touched-per-step bound is static given the step's inputs, so it
+        # is resolved here, once a compiled key — a driver's hot loop only
+        # bumps a counter by `_embed_rows_step`
+        self._embed_rows = self._rows_per_step(input_of)
+        self._embed_rows_step = sum(self._embed_rows.values())
         # Donation/memory plan (fluid.passes.memplan): which persistables
         # the ops actually WRITE decides donation. A mutating step
         # (training: optimizer updates, BN stats, LR counters) donates
@@ -236,7 +253,7 @@ class StepArtifact(object):
 
         run_range = self._run_ops
 
-        def step(donated, readonly, feed, key):
+        def step_body(donated, readonly, feed, key):
             env = dict(readonly)
             env.update(donated)
             env.update(feed)
@@ -262,9 +279,21 @@ class StepArtifact(object):
                 if n in new_persist and not isinstance(new_persist[n], SeqValue):
                     new_persist[n] = jax.lax.with_sharding_constraint(
                         new_persist[n], sh)
-            return fetches, new_persist, health, self._device_counters(env)
+            return StepResult(fetches, new_persist, health,
+                              self._device_counters(env))
 
-        self._step_fn = step  # pure, un-jitted, split (donated, readonly)
+        def step(donated, readonly, feed, key):
+            # a plain tuple across the jit boundary: jax writes each
+            # result's path in the output tree into the lowered module
+            # (`jax.result_info`), so a named record here would change
+            # every module's text and, with it, its key in the persistent
+            # compile cache. `__call__` puts the names back on the host.
+            return tuple(step_body(donated, readonly, feed, key))
+
+        # the pure, un-jitted body over (donated, readonly, feed, key): what
+        # a bundle scans, and what a caller that traces the step into a
+        # function of its own calls (export_compiled, __graft_entry__)
+        self._step = step_body
         # the donation vector comes from the memory plan for BOTH paths
         # (one definition: donate exactly the written-persistables arg)
         donate = self.plan.donate_argnums(self.persist_in)
@@ -280,13 +309,6 @@ class StepArtifact(object):
         # K -> jitted K-step lax.scan over the SAME step body (run_bundle)
         self._bundles = {}
 
-    def _step(self, persist, feed, key):
-        """Un-jitted step over a FULL persist dict (the pre-plan
-        signature; export_compiled and the transpiler drills trace
-        through this)."""
-        donated, readonly = self.plan.split(persist)
-        return self._step_fn(donated, readonly, feed, key)
-
     def bundle(self, K):
         """The K-step bundled executable: ONE jitted lax.scan whose body is
         the exact `step` the unbundled path jits — one device dispatch and
@@ -299,11 +321,13 @@ class StepArtifact(object):
         the per-step fetches (stacked on a leading K axis) and, when the
         anomaly guard is armed, the per-step health vectors (rollback
         already applied in-graph by `step`, per inner step) and, where the
-        step keeps device counters, theirs ([K, counters])."""
+        step keeps device counters, theirs ([K, counters]). Returns the
+        call seam over it: `(donated, readonly, feeds, seeds)` ->
+        StepResult, named on the host as `__call__` names the step's."""
         K = int(K)
         fn = self._bundles.get(K)
         if fn is None:
-            step = self._step_fn
+            step = self._step
 
             def bundled(donated, readonly, feeds, seeds):
                 # carry = the plan's donated (written) set only; the
@@ -311,10 +335,10 @@ class StepArtifact(object):
                 # invariant across the scan
                 def body(carry, xs):
                     feed, seed = xs
-                    fetches, new_persist, health, counters = step(
-                        carry, readonly, feed, jax.random.key(seed))
-                    nxt = {n: new_persist.get(n, carry[n]) for n in carry}
-                    return nxt, (fetches, health, counters)
+                    res = step(carry, readonly, feed, jax.random.key(seed))
+                    nxt = {n: res.new_persist.get(n, carry[n])
+                           for n in carry}
+                    return nxt, (res.fetches, res.health, res.counters)
 
                 return jax.lax.scan(body, donated, (feeds, seeds))
 
@@ -338,7 +362,12 @@ class StepArtifact(object):
             else:
                 fn = jax.jit(bundled, donate_argnums=donate)
             self._bundles[K] = fn
-        return fn
+
+        def call(donated, readonly, feeds, seeds):
+            new_persist, stacked = fn(donated, readonly, feeds, seeds)
+            return StepResult(stacked[0], new_persist, *stacked[1:])
+
+        return call
 
     # optimizer ops with a SparseRows (SelectedRows-analogue) grad branch
     # in ops_impl/optim_ops.py
@@ -426,6 +455,32 @@ class StepArtifact(object):
                     and grad_readers <= {opt_idx} and not grad_writers):
                 plan[w] = {'lookups': sorted(lookups), 'gname': gname}
         return plan
+
+    def _rows_per_step(self, input_of):
+        """Static per-step bound on table rows the sparse-embedding plan
+        touches: the total id count of the plan's lookups, from the shapes
+        of the step's inputs (`input_of`: name -> the fed or scope-held
+        value, or None). On-device merge collapses duplicates, so the true
+        unique count is <= this; the dense path would touch the full vocab
+        instead. Mirrors _grad_setup's ALL-OR-NOTHING activation per
+        table: a table with ANY unresolvable ids tensor falls back to the
+        dense path there, so it must contribute zero here, or the counter
+        would claim touched-rows updates while the [vocab, dim] dense grad
+        materializes. Returns {table: rows}, 0 for fallen-back tables."""
+        per_table = {}
+        for w, plan in self.sparse_plan.items():
+            table_rows = 0
+            for _, ids_name, _ in plan['lookups']:
+                v = input_of(ids_name)
+                if v is None:
+                    table_rows = 0
+                    break   # dense fallback for this whole table
+                shp = tuple(getattr(lowering.data_of(v), 'shape', ()))
+                if shp and shp[-1] == 1:
+                    shp = shp[:-1]
+                table_rows += int(np.prod(shp)) if shp else 1
+            per_table[w] = table_rows
+        return per_table
 
     @staticmethod
     def _tap_name(w, op_idx):
@@ -564,7 +619,7 @@ class StepArtifact(object):
                     "NaN/Inf in gradient %r (of parameter %r)"
                     % (gnames[n], n))
             env[gnames[n]] = g
-        for w, plan in getattr(self, '_sparse_active', {}).items():
+        for w, plan in self._sparse_active.items():
             d = env[w].shape[-1]
             ids_parts, row_parts = [], []
             for op_idx, ids_name, pad in plan['lookups']:
@@ -599,7 +654,7 @@ class StepArtifact(object):
         loss_finite = jnp.isfinite(loss.astype(jnp.float32)).all()
         grads_finite = jnp.asarray(True)
         sq = jnp.asarray(0.0, jnp.float32)
-        names = list(pnames) + list(getattr(self, '_sparse_active', {}))
+        names = list(pnames) + list(self._sparse_active)
         for n in names:
             g = env.get(gnames[n])
             if g is None:
@@ -875,11 +930,14 @@ class StepArtifact(object):
         new_persist = {n: env[n] for n in self.persist_out if n in env}
         if health is not None:
             self._select_healthy(health['healthy'], new_persist, persist)
-        return fetches, new_persist, health, self._device_counters(env)
+        return StepResult(fetches, new_persist, health,
+                          self._device_counters(env))
 
-    def __call__(self, persist, feed, key):
-        donated, readonly = self.plan.split(persist)
-        return self._jitted(donated, readonly, feed, key)
+    def __call__(self, donated, readonly, feed, key):
+        """THE call seam of the jitted step: every driver's dispatch (run,
+        a StepHandle, a first call) goes through here, over the persist
+        dict as `plan.split` divides it."""
+        return StepResult(*self._jitted(donated, readonly, feed, key))
 
     # -- first-class artifact surface ----------------------------------
 
@@ -962,12 +1020,6 @@ class StepArtifact(object):
                 out[w] = np.unique(np.concatenate(parts))
         return out
 
-    @property
-    def state_names(self):
-        """The persistable names this step reads/writes — the artifact's
-        state_dict seam (what sharded checkpointing walks)."""
-        return list(self.persist_in)
-
     def state_dict(self, scope):
         """Placement-true {name: jax.Array} view of THIS step's
         persistable state, read live from `scope` — the state_dict seam
@@ -1010,6 +1062,12 @@ def program_fingerprint(program):
     return hashlib.sha256(doc.encode('utf-8')).hexdigest()[:16]
 
 
+def _mesh_axes(art):
+    if art.mesh is None:
+        return None
+    return sorted([str(a), int(s)] for a, s in art.mesh.shape.items())
+
+
 def stable_signature(art):
     """Process-independent identity of one compiled step signature —
     unlike the Executor's in-process cache key (which embeds the
@@ -1021,19 +1079,16 @@ def stable_signature(art):
         return art._stable_sig
     import hashlib
     import json
-    src = art._source_program if art._source_program is not None \
-        else art.program
     payload = json.dumps({
-        'program': program_fingerprint(src),
-        'feed_sig': [[str(x) for x in sig] for sig in (art._feed_sig or ())],
+        'program': program_fingerprint(art._source_program),
+        'feed_sig': [[str(x) for x in sig] for sig in art._feed_sig],
         'fetches': list(art.fetch_names),
         'persist_in': list(art.persist_in),
         'donates': sorted(art.donate_names),
         'amp': bool(art.amp),
         'guard': bool(art.guard),
         'remat': bool(art.use_remat),
-        'mesh': (sorted([str(a), int(s)] for a, s in art.mesh.shape.items())
-                 if art.mesh is not None else None),
+        'mesh': _mesh_axes(art),
     }, sort_keys=True)
     art._stable_sig = hashlib.sha256(
         payload.encode('utf-8')).hexdigest()[:16]
@@ -1045,7 +1100,7 @@ def _feed_entries(art):
     [{'name', 'shape', 'dtype', 'seq'}...] (seq inputs record their dense
     data plane's shape)."""
     out = []
-    for sig in (art._feed_sig or ()):
+    for sig in art._feed_sig:
         if len(sig) == 4 and sig[1] == 'seq':
             name, _, shape, dtype = sig
             seq = True
@@ -1063,12 +1118,10 @@ def aot_manifest(executor):
     program_lint --aot checks against."""
     sigs = []
     for art in executor._cache.values():
-        src = art._source_program if art._source_program is not None \
-            else art.program
         sigs.append({
             'sig': stable_signature(art),
             'key': art._key_id,
-            'program': program_fingerprint(src),
+            'program': program_fingerprint(art._source_program),
             'feeds': _feed_entries(art),
             'fetches': list(art.fetch_names),
             'donates': sorted(art.donate_names),
@@ -1078,12 +1131,10 @@ def aot_manifest(executor):
             # replica warmed only through run_bundle never serialized
             # the plain step, and the importer's stale detection must
             # know that (Executor._aot_warmed)
-            'warmed_step': bool(getattr(art, '_obs_compiled', False)),
+            'warmed_step': art._obs_compiled,
             'guard': bool(art.guard),
             'amp': bool(art.amp),
-            'mesh': (sorted([str(a), int(s)]
-                            for a, s in art.mesh.shape.items())
-                     if art.mesh is not None else None),
+            'mesh': _mesh_axes(art),
         })
     try:
         platform = jax.devices()[0].platform

@@ -144,7 +144,8 @@ def export_compiled(dirname, feed_example, target_vars, executor,
                 feed[n] = SeqValue(a, lens)
             else:
                 feed[n] = a
-        fetches = compiled._step(persist, feed, jax.random.key(0))[0]
+        fetches = compiled._step(*compiled.plan.split(persist), feed,
+                                 jax.random.key(0)).fetches
         return [f.data if isinstance(f, SeqValue) else f for f in fetches]
 
     args = [jnp.asarray(feed_example[n]) for n in feed_names]

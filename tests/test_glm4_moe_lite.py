@@ -452,8 +452,7 @@ def test_memory_optimize_without_a_region_recomputes_the_whole_forward():
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(startup)
         feed = {'x': np.ones((4, 8), 'float32')}
-        compiled, _, _ = exe._prepare(main, feed, [loss],
-                                      fluid.global_scope())
+        compiled = exe.step_artifact(main, feed, [loss])
         ad = compiled.ad_idx
         assert list(compiled.regions) == [0]
         assert compiled.regions[0][0] == ad

@@ -321,8 +321,8 @@ def test_the_toy_cells_expert_layers_share_a_body_across_their_regions():
         exe.run(built['startup'])
         pool, _ = cell['generator'].make_pool(cell['traffic'],
                                               cell['config'], 3)
-        assert len(exe._prepare(built['main'], pool[0], [built['loss']],
-                                fluid.global_scope())[0].regions) == 4
+        assert len(exe.step_artifact(built['main'], pool[0],
+                                     [built['loss']]).regions) == 4
         text = exe.lowered_hlo(built['main'], pool[0], [built['loss']])
     bodies = re.findall(r'func\.func private @(_held_paths\w*)', text)
     calls = re.findall(r'call @(_held_paths\w*)', text)

@@ -37,7 +37,8 @@ import pytest
 import paddle_tpu.fluid as fluid
 from paddle_tpu import obs
 from paddle_tpu.fluid import step_artifact
-from paddle_tpu.fluid.executor import StepArtifact, _CompiledStep
+from paddle_tpu.fluid.executor import StepArtifact
+from paddle_tpu.fluid.step_artifact import StepResult
 from paddle_tpu.obs import report as obs_report
 
 pytestmark = pytest.mark.artifact
@@ -178,9 +179,97 @@ def test_four_drivers_share_one_artifact_and_match_bitwise():
     # and the artifact enumerates both compiled entry points
     art = list(exe._cache.values())[0]
     assert isinstance(art, StepArtifact)
-    assert _CompiledStep is StepArtifact  # migration alias holds
     assert ('step',) in art.signatures()
     assert ('bundle', 1) in art.signatures()
+
+
+def _guarded_held_step():
+    """A training step that hands back all four fields of a StepResult:
+    an expert layer with a held share (a device counter, PR 35) under an
+    armed anomaly guard (the health vector)."""
+    from paddle_tpu.fluid import unique_name
+    prog, start = fluid.Program(), fluid.Program()
+    prog.random_seed = start.random_seed = 3
+    with unique_name.guard(), fluid.program_guard(prog, start):
+        x = fluid.layers.data(name='x', shape=[16], dtype='float32')
+        out, _count = fluid.layers.moe_mlp(
+            x, num_experts=16, hidden_size=8, act='swish', gated=True,
+            top_k=2, norm_topk_prob=True, capacity_factor=None,
+            bias_attr=False, return_expert_count=True, experts_held=(8, 8))
+        loss = fluid.layers.mean(out)
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    fluid.anomaly_guard(prog)
+    feed = {'x': np.random.RandomState(5).rand(64, 16).astype('float32')}
+    return prog, start, loss, feed
+
+
+def test_no_attribute_of_an_artifact_is_born_outside_its_constructor():
+    """The artifact is born whole: after run, run_bundle(K=2) and a
+    StepHandle's step on one program it has the attributes it had right
+    after construction (its identity and its first-call flags are
+    constructor state, not set from outside)."""
+    prog, start, loss, feed = _guarded_held_step()
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(start)
+        art = exe.step_artifact(prog, feed, [loss])
+        born = set(vars(art))
+        assert art._key_id == exe._last_cache_lookup['key']
+        assert art._source_program is prog and art._feed_sig
+        assert not art._obs_compiled and art._obs_bundles == set()
+        exe.run(prog, feed=feed, fetch_list=[loss])
+        exe.run_bundle(prog, feeds=[feed, feed], fetch_list=[loss])
+        handle = exe.acquire_step(prog, feed=feed, fetch_list=[loss])
+        handle.step({'x': feed['x']})
+        assert exe.step_artifact(prog, feed, [loss]) is art
+    assert art._obs_compiled and art._obs_bundles == {2}
+    assert set(vars(art)) == born
+
+
+@pytest.mark.parametrize('driver', ['call', 'first_call', 'debug_step',
+                                    'unjitted', 'bundle'])
+def test_every_driver_hands_back_a_step_result_read_by_name(driver):
+    """One record for every way a step executes: the jitted call seam, a
+    first call through the executor's probe, the eager debug_step, the
+    un-jitted body `_step` and a bundle's K-scan (fields stacked on K) return a
+    StepResult whose fetches, new_persist, health (the guard's) and
+    counters (PR 35's) are read by name."""
+    import jax
+    prog, start, loss, feed = _guarded_held_step()
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        scope = fluid.global_scope()
+        exe.run(start)
+        art = exe.step_artifact(prog, feed, [loss])
+        persist = {n: scope._chain_get(n) for n in art.persist_in}
+        placed = {'x': jax.numpy.asarray(feed['x'])}
+        key = jax.random.key(0)
+        split = art.plan.split(persist)
+        K = 1
+        if driver == 'call':
+            res = art(*split, placed, key)
+        elif driver == 'first_call':
+            res, outcome = exe._timed_first_call(
+                art, 'step', split + (placed, key), art._key_id)
+            assert outcome == 'compile' and art._obs_compiled
+        elif driver == 'debug_step':
+            res = art.debug_step(persist, placed, key)
+        elif driver == 'unjitted':
+            res = art._step(*split, placed, key)
+        else:
+            K = 2
+            stacked = {'x': jax.numpy.stack([placed['x']] * K)}
+            res = art.bundle(K)(*split, stacked,
+                                np.asarray([1, 2], np.uint32))
+    assert isinstance(res, StepResult)
+    assert res._fields == ('fetches', 'new_persist', 'health', 'counters')
+    lead = () if K == 1 else (K,)
+    assert np.asarray(res.fetches[0]).shape == lead + (1,)
+    assert set(res.new_persist) == set(art.persist_out)
+    assert np.asarray(res.health['healthy']).shape == lead
+    assert np.asarray(res.health['healthy']).all()
+    assert np.asarray(res.counters).shape == lead + (len(art.counters),)
+    assert len(art.counters) == 1
 
 
 def test_each_signature_compiles_exactly_once():
@@ -228,7 +317,7 @@ def test_step_handle_state_dict_seam():
         exe.run(start)
         h = exe.acquire_step(prog, feed=_feeds(1)[0], fetch_list=[loss])
         sd = h.state_dict()
-    assert set(sd) == set(h._compiled.state_names)
+    assert set(sd) == set(h._compiled.persist_in)
     for n, v in sd.items():
         np.testing.assert_array_equal(np.asarray(v),
                                       np.asarray(scope._chain_get(n)))

@@ -36,8 +36,8 @@ def _trace_step(main, startup, cost):
     from paddle_tpu.fluid.executor import global_scope
     persist = {n: global_scope().vars[n] for n in compiled.persist_in}
     feed_dev = {k: jax.numpy.asarray(v) for k, v in feed.items()}
-    jaxpr = jax.make_jaxpr(compiled._step)(persist, feed_dev,
-                                           jax.random.key(0))
+    jaxpr = jax.make_jaxpr(compiled._step)(
+        *compiled.plan.split(persist), feed_dev, jax.random.key(0))
     return compiled, str(jaxpr)
 
 

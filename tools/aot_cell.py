@@ -41,7 +41,6 @@ def main(argv=None):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     import paddle_tpu.fluid as fluid
-    from paddle_tpu.fluid import executor
     from chipbench.harness import catalog
 
     cell = catalog.load_cell(args.workload)
@@ -64,17 +63,12 @@ def main(argv=None):
     exe.run(cell['builder'].build(cell['config'], traffic)['startup'])
 
     # the step as the TPU's place would lower it
-    step_class = executor._CompiledStep
-    executor._CompiledStep = lambda *a, **k: step_class(
-        *a, **dict(k, platform='tpu'))
-    try:
-        compiled, feed_vals, persist = exe._prepare(
-            built['main'], pool[0],
-            [built['loss']] + [built['grads'][n]
-                               for n in sorted(built['grads'])],
-            fluid.global_scope())
-    finally:
-        executor._CompiledStep = step_class
+    exe._lowering_platform = lambda mesh: 'tpu'
+    scope = fluid.global_scope()
+    compiled = exe.step_artifact(
+        built['main'], pool[0],
+        [built['loss']] + [built['grads'][n] for n in sorted(built['grads'])],
+        scope)
     topo = topologies.get_topology_desc(platform='tpu',
                                         topology_name='v5e:2x2')
     chip = SingleDeviceSharding(topo.devices[0])
@@ -82,8 +76,13 @@ def main(argv=None):
     def spec(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
 
-    donated, readonly = compiled.plan.split(persist)
-    shapes = jax.tree_util.tree_map(spec, (donated, readonly, feed_vals))
+    # shapes only: the state as the scope holds it, the feed as jax
+    # holds a batch of the pool (an int64 id is an int32 there)
+    donated, readonly = compiled.plan.split(compiled.state_dict(scope))
+    feed = {n: jax.ShapeDtypeStruct(
+        v.shape, jax.dtypes.canonicalize_dtype(v.dtype))
+        for n, v in pool[0].items()}
+    shapes = jax.tree_util.tree_map(spec, (donated, readonly, feed))
     with (jax.default_matmul_precision(precision) if precision
           else contextlib.nullcontext()):
         done = compiled._jitted.lower(
@@ -119,7 +118,6 @@ def main(argv=None):
     # (a reference with forward_loss(params, model, *feeds), as the causal
     # language models' have)
     from chipbench.harness import check as check_mod
-    scope = fluid.global_scope()
     params, tree = cell['builder'].reference_params(
         config, built['main'],
         lambda name: np.asarray(scope.find_var(name).get_tensor()))
