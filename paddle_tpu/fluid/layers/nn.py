@@ -807,17 +807,25 @@ def ssd_scan(x, dt, a, b, c, d=None, chunk_size=128, name=None):
 
 
 def causal_conv1d(input, kernel_size, act=None, param_attr=None, name=None,
-                  bias_attr=False):
+                  bias_attr=False, in_gate=None, out_gate=None):
     """Depthwise causal convolution along the time axis of ``input``
     [B, T, C]: ``y[t] = act(sum_j w[j] * x[t - (kernel_size - 1) + j] +
     bias)`` per channel, zeros before the first token (left padding).
     The filter is a parameter [kernel_size, C]; `act` is None or 'silu';
     ``bias_attr`` False (the default) is no bias, anything else a
-    parameter [C] from 0 (a ParamAttr names or initialises it). One
-    Program op. TPU extension (the reference's sequence_conv mixes
-    channels and looks both ways)."""
+    parameter [C] from 0 (a ParamAttr names or initialises it).
+    ``in_gate`` and ``out_gate`` (tensors of ``input``'s shape, each
+    optional) multiply the convolution's input and its result:
+    ``out_gate * conv(in_gate * input)``, LFM2's double-gated short
+    convolution with both. One Program op. TPU extension (the reference's
+    sequence_conv mixes channels and looks both ways)."""
     if act not in (None, 'silu', 'swish'):
         raise ValueError("causal_conv1d act=%r: None or 'silu'" % (act,))
+    for gate in (in_gate, out_gate):
+        if gate is not None and tuple(gate.shape) != tuple(input.shape):
+            raise ValueError('causal_conv1d: a gate of shape %r on an input '
+                             'of %r' % (tuple(gate.shape),
+                                        tuple(input.shape)))
     helper = LayerHelper('causal_conv1d', **locals())
     dtype = helper.input_dtype()
     w = helper.create_parameter(
@@ -828,6 +836,10 @@ def causal_conv1d(input, kernel_size, act=None, param_attr=None, name=None,
         inputs['Bias'] = [helper.create_parameter(
             attr=helper.bias_attr, shape=[int(input.shape[-1])],
             dtype=dtype, is_bias=True)]
+    if in_gate is not None:
+        inputs['InGate'] = [in_gate]
+    if out_gate is not None:
+        inputs['OutGate'] = [out_gate]
     out = helper.create_variable_for_type_inference(dtype)
     helper.append_op(type='causal_conv1d', inputs=inputs,
                      outputs={'Out': [out]}, attrs={'act': act or ''})
@@ -1657,7 +1669,7 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
             bias_attr=None, name=None, top_k=1, return_aux_loss=False,
             gated=False, norm_topk_prob=True, return_expert_count=False,
             experts_held=None, scoring='softmax', selection_bias=False,
-            gate_scale=1.0, router_input=None):
+            gate_scale=1.0, router_input=None, norm_eps=None):
     """Top-k gated mixture-of-experts FFN (TPU extension; the reference
     predates MoE — its conditional-computation ancestor is layers.Switch).
 
@@ -1707,6 +1719,9 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
     move by the experts' load (layers.router_bias_update). `gate_scale`
     multiplies the gates after the renormalisation. Both come with the
     sigmoid router and are refused under ``scoring='softmax'``.
+    ``norm_eps`` is what the renormalisation adds to the chosen scores'
+    sum (``None``: the scoring's own, 0 under 'softmax' and DeepSeek-V3's
+    1e-20 under 'sigmoid'; LFM2's router carries 1e-6).
 
     ``router_input`` (dropless only): a tensor of `input`'s shape that the
     ROUTER reads in place of `input`: the logits are ``router_input @
@@ -1830,6 +1845,8 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
         attrs['scoring'] = scoring
     if gate_scale != 1.0:
         attrs['gate_scale'] = float(gate_scale)
+    if norm_eps is not None:
+        attrs['norm_eps'] = float(norm_eps)
     helper.append_op(type='moe_mlp', inputs=inputs, outputs=outputs,
                      attrs=attrs)
     got = (out,) + ((aux,) if return_aux_loss else ()) \

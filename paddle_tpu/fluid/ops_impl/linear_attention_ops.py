@@ -104,7 +104,11 @@ for the delta rule's stage.
 `causal_conv1d`: y[b, t, c] = act(sum_j w[j, c] x[b, t - (K - 1) + j, c]
 + bias[c]), x = 0 before the row's first token; depthwise (a filter a
 channel), the bias optional (input `Bias`). Float32 elementwise work (K
-shifted multiply-adds), never the MXU.
+shifted multiply-adds), never the MXU. Two more optional inputs of x's
+shape make it LFM2's double-gated short convolution,
+y = OutGate * conv(InGate * x): each product is taken in float32 and
+rounded to x's dtype, around the convolution (the rule's own
+multiplications, under the op's scope; the kernels are what they were).
 Under AMP it reads its input rounded to bf16 and gives its result in
 bf16, as attention does: the input is what its backward keeps, and a
 [B, T, C] float32 array twice a layer is what the cell cannot hold.
@@ -129,9 +133,12 @@ Trace-time counters: `gdn.lowered{chunk=}` once per op per trace,
 `gdn.intra{way=kernel|composed}` beside it (which way stage `gdn_intra`
 went), `gdn.tokens` the B x T of the traced shape,
 `ssd.lowered{chunk=, heads=, groups=}` and `ssd.tokens` likewise and
-`ssd.way{way=kernel|composed}` beside them, `conv1d.lowered` (with the
-label `bias=true` where the op has one) and
-`conv1d.way{way=kernel|composed}` beside it, `gated_rms_norm.lowered`.
+`ssd.way{way=kernel|composed}` beside them,
+`conv1d.lowered{taps=K, act=silu|none}` (and the labels `bias=true` and
+`gates=1|2` where the op has them; `obs.REGISTRY.total('conv1d.lowered')`
+is every form's), `shortconv.tokens` the B x T of an op with both gates
+and `conv1d.way{way=kernel|composed}` beside it,
+`gated_rms_norm.lowered`.
 """
 import functools
 
@@ -570,19 +577,40 @@ def _conv_bwd(act, kernel, res, g):
 causal_conv1d.defvjp(_conv_fwd, _conv_bwd)
 
 
+def _gate(x, gate):
+    """x * gate in float32, rounded to x's dtype; x where there is none."""
+    if gate is None:
+        return x
+    return (x.astype(jnp.float32) * gate.astype(jnp.float32)).astype(x.dtype)
+
+
 @register('causal_conv1d')
 def _causal_conv1d(ins, attrs, ctx):
     b = data_of(ins['Bias'][0]) if ins.get('Bias') else None
-    obs.counter('conv1d.lowered',                            # trace time
-                **({} if b is None else {'bias': 'true'})).inc()
     x = amp_cast(ctx, data_of(ins['X'][0]))
     w = data_of(ins['Filter'][0])
+    # the gates of a double-gated short convolution: y = OutGate *
+    # conv(InGate * X), each product taken here, around the kernel
+    gates = [amp_cast(ctx, data_of(ins[slot][0])) if ins.get(slot) else None
+             for slot in ('InGate', 'OutGate')]
+    n_gates = sum(g is not None for g in gates)
+    act = attrs.get('act') or ''
+    labels = {'taps': int(w.shape[0]), 'act': act or 'none'}
+    if b is not None:
+        labels['bias'] = 'true'
+    if n_gates:
+        labels['gates'] = n_gates
+    obs.counter('conv1d.lowered', **labels).inc()            # trace time
+    if n_gates == 2:
+        obs.counter('shortconv.tokens').inc(int(x.shape[0])
+                                            * int(x.shape[1]))
     # on the TPU, for a shape they take, one Pallas kernel each way
     kernel = ctx.platform == 'tpu' and conv_kernel.usable(
         x.shape[1], x.shape[2], w.shape[0], x.dtype)
     obs.counter('conv1d.way',                                # trace time
                 way='kernel' if kernel else 'composed').inc()
-    return {'Out': causal_conv1d(x, w, attrs.get('act') or '', kernel, b)}
+    y = causal_conv1d(_gate(x, gates[0]), w, act, kernel, b)
+    return {'Out': _gate(y, gates[1])}
 
 
 def _gated_norm(x, gate, w, cfg):

@@ -82,7 +82,9 @@ the default takes a softmax over all; an input `SelectionBias` [E]
 (float32, a persistable that is no trainable parameter) is added to the
 scores for the CHOICE of the top k and for nothing else: the gates are
 the chosen experts' scores without it, renormalised over the chosen
-under `norm_topk_prob`, times `gate_scale`; no gradient reaches the bias
+under `norm_topk_prob` (the attribute `norm_eps`, where the op has it,
+is what that sum carries in place of the scoring's own), times
+`gate_scale`; no gradient reaches the bias
 (parallel/moe.py router_topk: one code path for both scorings). Its
 owner moves it after the step from `ExpertCount`
 (layers.router_bias_update). `AuxLoss` stays the softmax form. An
@@ -563,7 +565,8 @@ def _moe_mlp(ins, attrs, ctx):
         aux = load_balancing_loss(logits, top_k)
         expert, gate = router_topk(
             logits, top_k, norm, scoring, bias,
-            float(attrs.get('gate_scale', 1.0)))               # [k, nt]
+            float(attrs.get('gate_scale', 1.0)),
+            attrs.get('norm_eps'))                             # [k, nt]
         sizes = jnp.bincount(expert.reshape(-1), length=n_exp
                              ).astype(jnp.int32)
     params = dict(zip(params, amp_cast(ctx, *params.values())))

@@ -647,7 +647,12 @@ def _head_vmem_limit(T, D, bq, bk, itemsize):
     output block, a grid step's blocks twice over: q, do, k, v in, dk, dv
     out, lse and delta, the bias in its sublane tile; the dk and dv
     accumulators) plus Mosaic's default scope for the body's score tiles,
-    as the two kernels have it at the same tiles."""
+    as the two kernels have it at the same tiles. A head narrower than a
+    lane tile takes a whole one in VMEM: D = 64 is counted as 128 (float32
+    operands of D = 64 over 16384 positions, traced under highest
+    precision, were refused by 1.75 MiB at the narrow count; compiled for
+    a described v5e, PR 44)."""
+    D = max(D, LANES)
     dq = T * D * (4 + 2 * itemsize)
     blocks = 2 * ((2 * bq + 4 * bk) * D * itemsize + 2 * bq * LANES * 4
                   + 8 * bk * 4)

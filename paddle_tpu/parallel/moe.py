@@ -49,7 +49,7 @@ stack_expert_params = stack_unit_params
 
 
 def router_topk(logits, top_k, norm_topk_prob=True, scoring='softmax',
-                bias=None, gate_scale=1.0):
+                bias=None, gate_scale=1.0, norm_eps=None):
     """Routing decisions shared by every path.
 
     Returns (expert [k, nt] int, gate [k, nt] f32). k=1 keeps the Switch
@@ -59,7 +59,8 @@ def router_topk(logits, top_k, norm_topk_prob=True, scoring='softmax',
 
     `scoring` 'sigmoid' scores each expert by itself, sigmoid(logit)
     (DeepSeek-V3, arXiv:2412.19437 section 2.1.2), and the sum the chosen
-    scores are renormalised by carries that source's 1e-20. `bias` [E]
+    scores are renormalised by carries that source's 1e-20 unless
+    `norm_eps` gives another (LFM2's router adds 1e-6). `bias` [E]
     moves the CHOICE and nothing else: the top k are taken of score +
     bias, the gates from the scores without it, and no gradient reaches
     it (its owner moves it by the experts' load, not by the loss).
@@ -86,7 +87,8 @@ def router_topk(logits, top_k, norm_topk_prob=True, scoring='softmax',
     _, idx = lax.top_k(pick, top_k)                              # [nt, k]
     gate = jnp.take_along_axis(scores, idx, axis=-1)             # [nt, k]
     if top_k > 1 and norm_topk_prob:
-        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + eps)
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True)
+                       + (eps if norm_eps is None else norm_eps))
     if gate_scale != 1.0:
         gate = gate * gate_scale
     return idx.T, gate.T

@@ -39,7 +39,7 @@ ROWS = {'one_tile': (1, 32), 'three_tiles': (1, 96), 'two_rows': (2, 64)}
 
 
 @pytest.mark.parametrize('rows', list(ROWS))
-@pytest.mark.parametrize('taps', [2, 4])
+@pytest.mark.parametrize('taps', [2, 3, 4])
 @pytest.mark.parametrize('act', ['', 'silu'])
 @pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
 def test_kernel_is_the_composition(dtype, act, taps, rows):
@@ -214,6 +214,10 @@ def _ways():
             for w in ('kernel', 'composed')}
 
 
+def _lowered(taps=4, act='silu', **labels):
+    return obs.counter('conv1d.lowered', taps=taps, act=act, **labels).value
+
+
 @pytest.mark.parametrize('platform', ['cpu', 'tpu'])
 def test_the_rule_chooses_on_platform_and_shape(platform, monkeypatch,
                                                 interpreted):
@@ -239,10 +243,10 @@ def test_the_rule_chooses_on_platform_and_shape(platform, monkeypatch,
                     name='f', initializer=fluid.initializer
                     .NumpyArrayInitializer(f)))
 
-        before = _ways(), obs.counter('conv1d.lowered').value
+        before = _ways(), _lowered()
         got, (gx, gf), text = _grads_of(build, {'w': w}, ['x', 'f'],
                                         optimized=True)
-        after = _ways(), obs.counter('conv1d.lowered').value
+        after = _ways(), _lowered()
         want, pull = jax.vjp(lambda x, f: la._conv(x, f, 'silu'),
                              jnp.asarray(x), jnp.asarray(f))
         wx, wf = pull(jnp.asarray(w))
@@ -293,10 +297,10 @@ def test_the_layers_bias_reaches_either_way(platform, monkeypatch,
                                     param_attr=attr('f', f),
                                     bias_attr=attr('b', b))
 
-    before = obs.counter('conv1d.lowered', bias='true').value, _ways()
+    before = _lowered(bias='true'), _ways()
     got, (gx, gf, gb), _ = _grads_of(build, {'w': w}, ['x', 'f', 'b'],
                                      optimized=True)
-    assert obs.counter('conv1d.lowered', bias='true').value > before[0]
+    assert _lowered(bias='true') > before[0]
     assert _ways()['kernel' if platform == 'tpu' else 'composed'] \
         > before[1]['kernel' if platform == 'tpu' else 'composed']
     want, pull = jax.vjp(lambda x, f, b: la._conv(x, f, 'silu', b),
@@ -312,3 +316,65 @@ def test_the_layers_bias_reaches_either_way(platform, monkeypatch,
         op, = [o for o in fluid.default_main_program().global_block().ops
                if o.type == 'causal_conv1d']
         assert not op.input('Bias')
+
+
+@pytest.mark.parametrize('amp', [False, True], ids=['float32', 'bf16'])
+@pytest.mark.parametrize('platform', ['cpu', 'tpu'])
+def test_two_gates_make_the_short_convolution_either_way(platform, amp,
+                                                         monkeypatch,
+                                                         interpreted):
+    """`layers.causal_conv1d(in_gate=, out_gate=)` through the Executor
+    (ISSUE 44: LFM2's mixer): out_gate * conv3(in_gate * x) with NO
+    activation against three shifted multiply-adds, the value and the
+    gradient of x, both gates and the filter; the composition on the CPU,
+    the kernels (K = 3, in the interpreter) with the platform reported as
+    `tpu`, two rows; under AMP the three arrays are read in bf16. Counted
+    `conv1d.lowered{taps=3, act=none, gates=2}` and, by token,
+    `shortconv.tokens`. Without the gates the op is what it was."""
+    init = lowering.Ctx.__init__
+    monkeypatch.setattr(
+        lowering.Ctx, '__init__',
+        lambda self, *a, **kw: init(self, *a, **dict(kw, platform=platform)))
+    rng = np.random.default_rng(44)
+    x, b, c, w = (rng.normal(size=(2, 48, 128)).astype('float32')
+                  for _ in range(4))
+    f = rng.normal(size=(3, 128)).astype('float32')
+
+    def attr(name, value):
+        return fluid.ParamAttr(name=name, initializer=fluid.initializer
+                               .NumpyArrayInitializer(value))
+
+    def build():
+        return layers.causal_conv1d(
+            _input('x', x), 3, param_attr=attr('f', f),
+            in_gate=_input('b', b), out_gate=_input('c', c))
+
+    def formula(x, b, c, f):
+        if amp:
+            x, b, c = (t.astype(jnp.bfloat16).astype(jnp.float32)
+                       for t in (x, b, c))
+        p = jnp.pad(b * x, ((0, 0), (2, 0), (0, 0)))
+        return c * (f[0] * p[:, 0:48] + f[1] * p[:, 1:49] + f[2] * p[:, 2:50])
+
+    before = (_lowered(3, 'none', gates='2'), _ways(),
+              obs.counter('shortconv.tokens').value)
+    got, grads, _ = _grads_of(build, {'w': w}, ['x', 'b', 'c', 'f'], amp=amp)
+    assert _lowered(3, 'none', gates='2') > before[0]
+    assert _ways()['kernel' if platform == 'tpu' else 'composed'] \
+        > before[1]['kernel' if platform == 'tpu' else 'composed']
+    assert (obs.counter('shortconv.tokens').value - before[2]) % 96 == 0
+    assert obs.counter('shortconv.tokens').value > before[2]
+    want = formula(x, b, c, f)
+    wants = jax.grad(lambda *a: jnp.sum(formula(*a) * w),
+                     argnums=range(4))(x, b, c, f)
+    tol = 2.0 ** -6 if amp else 1e-5
+    assert np.abs(np.asarray(got, np.float32) - want).max() \
+        <= tol * np.abs(want).max()
+    for name, a, ref in zip('x b c f'.split(), grads, wants):
+        assert np.linalg.norm(np.asarray(a, np.float32) - ref) \
+            <= 2 * tol * np.linalg.norm(ref), name
+    # a gate of another shape is refused where the layer is built
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        with pytest.raises(ValueError, match='a gate of shape'):
+            layers.causal_conv1d(_input('x', x), 3,
+                                 in_gate=_input('g', x[:, :, :64]))

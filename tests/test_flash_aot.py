@@ -165,22 +165,33 @@ def test_gated_delta_intra_compiles_for_v5e(one_chip, dtype):
     assert compiled.as_text().count('tpu_custom_call') == 2
 
 
+# a cell's depthwise convolution as its rule hands it to the op: tokens a
+# row, channels, taps, activation
+_CONVS = {'qwen3next': (8192, 8192, 4, 'silu'),
+          'lfm2': (16384, 2048, 3, '')}
+
+
 @pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
-def test_causal_conv1d_compiles_for_v5e(one_chip, dtype):
+@pytest.mark.parametrize('cell', sorted(_CONVS))
+def test_causal_conv1d_compiles_for_v5e(one_chip, cell, dtype):
     """qwen3next_s8192's depthwise convolution (one row of 8192 tokens,
-    8192 channels, four taps, silu) as the rule hands it to the op,
-    forward and backward, in the cell's bf16 and in its float32 check's
-    arithmetic, at the tile `tile_of` gives each: three blocks of a tile
-    twice over are what the backward asks of VMEM."""
+    8192 channels, four taps, silu) and lfm2_s16384's (one row of 16384,
+    2048 channels, THREE taps, no activation: the kernel's first K = 3 and
+    its first row of 16384) as the rule hands them to the op, forward and
+    backward, in the cell's bf16 and in its float32 check's arithmetic, at
+    the tile `tile_of` gives each: three blocks of a tile twice over are
+    what the backward asks of VMEM."""
     from paddle_tpu.fluid.ops_impl.linear_attention_ops import causal_conv1d
     from paddle_tpu.ops.kernels import causal_conv1d as kernel
+    tokens, channels, taps, act = _CONVS[cell]
     dt = jnp.dtype(dtype)
-    assert kernel.usable(8192, 8192, 4, dt)
-    x = jax.ShapeDtypeStruct((1, 8192, 8192), dt, sharding=one_chip)
-    w = jax.ShapeDtypeStruct((4, 8192), jnp.float32, sharding=one_chip)
+    assert kernel.usable(tokens, channels, taps, dt)
+    x = jax.ShapeDtypeStruct((1, tokens, channels), dt, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((taps, channels), jnp.float32,
+                             sharding=one_chip)
 
     def loss(x, w):
-        return jnp.sum(causal_conv1d(x, w, 'silu', True)
+        return jnp.sum(causal_conv1d(x, w, act, True)
                        .astype(jnp.float32) ** 2)
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).compile()
@@ -249,6 +260,8 @@ _CELL_CALLS = {
     'qwen3next': ((1, 16, 8192, 256), None, 'triangle', 136),
     'nemotron3nano': ((1, 32, 8192, 128), None, 'triangle', 136),
     'olmoe': ((2, 16, 4096, 128), None, 'triangle', 36),
+    # heads of HALF a lane tile over 64 tiles: 16384 x 64 (PR 44)
+    'lfm2': ((1, 32, 16384, 64), None, 'triangle', 528),
 }
 
 
@@ -258,7 +271,7 @@ _CELL_CALLS = {
 @pytest.mark.parametrize('call', sorted(_CELL_CALLS))
 def test_head_backward_compiles_for_the_cells(one_chip, call, dtype,
                                               precision):
-    """Every causal attention call of the five language-model cells, the
+    """Every causal attention call of the six language-model cells, the
     forward and the ONE-pass backward over the head (PR 42), in the cell's
     bf16 and in its float32 check's arithmetic (traced under jax's highest
     matmul precision, as harness/check.py traces it; rows of D = 256 in
@@ -297,13 +310,16 @@ def test_head_backward_compiles_for_the_cells(one_chip, call, dtype,
         g: 2 * pairs * (g == grid) for g in after}
 
 
-@pytest.mark.parametrize('cell,tokens,k,width,hidden,cap,dtype', [
-    ('smallthinker_s16384', 16384, 6, 2560, 768, 49152, 'bfloat16'),
-    ('glm47flash_s8192', 8192, 4, 2048, 1536, 16384, 'float32'),
-], ids=['smallthinker', 'glm47flash_float32'])
+@pytest.mark.parametrize('cell,tokens,k,experts,width,hidden,cap,dtype', [
+    ('smallthinker_s16384', 16384, 6, 64, 2560, 768, 49152, 'bfloat16'),
+    ('glm47flash_s8192', 8192, 4, 64, 2048, 1536, 16384, 'float32'),
+    ('lfm2_s16384', 16384, 4, 32, 2048, 1792, 32768, 'bfloat16'),
+], ids=['smallthinker', 'glm47flash_float32', 'lfm2_a_quarter'])
 def test_an_eighth_held_compiles_both_paths_for_v5e(
-        one_chip, cell, tokens, k, width, hidden, cap, dtype):
-    """A held expert layer of the two 8-of-64 cells from its keys on
+        one_chip, cell, tokens, k, experts, width, hidden, cap, dtype):
+    """A held expert layer of the two 8-of-64 cells, and of the cell that
+    holds a QUARTER (8 of 32: half the layer's rows are twice the expected
+    ones, PR 44), from its keys on
     (`_held_paths`: the conditional, the layout of half the rows and the
     blocks behind it), forward and backward at the cell's shapes, in one
     cell's bf16 and in the other's float32 check's arithmetic: the
@@ -315,7 +331,7 @@ def test_an_eighth_held_compiles_both_paths_for_v5e(
     import re
     import types
     from paddle_tpu.fluid.ops_impl import moe_ops
-    assert moe_ops._held_layout(tokens * k, 8, 64) == cap
+    assert moe_ops._held_layout(tokens * k, 8, experts) == cap
     dt = jnp.dtype(dtype)
 
     def like(shape, dtype):

@@ -559,11 +559,13 @@ def test_blocks_are_one_part_each_scopes_regions_and_counters():
     moe = dict(path='grouped', held='4of16', dispatch='index',
                scoring='sigmoid', act='relu2', gated='false')
     before = (obs.counter('moe.lowered', **moe).value,
-              obs.counter('conv1d.lowered', bias='true').value,
+              obs.counter('conv1d.lowered', taps=4, act='silu',
+                          bias='true').value,
               obs.counter('moe.bias_updates').value)
     config, built = _build_toy(cell, train=True)
     assert obs.counter('moe.lowered', **moe).value - before[0] == 4
-    assert obs.counter('conv1d.lowered', bias='true').value - before[1] == 4
+    assert obs.counter('conv1d.lowered', taps=4, act='silu',
+                          bias='true').value - before[1] == 4
     assert obs.counter('moe.bias_updates').value - before[2] == 4
     ops = built['main'].global_block().ops
     forward = [op for op in ops if not op.type.endswith('_grad')]

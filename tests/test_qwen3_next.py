@@ -206,7 +206,7 @@ def test_causal_conv1d_is_a_four_term_sum():
     x = rng.normal(size=(2, 11, 6)).astype('float32')
     f = rng.normal(size=(4, 6)).astype('float32')
     w = rng.normal(size=(2, 11, 6)).astype('float32')
-    before = obs.counter('conv1d.lowered').value
+    before = obs.counter('conv1d.lowered', taps=4, act='silu').value
 
     def build():
         return layers.causal_conv1d(
@@ -229,7 +229,7 @@ def test_causal_conv1d_is_a_four_term_sum():
     # causal: token t sees nothing after t; the last tap is token t's own
     np.testing.assert_allclose(
         got[:, 0], jax.nn.silu(f[3] * x[:, 0]), rtol=1e-5, atol=1e-6)
-    assert obs.counter('conv1d.lowered').value > before
+    assert obs.counter('conv1d.lowered', taps=4, act='silu').value > before
     assert 'causal_conv1d_' in text
 
 
