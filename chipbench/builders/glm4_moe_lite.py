@@ -21,6 +21,7 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import framework, unique_name
 from paddle_tpu.models import glm4_moe_lite as G
 
+from chipbench.builders.adam import adam
 from chipbench.harness import check
 
 
@@ -58,9 +59,7 @@ def build(config, traffic, train=True):
             mtp_weight=m['mtp_loss_weight'], std=m['initializer_range'])
         grads = {}
         if train:
-            fluid.optimizer.Adam(
-                learning_rate=opt['learning_rate'], beta1=opt['beta1'],
-                beta2=opt['beta2'], epsilon=opt['epsilon']).minimize(loss)
+            adam(opt).minimize(loss)
             G.router_bias_updates(counts, biases,
                                   rate=m['bias_update_speed'])
         else:

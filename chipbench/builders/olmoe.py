@@ -13,6 +13,7 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import framework, unique_name
 from paddle_tpu.models import olmoe as O
 
+from chipbench.builders.adam import adam
 from chipbench.harness import check
 
 
@@ -32,9 +33,7 @@ def build(config, traffic, train=True):
             aux_coef=m['router_aux_loss_coef'], std=m['initializer_range'])
         grads = {}
         if train:
-            fluid.optimizer.Adam(
-                learning_rate=opt['learning_rate'], beta1=opt['beta1'],
-                beta2=opt['beta2'], epsilon=opt['epsilon']).minimize(loss)
+            adam(opt).minimize(loss)
         else:
             want = set(config['check']['grads'])
             grads = {p.name: g for p, g in fluid.backward.append_backward(loss)

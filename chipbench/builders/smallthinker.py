@@ -24,6 +24,7 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import framework, unique_name
 from paddle_tpu.models import smallthinker as S
 
+from chipbench.builders.adam import adam
 from chipbench.harness import check
 
 
@@ -56,14 +57,7 @@ def build(config, traffic, train=True):
             emb_std=m['embedding_initializer_range'])
         grads = {}
         if train:
-            # noam_decay(d, w) climbs linearly to (d w)^-0.5 at step w and
-            # falls as step^-0.5 after it: `learning_rate` is the peak
-            peak, warmup = opt['learning_rate'], opt['warmup_steps']
-            lr = fluid.layers.learning_rate_scheduler.noam_decay(
-                1.0 / (peak * peak * warmup), warmup)
-            fluid.optimizer.Adam(
-                learning_rate=lr, beta1=opt['beta1'], beta2=opt['beta2'],
-                epsilon=opt['epsilon']).minimize(loss)
+            adam(opt).minimize(loss)
         else:
             want = set(config['check']['grads'])
             grads = {p.name: g for p, g in fluid.backward.append_backward(loss)

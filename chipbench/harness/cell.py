@@ -260,6 +260,7 @@ def run_cell(cell, seed, seconds, traced, place, t_start, work_dir,
             1.0 - red['busy0_s'] / red['window_s'])
         summary['kernel_s'] = red['kernel_s']
         summary['kernel_by_op_s'] = red['kernel_by_op_s']
+        summary['kernel_by_callee_s'] = red['kernel_by_callee_s']
     if kernel_cost and peak:
         summary['kernel_bound'] = {op: peaks.roofline(cost, peak)[1]
                                    for op, cost in kernel_cost.items()}

@@ -13,6 +13,7 @@ does not move with the program.
 import re
 
 _SCOPE_RE = re.compile(r'(?:^|[/(])([A-Za-z][A-Za-z0-9_]*?)_(\d+)(?=[/)]|$)')
+_JIT_RE = re.compile(r'jit\(([A-Za-z_][A-Za-z0-9_]*)\)')
 _INSTR_RE = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s.*?op_name="([^"]*)"')
 
 COLLECTIVES = ('all-gather', 'all-reduce', 'reduce-scatter', 'all-to-all',
@@ -26,6 +27,16 @@ def scope_of(op_name):
     for m in _SCOPE_RE.finditer(op_name):
         best = (m.group(1), int(m.group(2)))
     return best
+
+
+def callee_of(op_name):
+    """The function a kernel's call was made in, where it is a jitted one
+    of its own: the innermost `jit(<name>)` of the op_name path after the
+    step's own (`jit(step)/moe_mlp_9/transpose(jvp(jit(tgmm)))/pallas_call`
+    -> 'tgmm': jax's megablox pair is `gmm` and `tgmm`); '' for a call a
+    rule makes itself (`jit(step)/jvp(flash_attention_4)/pallas_call`)."""
+    found = _JIT_RE.findall(op_name.partition('/')[2])
+    return found[-1] if found else ''
 
 
 def instruction_scopes(hlo_text):

@@ -116,7 +116,8 @@ def reduce(raw, instr_scopes, steps):
 
     by_op = collections.Counter()         # Fluid op type -> ns
     by_scope = collections.Counter()      # '<op type>_<index>' -> ns
-    kernel_by_op = collections.Counter()  # Fluid op type -> Mosaic ns
+    # Fluid op type -> {callee (scopes.callee_of) -> Mosaic ns}
+    kernel_by_callee = collections.defaultdict(collections.Counter)
     for name, self_ns in iv.self_times([(s, e, n) for s, e, n, _ in events0]):
         scope = scopes.scope_of(instr_scopes.get(name, ''))
         by_op[scope[0] if scope else 'unattributed'] += self_ns
@@ -127,8 +128,10 @@ def reduce(raw, instr_scopes, steps):
     # the Mosaic events alone, by the Fluid op type whose scope they lie
     # in: self times among themselves, so the parts add up to kernel_ns
     for name, self_ns in iv.self_times(kernels):
-        scope = scopes.scope_of(instr_scopes.get(name, ''))
-        kernel_by_op[scope[0] if scope else 'unattributed'] += self_ns
+        op_name = instr_scopes.get(name, '')
+        scope = scopes.scope_of(op_name)
+        kernel_by_callee[scope[0] if scope else 'unattributed'][
+            scopes.callee_of(op_name)] += self_ns
 
     # a collective is busy from its start to its done (the async span);
     # what runs meanwhile on the op queue, other than its own two ends,
@@ -158,7 +161,10 @@ def reduce(raw, instr_scopes, steps):
         'fluid_op_s': {k: v * ns for k, v in by_op.items()},
         'fluid_scope_s': {k: v * ns for k, v in by_scope.items()},
         'kernel_s': kernel_ns * ns,
-        'kernel_by_op_s': {k: v * ns for k, v in kernel_by_op.items()},
+        'kernel_by_op_s': {op: sum(by.values()) * ns
+                           for op, by in kernel_by_callee.items()},
+        'kernel_by_callee_s': {op: {c: v * ns for c, v in by.items()}
+                               for op, by in kernel_by_callee.items()},
         'collective_s': iv.total(iv.clip(coll, lo, hi)) * ns,
         'collective_exposed_s': exposed * ns,
         'idle_by_span_s': {k: v * ns for k, v in gap_ns.items()},

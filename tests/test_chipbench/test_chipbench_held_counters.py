@@ -1,8 +1,8 @@
 """The per-layer metrics that read a step's device counters (PR 35):
 `held_rows_x`, `held_blocks_layers`, `held_blocks_layers_window` and
-`held_rows_x_peak`, on the traced toy cells of the two configurations
-that hold a share of their experts, on a cell that holds them all, and on
-step records made by hand.
+`held_rows_x_peak`, on the traced toy cells of the five configurations
+that hold a share of their experts (all five listed since PR 46), on a cell
+that holds them all, and on step records made by hand.
 """
 import json
 import os
@@ -17,7 +17,11 @@ sys.path.insert(0, toy.REPO)
 
 READERS = ['held_rows_x', 'held_blocks_layers', 'held_blocks_layers_window',
            'held_rows_x_peak']
-HELD = {'qwen3next_s8192': 4, 'glm47flash_s8192': 5}     # expert layers
+# the held cells in the order BENCHMARK.json lists them, with their
+# expert layers
+HELD = {'qwen3next_s8192': 4, 'glm47flash_s8192': 5,
+        'smallthinker_s16384': 4, 'nemotron3nano_s8192': 4,
+        'lfm2_s16384': 4}
 STEP_PARTS = ['prepare_ms', 'feed_place_ms', 'rng_ms', 'dispatch_ms',
               'step_self_ms', 'placement_ms']
 
@@ -47,7 +51,7 @@ def test_the_entries_are_the_issues():
         assert entries[name] == {
             'name': name, 'unit': units[name], 'better': 'lower',
             'source': 'program_counter', 'layer': 'Lowering rules',
-            'moves': 'tokens_per_s', 'workloads': sorted(HELD, reverse=True)}
+            'moves': 'tokens_per_s', 'workloads': list(HELD)}
         assert os.path.exists(os.path.join(
             toy.REPO, 'chipbench', 'layers', name + '.py'))
 

@@ -128,6 +128,69 @@ def test_reduce_tells_the_kernels_of_two_op_types_apart():
     assert kernels.ms(dict(reading, trace=None), 'moe_mlp') is None
 
 
+@pytest.mark.parametrize('op_name, callee', [
+    ('jit(step)/jvp(flash_attention_4)/pallas_call', ''),
+    ('jit(step)/moe_mlp_9/jvp(jit(gmm))/pallas_call', 'gmm'),
+    ('jit(step)/moe_mlp_9/transpose(moe_mlp_9)/jvp(jit(tgmm))/pallas_call',
+     'tgmm'),
+    # a layer's paths are one jitted function a step (`_held_paths`), a
+    # recompute region wraps it: the innermost function is the kernel's
+    ('jit(step)/checkpoint/jit(_held_paths)/moe_mlp_3/jit(gmm)/pallas_call',
+     'gmm'),
+    ('jit(step)/jit(_held_paths)/moe_mlp_3/pallas_call', '_held_paths'),
+    ('jit(step)', ''), ('', '')])
+def test_callee_of_is_the_innermost_function_after_the_steps_own(op_name,
+                                                                 callee):
+    assert scopes.callee_of(op_name) == callee
+
+
+def test_grouped_matmul_readers_take_the_megablox_kernels_alone():
+    """Three kinds of Mosaic events in `moe_mlp` scopes: megablox's pair,
+    a kernel the rule calls itself and one in a jitted function of its
+    own (PR 45's row add was read as grouped matmul: ledger, PR 45). The
+    op's reading holds them all; `grouped_matmul_ms` and
+    `grouped_matmul_roofline` the first two."""
+    from chipbench.harness import catalog, kernels, peaks
+    instr = {'custom.1': 'jit(step)/jvp(moe_mlp_9)/jit(gmm)/pallas_call',
+             'custom.2': 'jit(step)/transpose(jvp(moe_mlp_9))/jit(gmm)/'
+                         'pallas_call',
+             'custom.3': 'jit(step)/transpose(jvp(moe_mlp_9))/jit(tgmm)/'
+                         'pallas_call',
+             'custom.4': 'jit(step)/jvp(moe_mlp_9)/pallas_call',
+             'custom.5': 'jit(step)/jvp(moe_mlp_9)/jit(row_add)/pallas_call',
+             'custom.6': 'jit(step)/jvp(flash_attention_2)/pallas_call'}
+    events = [(0, 100, 'custom.1', True), (100, 250, 'custom.2', True),
+              (300, 350, 'custom.3', True), (400, 430, 'custom.4', True),
+              (500, 570, 'custom.5', True), (600, 700, 'custom.6', True)]
+    red = trace.reduce(_raw(events), instr, steps=2)
+    assert red['kernel_by_op_s']['moe_mlp'] == pytest.approx(400e-9)
+    assert red['kernel_by_callee_s'] == {
+        'moe_mlp': {'gmm': pytest.approx(250e-9),
+                    'tgmm': pytest.approx(50e-9), '': pytest.approx(30e-9),
+                    'row_add': pytest.approx(70e-9)},
+        'flash_attention': {'': pytest.approx(100e-9)}}
+    # the parts of an op add up to the op
+    for op, by in red['kernel_by_callee_s'].items():
+        assert sum(by.values()) == pytest.approx(red['kernel_by_op_s'][op])
+    reading = {'trace': red, 'peaks': peaks.PEAKS['TPU v5 lite'],
+               'kernel_cost': {'moe_mlp': (1.0, 819e9 * 15e-9)}}
+    assert kernels.ms(reading, 'moe_mlp') == pytest.approx(200e-6)
+    assert catalog.load_reader('grouped_matmul_ms')(reading) \
+        == pytest.approx(150e-6)
+    assert catalog.load_reader('grouped_matmul_roofline')(reading) \
+        == pytest.approx(10.0)
+    assert kernels.ms(reading, 'moe_mlp', ('row_add',)) \
+        == pytest.approx(35e-6)
+    # nothing of that name in the trace: nothing to read, never 0
+    assert kernels.ms(reading, 'moe_mlp', ('ragged_dot',)) is None
+    assert kernels.roofline_pct(reading, 'flash_attention',
+                                kernels.MEGABLOX) is None
+    # without the megablox pair the two readers are silent
+    only = trace.reduce(_raw(events[3:]), instr, steps=2)
+    assert catalog.load_reader('grouped_matmul_ms')(
+        dict(reading, trace=only)) is None
+
+
 def test_reduce_exposed_collective_time_half_under_compute():
     events = [(0, 100, 'fusion.1', False),
               (50, 52, 'all-reduce-start.1', False),
