@@ -351,6 +351,29 @@ def _check_grouped_matmul():
     return max(errs), BF16_TOL
 
 
+def _check_row_add():
+    """A held share's add of its laid-out rows to their tokens, with the
+    gates and as the row gather's transpose, at (4096 tokens x 4, 8 held
+    experts, a layout of 8192 rows of 2048 with 3000 live) bf16, against
+    the scatter-add."""
+    import jax.numpy as jnp
+    from paddle_tpu.fluid.ops_impl import moe_ops
+    r = np.random.RandomState(5)
+    n, k, held, cap, d, live = 4096, 4, 8, 8192, 2048, 3000
+    flat = np.full(n * k, held, np.int32)
+    flat[r.choice(n * k, size=live, replace=False)] = r.randint(
+        0, held, size=live)
+    key = jnp.asarray(flat.reshape(n, k))
+    src = moe_ops._argsort(key.reshape(-1), held + 1)[:cap]
+    rows = moe_ops._keep(live)(jnp.asarray(r.randn(cap, d), jnp.bfloat16))
+    gate = jnp.asarray(r.rand(cap, 1), jnp.float32)
+    kernel = moe_ops._index(src, key, held, d)
+    scatter = moe_ops._index(src, key, held)
+    return max(_rel_err(moe_ops._add_up(rows, g, kernel, n, False),
+                        moe_ops._add_up(rows, g, scatter, n, None))
+               for g in (gate, None)), 1e-5
+
+
 KERNEL_CHECKS = {
     'flash_attention': lambda: _check_flash(False),
     'flash_attention_causal': lambda: _check_flash(True),
@@ -361,6 +384,7 @@ KERNEL_CHECKS = {
     'sparse_adagrad': _check_sparse_adagrad,
     'sparse_adam': _check_sparse_adam,
     'grouped_matmul': _check_grouped_matmul,
+    'row_add': _check_row_add,
 }
 
 

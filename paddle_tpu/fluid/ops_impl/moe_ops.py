@@ -65,9 +65,18 @@ the tokens x k assignments puts the held ones first in expert order; a
 layout of `_HELD_SLACK` times the expected number of held rows, and never
 more than half the layer's rows (`_held_layout`: a share of an eighth of
 the experts lays out 4 times its expected rows, one of a 32nd all 10), is
-gathered from the tokens, goes through the grouped matmuls, and is added
-back to the tokens by a scatter-add (`_compact_moe`; the cost follows the
-rows laid out, not rows x tokens). A router that sends more than that to
+gathered from the tokens, goes through the grouped matmuls, and is
+weighted by the gates and added back to the tokens (`_compact_moe`,
+`_lay_out` / `_add_up`; the cost follows the rows laid out, not rows x
+tokens). That add runs twice a layer, forward and as the gather's
+transpose. On the TPU it is one Pallas kernel (ops/kernels/row_add.py)
+that reads the rows where the matmuls left them, sorted by expert and an
+expert's by token, and walks the live ones only, a tile of tokens at a
+time; on the host, and at a shape the kernel does not take (`usable`), it
+is the scatter-add the kernel is tested against, which walks every row of
+the layout after a gather of them all into token order
+(`moe.add{way=kernel|scatter}` counts the choice at trace time, once an
+add). A router that sends more than that to
 the held experts is answered, on the device (one `lax.cond` a layer), by
 the static tokens x k rows a block at a time (`_held_blocks`), which is
 also all a layer has where half its rows are under one 256-row tile. The
@@ -125,6 +134,7 @@ from jax import lax
 from ... import obs
 from ..lowering import (DeviceCounter, amp_cast, data_of, register,
                         register_device_counter, traced_once)
+from ...ops.kernels import row_add
 
 _ACTS = {
     'relu': jax.nn.relu,
@@ -304,40 +314,89 @@ def _argsort(keys, bound):
 
 
 # The two row moves of a held share's compact path, each the other's
-# transpose, both by index: `at` is (token, order, token[order]): token
-# [cap] is the row of x that a laid-out row takes, order [cap] lists the
-# laid-out rows by token. Written as a pair so that the add is float32
-# whatever the rows are (jax's own transpose of a bf16 gather adds in
-# bf16), neither way clamps an index, and the add is given its rows in the
-# order of the rows they are added to: left to itself XLA sorts a
-# scatter's indices anew in every scatter, forward and backward, and its
-# compiler takes 11 s over each such sort of 49152 (2 s over the scatter
-# told its indices are sorted; AOT, PR 39). The zero-size residuals carry
-# a shape and a dtype.
-@jax.custom_vjp
-def _lay_out(x, at):
+# transpose, both by index. `at` is (token, steps) (`_index`): token [cap]
+# is the row of x that a laid-out row takes; `steps` is what the add goes
+# by. Written as a pair so that the add is float32 whatever the rows are
+# (jax's own transpose of a bf16 gather adds in bf16) and neither way
+# clamps an index. The add, two ways (`interpret` says which):
+#
+# - the kernel (ops/kernels/row_add.py; `interpret` True or False, as the
+#   kernel takes it): `steps` is its plan, the rows are read where the
+#   grouped matmuls left them, the live ones only, and the gates are its
+#   second operand, so no float32 product of the layout is written;
+# - the scatter-add (`interpret` None): `steps` is (order, token[order]),
+#   order [cap] the laid-out rows by token, so that the add is given its
+#   rows in the order of the rows they are added to: left to itself XLA
+#   sorts a scatter's indices anew in every scatter, forward and backward,
+#   and its compiler takes 11 s over each such sort of 49152 (2 s over the
+#   scatter told its indices are sorted; AOT, PR 39).
+#
+# The zero-size residuals carry a shape and a dtype.
+def _index(src, key, groups, width=None):
+    """`at` of a layout: `src` [cap] are the laid-out assignments'
+    positions among the token-major `key` [n, k] (each assignment's group,
+    or `groups` for one without a row). `width`: the rows' width where the
+    add is the kernel's, None where it is the scatter's."""
+    n, k = key.shape
+    token = src // k
+    if width is not None:
+        return token, row_add.plan(key, groups, src.shape[0], width)
+    order = _argsort(token, n)
+    return token, (order, _rows(token, order))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _lay_out(x, at, interpret):
     """x[token]: the laid-out rows [cap, d] of x [n, d]."""
     return _rows(x, at[0])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _add_up(rows, at, n):
-    """The laid-out rows [cap, d] added to the rows of x they came from:
-    [n, d] float32. A row that no assignment fills must come as zeros."""
-    _, order, by_token = at
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _add_up(rows, gate, at, n, interpret):
+    """The laid-out rows [cap, d], each times its gate ([cap, 1] float32;
+    None: as they are), added to the rows of x they came from: [n, d]
+    float32. A row that no assignment fills must come as zeros."""
+    token, steps = at
+    if interpret is not None:
+        return row_add.row_add(
+            rows, token, None if gate is None else gate.reshape(-1), steps,
+            n=n, interpret=interpret)
+    order, by_token = steps
+    if gate is not None:
+        rows = rows.astype(jnp.float32) * gate
     zero = jnp.zeros((n,) + rows.shape[1:], jnp.float32)
     return zero.at[by_token].add(
         _rows(rows, order).astype(jnp.float32), indices_are_sorted=True,
         mode='promise_in_bounds')
 
 
+def _add_up_fwd(rows, gate, at, n, interpret):
+    return (_add_up(rows, gate, at, n, interpret),
+            (at, rows[:0], gate) if gate is None else (at, rows, gate))
+
+
+def _add_up_bwd(n, interpret, res, g):
+    at, rows, gate = res
+    g = _lay_out(g, at, interpret)
+    if gate is None:
+        return g.astype(rows.dtype), None, None
+    return ((g * gate).astype(rows.dtype),
+            jnp.sum(g * rows.astype(jnp.float32), axis=-1, keepdims=True),
+            None)
+
+
 _lay_out.defvjp(
-    lambda x, at: (_lay_out(x, at), (at, x[:, :0])),
-    lambda res, g: (_add_up(g, res[0], res[1].shape[0]).astype(res[1].dtype),
-                    None))
-_add_up.defvjp(
-    lambda rows, at, n: (_add_up(rows, at, n), (at, rows[:0])),
-    lambda n, res, g: (_lay_out(g, res[0]).astype(res[1].dtype), None))
+    lambda x, at, interpret: (_lay_out(x, at, interpret), (at, x[:, :0])),
+    lambda interpret, res, g: (
+        _add_up(g, None, res[0], res[1].shape[0], interpret
+                ).astype(res[1].dtype), None))
+_add_up.defvjp(_add_up_fwd, _add_up_bwd)
+
+
+def _add_kernel(ctx, cap, n, d, dtype):
+    """Is a layout's add the Pallas kernel? On the TPU, at the shapes it
+    takes; the scatter-add on every other platform and shape."""
+    return ctx.platform == 'tpu' and row_add.usable(cap, n, d, dtype)
 
 
 def _compact_moe(params, x, key, gate, sizes, cap, act, ctx):
@@ -346,17 +405,18 @@ def _compact_moe(params, x, key, gate, sizes, cap, act, ctx):
     tokens x k rows is built. One stable sort of the tokens x k keys puts
     the held assignments first, in expert order; its first `cap` entries
     are the assignments that get a row. The rows are gathered by that
-    index (`_lay_out`), go through the experts, are weighted by their
-    gates and added to their tokens (`_add_up`). `key` [nt, k] is the held
+    index (`_lay_out`), go through the experts, and are added to their
+    tokens, each times its gate (`_add_up`). `key` [nt, k] is the held
     expert's index or, for an absent one, the number of held experts;
     `sizes` are the held experts' counts; `x` is in the experts' dtype."""
     nt, k = key.shape
+    kernel = _add_kernel(ctx, cap, nt, x.shape[1], x.dtype)
+    interpret = ctx.pallas_interpret if kernel else None
     with jax.named_scope('moe_route'):
         flat = key.reshape(-1)                         # token-major
         src = _argsort(flat, sizes.shape[0] + 1)[:cap]
-        token = src // k
-        order = _argsort(token, nt)
-        at = (token, order, _rows(token, order))
+        at = _index(src, key, sizes.shape[0],
+                    x.shape[1] if kernel else None)
         # `keep`: a row past `live` is some absent assignment's token, and
         # the kernels' gradient of the rows is unwritten there
         keep = _keep(jnp.sum(sizes))
@@ -370,12 +430,12 @@ def _compact_moe(params, x, key, gate, sizes, cap, act, ctx):
     @jax.checkpoint
     def rows_of(params, x, gate):
         with jax.named_scope('moe_route'):
-            rows = keep(_lay_out(x, at))
+            rows = keep(_lay_out(x, at, interpret))
             row_gate = _rows(gate.reshape(-1, 1), src)
         with jax.named_scope('moe_experts'):
             out = _experts(params, rows, sizes, group, act, ctx, keep)
         with jax.named_scope('moe_combine'):
-            return _add_up(out.astype(jnp.float32) * row_gate, at, nt)
+            return _add_up(out, row_gate, at, nt, interpret)
 
     return rows_of(params, x, gate)
 
@@ -462,6 +522,11 @@ def _held_moe(params, x, expert, gate, sizes, held, act, ctx):
         local = expert - first
         key = jnp.where((local >= 0) & (local < count), local, count)
     cap = _held_layout(nt * k, count, sizes.shape[0])
+    if cap is not None:
+        # the layout's two adds, forward and the row gather's transpose
+        way = 'kernel' if _add_kernel(ctx, cap, nt, x.shape[1], x.dtype) \
+            else 'scatter'
+        obs.counter('moe.add', way=way).inc(2)
     paths = traced_once(ctx, _held_paths, cap=cap, act=act)
     return paths(params, x, key, gate, sizes[first:first + count],
                  _held_rows(sizes, held))

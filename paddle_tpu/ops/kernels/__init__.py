@@ -45,7 +45,16 @@ platform's and shape's path:
   * `causal_conv1d` — the op `causal_conv1d`, one kernel forward and one
     backward that shift the K taps in VMEM (else
     `linear_attention_ops._conv`); `conv1d.way{way=kernel|composed}`
-    counts the choice at trace time.
+    counts the choice at trace time;
+  * `ssd_scan` — the op `ssd_scan`, one kernel forward and one backward
+    with the state in VMEM (else the composition's three stages);
+    `ssd.way{way=kernel|composed}`;
+  * `row_add` — the add of a held share's laid-out rows to their tokens
+    (`moe_ops._add_up`: forward under `moe_combine`, and as the row
+    gather's transpose in the backward pass), one kernel that walks the
+    live rows only (else the sorted scatter-add);
+    `moe.add{way=kernel|scatter}` counts the choice at trace time, once
+    for each of a layout's two adds.
 """
 import os
 

@@ -1,6 +1,6 @@
 """The flash kernels at their default tiles, the grouped-matmul kernels at
-theirs, the delta rule's chunk kernels, the causal convolution's and the
-state-space scan's, compiled by Mosaic for a DESCRIBED v5e (no chip,
+theirs, the delta rule's chunk kernels, the causal convolution's, the
+state-space scan's and a held share's row add, compiled by Mosaic for a DESCRIBED v5e (no chip,
 nothing runs): what the
 interpreter and jax.export cannot refuse — VMEM the kernel may not have,
 slices Mosaic will not tile — is refused here. The cells' shapes, and the
@@ -310,6 +310,47 @@ def test_head_backward_compiles_for_the_cells(one_chip, call, dtype,
         g: 2 * pairs * (g == grid) for g in after}
 
 
+# a held cell's layer: tokens, top k, held, routed experts, width
+_HELD = {'smallthinker': (16384, 6, 8, 64, 2560),
+         'lfm2': (16384, 4, 8, 32, 2048),
+         'nemotron3nano': (8192, 6, 8, 128, 2688),
+         'qwen3next': (8192, 10, 16, 512, 2048),
+         'glm47flash': (8192, 4, 8, 64, 2048)}
+
+
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+@pytest.mark.parametrize('cell', sorted(_HELD))
+def test_row_add_compiles_for_v5e(one_chip, cell, dtype):
+    """The add of a held share's rows to their tokens at each held cell's
+    layout, in the step's bf16 and in its float32 check's rows, forward
+    (the rows times their gates) and as the row gather's transpose: one
+    Mosaic call each over one plan, and within the default scoped VMEM
+    (the kernel states no limit)."""
+    from paddle_tpu.fluid.ops_impl import moe_ops
+    from paddle_tpu.ops.kernels import row_add
+    tokens, k, held, routed, width = _HELD[cell]
+    cap = moe_ops._held_layout(tokens * k, held, routed)
+    dt = jnp.dtype(dtype)
+    assert row_add.usable(cap, tokens, width, dt)
+
+    def like(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(key, src, out, gate, g):
+        at = moe_ops._index(src, key, held, width)
+        return (moe_ops._add_up(out, gate, at, tokens, False),
+                moe_ops._add_up(g, None, at, tokens, False))
+
+    text = jax.jit(both).lower(
+        like((tokens, k), jnp.int32), like((cap,), jnp.int32),
+        like((cap, width), dt), like((cap, 1), jnp.float32),
+        like((cap, width), dt)).compile().as_text()
+    assert text.count('tpu_custom_call') == 2
+    # a call that states a `vmem_limit_bytes` carries a scoped memory
+    # config; this one's list stays empty
+    assert '"scoped_memory_configs":[{' not in text
+
+
 @pytest.mark.parametrize('cell,tokens,k,experts,width,hidden,cap,dtype', [
     ('smallthinker_s16384', 16384, 6, 64, 2560, 768, 49152, 'bfloat16'),
     ('glm47flash_s8192', 8192, 4, 64, 2048, 1536, 16384, 'float32'),
@@ -324,8 +365,9 @@ def test_an_eighth_held_compiles_both_paths_for_v5e(
     blocks behind it), forward and backward at the cell's shapes, in one
     cell's bf16 and in the other's float32 check's arithmetic: the
     layout's size is the rule's, both paths take the Mosaic grouped
-    matmuls (3 forward, 3 again, 3 + 3 backward, a path), and the
-    compact path sorts one operand (`_argsort`: the TPU's compiler takes
+    matmuls (3 forward, 3 again, 3 + 3 backward, a path), the compact
+    path's two adds are the row-add kernel (forward, and the row gather's
+    transpose in the backward pass), and the compact path sorts one operand (`_argsort`: the TPU's compiler takes
     seven times as long over a stable sort of keys beside their
     positions)."""
     import re
@@ -340,7 +382,7 @@ def test_an_eighth_held_compiles_both_paths_for_v5e(
     params = {'w1': like((8, width, hidden), dt),
               'w3': like((8, width, hidden), dt),
               'w2': like((8, hidden, width), dt)}
-    ctx = types.SimpleNamespace(platform='tpu')
+    ctx = types.SimpleNamespace(platform='tpu', pallas_interpret=False)
 
     def loss(params, x, gate, key, sizes):
         y = moe_ops._held_paths(ctx, params, x, key, gate, sizes,
@@ -351,7 +393,7 @@ def test_an_eighth_held_compiles_both_paths_for_v5e(
         params, like((tokens, width), dt), like((tokens, k), jnp.float32),
         like((tokens, k), jnp.int32), like((8,), jnp.int32)).compile()
     text = compiled.as_text()
-    assert text.count('tpu_custom_call') == 24
+    assert text.count('tpu_custom_call') == 26
     # the compact path sorts its tokens x k keys as ONE operand (a result
     # that is an array, not argsort's pair)
     assert re.search(r'= s32\[%d\]\S* sort\(' % (tokens * k), text)
