@@ -123,11 +123,25 @@ the kernel is tested against. The rule chooses as it does for the delta
 rule's stage.
 
 `gated_rms_norm`: y = w * x * rsqrt(mean(x^2) + eps) * silu(gate) over the
-last axis, statistics in float32; its backward keeps x and the gate
-(bf16 under AMP) and computes the rest again. Two attributes give
+last axis, statistics in float32; its backward keeps x, the gate (bf16
+under AMP) and w and computes the rest again. Two attributes give
 Mamba-2's form: `norm_before_gate` false gates FIRST,
 y = w * rmsnorm(x * silu(gate)), and `groups` G takes the mean over each
 of G equal parts of the last axis by itself.
+On the TPU, for groups of whole lane tiles and rows that merge without a
+copy (`usable` of ops/kernels/gated_norm.py), it is one Pallas kernel
+forward and one backward whose block holds a group's columns: the mean is
+taken in VMEM along the lanes, each pass moves its arrays once, the
+backward gives dx, dgate and dw from one read and computes `inv` again in
+VMEM, so it runs no forward again and holds nothing behind a barrier. A
+float32 x [.., heads, 128] with one group is read BY HEAD, where its
+producer's heads left it, beside the gate and the result as the matmuls
+round the op hold them, so nothing moves to meet the kernel's view. The
+same arithmetic in the same precisions, the result in x's dtype. Every
+other platform and shape takes `_gated_norm` below (with G > 1 a reshape
+to [.., G, width], which on the TPU puts the groups where the tiles keep
+eight rows and moves the array round the sum), the composition the kernel
+is tested against. The rule chooses as it does for the delta rule's stage.
 
 Trace-time counters: `gdn.lowered{chunk=}` once per op per trace,
 `gdn.intra{way=kernel|composed}` beside it (which way stage `gdn_intra`
@@ -138,7 +152,8 @@ went), `gdn.tokens` the B x T of the traced shape,
 `gates=1|2` where the op has them; `obs.REGISTRY.total('conv1d.lowered')`
 is every form's), `shortconv.tokens` the B x T of an op with both gates
 and `conv1d.way{way=kernel|composed}` beside it,
-`gated_rms_norm.lowered`.
+`gated_rms_norm.lowered` and `gated_rms_norm.way{way=kernel|composed}`
+beside it.
 """
 import functools
 
@@ -150,6 +165,7 @@ from jax import lax
 from ... import obs
 from ...ops.kernels import causal_conv1d as conv_kernel
 from ...ops.kernels import gated_delta_intra as intra_kernel
+from ...ops.kernels import gated_norm as norm_kernel
 from ...ops.kernels import ssd_scan as ssd_kernel
 from ..lowering import register, data_of, amp_cast
 
@@ -625,20 +641,37 @@ def _gated_norm(x, gate, w, cfg):
     return y * jax.nn.silu(gate.astype(jnp.float32)) if norm_first else y
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def gated_rms_norm(x, gate, w, cfg):
-    """`cfg` = (eps, norm_before_gate, groups), float32:
-    w * x * rsqrt(mean(x^2) + eps) * silu(gate), or with
-    norm_before_gate false w * u * rsqrt(mean(u^2) + eps), u = x *
-    silu(gate); the mean over each of `groups` parts of the last axis."""
+def _kernel_args(cfg):
+    eps, norm_first, groups = cfg
+    return dict(eps=eps, norm_first=norm_first, groups=groups,
+                interpret=False)
+
+
+def _gated_norm_forward(x, gate, w, cfg, kernel):
+    if kernel:
+        return norm_kernel.gated_norm_fwd(x, gate, w, **_kernel_args(cfg))
     return _gated_norm(x, gate, w, cfg)
 
 
-def _gated_norm_fwd(x, gate, w, cfg):
-    return _gated_norm(x, gate, w, cfg), (x, gate, w)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def gated_rms_norm(x, gate, w, cfg, kernel=False):
+    """`cfg` = (eps, norm_before_gate, groups), float32:
+    w * x * rsqrt(mean(x^2) + eps) * silu(gate), or with
+    norm_before_gate false w * u * rsqrt(mean(u^2) + eps), u = x *
+    silu(gate); the mean over each of `groups` parts of the last axis.
+    `kernel`: the Pallas kernels, forward and backward, which give the
+    result in x's dtype (the rule's choice; the caller has asked their
+    `usable`), else `_gated_norm`."""
+    return _gated_norm_forward(x, gate, w, cfg, kernel)
 
 
-def _gated_norm_bwd(cfg, res, g):
+def _gated_norm_fwd(x, gate, w, cfg, kernel):
+    return _gated_norm_forward(x, gate, w, cfg, kernel), (x, gate, w)
+
+
+def _gated_norm_bwd(cfg, kernel, res, g):
+    if kernel:      # `inv` again in VMEM: nothing XLA could keep instead
+        return norm_kernel.gated_norm_bwd(*res, g, **_kernel_args(cfg))
     res, g = _recompute_after(res, g)
     return jax.vjp(lambda *a: _gated_norm(*a, cfg), *res)[1](g)
 
@@ -649,10 +682,16 @@ gated_rms_norm.defvjp(_gated_norm_fwd, _gated_norm_bwd)
 @register('gated_rms_norm')
 def _gated_rms_norm(ins, attrs, ctx):
     x = data_of(ins['X'][0])
+    gate = amp_cast(ctx, data_of(ins['Gate'][0]))
+    groups = int(attrs.get('groups', 1))
     obs.counter('gated_rms_norm.lowered').inc()              # trace time
-    y = gated_rms_norm(x, amp_cast(ctx, data_of(ins['Gate'][0])),
-                       data_of(ins['Scale'][0]),
+    # on the TPU, for a shape they take, one Pallas kernel each way
+    kernel = ctx.platform == 'tpu' and norm_kernel.usable(
+        x.shape, groups, x.dtype, gate.dtype)
+    obs.counter('gated_rms_norm.way',                        # trace time
+                way='kernel' if kernel else 'composed').inc()
+    y = gated_rms_norm(x, gate, data_of(ins['Scale'][0]),
                        (float(attrs.get('epsilon', 1e-5)),
-                        bool(attrs.get('norm_before_gate', True)),
-                        int(attrs.get('groups', 1))))
+                        bool(attrs.get('norm_before_gate', True)), groups),
+                       kernel)
     return {'Y': y.astype(x.dtype)}

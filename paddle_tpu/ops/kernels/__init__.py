@@ -49,6 +49,11 @@ platform's and shape's path:
   * `ssd_scan` — the op `ssd_scan`, one kernel forward and one backward
     with the state in VMEM (else the composition's three stages);
     `ssd.way{way=kernel|composed}`;
+  * `gated_norm` — the op `gated_rms_norm`, one kernel forward and one
+    backward whose block holds a group's columns, a float32 x
+    [.., heads, 128] read by head (else
+    `linear_attention_ops._gated_norm`);
+    `gated_rms_norm.way{way=kernel|composed}`;
   * `row_add` — the add of a held share's laid-out rows to their tokens
     (`moe_ops._add_up`: forward under `moe_combine`, and as the row
     gather's transpose in the backward pass), one kernel that walks the

@@ -8,6 +8,7 @@ shapes on either side of the default-tile rule (_default_tile) and of the
 backward's schedule. The
 topology is described inside a fixture and in this file only: one process
 at a time may load the TPU's library."""
+import math
 import os
 
 import pytest
@@ -248,6 +249,58 @@ def test_ssd_scan_compiles_for_v5e(one_chip, dtype):
     assert compiled.as_text().count('tpu_custom_call') == 2
     # y, its cotangent and the starts, 134 MB each, and no decays
     assert compiled.memory_analysis().temp_size_in_bytes < 4.2 * 2 ** 27
+
+
+# a cell's gated norm as its rule hands it to the op: x's shape, groups,
+# norm_before_gate
+_NORMS = {'nemotron3nano': ((1, 8192, 4096), 8, False),
+          'qwen3next': ((1, 8192, 32, 128), 1, True)}
+
+
+# the (x, gate) dtypes of a cell's three Programs: the step and the AMP
+# checks read the gate in bf16, the float32 check in float32
+@pytest.mark.parametrize('gate', ['bfloat16', 'float32'])
+@pytest.mark.parametrize('cell', sorted(_NORMS))
+def test_gated_norm_compiles_for_v5e(one_chip, cell, gate):
+    """nemotron3nano_s8192's gated norm (gate first, 8 groups of 512
+    columns of [8192, 4096]) and qwen3next_s8192's (norm first, a head of
+    128 as the last axis of [8192, 32, 128]) as the rule hands them to the
+    op, forward and backward, x float32 and the gate in the step's bf16 and
+    in the float32 check's: two Mosaic calls, no copy round them (the rows
+    merge as a bitcast), within the default scoped VMEM (the kernels state
+    no limit), and nothing of x's size kept between them but the three
+    inputs."""
+    from paddle_tpu.fluid.ops_impl.linear_attention_ops import gated_rms_norm
+    from paddle_tpu.ops.kernels import gated_norm as kernel
+    shape, groups, first = _NORMS[cell]
+    assert kernel.usable(shape, groups, jnp.float32, jnp.dtype(gate))
+
+    def like(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    # as in the cells' steps: the gate comes from a matmul and the result
+    # goes to one, [B, T, all the columns] on both sides of the op
+    flat = shape[:2] + (math.prod(shape[2:]),)
+
+    def loss(x, z, w):
+        y = gated_rms_norm(x, z.reshape(shape), w, (1e-5, first, groups),
+                           True)
+        return jnp.sum(y.reshape(flat) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        like(shape, 'float32'),
+        like(flat, gate),
+        like(shape[-1:], 'float32')).compile()
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 2
+    # a call that states a `vmem_limit_bytes` carries a scoped memory
+    # config; these calls' lists stay empty
+    assert '"scoped_memory_configs":[{' not in text
+    assert ' copy(' not in text and ' transpose(' not in text
+    # y and its cotangent beside the gradients, which are outputs: no
+    # third array of x's size is alive
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.1 * 2 ** 27
 
 
 # the causal attention calls of the five language-model cells as their

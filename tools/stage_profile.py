@@ -4,7 +4,7 @@ rules name with `jax.named_scope` (`gdn_intra`, `gdn_scan`, `moe_route`,
 their op. On the chip only.
 
     python tools/stage_profile.py --workload qwen3next_s8192 [--seed N]
-        [--steps 3] [--top 12]
+        [--steps 3] [--top 12] [--op gated_rms_norm]
 
 Builds the cell's training step as chipbench/run.py does (no reference
 check), warms it up, traces `--steps` steps and reduces the trace with
@@ -13,7 +13,8 @@ device event's self time goes to `<op type>/<stage>`, the stage being the
 last path element of its HLO op_name that one of STAGES names (or `-`).
 Prints one JSON line: ms a step by op type and stage, forward apart from
 backward (`transpose(` in the op_name), and the largest unattributed
-instructions by name.
+instructions by name; with `--op TYPE`, that op type's instructions by
+name besides (which fusions and copies an op's time is).
 """
 import argparse
 import collections
@@ -34,6 +35,7 @@ def main(argv=None):
     p.add_argument('--seed', type=int, default=1)
     p.add_argument('--steps', type=int, default=3)
     p.add_argument('--top', type=int, default=12)
+    p.add_argument('--op', help='list this op type\'s instructions by name')
     args = p.parse_args(argv)
 
     import shutil
@@ -72,7 +74,7 @@ def main(argv=None):
     shutil.rmtree(out, ignore_errors=True)
     events = raw['devices'][sorted(raw['devices'])[0]]
     by_stage = collections.Counter()
-    loose = collections.Counter()
+    loose, inside = collections.Counter(), collections.Counter()
     for name, self_ns in intervals.self_times(
             [(s, e, n) for s, e, n, _ in events]):
         op_name = names.get(name, '')
@@ -81,6 +83,8 @@ def main(argv=None):
         way = 'bwd' if 'transpose(' in op_name else 'fwd'
         if scope is None:
             loose[name.rstrip('0123456789.')] += self_ns
+        elif scope[0] == args.op:
+            inside['%s/%s' % (name, way)] += self_ns
         by_stage['%s/%s/%s' % (scope[0] if scope else 'unattributed',
                                stage, way)] += self_ns
     per_step = 1e-6 / args.steps
@@ -91,7 +95,10 @@ def main(argv=None):
                         v * per_step >= 0.05},
         'unattributed_ms_per_step': {
             k: round(v * per_step, 3)
-            for k, v in loose.most_common(args.top)}}))
+            for k, v in loose.most_common(args.top)},
+        **({'instructions_ms_per_step': {
+            k: round(v * per_step, 3) for k, v in inside.most_common()}}
+           if args.op else {})}))
     exe.close()
     return 0
 
