@@ -86,7 +86,10 @@ def main(argv=None):
         overrides = chipbench_toy.toy_overrides(name)
     cell = catalog.load_cell(args.workload, overrides=overrides)
     config, traffic = cell['config'], cell['traffic']
-    routed, held = cell['builder'].experts(config)
+    # builders/afmoe.py names it `held_share` (its docstring says why)
+    share = getattr(cell['builder'], 'experts', None) \
+        or cell['builder'].held_share
+    routed, held = share(config)
     if held is None:
         raise SystemExit('%s holds every expert: nothing to measure'
                          % args.workload)
