@@ -48,8 +48,16 @@ the same arithmetic in the same precisions, W, Qg, Kd and P handed over in
 the matmuls' dtype (what the scan's matmuls round them to anyway), the
 backward's recomputed and transposed passes one grid step. Every other
 platform and shape takes `_intra` below, the composition the kernel is
-tested against. The rule chooses on what it can see (platform, shapes,
-dtype); nothing else selects it.
+tested against. Stage `gdn_scan` reads there what that kernel hands over
+and is three calls of two Pallas kernels (`usable` of
+ops/kernels/gated_delta_scan.py: the chunks the grid's last, sequential
+axis, S a float32 VMEM scratch): the forward walk for O, in the backward
+the forward walk again for S at each chunk's start (a temporary of the
+backward, never a residual) and the reverse walk with dS in the scratch,
+the transposition of `_chunk_step`. Every other platform and shape takes
+the `lax.scan`s of `_chunk_step` below, which the kernels are tested
+against. The rule chooses on what it can see (platform, shapes, dtype);
+nothing else selects it.
 
 Under AMP the rule is one of the MXU's: q, k, v are cast to bf16
 (`lowering.amp_cast`; they are what the backward keeps) and every matmul
@@ -144,8 +152,9 @@ eight rows and moves the array round the sum), the composition the kernel
 is tested against. The rule chooses as it does for the delta rule's stage.
 
 Trace-time counters: `gdn.lowered{chunk=}` once per op per trace,
-`gdn.intra{way=kernel|composed}` beside it (which way stage `gdn_intra`
-went), `gdn.tokens` the B x T of the traced shape,
+`gdn.intra{way=kernel|composed}` and `gdn.scan{way=kernel|composed}`
+beside it (which way each stage went), `gdn.tokens` the B x T of the
+traced shape,
 `ssd.lowered{chunk=, heads=, groups=}` and `ssd.tokens` likewise and
 `ssd.way{way=kernel|composed}` beside them,
 `conv1d.lowered{taps=K, act=silu|none}` (and the labels `bias=true` and
@@ -165,6 +174,7 @@ from jax import lax
 from ... import obs
 from ...ops.kernels import causal_conv1d as conv_kernel
 from ...ops.kernels import gated_delta_intra as intra_kernel
+from ...ops.kernels import gated_delta_scan as delta_scan
 from ...ops.kernels import gated_norm as norm_kernel
 from ...ops.kernels import ssd_scan as ssd_kernel
 from ..lowering import register, data_of, amp_cast
@@ -301,7 +311,7 @@ def _stage_intra(q, k, v, g, beta, cfg):
     into chunks (the padding tokens change nothing: k = 0, beta = 0,
     g = 0), then stage `gdn_intra`: the kernel where `cfg` says so (with G
     summed here: a scan over 64 that XLA does in passing), else `_intra`."""
-    chunk, scale, l2norm, eps, kernel = cfg
+    chunk, scale, l2norm, eps, kernel = cfg[:5]
     dtype = v.dtype
     qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
     if l2norm:
@@ -321,19 +331,53 @@ def _stage_intra(q, k, v, g, beta, cfg):
     return _intra(q, k, v, g, beta)
 
 
-def _zero_state(q, v):
-    return jnp.zeros((q.shape[0], v.shape[2], q.shape[3], v.shape[3]),
-                     jnp.float32)
+def _zero_state(xs):
+    """S = 0 [B, H, Dk, Dv] for the chunks `xs` of stage `gdn_intra`."""
+    w, u = xs[:2]
+    return jnp.zeros(w.shape[1:3] + (w.shape[4], u.shape[4]), jnp.float32)
+
+
+def _scan(xs, dtype, kernel):
+    """Stage `gdn_scan` from S = 0 over `xs`, what stage `gdn_intra` hands
+    over of every chunk: the tokens' outputs [B, N x C, H, Dv] float32. The
+    Pallas kernels where `kernel` says so (they write that layout
+    themselves), else a `lax.scan` of `_chunk_step`."""
+    if kernel:
+        return delta_scan.gated_delta_scan(xs, dtype, False)
+    o = lax.scan(functools.partial(_chunk_step, dtype=dtype),
+                 _zero_state(xs), xs)[1]
+    return _from_chunks(o, o.shape[0] * o.shape[3])
+
+
+def _scan_bwd(xs, do, dtype, kernel):
+    """The tokens' cotangents `do` [B, N x C, H, Dv] pulled back through
+    the scan to `xs`, in two walks: forward again for S at each chunk's
+    start (a temporary), then the chunks in reverse with the transposed
+    step."""
+    if kernel:      # the kernels' own backward is those two walks; the
+        # forward that jax.vjp runs first has no reader and leaves no call
+        return jax.vjp(lambda *x: _scan(x, dtype, True), *xs)[1](do)
+    step = functools.partial(_chunk_step, dtype=dtype)
+    _, starts = lax.scan(lambda s, x: (step(s, x)[0], s), _zero_state(xs),
+                         xs)
+
+    def body(ds, inp):
+        s, x, do_c = inp
+        _, back = jax.vjp(step, s, x)
+        return back((ds, do_c))
+
+    return lax.scan(body, jnp.zeros_like(starts[0]),
+                    (starts, xs, _to_chunks(do, xs[0].shape[3])),
+                    reverse=True)[1]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _chunked(q, k, v, g, beta, cfg):
-    step = functools.partial(_chunk_step, dtype=v.dtype)
     with jax.named_scope('gdn_intra'):
         xs = _stage_intra(q, k, v, g, beta, cfg)
     with jax.named_scope('gdn_scan'):
-        _, o = lax.scan(step, _zero_state(q, v), xs)
-    return _from_chunks(o, q.shape[1])
+        o = _scan(xs, v.dtype, cfg[5])
+    return o[:, :q.shape[1]]
 
 
 def _chunked_fwd(q, k, v, g, beta, cfg):
@@ -350,23 +394,13 @@ def _recompute_after(res, g):
 
 def _chunked_bwd(cfg, res, do):
     (q, k, v, g, beta), do = _recompute_after(res, do)
-    step = functools.partial(_chunk_step, dtype=v.dtype)
     with jax.named_scope('gdn_intra'):
         xs, pull = jax.vjp(
             lambda *a: _stage_intra(*a, cfg), q, k, v, g, beta)
     with jax.named_scope('gdn_scan'):
-        # S at each chunk's start, by the forward's scan over again
-        _, starts = lax.scan(lambda s, x: (step(s, x)[0], s),
-                             _zero_state(q, v), xs)
-
-        def body(ds, inp):
-            s, x, do_c = inp
-            _, back = jax.vjp(step, s, x)
-            return back((ds, do_c))
-
-        _, dxs = lax.scan(body, jnp.zeros_like(starts[0]),
-                          (starts, xs, _to_chunks(do, cfg[0])),
-                          reverse=True)
+        do = jnp.pad(do, [(0, 0), (0, -do.shape[1] % cfg[0]), (0, 0),
+                          (0, 0)])
+        dxs = _scan_bwd(xs, do, v.dtype, cfg[5])
     with jax.named_scope('gdn_intra'):
         return pull(dxs)
 
@@ -380,19 +414,22 @@ def _chunk_of(chunk_size, t):
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk_size=64, scale=None,
-                     qk_l2norm=False, l2norm_eps=1e-6, kernel=False):
+                     qk_l2norm=False, l2norm_eps=1e-6, kernel=False,
+                     scan_kernel=False):
     """q, k [B, T, Hk, Dk], v [B, T, Hv, Dv] (float32, or bf16 for bf16
     matmuls), g, beta [B, T, Hv]; Hk divides Hv and key head h serves the
     value heads h * Hv / Hk and following. Returns o [B, T, Hv, Dv]
     float32. `qk_l2norm`: q and k are first divided by their norm over
     Dk, x * rsqrt(sum x^2 + eps), in float32; then q is scaled (`scale`,
-    default Dk^-0.5). `kernel`: stage `gdn_intra` as the Pallas kernel
-    (the rule's choice; the caller has asked its `usable`)."""
+    default Dk^-0.5). `kernel`: stage `gdn_intra` as the Pallas kernel,
+    `scan_kernel`: stage `gdn_scan` as the Pallas kernels (the rule's
+    choices; the caller has asked each one's `usable`)."""
     dk = q.shape[3]
     scale = dk ** -0.5 if scale is None else float(scale)
     return _chunked(q, k, v, g, beta,
                     (_chunk_of(chunk_size, q.shape[1]), scale,
-                     bool(qk_l2norm), float(l2norm_eps), bool(kernel)))
+                     bool(qk_l2norm), float(l2norm_eps), bool(kernel),
+                     bool(scan_kernel)))
 
 
 @register('gated_delta_rule')
@@ -403,19 +440,25 @@ def _gated_delta_rule(ins, attrs, ctx):
     obs.counter('gdn.lowered', chunk=chunk).inc()            # trace time
     obs.counter('gdn.tokens').inc(int(v.shape[0]) * int(v.shape[1]))
     q, k, v = amp_cast(ctx, q, k, v)
+    cut = _chunk_of(chunk, q.shape[1])
     # stage `gdn_intra`: the Pallas kernel on the TPU for a shape it takes,
     # as the expert layer takes its grouped matmul there
     kernel = ctx.platform == 'tpu' and intra_kernel.usable(
-        _chunk_of(chunk, q.shape[1]), q.shape[3], v.shape[3], v.dtype)
+        cut, q.shape[3], v.shape[3], v.dtype)
     obs.counter('gdn.intra',                                 # trace time
                 way='kernel' if kernel else 'composed').inc()
+    # stage `gdn_scan`: the Pallas kernels read what that kernel hands over
+    scan = kernel and delta_scan.usable(
+        cut, q.shape[3], v.shape[3], v.shape[2], v.dtype)
+    obs.counter('gdn.scan',                                  # trace time
+                way='kernel' if scan else 'composed').inc()
     scale = attrs.get('scale', -1.0)
     o = gated_delta_rule(
         q, k, v, g, beta, chunk_size=chunk,
         scale=None if scale is None or scale < 0 else float(scale),
         qk_l2norm=bool(attrs.get('qk_l2norm', False)),
         l2norm_eps=float(attrs.get('l2norm_eps', 1e-6)),
-        kernel=kernel)
+        kernel=kernel, scan_kernel=scan)
     return {'Out': o}
 
 

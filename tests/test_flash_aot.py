@@ -8,8 +8,10 @@ shapes on either side of the default-tile rule (_default_tile) and of the
 backward's schedule. The
 topology is described inside a fixture and in this file only: one process
 at a time may load the TPU's library."""
+import functools
 import math
 import os
+import re
 
 import pytest
 
@@ -164,6 +166,49 @@ def test_gated_delta_intra_compiles_for_v5e(one_chip, dtype):
         keys, keys, x, gate, gate).compile()
     # the forward (it writes T for the backward) and the backward
     assert compiled.as_text().count('tpu_custom_call') == 2
+
+
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+def test_gated_delta_scan_compiles_for_v5e(one_chip, dtype):
+    """qwen3next_s8192's stage `gdn_scan` (one row of 128 chunks of 64
+    tokens, 32 value heads of 128 x 128) on what the `gdn_intra` kernel
+    hands over, in the cell's bf16 and in its float32 check's arithmetic,
+    at eight heads a grid step: three Mosaic calls (forward, forward again
+    for S at the chunks' starts, reverse), whose blocks twice over and
+    whose scratch Mosaic's DEFAULT limit of 16 MiB holds (5.6 MiB in bf16;
+    13.0 in float32, whose products at full precision are six passes of
+    split operands): no call states a `vmem_limit_bytes` (ROADMAP.md Speed
+    3 (c)), so a compile that passes here is under it. O is written and
+    its cotangent read by head, as [B, T, H, Dv]. S at the starts (256
+    MiB) is the one temporary larger than an operand."""
+    from paddle_tpu.ops.kernels import gated_delta_scan as kernel
+    dt = jnp.dtype(dtype)
+    assert kernel.usable(64, 128, 128, 32, dt)
+    assert kernel._heads(32) == 8
+    wide = (128, 1, 32, 64, 128)
+    like = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    xs = (like(wide, dt), like(wide, jnp.float32), like(wide, dt),
+          like(wide, dt), like((128, 1, 32, 64, 64), dt),
+          like((128, 1, 32), jnp.float32))
+
+    def loss(*xs):
+        return jnp.sum(kernel.gated_delta_scan(xs, dt, False) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=range(6))).lower(*xs).compile()
+    text = compiled.as_text()
+    calls = [l for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == 3
+    # no limit stated, and what each call uses of VMEM under the default
+    assert all('"scoped_memory_configs":[]' in l for l in calls)
+    used = [int(n) for n in re.findall(
+        r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
+        r'"offset":"0","size":"(\d+)"', '\n'.join(calls))]
+    assert len(used) == 3 and max(used) < (
+        6 if dtype == 'bfloat16' else 14) * 2 ** 20, used
+    # the starts 256 MiB, O and its cotangent 128 each, the decays' rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.1 * 2 ** 27 + (
+        0 if dtype == 'bfloat16' else 2 ** 28)
 
 
 # a cell's depthwise convolution as its rule hands it to the op: tokens a

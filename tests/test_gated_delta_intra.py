@@ -14,6 +14,7 @@ from paddle_tpu import obs
 from paddle_tpu.fluid import layers, lowering
 from paddle_tpu.fluid.ops_impl import linear_attention_ops as la
 from paddle_tpu.ops.kernels import gated_delta_intra as gdi
+from paddle_tpu.ops.kernels import gated_delta_scan as gds
 
 from test_qwen3_next import _grads_of, _input, plain_delta_net
 
@@ -23,18 +24,22 @@ BF16_ULP = 2.0 ** -8
 @pytest.fixture
 def interpreted(monkeypatch):
     """The rule hands the kernel `interpret=False` (Mosaic); here its
-    bodies run in the Pallas interpreter."""
-    real = gdi.gated_delta_intra
+    bodies run in the Pallas interpreter (and those of stage `gdn_scan`,
+    which the rule takes wherever it takes this one: ISSUE 50)."""
+    real, scan = gdi.gated_delta_intra, gds.gated_delta_scan
     monkeypatch.setattr(
         gdi, 'gated_delta_intra',
         lambda q, k, v, g_sum, beta, interpret, heads=None: real(
             q, k, v, g_sum, beta, True, heads))
+    monkeypatch.setattr(
+        gds, 'gated_delta_scan',
+        lambda xs, dtype, interpret: scan(xs, dtype, True))
 
 
-def op_inputs(seed, t, hk, hv, gates, dtype=jnp.float32):
+def op_inputs(seed, t, hk, hv, gates, dtype=jnp.float32, b=1):
     """As test_qwen3_next.delta_inputs draws them, at heads of 128."""
     rng = np.random.default_rng(seed)
-    b, d = 1, 128
+    d = 128
     q, k = (jnp.asarray(rng.normal(size=(b, t, hk, d)), dtype)
             for _ in range(2))
     v = jnp.asarray(rng.normal(size=(b, t, hv, d)), dtype)
