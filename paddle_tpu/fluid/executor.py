@@ -1488,7 +1488,16 @@ class Executor(object):
                     so it syncs on the health vector before returning —
                     the wait is recorded as a host_stall
                     (cause=anomaly_guard) and mostly serializes the
-                    async window."""
+                    async window.
+
+        Spans (docs/observability.md): `executor.step` and its child
+        `executor.fetch` always; while observability is on also
+        `executor.prepare` (with `executor.placement` and `executor.feed`
+        below it), `executor.rng`, `executor.dispatch` (a first call:
+        `executor.first_call`) and, first thing inside a blocking
+        `executor.fetch`, `executor.feed_wait`: the wait for the fed
+        arrays' transfer, which leaves the fetch's self time the wait for
+        the device."""
         if sync not in ('auto', 'block', 'async'):
             raise ValueError(
                 "sync must be 'auto', 'block' or 'async', got %r" % (sync,))
@@ -1506,7 +1515,8 @@ class Executor(object):
         # observability is off this is two perf_counter calls and an
         # in-memory histogram record; no file IO, no device syncs. Its
         # child spans (prepare, placement, feed, rng, dispatch,
-        # first_call) say where a step's host time goes and exist only
+        # first_call, and feed_wait inside the blocking fetch:
+        # _await_feed) say where a step's time goes and exist only
         # while observability is on: `on` is the step's one check.
         on = obs.enabled()
         with obs.span('executor.step') as step_sp:
@@ -1573,6 +1583,8 @@ class Executor(object):
             # sync='async', which wraps each output in a lazy FetchHandle
             # and returns without waiting on the device
             with obs.span('executor.fetch', sync=sync):
+                if on and sync != 'async':
+                    self._await_feed(feed_vals, feed_bytes)
                 out = [self._convert_fetch(v, fetch_f32, return_numpy,
                                            sync == 'async')
                        for v in res.fetches]
@@ -1830,6 +1842,8 @@ class Executor(object):
 
             fetch_f32 = bool(getattr(program, '_fetch_f32', False))
             with obs.span('executor.fetch', sync=sync, steps=K):
+                if on and sync != 'async':
+                    self._await_feed(stacked, fb)
                 out = []
                 for v in res.fetches:
                     if isinstance(v, SeqValue):
@@ -1855,6 +1869,27 @@ class Executor(object):
                     bsp.fields['device'] = self._read_device(
                         compiled, res.counters)
         return out
+
+    def _await_feed(self, feed_vals, feed_bytes):
+        """The wait for the step's INPUT, as the span `executor.feed_wait`
+        (docs/observability.md): blocks on every jax.Array the step was
+        fed (a SeqValue's planes among them; a host-staged numpy value or
+        a feedless step leaves nothing to wait for). The feed is never
+        donated (StepArtifact donates persistables only), so the arrays
+        outlive the dispatch and the wait ends when the transfer has
+        landed, not when the step has read them. `ready` says every array
+        had landed on entry: the transfer hid under the host's own
+        dispatch. Called only while observability is on, first thing
+        inside a blocking `executor.fetch`, whose self time is then the
+        wait for the device alone; off, or under sync='async' (it would
+        serialize what that mode overlaps), nobody calls it and no fed
+        array is touched after dispatch."""
+        arrays = [a for a in jax.tree_util.tree_leaves(feed_vals)
+                  if isinstance(a, jax.Array)]
+        with obs.span('executor.feed_wait', bytes=feed_bytes) as sp:
+            sp.fields['ready'] = all(a.is_ready() for a in arrays)
+            for a in arrays:
+                a.block_until_ready()
 
     def _read_device(self, compiled, counters):
         """THE host read of a step's device counters (StepArtifact.

@@ -60,7 +60,8 @@ ENV_RUN_FILE = 'PADDLE_TPU_OBS_RUN_FILE'
 ENV_MAX_EVENTS = 'PADDLE_TPU_OBS_MAX_EVENTS'
 DEFAULT_MAX_EVENTS = 500000
 # Bound of the completed-span buffer: a 20 s benchmark window is some 140
-# steps of 8 records, a whole run with its set-up a few thousand.
+# steps of 8 records (`executor.step` and its seven children; 2000 steps
+# fit), a whole run with its set-up a few thousand.
 SPAN_BUFFER_MAX = 16384
 
 _state = {
@@ -83,7 +84,7 @@ _gc_pending = []
 _span_ids = itertools.count(1)
 _local = threading.local()
 # span-name -> registry histogram, so the per-span fast path skips the
-# registry's label-normalizing lookup (hot: 3 spans per executor step,
+# registry's label-normalizing lookup (hot: 2 spans per executor step,
 # 8 while observability is on)
 _span_hists = {}
 

@@ -752,7 +752,8 @@ STEP_CHILDREN = {'executor.prepare': 'executor.step',
                  'executor.feed': 'executor.prepare',
                  'executor.rng': 'executor.step',
                  'executor.dispatch': 'executor.step',
-                 'executor.fetch': 'executor.step'}
+                 'executor.fetch': 'executor.step',
+                 'executor.feed_wait': 'executor.fetch'}
 
 
 @pytest.mark.parametrize('on', [False, True], ids=['obs_off', 'obs_on'])
@@ -806,6 +807,8 @@ def test_executor_step_child_spans_exist_only_with_observability_on(
     assert kept['executor.prepare']['fields'] == {'cache': 'hit'}
     assert kept['executor.placement']['fields'] == {'mesh': False}
     assert kept['executor.feed']['fields']['bytes'] == xb.nbytes + yb.nbytes
+    assert kept['executor.feed_wait']['fields'] == {
+        'bytes': xb.nbytes + yb.nbytes, 'ready': True}
     call, trace_, backend = (kept['executor.first_call' + k]
                              for k in ('', '.trace', '.backend'))
     assert by_id[call['parent']]['name'] == 'executor.step'
@@ -817,6 +820,158 @@ def test_executor_step_child_spans_exist_only_with_observability_on(
     assert trace_['dur_s'] > 0 and backend['dur_s'] > 0
     assert trace_['dur_s'] + backend['dur_s'] < call['dur_s']
     assert call['t0'] <= trace_['t0'] and backend['t1'] <= call['t1']
+
+
+def _fed_step(**run_kwargs):
+    """A warm fit_a_line step under the caller's observability, then one
+    more: ({span name: count} the second opened, its records)."""
+    with fresh_program() as (main, startup):
+        loss = _fit_a_line_graph()
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        xb, yb = _housing_batch()
+        feed = {'x': xb, 'y': yb}
+        exe.run(main, feed=feed, fetch_list=[loss])
+        before, kept = _span_counts(), len(obs.completed_spans())
+        out = exe.run(main, feed=feed, fetch_list=[loss], **run_kwargs)
+        np.asarray(out[0])
+        opened = _opened(before)
+        exe.close()
+    return opened, obs.completed_spans()[kept:]
+
+
+class _Fed(object):
+    """Stands where a fed jax.Array stands and counts what is asked of
+    it: lands after `polls` calls of is_ready."""
+
+    def __init__(self, polls):
+        self.polls, self.asked, self.blocked = polls, 0, 0
+
+    def is_ready(self):
+        self.asked += 1
+        return self.asked > self.polls
+
+    def block_until_ready(self):
+        self.blocked += 1
+        return self
+
+
+@pytest.mark.parametrize('landed', [True, False])
+def test_feed_wait_blocks_on_every_fed_array_and_says_if_it_waited(
+        landed, obs_dir, monkeypatch):
+    """`ready` is every fed array's is_ready() on entry; each is then
+    blocked on, a SeqValue's planes among them, and a host-staged numpy
+    value is left alone."""
+    import jax
+    from paddle_tpu.fluid.lowering import SeqValue
+    dense, data, lengths = _Fed(0), _Fed(0), _Fed(0 if landed else 1)
+    exe = fluid.Executor(fluid.CPUPlace())
+    with monkeypatch.context() as m:
+        m.setattr(jax, 'Array', _Fed)
+        exe._await_feed({'x': dense, 'seq': SeqValue(data, lengths),
+                         'host': np.zeros((4, 2), 'float32')}, 96)
+    rec, = [r for r in obs.completed_spans()
+            if r['name'] == 'executor.feed_wait']
+    assert rec['fields'] == {'bytes': 96, 'ready': landed}
+    assert [a.blocked for a in (dense, data, lengths)] == [1, 1, 1]
+    exe.close()
+
+
+def test_feed_wait_of_a_feedless_step_waits_on_nothing(obs_dir):
+    """The startup Program is fed nothing: the span is opened with zero
+    bytes, ready, and asks nothing of any array."""
+    with fresh_program() as (main, startup):
+        _fit_a_line_graph()
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        exe.close()
+    by_id = {r['span']: r for r in obs.completed_spans()}
+    rec, = [r for r in by_id.values() if r['name'] == 'executor.feed_wait']
+    assert rec['fields'] == {'bytes': 0, 'ready': True}
+    assert by_id[rec['parent']]['name'] == 'executor.fetch'
+    assert rec['dur_s'] < 1e-3
+
+
+def test_feed_wait_is_not_opened_under_sync_async(obs_dir):
+    """It would serialize what the mode exists to overlap:
+    executor.host_stall stays that mode's span."""
+    opened, recs = _fed_step(sync='async')
+    assert 'executor.feed_wait' not in opened
+    assert opened['executor.fetch'] == opened['executor.host_stall'] == 1
+    assert 'executor.feed_wait' not in {r['name'] for r in recs}
+    opened, _ = _fed_step(sync='block')
+    assert opened['executor.feed_wait'] == 1
+
+
+def test_run_bundle_opens_feed_wait_once_a_bundle(obs_dir):
+    with fresh_program() as (main, startup):
+        loss = _fit_a_line_graph()
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        feeds = [dict(zip('xy', _housing_batch(seed=i))) for i in range(3)]
+        exe.run_bundle(main, feeds=feeds, fetch_list=[loss])
+        before, kept = _span_counts(), len(obs.completed_spans())
+        exe.run_bundle(main, feeds=feeds, fetch_list=[loss])
+        opened = _opened(before)
+        recs = obs.completed_spans()[kept:]
+        before = _span_counts()
+        exe.run_bundle(main, feeds=feeds, fetch_list=[loss], sync='async')
+        assert 'executor.feed_wait' not in _opened(before)
+        exe.close()
+    assert opened['executor.bundle'] == opened['executor.fetch'] \
+        == opened['executor.feed_wait'] == 1
+    by_id = {r['span']: r for r in recs}
+    wait, = [r for r in recs if r['name'] == 'executor.feed_wait']
+    assert by_id[wait['parent']]['name'] == 'executor.fetch'
+    # the stacked feed of the whole bundle, not step 0's
+    assert wait['fields']['bytes'] == sum(
+        v.nbytes for f in feeds for v in f.values())
+    assert isinstance(wait['fields']['ready'], bool)
+
+
+def test_await_feed_is_never_called_with_observability_off(monkeypatch):
+    """Off, no fed array is touched after dispatch: run() and run_bundle()
+    do not reach the helper."""
+    calls = []
+    monkeypatch.setattr(fluid.Executor, '_await_feed',
+                        lambda self, *a: calls.append(a))
+    obs.disable()
+    opened, _ = _fed_step()
+    assert opened == {'executor.step': 1, 'executor.fetch': 1}
+    with fresh_program() as (main, startup):
+        loss = _fit_a_line_graph()
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        feeds = [dict(zip('xy', _housing_batch(seed=i))) for i in range(2)]
+        exe.run_bundle(main, feeds=feeds, fetch_list=[loss])
+        exe.close()
+    assert calls == []
+
+
+def test_feed_wait_leaves_the_host_dispatch_identity_alone(obs_dir):
+    """`host_dispatch` is executor.step minus executor.fetch, and the
+    step's parts outside the fetch (prepare self, placement, feed, rng,
+    dispatch, step self) add up to it: the wait is inside the fetch, so
+    it is in neither, and with the fetch's self time it is the fetch."""
+    _, recs = _fed_step()
+    by_name = {r['name']: r for r in recs}
+    below = {}
+    for r in recs:
+        below.setdefault(r['parent'], []).append(r)
+
+    def own(name):
+        r = by_name[name]
+        return r['dur_s'] - sum(c['dur_s'] for c in below.get(r['span'], ()))
+
+    step, fetch, wait = (by_name['executor.' + k]
+                         for k in ('step', 'fetch', 'feed_wait'))
+    assert wait['parent'] == fetch['span'] and fetch['parent'] == step['span']
+    outside = sum(own('executor.' + k) for k in (
+        'prepare', 'placement', 'feed', 'rng', 'dispatch', 'step'))
+    assert outside == pytest.approx(step['dur_s'] - fetch['dur_s'], rel=1e-9)
+    assert wait['dur_s'] + own('executor.fetch') == pytest.approx(
+        fetch['dur_s'], rel=1e-9)
+    assert fetch['t0'] <= wait['t0'] <= wait['t1'] <= fetch['t1']
 
 
 def test_span_records_reach_the_run_log_in_batches(obs_dir):
