@@ -23,6 +23,7 @@ cold compiles on restart).
 """
 import collections
 import contextlib
+import functools
 import os
 import threading
 import time
@@ -354,6 +355,8 @@ class _Prepared(NamedTuple):
 # per-instance view lives in plain ints behind exe.cache_stats.
 _C_MISSES = obs.counter('executor.cache.misses')
 _C_FEED_BYTES = obs.counter('executor.feed.bytes')
+# of those, the bytes `_put` handed to PJRT as views (below)
+_C_FEED_RESHAPED = obs.counter('executor.feed.reshaped_bytes')
 _C_SKIPPED = obs.counter('anomaly.skipped_steps')
 # async-fetch pipeline (docs/perf.md): how many run(sync='async') fetch
 # handles are outstanding (dispatched, not yet host-synced), and the
@@ -373,6 +376,66 @@ _C_REMAT = obs.counter('executor.remat_detected')
 # shrink it). The per-key geometry lives in the embedding.lookup /
 # embedding.update_rows run-log events; this counter carries the volume.
 _C_EMBED_ROWS = obs.counter('embedding.rows_touched')
+
+# A large dense host array crosses the link in a shape the host can copy
+# in runs (docs/perf.md): PJRT lays a host array out for the device's
+# tiling on the host, before the DMA, and for `f32[256,224,224,3]` that
+# relayout moves two elements at a time and takes twice as long as the
+# link. `_put` hands such an array over as views of the same buffer,
+# `[shape[0], the rest]` cut along its rows, and the device gives it its
+# declared shape. On a TPU v5e (PERF.md section 6, PR 52,
+# `tools/bench_feed_put.py`; put to landed in the declared shape, medians
+# of 20): the 154 MB batch 38.0 to 38.4 ms as it is, 18.8 to 20.7 as
+# `[256, 150528]`, 15.5 in 8 pieces of 19 MB (each piece's relayout runs
+# under the DMA of the one before; 16 pieces 14.9), of which the device's
+# reshape is 2.8; flat it takes 45 to 48 (the device's reshape 20.6).
+# _VIEW_FEED_BYTES is the smallest power of two at which one view beat
+# or tied the plain put for every shape swept (`--sweep`, declared
+# against one view, ms): [n, 32, 32, 3] 1 MiB 1.25 / 1.15 to 1.22, 2 MiB
+# 1.97 / 1.23 to 1.37, 4 MiB 3.23 / 1.49; [n, 100, 50] and
+# [n, 3, 64, 64] still LOSE at 2 MiB (1.16 / 1.32, 1.11 / 1.37), tie at
+# 4 (1.75 / 1.58, 1.60 / 1.58) and win from 8 (2.87 / 2.00).
+_VIEW_FEED_BYTES = 1 << 22
+# A piece holds at least this much: at 8 to 9 MB a piece the pieces
+# bought 0.3 to 0.8 ms of 3.0 to 4.9, at 19 MB 3.3 to 5.3 of 18.8 to
+# 20.7; a piece's put costs the host 0.1 to 0.2 ms alone and 0.7 ms in
+# the cell's step, where it waits for room behind the pieces before it.
+_VIEW_PIECE_BYTES = 1 << 24
+_SUBLANES, _LANES = 8, 128
+# Where that holds. The host's own backend has no tiling to lay out for:
+# there the views cost a copy the plain put does not make (19 MB: 15.0
+# against 1.9 ms; CPU, PR 52).
+_VIEW_PLATFORMS = ('tpu',)
+
+
+def _run_views(arr):
+    """The views in which `_put` hands the host array `arr` to PJRT when
+    it does not go as it is, else None: for a C-contiguous array of at
+    least `_VIEW_FEED_BYTES` whose rows the device's tiling breaks
+    (`ndim` >= 3, a trailing dimension that is no multiple of the 128
+    lanes) and which as `[shape[0], the rest]` fills the device's
+    (8, 128) tiles (at least 8 rows of at least a tile's 1024 elements:
+    a row's last lane tile then pads it by under an eighth), that view
+    cut along its rows into pieces of whole tiles of at least
+    `_VIEW_PIECE_BYTES` (the last: what is left). Reshapes and row
+    slices of a C-contiguous array are views: no host copy is made
+    here, and none for an array that is not (it goes as it is)."""
+    if (arr.nbytes < _VIEW_FEED_BYTES or arr.ndim < 3
+            or arr.shape[-1] % _LANES == 0 or arr.shape[0] < _SUBLANES
+            or arr.size < arr.shape[0] * _SUBLANES * _LANES
+            or not arr.flags.c_contiguous):
+        return None
+    rows = arr.reshape(arr.shape[0], -1)
+    step = -(-_VIEW_PIECE_BYTES // (arr.nbytes // len(rows)))
+    step = -(-step // _SUBLANES) * _SUBLANES
+    return [rows[i:i + step] for i in range(0, len(rows), step)]
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _declared_shape(shape, *pieces):
+    rows = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
+    return rows.reshape(shape)
+
 
 # The parts of a first call (docs/observability.md): jax reports how long
 # its own stages took through jax.monitoring duration events. While an
@@ -741,8 +804,23 @@ class Executor(object):
         if isinstance(val, LoDTensor):
             sv = val.to_seq_value()
             return self._to_device(sv)
-        arr = np.asarray(val)
-        return jax.device_put(arr, self._device())
+        return self._put(np.asarray(val))
+
+    def _put(self, arr):
+        """THE place a host ndarray meets this executor's device
+        (`_to_device`, `run_bundle`'s stacker): put as it is, or, on a
+        TPU and where `_run_views` names them, as views of the same
+        buffer, one put after the other, that a jitted reshape dispatched
+        right behind the last gives the declared shape, so that what the
+        step, the feed signature and `_await_feed` see is the array the
+        plain put would have made."""
+        dev = self._device()
+        views = _run_views(arr) if dev.platform in _VIEW_PLATFORMS else None
+        if views is None:
+            return jax.device_put(arr, dev)
+        _C_FEED_RESHAPED.inc(arr.nbytes)
+        return _declared_shape(
+            arr.shape, *[jax.device_put(v, dev) for v in views])
 
     def _host_stage(self, val):
         """Host-side feed normalization WITHOUT device placement (the
@@ -1120,6 +1198,7 @@ class Executor(object):
             if sp is not None:
                 sp.fields['mesh'] = dist_mesh is not None
         with obs.span_if(spans, 'executor.feed') as sp:
+            reshaped = _C_FEED_RESHAPED.value
             feed_vals = self._place_feed(program, feed, dist_mesh)
             # feed-transfer accounting: nbytes is metadata only (no device
             # sync); SeqValues carry their dense payload + length vectors
@@ -1133,6 +1212,8 @@ class Executor(object):
             _C_FEED_BYTES.inc(fb)
             if sp is not None:
                 sp.fields['bytes'] = fb
+                sp.fields['reshaped'] = int(
+                    _C_FEED_RESHAPED.value - reshaped)
 
         fetch_names = [_as_fetch_name(f) for f in fetch_list]
         feed_sig = tuple(sorted(_feed_signature(n, v) for n, v in feed_vals.items()))
@@ -1769,7 +1850,7 @@ class Executor(object):
                     arr = np.stack(vals)
                     if arr.dtype != v0.dtype:
                         arr = arr.astype(v0.dtype)
-                    stacked[name] = jax.device_put(arr, self._device())
+                    stacked[name] = self._put(arr)
                 else:
                     slow_names.append(name)
             if slow_names:
@@ -1877,7 +1958,9 @@ class Executor(object):
         a feedless step leaves nothing to wait for). The feed is never
         donated (StepArtifact donates persistables only), so the arrays
         outlive the dispatch and the wait ends when the transfer has
-        landed, not when the step has read them. `ready` says every array
+        landed, not when the step has read them; where `_put` sent a view
+        the fed array is the device-side reshape's result, so the wait
+        includes that reshape. `ready` says every array
         had landed on entry: the transfer hid under the host's own
         dispatch. Called only while observability is on, first thing
         inside a blocking `executor.fetch`, whose self time is then the

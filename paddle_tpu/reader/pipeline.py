@@ -44,7 +44,10 @@ def prefetch(reader, depth=2, transform=None):
     before it is queued — e.g. ``transform=exe._to_device`` (or a feeder
     + device_put composition) stages upcoming batches onto the device
     while the previous step still runs, which is what feeds
-    `Executor.run_bundle`'s stacker without a host stall."""
+    `Executor.run_bundle`'s stacker without a host stall. A large dense
+    batch whose rows the device's tiling breaks (float32 NHWC images)
+    is put as views of its rows there too and reshaped on the device
+    (`Executor._put`, docs/perf.md)."""
 
     def wrapped():
         q = Queue(maxsize=depth)

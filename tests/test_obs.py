@@ -974,6 +974,46 @@ def test_feed_wait_leaves_the_host_dispatch_identity_alone(obs_dir):
     assert fetch['t0'] <= wait['t0'] <= wait['t1'] <= fetch['t1']
 
 
+@pytest.mark.parametrize('large', [True, False], ids=['large', 'small'])
+def test_feed_span_and_counter_say_what_went_as_a_view(large, obs_dir,
+                                                       monkeypatch):
+    """`executor.feed`'s `reshaped` is the bytes of THIS step that
+    `Executor._put` handed over as views of its rows, and the process-wide
+    counter `executor.feed.reshaped_bytes` rises by them; `bytes`, and
+    the `bytes` of `executor.feed_wait` (which now waits on the reshaped
+    array), are what they were. A small feed leaves both at rest."""
+    from paddle_tpu.fluid import executor as executor_mod
+    monkeypatch.setattr(executor_mod, '_VIEW_FEED_BYTES',
+                        1024 if large else 1 << 40)
+    monkeypatch.setattr(executor_mod, '_VIEW_PLATFORMS', ('tpu', 'cpu'))
+    rs = np.random.RandomState(0)
+    img = rs.rand(8, 3, 32, 16).astype('float32')
+    yb = rs.rand(8, 1).astype('float32')
+    with fresh_program() as (main, startup):
+        x = fluid.layers.data(name='img', shape=[3, 32, 16],
+                              dtype='float32')
+        y = fluid.layers.data(name='y', shape=[1], dtype='float32')
+        pred = fluid.layers.fc(input=x, size=1, act=None)
+        loss = fluid.layers.mean(
+            fluid.layers.square_error_cost(input=pred, label=y))
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        feed = {'img': img, 'y': yb}
+        exe.run(main, feed=feed, fetch_list=[loss])
+        counted = obs.REGISTRY.total('executor.feed.reshaped_bytes') or 0
+        kept = len(obs.completed_spans())
+        exe.run(main, feed=feed, fetch_list=[loss])
+        recs = {r['name']: r for r in obs.completed_spans()[kept:]}
+        exe.close()
+    want = img.nbytes if large else 0
+    assert recs['executor.feed']['fields'] == {
+        'bytes': img.nbytes + yb.nbytes, 'reshaped': want}
+    assert recs['executor.feed_wait']['fields']['bytes'] == \
+        img.nbytes + yb.nbytes
+    assert (obs.REGISTRY.total('executor.feed.reshaped_bytes') or 0) \
+        - counted == want
+
+
 def test_span_records_reach_the_run_log_in_batches(obs_dir):
     """A span record waits in the run log for its batch (a write and a
     flush for each cost a training step 0.7 ms on the chip's host, PR 23);
