@@ -98,8 +98,9 @@ backward keeps) and the matmuls of `ssd_intra` and `ssd_inter` take bf16
 operands (M, the weighted X and S_start rounded once) with float32
 accumulation; dt, A, D, every decay and the carried state are float32.
 The output is float32.
-On the TPU, for chunks of 128, heads of 64 or 128 and states of whole lane
-tiles (`usable` of ops/kernels/ssd_scan.py), the whole scan is one Pallas
+On the TPU, for chunks of 128 or 256 (the op's `chunk_size`, handed to the
+kernels as their static argument), heads of 64 or 128 and states of whole
+lane tiles (`usable` of ops/kernels/ssd_scan.py), the whole scan is one Pallas
 kernel forward and one backward: the chunks are the grid's last,
 sequential axis, S a float32 VMEM scratch, nothing [C, C] reaches HBM,
 and the backward reads S_start of each chunk, which the rule's forward
@@ -520,7 +521,7 @@ def _ssd_kernel(x, dt, a, b, c, d, chunk):
     """The Pallas forward: (y, S at each chunk's start, which only a
     caller that reads it pays for)."""
     xp, dtp, bp, cp = _ssd_padded(x, dt, b, c, chunk)
-    y, starts = ssd_kernel.ssd_scan_fwd(xp, dtp, a, bp, cp, d,
+    y, starts = ssd_kernel.ssd_scan_fwd(xp, dtp, a, bp, cp, d, chunk=chunk,
                                         interpret=False)
     return y[:, :x.shape[1]], starts
 
@@ -546,7 +547,8 @@ def _ssd_bwd(chunk, kernel, res, dy):
         xp, dtp, bp, cp = _ssd_padded(x, dt, b, c, chunk)
         dyp = jnp.pad(dy, [(0, 0), (0, -t % chunk), (0, 0), (0, 0)])
         dx, ddt, da, db, dc, dd = ssd_kernel.ssd_scan_bwd(
-            xp, dtp, a, bp, cp, d, starts, dyp, interpret=False)
+            xp, dtp, a, bp, cp, d, starts, dyp, chunk=chunk,
+            interpret=False)
         return (dx[:, :t], ddt[:, :t].astype(dt.dtype), da, db[:, :t],
                 dc[:, :t], dd)
     res, dy = _recompute_after(res, dy)

@@ -122,7 +122,9 @@ def mamba_mixer(u, c, index):
 
 def attention_mixer(u, c):
     """Grouped-head causal attention without positions on the normed input
-    `u`. Parameters in creation order: Wq, Wk, Wv, Wo."""
+    `u`, its scores scaled by `attn_scale` where the model states one
+    (GraniteMoeHybrid), else by d_head^-0.5. Parameters in creation order:
+    Wq, Wk, Wv, Wo."""
     d = c['d_head']
 
     def heads(t, n):
@@ -133,7 +135,8 @@ def attention_mixer(u, c):
         q = heads(_proj(u, c['n_head'] * d, c['std']), c['n_head'])
         k, v = (heads(_proj(u, c['n_kv_head'] * d, c['std']),
                       c['n_kv_head']) for _ in range(2))
-        ctx = layers.fused_attention(q, k, v, causal=True, scale=d ** -0.5)
+        ctx = layers.fused_attention(q, k, v, causal=True,
+                                     scale=c.get('attn_scale', d ** -0.5))
         ctx = layers.reshape(layers.transpose(ctx, perm=[0, 2, 1, 3]),
                              shape=[0, 0, c['n_head'] * d])
         return _proj(ctx, c['hidden'], c['std'])

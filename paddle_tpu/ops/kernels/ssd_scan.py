@@ -22,6 +22,19 @@ every elementwise product and the carried state in float32. C B^T is one
 product a step, shared by its heads; the reads and writes of the state are
 one product each for all heads of the step.
 
+The chunk is a static argument of both kernels, 128 or 256 tokens (whole
+lane tiles; `usable`): at 256 the decays, C B^T and M are [256, 256]
+float32, 256 KiB each, and a step's x block [256, 512]. Neither call states
+a VMEM limit, so a step lives within Mosaic's default scoped 16 MiB.
+Compiled for a v5e at 64 heads of 64, state 128, one row of 8192 (Mosaic's
+own count, forward / backward, MiB): chunk 128 in 8 groups at 8 heads a step
+under 5 / 5.62 in bf16 and under 5 / 10.18 in float32; chunk 256 in one
+group at 8 heads 4.28 / 10.01 in bf16 and 7.09 / 16.62 in float32, which
+is over, so float32 at 256 takes 4 heads a step (4.15 / 9.94; `_heads`).
+A group whose heads take several grid steps (ONE group for 64 heads: eight
+steps a chunk, sixteen of four) forms the same C B^T in each of them: it
+is shared among a step's heads, not across steps.
+
 Operands in the layout the model hands over: x [B, T, H P] and B, C
 [B, T, G N] are cut into (chunk, heads P) and (chunk, N) blocks by the
 index maps. What a head needs a token (dt and L) is 2 MB a layer: XLA sums
@@ -62,26 +75,31 @@ __all__ = ['ssd_scan_fwd', 'ssd_scan_bwd', 'usable', 'HEADS']
 # heads a grid step (tools/bench_ssd_scan.py --sweep; docs/perf.md has the
 # rows): the largest divisor of a group's heads within it
 HEADS = 8
-_CHUNK = 128
+# tokens a chunk: one or two lane tiles ([chunk, chunk] is one MXU pass or
+# four); a static argument of both kernels, `usable` says which it takes
+CHUNKS = (128, 256)
 _LANES = 128
 
 _F32 = jnp.float32
 
 
 def usable(chunk, p, n, r, dtype):
-    """A chunk of 128 (one lane tile of tokens: [chunk, chunk] is an MXU
-    pass), heads of 64 or 128, a group's `r` heads filling whole lane
+    """A chunk of 128 or 256 (whole lane tiles of tokens, and what the
+    scoped VMEM holds of [chunk, chunk] float32: the module docstring has
+    the count), heads of 64 or 128, a group's `r` heads filling whole lane
     tiles, a state of whole lane tiles, bf16 or float32 operands."""
-    return (chunk == _CHUNK and p in (64, 128) and (r * p) % _LANES == 0
+    return (chunk in CHUNKS and p in (64, 128) and (r * p) % _LANES == 0
             and n % _LANES == 0
             and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
                                      jnp.dtype(jnp.float32)))
 
 
-def _heads(r, p):
+def _heads(r, p, chunk=128, itemsize=2):
     """Heads a grid step: the largest divisor of a group's `r` heads
-    within HEADS that fills whole lane tiles."""
-    n = min(r, HEADS)
+    within HEADS that fills whole lane tiles; within half of HEADS where a
+    chunk's column of operands passes 512 bytes (256 tokens of float32:
+    the backward at 8 heads would ask 16.6 MiB of scoped VMEM)."""
+    n = min(r, HEADS if chunk * itemsize <= 512 else max(HEADS // 2, 1))
     while r % n or (n * p) % _LANES:
         n -= 1
     return n
@@ -253,47 +271,47 @@ def _bwd_kernel(x_ref, b_ref, c_ref, col_ref, row_ref, d_ref, s_ref, dy_ref,
         - direct * dt_c + jnp.where(at_last, d_last, 0.0)
 
 
-def _small(dt, a, hs):
+def _small(dt, a, hs, chunk):
     """What a head needs a token, both ways up: (dt, L) as
     [B, steps, 2, T, hs] and [B, steps, 2, hs, T], L the running sum of
     dt A inside each chunk. dt [B, T, H] float32, T whole chunks."""
     bsz, t, h = dt.shape
-    run = jnp.cumsum((dt * a).reshape(bsz, -1, _CHUNK, h), axis=2)
+    run = jnp.cumsum((dt * a).reshape(bsz, -1, chunk, h), axis=2)
     both = jnp.stack([dt, run.reshape(dt.shape)], axis=1)
     both = both.reshape(bsz, 2, t, h // hs, hs)
     return both.transpose(0, 3, 1, 2, 4), both.transpose(0, 3, 1, 4, 2)
 
 
-def _dims(x, b, p, groups, hs):
+def _dims(x, b, p, groups, hs, chunk):
     """(B, T, H, N, grid steps a group, grid steps a row, chunks, lanes a
     step) of x [B, T, H P] and b [B, T, G N] at `hs` heads a step."""
     bsz, t, width = x.shape
     h = width // p
     return (bsz, t, h, b.shape[2] // groups, h // groups // hs, h // hs,
-            t // _CHUNK, hs * p)
+            t // chunk, hs * p)
 
 
-@functools.partial(jax.jit, static_argnames=('p', 'groups', 'hs', 'save',
-                                             'interpret'))
-def _forward(x, dt, a, b, c, d, *, p, groups, hs, save, interpret):
-    bsz, t, h, n, per, steps, z, width = _dims(x, b, p, groups, hs)
-    cols, rows = _small(dt, a, hs)
+@functools.partial(jax.jit, static_argnames=('p', 'groups', 'hs', 'chunk',
+                                             'save', 'interpret'))
+def _forward(x, dt, a, b, c, d, *, p, groups, hs, chunk, save, interpret):
+    bsz, t, h, n, per, steps, z, width = _dims(x, b, p, groups, hs, chunk)
+    cols, rows = _small(dt, a, hs, chunk)
     like = jax.ShapeDtypeStruct
-    tokens = pl.BlockSpec((1, _CHUNK, width), lambda i, j, k: (i, k, j))
+    tokens = pl.BlockSpec((1, chunk, width), lambda i, j, k: (i, k, j))
     outs, out_specs = [like(x.shape, _F32)], [tokens]
     if save:
         outs.append(like((bsz, steps, z, n, width), _F32))
         out_specs.append(pl.BlockSpec((1, 1, 1, n, width),
                                       lambda i, j, k: (i, j, k, 0, 0)))
-    group = pl.BlockSpec((1, _CHUNK, n), lambda i, j, k: (i, k, j // per))
+    group = pl.BlockSpec((1, chunk, n), lambda i, j, k: (i, k, j // per))
     got = pl.pallas_call(
         functools.partial(_fwd_kernel, p=p, dtype=x.dtype),
         grid=(bsz, steps, z),
         in_specs=[
             tokens, group, group,
-            pl.BlockSpec((1, 1, 2, _CHUNK, hs),
+            pl.BlockSpec((1, 1, 2, chunk, hs),
                          lambda i, j, k: (i, j, 0, k, 0)),
-            pl.BlockSpec((1, 1, 2, hs, _CHUNK),
+            pl.BlockSpec((1, 1, 2, hs, chunk),
                          lambda i, j, k: (i, j, 0, 0, k)),
             pl.BlockSpec((1, width), lambda i, j, k: (0, j))],
         out_specs=out_specs, out_shape=outs,
@@ -305,45 +323,46 @@ def _forward(x, dt, a, b, c, d, *, p, groups, hs, save, interpret):
     return tuple(got)
 
 
-@functools.partial(custom_dce.custom_dce, static_argnums=(6, 7, 8, 9))
-def _forward_kept(x, dt, a, b, c, d, p, groups, hs, interpret):
+@functools.partial(custom_dce.custom_dce, static_argnums=(6, 7, 8, 9, 10))
+def _forward_kept(x, dt, a, b, c, d, p, groups, hs, chunk, interpret):
     """(y, S at each chunk's start). Where nothing reads the starts (a
     forward that no backward follows; a recompute region's first pass,
     whose backward runs the forward again), the call that is left is the
     forward that does not write them."""
-    return _forward(x, dt, a, b, c, d, p=p, groups=groups, hs=hs, save=True,
-                    interpret=interpret)
+    return _forward(x, dt, a, b, c, d, p=p, groups=groups, hs=hs,
+                    chunk=chunk, save=True, interpret=interpret)
 
 
 @_forward_kept.def_dce
-def _forward_used(p, groups, hs, interpret, used, x, dt, a, b, c, d):
+def _forward_used(p, groups, hs, chunk, interpret, used, x, dt, a, b, c, d):
     got = _forward(x, dt, a, b, c, d, p=p, groups=groups, hs=hs,
-                   save=used[1], interpret=interpret)
+                   chunk=chunk, save=used[1], interpret=interpret)
     return (got[0] if used[0] else None, got[1] if used[1] else None)
 
 
-@functools.partial(jax.jit, static_argnames=('p', 'groups', 'hs',
+@functools.partial(jax.jit, static_argnames=('p', 'groups', 'hs', 'chunk',
                                              'interpret'))
-def _backward(x, dt, a, b, c, d, starts, dy, *, p, groups, hs, interpret):
-    bsz, t, h, n, per, steps, z, width = _dims(x, b, p, groups, hs)
-    cols, rows = _small(dt, a, hs)
+def _backward(x, dt, a, b, c, d, starts, dy, *, p, groups, hs, chunk,
+              interpret):
+    bsz, t, h, n, per, steps, z, width = _dims(x, b, p, groups, hs, chunk)
+    cols, rows = _small(dt, a, hs, chunk)
     like = jax.ShapeDtypeStruct
     # a group's dB and dC: the step's own where it takes the whole group,
     # else float32 parts a step, summed below
     part = x.dtype if per == 1 else _F32
-    tokens = pl.BlockSpec((1, _CHUNK, width),
+    tokens = pl.BlockSpec((1, chunk, width),
                           lambda i, j, k: (i, z - 1 - k, j))
-    group = pl.BlockSpec((1, _CHUNK, n),
+    group = pl.BlockSpec((1, chunk, n),
                          lambda i, j, k: (i, z - 1 - k, j // per))
-    parts = pl.BlockSpec((1, _CHUNK, n), lambda i, j, k: (i, z - 1 - k, j))
-    col = pl.BlockSpec((1, 1, 2, _CHUNK, hs),
+    parts = pl.BlockSpec((1, chunk, n), lambda i, j, k: (i, z - 1 - k, j))
+    col = pl.BlockSpec((1, 1, 2, chunk, hs),
                        lambda i, j, k: (i, j, 0, z - 1 - k, 0))
     dx, db, dc, dcols, drows, dd = pl.pallas_call(
         functools.partial(_bwd_kernel, p=p, dtype=x.dtype),
         grid=(bsz, steps, z),
         in_specs=[
             tokens, group, group, col,
-            pl.BlockSpec((1, 1, 2, hs, _CHUNK),
+            pl.BlockSpec((1, 1, 2, hs, chunk),
                          lambda i, j, k: (i, j, 0, 0, z - 1 - k)),
             pl.BlockSpec((1, width), lambda i, j, k: (0, j)),
             pl.BlockSpec((1, 1, 1, n, width),
@@ -351,7 +370,7 @@ def _backward(x, dt, a, b, c, d, starts, dy, *, p, groups, hs, interpret):
             tokens],
         out_specs=[
             tokens, parts, parts, col,
-            pl.BlockSpec((1, 1, hs, _CHUNK),
+            pl.BlockSpec((1, 1, hs, chunk),
                          lambda i, j, k: (i, j, 0, z - 1 - k)),
             pl.BlockSpec((1, 1, width), lambda i, j, k: (i, 0, j))],
         out_shape=[
@@ -371,7 +390,7 @@ def _backward(x, dt, a, b, c, d, starts, dy, *, p, groups, hs, interpret):
     dcols = dcols.transpose(0, 2, 3, 1, 4).reshape(bsz, 2, t, h)
     dl = dcols[:, 1] + drows.transpose(0, 3, 1, 2).reshape(dt.shape)
     # L is the running sum of dt A inside a chunk: its cotangent runs back
-    dl = dl.reshape(bsz, z, _CHUNK, h)
+    dl = dl.reshape(bsz, z, chunk, h)
     back = jnp.cumsum(dl[:, :, ::-1], axis=2)[:, :, ::-1].reshape(dt.shape)
     return (dx, dcols[:, 0] + back * a, jnp.sum(back * dt, axis=(0, 1)),
             db, dc, dd.reshape(bsz, h, p).sum((0, 2)))
@@ -387,21 +406,22 @@ def _flat(x, dt, b, c, d):
             jnp.repeat(skip, p)[None])
 
 
-def ssd_scan_fwd(x, dt, a, b, c, d, *, interpret):
+def ssd_scan_fwd(x, dt, a, b, c, d, *, chunk, interpret):
     """x [B, T, H, P], b, c [B, T, G, N] in the matmuls' dtype, dt
-    [B, T, H], a [H], d [H] or None, T whole chunks of 128. Returns
+    [B, T, H], a [H], d [H] or None, T whole chunks of `chunk`. Returns
     (y [B, T, H, P] float32 with the skip, S at each chunk's start for
     `ssd_scan_bwd`); a caller that drops the starts pays nothing for them
     once jitted (`_forward_kept`)."""
     p, groups = x.shape[3], b.shape[2]
     x2, dt, b2, c2, skip = _flat(x, dt, b, c, d)
     y, starts = _forward_kept(x2, dt, a.astype(_F32), b2, c2, skip, p,
-                              groups, _heads(x.shape[2] // groups, p),
+                              groups, _heads(x.shape[2] // groups, p, chunk,
+                                             x.dtype.itemsize), chunk,
                               interpret)
     return y.reshape(x.shape), starts
 
 
-def ssd_scan_bwd(x, dt, a, b, c, d, starts, dy, *, interpret):
+def ssd_scan_bwd(x, dt, a, b, c, d, starts, dy, *, chunk, interpret):
     """The cotangents of (x, dt, a, b, c, d) for dy [B, T, H, P] float32;
     `starts` is what `ssd_scan_fwd` kept (its shape says how many heads a
     grid step took)."""
@@ -410,7 +430,7 @@ def ssd_scan_bwd(x, dt, a, b, c, d, starts, dy, *, interpret):
     dx, ddt, da, db, dc, dd = _backward(
         flat[0], flat[1], a.astype(_F32), *flat[2:], starts,
         dy.astype(_F32).reshape(flat[0].shape), p=p, groups=groups,
-        hs=starts.shape[4] // p, interpret=interpret)
+        hs=starts.shape[4] // p, chunk=chunk, interpret=interpret)
     return (dx.reshape(x.shape), ddt, da.astype(a.dtype),
             db.reshape(b.shape), dc.reshape(c.shape),
             None if d is None else dd.astype(d.dtype))

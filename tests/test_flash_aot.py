@@ -267,38 +267,56 @@ def test_causal_conv1d_with_a_bias_compiles_for_v5e(one_chip, dtype):
     assert compiled.as_text().count('tpu_custom_call') == 2
 
 
+# a cell's scan as its rule hands it to the op: groups, chunk
+_SCANS = {'nemotron3nano': (8, 128), 'granite4hmicro': (1, 256)}
+
+
 @pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
-def test_ssd_scan_compiles_for_v5e(one_chip, dtype):
+@pytest.mark.parametrize('cell', sorted(_SCANS))
+def test_ssd_scan_compiles_for_v5e(one_chip, cell, dtype):
     """nemotron3nano_s8192's state-space scan (one row of 8192 tokens, 64
-    heads of 64 in 8 groups of state 128, chunks of 128, the skip) as the
-    rule hands it to the op, forward and backward, in the cell's bf16 and
-    in its float32 check's arithmetic, at the heads a grid step takes:
-    two Mosaic calls, the forward that keeps the starts and the backward,
-    and the starts are all the float32 the op keeps between them."""
+    heads of 64 in 8 groups of state 128, chunks of 128, the skip) and
+    granite4hmicro_s8192's (ONE group, chunks of 256) as the rule hands
+    them to the op, forward and backward, in the cell's bf16 and in its
+    float32 check's arithmetic, at the heads a grid step takes: two Mosaic
+    calls within the default scoped VMEM (the kernels state no limit), the
+    forward that keeps the starts and the backward, and the starts are all
+    the float32 the op keeps between them."""
     from paddle_tpu.fluid.ops_impl.linear_attention_ops import ssd_scan
     from paddle_tpu.ops.kernels import ssd_scan as kernel
+    groups, chunk = _SCANS[cell]
     dt = jnp.dtype(dtype)
-    assert kernel.usable(128, 64, 128, 8, dt) and kernel._heads(8, 64) == 8
+    assert kernel.usable(chunk, 64, 128, 64 // groups, dt)
+    assert kernel._heads(64 // groups, 64, chunk, dt.itemsize) == (
+        4 if (chunk, dtype) == (256, 'float32') else 8)
     x = jax.ShapeDtypeStruct((1, 8192, 64, 64), dt, sharding=one_chip)
-    bc = jax.ShapeDtypeStruct((1, 8192, 8, 128), dt, sharding=one_chip)
+    bc = jax.ShapeDtypeStruct((1, 8192, groups, 128), dt, sharding=one_chip)
     step = jax.ShapeDtypeStruct((1, 8192, 64), jnp.float32,
                                 sharding=one_chip)
     head = jax.ShapeDtypeStruct((64,), jnp.float32, sharding=one_chip)
 
     def loss(x, step, a, b, c, d):
-        return jnp.sum(ssd_scan(x, step, a, b, c, d, chunk_size=128,
+        return jnp.sum(ssd_scan(x, step, a, b, c, d, chunk_size=chunk,
                                 kernel=True) ** 2)
 
     compiled = jax.jit(jax.grad(loss, argnums=range(6))).lower(
         x, step, head, bc, bc, head).compile()
-    assert compiled.as_text().count('tpu_custom_call') == 2
-    # y, its cotangent and the starts, 134 MB each, and no decays
-    assert compiled.memory_analysis().temp_size_in_bytes < 4.2 * 2 ** 27
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 2
+    assert 'vmem_limit_bytes' not in text
+    # y, its cotangent and the starts (134 MB each at 128, the starts half
+    # that at 256), and no decays; where one group's heads take several
+    # grid steps, dB and dC besides as float32 parts a step (sixteen steps
+    # of four heads in float32: 2 x 67 MB)
+    parts = 1.0 if (cell, dtype) == ('granite4hmicro', 'float32') else 0.0
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        4.2 + parts) * 2 ** 27
 
 
 # a cell's gated norm as its rule hands it to the op: x's shape, groups,
 # norm_before_gate
 _NORMS = {'nemotron3nano': ((1, 8192, 4096), 8, False),
+          'granite4hmicro': ((1, 8192, 4096), 1, False),
           'qwen3next': ((1, 8192, 32, 128), 1, True)}
 
 
@@ -360,6 +378,8 @@ _CELL_CALLS = {
     'olmoe': ((2, 16, 4096, 128), None, 'triangle', 36),
     # heads of HALF a lane tile over 64 tiles: 16384 x 64 (PR 44)
     'lfm2': ((1, 32, 16384, 64), None, 'triangle', 528),
+    # the same heads over 16 tiles of a row of 8192 (PR 53)
+    'granite4hmicro': ((1, 32, 8192, 64), None, 'triangle', 136),
 }
 
 

@@ -581,6 +581,12 @@ _SWEEP = {
         kwargs=dict(batch_size=2, seq_len=16, vocab_size=64, hidden=32,
                     n_expert=8, expert_width=16, experts_held=(2, 4)),
         feeds_idx=4, stack=True),
+    'granitemoehybrid': dict(
+        kwargs=dict(batch_size=2, seq_len=16, vocab_size=64, hidden=32,
+                    ssm_heads=2, ssm_head_dim=16, ssm_state=8, chunk_size=8,
+                    n_head=2, n_kv_head=1, d_head=16, mlp_width=64,
+                    layer_types=('mamba', 'attention')),
+        feeds_idx=4, stack=True),
 }
 
 

@@ -3,9 +3,10 @@ the interpreter against the composition they replace on the TPU
 (`linear_attention_ops._ssd` and `jax.vjp` of it) and against the
 token-by-token recurrence of the benchmark's plain reference, the shapes
 they take and refuse, the type of the state they carry, the rule's choice
-between the two ways and the calls a lowered step holds. Chunks of 128 and
-states of 128, which the kernels ask for; few heads and two or three chunks
-keep the interpreter cheap. On the CPU."""
+between the two ways and the calls a lowered step holds. Chunks of 128 or
+256 (ISSUE 53: the chunk is an argument of both kernels) and states of 128,
+which the kernels ask for; few heads and two or three chunks keep the
+interpreter cheap. On the CPU."""
 import re
 
 import numpy as np
@@ -34,14 +35,20 @@ def interpreted(monkeypatch):
                 *a, **dict(kw, interpret=True)))
 
 
-# (batch, tokens, heads, head width, groups, state): two rows whose last
-# chunk is 44 tokens and 84 of padding, a group serving two heads of 64
-# (two heads a lane tile); a head a group, heads of a whole lane tile;
-# four heads a group in steps of two (dB and dC summed over the steps)
+# (batch, tokens, heads, head width, groups, state, chunk): two rows whose
+# last chunk is 44 tokens and 84 of padding, a group serving two heads of
+# 64 (two heads a lane tile); a head a group, heads of a whole lane tile;
+# four heads a group in steps of two (dB and dC summed over the steps);
+# the published chunk of 256: a row of two chunks whose second is 44
+# tokens and 212 of padding, two groups; and ONE group for sixteen heads,
+# so two grid steps of eight (four in float32) read the same B and C and
+# hand dB and dC on in parts
 _SCANS = {
-    'ragged_two_rows': (2, 300, 4, 64, 2, 128),
-    'a_head_a_group': (1, 200, 2, 128, 2, 128),
-    'whole_chunks_one_group': (1, 256, 4, 64, 1, 128),
+    'ragged_two_rows': (2, 300, 4, 64, 2, 128, 128),
+    'a_head_a_group': (1, 200, 2, 128, 2, 128, 128),
+    'whole_chunks_one_group': (1, 256, 4, 64, 1, 128, 128),
+    'chunk_256_ragged': (1, 300, 4, 64, 2, 128, 256),
+    'chunk_256_one_group_in_steps': (1, 512, 16, 64, 1, 128, 256),
 }
 
 
@@ -64,9 +71,13 @@ def _value_and_grads(fn, args, w):
     return y, grads
 
 
-@pytest.mark.parametrize('skip', [True, False], ids=['d', 'no_d'])
-@pytest.mark.parametrize('amp', [False, True], ids=['float32', 'bf16'])
-@pytest.mark.parametrize('case', sorted(_SCANS))
+# every case both ways and with and without the skip at a chunk of 128;
+# the skip is the same lines of the bodies at 256, so those cases keep it
+@pytest.mark.parametrize('case,amp,skip', [
+    pytest.param(case, amp, skip, id='%s-%s-%s' % (
+        case, 'bf16' if amp else 'float32', 'd' if skip else 'no_d'))
+    for case in sorted(_SCANS) for amp in (False, True)
+    for skip in (True, False) if skip or _SCANS[case][-1] == 128])
 def test_the_kernels_are_the_composition_and_the_recurrence(
         case, amp, skip, interpreted):
     """The output and the gradient of all six inputs (five without D):
@@ -75,14 +86,15 @@ def test_the_kernels_are_the_composition_and_the_recurrence(
     sums in another order (A's gradient is a sum of cancelling terms a
     token: 3e-5 between the two where either is 2e-5 from the
     recurrence)."""
-    args = _scan_inputs(*_SCANS[case], seed=len(case))
+    *shape, chunk = _SCANS[case]
+    args = _scan_inputs(*shape, seed=len(case))
     if not skip:
         args = args[:5]
     w = jnp.asarray(np.random.default_rng(9).normal(size=args[0].shape),
                     jnp.float32)
     with jax.default_matmul_precision('highest'):
-        y, got = _value_and_grads(_op(True, amp), args, w)
-        near_y, near = _value_and_grads(_op(False, amp), args, w)
+        y, got = _value_and_grads(_op(True, amp, chunk), args, w)
+        near_y, near = _value_and_grads(_op(False, amp, chunk), args, w)
         want_y, want = _value_and_grads(
             _recurrence if skip else lambda *v: _recurrence(
                 *v, jnp.zeros_like(v[2])), args, w)
@@ -117,11 +129,12 @@ def test_every_heads_a_grid_step_gives_the_same_scan(monkeypatch):
     for heads in (4, 2):
         monkeypatch.setattr(sk, 'HEADS', heads)
         y, starts = sk.ssd_scan_fwd(x, dt, args[2], b, c, args[5],
-                                    interpret=True)
+                                    chunk=128, interpret=True)
         assert starts.shape == (1, 8 // heads, 2, 128, heads * 64)
         assert starts.dtype == jnp.float32
         got.append((y,) + sk.ssd_scan_bwd(x, dt, args[2], b, c, args[5],
-                                          starts, w, interpret=True))
+                                          starts, w, chunk=128,
+                                          interpret=True))
     for name, a, b in zip('y x dt a b c d'.split(), *got):
         assert a.dtype == b.dtype, name
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
@@ -131,6 +144,10 @@ def test_every_heads_a_grid_step_gives_the_same_scan(monkeypatch):
     assert sk._heads(16, 64) == 8 and sk._heads(4, 64) == 4
     assert sk._heads(6, 64) == 6 and sk._heads(3, 128) == 3
     assert sk._heads(1, 128) == 1 and sk._heads(12, 128) == 6
+    # a chunk of 256: eight heads of bf16 operands, four of float32 (the
+    # backward's scoped VMEM, tests/test_flash_aot.py compiles both)
+    assert sk._heads(64, 64, 256, 2) == 8 and sk._heads(64, 64, 256, 4) == 4
+    assert sk._heads(64, 64, 128, 4) == 8 and sk._heads(2, 64, 256, 4) == 2
 
 
 def test_usable_at_its_boundaries():
@@ -142,6 +159,11 @@ def test_usable_at_its_boundaries():
     assert not sk.usable(128, 64, 128, 1, bf16)   # half a lane tile a group
     assert not sk.usable(128, 64, 128, 3, bf16)
     assert not sk.usable(64, 64, 128, 8, bf16)
+    # the published chunk of 256, one group for all 64 heads (ISSUE 53)
+    assert sk.usable(256, 64, 128, 64, bf16) and sk.usable(256, 64, 128, 64, f32)
+    assert sk.usable(128, 64, 128, 64, bf16)
+    assert not sk.usable(512, 64, 128, 64, bf16)
+    assert not sk.usable(192, 64, 128, 64, bf16)
     assert not sk.usable(16, 8, 16, 2, f32)       # the toy cell's
     assert not sk.usable(128, 32, 128, 8, bf16)
     assert not sk.usable(128, 64, 64, 8, bf16)
@@ -149,6 +171,7 @@ def test_usable_at_its_boundaries():
     # a row shorter than a chunk is cut to the power of two that holds it
     assert la._chunk_of(128, 8192) == 128 and la._chunk_of(128, 100) == 128
     assert la._chunk_of(128, 40) == 64
+    assert la._chunk_of(256, 8192) == 256 and la._chunk_of(256, 200) == 256
 
 
 def _pallas_calls(jaxpr, found=None):
@@ -271,3 +294,43 @@ def test_the_rule_chooses_on_platform_and_shape(platform, monkeypatch,
     after = _ways()
     assert after['kernel'] == before['kernel']
     assert after['composed'] > before['composed']
+
+
+@pytest.mark.parametrize('amp', [False, True], ids=['float32', 'bf16'])
+def test_the_rule_asks_the_kernels_at_the_published_chunk_of_256(
+        amp, monkeypatch, interpreted):
+    """granite4hmicro_s8192's scan as the model hands it over (ISSUE 53):
+    64 heads of 64, ONE group of state 128, chunk_size 256. On the TPU the
+    rule asks the kernels, in the step's bf16 and in the float32 check's
+    arithmetic, and counts the lowering under its own labels; only
+    lowered here (two chunks), nothing runs."""
+    from paddle_tpu import fluid
+    from paddle_tpu.fluid import framework, unique_name
+    init = lowering.Ctx.__init__
+    monkeypatch.setattr(
+        lowering.Ctx, '__init__',
+        lambda self, *a, **kw: init(self, *a, **dict(kw, platform='tpu')))
+    args = _scan_inputs(1, 512, 64, 64, 1, 128, seed=7)
+    label = dict(chunk=256, heads=64, groups=1)
+    before, lowered = _ways(), obs.counter('ssd.lowered', **label).value
+    main, startup = framework.Program(), framework.Program()
+    with unique_name.guard(), framework.program_guard(main, startup):
+        out = layers.ssd_scan(*(_input(n, v) for n, v in zip('xtabcd', args)),
+                              chunk_size=256)
+        loss = layers.reduce_sum(out)
+        grads = [g for _, g in fluid.backward.append_backward(loss)]
+        if amp:
+            fluid.amp.decorate_program(main)
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        text = exe.lowered_hlo(main, {}, [loss] + grads)
+    after = _ways()
+    assert after['kernel'] - before['kernel'] == \
+        obs.counter('ssd.lowered', **label).value - lowered >= 1
+    assert after['composed'] == before['composed']
+    assert not re.search(r'[/(]ssd_intra[/)]', text)
+    # the starts the backward reads: two chunks of eight steps of eight
+    # heads in bf16, sixteen steps of four in float32
+    assert ('tensor<1x8x2x128x512xf32>' if amp
+            else 'tensor<1x16x2x128x256xf32>') in text

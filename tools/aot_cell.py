@@ -12,7 +12,9 @@ grouped-matmul kernels, not their host fallbacks) for device 0 of a
 described `v5e:2x2`, and compiles. With `--check NAME` it compiles that
 entry of the configuration's `checks` instead: the check Program in its
 own arithmetic and the plain reference on the check's sample, the two
-programs that share the chip with the scope before the window. One chip
+programs that share the chip with the scope before the window (a
+reference that walks its layers with the weights on the host, one that
+has `pieces`, as the pieces of its walk). One chip
 only: a mesh cell builds its mesh from jax.devices(). A compile that
 passes is not a chip run.
 """
@@ -124,6 +126,31 @@ def main(argv=None):
     paths = check_mod.grad_paths(tree, set(built['grads']))
     wanted = sorted({path for path, _ in paths.values()})
     reference = cell['reference']
+
+    def like(a, dtype=np.float32):
+        return jax.ShapeDtypeStruct(np.shape(a), dtype, sharding=chip)
+
+    if hasattr(reference, 'pieces'):
+        # a reference that walks its layers with the weights on the host
+        # (references/granitemoehybrid.py): each piece of the walk alone,
+        # a layer's parameters and its input the arguments
+        model = config['model']
+        fn = reference.pieces(model)
+        ids, labels = (like(pool[0][k], np.int32) for k in built['feeds'])
+        x = like(np.empty(ids.shape + (model['hidden_size'],)))
+        table = like(params['tok_emb'])
+        with jax.default_matmul_precision('highest'):
+            report('reference.head', fn['head'].lower(
+                x, like(params['norm_final']), table, labels).compile())
+            kinds = reference.kinds_of(model)
+            for kind in sorted(set(kinds)):
+                w = jax.tree_util.tree_map(like, reference.sub(
+                    params, 'layer%d.' % kinds.index(kind)))
+                report('reference.%s.forward' % kind,
+                       fn['forward'][kind].lower(w, x).compile())
+                report('reference.%s.backward' % kind,
+                       fn['backward'][kind].lower(w, x, x).compile())
+        return 0
     with jax.default_matmul_precision('highest'):
         ref = jax.jit(lambda p, ids, labels: jax.value_and_grad(
             lambda w: reference.forward_loss({**p, **w}, config['model'],

@@ -6,7 +6,8 @@
 Two implementations at one layer's shape of each cell that builds the op
 (`nemotron3nano_s8192`: gate first, 8 groups of 512 columns of
 [1, 8192, 4096]; `qwen3next_s8192`: norm first, a head of 128 as the last
-axis of [1, 8192, 32, 128]), x float32 and the gate in `--gate` (bf16 in a
+axis of [1, 8192, 32, 128]; `granite4hmicro_s8192`: gate first, ONE group
+of all 4096 columns of [1, 8192, 4096]), x float32 and the gate in `--gate` (bf16 in a
 cell's step, float32 in its float32 check), forward alone and the backward
 as the op runs it. The gate and the cotangent come, and the result and the
 gate's gradient go, as the cell's step has them: [B, T, all the columns],
@@ -43,6 +44,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CELLS = {
     'nemotron3nano_s8192': ((1, 8192, 4096), 8, False),
     'qwen3next_s8192': ((1, 8192, 32, 128), 1, True),
+    'granite4hmicro_s8192': ((1, 8192, 4096), 1, False),
 }
 SWEEP_ELEMENTS = (1 << 15, 1 << 16, 1 << 17, 1 << 18, 1 << 19)
 EPS = 1e-5

@@ -24,7 +24,8 @@ softplus of a normal around the inverse softplus of a log-uniform step in
   by one: some 10 s a pass at 8192; --no-recurrence leaves it out);
   with --sweep, the kernels' two times again for every number of heads a
   grid step may take (the divisors of a group's heads that fill whole
-  lane tiles; `HEADS` of the kernel file is the winner).
+  lane tiles; `HEADS` of the kernel file is the winner; a number Mosaic
+  refuses, for the scoped VMEM a chunk of 256 asks, prints `refused`).
 
 Exits non-zero off the chip: a time from the CPU is no device number.
 """
@@ -152,7 +153,14 @@ def main(argv=None):
                 if rep % n or n * args.head_dim % 128:
                     continue
                 ssd_kernel.HEADS = n
-                measure('kernel', heads_a_step=n)
+                try:
+                    measure('kernel', heads_a_step=n)
+                except Exception as e:      # Mosaic refuses it: scoped VMEM
+                    print(json.dumps({
+                        'way': 'kernel', 'heads_a_step': n,
+                        'chunk': args.chunk,
+                        'refused': str(e).strip().splitlines()[-1][:300]}),
+                        flush=True)
             ssd_kernel.HEADS = default
     if args.no_recurrence:
         return
