@@ -6,7 +6,8 @@ from . import core
 from . import framework
 from .framework import Program, Operator, Parameter, Variable, \
     default_startup_program, default_main_program, program_guard, \
-    name_scope, recompute_guard, device_guard, get_var
+    name_scope, recompute_guard, recompute_keep, RecomputeKeepError, \
+    device_guard, get_var
 from . import executor
 from .executor import Executor, global_scope, scope_guard, _switch_scope, \
     Scope, anomaly_guard

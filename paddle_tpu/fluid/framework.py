@@ -26,7 +26,8 @@ from . import unique_name
 __all__ = [
     'Program', 'Operator', 'Parameter', 'Variable', 'Block',
     'default_startup_program', 'default_main_program', 'program_guard',
-    'name_scope', 'recompute_guard', 'device_guard', 'get_var', 'grad_var_name',
+    'name_scope', 'recompute_guard', 'recompute_keep', 'RecomputeKeepError',
+    'device_guard', 'get_var', 'grad_var_name',
     'strict_infer_shape', 'normalize_sharding',
 ]
 
@@ -874,8 +875,9 @@ _recompute_serial = itertools.count(1)
 def recompute_guard():
     """The forward ops appended inside form ONE region that the training
     step recomputes in its backward pass: the step keeps the region's
-    inputs (and the attention kernels' outputs and statistics) and not
-    what the region computes on the way, one `jax.checkpoint` a region
+    inputs (and the attention kernels' outputs and statistics, and what
+    the model marks with `recompute_keep`) and not what else the region
+    computes on the way, one `jax.checkpoint` a region
     (step_artifact._run_ops). A model marks each decoder layer. A region
     is the guard's ops in a row; a guard inside another belongs to the
     outer one. The default main program is marked `_use_remat`, which
@@ -888,6 +890,33 @@ def recompute_guard():
         yield
     finally:
         _recompute_stack.pop()
+
+
+class RecomputeKeepError(ValueError):
+    """`fluid.recompute_keep` was handed something no recompute region
+    built: a mark there would keep nothing, silently."""
+
+
+def recompute_keep(var):
+    """Marks `var`, built inside a `fluid.recompute_guard()` region, as
+    KEPT: the step saves its value beside the region's inputs and the
+    backward pass does not compute it again (nor what only it needed: a
+    kept projection's matmul leaves the region's second forward). A kept
+    value costs its bytes for the whole step and saves the FLOPs that made
+    it: for the output of a matmul over K columns, 2 x K FLOPs for an
+    element of 4 bytes. The mark is the attribute `recompute_keep` of the
+    op that produced `var` (its output names), so the Program's
+    fingerprint carries it. Returns `var`."""
+    op = getattr(var, 'op', None)
+    if op is None or op.attrs.get('recompute') is None:
+        raise RecomputeKeepError(
+            'recompute_keep: %r was not built inside a '
+            'fluid.recompute_guard() region'
+            % (getattr(var, 'name', var),))
+    kept = op.attrs.get('recompute_keep', [])
+    if var.name not in kept:
+        op._set_attr('recompute_keep', sorted(kept + [var.name]))
+    return var
 
 
 _device_guard_stack = []

@@ -40,7 +40,9 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
+from .. import obs
 from . import lowering
 from .lowering import SeqValue, Ctx
 
@@ -49,13 +51,17 @@ __all__ = ['StepArtifact', 'StepResult', 'program_fingerprint',
            'aot_check', 'AOT_MANIFEST', 'AOT_CACHE_DIR']
 
 
-# What a recompute region keeps besides its inputs (`_run_region`). ONE
-# object for every region: jax caches the split of a jitted body into what
-# is known and what is run again on the policy's identity, so regions that
-# call one shared body (lowering.traced_once, the kernels' own jits) share
-# its halves too; a policy made anew a region gave every layer its own.
+# What a recompute region keeps besides its inputs (`_run_region`): the
+# flash calls' outputs and row statistics, and what the model marked with
+# `fluid.recompute_keep`, every mark of every region under the ONE name
+# REGION_KEEP. ONE object for every region: jax caches the split of a
+# jitted body into what is known and what is run again on the policy's
+# identity, so regions that call one shared body (lowering.traced_once,
+# the kernels' own jits) share its halves too; a policy made anew a region
+# gave every layer its own.
+REGION_KEEP = 'region_keep'
 _REGION_KEEPS = jax.checkpoint_policies.save_only_these_names(
-    'flash_out', 'flash_lse')
+    'flash_out', 'flash_lse', REGION_KEEP)
 
 
 def _is_annotated(program):
@@ -577,7 +583,8 @@ class StepArtifact(object):
         again. Kept besides: what ops/flash_attention.py names
         `flash_out` and `flash_lse`, an attention call's output and row
         statistics (a sixth of its forward's bytes, and its forward is a
-        third of its work)."""
+        third of its work), and the outputs the model marked
+        (`fluid.recompute_keep`; `_keep_marked`)."""
         hi, read, handed_on = self.regions[lo]
         tap_names = [taps[i][0] for i in range(lo, hi) if taps and i in taps]
         inputs = {n: env[n] for n in read + tap_names if n in env}
@@ -590,6 +597,29 @@ class StepArtifact(object):
 
         env.update(jax.checkpoint(region, policy=_REGION_KEEPS)(inputs))
         return hi
+
+    @staticmethod
+    def _keep_marked(op, env):
+        """Names the outputs of `op` that carry `fluid.recompute_keep`'s
+        mark REGION_KEEP, which `_REGION_KEEPS` saves: the region's
+        second forward reads them and does not run what made them. Each
+        goes on behind an `optimization_barrier`, an array in memory that
+        its readers read: a kept value is written once whatever happens,
+        and without the barrier XLA builds it again inside each reader
+        from what made it (two operands and a `reduce_precision` where
+        the parent's fusion read one array), and a matmul that takes such
+        a reader in gets a tiling three times as slow
+        (`granite4hmicro_s8192`'s W_out; chip, PR 54). Counted at trace
+        time: `recompute.kept_values`, and `recompute.kept_bytes` from
+        their shapes and dtypes."""
+        for name in op.attrs.get('recompute_keep', ()):
+            v = env[name]
+            data = jax.lax.optimization_barrier(
+                checkpoint_name(lowering.data_of(v), REGION_KEEP))
+            env[name] = lowering.like(v, data)
+            obs.counter('recompute.kept_values').inc()
+            obs.counter('recompute.kept_bytes').inc(
+                data.size * data.dtype.itemsize)
 
     def _make_fwd(self, base, ad, key, taps=None):
         """The differentiable forward closure: trainable -> (loss, env)."""
@@ -770,6 +800,8 @@ class StepArtifact(object):
                         if v.stop_gradient and v.name in env and env[v.name] is not None:
                             env[v.name] = jax.tree_util.tree_map(
                                 jax.lax.stop_gradient, env[v.name])
+            if in_region:
+                self._keep_marked(op, env)
 
     def _run_pipeline_region(self, env, key, grad_mode=False):
         with jax.named_scope('pipeline_region_%d' % self.pipe['region'][0]):

@@ -40,9 +40,10 @@ projection:
 
 `run_layers` names the layers that RUN by their index in `layer_types` (a
 pipeline stage runs a stretch of them). Each LAYER is one
-`fluid.recompute_guard()` region (the step keeps a layer's input and
-recomputes the rest); every Mamba-2 mixer is built under
-`fluid.name_scope('mamba_mixer')`, the attention mixer under
+`fluid.recompute_guard()` region (the step keeps a layer's input, its
+residual after the mixer and the outputs of the mixer's input projections,
+`decoder_layer`, and recomputes the rest); every Mamba-2 mixer is built
+under `fluid.name_scope('mamba_mixer')`, the attention mixer under
 `'attention_mixer'`, every feed-forward under `'dense_mlp'`; the builder
 counts `granite.layers{kind=}` once a layer it builds. The multipliers are
 `layers.scale` ops. The head's projection is the LAST `mul` built
@@ -57,7 +58,8 @@ import numpy as np
 import paddle_tpu.fluid as fluid
 from paddle_tpu import obs
 from paddle_tpu.fluid import layers
-from paddle_tpu.models.nemotron_h import attention_mixer, mamba_mixer
+from paddle_tpu.models.nemotron_h import (_unmarked, attention_mixer,
+                                          mamba_mixer)
 
 __all__ = ['granitemoehybrid', 'decoder_layer', 'dense_mlp', 'get_model',
            'LAYER_TYPES']
@@ -91,21 +93,28 @@ def dense_mlp(m, c):
                      c['hidden'], c['std'])
 
 
-def decoder_layer(x, index, c):
+def decoder_layer(x, index, c, keep=_unmarked):
     """Layer `index` of `layer_types`: its mixer, then the dense gated
-    feed-forward, each behind its norm and scaled into the residual."""
+    feed-forward, each behind its norm and scaled into the residual.
+    `keep` is called on the residual `h` after the mixer and on the
+    outputs of the mixer's input projections: whoever builds the layer
+    inside a recompute region passes `fluid.recompute_keep`
+    (`granitemoehybrid`), and the backward pass then runs neither those
+    projections nor the mixer's output projection again; the
+    feed-forward's matmuls are not marked (W_in's output is four times
+    `h`)."""
     kind = c['layer_types'][index]
     u = layers.rms_norm(x, epsilon=c['eps'])
     if kind == 'mamba':
-        mixed = mamba_mixer(u, c, index)
+        mixed = mamba_mixer(u, c, index, keep)
     elif kind == 'attention':
-        mixed = attention_mixer(u, c)
+        mixed = attention_mixer(u, c, keep)
     else:
         raise ValueError("granitemoehybrid: layer %d is %r; 'mamba' or "
                          "'attention'" % (index, kind))
     obs.counter('granite.layers', kind=kind).inc()          # build time
-    h = layers.elementwise_add(
-        x, layers.scale(mixed, scale=c['residual_scale']))
+    h = keep(layers.elementwise_add(
+        x, layers.scale(mixed, scale=c['residual_scale'])))
     y = dense_mlp(layers.rms_norm(h, epsilon=c['eps']), c)
     return layers.elementwise_add(
         h, layers.scale(y, scale=c['residual_scale']))
@@ -133,7 +142,7 @@ def granitemoehybrid(vocab_size, seq_len, layer_types=LAYER_TYPES,
         scale=embedding_scale)
     for i in run_layers:
         with fluid.recompute_guard():
-            x = decoder_layer(x, i, c)
+            x = decoder_layer(x, i, c, keep=fluid.recompute_keep)
     # the tied head: the embedding's second use (the name bound again),
     # transposed, into the last `mul` built
     table = layers.create_parameter([vocab_size, hidden], 'float32',

@@ -44,7 +44,9 @@ anywhere:
 `run_layers` names the layers that RUN by their index in `layer_types` (a
 pipeline stage runs a stretch of them); a layer is dense where its index
 is under `n_dense`. Each layer is one `fluid.recompute_guard()` region
-(the step keeps a layer's input and recomputes the rest); every short
+(the step keeps a layer's input, its residual after the operator and the
+outputs of the operator's input projections, `fluid.recompute_keep`, and
+recomputes the rest: the feed-forward and the experts); every short
 convolution is built under `fluid.name_scope('short_conv_mixer')`, every
 attention operator under `'attention_mixer'`, the bias update under
 `router_bias`. The head's projection is the LAST `mul` built (chipbench's
@@ -88,13 +90,18 @@ def _gated_mlp(m, hidden, width, std):
                                         _proj(m, width, std)), hidden, std)
 
 
-def short_conv_mixer(g, c):
+def _unmarked(var):
+    return var
+
+
+def short_conv_mixer(g, c, keep=_unmarked):
     """The double-gated short convolution on the normed input `g`.
-    Parameters in creation order: Win, the filter, Wout."""
+    Parameters in creation order: Win, the filter, Wout. `keep` is called
+    on the input projection's output (`decoder_layer`)."""
     with fluid.name_scope('short_conv_mixer'):
         obs.counter('shortconv.mixers').inc()               # build time
-        b, gate, x = layers.split(_proj(g, 3 * c['hidden'], c['std']), 3,
-                                  dim=-1)
+        b, gate, x = layers.split(
+            keep(_proj(g, 3 * c['hidden'], c['std'])), 3, dim=-1)
         # the filter starts where torch's Conv1d leaves it: uniform within
         # 1 / sqrt(taps) (a depthwise filter's fan-in is its taps)
         bound = c['conv_kernel'] ** -0.5
@@ -105,19 +112,20 @@ def short_conv_mixer(g, c):
         return _proj(y, c['hidden'], c['std'])
 
 
-def attention_mixer(g, c):
+def attention_mixer(g, c, keep=_unmarked):
     """Grouped-head causal attention on the normed input `g`, the queries
     and keys normed a head and then turned. Parameters in creation order:
-    Wq, Wk, Wv, the query norm, the key norm, Wo."""
+    Wq, Wk, Wv, the query norm, the key norm, Wo. `keep` is called on the
+    outputs of Wq, Wk and Wv."""
     d = c['d_head']
 
     def heads(t):
         return layers.transpose(t, perm=[0, 2, 1, 3])
 
     with fluid.name_scope('attention_mixer'):
-        q = layers.reshape(_proj(g, c['n_head'] * d, c['std']),
+        q = layers.reshape(keep(_proj(g, c['n_head'] * d, c['std'])),
                            shape=[0, 0, c['n_head'], d])
-        k, v = (layers.reshape(_proj(g, c['n_kv_head'] * d, c['std']),
+        k, v = (layers.reshape(keep(_proj(g, c['n_kv_head'] * d, c['std'])),
                                shape=[0, 0, c['n_kv_head'], d])
                 for _ in range(2))
         q, k = (layers.rotary_embedding(
@@ -145,20 +153,24 @@ def expert_block(m, c):
         return_expert_count=True)
 
 
-def decoder_layer(x, index, c):
+def decoder_layer(x, index, c, keep=_unmarked):
     """Layer `index` of `layer_types`: its operator, then the dense
     feed-forward (index < n_dense) or the expert block. Returns (output,
-    assignments per expert or None, the selection bias or None)."""
+    assignments per expert or None, the selection bias or None). `keep`
+    is called on the residual `h` after the operator and on the outputs
+    of the operator's input projections: whoever builds the layer inside
+    a recompute region passes `fluid.recompute_keep` (`lfm2_moe`), and
+    the backward pass then runs no projection of the operator again."""
     g = layers.rms_norm(x, epsilon=c['eps'])
     kind = c['layer_types'][index]
     if kind == 'conv':
-        mixed = short_conv_mixer(g, c)
+        mixed = short_conv_mixer(g, c, keep)
     elif kind == 'full_attention':
-        mixed = attention_mixer(g, c)
+        mixed = attention_mixer(g, c, keep)
     else:
         raise ValueError("lfm2_moe: layer %d is %r; 'conv' or "
                          "'full_attention'" % (index, kind))
-    h = layers.elementwise_add(x, mixed)
+    h = keep(layers.elementwise_add(x, mixed))
     m = layers.rms_norm(h, epsilon=c['eps'])
     if index < c['n_dense']:
         y, count, bias = _gated_mlp(m, c['hidden'], c['dense_width'],
@@ -189,7 +201,8 @@ def lfm2_moe(vocab_size, seq_len, layer_types=LAYER_TYPES, run_layers=None,
     counts, biases = [], []
     for i in run_layers:
         with fluid.recompute_guard():
-            x, count, bias = decoder_layer(x, i, c)
+            x, count, bias = decoder_layer(x, i, c,
+                                           keep=fluid.recompute_keep)
         if count is not None:
             counts.append(count)
             biases.append(bias)

@@ -84,16 +84,23 @@ def head_vectors(n_head, seed, dt_min, dt_max, dt_floor):
             np.ones(n_head))
 
 
-def mamba_mixer(u, c, index):
+def _unmarked(var):
+    return var
+
+
+def mamba_mixer(u, c, index, keep=_unmarked):
     """The Mamba-2 mixer on the normed input `u`. Parameters in creation
     order: Win, the convolution's filter and bias, dt_bias, A_log, D, the
-    gated norm's weight, Wout."""
+    gated norm's weight, Wout. `keep` is called on the input projection's
+    output: a caller whose recompute regions have the room passes
+    `fluid.recompute_keep` (GraniteMoeHybrid); this model's own blocks
+    pass nothing."""
     h, p, g, n = c['ssm_heads'], c['ssm_head_dim'], c['ssm_groups'], \
         c['ssm_state']
     inner, width = h * p, g * n
     with fluid.name_scope('mamba_mixer'):
-        z, xbc, dt = layers.split(_proj(u, 2 * inner + 2 * width + h,
-                                        c['std']),
+        z, xbc, dt = layers.split(keep(_proj(u, 2 * inner + 2 * width + h,
+                                             c['std'])),
                                   [inner, inner + 2 * width, h], dim=-1)
         # the filter and its bias start where torch's Conv1d leaves them:
         # uniform within 1 / sqrt(taps)
@@ -120,11 +127,12 @@ def mamba_mixer(u, c, index):
         return _proj(y, c['hidden'], c['std'])
 
 
-def attention_mixer(u, c):
+def attention_mixer(u, c, keep=_unmarked):
     """Grouped-head causal attention without positions on the normed input
     `u`, its scores scaled by `attn_scale` where the model states one
     (GraniteMoeHybrid), else by d_head^-0.5. Parameters in creation order:
-    Wq, Wk, Wv, Wo."""
+    Wq, Wk, Wv, Wo. `keep` is called on the outputs of Wq, Wk and Wv, as
+    `mamba_mixer`'s is on its input projection's."""
     d = c['d_head']
 
     def heads(t, n):
@@ -132,8 +140,8 @@ def attention_mixer(u, c):
                                 perm=[0, 2, 1, 3])
 
     with fluid.name_scope('attention_mixer'):
-        q = heads(_proj(u, c['n_head'] * d, c['std']), c['n_head'])
-        k, v = (heads(_proj(u, c['n_kv_head'] * d, c['std']),
+        q = heads(keep(_proj(u, c['n_head'] * d, c['std'])), c['n_head'])
+        k, v = (heads(keep(_proj(u, c['n_kv_head'] * d, c['std'])),
                       c['n_kv_head']) for _ in range(2))
         ctx = layers.fused_attention(q, k, v, causal=True,
                                      scale=c.get('attn_scale', d ** -0.5))

@@ -40,9 +40,13 @@ anywhere, for layer l with g = rms(x, w_in):
 In the source layers 4n are global without positions and layers 4n + 1
 .. 4n + 3 windowed with rotary positions (`sliding_window_layout` and
 `rope_layout`, [0, 1, 1, 1] repeated). Each layer is one
-`fluid.recompute_guard()` region; a layer's mixer (projections, rotary,
-the attention call) is built under `fluid.name_scope('window_attention')`
-or `fluid.name_scope('global_attention')` by its kind. The whole train
+`fluid.recompute_guard()` region that keeps, besides its input, the
+residual after the mixer and q, k and v as Wq, Wk and Wv give them
+(`fluid.recompute_keep`: the backward pass runs none of the mixer's four
+projections again, and the experts as before); a layer's mixer
+(projections, rotary, the attention call) is built under
+`fluid.name_scope('window_attention')` or
+`fluid.name_scope('global_attention')` by its kind. The whole train
 step is one XLA module.
 """
 import numpy as np
@@ -62,18 +66,23 @@ def _proj(x, size, std):
                      param_attr=_weight(std), bias_attr=False)
 
 
-def attention(g, windowed, rope, c):
+def _unmarked(var):
+    return var
+
+
+def attention(g, windowed, rope, c, keep=_unmarked):
     """The mixer on the normed input `g`. Parameters in creation order:
-    Wq, Wk, Wv, Wo."""
+    Wq, Wk, Wv, Wo. `keep` is called on the outputs of Wq, Wk and Wv
+    (`decoder_layer`)."""
     d = c['d_head']
 
     def heads(t, n):
         return layers.transpose(layers.reshape(t, shape=[0, 0, n, d]),
                                 perm=[0, 2, 1, 3])
 
-    q = heads(_proj(g, c['n_head'] * d, c['std']), c['n_head'])
-    k, v = (heads(_proj(g, c['n_kv_head'] * d, c['std']), c['n_kv_head'])
-            for _ in range(2))
+    q = heads(keep(_proj(g, c['n_head'] * d, c['std'])), c['n_head'])
+    k, v = (heads(keep(_proj(g, c['n_kv_head'] * d, c['std'])),
+                  c['n_kv_head']) for _ in range(2))
     if rope:
         q, k = (layers.rotary_embedding(t, base=c['rope_theta'])
                 for t in (q, k))
@@ -84,17 +93,21 @@ def attention(g, windowed, rope, c):
     return _proj(ctx, c['hidden'], c['std'])
 
 
-def decoder_layer(x, index, c):
+def decoder_layer(x, index, c, keep=_unmarked):
     """Layer `index`. Returns (output, load-balancing loss, assignments
     per expert). Parameters in creation order: the input norm, Wq, Wk, Wv,
     Wo, the post-attention norm, the router, the experts' gate, up and
-    down stacks."""
+    down stacks. `keep` is called on the residual `h` after the mixer and
+    on q, k and v as their projections give them: whoever builds the
+    layer inside a recompute region passes `fluid.recompute_keep`
+    (`smallthinker`)."""
     windowed = bool(c['sliding_window_layout'][index])
     g = layers.rms_norm(x, epsilon=c['eps'])
     with fluid.name_scope('window_attention' if windowed
                           else 'global_attention'):
-        mixed = attention(g, windowed, bool(c['rope_layout'][index]), c)
-    h = layers.elementwise_add(x, mixed)
+        mixed = attention(g, windowed, bool(c['rope_layout'][index]), c,
+                          keep)
+    h = keep(layers.elementwise_add(x, mixed))
     y, aux, count = layers.moe_mlp(
         layers.rms_norm(h, epsilon=c['eps']), num_experts=c['n_expert'],
         hidden_size=c['expert_width'], act='relu', gated=True,
@@ -131,7 +144,8 @@ def smallthinker(vocab_size, seq_len, n_layer=52, hidden=2560, n_head=28,
     auxes, counts = [], []
     for i in range(n_layer):
         with fluid.recompute_guard():
-            x, aux, count = decoder_layer(x, i, c)
+            x, aux, count = decoder_layer(x, i, c,
+                                          keep=fluid.recompute_keep)
         auxes.append(aux)
         counts.append(count)
     # the head is the last fc built (chipbench's loss_head_ms reads that)

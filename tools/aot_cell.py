@@ -14,7 +14,9 @@ entry of the configuration's `checks` instead: the check Program in its
 own arithmetic and the plain reference on the check's sample, the two
 programs that share the chip with the scope before the window (a
 reference that walks its layers with the weights on the host, one that
-has `pieces`, as the pieces of its walk). One chip
+has `pieces`, as the pieces of its walk). The step's line also says how
+many values its recompute regions keep by the model's marks and their
+bytes (`recompute.kept_values`, `recompute.kept_bytes`). One chip
 only: a mesh cell builds its mesh from jax.devices(). A compile that
 passes is not a chip run.
 """
@@ -43,6 +45,7 @@ def main(argv=None):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     import paddle_tpu.fluid as fluid
+    from paddle_tpu import obs
     from chipbench.harness import catalog
 
     cell = catalog.load_cell(args.workload)
@@ -89,6 +92,10 @@ def main(argv=None):
           else contextlib.nullcontext()):
         done = compiled._jitted.lower(
             *shapes, spec(jax.random.key(0))).compile()
+    # what the step's recompute regions keep by the model's marks
+    # (fluid.recompute_keep): this process's one trace of a step counted
+    kept = {n: int(obs.counter(n).value) for n in (
+        'recompute.kept_values', 'recompute.kept_bytes')}
     text = done.as_text()
     if args.hlo:
         with open(args.hlo, 'w') as f:
@@ -112,7 +119,7 @@ def main(argv=None):
             flush=True)
 
     report(args.check or 'train_step', done,
-           mosaic_calls=text.count('tpu_custom_call'))
+           mosaic_calls=text.count('tpu_custom_call'), **kept)
     if not args.check:
         return 0
 
