@@ -678,7 +678,7 @@ def rms_norm(input, epsilon=1e-05, param_attr=None, name=None):
 
 
 def gated_rms_norm(input, gate, epsilon=1e-05, param_attr=None, name=None,
-                   norm_before_gate=True, groups=1):
+                   norm_before_gate=True, groups=1, gate_act='silu'):
     """RMS norm over the last axis times a SiLU gate of the same shape:
     ``scale * x * rsqrt(mean(x^2) + epsilon) * silu(gate)``, `scale` as
     layers.rms_norm's. One Program op whose backward keeps `input` and
@@ -689,7 +689,11 @@ def gated_rms_norm(input, gate, epsilon=1e-05, param_attr=None, name=None,
     (Mamba-2): ``scale * u * rsqrt(mean(u^2) + epsilon)`` with ``u = x *
     silu(gate)``. ``groups`` G > 1 takes the mean of squares over each of
     G equal parts of the last axis by itself (`scale` stays one weight an
-    element of the whole axis)."""
+    element of the whole axis). ``gate_act='sigmoid'`` gates by
+    ``sigmoid(gate)`` wherever ``silu(gate)`` stands above."""
+    if gate_act not in ('silu', 'sigmoid'):
+        raise ValueError('gated_rms_norm: gate_act is silu or sigmoid, got '
+                         '%r' % (gate_act,))
     if int(groups) < 1 or int(input.shape[-1]) % int(groups):
         raise ValueError('gated_rms_norm: %r groups do not divide the last '
                          'axis of %r' % (groups, int(input.shape[-1])))
@@ -706,6 +710,8 @@ def gated_rms_norm(input, gate, epsilon=1e-05, param_attr=None, name=None,
         attrs['norm_before_gate'] = False
     if int(groups) > 1:
         attrs['groups'] = int(groups)
+    if gate_act != 'silu':
+        attrs['gate_act'] = gate_act
     helper.append_op(type='gated_rms_norm',
                      inputs={'X': [input], 'Gate': [gate],
                              'Scale': [scale]},
@@ -713,14 +719,17 @@ def gated_rms_norm(input, gate, epsilon=1e-05, param_attr=None, name=None,
     return out
 
 
-def rotary_embedding(x, base=10000.0, rotary_dim=None, name=None):
+def rotary_embedding(x, base=10000.0, rotary_dim=None, name=None,
+                     interleave=False):
     """Rotary position embedding of heads ``x`` [B, H, T, D] at positions
     0..T-1: element i of a head is rotated with element i + D/2 (the
     rotate-half pairing) by the angle ``t * base**(-2i/D)``. With
     `rotary_dim` R < D (a partial rotary factor) only the FIRST R elements
     of each head turn, paired (i, i + R/2) at ``t * base**(-2i/R)``; the
-    rest pass through. No parameter. One Program op. TPU extension (the
-    reference predates it)."""
+    rest pass through. ``interleave=True`` pairs NEIGHBOURS instead:
+    elements 2i and 2i + 1 turn together by the same angle, each staying
+    where it is (DeepSeek's `rope_interleave`). No parameter. One Program
+    op. TPU extension (the reference predates it)."""
     width = int(x.shape[-1])
     rotary_dim = width if rotary_dim is None else int(rotary_dim)
     if rotary_dim % 2 or not 0 < rotary_dim <= width:
@@ -731,13 +740,15 @@ def rotary_embedding(x, base=10000.0, rotary_dim=None, name=None):
     attrs = {'base': float(base)}
     if rotary_dim != width:
         attrs['rotary_dim'] = rotary_dim
+    if interleave:
+        attrs['interleave'] = True
     helper.append_op(type='rotary_embedding', inputs={'X': [x]},
                      outputs={'Out': [out]}, attrs=attrs)
     return out
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk_size=64, scale=None,
-                     qk_l2norm=False, name=None):
+                     qk_l2norm=False, name=None, gate_floor=None):
     """The gated delta rule, Gated DeltaNet's linear attention (Yang et
     al. 2024, arXiv:2412.06464), in ONE op. Per head a [Dk, Dv] float32
     state S, zero at a row's first token, and for each token t
@@ -747,7 +758,12 @@ def gated_delta_rule(q, k, v, g, beta, chunk_size=64, scale=None,
 
     q, k: [B, T, Hk, Dk]; v: [B, T, Hv, Dv] with Hk dividing Hv (key head
     h serves value heads h * Hv/Hk and following); g (<= 0, the log of
-    the decay) and beta (the write strength): [B, T, Hv]. With
+    the decay) and beta (the write strength): [B, T, Hv]. A g of
+    [B, T, Hv, Dk] is a decay a CHANNEL (Kimi Delta Attention,
+    arXiv:2510.26692): ``S = diag(exp(g_t)) S``, each row of the state at
+    its own rate; it needs ``gate_floor``, the bound ``g >= gate_floor``
+    its producer keeps (-5 for ``-5 * sigmoid(.)``), which the rule holds g
+    to and refuses where 16 rows of it overflow a float32. With
     ``qk_l2norm`` q and k are first divided by their norm over Dk
     (``x * rsqrt(sum x^2 + 1e-6)``); q is then multiplied by `scale`
     (default ``Dk ** -0.5``). Returns o [B, T, Hv, Dv].
@@ -762,15 +778,20 @@ def gated_delta_rule(q, k, v, g, beta, chunk_size=64, scale=None,
     if int(v.shape[2]) % int(q.shape[2]) or q.shape[2] != k.shape[2]:
         raise ValueError('gated_delta_rule: %r key heads do not divide %r '
                          'value heads' % (q.shape[2], v.shape[2]))
+    if len(g.shape) == 4 and gate_floor is None:
+        raise ValueError('gated_delta_rule: a decay a channel (g %r) needs '
+                         'gate_floor' % (tuple(g.shape),))
     helper = LayerHelper('gated_delta_rule', **locals())
     out = helper.create_variable_for_type_inference(v.dtype)
+    attrs = {'chunk_size': int(chunk_size),
+             'scale': float(scale) if scale is not None else -1.0,
+             'qk_l2norm': bool(qk_l2norm)}
+    if gate_floor is not None:
+        attrs['gate_floor'] = float(gate_floor)
     helper.append_op(
         type='gated_delta_rule',
         inputs={'Q': [q], 'K': [k], 'V': [v], 'G': [g], 'Beta': [beta]},
-        outputs={'Out': [out]},
-        attrs={'chunk_size': int(chunk_size),
-               'scale': float(scale) if scale is not None else -1.0,
-               'qk_l2norm': bool(qk_l2norm)})
+        outputs={'Out': [out]}, attrs=attrs)
     return out
 
 
@@ -1092,7 +1113,7 @@ def fused_attention(q, k, v, key_bias=None, causal=False, scale=None,
 def latent_attention(input, size, num_heads, q_lora_rank, kv_lora_rank,
                      qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
                      rope_theta=10000.0, epsilon=1e-05, param_attr=None,
-                     name=None):
+                     name=None, rope_interleave=False, head_gate=False):
     """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434, section
     2.1), causal, over ``input`` [B, T, d]: queries, keys and values are
     made from low-rank latents, and the rotary part of a key is ONE head
@@ -1106,22 +1127,26 @@ def latent_attention(input, size, num_heads, q_lora_rank, kv_lora_rank,
         q_h = [q_nope_h | q_rope_h];  k_h = [k_nope_h | kr]
         out = concat_h(causal_softmax(q_h k_h^T / sqrt(width)) v_h) Wo
 
+    ``q_lora_rank=None``: no query latent, ``q = input Wq`` (no norm).
+    `v_head_dim` is free of the keys' width ``qk_nope_head_dim +
+    qk_rope_head_dim``: fused_attention and the flash kernels take the
+    values and the output at their own width. ``rope_interleave`` turns
+    neighbouring pairs (2j, 2j + 1) of the rope part
+    (layers.rotary_embedding). ``head_gate=True`` multiplies each head's
+    output, before Wo, by ``sigmoid(input Wgate)``, Wgate [d, heads]: one
+    gate a head a token.
+
     No biases. Built from fc, rms_norm, split, rotary_embedding, expand,
-    concat and fused_attention (the flash kernels on the TPU), so the
-    keys' and the values' head width must be one:
-    ``qk_nope_head_dim + qk_rope_head_dim == v_head_dim``. What the
+    concat and fused_attention (the flash kernels on the TPU). What the
     training step keeps of it is the recompute region's to say
     (fluid.recompute_guard). Parameters in creation order: Wqa, the query
-    latent's norm, Wqb, Wkva, the key-value latent's norm, Wkvb, Wo;
-    `param_attr` (its initializer) serves the five matrices. Returns
-    [B, T, size]. TPU extension (the reference predates it)."""
+    latent's norm, Wqb (or Wq alone), Wkva, the key-value latent's norm,
+    Wkvb, Wgate where there is one, Wo; `param_attr` (its initializer)
+    serves the matrices. Returns [B, T, size]. TPU extension (the
+    reference predates it)."""
     h, nope, rope = int(num_heads), int(qk_nope_head_dim), \
         int(qk_rope_head_dim)
-    width = nope + rope
-    if width != int(v_head_dim):
-        raise ValueError('latent_attention: keys of %d + %d and values of '
-                         '%d: the attention kernels take one head width'
-                         % (nope, rope, v_head_dim))
+    width, dv = nope + rope, int(v_head_dim)
 
     def proj(x, n):
         return fc(input=x, size=n, num_flatten_dims=2, bias_attr=False,
@@ -1130,23 +1155,30 @@ def latent_attention(input, size, num_heads, q_lora_rank, kv_lora_rank,
     def heads(x, n, d):
         return transpose(reshape(x, shape=[0, 0, n, d]), perm=[0, 2, 1, 3])
 
-    cq = rms_norm(proj(input, int(q_lora_rank)), epsilon=epsilon)
+    def rotary(x):
+        return rotary_embedding(x, base=rope_theta,
+                                interleave=bool(rope_interleave))
+
+    cq = input if q_lora_rank is None else \
+        rms_norm(proj(input, int(q_lora_rank)), epsilon=epsilon)
     q_nope, q_rope = split(heads(proj(cq, h * width), h, width),
                            [nope, rope], dim=-1)
     ckv, kr = split(proj(input, int(kv_lora_rank) + rope),
                     [int(kv_lora_rank), rope], dim=-1)
     k_nope, v = split(
-        heads(proj(rms_norm(ckv, epsilon=epsilon), h * (nope + width)), h,
-              nope + width), [nope, width], dim=-1)
-    q_rope = rotary_embedding(q_rope, base=rope_theta)
-    kr = expand(rotary_embedding(heads(kr, 1, rope), base=rope_theta),
-                expand_times=[1, h, 1, 1])
+        heads(proj(rms_norm(ckv, epsilon=epsilon), h * (nope + dv)), h,
+              nope + dv), [nope, dv], dim=-1)
+    q_rope = rotary(q_rope)
+    kr = expand(rotary(heads(kr, 1, rope)), expand_times=[1, h, 1, 1])
     ctx = fused_attention(
         tensor_mod.concat([q_nope, q_rope], axis=-1),
         tensor_mod.concat([k_nope, kr], axis=-1), v, causal=True,
         scale=width ** -0.5)
-    ctx = reshape(transpose(ctx, perm=[0, 2, 1, 3]), shape=[0, 0, h * width])
-    return proj(ctx, int(size))
+    ctx = transpose(ctx, perm=[0, 2, 1, 3])                  # [B, T, h, dv]
+    if head_gate:
+        from . import ops
+        ctx = ops.elementwise_mul(ctx, ops.sigmoid(proj(input, h)), axis=0)
+    return proj(reshape(ctx, shape=[0, 0, h * dv]), int(size))
 
 
 def topk(input, k, name=None):
@@ -1669,7 +1701,8 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
             bias_attr=None, name=None, top_k=1, return_aux_loss=False,
             gated=False, norm_topk_prob=True, return_expert_count=False,
             experts_held=None, scoring='softmax', selection_bias=False,
-            gate_scale=1.0, router_input=None, norm_eps=None):
+            gate_scale=1.0, router_input=None, norm_eps=None, n_group=1,
+            topk_group=1):
     """Top-k gated mixture-of-experts FFN (TPU extension; the reference
     predates MoE — its conditional-computation ancestor is layers.Switch).
 
@@ -1723,6 +1756,12 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
     sum (``None``: the scoring's own, 0 under 'softmax' and DeepSeek-V3's
     1e-20 under 'sigmoid'; LFM2's router carries 1e-6).
 
+    ``n_group`` G > 1 with ``topk_group`` (the sigmoid router's:
+    DeepSeek-V3's group-limited routing) confines the choice to the best
+    `topk_group` of G groups of ``num_experts / G`` consecutive experts, a
+    group ranked by the sum of its two largest score + bias; the gates, a
+    held share and `expert_count` are what they are without groups.
+
     ``router_input`` (dropless only): a tensor of `input`'s shape that the
     ROUTER reads in place of `input`: the logits are ``router_input @
     gate_w`` and `input` feeds the experts alone (SmallThinker,
@@ -1766,6 +1805,18 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
         raise ValueError("moe_mlp: selection_bias and gate_scale belong to "
                          "scoring='sigmoid' (no model here has them under "
                          'a softmax router)')
+    if int(n_group) > 1:
+        if scoring != 'sigmoid':
+            raise ValueError("moe_mlp: n_group belongs to scoring='sigmoid' "
+                             '(no model here has groups under a softmax '
+                             'router)')
+        per_group = int(num_experts) // int(n_group)
+        if per_group * int(n_group) != int(num_experts) or not (
+                1 <= int(topk_group) <= int(n_group)) or \
+                int(top_k) > int(topk_group) * per_group:
+            raise ValueError('moe_mlp: %r groups of which %r stay do not '
+                             'hold the top %r of %r experts'
+                             % (n_group, topk_group, top_k, num_experts))
     if router_input is not None:
         if capacity_factor is not None:
             raise ValueError("moe_mlp: router_input is the dropless "
@@ -1847,6 +1898,8 @@ def moe_mlp(input, num_experts, hidden_size, size=None, act='relu',
         attrs['gate_scale'] = float(gate_scale)
     if norm_eps is not None:
         attrs['norm_eps'] = float(norm_eps)
+    if int(n_group) > 1:
+        attrs['n_group'], attrs['topk_group'] = int(n_group), int(topk_group)
     helper.append_op(type='moe_mlp', inputs=inputs, outputs=outputs,
                      attrs=attrs)
     got = (out,) + ((aux,) if return_aux_loss else ()) \

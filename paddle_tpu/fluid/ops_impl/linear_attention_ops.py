@@ -30,6 +30,44 @@ inside a chunk and D_ij = exp(G_i - G_j) for i >= j, 0 above the diagonal
     O   = diag(exp G) Q S + P Vn
     S   = exp(G_C) S + (diag(exp(G_C - G)) K)^T Vn
 
+A DECAY A CHANNEL (Kimi Delta Attention, arXiv:2510.26692): g [B, T, H,
+Dk] in place of [B, T, H] (the rank of G says which; no attribute
+chooses), S = diag(exp(g_t)) S: each ROW of the state at its own rate. G is
+then [C, Dk] and D no longer factors out of K K^T and Q K^T:
+
+    A_ij = beta_i sum_d k_id k_jd exp(G_id - G_jd)        i > j
+    P_ij = sum_d q_id k_jd exp(G_id - G_jd)      i > j;  P_ii = q_i . k_i
+    T = (I + A)^-1,  U = T diag(beta) V,  W = T diag(beta) (K * exp G)
+    Vn = U - W S,  O = (Q * exp G) S + P Vn
+    S  = diag(exp G_C) S + (K * exp(G_C - G))^T Vn        exp G_C a [Dk] vector
+
+A and P are formed by row blocks of 16 as matmuls of K_i * exp(G_i - r_a)
+with K_j * exp(r_a - G_j), r_a the running sum at the block's MIDDLE row:
+for j before the block the second exponent is <= 0, inside the block both
+lie within 8 x |gate_floor| of 0. That bound is the op's attribute
+`gate_floor` (the layer's producer keeps g >= gate_floor: -5 for
+-5 * sigmoid(.)): the rule holds g to it (a g AT the floor keeps its whole
+gradient) and refuses a floor whose half block passes exp(44), so that a
+factor and the operand it scales stay normal float32 numbers both ways
+(referred to the block's edge, exp(-80) times a small cotangent is flushed
+to zero and its partner exp(80) makes the loss all of g's gradient). A
+token's own pair (the diagonal of P) decays by exp(0) and is q . k as it
+is, outside the product: inside it, its two cotangents to G_i cancel only
+to the matmuls' rounding, which under bf16 is more than the whole of g's
+gradient where the decay is strong. What is still exponentiated from
+running sums is rounded as they are: at g near -5 G reaches -320 a chunk
+and a factor is off by 3e-5, which is what a float32 comparison reads on
+the decay's own parameters. The per-channel form has its own composition
+(`_intra_channel`), its own `gdn_intra` kernel (a key head a value head;
+a grid step of 8 heads in bf16 asks 5.88 MiB of scoped VMEM forward and
+8.36 backward, 4.13 and 8.70 at 4 heads in float32, where the per-head
+kernel asks 4.62 and 6.76: compiled for a described v5e, PR 55) and the
+same `gdn_scan` kernels, which scale S's rows by the chunk's [Dk] decay (a
+head's row of lanes turned to a column in VMEM) where they multiply by a
+scalar (2.52, 2.74 and 5.74 MiB for the three walks in bf16, 12.99 the
+reverse walk in float32): all within Mosaic's default of 16 MiB, no call
+states a limit. The per-head path is what it was.
+
 The backward is the op's own (`jax.custom_vjp`) and keeps the op's inputs
 alone: no state a token, and not even a state a chunk (S at the starts of
 128 chunks is [128, B, 32, 128, 128] float32, 256 MiB a layer at B = 1,
@@ -133,7 +171,9 @@ rule's stage.
 
 `gated_rms_norm`: y = w * x * rsqrt(mean(x^2) + eps) * silu(gate) over the
 last axis, statistics in float32; its backward keeps x, the gate (bf16
-under AMP) and w and computes the rest again. Two attributes give
+under AMP) and w and computes the rest again. `gate_act` `sigmoid` puts
+sigmoid(gate) where silu(gate) stands (Kimi Delta Attention's output
+gate), in the composition and in both kernels. Two attributes give
 Mamba-2's form: `norm_before_gate` false gates FIRST,
 y = w * rmsnorm(x * silu(gate)), and `groups` G takes the mean over each
 of G equal parts of the last axis by itself.
@@ -152,7 +192,8 @@ to [.., G, width], which on the TPU puts the groups where the tiles keep
 eight rows and moves the array round the sum), the composition the kernel
 is tested against. The rule chooses as it does for the delta rule's stage.
 
-Trace-time counters: `gdn.lowered{chunk=}` once per op per trace,
+Trace-time counters: `gdn.lowered{chunk=, gate=head|channel}` once per op
+per trace,
 `gdn.intra{way=kernel|composed}` and `gdn.scan{way=kernel|composed}`
 beside it (which way each stage went), `gdn.tokens` the B x T of the
 traced shape,
@@ -280,14 +321,67 @@ def _intra(q, k, v, g, beta):
     return w, u, qg, kd, p, jnp.exp(last[..., 0])
 
 
+def _channel_block(c):
+    """Rows of a chunk of `c` whose decays by channel share one reference:
+    the solve's block where the chunk is whole blocks, else the chunk's
+    largest divisor within one."""
+    return max(b for b in range(1, _SOLVE_BLOCK + 1) if c % b == 0)
+
+
+def _intra_channel(q, k, v, g, beta):
+    """Stage `gdn_intra` with a decay a CHANNEL: g [N, B, H, C, Dk]
+    float32, the rest as `_intra`. The chunk's decay is [N, B, H, Dk]. The
+    scores by row block a (`_channel_block` rows) with r_a the running sum
+    G at the block's MIDDLE row: rows times exp(G_i - r_a), columns of the
+    blocks up to a times exp(r_a - G_j) (at most 1 before the block), one
+    matmul a block. Inside the block either exponent is within half a
+    block x |gate_floor| of 0 (40 here: the rule has bounded it), so that
+    a factor and what it multiplies stay normal float32 numbers both ways:
+    with the reference at the block's edge a factor of exp(-80) times a
+    small cotangent is flushed to zero, and its partner exp(80) makes that
+    loss the whole of g's gradient."""
+    dtype = v.dtype
+    c, dk = q.shape[-2:]
+    blk = _channel_block(c)
+    nb = c // blk
+    gc = jnp.cumsum(g, axis=-2)                              # G
+    starts = gc[..., (blk - 1) // 2::blk, :][..., :, None, :]    # r_a
+    blocks = gc.shape[:-2] + (nb, blk, dk)
+    rows = jnp.exp(gc.reshape(blocks) - starts)
+    # the columns a row block sees: its own block's and the earlier ones'
+    seen = (np.arange(c) < (np.arange(nb)[:, None] + 1) * blk)[..., None]
+    cols = jnp.where(seen, jnp.exp(jnp.where(
+        seen, starts - gc[..., None, :, :], 0.0)), 0.0)      # [.., nb, C, Dk]
+    qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
+    kcols = kf[..., None, :, :] * cols
+    kk, qk = (_mm('...aik,...ajk->...aij', x.reshape(blocks) * rows, kcols,
+                  dtype).reshape(gc.shape[:-1] + (c,)) for x in (kf, qf))
+    strict = np.tril(np.ones((c, c), bool), -1)
+    a = jnp.where(strict, kk, 0.0) * beta[..., :, None]
+    t = _unit_lower_inverse(a)
+    e_g = jnp.exp(gc)
+    u = _mm('...ij,...jd->...id', t, vf * beta[..., None], dtype)
+    w = _mm('...ij,...jd->...id', t, kf * (beta[..., None] * e_g), dtype)
+    # a token's own pair decays by exp(0): q . k as it is, so that G's
+    # gradient is not what two roundings leave of a term that cancels
+    p = jnp.where(strict, qk, 0.0) + jnp.eye(c, dtype=jnp.float32) \
+        * jnp.sum(qf * kf, axis=-1)[..., None]
+    last = gc[..., -1:, :]
+    return (w, u, qf * e_g, kf * jnp.exp(last - gc), p,
+            jnp.exp(last[..., 0, :]))
+
+
 def _chunk_step(s, x, dtype):
     """One chunk of stage `gdn_scan`: the state S [B, H, Dk, Dv] float32 in,
-    (the state after the chunk, the chunk's outputs) out."""
+    (the state after the chunk, the chunk's outputs) out. The chunk's
+    decay is [B, H], or [B, H, Dk] where the state's rows decay each at
+    its own rate."""
     w, u, qg, kd, p, decay = x
     vn = u - _mm('...ck,...kv->...cv', w, s, dtype)
     o = _mm('...ck,...kv->...cv', qg, s, dtype) \
         + _mm('...ij,...jv->...iv', p, vn, dtype)
-    s = s * decay[..., None, None] + _mm('...ck,...cv->...kv', kd, vn, dtype)
+    s = s * decay[(Ellipsis,) + (None,) * (s.ndim - decay.ndim)] \
+        + _mm('...ck,...cv->...kv', kd, vn, dtype)
     return s, o
 
 
@@ -326,6 +420,11 @@ def _stage_intra(q, k, v, g, beta, cfg):
         _to_chunks(x, chunk) for x in (
             qf.astype(dtype), kf.astype(dtype), v, g.astype(jnp.float32),
             beta.astype(jnp.float32)))
+    if g.ndim == 5:                 # a decay a channel
+        if kernel:
+            return intra_kernel.gated_delta_intra(
+                q, k, v, jnp.cumsum(g, axis=-2), beta, False)
+        return _intra_channel(q, k, v, g, beta)
     if kernel:
         return intra_kernel.gated_delta_intra(
             q, k, v, jnp.cumsum(g, axis=-1), beta, False)
@@ -414,23 +513,47 @@ def _chunk_of(chunk_size, t):
     return min(int(chunk_size), 1 << max(t - 1, 0).bit_length())
 
 
+# the largest exponent stage `gdn_intra` may take of a decay a channel:
+# exp(44) squared is finite in float32 (and in bf16, which has its range),
+# so a factor leaves its operand half the range
+_MAX_EXPONENT = 44.0
+
+
 def gated_delta_rule(q, k, v, g, beta, chunk_size=64, scale=None,
                      qk_l2norm=False, l2norm_eps=1e-6, kernel=False,
-                     scan_kernel=False):
+                     scan_kernel=False, gate_floor=None):
     """q, k [B, T, Hk, Dk], v [B, T, Hv, Dv] (float32, or bf16 for bf16
-    matmuls), g, beta [B, T, Hv]; Hk divides Hv and key head h serves the
-    value heads h * Hv / Hk and following. Returns o [B, T, Hv, Dv]
-    float32. `qk_l2norm`: q and k are first divided by their norm over
-    Dk, x * rsqrt(sum x^2 + eps), in float32; then q is scaled (`scale`,
-    default Dk^-0.5). `kernel`: stage `gdn_intra` as the Pallas kernel,
-    `scan_kernel`: stage `gdn_scan` as the Pallas kernels (the rule's
-    choices; the caller has asked each one's `usable`)."""
+    matmuls), beta [B, T, Hv], g [B, T, Hv] (a decay a head) or
+    [B, T, Hv, Dk] (a decay a channel: the rank says which); Hk divides Hv
+    and key head h serves the value heads h * Hv / Hk and following.
+    Returns o [B, T, Hv, Dv] float32. `qk_l2norm`: q and k are first
+    divided by their norm over Dk, x * rsqrt(sum x^2 + eps), in float32;
+    then q is scaled (`scale`, default Dk^-0.5). `kernel`: stage
+    `gdn_intra` as the Pallas kernel, `scan_kernel`: stage `gdn_scan` as
+    the Pallas kernels (the rule's choices; the caller has asked each
+    one's `usable`). `gate_floor`: the bound g >= gate_floor that a decay
+    a channel needs (a block of rows exponentiates up to half a block x
+    |gate_floor| either way); the rule holds g to it and refuses one too
+    low for a float32."""
     dk = q.shape[3]
     scale = dk ** -0.5 if scale is None else float(scale)
+    chunk = _chunk_of(chunk_size, q.shape[1])
+    if g.ndim == 4:
+        reach = (_channel_block(chunk) + 1) // 2     # from a block's middle
+        if gate_floor is None or not \
+                -_MAX_EXPONENT <= reach * float(gate_floor) <= 0.0:
+            raise ValueError(
+                'gated_delta_rule: a decay a channel needs gate_floor with '
+                '%d x gate_floor >= %g (half a block of a chunk of %d is '
+                'exponentiated at once), got %r'
+                % (reach, -_MAX_EXPONENT, chunk, gate_floor))
+        # held to its floor; a g AT the floor (a saturated gate) keeps its
+        # whole gradient, which `jnp.maximum` would halve
+        g = g.astype(jnp.float32)
+        g = jnp.where(g < float(gate_floor), float(gate_floor), g)
     return _chunked(q, k, v, g, beta,
-                    (_chunk_of(chunk_size, q.shape[1]), scale,
-                     bool(qk_l2norm), float(l2norm_eps), bool(kernel),
-                     bool(scan_kernel)))
+                    (chunk, scale, bool(qk_l2norm), float(l2norm_eps),
+                     bool(kernel), bool(scan_kernel)))
 
 
 @register('gated_delta_rule')
@@ -438,14 +561,18 @@ def _gated_delta_rule(ins, attrs, ctx):
     q, k, v, g, beta = (data_of(ins[s][0])
                         for s in ('Q', 'K', 'V', 'G', 'Beta'))
     chunk = int(attrs.get('chunk_size', 64))
-    obs.counter('gdn.lowered', chunk=chunk).inc()            # trace time
+    channel = g.ndim == 4           # a decay a channel: the rank says so
+    obs.counter('gdn.lowered', chunk=chunk,                  # trace time
+                gate='channel' if channel else 'head').inc()
     obs.counter('gdn.tokens').inc(int(v.shape[0]) * int(v.shape[1]))
     q, k, v = amp_cast(ctx, q, k, v)
     cut = _chunk_of(chunk, q.shape[1])
     # stage `gdn_intra`: the Pallas kernel on the TPU for a shape it takes,
-    # as the expert layer takes its grouped matmul there
+    # as the expert layer takes its grouped matmul there (by channel: a
+    # key head a value head)
     kernel = ctx.platform == 'tpu' and intra_kernel.usable(
-        cut, q.shape[3], v.shape[3], v.dtype)
+        cut, q.shape[3], v.shape[3], v.dtype) and not (
+            channel and q.shape[2] != v.shape[2])
     obs.counter('gdn.intra',                                 # trace time
                 way='kernel' if kernel else 'composed').inc()
     # stage `gdn_scan`: the Pallas kernels read what that kernel hands over
@@ -459,7 +586,8 @@ def _gated_delta_rule(ins, attrs, ctx):
         scale=None if scale is None or scale < 0 else float(scale),
         qk_l2norm=bool(attrs.get('qk_l2norm', False)),
         l2norm_eps=float(attrs.get('l2norm_eps', 1e-6)),
-        kernel=kernel, scan_kernel=scan)
+        kernel=kernel, scan_kernel=scan,
+        gate_floor=attrs.get('gate_floor'))
     return {'Out': o}
 
 
@@ -674,22 +802,35 @@ def _causal_conv1d(ins, attrs, ctx):
     return {'Out': _gate(y, gates[1])}
 
 
+_GATE_ACTS = {'silu': jax.nn.silu, 'sigmoid': jax.nn.sigmoid}
+
+
+def _norm_cfg(cfg):
+    """(eps, norm_before_gate, groups, the gate's activation: `silu` where
+    `cfg` names none)"""
+    return tuple(cfg) + ('silu',) * (4 - len(cfg))
+
+
 def _gated_norm(x, gate, w, cfg):
-    eps, norm_first, groups = cfg
+    eps, norm_first, groups, act = _norm_cfg(cfg)
+    act = _GATE_ACTS[act]
     xf = x.astype(jnp.float32)
     if not norm_first:
-        xf = xf * jax.nn.silu(gate.astype(jnp.float32))
+        xf = xf * act(gate.astype(jnp.float32))
     parts = xf.reshape(xf.shape[:-1] + (groups, -1)) if groups > 1 else xf
     inv = lax.rsqrt(jnp.mean(jnp.square(parts), axis=-1, keepdims=True) + eps)
     y = (parts * inv).reshape(xf.shape) if groups > 1 else xf * inv
     y = y * w.astype(jnp.float32)
-    return y * jax.nn.silu(gate.astype(jnp.float32)) if norm_first else y
+    return y * act(gate.astype(jnp.float32)) if norm_first else y
 
 
 def _kernel_args(cfg):
-    eps, norm_first, groups = cfg
-    return dict(eps=eps, norm_first=norm_first, groups=groups,
+    eps, norm_first, groups, act = _norm_cfg(cfg)
+    args = dict(eps=eps, norm_first=norm_first, groups=groups,
                 interpret=False)
+    if act != 'silu':
+        args['gate_act'] = act
+    return args
 
 
 def _gated_norm_forward(x, gate, w, cfg, kernel):
@@ -700,7 +841,8 @@ def _gated_norm_forward(x, gate, w, cfg, kernel):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def gated_rms_norm(x, gate, w, cfg, kernel=False):
-    """`cfg` = (eps, norm_before_gate, groups), float32:
+    """`cfg` = (eps, norm_before_gate, groups) and optionally the gate's
+    activation (`silu`, or `sigmoid`), float32:
     w * x * rsqrt(mean(x^2) + eps) * silu(gate), or with
     norm_before_gate false w * u * rsqrt(mean(u^2) + eps), u = x *
     silu(gate); the mean over each of `groups` parts of the last axis.
@@ -735,8 +877,9 @@ def _gated_rms_norm(ins, attrs, ctx):
         x.shape, groups, x.dtype, gate.dtype)
     obs.counter('gated_rms_norm.way',                        # trace time
                 way='kernel' if kernel else 'composed').inc()
-    y = gated_rms_norm(x, gate, data_of(ins['Scale'][0]),
-                       (float(attrs.get('epsilon', 1e-5)),
-                        bool(attrs.get('norm_before_gate', True)), groups),
-                       kernel)
+    cfg = (float(attrs.get('epsilon', 1e-5)),
+           bool(attrs.get('norm_before_gate', True)), groups)
+    if attrs.get('gate_act', 'silu') != 'silu':     # as the op was, else
+        cfg += (attrs['gate_act'],)
+    y = gated_rms_norm(x, gate, data_of(ins['Scale'][0]), cfg, kernel)
     return {'Y': y.astype(x.dtype)}

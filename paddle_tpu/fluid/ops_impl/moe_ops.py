@@ -85,6 +85,11 @@ one `jax.jit` function a step, shared by the step's layers of equal
 shapes (`lowering.traced_once`). One device, as the whole dropless layer:
 the same refusal on a mesh.
 
+GROUP-LIMITED ROUTING (the sigmoid router's; `n_group`, `topk_group`): the
+choice confined to the best `topk_group` of `n_group` groups of consecutive
+experts (parallel/moe.py router_topk), counted at trace time as
+`moe.router{groups=, kept=}`; the held share and `ExpertCount` are what
+they are without groups.
 ANOTHER ROUTER (dropless only; `scoring`, `SelectionBias`, `gate_scale`):
 `scoring` 'sigmoid' scores every expert by itself, sigmoid(logit), where
 the default takes a softmax over all; an input `SelectionBias` [E]
@@ -628,10 +633,15 @@ def _moe_mlp(ins, attrs, ctx):
                             gate_w.astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
         aux = load_balancing_loss(logits, top_k)
+        n_group = int(attrs.get('n_group', 1))
+        if n_group > 1:                                      # trace time
+            obs.counter('moe.router', groups=n_group,
+                        kept=int(attrs['topk_group'])).inc()
         expert, gate = router_topk(
             logits, top_k, norm, scoring, bias,
             float(attrs.get('gate_scale', 1.0)),
-            attrs.get('norm_eps'))                             # [k, nt]
+            attrs.get('norm_eps'), n_group,
+            int(attrs.get('topk_group', 1)))                   # [k, nt]
         sizes = jnp.bincount(expert.reshape(-1), length=n_exp
                              ).astype(jnp.int32)
     params = dict(zip(params, amp_cast(ctx, *params.values())))

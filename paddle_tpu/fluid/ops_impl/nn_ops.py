@@ -267,7 +267,9 @@ def _rotary_embedding(ins, attrs, ctx):
     frequency turns the angle by 2e-4 rad, which moved OLMoE's attention
     output by 1e-4 between two float32 programs on the chip (PR 26) and
     with it a few tokens' choice of experts. The product is float32; the
-    result has the input's dtype. Trace-time counter `rotary.lowered`,
+    result has the input's dtype. The attribute `interleave` pairs
+    neighbours, (2i, 2i + 1) at the same angle, in place of (i, i + R/2).
+    Trace-time counter `rotary.lowered`,
     labelled `rotary_dim=` where the rotation is partial."""
     x = data_of(ins['X'][0])
     t, d = x.shape[-2], x.shape[-1]
@@ -281,6 +283,13 @@ def _rotary_embedding(ins, attrs, ctx):
     cos = jnp.asarray(np.cos(angle), jnp.float32)  # [T, R/2]
     sin = jnp.asarray(np.sin(angle), jnp.float32)
     xf = x.astype(jnp.float32)
+    if attrs.get('interleave'):
+        # neighbours (2i, 2i + 1) turn together, each where it is
+        x1, x2 = xf[..., 0:rd:2], xf[..., 1:rd:2]
+        turned = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).reshape(xf.shape[:-1] + (rd,))
+        y = jnp.concatenate([turned, xf[..., rd:]], axis=-1)
+        return {'Out': y.astype(x.dtype)}
     x1, x2 = xf[..., :half], xf[..., half:rd]
     y = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
                          xf[..., rd:]], axis=-1)

@@ -527,35 +527,47 @@ def _use_tri(causal, Tq, Tk, bq, bk):
     return causal and Tq == Tk and bq == bk and Tq // bq > 1
 
 
-def _tri_specs(bq, bk, D):
+def _tri_specs(bq, bk, D, Dv):
     """Shared BlockSpecs for the triangular grids: q-row-indexed [bq, D]
-    blocks (q/do/dq), k-col-indexed [bk, D] blocks (k/v/dk/dv), the
-    [1, bk] key-bias block, and the q-row [bq, LANES] stats block
-    (lse/delta). One definition keeps the three pallas_calls in sync."""
-    qrow = pl.BlockSpec((1, 1, bq, D), lambda b, h, t, im, jm: (b, h, im[t], 0))
-    kcol = pl.BlockSpec((1, 1, bk, D), lambda b, h, t, im, jm: (b, h, jm[t], 0))
+    blocks (q/dq) and [bq, Dv] blocks (o/do), k-col-indexed [bk, D] blocks
+    (k/dk) and [bk, Dv] blocks (v/dv), the [1, bk] key-bias block, and the
+    q-row [bq, LANES] stats block (lse/delta). One definition keeps the
+    three pallas_calls in sync. Returns (qrow, kcol, kbias, stats, orow,
+    vcol); the last two ARE the first two where values are as wide as
+    keys."""
+    def row(d):
+        return pl.BlockSpec((1, 1, bq, d),
+                            lambda b, h, t, im, jm: (b, h, im[t], 0))
+
+    def col(d):
+        return pl.BlockSpec((1, 1, bk, d),
+                            lambda b, h, t, im, jm: (b, h, jm[t], 0))
+
+    qrow, kcol = row(D), col(D)
     kbias = pl.BlockSpec((1, 1, bk), lambda b, h, t, im, jm: (b, 0, jm[t]))
     stats = pl.BlockSpec((1, 1, bq, LANES),
                          lambda b, h, t, im, jm: (b, h, im[t], 0))
-    return qrow, kcol, kbias, stats
+    if Dv == D:
+        return qrow, kcol, kbias, stats, qrow, kcol
+    return qrow, kcol, kbias, stats, row(Dv), col(Dv)
 
 
 def _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret, window=None):
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Tk, Dv = k.shape[2], v.shape[3]
     out_shape = [
-        jax.ShapeDtypeStruct(q.shape, q.dtype),
+        jax.ShapeDtypeStruct((B, H, Tq, Dv), q.dtype),
         jax.ShapeDtypeStruct((B, H, Tq, LANES), jnp.float32),
     ]
     scratch_shapes = [
         pltpu.VMEM((bq, LANES), jnp.float32),
         pltpu.VMEM((bq, LANES), jnp.float32),
-        pltpu.VMEM((bq, D), jnp.float32),
+        pltpu.VMEM((bq, Dv), jnp.float32),
     ]
     if _use_tri(causal, Tq, Tk, bq, bk):
         nb = _band(window, bk, Tq // bq)
         im, jm = _tri_maps(Tq // bq, nb)
-        qrow, kcol, kbias, stats = _tri_specs(bq, bk, D)
+        qrow, kcol, kbias, stats, orow, vcol = _tri_specs(bq, bk, D, Dv)
         kern = functools.partial(_fwd_kernel_tri, scale=scale,
                                  block_q=bq, block_k=bk, window=window,
                                  nb=nb)
@@ -564,8 +576,8 @@ def _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret, window=None):
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(B, H, len(im)),
-                in_specs=[qrow, kcol, kcol, kbias],
-                out_specs=[qrow, stats],
+                in_specs=[qrow, kcol, vcol, kbias],
+                out_specs=[orow, stats],
                 scratch_shapes=scratch_shapes,
             ),
             out_shape=out_shape,
@@ -579,11 +591,11 @@ def _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret, window=None):
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bk, Dv), lambda b, h, i, j: (b, h, j, 0)),
             pl.BlockSpec((1, 1, bk), lambda b, h, i, j: (b, 0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, LANES), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=out_shape,
@@ -599,10 +611,11 @@ def _bwd_call_tri(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret,
     k-blocks, then dk/dv re-walk the same pairs k-block-major
     (_tri_maps_kv order)."""
     B, H, Tq, D = q.shape
+    Dv = v.shape[3]
     nq = Tq // bq
     nb = _band(window, bk, nq)
-    qrow, kcol, kbias, stats = _tri_specs(bq, bk, D)
-    bwd_in_specs = [qrow, kcol, kcol, kbias, qrow, stats, stats]
+    qrow, kcol, kbias, stats, orow, vcol = _tri_specs(bq, bk, D, Dv)
+    bwd_in_specs = [qrow, kcol, vcol, kbias, orow, stats, stats]
     im, jm = _tri_maps(nq, nb)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel_tri, scale=scale,
@@ -626,10 +639,10 @@ def _bwd_call_tri(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret,
             num_scalar_prefetch=2,
             grid=(B, H, len(im2)),
             in_specs=bwd_in_specs,
-            out_specs=[kcol, kcol],
+            out_specs=[kcol, vcol],
             scratch_shapes=[
                 pltpu.VMEM((bk, D), jnp.float32),
-                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, Dv), jnp.float32),
             ],
         ),
         out_shape=[
@@ -651,8 +664,9 @@ def _head_vmem_limit(T, D, bq, bk, itemsize):
     lane tile takes a whole one in VMEM: D = 64 is counted as 128 (float32
     operands of D = 64 over 16384 positions, traced under highest
     precision, were refused by 1.75 MiB at the narrow count; compiled for
-    a described v5e, PR 44)."""
-    D = max(D, LANES)
+    a described v5e, PR 44), and keys of 192 two. D is the keys' width:
+    values narrower than the keys are counted as wide as they."""
+    D = _round_up(D, LANES)
     dq = T * D * (4 + 2 * itemsize)
     blocks = 2 * ((2 * bq + 4 * bk) * D * itemsize + 2 * bq * LANES * 4
                   + 8 * bk * 4)
@@ -666,9 +680,10 @@ def _bwd_call_head(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret,
     it needs (_head_vmem_limit): Mosaic's default does not cover a head's
     dq."""
     B, H, Tq, D = q.shape
+    Dv = v.shape[3]
     nq = Tq // bq
     nb = _band(window, bk, nq)
-    qrow, kcol, kbias, stats = _tri_specs(bq, bk, D)
+    qrow, kcol, kbias, stats, orow, vcol = _tri_specs(bq, bk, D, Dv)
     head = pl.BlockSpec((1, 1, nq, bq, D),
                         lambda b, h, t, im, jm: (b, h, 0, 0, 0))
     im, jm = _tri_maps_kv(nq, nb)
@@ -678,12 +693,12 @@ def _bwd_call_head(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, H, len(im)),
-            in_specs=[qrow, kcol, kcol, kbias, qrow, stats, stats],
-            out_specs=[head, kcol, kcol],
+            in_specs=[qrow, kcol, vcol, kbias, orow, stats, stats],
+            out_specs=[head, kcol, vcol],
             scratch_shapes=[
                 pltpu.VMEM((nq, bq, D), jnp.float32),
                 pltpu.VMEM((bk, D), jnp.float32),
-                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, Dv), jnp.float32),
             ],
         ),
         out_shape=[
@@ -704,17 +719,21 @@ def _bwd_call_fused(q, k, v, kb, do, lse, delta, causal, scale, sub_q,
     """One pass over a head's whole score matrix: grid (B, H), one kernel
     for dq, dk and dv (_bwd_fused_kernel)."""
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Tk, Dv = k.shape[2], v.shape[3]
     qrow = pl.BlockSpec((1, 1, Tq, D), lambda b, h: (b, h, 0, 0))
     kcol = pl.BlockSpec((1, 1, Tk, D), lambda b, h: (b, h, 0, 0))
+    orow, vcol = qrow, kcol
+    if Dv != D:
+        orow = pl.BlockSpec((1, 1, Tq, Dv), lambda b, h: (b, h, 0, 0))
+        vcol = pl.BlockSpec((1, 1, Tk, Dv), lambda b, h: (b, h, 0, 0))
     kbias = pl.BlockSpec((1, 1, Tk), lambda b, h: (b, 0, 0))
     stats = pl.BlockSpec((1, 1, Tq, LANES), lambda b, h: (b, h, 0, 0))
     return pl.pallas_call(
         functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
                           sub_q=sub_q, window=window),
         grid=(B, H),
-        in_specs=[qrow, kcol, kcol, kbias, qrow, stats, stats],
-        out_specs=[qrow, kcol, kcol],
+        in_specs=[qrow, kcol, vcol, kbias, orow, stats, stats],
+        out_specs=[qrow, kcol, vcol],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -751,7 +770,7 @@ def _bwd_call_rect(q, k, v, kb, do, lse, delta, causal, scale, bq, bk,
     """Two passes over the rectangular grid: dq accumulates over a q-row's
     k-blocks, dk/dv over a k-column's q-blocks."""
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Tk, Dv = k.shape[2], v.shape[3]
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, window=window),
@@ -759,9 +778,9 @@ def _bwd_call_rect(q, k, v, kb, do, lse, delta, causal, scale, bq, bk,
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bk, Dv), lambda b, h, i, j: (b, h, j, 0)),
             pl.BlockSpec((1, 1, bk), lambda b, h, i, j: (b, 0, j)),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, LANES), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, LANES), lambda b, h, i, j: (b, h, i, 0)),
         ],
@@ -777,15 +796,15 @@ def _bwd_call_rect(q, k, v, kb, do, lse, delta, causal, scale, bq, bk,
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, j, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bk, Dv), lambda b, h, j, i: (b, h, j, 0)),
             pl.BlockSpec((1, 1, bk), lambda b, h, j, i: (b, 0, j)),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, j, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, Dv), lambda b, h, j, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, LANES), lambda b, h, j, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, LANES), lambda b, h, j, i: (b, h, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j, i: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bk, Dv), lambda b, h, j, i: (b, h, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -793,7 +812,7 @@ def _bwd_call_rect(q, k, v, kb, do, lse, delta, causal, scale, bq, bk,
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((bk, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v, kb, do, lse, delta)
@@ -969,7 +988,10 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
         schedule, bwd_pairs = 'head', pairs
     else:
         schedule, bwd_pairs = None, 2 * pairs
-    obs.counter('flash.lowered', operands=operands.name, grid=grid).inc()
+    # values narrower (or wider) than the keys say so; equal widths count
+    # under the labels they always had
+    obs.counter('flash.lowered', operands=operands.name, grid=grid,
+                **({'dv': int(v.shape[3])} if v.shape[3] != D else {})).inc()
     if schedule:
         obs.counter('flash.backward', passes='one', span=schedule).inc()
     else:
@@ -1024,7 +1046,9 @@ def flash_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,
               table; tools/tune_flash.py sweeps them).
     interpret: required. False compiles through Mosaic (TPU only); True
               runs the kernel bodies under the pallas interpreter.
-    Returns [B, H, Tq, D] in q's dtype; differentiable w.r.t. q/k/v.
+    v may be [B, H, Tk, Dv] with Dv another width than q's and k's D (the
+    v, o, do and dv blocks are then [block, Dv], the scores untouched).
+    Returns [B, H, Tq, Dv] in q's dtype; differentiable w.r.t. q/k/v.
     """
     # one custom_vjp serves both wrappers: the unused lse output gets a
     # zero cotangent, making _flash_lse_bwd exactly the classic backward

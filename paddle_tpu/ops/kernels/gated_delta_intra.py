@@ -57,6 +57,7 @@ __all__ = ['gated_delta_intra', 'usable', 'HEADS']
 HEADS = 8
 _CHUNK = 64
 _BLOCK = 16          # linear_attention_ops._SOLVE_BLOCK
+_MIDDLE = (_BLOCK - 1) // 2     # the row of a block its decays refer to
 
 
 def usable(chunk, dk, dv, dtype):
@@ -236,6 +237,151 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gb_ref, t_ref, dw_ref, du_ref, dqg_ref,
         dk_ref[0, kh] = sum(x['dk'] for x in served).astype(dtype)
 
 
+def _channel_scores(qf, kf, g, dtype):
+    """The scores of a head whose decay is a channel's: qf, kf, g [C, Dk]
+    float32, g the running sum G. By row block a of 16, with r_a = G at
+    the block's middle row: the block's rows times exp(G_i - r_a) against
+    every row of its own and the earlier blocks times exp(r_a - G_j) (at
+    most 1 before the block; inside it either exponent within 8 x
+    |gate_floor| of 0: the rule has bounded that, and
+    linear_attention_ops `_intra_channel` says why the middle), keys and
+    queries of a block in ONE product. Returns (K K^T and Q K^T [C, C]
+    with their decays, the rows' factors, what the backward reads again of
+    each block)."""
+    c, dk = g.shape
+    rowi = lax.broadcasted_iota(jnp.int32, (c, dk), 0)
+    starts = [g[lo + _MIDDLE:lo + _MIDDLE + 1] for lo in range(0, c, _BLOCK)]
+    rows = jnp.exp(g - jnp.concatenate(
+        [jnp.broadcast_to(r, (_BLOCK, dk)) for r in starts], axis=0))
+    ke, qe = kf * rows, qf * rows
+    kk, qk, kept = [], [], []
+    for a, start in enumerate(starts):
+        lo = a * _BLOCK
+        seen = rowi < lo + _BLOCK
+        cols = jnp.where(seen, jnp.exp(jnp.where(seen, start - g, 0.0)), 0.0)
+        kc = kf * cols
+        lhs = jnp.concatenate([ke[lo:lo + _BLOCK], qe[lo:lo + _BLOCK]],
+                              axis=0)
+        got = _dot(lhs, kc, 'nt', dtype)
+        kk.append(got[:_BLOCK])
+        qk.append(got[_BLOCK:])
+        kept.append((lhs, kc, cols))
+    return (jnp.concatenate(kk, axis=0), jnp.concatenate(qk, axis=0), rows,
+            kept)
+
+
+def _channel_chunks(q_ref, k_ref, v_ref, g_ref, beta_ref, dtype):
+    """`_chunks` for a decay a channel: g [C, Dk] float32 (G), beta [1, C];
+    a key head a value head."""
+    c = q_ref.shape[2]
+    row, col = _iotas(c)
+    eye = row == col
+    heads = []
+    for h in range(v_ref.shape[1]):
+        qf, kf = (r[0, h].astype(jnp.float32) for r in (q_ref, k_ref))
+        g = g_ref[0, h]
+        kk, qk, rows, kept = _channel_scores(qf, kf, g, dtype)
+        heads.append(dict(
+            qf=qf, kf=kf, vf=v_ref[0, h].astype(jnp.float32), g=g, kk=kk,
+            qk=qk, rows=rows, kept=kept, a0=jnp.where(row > col, kk, 0.0),
+            beta=_column(beta_ref[0, h], eye), e_g=jnp.exp(g),
+            e_last=jnp.exp(g[c - 1:c] - g)))
+    return heads
+
+
+def _fwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, beta_ref, w_ref, u_ref,
+                        qg_ref, kd_ref, p_ref, *rest, dtype):
+    row, col = _iotas(q_ref.shape[2])
+    heads = _channel_chunks(q_ref, k_ref, v_ref, g_ref, beta_ref, dtype)
+    solved = _solve([x['a0'] * x['beta'] for x in heads])
+    for h, (x, t) in enumerate(zip(heads, solved)):
+        kf = x['kf']
+        if rest:                 # the backward's residual
+            rest[0][0, h] = t
+        u_ref[0, h] = _dot(t, x['vf'] * x['beta'], 'nn', dtype)
+        w_ref[0, h] = _dot(t, kf * (x['beta'] * x['e_g']), 'nn',
+                           dtype).astype(dtype)
+        # a token's own pair decays by exp(0): q . k as it is
+        own = jnp.sum(x['qf'] * kf, axis=1, keepdims=True)
+        p_ref[0, h] = (jnp.where(row > col, x['qk'], 0.0)
+                       + jnp.where(row == col, own, 0.0)).astype(dtype)
+        qg_ref[0, h] = (x['qf'] * x['e_g']).astype(dtype)
+        kd_ref[0, h] = (kf * x['e_last']).astype(dtype)
+
+
+def _bwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, dw_ref,
+                        du_ref, dqg_ref, dkd_ref, dp_ref, dq_ref, dk_ref,
+                        dv_ref, dg_ref, dbeta_ref, *, dtype):
+    f32 = jnp.float32
+    c, dk = q_ref.shape[2:]
+    row, col = _iotas(c)
+    eye, strict = row == col, row > col
+    rowi = lax.broadcasted_iota(jnp.int32, (c, dk), 0)
+    heads = _channel_chunks(q_ref, k_ref, v_ref, g_ref, beta_ref, dtype)
+    for h, x in enumerate(heads):
+        x['t'] = t_ref[0, h]
+        x['g_w'], x['g_u'] = dw_ref[0, h], du_ref[0, h]
+        x['scale_k'] = x['beta'] * x['e_g']
+        # U = T (beta V), W = T (beta exp(G) K)
+        x['d_t'] = _dot(x['g_u'], x['vf'] * x['beta'], 'nt', dtype) \
+            + _dot(x['g_w'], x['kf'] * x['scale_k'], 'nt', dtype)
+    # T = (I + A)^-1: dA = -T^T dT T^T on the strict lower triangle
+    for x in heads:
+        x['tt_dt'] = _dot(x['t'], x['d_t'], 'tn', f32)
+    for x in heads:
+        x['d_a'] = jnp.where(strict, -_dot(x['tt_dt'], x['t'], 'nt', f32),
+                             0.0)
+    for h, x in enumerate(heads):
+        beta, t, d_a, scale_k, e_g, e_last, qf, kf, vf, rows = (
+            x[n] for n in ('beta', 't', 'd_a', 'scale_k', 'e_g', 'e_last',
+                           'qf', 'kf', 'vf', 'rows'))
+        g_qg, g_kd, g_p = (r[0, h].astype(f32) for r in (
+            dqg_ref, dkd_ref, dp_ref))
+        d_vb = _dot(t, x['g_u'], 'tn', dtype)
+        d_kb = _dot(t, x['g_w'], 'tn', dtype)
+        dv_ref[0, h] = (d_vb * beta).astype(dtype)
+        d_beta = jnp.sum(d_a * x['a0'], axis=1, keepdims=True) \
+            + jnp.sum(d_vb * vf, axis=1, keepdims=True) \
+            + jnp.sum(d_kb * kf * e_g, axis=1, keepdims=True)
+        dbeta_ref[0, h] = _as_row(d_beta, eye)
+        # the scores' cotangents, keys over queries as the forward stacks
+        # a block's rows
+        d_kk = d_a * beta
+        d_qk = jnp.where(strict, g_p, 0.0)
+        d_own = jnp.sum(jnp.where(eye, g_p, 0.0), axis=1, keepdims=True)
+        to_last = g_kd * kf * e_last
+        dq = g_qg * e_g + d_own * kf
+        dkey = d_kb * scale_k + g_kd * e_last + d_own * qf
+        # G's cotangent: through exp(G), exp(G_C - G), and below through
+        # each block's two factors and its reference r_a
+        d_g = (d_kb * kf * beta + g_qg * qf) * e_g - to_last \
+            + jnp.where(rowi == c - 1,
+                        jnp.sum(to_last, axis=0, keepdims=True), 0.0)
+        d_lhs, d_starts = [], []
+        for a, (lhs, kc, cols) in enumerate(x['kept']):
+            lo = a * _BLOCK
+            d_got = jnp.concatenate([d_kk[lo:lo + _BLOCK],
+                                     d_qk[lo:lo + _BLOCK]], axis=0)
+            d_lhs.append(_dot(d_got, kc, 'nn', dtype))       # [32, Dk]
+            d_kc = _dot(d_got, lhs, 'tn', dtype)             # [C, Dk]
+            dkey = dkey + d_kc * cols
+            d_cols = d_kc * kf * cols
+            d_g = d_g - d_cols
+            d_starts.append(jnp.sum(d_cols, axis=0, keepdims=True))
+        d_ke = jnp.concatenate([d[:_BLOCK] for d in d_lhs], axis=0)
+        d_qe = jnp.concatenate([d[_BLOCK:] for d in d_lhs], axis=0)
+        d_rows = (d_qe * qf + d_ke * kf) * rows
+        d_g = d_g + d_rows
+        for a, d_start in enumerate(d_starts):  # r_a: G's row in the middle
+            lo = a * _BLOCK
+            d_start = d_start - jnp.sum(
+                d_rows[lo:lo + _BLOCK], axis=0, keepdims=True)
+            d_g = d_g + jnp.where(rowi == lo + _MIDDLE, d_start, 0.0)
+        dg_ref[0, h] = d_g
+        dq_ref[0, h] = (dq + d_qe * rows).astype(dtype)
+        dk_ref[0, h] = (dkey + d_ke * rows).astype(dtype)
+
+
 def _heads(hv, rep, dtype):
     """Value heads a grid step: the largest divisor of hv within HEADS
     (half of it at 4-byte operands, whose blocks are twice the bytes)
@@ -319,6 +465,48 @@ def _intra_bwd(interpret, heads, res, g):
 _intra.defvjp(_intra_fwd, _intra_bwd)
 
 
+@functools.partial(jax.jit, static_argnames=('interpret', 'heads', 'solved'))
+def _forward_channel(q, k, v, g, beta, *, interpret, heads, solved):
+    """`_forward` for a decay a channel: g [rows, H, C, Dk], beta
+    [rows, H, 1, C] float32."""
+    dtype = v.dtype
+    like = jax.ShapeDtypeStruct
+    scores = v.shape[:3] + (v.shape[2],)
+    outs = [like(q.shape, dtype), like(v.shape, jnp.float32),
+            like(q.shape, dtype), like(q.shape, dtype), like(scores, dtype)]
+    if solved:
+        outs.append(like(scores, jnp.float32))
+    return tuple(_call(functools.partial(_fwd_kernel_channel, dtype=dtype),
+                       (q, k, v, g, beta), outs, heads, interpret))
+
+
+@functools.partial(jax.jit, static_argnames=('interpret', 'heads'))
+def _backward_channel(res, cts, *, interpret, heads):
+    outs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in res[:5]]
+    return tuple(_call(
+        functools.partial(_bwd_kernel_channel, dtype=res[2].dtype),
+        tuple(res) + tuple(cts), outs, heads, interpret))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _intra_channel(q, k, v, g, beta, interpret, heads):
+    return _forward_channel(q, k, v, g, beta, interpret=interpret,
+                            heads=heads, solved=False)
+
+
+def _intra_channel_fwd(q, k, v, g, beta, interpret, heads):
+    outs = _forward_channel(q, k, v, g, beta, interpret=interpret,
+                            heads=heads, solved=True)
+    return outs[:5], (q, k, v, g, beta, outs[5])
+
+
+def _intra_channel_bwd(interpret, heads, res, cts):
+    return _backward_channel(res, cts, interpret=interpret, heads=heads)
+
+
+_intra_channel.defvjp(_intra_channel_fwd, _intra_channel_bwd)
+
+
 def gated_delta_intra(q, k, v, g_sum, beta, interpret, heads=None):
     """q, k [N, B, Hk, C, Dk] (normalised, q scaled), v [N, B, Hv, C, Dv]
     in the matmuls' dtype, g_sum, beta [N, B, Hv, C] float32, g_sum the
@@ -333,6 +521,14 @@ def gated_delta_intra(q, k, v, g_sum, beta, interpret, heads=None):
     divides Hv)."""
     lead, hv = q.shape[:2], v.shape[2]
     heads = heads or _heads(hv, hv // q.shape[2], v.dtype)
+    if g_sum.ndim == 5:
+        # a decay a channel: g_sum [N, B, Hv, C, Dk], Hk = Hv; the chunk's
+        # decay is then [N, B, Hv, Dk]
+        outs = _intra_channel(
+            _flat(q), _flat(k), _flat(v), _flat(g_sum.astype(jnp.float32)),
+            _flat(beta.astype(jnp.float32)[..., None, :]), interpret, heads)
+        return tuple(o.reshape(lead + o.shape[1:]) for o in outs) \
+            + (jnp.exp(g_sum[..., -1, :]),)
     gb = jnp.stack([g_sum, beta], axis=-2).astype(jnp.float32)
     outs = _intra(_flat(q), _flat(k), _flat(v), _flat(gb), interpret, heads)
     return tuple(o.reshape(lead + o.shape[1:]) for o in outs) \

@@ -102,9 +102,30 @@ def _dot(a, b, dims, dtype):
                            preferred_element_type=_F32)
 
 
-def _fwd_kernel(*refs, dtype, out, save):
+def _eye(n):
+    """The unit diagonal of [1, n, n], each iota made at its shape."""
+    return (lax.broadcasted_iota(jnp.int32, (1, n, n), 1)
+            == lax.broadcasted_iota(jnp.int32, (1, n, n), 2))
+
+
+def _as_rows(x):
+    """[h, 1, n] -> [h, n, 1]: a head's lanes spread over n rows (a decay
+    a channel scales the state's ROWS)."""
+    return jnp.sum(jnp.where(_eye(x.shape[2]), x, 0.0), axis=2,
+                   keepdims=True)
+
+
+def _as_lanes(x):
+    """[h, n, 1] -> [h, 1, n]"""
+    return jnp.sum(jnp.where(_eye(x.shape[1]), x, 0.0), axis=1,
+                   keepdims=True)
+
+
+def _fwd_kernel(*refs, dtype, out, save, channel=False):
     """`out`: O is written (and Qg, P are read); `save`: S at the chunk's
-    start is. refs: the operands read, the outputs written, the scratch."""
+    start is; `channel`: the chunk's decay is a head's [1, Dk] row, one a
+    row of the state. refs: the operands read, the outputs written, the
+    scratch."""
     outs, s_ref = list(refs[6 if out else 4:-1]), refs[-1]
     if out:
         w_ref, u_ref, qg_ref, kd_ref, p_ref, decay_ref = refs[:6]
@@ -126,12 +147,14 @@ def _fwd_kernel(*refs, dtype, out, save):
             + _dot(p_ref[0, 0], vn, 'nn', dtype)
         for h in range(o.shape[0]):      # a head a sublane of a token's tile
             o_ref[0, :, h, :] = o[h]
-    s_ref[...] = s * decay_ref[0, 0] + _dot(kd_ref[0, 0], vn, 'tn', dtype)
+    decay = decay_ref[0, 0]
+    s_ref[...] = s * (_as_rows(decay) if channel else decay) \
+        + _dot(kd_ref[0, 0], vn, 'tn', dtype)
 
 
 def _bwd_kernel(w_ref, u_ref, qg_ref, kd_ref, p_ref, decay_ref, s_ref,
                 do_ref, dw_ref, du_ref, dqg_ref, dkd_ref, dp_ref,
-                ddecay_ref, ds_ref, *, dtype):
+                ddecay_ref, ds_ref, *, dtype, channel=False):
     @pl.when(pl.program_id(2) == 0)
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
@@ -150,8 +173,13 @@ def _bwd_kernel(w_ref, u_ref, qg_ref, kd_ref, p_ref, decay_ref, s_ref,
     dqg_ref[0, 0] = _dot(do, sr, 'nt', dtype).astype(dqg_ref.dtype)
     # Vn = U - W S
     dw_ref[0, 0] = (-_dot(dvn, sr, 'nt', dtype)).astype(dw_ref.dtype)
-    ddecay_ref[0, 0] = jnp.sum(ds1 * s, axis=1, keepdims=True)
-    ds_ref[...] = ds1 * decay_ref[0, 0] + _dot(qg, do, 'tn', dtype) \
+    decay = decay_ref[0, 0]
+    if channel:
+        ddecay_ref[0, 0] = _as_lanes(jnp.sum(ds1 * s, axis=2, keepdims=True))
+        decay = _as_rows(decay)
+    else:
+        ddecay_ref[0, 0] = jnp.sum(ds1 * s, axis=1, keepdims=True)
+    ds_ref[...] = ds1 * decay + _dot(qg, do, 'tn', dtype) \
         - _dot(w, dvn, 'tn', dtype)
 
 
@@ -166,7 +194,10 @@ def _specs(arrays, heads, chunk_of):
 
 
 def _spread(decay, dv):
-    """[N, B, H] -> [N, B, H, 1, Dv]: a head's decay along a row of lanes."""
+    """[N, B, H] -> [N, B, H, 1, Dv]: a head's decay along a row of lanes;
+    [N, B, H, Dk], a decay a channel, is that row already."""
+    if decay.ndim == 4:
+        return decay.astype(_F32)[..., None, :]
     return jnp.broadcast_to(decay.astype(_F32)[..., None, None],
                             decay.shape + (1, dv))
 
@@ -193,7 +224,8 @@ def _forward(w, u, qg, kd, p, decay, *, dtype, heads, out, save, interpret):
         outs.append(like((n, bsz, h, dk, dv), _F32))
         out_specs += _specs(outs[-1:], heads, lambda k: k)
     got = pl.pallas_call(
-        functools.partial(_fwd_kernel, dtype=dtype, out=out, save=save),
+        functools.partial(_fwd_kernel, dtype=dtype, out=out, save=save,
+                          **({'channel': True} if decay.ndim == 4 else {})),
         grid=(bsz, h // heads, n),
         in_specs=_specs(ins, heads, lambda k: k),
         out_specs=out_specs, out_shape=outs,
@@ -227,10 +259,12 @@ def _backward(w, u, qg, kd, p, decay, starts, do, *, dtype, heads,
     dv = u.shape[-1]
     like = jax.ShapeDtypeStruct
     ins = (w, u, qg, kd, p, _spread(decay, dv), starts, do)
+    channel = decay.ndim == 4
     outs = [like(a.shape, a.dtype) for a in (w, u, qg, kd, p)] \
-        + [like((n, bsz, h, 1, dv), _F32)]
+        + [like(ins[5].shape, _F32)]
     dw, du, dqg, dkd, dp, ddecay = pl.pallas_call(
-        functools.partial(_bwd_kernel, dtype=dtype),
+        functools.partial(_bwd_kernel, dtype=dtype,
+                          **({'channel': True} if channel else {})),
         grid=(bsz, h // heads, n),
         in_specs=_specs(ins[:-1], heads, lambda k: n - 1 - k)
         + [_token_spec(c, heads, dv, lambda k: n - 1 - k)],
@@ -239,6 +273,8 @@ def _backward(w, u, qg, kd, p, decay, starts, do, *, dtype, heads,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary')))(*ins)
+    if channel:
+        return dw, du, dqg, dkd, dp, ddecay[..., 0, :].astype(decay.dtype)
     return dw, du, dqg, dkd, dp, \
         jnp.sum(ddecay, axis=(3, 4)).astype(decay.dtype)
 
@@ -265,8 +301,10 @@ _scan.defvjp(_scan_fwd, _scan_bwd)
 def gated_delta_scan(xs, dtype, interpret):
     """`xs` = (W, U, Qg, Kd, P, decay) of every chunk as stage `gdn_intra`
     hands them over: W, Qg, Kd [N, B, H, C, Dk], P [N, B, H, C, C], U
-    [N, B, H, C, Dv] float32, decay [N, B, H] float32; `dtype` the
-    matmuls'. Returns O [B, N x C, H, Dv] float32, the tokens' outputs of
+    [N, B, H, C, Dv] float32, decay [N, B, H] float32, or [N, B, H, Dk]
+    where the state's rows decay each at its own rate (the rank says
+    which); `dtype` the matmuls'. Returns O [B, N x C, H, Dv] float32, the
+    tokens' outputs of
     the scan from S = 0. Differentiable in all six: the backward keeps
     `xs` alone, walks the chunks forward again for S at each chunk's
     start (a temporary, [N, B, H, Dk, Dv] float32) and then in reverse."""

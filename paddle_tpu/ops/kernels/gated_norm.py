@@ -161,14 +161,15 @@ def _inv(v, eps):
 
 
 def _fwd_kernel(x_ref, gate_ref, w_ref, y_ref, *, eps, norm_first, rows,
-                heads):
+                heads, gate_act='silu'):
     w = w_ref[...]
     width = w.shape[1]
+    act = jax.nn.sigmoid if gate_act == 'sigmoid' else jax.nn.silu
 
     def piece(i, _):
         head, at, _ = _piece(i, rows, width, heads)
         x = x_ref[head].astype(_F32)
-        s = jax.nn.silu(gate_ref[at].astype(_F32))
+        s = act(gate_ref[at].astype(_F32))
         if norm_first:
             y = x * _inv(x, eps) * w * s
         else:
@@ -189,7 +190,7 @@ def _fold(x):
 
 
 def _bwd_kernel(x_ref, gate_ref, w_ref, g_ref, dx_ref, dgate_ref, dw_ref,
-                *, eps, norm_first, rows, heads, total):
+                *, eps, norm_first, rows, heads, total, gate_act='silu'):
     w = w_ref[...]
     width = w.shape[1]
     tr = gate_ref.shape[0]
@@ -207,8 +208,11 @@ def _bwd_kernel(x_ref, gate_ref, w_ref, g_ref, dx_ref, dgate_ref, dw_ref,
         z = gate_ref[at].astype(_F32)
         gw = g_ref[at].astype(_F32)
         sig = jax.nn.sigmoid(z)
-        s = z * sig
-        ds = sig * (1.0 + z * (1.0 - sig))                  # silu'(gate)
+        if gate_act == 'sigmoid':
+            s, ds = sig, sig * (1.0 - sig)                  # sigmoid'(gate)
+        else:
+            s = z * sig
+            ds = sig * (1.0 + z * (1.0 - sig))              # silu'(gate)
         u = x if norm_first else x * s
         inv = _inv(u, eps)
         v = u * inv
@@ -260,22 +264,31 @@ def _plan(x, gate, groups, tile):
                  (tr, width), (tr, width))
 
 
+def _act(gate_act):
+    """The kernels' keyword for the gate's activation, where it is not the
+    SiLU they were written with."""
+    if gate_act not in ('silu', 'sigmoid'):
+        raise ValueError('gated norm: gate_act %r' % (gate_act,))
+    return {} if gate_act == 'silu' else {'gate_act': gate_act}
+
+
 # Both calls are jitted functions of their own, as the convolution's: a
 # model has several such ops, each traced for the primal, for its forward
 # rule and in every check Program. jit keeps one trace a shape and emits
 # one function a module, called under each place's scopes.
 @functools.partial(jax.jit, static_argnames=(
-    'eps', 'norm_first', 'groups', 'interpret', 'tile'))
+    'eps', 'norm_first', 'groups', 'interpret', 'tile', 'gate_act'))
 def gated_norm_fwd(x, gate, w, *, eps, norm_first, groups, interpret,
-                   tile=None):
+                   tile=None, gate_act='silu'):
     """x, gate [..., G x width], w [G x width] -> y of x's shape and
     dtype. `tile` overrides the rows of a block (the sweep's and the
-    tests' door)."""
+    tests' door); `gate_act` `sigmoid` gates by sigmoid(gate) where the
+    formulas above say silu."""
     p = _plan(x, gate, groups, tile)
     here = pl.BlockSpec(p.block, lambda i, j: (i, j))
     y = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps, norm_first=norm_first,
-                          rows=p.rows, heads=p.heads),
+                          rows=p.rows, heads=p.heads, **_act(gate_act)),
         grid=p.grid,
         in_specs=[pl.BlockSpec(p.x_block, lambda i, j: (i, j)), here,
                   pl.BlockSpec((1, p.width), lambda i, j: (0, j))],
@@ -290,9 +303,9 @@ def gated_norm_fwd(x, gate, w, *, eps, norm_first, groups, interpret,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    'eps', 'norm_first', 'groups', 'interpret', 'tile'))
+    'eps', 'norm_first', 'groups', 'interpret', 'tile', 'gate_act'))
 def gated_norm_bwd(x, gate, w, g, *, eps, norm_first, groups, interpret,
-                   tile=None):
+                   tile=None, gate_act='silu'):
     """The cotangent g of y -> (dx in x's dtype, dgate in the gate's, dw
     in w's)."""
     p = _plan(x, gate, groups, tile)
@@ -301,7 +314,8 @@ def gated_norm_bwd(x, gate, w, g, *, eps, norm_first, groups, interpret,
     group = pl.BlockSpec((1, p.width), lambda j, i: (0, j))
     dx, dgate, dw = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=eps, norm_first=norm_first,
-                          rows=p.rows, heads=p.heads, total=p.total),
+                          rows=p.rows, heads=p.heads, total=p.total,
+                          **_act(gate_act)),
         grid=p.grid[::-1],
         in_specs=[head, here, group, here],
         out_specs=[head, here, group],

@@ -49,7 +49,8 @@ stack_expert_params = stack_unit_params
 
 
 def router_topk(logits, top_k, norm_topk_prob=True, scoring='softmax',
-                bias=None, gate_scale=1.0, norm_eps=None):
+                bias=None, gate_scale=1.0, norm_eps=None, n_group=1,
+                topk_group=1):
     """Routing decisions shared by every path.
 
     Returns (expert [k, nt] int, gate [k, nt] f32). k=1 keeps the Switch
@@ -64,16 +65,23 @@ def router_topk(logits, top_k, norm_topk_prob=True, scoring='softmax',
     moves the CHOICE and nothing else: the top k are taken of score +
     bias, the gates from the scores without it, and no gradient reaches
     it (its owner moves it by the experts' load, not by the loss).
-    `gate_scale` multiplies the gates last. Both belong to the sigmoid
-    router: under 'softmax' they are refused until a model brings them.
+    `gate_scale` multiplies the gates last. `n_group` G > 1 confines the
+    CHOICE to `topk_group` groups of E / G consecutive experts (DeepSeek-V3's
+    group-limited routing): a group's rank is the sum of its two largest
+    score + bias, the best `topk_group` groups stay (the lower index on a
+    tie, as `lax.top_k` breaks it), an expert of another group cannot be
+    chosen, and the top k are taken among those left; the gates and their
+    renormalisation are what they are without groups. All three belong to
+    the sigmoid router: under 'softmax' they are refused until a model
+    brings them.
     """
     x = logits.astype(jnp.float32)
     if scoring == 'softmax':
-        if bias is not None or gate_scale != 1.0:
+        if bias is not None or gate_scale != 1.0 or n_group > 1:
             raise NotImplementedError(
-                "router_topk: a selection bias or a gate scale under "
+                "router_topk: a selection bias, a gate scale or groups under "
                 "scoring='softmax' (no model here routes so; 'sigmoid' "
-                "takes both)")
+                "takes them)")
         # the logits choose what the probabilities would
         scores, pick, eps = jax.nn.softmax(x, axis=-1), logits, 0.0
     elif scoring == 'sigmoid':
@@ -84,6 +92,21 @@ def router_topk(logits, top_k, norm_topk_prob=True, scoring='softmax',
                          % (scoring,))
     if bias is not None:
         pick = scores + lax.stop_gradient(bias.astype(jnp.float32))
+    if n_group > 1:
+        size = pick.shape[-1] // n_group
+        if size * n_group != pick.shape[-1] or not (
+                1 <= topk_group <= n_group) or top_k > topk_group * size:
+            raise ValueError(
+                'router_topk: %d groups of which %d stay do not hold the top '
+                '%d of %d experts' % (n_group, topk_group, top_k,
+                                      pick.shape[-1]))
+        rank = jnp.sum(lax.top_k(pick.reshape(pick.shape[:-1]
+                                              + (n_group, size)),
+                                 min(2, size))[0], axis=-1)     # [nt, G]
+        _, kept = lax.top_k(rank, topk_group)
+        stays = jnp.sum(jax.nn.one_hot(kept, n_group, dtype=jnp.int32),
+                        axis=-2) > 0
+        pick = jnp.where(jnp.repeat(stays, size, axis=-1), pick, -jnp.inf)
     _, idx = lax.top_k(pick, top_k)                              # [nt, k]
     gate = jnp.take_along_axis(scores, idx, axis=-1)             # [nt, k]
     if top_k > 1 and norm_topk_prob:

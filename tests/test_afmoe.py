@@ -843,10 +843,14 @@ def test_new_readers_read_their_scopes_or_nothing():
             assert catalog.load_reader(name)(other) is None
     entries = {m['name']: m for m in catalog.benchmark_json()['per_layer']}
     for name in ('sandwich_norm_ms', 'shared_expert_ms'):
-        assert entries[name] == {
+        # a later cell that names the scope follows (PR 55: the shared
+        # expert of `ling3flash_s8192`)
+        listed = entries[name]['workloads']
+        assert listed == [CELL, 'ling3flash_s8192'][:len(listed)]
+        assert dict(entries[name], workloads=None) == {
             'name': name, 'unit': 'ms', 'better': 'lower',
             'source': 'device_trace', 'layer': 'Lowering rules',
-            'moves': 'tokens_per_s', 'workloads': [CELL]}
+            'moves': 'tokens_per_s', 'workloads': None}
     for name in ('swa_ms', 'swa_roofline', 'global_attn_ms'):
         assert entries[name]['workloads'] == ['smallthinker_s16384', CELL]
     for name in ('moe_ms', 'grouped_matmul_roofline', 'flash_roofline',

@@ -67,6 +67,15 @@ def _delta_rule(x):
         qk_l2norm=True)
 
 
+def _delta_rule_by_channel(x):
+    """A decay a channel: g [B, T, H, Dk] within its floor of -5."""
+    q = fluid.layers.reshape(x, [-1, 16, 2, 8])
+    beta = fluid.layers.sigmoid(fluid.layers.reduce_mean(q, dim=-1))
+    return fluid.layers.gated_delta_rule(
+        q, q, q, fluid.layers.scale(fluid.layers.sigmoid(q), scale=-5.0),
+        beta, chunk_size=16, qk_l2norm=True, gate_floor=-5.0)
+
+
 def _ssd(x):
     v = fluid.layers.reshape(x, [-1, 16, 2, 8])
     dt = fluid.layers.sigmoid(fluid.layers.reduce_mean(v, dim=-1))
@@ -93,6 +102,9 @@ _MXU_OPS = {
         x, num_experts=4, hidden_size=16, act='swish', gated=True, top_k=2,
         capacity_factor=None, bias_attr=False), 'float32'),
     'gated_delta_rule': ((2, 16 * 2 * 8), _delta_rule, 'float32'),
+    # the same rule on a decay a channel (a case of the op after the colon)
+    'gated_delta_rule:channel': ((2, 16 * 2 * 8), _delta_rule_by_channel,
+                                 'float32'),
     'ssd_scan': ((2, 16 * 2 * 8), _ssd, 'float32'),
 }
 
@@ -117,7 +129,8 @@ def test_mxu_ops_take_bf16_operands_only_under_amp(op_type, monkeypatch):
             x = fluid.layers.data(name='x', shape=list(shape[1:]),
                                   dtype='float32')
             out = layer(x)
-            assert op_type in [op.type for op in main.global_block().ops]
+            assert op_type.split(':')[0] in [
+                op.type for op in main.global_block().ops]
             assert out.dtype == 'float32'
             if amp:
                 fluid.amp.decorate_program(main)

@@ -143,33 +143,72 @@ def test_grouped_matmul_takes_a_width_of_no_power_of_two(one_chip, dtype):
     assert compiled.as_text().count('tpu_custom_call') == 6
 
 
+@pytest.mark.parametrize('dtype,precision,schedule', [
+    ('bfloat16', None, 'head'), ('float32', 'highest', 'head')],
+    ids=['bf16', 'float32_highest'])
+def test_keys_of_192_beside_values_of_128_compile_for_v5e(one_chip, dtype,
+                                                          precision,
+                                                          schedule):
+    """ling3flash_s8192's one attention call (latent attention without a
+    query latent: 32 heads, keys of 128 + 64 rotary, values of 128, one
+    causal row of 8192) in the cell's bf16 and in its float32 check's
+    arithmetic: Mosaic tiles the 192-wide q and k blocks as they are (no
+    padding to 256), v, o, do and dv at 128; one pass over the head's
+    triangle either way (float32 rows of 768 bytes take 256-tiles)."""
+    dt = jnp.dtype(dtype)
+    qk = jax.ShapeDtypeStruct((1, 4, 8192, 192), dt, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 4, 8192, 128), dt, sharding=one_chip)
+
+    def loss(q, k, v):
+        o = ops.flash_attention(q, k, v, causal=True, sm_scale=192 ** -0.5,
+                                interpret=False)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    before = _schedules()
+    with jax.default_matmul_precision(precision or 'default'):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            qk, qk, v).compile()
+    after = _schedules()
+    assert {k: after[k] - before[k] for k in after} == {
+        'tile': 0, 'head': 1, 'two': 0, schedule: 1}
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 2
+    assert 'bf16[1,4,8192,256]' not in text and 'f32[1,4,8192,256]' not in text
+
+
+@pytest.mark.parametrize('gate', ['head', 'channel'])
 @pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
-def test_gated_delta_intra_compiles_for_v5e(one_chip, dtype):
+def test_gated_delta_intra_compiles_for_v5e(one_chip, dtype, gate):
     """qwen3next_s8192's stage `gdn_intra` (128 chunks of 64 tokens, 16
     key heads serving 32 value heads of 128), forward and backward, in the cell's bf16 and in its
     float32 check's arithmetic, at the heads a grid step each takes:
     Mosaic slices no iota and broadcasts no [1, 1] both ways, which the
-    interpreter lets pass (AOT, PR 34)."""
+    interpreter lets pass (AOT, PR 34). `channel`: ling3flash_s8192's,
+    a decay a channel (G [.., 64, 128]) and a key head a value head."""
     from paddle_tpu.ops.kernels.gated_delta_intra import gated_delta_intra
     dt = jnp.dtype(dtype)
-    keys = jax.ShapeDtypeStruct((128, 1, 16, 64, 128), dt,
+    channel = gate == 'channel'
+    keys = jax.ShapeDtypeStruct((128, 1, 32 if channel else 16, 64, 128), dt,
                                 sharding=one_chip)
     x = jax.ShapeDtypeStruct((128, 1, 32, 64, 128), dt, sharding=one_chip)
     gate = jax.ShapeDtypeStruct((128, 1, 32, 64), jnp.float32,
                                 sharding=one_chip)
+    g_sum = jax.ShapeDtypeStruct((128, 1, 32, 64, 128), jnp.float32,
+                                 sharding=one_chip) if channel else gate
 
     def loss(q, k, v, g_sum, beta):
         return sum(jnp.sum(o.astype(jnp.float32)) for o in
                    gated_delta_intra(q, k, v, g_sum, beta, False))
 
     compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
-        keys, keys, x, gate, gate).compile()
+        keys, keys, x, g_sum, gate).compile()
     # the forward (it writes T for the backward) and the backward
     assert compiled.as_text().count('tpu_custom_call') == 2
 
 
+@pytest.mark.parametrize('gate', ['head', 'channel'])
 @pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
-def test_gated_delta_scan_compiles_for_v5e(one_chip, dtype):
+def test_gated_delta_scan_compiles_for_v5e(one_chip, dtype, gate):
     """qwen3next_s8192's stage `gdn_scan` (one row of 128 chunks of 64
     tokens, 32 value heads of 128 x 128) on what the `gdn_intra` kernel
     hands over, in the cell's bf16 and in its float32 check's arithmetic,
@@ -180,7 +219,9 @@ def test_gated_delta_scan_compiles_for_v5e(one_chip, dtype):
     split operands): no call states a `vmem_limit_bytes` (ROADMAP.md Speed
     3 (c)), so a compile that passes here is under it. O is written and
     its cotangent read by head, as [B, T, H, Dv]. S at the starts (256
-    MiB) is the one temporary larger than an operand."""
+    MiB) is the one temporary larger than an operand. `channel`:
+    ling3flash_s8192's walk, the chunk's decay a head's row of 128 lanes
+    that the kernels turn to a column in VMEM, under the same limits."""
     from paddle_tpu.ops.kernels import gated_delta_scan as kernel
     dt = jnp.dtype(dtype)
     assert kernel.usable(64, 128, 128, 32, dt)
@@ -189,7 +230,8 @@ def test_gated_delta_scan_compiles_for_v5e(one_chip, dtype):
     like = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     xs = (like(wide, dt), like(wide, jnp.float32), like(wide, dt),
           like(wide, dt), like((128, 1, 32, 64, 64), dt),
-          like((128, 1, 32), jnp.float32))
+          like((128, 1, 32) + ((128,) if gate == 'channel' else ()),
+               jnp.float32))
 
     def loss(*xs):
         return jnp.sum(kernel.gated_delta_scan(xs, dt, False) ** 2)

@@ -708,6 +708,68 @@ def test_one_pass_equals_two_passes(dtype, schedule, causal):
                    for a, b in zip(one[1:], two[1:]))
 
 
+@pytest.mark.parametrize('schedule', ['tile', 'head', None],
+                         ids=['tile', 'head', 'two'])
+@pytest.mark.parametrize('widths', [(192, 128), (24, 16)],
+                         ids=['192x128', '24x16'])
+def test_values_narrower_than_keys_match_reference(widths, schedule):
+    """Keys and queries of one width beside values of another (latent
+    attention without a query latent: 128 + 64 rotary against 128): the v,
+    o, do and dv blocks take the values' width, the scores are what they
+    were. Forward and every backward schedule against the XLA chain,
+    causal over 512 positions (one tile, or three pairs of 256-tiles)."""
+    fa = _fa()
+    d, dv = widths
+    r = np.random.RandomState(d)
+    q, k = (jnp.asarray(0.3 * r.randn(1, 2, 512, d), jnp.float32)
+            for _ in range(2))
+    v, do = (jnp.asarray(r.randn(1, 2, 512, dv), jnp.float32)
+             for _ in range(2))
+    block = None if schedule == 'tile' else 256
+
+    def lowered():      # counted under the values' width, whatever the grid
+        return sum(fa.obs.counter('flash.lowered', operands='float32',
+                                  grid=g, dv=dv).value
+                   for g in ('rect', 'triangle'))
+
+    before = lowered()
+    with jax.default_matmul_precision('highest'):
+        want, pull = jax.vjp(lambda *a: ops.reference_attention(
+            *a, causal=True, sm_scale=d ** -0.5), q, k, v)
+        got, pull_got = jax.vjp(
+            lambda *a: _flash_as(schedule, *a, causal=True, block_q=block,
+                                 block_k=block)[0], q, k, v)
+        g_want, g_got = pull(do), pull_got(do)
+    assert got.shape == (1, 2, 512, dv)
+    assert lowered() > before
+    np.testing.assert_allclose(got, want, rtol=3e-4, atol=3e-4)
+    for a, b, name in zip(g_got, g_want, ('dq', 'dk', 'dv')):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, rtol=3e-4, atol=3e-4, err_msg=name)
+
+
+def test_fused_attention_takes_values_of_their_own_width():
+    """The op through the Executor: [B, H, T, 24] queries and keys, values
+    of 16, the result [B, H, T, 16], equal to the XLA chain's."""
+    q, k, _, _ = _rand_qkv(D=24, seed=3)
+    _, _, v, _ = _rand_qkv(D=16, seed=4)
+    with fresh_program() as (main, startup):
+        out = layers.fused_attention(
+            *(layers.data(name=n, shape=list(a.shape), dtype='float32',
+                          append_batch_size=False)
+              for n, a in (('q', q), ('k', k), ('v', v))), causal=True,
+            scale=24 ** -0.5)
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        got, = exe.run(main, feed={'q': q, 'k': k, 'v': v},
+                       fetch_list=[out])
+    assert got.shape == v.shape
+    np.testing.assert_allclose(
+        got, ops.reference_attention(q, k, v, causal=True,
+                                     sm_scale=24 ** -0.5), rtol=1e-5,
+        atol=1e-5)
+
+
 # 2 x 2 x T x 64 bf16: a head's dq is 8 x 64 x T bytes of VMEM (float32 and
 # the two bf16 output buffers), 64 MiB at 131072 positions
 @pytest.mark.parametrize('case,kw,schedule', [

@@ -43,8 +43,16 @@ def op_inputs(seed, t, hk, hv, gates, dtype=jnp.float32, b=1):
     q, k = (jnp.asarray(rng.normal(size=(b, t, hk, d)), dtype)
             for _ in range(2))
     v = jnp.asarray(rng.normal(size=(b, t, hv, d)), dtype)
-    lo, hi = (5, 12) if gates == 'strong' else (0, 0.3)
-    g = -jnp.asarray(rng.uniform(lo, hi, size=(b, t, hv)), jnp.float32)
+    if gates.startswith('channel'):
+        # a decay a CHANNEL within its floor of -5; 'channel_floor' has
+        # most of it AT the floor (a saturated gate)
+        g = -jnp.asarray(rng.uniform(0, 5, size=(b, t, hv, d)), jnp.float32)
+        if gates == 'channel_floor':
+            g = jnp.where(jnp.asarray(rng.uniform(size=g.shape)) < 0.7,
+                          -5.0, g)
+    else:
+        lo, hi = (5, 12) if gates == 'strong' else (0, 0.3)
+        g = -jnp.asarray(rng.uniform(lo, hi, size=(b, t, hv)), jnp.float32)
     beta = jnp.asarray(rng.uniform(0, 1, size=(b, t, hv)), jnp.float32)
     return q, k, v, g, beta
 
@@ -68,14 +76,20 @@ def _stage(kernel, dtype):
 ROWS = {'padded_shared_keys': (100, 1, 2), 'whole_chunks': (128, 2, 2)}
 
 
-@pytest.mark.parametrize('gates', ['mild', 'strong'])
+@pytest.mark.parametrize('gates', ['mild', 'strong', 'channel',
+                                   'channel_floor'])
 @pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
 @pytest.mark.parametrize('rows', list(ROWS))
 def test_kernel_is_the_composition(rows, dtype, gates, interpreted):
     """The six outputs and the gradients pulled back to the op's five
-    inputs: float32 to 1e-5, bf16 to 2 ulp of bf16."""
+    inputs: float32 to 1e-5, bf16 to 2 ulp of bf16. With a decay a channel
+    (the per-channel kernel against `_intra_channel`) a key head a value
+    head."""
     dtype = jnp.dtype(dtype)
-    args = op_inputs(len(rows) + len(gates), *ROWS[rows], gates, dtype)
+    t, hk, hv = ROWS[rows]
+    if gates.startswith('channel'):
+        hk = hv
+    args = op_inputs(len(rows) + len(gates), t, hk, hv, gates, dtype)
     names = ('w', 'u', 'qg', 'kd', 'p', 'decay')
     with jax.default_matmul_precision('highest'):
         want, pull_want = jax.vjp(_stage(False, dtype), *args)
@@ -103,18 +117,21 @@ def test_kernel_is_the_composition(rows, dtype, gates, interpreted):
             name, np.linalg.norm(a - b) / scale)
 
 
-@pytest.mark.parametrize('gates', ['mild', 'strong'])
+@pytest.mark.parametrize('gates', ['mild', 'strong', 'channel',
+                                   'channel_floor'])
 def test_the_op_through_the_kernel_is_the_recurrence(gates, interpreted):
     """Values and all five gradients against the token-by-token
     definition, float32; the row ends in a padded chunk and each key head
-    serves two value heads."""
-    args = op_inputs(7, 100, 1, 2, gates)
+    serves two value heads (one, with a decay a channel)."""
+    channel = gates.startswith('channel')
+    args = op_inputs(7, 100, 2 if channel else 1, 2, gates)
     weight = jnp.asarray(np.random.default_rng(1).normal(
         size=args[2].shape), jnp.float32)
     with jax.default_matmul_precision('highest'):
         def through_kernel(*a):
             return la.gated_delta_rule(*a, chunk_size=64, qk_l2norm=True,
-                                       kernel=True)
+                                       kernel=True,
+                                       gate_floor=-5.0 if channel else None)
 
         got, want = through_kernel(*args), plain_delta_net(*args)
         g_got = jax.grad(lambda *a: jnp.sum(through_kernel(*a) * weight),

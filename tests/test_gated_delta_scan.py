@@ -43,13 +43,14 @@ def op_inputs(seed, b, t, hk, hv, gates, dtype=jnp.float32):
     return _op_inputs(seed, t, hk, hv, gates, dtype, b=b)
 
 
-def chunks_of(seed, b, t, hk, hv, dtype):
+def chunks_of(seed, b, t, hk, hv, dtype, gates='mild'):
     """What stage `gdn_intra` hands the scan of such a row (`_intra`, the
     norm, the key heads' repeat, the chunks and their padding included), in
-    the dtypes the kernel of that stage leaves them in."""
+    the dtypes the kernel of that stage leaves them in; with `gates`
+    'channel' the chunk's decay is [N, B, H, Dk]."""
     cfg = (64, 128 ** -0.5, True, 1e-6, False)
     w, u, qg, kd, p, decay = la._stage_intra(
-        *op_inputs(seed, b, t, hk, hv, 'mild', dtype), cfg)
+        *op_inputs(seed, b, t, hk, hv, gates, dtype), cfg)
     return (w.astype(dtype), u, qg.astype(dtype), kd.astype(dtype),
             p.astype(dtype), decay)
 
@@ -62,17 +63,23 @@ ROWS = {'padded_a_head': (1, 164, 1, 1, 1), 'two_rows': (2, 192, 1, 2, 2),
         'two_steps_of_heads': (1, 192, 8, 16, 8)}
 
 
+@pytest.mark.parametrize('gates', ['mild', 'channel'])
 @pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
 @pytest.mark.parametrize('rows', list(ROWS))
-def test_the_kernels_are_the_scan_and_its_transposition(rows, dtype):
+def test_the_kernels_are_the_scan_and_its_transposition(rows, dtype, gates):
     """The forward kernel against a `lax.scan` of `_chunk_step`, the
     reverse kernel (behind the forward-again walk) against `jax.vjp` of
     that scan, at one head, at several and at several steps of heads a
     row: float32 to 1e-5, bf16 to 2 ulp of bf16 (a cotangent is rounded
-    where a product reads it)."""
+    where a product reads it). With a decay a channel the state's rows are
+    scaled each by its own factor and the decay's cotangent is
+    [N, B, H, Dk]."""
     dtype = jnp.dtype(dtype)
-    heads = ROWS[rows][4]
-    xs = chunks_of(len(rows), *ROWS[rows][:4], dtype)
+    b, t, hk, hv, heads = ROWS[rows]
+    if gates == 'channel':
+        hk = hv
+    xs = chunks_of(len(rows), b, t, hk, hv, dtype, gates)
+    assert xs[5].ndim == (4 if gates == 'channel' else 3)
     n, b, h, c, d = xs[1].shape
     assert gds._heads(h) == heads
     do = jnp.asarray(np.random.default_rng(1).normal(
@@ -125,23 +132,26 @@ def test_the_padded_tokens_change_nothing():
     assert float(jnp.abs(g_long[2][:, 100:]).max()) == 0.0
 
 
-@pytest.mark.parametrize('gates', ['mild', 'strong'])
+@pytest.mark.parametrize('gates', ['mild', 'strong', 'channel',
+                                   'channel_floor'])
 @pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
 def test_the_op_through_the_kernels_is_the_composition(dtype, gates,
                                                        interpreted):
     """Values and all five gradients of `gated_delta_rule`, both stages'
     kernels against both compositions, two rows that end in a padded chunk
-    and key heads that serve two value heads; in float32 against the
-    token-by-token definition too."""
+    and key heads that serve two value heads (one, with a decay a
+    channel); in float32 against the token-by-token definition too."""
     dtype = jnp.dtype(dtype)
-    args = op_inputs(7, 2, 100, 1, 2, gates, dtype)
+    channel = gates.startswith('channel')
+    args = op_inputs(7, 2, 100, 2 if channel else 1, 2, gates, dtype)
     weight = jnp.asarray(np.random.default_rng(1).normal(
         size=args[2].shape), jnp.float32)
 
     def through(kernels):
         def op(*a):
             return la.gated_delta_rule(*a, chunk_size=64, qk_l2norm=True,
-                                       kernel=kernels, scan_kernel=kernels)
+                                       kernel=kernels, scan_kernel=kernels,
+                                       gate_floor=-5.0 if channel else None)
         return lambda *a: (op(*a), jax.grad(
             lambda *b: jnp.sum(op(*b) * weight), argnums=range(5))(*a))
 

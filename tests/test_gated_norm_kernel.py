@@ -38,17 +38,18 @@ def _operands(seed, shape, x_dtype, gate_dtype):
     return x, z, jnp.asarray(rng.normal(size=shape[-1]), jnp.float32), g
 
 
-def _composed(x, z, w, g, first, groups):
+def _composed(x, z, w, g, first, groups, act='silu'):
     """(y, (dx, dgate, dw)) as the rule's composed path gives them: the
     float32 result rounded to x's dtype."""
-    y, pull = jax.vjp(lambda *a: la._gated_norm(*a, (EPS, first, groups))
+    y, pull = jax.vjp(lambda *a: la._gated_norm(*a, (EPS, first, groups,
+                                                     act))
                       .astype(x.dtype), x, z, w)
     return y, pull(g)
 
 
-def _kernel(x, z, w, g, first, groups, tile=TILE):
+def _kernel(x, z, w, g, first, groups, tile=TILE, act='silu'):
     kw = dict(eps=EPS, norm_first=first, groups=groups, interpret=True,
-              tile=tile)
+              tile=tile, gate_act=act)
     return (gn.gated_norm_fwd(x, z, w, **kw),
             gn.gated_norm_bwd(x, z, w, g, **kw))
 
@@ -103,6 +104,32 @@ def test_a_head_is_the_last_axis_of_a_4d_input(form, dtypes):
     assert gn.by_head(x.shape, 1, x.dtype) == (x_dtype == 'float32')
     _assert_is(_kernel(x, z, w, g, FORMS[form], 1),
                _composed(x, z, w, g, FORMS[form], 1), x_dtype)
+
+
+@pytest.mark.parametrize('dtypes', list(DTYPES))
+@pytest.mark.parametrize('form', list(FORMS))
+def test_a_sigmoid_gate_is_the_composition_and_the_formula(form, dtypes):
+    """`gate_act='sigmoid'` (Kimi Delta Attention's output gate): the
+    kernels against `_gated_norm` with the same activation, by head and
+    flat, and the composition against the formula written out; a cfg that
+    names no activation is the SiLU it always was."""
+    x_dtype, gate_dtype = DTYPES[dtypes]
+    x, z, w, g = _operands(9, (2, 40, 8, 128), x_dtype, gate_dtype)
+    first = FORMS[form]
+    want = _composed(x, z, w, g, first, 1, 'sigmoid')
+    _assert_is(_kernel(x, z, w, g, first, 1, act='sigmoid'), want, x_dtype)
+    xf, gate = _f32(x), 1.0 / (1.0 + np.exp(-_f32(z)))
+    u = xf if first else xf * gate
+    y = _f32(w) * u / np.sqrt(np.mean(u * u, -1, keepdims=True) + EPS)
+    np.testing.assert_allclose(
+        _f32(want[0]), y * gate if first else y,
+        rtol=1e-5 if x_dtype == 'float32' else 2e-2, atol=1e-5)
+    np.testing.assert_array_equal(
+        la._gated_norm(x, z, w, (EPS, first, 1)),
+        la._gated_norm(x, z, w, (EPS, first, 1, 'silu')))
+    with pytest.raises(ValueError, match='gate_act'):
+        gn.gated_norm_fwd(x, z, w, eps=EPS, norm_first=first, groups=1,
+                          interpret=True, gate_act='tanh')
 
 
 @pytest.mark.parametrize('tile', [16, 32, 4096])

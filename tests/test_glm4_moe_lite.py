@@ -364,11 +364,24 @@ def test_latent_attention_is_the_references_mixer(amp):
         assert rel(g, np.asarray(want_grads[k])) < tol, k
 
 
-def test_latent_attention_takes_one_head_width():
+def test_latent_attention_takes_values_of_their_own_width():
+    """Keys of 12 + 4 beside values of 8 build since PR 55 (the attention
+    kernels take v and the output at their own width): the op's V is
+    [B, H, T, 8], Wo [4 x 8, size]; without a query latent one matrix
+    makes the queries."""
     with framework.program_guard(framework.Program(), framework.Program()):
         x = layers.data(name='x', shape=[8, 32], dtype='float32')
-        with pytest.raises(ValueError, match='one head width'):
-            layers.latent_attention(x, 32, 4, 12, 8, 12, 4, 8)
+        out = layers.latent_attention(x, 32, 4, 12, 8, 12, 4, 8)
+        block = framework.default_main_program().global_block()
+        flash = [op for op in block.ops if op.type == 'flash_attention'][0]
+        assert tuple(block.var(flash.input('V')[0]).shape)[1:] == (4, 8, 8)
+        assert tuple(block.var(flash.input('K')[0]).shape)[1:] == (4, 8, 16)
+        assert tuple(out.shape)[1:] == (8, 32)
+        n = len(framework.default_main_program().all_parameters())
+        layers.latent_attention(x, 32, 4, None, 8, 12, 4, 8,
+                                rope_interleave=True, head_gate=True)
+        assert len(framework.default_main_program().all_parameters()) \
+            == n + 6
 
 
 # ------------------------------------------------------- regions and scopes

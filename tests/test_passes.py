@@ -587,6 +587,13 @@ _SWEEP = {
                     n_head=2, n_kv_head=1, d_head=16, mlp_width=64,
                     layer_types=('mamba', 'attention')),
         feeds_idx=4, stack=True),
+    'bailing_hybrid': dict(
+        kwargs=dict(batch_size=2, seq_len=16, vocab_size=64, hidden=32,
+                    dense_width=64, n_head=2, head_dim=16, kv_rank=8,
+                    d_nope=16, d_rope=8, d_v=16, n_expert=8, top_k=2,
+                    n_group=4, topk_group=2, expert_width=16,
+                    experts_held=(2, 4)),
+        feeds_idx=4, stack=True),
 }
 
 

@@ -32,10 +32,12 @@ sys.path.insert(0, os.path.join(REPO, 'tests', 'test_chipbench'))
 
 def recurrence(q, k, v, g, beta):
     """The definition, token by token: q, k [B, T, H, Dk] (already
-    normalised, scaled and repeated), v [B, T, H, Dv], g, beta [B, T, H]."""
+    normalised, scaled and repeated), v [B, T, H, Dv], g, beta [B, T, H];
+    a g of [B, T, H, Dk] is a decay a CHANNEL (a row of the state)."""
     def token(s, x):
         q_t, k_t, v_t, g_t, b_t = x
-        s = s * jnp.exp(g_t)[..., None, None]
+        # g [B, H]: a decay a head; [B, H, Dk]: a decay a row of the state
+        s = s * jnp.exp(g_t)[(Ellipsis,) + (None,) * (4 - g_t.ndim)]
         write = b_t[..., None] * (v_t - jnp.einsum('bhkv,bhk->bhv', s, k_t))
         s = s + k_t[..., :, None] * write[..., None, :]
         return s, jnp.einsum('bhkv,bhk->bhv', s, q_t)
@@ -69,6 +71,14 @@ def delta_inputs(seed, t, gates):
     elif gates == 'strong':         # a state forgotten within a few tokens
         g = -jnp.asarray(rng.uniform(5, 12, size=(b, t, hv)), jnp.float32)
         beta = jnp.asarray(rng.uniform(0, 1, size=(b, t, hv)), jnp.float32)
+    elif gates.startswith('channel'):
+        # a decay a channel, within its floor of -5: everywhere in (-5, 0),
+        # or ('channel_floor') most of it AT the floor, a saturated gate
+        g = -jnp.asarray(rng.uniform(0, 5, size=(b, t, hv, dk)), jnp.float32)
+        if gates == 'channel_floor':
+            g = jnp.where(jnp.asarray(rng.uniform(size=g.shape)) < 0.7,
+                          -5.0, g)
+        beta = jnp.asarray(rng.uniform(0, 1, size=(b, t, hv)), jnp.float32)
     else:
         g = -jnp.asarray(rng.uniform(0, 0.3, size=(b, t, hv)), jnp.float32)
         beta = jnp.asarray(rng.uniform(0, 1, size=(b, t, hv)), jnp.float32)
@@ -81,16 +91,20 @@ SHAPES = [(64, 64), (128, 32), (40, 16), (50, 32), (37, 8), (72, 24),
           (200, 64)]
 
 
-@pytest.mark.parametrize('gates', ['mild', 'plain', 'strong'])
+@pytest.mark.parametrize('gates', ['mild', 'plain', 'strong', 'channel',
+                                   'channel_floor'])
 @pytest.mark.parametrize('t,chunk', SHAPES)
 def test_chunked_delta_rule_is_the_recurrence(t, chunk, gates):
-    """Forward and the gradient of every input, float32 on the host."""
+    """Forward and the gradient of every input, float32 on the host; with
+    a decay a channel ([B, T, H, Dk]) too, down to its floor."""
     args = delta_inputs(t, t, gates)
     weight = jnp.asarray(np.random.default_rng(1).normal(
         size=args[2].shape), jnp.float32)
+    floor = -5.0 if args[3].ndim == 4 else None
     with jax.default_matmul_precision('highest'):
         def chunked(*a):
-            return la.gated_delta_rule(*a, chunk_size=chunk, qk_l2norm=True)
+            return la.gated_delta_rule(*a, chunk_size=chunk, qk_l2norm=True,
+                                       gate_floor=floor)
 
         got = chunked(*args)
         want = plain_delta_net(*args)
@@ -103,6 +117,55 @@ def test_chunked_delta_rule_is_the_recurrence(t, chunk, gates):
     for name, a, b in zip('q k v g beta'.split(), g_got, g_want):
         err = float(jnp.linalg.norm(a - b))
         assert err < 3e-4 * float(jnp.linalg.norm(b)) + 1e-7, (name, err)
+
+
+def test_a_decay_constant_over_a_heads_channels_is_the_decay_a_head():
+    """g [B, T, H, Dk] with one value a head gives what g [B, T, H] gives,
+    values and gradients (g's summed over the channels)."""
+    q, k, v, g, beta = delta_inputs(3, 100, 'mild')
+    wide = jnp.broadcast_to(g[..., None], g.shape + (q.shape[-1],))
+    weight = jnp.asarray(np.random.default_rng(1).normal(size=v.shape),
+                         jnp.float32)
+
+    def loss(g, floor):
+        return jnp.sum(weight * la.gated_delta_rule(
+            q, k, v, g, beta, chunk_size=64, qk_l2norm=True,
+            gate_floor=floor))
+
+    with jax.default_matmul_precision('highest'):
+        by_head, d_head = jax.value_and_grad(loss)(g, None)
+        by_channel, d_channel = jax.value_and_grad(loss)(wide, -5.0)
+    np.testing.assert_allclose(by_channel, by_head, rtol=1e-5)
+    np.testing.assert_allclose(jnp.sum(d_channel, -1), d_head, rtol=1e-3,
+                               atol=1e-5)
+
+
+def test_a_decay_a_channel_needs_a_floor_it_can_exponentiate():
+    """The rule refuses a per-channel g without `gate_floor`, and one whose
+    half block of 16 rows overflows a float32 (8 x 6 > 44); it holds g to
+    the floor, and a g AT the floor keeps its whole gradient."""
+    q, k, v, g, beta = delta_inputs(3, 32, 'channel')
+    for floor in (None, -6.0, 1.0):
+        with pytest.raises(ValueError, match='gate_floor'):
+            la.gated_delta_rule(q, k, v, g, beta, chunk_size=16,
+                                gate_floor=floor)
+    # a chunk of 8 is one block of 8: 4 x 6 = 24 is taken
+    la.gated_delta_rule(q, k, v, g, beta, chunk_size=8, gate_floor=-6.0)
+    below = la.gated_delta_rule(q, k, v, g - 10.0, beta, chunk_size=16,
+                                gate_floor=-5.0)
+    at = la.gated_delta_rule(q, k, v, jnp.full_like(g, -5.0), beta,
+                             chunk_size=16, gate_floor=-5.0)
+    np.testing.assert_array_equal(below, at)
+    d = jax.grad(lambda g: jnp.sum(la.gated_delta_rule(
+        q, k, v, g, beta, chunk_size=16, gate_floor=-5.0)))(
+            jnp.full_like(g, -5.0))
+    d_in = jax.grad(lambda g: jnp.sum(la.gated_delta_rule(
+        q, k, v, g, beta, chunk_size=16, gate_floor=-5.0)))(
+            jnp.full_like(g, -5.0 + 1e-4))
+    np.testing.assert_allclose(d, d_in, rtol=2e-2, atol=1e-6)
+    with pytest.raises(ValueError, match='gate_floor'):
+        layers.gated_delta_rule(
+            *(_input(n, a) for n, a in zip('qkvgb', (q, k, v, g, beta))))
 
 
 def test_unit_lower_inverse_blockwise_equals_substitution():
@@ -155,7 +218,7 @@ def test_delta_rule_layer_runs_the_op_with_its_scopes_and_counters():
     args = delta_inputs(9, 48, 'mild')
     names = ['q', 'k', 'v', 'g', 'beta']
     w = np.random.default_rng(3).normal(size=args[2].shape).astype('float32')
-    lowered = obs.counter('gdn.lowered', chunk=16).value
+    lowered = obs.counter('gdn.lowered', chunk=16, gate='head').value
     tokens = obs.counter('gdn.tokens').value
 
     def build():
@@ -170,7 +233,7 @@ def test_delta_rule_layer_runs_the_op_with_its_scopes_and_counters():
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
     for a, b in zip(grads, g_want):
         np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-5)
-    assert obs.counter('gdn.lowered', chunk=16).value > lowered
+    assert obs.counter('gdn.lowered', chunk=16, gate='head').value > lowered
     assert obs.counter('gdn.tokens').value - tokens >= 2 * 48
     scoped = [l for l in text.splitlines() if 'gated_delta_rule_' in l]
     assert any('gdn_intra' in l for l in scoped)

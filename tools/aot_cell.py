@@ -145,7 +145,8 @@ def main(argv=None):
         fn = reference.pieces(model)
         ids, labels = (like(pool[0][k], np.int32) for k in built['feeds'])
         x = like(np.empty(ids.shape + (model['hidden_size'],)))
-        table = like(params['tok_emb'])
+        # the head's matrix: the tied table, or an untied head of its own
+        table = like(params.get('head', params['tok_emb']))
         with jax.default_matmul_precision('highest'):
             report('reference.head', fn['head'].lower(
                 x, like(params['norm_final']), table, labels).compile())

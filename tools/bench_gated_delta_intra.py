@@ -2,7 +2,7 @@
 
     python tools/bench_gated_delta_intra.py [--chunks 128] [--heads 32]
         [--key-heads 16] [--d 128] [--iters 30] [--dtype bfloat16]
-        [--sweep]
+        [--gate channel] [--sweep]
 
 Two implementations of the stage at one layer's shape of `qwen3next_s8192`
 (128 chunks of 64 tokens, 16 key heads serving 32 value heads of 128),
@@ -17,7 +17,10 @@ then pulled back):
 
 and the largest difference between the two, over each output's and each
 gradient's largest value. `--sweep` instead times the kernel's two calls
-over the heads a grid step takes. Prints one JSON line a measurement.
+over the heads a grid step takes. `--gate channel` times the form with a
+decay a CHANNEL (`ling3flash_s8192`: g [.., 64, 128] in (-5, 0), a key head
+a value head, `_intra_channel` against the per-channel kernel). Prints one
+JSON line a measurement.
 Exits non-zero off the chip: a time from the CPU is no device number.
 """
 import argparse
@@ -60,6 +63,12 @@ def _inputs(args, dtype):
     k = unit(rng.normal(size=keys))
     v = rng.normal(size=shape + (args.d,))
     g = -rng.uniform(0.0, 0.1, size=shape)
+    if getattr(args, 'gate', 'head') == 'channel':
+        # a decay a channel within its floor of -5: a slow decay in a
+        # quarter of the channels, near the floor in the rest
+        g = -rng.uniform(0.0, 0.1, size=shape + (args.d,))
+        g = np.where(rng.uniform(size=g.shape) < 0.75,
+                     -rng.uniform(4.0, 5.0, size=g.shape), g)
     beta = rng.uniform(0.0, 1.0, size=shape)
     return tuple(jnp.asarray(x, dtype) for x in (q, k, v)) \
         + tuple(jnp.asarray(x, jnp.float32) for x in (g, beta))
@@ -80,8 +89,12 @@ def main(argv=None):
     p.add_argument('--iters', type=int, default=30)
     p.add_argument('--dtype', default='bfloat16',
                    choices=['bfloat16', 'float32'])
+    p.add_argument('--gate', default='head', choices=['head', 'channel'])
     p.add_argument('--sweep', action='store_true')
     args = p.parse_args(argv)
+    channel = args.gate == 'channel'
+    if channel:
+        args.key_heads = args.heads         # a key head a value head
     dev = jax.devices()[0]
     if dev.platform != 'tpu':
         raise SystemExit('bench_gated_delta_intra: no TPU (%r)' % (dev,))
@@ -93,22 +106,24 @@ def main(argv=None):
     rep = args.heads // args.key_heads
 
     def composed(q, k, v, g, beta):
-        w, u, qg, kd, p_, decay = la._intra(
-            jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2), v, g,
-            beta)
+        w, u, qg, kd, p_, decay = (
+            la._intra_channel(q, k, v, g, beta) if channel else la._intra(
+                jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2), v,
+                g, beta))
         return (w.astype(dtype), u, qg.astype(dtype), kd.astype(dtype),
                 p_.astype(dtype), decay)
 
     def kernel(heads):
         return lambda q, k, v, g, beta: gdi.gated_delta_intra(
-            q, k, v, jnp.cumsum(g, axis=-1), beta, False, heads)
+            q, k, v, jnp.cumsum(g, axis=-2 if channel else -1), beta, False,
+            heads)
 
     def backward(fn):
         return lambda cts, *a: jax.vjp(fn, *a)[1](cts)
 
     cts = _cotangents(jax.eval_shape(kernel(None), *operands))
     base = {'chunks': args.chunks, 'heads': args.heads,
-            'key_heads': args.key_heads, 'd': args.d,
+            'key_heads': args.key_heads, 'd': args.d, 'gate': args.gate,
             'dtype': args.dtype, 'device': dev.device_kind}
     ways = [('kernel', n, kernel(n)) for n in SWEEP
             if args.heads % n == 0 and n % rep == 0] if args.sweep else \

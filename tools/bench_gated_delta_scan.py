@@ -2,7 +2,7 @@
 
     python tools/bench_gated_delta_scan.py [--chunks 128] [--heads 32]
         [--key-heads 16] [--d 128] [--iters 20] [--dtype bfloat16]
-        [--sweep]
+        [--gate channel] [--sweep]
 
 The stage's three walks over one layer's chunks at the shape of
 `qwen3next_s8192` (128 chunks of 64 tokens, 32 value heads of 128 x 128),
@@ -23,7 +23,9 @@ each two ways:
 in ms a call and us a chunk, and the largest difference between the two,
 over each output's and each cotangent's largest value. `--sweep` instead
 times the kernels over the value heads a grid step takes (whole sublane
-tiles of the output: multiples of 8). Prints one JSON
+tiles of the output: multiples of 8). `--gate channel` walks with a decay
+a CHANNEL (`ling3flash_s8192`: the chunk's decay [N, B, H, Dk], the state's
+rows each at its own rate). Prints one JSON
 line a measurement. Exits non-zero off the chip: a time from the CPU is no
 device number.
 """
@@ -54,8 +56,11 @@ def main(argv=None):
     p.add_argument('--iters', type=int, default=20)
     p.add_argument('--dtype', default='bfloat16',
                    choices=['bfloat16', 'float32'])
+    p.add_argument('--gate', default='head', choices=['head', 'channel'])
     p.add_argument('--sweep', action='store_true')
     args = p.parse_args(argv)
+    if args.gate == 'channel':
+        args.key_heads = args.heads         # a key head a value head
     dev = jax.devices()[0]
     if dev.platform != 'tpu':
         raise SystemExit('bench_gated_delta_scan: no TPU (%r)' % (dev,))
@@ -65,7 +70,8 @@ def main(argv=None):
     dtype = jnp.dtype(args.dtype)
     q, k, v, g, beta = _inputs(args, dtype)
     xs = jax.jit(lambda *a: gdi.gated_delta_intra(
-        *a[:3], jnp.cumsum(a[3], axis=-1), a[4], False))(q, k, v, g, beta)
+        *a[:3], jnp.cumsum(a[3], axis=-2 if a[3].ndim == 5 else -1), a[4],
+        False))(q, k, v, g, beta)
     # O's cotangent where the op's neighbours hold it: [B, T, H, Dv]
     do = jnp.asarray(np.random.default_rng(1).normal(
         size=(1, 64 * args.chunks, args.heads, args.d)), jnp.float32)
@@ -91,7 +97,8 @@ def main(argv=None):
 
     own = gds._heads(args.heads)
     base = {'chunks': args.chunks, 'heads': args.heads, 'd': args.d,
-            'dtype': args.dtype, 'device': dev.device_kind}
+            'gate': args.gate, 'dtype': args.dtype,
+            'device': dev.device_kind}
     ways = [('kernel', n, walks(n)) for n in SWEEP
             if args.heads % n == 0] if args.sweep else \
         [('composed', None, (lambda *x: la._scan(x, dtype, False), again,
