@@ -58,11 +58,14 @@ gradient where the decay is strong. What is still exponentiated from
 running sums is rounded as they are: at g near -5 G reaches -320 a chunk
 and a factor is off by 3e-5, which is what a float32 comparison reads on
 the decay's own parameters. The per-channel form has its own composition
-(`_intra_channel`), its own `gdn_intra` kernel (a key head a value head;
-a grid step of 8 heads in bf16 asks 5.88 MiB of scoped VMEM forward and
-8.36 backward, 4.13 and 8.70 at 4 heads in float32, where the per-head
-kernel asks 4.62 and 6.76: compiled for a described v5e, PR 55) and the
-same `gdn_scan` kernels, which scale S's rows by the chunk's [Dk] decay (a
+(`_intra_channel`), its own `gdn_intra` kernels (a key head a value head),
+which read the op's OWN operands cut into chunks and take q and k's l2
+norms, q's scale, the rounding to the matmuls' dtype and G's running sum
+in VMEM, forward and backward, so that XLA prepares nothing for them and
+finishes no cotangent behind them (ISSUE 56; a grid step of 8 heads in
+bf16 asks 6.21 MiB of scoped VMEM forward and 9.43 backward, 4.58 and 9.65
+at 4 heads in float32, where the per-head kernel asks 4.62 and 6.76:
+compiled for a described v5e, PR 56) and the same `gdn_scan` kernels, which scale S's rows by the chunk's [Dk] decay (a
 head's row of lanes turned to a column in VMEM) where they multiply by a
 scalar (2.52, 2.74 and 5.74 MiB for the three walks in bf16, 12.99 the
 reverse walk in float32): all within Mosaic's default of 16 MiB, no call
@@ -77,7 +80,8 @@ scans the chunks forward again for S at each chunk's start, walks them
 backwards with the transposed step (jax.vjp of the same step function
 that the forward scans, so the two cannot drift) and pulls the chunks'
 cotangents back through the recomputed stage (the l2 norm of q and k and
-the repeat of the key heads included).
+the repeat of the key heads included; with a decay a channel on the TPU
+the norm's and the running sum's pull-backs are the backward kernel's).
 
 On the TPU, for a chunk of 64 and heads of whole lane tiles (`usable` of
 ops/kernels/gated_delta_intra.py), stage `gdn_intra` is a Pallas kernel
@@ -195,7 +199,9 @@ is tested against. The rule chooses as it does for the delta rule's stage.
 Trace-time counters: `gdn.lowered{chunk=, gate=head|channel}` once per op
 per trace,
 `gdn.intra{way=kernel|composed}` and `gdn.scan{way=kernel|composed}`
-beside it (which way each stage went), `gdn.tokens` the B x T of the
+beside it (which way each stage went), `gdn.prologue{where=kernel|xla}`
+(where q and k's norm and g's running sum are taken: in the per-channel
+kernels, or by XLA on every other path), `gdn.tokens` the B x T of the
 traced shape,
 `ssd.lowered{chunk=, heads=, groups=}` and `ssd.tokens` likewise and
 `ssd.way{way=kernel|composed}` beside them,
@@ -404,10 +410,21 @@ def _stage_intra(q, k, v, g, beta, cfg):
     (float32), q scaled, each key head repeated for its value heads
     (`_intra`; the kernel reads a key head for each of them), all cut
     into chunks (the padding tokens change nothing: k = 0, beta = 0,
-    g = 0), then stage `gdn_intra`: the kernel where `cfg` says so (with G
-    summed here: a scan over 64 that XLA does in passing), else `_intra`."""
+    g = 0), then stage `gdn_intra`: the kernel where `cfg` says so, else
+    `_intra`. The per-head kernel reads what this prepares (q and k
+    normalised and rounded, G summed: passes of XLA's over each array).
+    The per-channel kernels read the op's OWN operands cut into chunks and
+    do the rest in VMEM (the norm, the scale, the rounding to the matmuls'
+    dtype, G's running sum, and in the backward their pull-backs), so that
+    XLA's part of that path is `_to_chunks` and its transpose."""
     chunk, scale, l2norm, eps, kernel = cfg[:5]
     dtype = v.dtype
+    if kernel and g.ndim == 4:      # a decay a channel: the raw operands
+        q, k, v, g, beta = (
+            _to_chunks(x, chunk) for x in (
+                q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32)))
+        return intra_kernel.gated_delta_intra(
+            q, k, v, g, beta, False, norm=(l2norm, eps, scale))
     qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
     if l2norm:
         qf, kf = (x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
@@ -421,9 +438,6 @@ def _stage_intra(q, k, v, g, beta, cfg):
             qf.astype(dtype), kf.astype(dtype), v, g.astype(jnp.float32),
             beta.astype(jnp.float32)))
     if g.ndim == 5:                 # a decay a channel
-        if kernel:
-            return intra_kernel.gated_delta_intra(
-                q, k, v, jnp.cumsum(g, axis=-2), beta, False)
         return _intra_channel(q, k, v, g, beta)
     if kernel:
         return intra_kernel.gated_delta_intra(
@@ -575,6 +589,10 @@ def _gated_delta_rule(ins, attrs, ctx):
             channel and q.shape[2] != v.shape[2])
     obs.counter('gdn.intra',                                 # trace time
                 way='kernel' if kernel else 'composed').inc()
+    # where q and k's norm and g's running sum are taken: in the
+    # per-channel kernels' VMEM, or by XLA ahead of the stage
+    obs.counter('gdn.prologue',                              # trace time
+                where='kernel' if kernel and channel else 'xla').inc()
     # stage `gdn_scan`: the Pallas kernels read what that kernel hands over
     scan = kernel and delta_scan.usable(
         cut, q.shape[3], v.shape[3], v.shape[2], v.dtype)

@@ -38,6 +38,27 @@ read, and that run writes T out (float32, 67 MB a layer at 4096
 chunk-heads, alive from there to the backward kernel of the same layer):
 a third of a step's solves for 0.16 ms of traffic a layer.
 
+What the two kernel pairs read, by the rank of g. With a decay a HEAD
+(`_fwd_kernel`, `_bwd_kernel`) the operands are prepared by XLA: q and k
+normalised, q scaled, both rounded to `dtype`, G summed. With a decay a
+CHANNEL (`_fwd_kernel_channel`, `_bwd_kernel_channel`; G is [C, Dk] and D
+stays inside the products, linear_attention_ops `_intra_channel`) the
+kernels read the OP's own operands cut into chunks (ISSUE 56: on a
+[8192, 32, 128] array each pass XLA makes to prepare an operand is 0.2 to
+0.4 GB, and they were 29 GB a step): q and k as the op holds them, g held
+to its floor and NOT summed, beta. In VMEM, a head at a time: the l2 norm
+in float32 (`_unit`), q's scale, the rounding to `dtype` where
+`_stage_intra` rounds on the other paths, and G as the product of the
+lower triangle of ones with g at float32 precision (`_ones_below`: the
+ones are exact in bf16 and the MXU adds in float32, so each of the 64 sums
+is rounded about once; a second product with the triangle transposed turns
+G's cotangent into g's in the backward). The forward also writes G's last
+row [1, Dk], the log of the chunk's decay, and the backward takes that
+row's cotangent as an operand and hands back the cotangents of the RAW q
+and k, through the scale and the norm in float32 (`_unit_pull`).
+`_unit` and `_unit_pull` take a head's [C, Dk] rows and nothing of the
+per-channel form: the per-head body can take them (ROADMAP.md Speed 4 (f)).
+
 `interpret` as every kernel here: True for the Pallas interpreter, False
 for Mosaic. Not under the PADDLE_TPU_KERNELS knob: like the flash kernels
 and the grouped matmul it is what the op lowers to on the TPU.
@@ -270,29 +291,80 @@ def _channel_scores(qf, kf, g, dtype):
             kept)
 
 
-def _channel_chunks(q_ref, k_ref, v_ref, g_ref, beta_ref, dtype):
-    """`_chunks` for a decay a channel: g [C, Dk] float32 (G), beta [1, C];
-    a key head a value head."""
+def _unit(x, l2norm, eps, scale=1.0):
+    """A chunk-head's rows x [C, Dk] as the op holds them, widened to
+    float32 and, where `l2norm`, divided by their norm over Dk,
+    x * rsqrt(sum x^2 + eps), then scaled (what `_stage_intra` does in XLA
+    on the other paths). Returns (the rows, rsqrt's column [C, 1] or
+    None). A padded row is exactly 0 and stays so: 0 * rsqrt(eps); the
+    floor under the sum is float32's smallest normal number and moves no
+    row that has a norm or an eps."""
+    x = x.astype(jnp.float32)
+    inv = None
+    if l2norm:
+        inv = lax.rsqrt(jnp.maximum(
+            jnp.sum(x * x, axis=1, keepdims=True) + eps,
+            float(jnp.finfo(jnp.float32).tiny)))
+        x = x * inv
+    return (x if scale == 1.0 else x * scale), inv
+
+
+def _unit_pull(d, x, inv, scale=1.0):
+    """The cotangent d [C, Dk] float32 of `_unit(x, ..)`'s rows pulled back
+    to x, from the float32 sum that `_unit` took (inv; None without a
+    norm): with n = x inv, inv (d - n sum(d n)) over Dk."""
+    if scale != 1.0:
+        d = d * scale
+    if inv is None:
+        return d
+    n = x.astype(jnp.float32) * inv
+    return inv * (d - n * jnp.sum(d * n, axis=1, keepdims=True))
+
+
+def _ones_below(c):
+    """The lower triangle of ones [C, C] float32, diagonal included: times
+    g, the running sum of its rows; transposed times a cotangent, the sum
+    from each row to the last."""
+    row, col = _iotas(c)
+    return (row >= col).astype(jnp.float32)
+
+
+def _channel_chunks(q_ref, k_ref, v_ref, g_ref, beta_ref, dtype, norm):
+    """`_chunks` for a decay a channel, from the OP's operands: q, k
+    [C, Dk] not normalised, g [C, Dk] float32 held to its floor and not
+    summed, beta [1, C]; a key head a value head. `norm` (qk_l2norm, eps,
+    q's scale). q and k are normalised in float32, q scaled, both rounded
+    to `dtype` where `_stage_intra` rounds them on the other paths and
+    widened again; G is the running sum of g's rows as a product with the
+    triangle of ones at float32 precision (the MXU takes g in three bf16
+    pieces, the ones are exact and the accumulator is float32: each sum
+    is rounded about once, where a chain of 64 adds rounds 63 times)."""
+    l2norm, eps, scale = norm
     c = q_ref.shape[2]
     row, col = _iotas(c)
     eye = row == col
+    ones = _ones_below(c)
     heads = []
     for h in range(v_ref.shape[1]):
-        qf, kf = (r[0, h].astype(jnp.float32) for r in (q_ref, k_ref))
-        g = g_ref[0, h]
+        qn, q_inv = _unit(q_ref[0, h], l2norm, eps, scale)
+        kn, k_inv = _unit(k_ref[0, h], l2norm, eps)
+        qf, kf = (x.astype(dtype).astype(jnp.float32) for x in (qn, kn))
+        g = _dot(ones, g_ref[0, h], 'nn', jnp.float32)        # G
         kk, qk, rows, kept = _channel_scores(qf, kf, g, dtype)
         heads.append(dict(
             qf=qf, kf=kf, vf=v_ref[0, h].astype(jnp.float32), g=g, kk=kk,
             qk=qk, rows=rows, kept=kept, a0=jnp.where(row > col, kk, 0.0),
             beta=_column(beta_ref[0, h], eye), e_g=jnp.exp(g),
-            e_last=jnp.exp(g[c - 1:c] - g)))
+            e_last=jnp.exp(g[c - 1:c] - g), q_inv=q_inv, k_inv=k_inv))
     return heads
 
 
 def _fwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, beta_ref, w_ref, u_ref,
-                        qg_ref, kd_ref, p_ref, *rest, dtype):
-    row, col = _iotas(q_ref.shape[2])
-    heads = _channel_chunks(q_ref, k_ref, v_ref, g_ref, beta_ref, dtype)
+                        qg_ref, kd_ref, p_ref, last_ref, *rest, dtype, norm):
+    c = q_ref.shape[2]
+    row, col = _iotas(c)
+    heads = _channel_chunks(q_ref, k_ref, v_ref, g_ref, beta_ref, dtype,
+                            norm)
     solved = _solve([x['a0'] * x['beta'] for x in heads])
     for h, (x, t) in enumerate(zip(heads, solved)):
         kf = x['kf']
@@ -307,17 +379,20 @@ def _fwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, beta_ref, w_ref, u_ref,
                        + jnp.where(row == col, own, 0.0)).astype(dtype)
         qg_ref[0, h] = (x['qf'] * x['e_g']).astype(dtype)
         kd_ref[0, h] = (kf * x['e_last']).astype(dtype)
+        last_ref[0, h] = x['g'][c - 1:c]       # G_C: the chunk's decay, log
 
 
 def _bwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, dw_ref,
-                        du_ref, dqg_ref, dkd_ref, dp_ref, dq_ref, dk_ref,
-                        dv_ref, dg_ref, dbeta_ref, *, dtype):
+                        du_ref, dqg_ref, dkd_ref, dp_ref, dlast_ref, dq_ref,
+                        dk_ref, dv_ref, dg_ref, dbeta_ref, *, dtype, norm):
     f32 = jnp.float32
     c, dk = q_ref.shape[2:]
     row, col = _iotas(c)
     eye, strict = row == col, row > col
     rowi = lax.broadcasted_iota(jnp.int32, (c, dk), 0)
-    heads = _channel_chunks(q_ref, k_ref, v_ref, g_ref, beta_ref, dtype)
+    ones = _ones_below(c)
+    heads = _channel_chunks(q_ref, k_ref, v_ref, g_ref, beta_ref, dtype,
+                            norm)
     for h, x in enumerate(heads):
         x['t'] = t_ref[0, h]
         x['g_w'], x['g_u'] = dw_ref[0, h], du_ref[0, h]
@@ -352,11 +427,12 @@ def _bwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, dw_ref,
         to_last = g_kd * kf * e_last
         dq = g_qg * e_g + d_own * kf
         dkey = d_kb * scale_k + g_kd * e_last + d_own * qf
-        # G's cotangent: through exp(G), exp(G_C - G), and below through
+        # G's cotangent: through exp(G), exp(G_C - G) and G_C itself (the
+        # chunk's decay, whose cotangent is an operand), and below through
         # each block's two factors and its reference r_a
         d_g = (d_kb * kf * beta + g_qg * qf) * e_g - to_last \
-            + jnp.where(rowi == c - 1,
-                        jnp.sum(to_last, axis=0, keepdims=True), 0.0)
+            + jnp.where(rowi == c - 1, dlast_ref[0, h]
+                        + jnp.sum(to_last, axis=0, keepdims=True), 0.0)
         d_lhs, d_starts = [], []
         for a, (lhs, kc, cols) in enumerate(x['kept']):
             lo = a * _BLOCK
@@ -377,9 +453,14 @@ def _bwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, dw_ref,
             d_start = d_start - jnp.sum(
                 d_rows[lo:lo + _BLOCK], axis=0, keepdims=True)
             d_g = d_g + jnp.where(rowi == lo + _MIDDLE, d_start, 0.0)
-        dg_ref[0, h] = d_g
-        dq_ref[0, h] = (dq + d_qe * rows).astype(dtype)
-        dk_ref[0, h] = (dkey + d_ke * rows).astype(dtype)
+        # g's: the sum of G's from each row to the chunk's last
+        dg_ref[0, h] = _dot(ones, d_g, 'tn', f32)
+        # q's and k's as the op holds them: through the scale and the norm
+        # in float32 (the rounding to `dtype` passes a cotangent as it is)
+        dq_ref[0, h] = _unit_pull(dq + d_qe * rows, q_ref[0, h], x['q_inv'],
+                                  norm[2]).astype(dq_ref.dtype)
+        dk_ref[0, h] = _unit_pull(dkey + d_ke * rows, k_ref[0, h],
+                                  x['k_inv']).astype(dk_ref.dtype)
 
 
 def _heads(hv, rep, dtype):
@@ -465,49 +546,57 @@ def _intra_bwd(interpret, heads, res, g):
 _intra.defvjp(_intra_fwd, _intra_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=('interpret', 'heads', 'solved'))
-def _forward_channel(q, k, v, g, beta, *, interpret, heads, solved):
-    """`_forward` for a decay a channel: g [rows, H, C, Dk], beta
-    [rows, H, 1, C] float32."""
+@functools.partial(jax.jit,
+                   static_argnames=('interpret', 'heads', 'solved', 'norm'))
+def _forward_channel(q, k, v, g, beta, *, interpret, heads, solved, norm):
+    """`_forward` for a decay a channel, from the op's operands: q, k as
+    the op holds them, g [rows, H, C, Dk] float32 not summed, beta
+    [rows, H, 1, C] float32. Beside W, U, Qg, Kd, P (and T) it writes G's
+    last row [rows, H, 1, Dk] float32, the log of the chunk's decay."""
     dtype = v.dtype
     like = jax.ShapeDtypeStruct
     scores = v.shape[:3] + (v.shape[2],)
     outs = [like(q.shape, dtype), like(v.shape, jnp.float32),
-            like(q.shape, dtype), like(q.shape, dtype), like(scores, dtype)]
+            like(q.shape, dtype), like(q.shape, dtype), like(scores, dtype),
+            like(q.shape[:2] + (1,) + q.shape[3:], jnp.float32)]
     if solved:
         outs.append(like(scores, jnp.float32))
-    return tuple(_call(functools.partial(_fwd_kernel_channel, dtype=dtype),
-                       (q, k, v, g, beta), outs, heads, interpret))
+    return tuple(_call(
+        functools.partial(_fwd_kernel_channel, dtype=dtype, norm=norm),
+        (q, k, v, g, beta), outs, heads, interpret))
 
 
-@functools.partial(jax.jit, static_argnames=('interpret', 'heads'))
-def _backward_channel(res, cts, *, interpret, heads):
+@functools.partial(jax.jit, static_argnames=('interpret', 'heads', 'norm'))
+def _backward_channel(res, cts, *, interpret, heads, norm):
     outs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in res[:5]]
     return tuple(_call(
-        functools.partial(_bwd_kernel_channel, dtype=res[2].dtype),
+        functools.partial(_bwd_kernel_channel, dtype=res[2].dtype,
+                          norm=norm),
         tuple(res) + tuple(cts), outs, heads, interpret))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _intra_channel(q, k, v, g, beta, interpret, heads):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _intra_channel(q, k, v, g, beta, interpret, heads, norm):
     return _forward_channel(q, k, v, g, beta, interpret=interpret,
-                            heads=heads, solved=False)
+                            heads=heads, solved=False, norm=norm)
 
 
-def _intra_channel_fwd(q, k, v, g, beta, interpret, heads):
+def _intra_channel_fwd(q, k, v, g, beta, interpret, heads, norm):
     outs = _forward_channel(q, k, v, g, beta, interpret=interpret,
-                            heads=heads, solved=True)
-    return outs[:5], (q, k, v, g, beta, outs[5])
+                            heads=heads, solved=True, norm=norm)
+    return outs[:6], (q, k, v, g, beta, outs[6])
 
 
-def _intra_channel_bwd(interpret, heads, res, cts):
-    return _backward_channel(res, cts, interpret=interpret, heads=heads)
+def _intra_channel_bwd(interpret, heads, norm, res, cts):
+    return _backward_channel(res, cts, interpret=interpret, heads=heads,
+                             norm=norm)
 
 
 _intra_channel.defvjp(_intra_channel_fwd, _intra_channel_bwd)
 
 
-def gated_delta_intra(q, k, v, g_sum, beta, interpret, heads=None):
+def gated_delta_intra(q, k, v, g_sum, beta, interpret, heads=None,
+                      norm=None):
     """q, k [N, B, Hk, C, Dk] (normalised, q scaled), v [N, B, Hv, C, Dv]
     in the matmuls' dtype, g_sum, beta [N, B, Hv, C] float32, g_sum the
     running sum of g inside each chunk. Hk divides Hv and key head h
@@ -518,17 +607,25 @@ def gated_delta_intra(q, k, v, g_sum, beta, interpret, heads=None):
     repeated key heads, W, Qg, Kd, P in the matmuls' dtype and U in
     float32. Differentiable in all five. `heads` overrides the value
     heads a grid step takes (the sweep's door: a multiple of Hv / Hk that
-    divides Hv)."""
+    divides Hv).
+
+    A decay a CHANNEL (`g_sum` of rank 5, [N, B, Hv, C, Dk], Hk = Hv) takes
+    the OP's operands: `g_sum` is then g itself, held to its floor and NOT
+    summed, q and k are not normalised and `norm` (qk_l2norm, eps, q's
+    scale; None: no norm, a scale of 1) says what the kernels do to them
+    in VMEM before they round them to the matmuls' dtype. The chunk's
+    decay is [N, B, Hv, Dk], and the five gradients are those of the raw
+    q, k, v, g and beta."""
     lead, hv = q.shape[:2], v.shape[2]
     heads = heads or _heads(hv, hv // q.shape[2], v.dtype)
     if g_sum.ndim == 5:
-        # a decay a channel: g_sum [N, B, Hv, C, Dk], Hk = Hv; the chunk's
-        # decay is then [N, B, Hv, Dk]
+        l2norm, eps, scale = norm or (False, 0.0, 1.0)
         outs = _intra_channel(
             _flat(q), _flat(k), _flat(v), _flat(g_sum.astype(jnp.float32)),
-            _flat(beta.astype(jnp.float32)[..., None, :]), interpret, heads)
-        return tuple(o.reshape(lead + o.shape[1:]) for o in outs) \
-            + (jnp.exp(g_sum[..., -1, :]),)
+            _flat(beta.astype(jnp.float32)[..., None, :]), interpret, heads,
+            (bool(l2norm), float(eps), float(scale)))
+        outs = tuple(o.reshape(lead + o.shape[1:]) for o in outs)
+        return outs[:5] + (jnp.exp(outs[5][..., 0, :]),)
     gb = jnp.stack([g_sum, beta], axis=-2).astype(jnp.float32)
     outs = _intra(_flat(q), _flat(k), _flat(v), _flat(gb), interpret, heads)
     return tuple(o.reshape(lead + o.shape[1:]) for o in outs) \

@@ -176,6 +176,18 @@ def test_keys_of_192_beside_values_of_128_compile_for_v5e(one_chip, dtype,
     assert 'bf16[1,4,8192,256]' not in text and 'f32[1,4,8192,256]' not in text
 
 
+def _mosaic_calls(text):
+    return [l for l in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in l]
+
+
+def _scoped_vmem(calls):
+    """What each Mosaic call uses of scoped VMEM, bytes."""
+    return [int(n) for n in re.findall(
+        r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
+        r'"offset":"0","size":"(\d+)"', '\n'.join(calls))]
+
+
 @pytest.mark.parametrize('gate', ['head', 'channel'])
 @pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
 def test_gated_delta_intra_compiles_for_v5e(one_chip, dtype, gate):
@@ -184,7 +196,11 @@ def test_gated_delta_intra_compiles_for_v5e(one_chip, dtype, gate):
     float32 check's arithmetic, at the heads a grid step each takes:
     Mosaic slices no iota and broadcasts no [1, 1] both ways, which the
     interpreter lets pass (AOT, PR 34). `channel`: ling3flash_s8192's,
-    a decay a channel (G [.., 64, 128]) and a key head a value head."""
+    a decay a channel and a key head a value head, from the op's own
+    operands (g [.., 64, 128] not summed, q and k not normalised: the l2
+    norm, q's scale and the running sum in VMEM, forward and backward; the
+    sixth output G's last row and its cotangent the backward's operand),
+    within Mosaic's default 16 MiB with no limit stated."""
     from paddle_tpu.ops.kernels.gated_delta_intra import gated_delta_intra
     dt = jnp.dtype(dtype)
     channel = gate == 'channel'
@@ -195,15 +211,79 @@ def test_gated_delta_intra_compiles_for_v5e(one_chip, dtype, gate):
                                 sharding=one_chip)
     g_sum = jax.ShapeDtypeStruct((128, 1, 32, 64, 128), jnp.float32,
                                  sharding=one_chip) if channel else gate
+    norm = dict(norm=(True, 1e-6, 128 ** -0.5)) if channel else {}
 
     def loss(q, k, v, g_sum, beta):
         return sum(jnp.sum(o.astype(jnp.float32)) for o in
-                   gated_delta_intra(q, k, v, g_sum, beta, False))
+                   gated_delta_intra(q, k, v, g_sum, beta, False, **norm))
 
     compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
         keys, keys, x, g_sum, gate).compile()
     # the forward (it writes T for the backward) and the backward
-    assert compiled.as_text().count('tpu_custom_call') == 2
+    calls = _mosaic_calls(compiled.as_text())
+    assert len(calls) == 2
+    assert all('"scoped_memory_configs":[]' in l for l in calls)
+    used = _scoped_vmem(calls)
+    assert len(used) == 2 and max(used) < 12 * 2 ** 20, used
+
+
+def _delta_rule_vjp_account(one_chip, dtype, channel):
+    """`jax.vjp` of the whole op at a layer's shape (one row of 8192
+    tokens, 32 value heads of 128; both stages as their kernels), compiled
+    under the scope a Program gives it, and what
+    tools/hlo_scope_bytes.py counts of XLA's own instructions there:
+    (their bytes, how many are a `reduce-window`, how many an `rsqrt`).
+    The Mosaic calls' lines stay out: their bodies are serialized there."""
+    import importlib.util
+    from paddle_tpu.fluid.ops_impl import linear_attention_ops as la
+    spec = importlib.util.spec_from_file_location(
+        'hlo_scope_bytes', os.path.join(os.path.dirname(__file__), '..',
+                                        'tools', 'hlo_scope_bytes.py'))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    dt = jnp.dtype(dtype)
+    like = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    keys = like((1, 8192, 32 if channel else 16, 128), dt)
+    v = like((1, 8192, 32, 128), dt)
+    beta = like((1, 8192, 32), jnp.float32)
+    g = like((1, 8192, 32, 128), jnp.float32) if channel else beta
+    out = like((1, 8192, 32, 128), jnp.float32)
+
+    def both(do, *a):
+        with jax.named_scope('gated_delta_rule_0'):
+            o, pull = jax.vjp(lambda *a: la.gated_delta_rule(
+                *a, chunk_size=64, qk_l2norm=True, kernel=True,
+                scan_kernel=True, gate_floor=-5.0 if channel else None), *a)
+            return (o,) + pull(do)
+
+    text = jax.jit(both).lower(out, keys, keys, v, g, beta).compile() \
+        .as_text()
+    # gdn_intra forward, forward again with T and backward; three walks
+    assert len(_mosaic_calls(text)) == 6
+    rows, marks = tool.account(text, r'gated_delta_rule_\d+')
+    return sum(r[3] for r in rows), marks['reduce_window'], marks['rsqrt']
+
+
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+def test_per_channel_delta_rule_leaves_xla_no_sum_and_no_norm(one_chip,
+                                                              dtype):
+    """ling3flash_s8192's op, forward and backward: the per-channel
+    kernels take g's running sum and q and k's l2 norms in VMEM, so XLA's
+    part holds no `reduce-window` (jnp.cumsum on the TPU) and no `rsqrt`,
+    and what it moves is the chunks' copies: 2.71 GB an op in bf16 and
+    3.92 in float32 where the parent's passes over [8192, 32, 128] arrays
+    made it 6.23 and 7.97 (AOT, PR 56; ISSUE 56 counted 29 GB a step of
+    such passes by hand)."""
+    moved, sums, norms = _delta_rule_vjp_account(one_chip, dtype, True)
+    assert sums == 0 and norms == 0
+    assert moved < (3.4e9 if dtype == 'bfloat16' else 4.8e9), moved
+
+
+def test_per_head_delta_rule_still_sums_and_norms_in_xla(one_chip):
+    """qwen3next_s8192's op is the path ISSUE 56 leaves alone: G's sum
+    and the norms of q and k are XLA's, ahead of the per-head kernel."""
+    _, sums, norms = _delta_rule_vjp_account(one_chip, 'bfloat16', False)
+    assert sums > 0 and norms > 0
 
 
 @pytest.mark.parametrize('gate', ['head', 'channel'])
@@ -238,14 +318,11 @@ def test_gated_delta_scan_compiles_for_v5e(one_chip, dtype, gate):
 
     compiled = jax.jit(jax.grad(loss, argnums=range(6))).lower(*xs).compile()
     text = compiled.as_text()
-    calls = [l for l in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in l]
+    calls = _mosaic_calls(text)
     assert len(calls) == 3
     # no limit stated, and what each call uses of VMEM under the default
     assert all('"scoped_memory_configs":[]' in l for l in calls)
-    used = [int(n) for n in re.findall(
-        r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
-        r'"offset":"0","size":"(\d+)"', '\n'.join(calls))]
+    used = _scoped_vmem(calls)
     assert len(used) == 3 and max(used) < (
         6 if dtype == 'bfloat16' else 14) * 2 ** 20, used
     # the starts 256 MiB, O and its cotangent 128 each, the decays' rows

@@ -29,8 +29,8 @@ def interpreted(monkeypatch):
     real, scan = gdi.gated_delta_intra, gds.gated_delta_scan
     monkeypatch.setattr(
         gdi, 'gated_delta_intra',
-        lambda q, k, v, g_sum, beta, interpret, heads=None: real(
-            q, k, v, g_sum, beta, True, heads))
+        lambda q, k, v, g_sum, beta, interpret, heads=None, **kw: real(
+            q, k, v, g_sum, beta, True, heads, **kw))
     monkeypatch.setattr(
         gds, 'gated_delta_scan',
         lambda xs, dtype, interpret: scan(xs, dtype, True))
@@ -45,8 +45,16 @@ def op_inputs(seed, t, hk, hv, gates, dtype=jnp.float32, b=1):
     v = jnp.asarray(rng.normal(size=(b, t, hv, d)), dtype)
     if gates.startswith('channel'):
         # a decay a CHANNEL within its floor of -5; 'channel_floor' has
-        # most of it AT the floor (a saturated gate)
+        # most of it AT the floor (a saturated gate). On a grid of 2^-10,
+        # on which 64 of them sum exactly in float32 in ANY order: the
+        # per-channel kernel sums g itself (a product with a triangle of
+        # ones) where the composition calls jnp.cumsum, and G near -160
+        # rounds to 1.5e-5, so that on free gates a comparison reads the
+        # order of the additions ('channel_free': at that rounding) and
+        # here, at the tolerances of the per-head cases, the arithmetic
         g = -jnp.asarray(rng.uniform(0, 5, size=(b, t, hv, d)), jnp.float32)
+        if gates != 'channel_free':
+            g = jnp.round(g * 1024) / 1024
         if gates == 'channel_floor':
             g = jnp.where(jnp.asarray(rng.uniform(size=g.shape)) < 0.7,
                           -5.0, g)
@@ -57,11 +65,14 @@ def op_inputs(seed, t, hk, hv, gates, dtype=jnp.float32, b=1):
     return q, k, v, g, beta
 
 
-def _stage(kernel, dtype):
+def _stage(kernel, dtype, l2norm=True):
     """Stage `gdn_intra` from the op's inputs (norm, scale, the key heads'
     repeat, the chunks and their padding included), its six outputs in
-    the dtypes the kernel hands the scan."""
-    cfg = (64, 128 ** -0.5, True, 1e-6, kernel)
+    the dtypes the kernel hands the scan. With a decay a channel the
+    kernel reads those inputs cut into chunks and nothing else (the norm,
+    the scale and G's running sum are its own), the composition stands
+    behind `_stage_intra`'s prologue in XLA."""
+    cfg = (64, 128 ** -0.5, l2norm, 1e-6, kernel)
 
     def stage(*args):
         w, u, qg, kd, p, decay = la._stage_intra(*args, cfg)
@@ -76,24 +87,17 @@ def _stage(kernel, dtype):
 ROWS = {'padded_shared_keys': (100, 1, 2), 'whole_chunks': (128, 2, 2)}
 
 
-@pytest.mark.parametrize('gates', ['mild', 'strong', 'channel',
-                                   'channel_floor'])
-@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
-@pytest.mark.parametrize('rows', list(ROWS))
-def test_kernel_is_the_composition(rows, dtype, gates, interpreted):
-    """The six outputs and the gradients pulled back to the op's five
-    inputs: float32 to 1e-5, bf16 to 2 ulp of bf16. With a decay a channel
-    (the per-channel kernel against `_intra_channel`) a key head a value
-    head."""
-    dtype = jnp.dtype(dtype)
-    t, hk, hv = ROWS[rows]
-    if gates.startswith('channel'):
-        hk = hv
-    args = op_inputs(len(rows) + len(gates), t, hk, hv, gates, dtype)
+def _compare_stages(args, dtype, l2norm=True, rounding=0.0):
+    """The kernel's six outputs and five gradients against the
+    composition's on `args`: an element against its own size at float32's
+    1e-5 or 2 ulp of bf16, a gradient against its norm. `rounding`: what
+    G's float32 rounding may differ by between two orders of summation,
+    taken of the row's largest beside each element (zero where both sides
+    hold the same G)."""
     names = ('w', 'u', 'qg', 'kd', 'p', 'decay')
     with jax.default_matmul_precision('highest'):
-        want, pull_want = jax.vjp(_stage(False, dtype), *args)
-        got, pull_got = jax.vjp(_stage(True, dtype), *args)
+        want, pull_want = jax.vjp(_stage(False, dtype, l2norm), *args)
+        got, pull_got = jax.vjp(_stage(True, dtype, l2norm), *args)
         cts = tuple(jnp.asarray(np.random.default_rng(i).normal(
             size=o.shape), o.dtype) for i, o in enumerate(want))
         g_want, g_got = pull_want(cts), pull_got(cts)
@@ -101,20 +105,98 @@ def test_kernel_is_the_composition(rows, dtype, gates, interpreted):
     for name, a, b in zip(names, got, want):
         assert a.dtype == b.dtype and a.shape == b.shape, name
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.all(np.isfinite(a)), name
         # an element against its own size, or where sums cancel against
         # the float32 rounding of the row's largest
-        slack = 1e-6 * np.abs(b).max(-1, keepdims=True) + 1e-30
-        assert np.all(np.abs(a - b) <= tol * np.abs(b) + slack), name
+        slack = (1e-6 + rounding) * np.abs(b).max(-1, keepdims=True) + 1e-30
+        assert np.all(np.abs(a - b) <= (tol + rounding) * np.abs(b)
+                      + slack), name
     for name, a, b in zip(('q', 'k', 'v', 'g', 'beta'), g_got, g_want):
         assert a.dtype == b.dtype and a.shape == b.shape, name
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.all(np.isfinite(a)), name
         # 'strong' gates leave g's gradient to what rounding leaves of
         # decays of e^-5 and less: against the largest gradient there
         scale = np.linalg.norm(b) if name != 'g' else max(
             np.linalg.norm(b), 1e-3 * np.linalg.norm(np.asarray(
                 g_want[4], np.float32)))
-        assert np.linalg.norm(a - b) <= tol * scale, (
+        assert np.linalg.norm(a - b) <= (tol + rounding) * scale, (
             name, np.linalg.norm(a - b) / scale)
+
+
+@pytest.mark.parametrize('gates', ['mild', 'strong', 'channel',
+                                   'channel_floor'])
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize('rows', list(ROWS))
+def test_kernel_is_the_composition(rows, dtype, gates, interpreted):
+    """The six outputs and the gradients pulled back to the op's five
+    inputs: float32 to 1e-5, bf16 to 2 ulp of bf16. With a decay a channel
+    (the per-channel kernel on the op's raw operands against
+    `_intra_channel` behind the XLA prologue) a key head a value head;
+    the padded row's keys are exactly 0 there and nothing is NaN out of
+    `rsqrt(eps)`."""
+    dtype = jnp.dtype(dtype)
+    t, hk, hv = ROWS[rows]
+    if gates.startswith('channel'):
+        hk = hv
+    _compare_stages(op_inputs(len(rows) + len(gates), t, hk, hv, gates,
+                              dtype), dtype)
+
+
+def _scaled(args, rng, low, high):
+    """q and k's rows at norms from `low` to `high` times what they were"""
+    q, k = (x * jnp.asarray(np.exp(rng.uniform(
+        np.log(low), np.log(high), size=x.shape[:3] + (1,))), x.dtype)
+        for x in args[:2])
+    return (q, k) + tuple(args[2:])
+
+
+# what the per-channel kernels do in VMEM to the op's own operands
+# (ISSUE 56): rows of q and k of any norm, none taken, gates off the grid
+@pytest.mark.parametrize('case', ['norms_0.1_to_30', 'no_l2norm',
+                                  'channel_free'])
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+def test_channel_kernel_takes_the_ops_own_operands(case, dtype, interpreted):
+    dtype = jnp.dtype(dtype)
+    rng = np.random.default_rng(len(case))
+    gates = 'channel_free' if case == 'channel_free' else 'channel'
+    args = op_inputs(len(case), 100, 2, 2, gates, dtype)
+    if case == 'norms_0.1_to_30':
+        # rows of q and k from 0.1 to 30 times a unit normal's 11.3: the
+        # norm divides them out forward, and 1 / norm scales the pull-back
+        _compare_stages(_scaled(args, rng, 0.1, 30.0), dtype)
+    elif case == 'no_l2norm':
+        # q and k as they are: of unit size, so that the solve stays tame
+        q, k = ((x.astype(jnp.float32) * 128 ** -0.5).astype(dtype)
+                for x in args[:2])
+        _compare_stages((q, k) + args[2:], dtype, l2norm=False)
+    else:
+        # G near -160 a chunk's end is rounded to 1.5e-5 (float32's step
+        # between 128 and 256) and twice in a difference: four such steps.
+        # Under bf16 a factor that moves by that much takes one operand in
+        # a hundred to the next bf16 number: an ulp of the row's largest
+        _compare_stages(args, dtype, rounding=6e-5 if dtype == jnp.float32
+                        else BF16_ULP)
+
+
+def test_a_gate_at_the_floor_keeps_its_whole_gradient(interpreted):
+    """Every g AT the floor, through the op and the per-channel kernels:
+    the select that holds g passes the whole cotangent (a `maximum` would
+    halve it) and the kernel's reverse in-chunk sum hands back g's, not
+    G's; as tests/test_bailing_hybrid.py holds of the op's composition."""
+    q, k, v, g, beta = op_inputs(13, 100, 2, 2, 'channel')
+    g = jnp.full_like(g, -5.0)
+    weight = jnp.asarray(np.random.default_rng(2).normal(size=v.shape),
+                         jnp.float32)
+    with jax.default_matmul_precision('highest'):
+        got = jax.grad(lambda g: jnp.sum(la.gated_delta_rule(
+            q, k, v, g, beta, chunk_size=64, qk_l2norm=True, kernel=True,
+            gate_floor=-5.0) * weight))(g)
+        want = jax.grad(lambda g: jnp.sum(plain_delta_net(
+            q, k, v, g, beta) * weight))(g)
+    assert float(jnp.linalg.norm(want)) > 0
+    err = float(jnp.linalg.norm(got - want))
+    assert err < 3e-4 * float(jnp.linalg.norm(want)), err
 
 
 @pytest.mark.parametrize('gates', ['mild', 'strong', 'channel',
@@ -208,6 +290,40 @@ def test_the_rule_chooses_on_platform_and_shape(platform, monkeypatch,
     after = _ways()
     assert after['kernel'] == before['kernel']
     assert after['composed'] > before['composed']
+
+
+@pytest.mark.parametrize('platform,gates,where', [
+    ('tpu', 'channel', 'kernel'), ('tpu', 'mild', 'xla'),
+    ('cpu', 'channel', 'xla')])
+def test_the_prologue_is_counted_where_it_is_taken(platform, gates, where,
+                                                   monkeypatch, interpreted):
+    """`gdn.prologue{where=}` once an op a trace beside `gdn.intra{way=}`:
+    `kernel` where the per-channel kernels take the raw operands (rank-4 g
+    on the TPU), `xla` with a decay a head and on every other platform."""
+    init = lowering.Ctx.__init__
+    monkeypatch.setattr(
+        lowering.Ctx, '__init__',
+        lambda self, *a, **kw: init(self, *a, **dict(kw, platform=platform)))
+    channel = gates == 'channel'
+    args = op_inputs(17, 100, 2 if channel else 1, 2, gates)
+    names = ['q', 'k', 'v', 'g', 'beta']
+    w = np.random.default_rng(3).normal(size=args[2].shape).astype('float32')
+
+    def count():
+        return {x: obs.counter('gdn.prologue', where=x).value
+                for x in ('kernel', 'xla')}, sum(_ways().values())
+
+    before, ops_before = count()
+    got, _, _ = _grads_of(lambda: layers.gated_delta_rule(
+        *(_input(n, a) for n, a in zip(names, args)), chunk_size=64,
+        qk_l2norm=True, gate_floor=-5.0 if channel else None),
+        {'w': w}, names)
+    after, ops_after = count()
+    other = 'xla' if where == 'kernel' else 'kernel'
+    assert after[where] - before[where] == ops_after - ops_before >= 1
+    assert after[other] == before[other]
+    np.testing.assert_allclose(got, plain_delta_net(*args), rtol=1e-4,
+                               atol=1e-5)
 
 
 def test_every_heads_a_grid_step_gives_the_same_chunks():

@@ -32,8 +32,8 @@ def interpreted(monkeypatch):
     intra, scan = gdi.gated_delta_intra, gds.gated_delta_scan
     monkeypatch.setattr(
         gdi, 'gated_delta_intra',
-        lambda q, k, v, g_sum, beta, interpret, heads=None: intra(
-            q, k, v, g_sum, beta, True, heads))
+        lambda q, k, v, g_sum, beta, interpret, heads=None, **kw: intra(
+            q, k, v, g_sum, beta, True, heads, **kw))
     monkeypatch.setattr(
         gds, 'gated_delta_scan',
         lambda xs, dtype, interpret: scan(xs, dtype, True))

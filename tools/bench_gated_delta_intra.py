@@ -19,8 +19,12 @@ and the largest difference between the two, over each output's and each
 gradient's largest value. `--sweep` instead times the kernel's two calls
 over the heads a grid step takes. `--gate channel` times the form with a
 decay a CHANNEL (`ling3flash_s8192`: g [.., 64, 128] in (-5, 0), a key head
-a value head, `_intra_channel` against the per-channel kernel). Prints one
-JSON line a measurement.
+a value head) from the OP's operands on both sides, so that "alone" holds
+what ISSUE 56 moved: q and k not normalised and g not summed, cut into
+chunks; `composed` is `_stage_intra`'s prologue in XLA (two l2 norms, q's
+scale, the rounding to the matmuls' dtype) and `_intra_channel` with its
+`cumsum`, `kernel` the per-channel kernels, which do all of it in VMEM.
+Prints one JSON line a measurement.
 Exits non-zero off the chip: a time from the CPU is no device number.
 """
 import argparse
@@ -51,7 +55,8 @@ def _time(fn, args, iters):
 def _inputs(args, dtype):
     """A layer's chunked operands as the op hands them over: k of unit
     length, q scaled, gates as a trained layer's (a decay of a few
-    percent a token), G summed inside each chunk."""
+    percent a token). With a decay a channel: q and k as a projection
+    leaves them (no norm taken, no scale), g within its floor."""
     rng = np.random.default_rng(0)
     shape = (args.chunks, 1, args.heads, 64)
     keys = (args.chunks, 1, args.key_heads, 64, args.d)
@@ -64,6 +69,7 @@ def _inputs(args, dtype):
     v = rng.normal(size=shape + (args.d,))
     g = -rng.uniform(0.0, 0.1, size=shape)
     if getattr(args, 'gate', 'head') == 'channel':
+        q, k = (rng.normal(size=keys) for _ in range(2))
         # a decay a channel within its floor of -5: a slow decay in a
         # quarter of the channels, near the floor in the rest
         g = -rng.uniform(0.0, 0.1, size=shape + (args.d,))
@@ -105,18 +111,29 @@ def main(argv=None):
 
     rep = args.heads // args.key_heads
 
+    norm = (True, 1e-6, args.d ** -0.5)
+
     def composed(q, k, v, g, beta):
-        w, u, qg, kd, p_, decay = (
-            la._intra_channel(q, k, v, g, beta) if channel else la._intra(
+        if channel:         # `_stage_intra`'s prologue, on chunks
+            qf, kf = (x.astype(jnp.float32) for x in (q, k))
+            qf, kf = (x * jax.lax.rsqrt(
+                jnp.sum(x * x, -1, keepdims=True) + norm[1])
+                for x in (qf, kf))
+            w, u, qg, kd, p_, decay = la._intra_channel(
+                (qf * norm[2]).astype(dtype), kf.astype(dtype), v, g, beta)
+        else:
+            w, u, qg, kd, p_, decay = la._intra(
                 jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2), v,
-                g, beta))
+                g, beta)
         return (w.astype(dtype), u, qg.astype(dtype), kd.astype(dtype),
                 p_.astype(dtype), decay)
 
     def kernel(heads):
+        if channel:
+            return lambda q, k, v, g, beta: gdi.gated_delta_intra(
+                q, k, v, g, beta, False, heads, norm=norm)
         return lambda q, k, v, g, beta: gdi.gated_delta_intra(
-            q, k, v, jnp.cumsum(g, axis=-2 if channel else -1), beta, False,
-            heads)
+            q, k, v, jnp.cumsum(g, axis=-1), beta, False, heads)
 
     def backward(fn):
         return lambda cts, *a: jax.vjp(fn, *a)[1](cts)
@@ -150,9 +167,12 @@ def main(argv=None):
         names = ('w', 'u', 'qg', 'kd', 'p', 'decay', 'dq', 'dk', 'dv', 'dg',
                  'dbeta')
         print(json.dumps(dict(base, largest_difference={
+            # (a chunk's decay by channel is exp(-200) = 0 on both sides
+            # at these gates: over the smallest normal number, not 0 / 0)
             n: float(jnp.max(jnp.abs(a.astype(jnp.float32)
                                      - b.astype(jnp.float32)))
-                     / jnp.max(jnp.abs(b.astype(jnp.float32))))
+                     / jnp.maximum(jnp.max(jnp.abs(b.astype(jnp.float32))),
+                                   jnp.finfo(jnp.float32).tiny))
             for n, a, b in zip(names, results['kernel'],
                                results['composed'])})), flush=True)
     return 0

@@ -69,9 +69,15 @@ def main(argv=None):
     from paddle_tpu.ops.kernels import gated_delta_scan as gds
     dtype = jnp.dtype(args.dtype)
     q, k, v, g, beta = _inputs(args, dtype)
-    xs = jax.jit(lambda *a: gdi.gated_delta_intra(
-        *a[:3], jnp.cumsum(a[3], axis=-2 if a[3].ndim == 5 else -1), a[4],
-        False))(q, k, v, g, beta)
+    # what stage `gdn_intra` hands over; with a decay a channel its
+    # kernel takes the op's own operands (no norm taken, g not summed)
+    xs = jax.jit(
+        (lambda *a: gdi.gated_delta_intra(
+            *a, False, norm=(True, 1e-6, args.d ** -0.5)))
+        if args.gate == 'channel' else
+        (lambda *a: gdi.gated_delta_intra(
+            *a[:3], jnp.cumsum(a[3], axis=-1), a[4], False)))(
+        q, k, v, g, beta)
     # O's cotangent where the op's neighbours hold it: [B, T, H, Dv]
     do = jnp.asarray(np.random.default_rng(1).normal(
         size=(1, 64 * args.chunks, args.heads, args.d)), jnp.float32)
