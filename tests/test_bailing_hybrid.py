@@ -9,9 +9,9 @@ reference's walk with its weights on the host against `jax.grad` of the
 same function in one piece; name scopes, regions, counters, the
 configuration's file against the catalog's row, its FLOPs and its reader.
 Small sizes, on the CPU."""
+import functools
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -24,44 +24,17 @@ from paddle_tpu import obs
 from paddle_tpu.fluid import framework, layers, unique_name
 from paddle_tpu.parallel.moe import router_topk
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
-sys.path.insert(0, os.path.join(REPO, 'tests', 'test_chipbench'))
+import decoder_toy
+from decoder_toy import REPO, build_toy, check_all
 
 CELL = 'ling3flash_s8192'
 KINDS = ['kda_dense', 'kda_experts', 'kda_experts', 'kda_experts',
          'mla_experts', 'kda_experts', 'kda_experts']
 
 
-def reference_module():
-    from chipbench.harness import catalog
-    return catalog.load_module(catalog.ROOT, 'references', 'bailing_hybrid')
-
-
-def _toy_cell(**model):
-    """The toy cell; `model` overrides keys of its model."""
-    import chipbench_toy as toy
-    cell = toy.load_toy_cell(CELL)
-    if model:
-        cell = dict(cell, config=dict(
-            cell['config'], model=dict(cell['config']['model'], **model)))
-    return cell
-
-
-def _check_all(cell, tolerance, seed=5, amp=None):
-    """harness/check.py's comparison of the toy cell's Program with the
-    plain reference on EVERY trainable parameter."""
-    from chipbench.harness import check
-    with fluid.scope_guard(fluid.Scope()):
-        exe = fluid.Executor(fluid.CPUPlace())
-        built = cell['builder'].build(cell['config'], cell['traffic'])
-        exe.run(built['startup'])
-        names = [v.name for v in built['main'].list_vars()
-                 if isinstance(v, framework.Parameter) and v.trainable]
-        entry = dict(cell['config']['checks'][amp or 'float32'],
-                     grads=names, tolerance=tolerance)
-        return names, check.run_check(cell, exe, fluid.global_scope(), seed,
-                                      entry)
+reference_module = functools.partial(decoder_toy.reference_module,
+                                     'bailing_hybrid')
+_toy_cell = functools.partial(decoder_toy.toy_cell, CELL)
 
 
 # trainable parameters a layer: the mixer's norm and parameters, the post
@@ -84,7 +57,7 @@ def test_toy_model_agrees_with_the_plain_reference_on_every_gradient():
     cell = _toy_cell()
     reference = reference_module()
     assert reference.kinds_of(cell['config']['model']) == KINDS
-    names, got = _check_all(cell, {'loss': 1e-5, 'grad': 1e-4})
+    names, got = check_all(cell, {'loss': 1e-5, 'grad': 1e-4})
     assert len(names) == 2 + 1 + sum(
         _PER_KIND[part] for kind in KINDS for part in kind.split('_'))
     assert set(got['grad_rel']) == set(names)
@@ -94,7 +67,7 @@ def test_toy_model_agrees_with_the_plain_reference_on_every_gradient():
     loose = {n: r for n, r in got['grad_rel'].items()
              if r > 1e-5 and n not in decays}
     assert not loose, loose
-    _, amp = _check_all(cell, {'loss': 1e-3, 'grad': 0.25}, amp='amp')
+    _, amp = check_all(cell, {'loss': 1e-3, 'grad': 0.25}, amp='amp')
     assert amp['passed'], amp
 
 
@@ -155,7 +128,7 @@ def test_a_moved_rule_fails_the_comparison(rule):
     reference = reference_module()       # a fresh copy, ONE function moved
     _MOVED[rule](reference)
     cell = dict(_toy_cell(), reference=reference)
-    _, got = _check_all(cell, {'loss': 1e-5, 'grad': 1e-4})
+    _, got = check_all(cell, {'loss': 1e-5, 'grad': 1e-4})
     assert not got['passed']
     assert max(got['grad_rel'].values()) > 1e-2, rule
 
@@ -179,10 +152,11 @@ def _run_share(held, xs, weights):
     first, n = held or (0, E)
     with fluid.scope_guard(fluid.Scope()):
         exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
+        # every parameter is set below: no start-up program (a compile a
+        # share) is run
         scope, place = fluid.global_scope(), fluid.CPUPlace()
         for i, w in enumerate(weights):
-            scope.find_var('moe_mlp_0.w_%d' % i).get_tensor().set(
+            scope.var('moe_mlp_0.w_%d' % i).get_tensor().set(
                 w[first:first + n] if i in (1, 2, 3) else w, place)
         return exe.run(main, feed={'x': xs}, fetch_list=[out, count])
 
@@ -374,11 +348,6 @@ def test_the_walk_with_host_weights_is_the_gradient_of_the_whole():
                 <= 1e-5 * np.linalg.norm(np.asarray(b)) + 1e-9, k
 
 
-def _build_toy(cell, train):
-    config = dict(cell['config'], check={'grads': []}, amp='none')
-    return cell['builder'].build(config, cell['traffic'], train=train)
-
-
 def test_layers_are_mixer_and_feed_forward_scopes_regions_and_counters():
     """Seven recompute regions, one a layer; the mixers' ops under
     `kda_mixer` or `latent_attention`, the shared experts' under
@@ -389,7 +358,7 @@ def test_layers_are_mixer_and_feed_forward_scopes_regions_and_counters():
         ('kda_dense', dict(mixer='kda', ffn='dense')),
         ('kda_experts', dict(mixer='kda', ffn='experts')),
         ('mla_experts', dict(mixer='mla', ffn='experts')))}
-    built = _build_toy(cell, train=True)
+    _, built = build_toy(cell, train=True)
     after = {k: obs.counter('bailing.layers', mixer=k.split('_')[0],
                             ffn=k.split('_')[1]).value for k in before}
     assert {k: after[k] - before[k] for k in before} == {
@@ -582,8 +551,13 @@ def test_configuration_file_holds_the_published_sizes():
     spec_cell = [w for w in spec['workloads'] if w['name'] == CELL][0]
     assert (spec_cell['config'], spec_cell['traffic'], spec_cell['chips']) \
         == ('ling_3_0_flash', 'zipf_lm_b1_s8192', 1)
-    assert [m_ for m_ in spec['per_layer'] if m_['name'] == 'kda_ms'][0][
-        'workloads'] == [CELL]
+    # the cell is IN the reader's list, whatever a later cell adds to it
+    kda_ms, = [m_ for m_ in spec['per_layer'] if m_['name'] == 'kda_ms']
+    assert CELL in kda_ms['workloads']
+    assert dict(kda_ms, workloads=None) == {
+        'name': 'kda_ms', 'unit': 'ms', 'better': 'lower',
+        'source': 'device_trace', 'layer': 'Lowering rules',
+        'moves': 'tokens_per_s', 'workloads': None}
 
 
 def test_the_checks_names_are_the_parameters_the_issue_asks_for():

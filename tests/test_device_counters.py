@@ -16,9 +16,7 @@ from paddle_tpu.fluid import executor as executor_mod
 from paddle_tpu.fluid import framework, layers, unique_name
 from paddle_tpu.fluid.ops_impl import moe_ops
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(REPO, 'tests', 'test_chipbench'))
-import chipbench_toy as toy  # noqa: E402
+from decoder_toy import REPO, toy_cell
 
 HELD_CELLS = ['qwen3next_s8192', 'glm47flash_s8192']
 
@@ -45,7 +43,7 @@ def _registry(label):
 def _toy(name, seed=3):
     """(cell, built, pool, the moe ops) of a toy held cell, built in the
     current scope; the start-up Program has run."""
-    cell = toy.load_toy_cell(name)
+    cell = toy_cell(name)
     built = cell['builder'].build(cell['config'], cell['traffic'], train=True)
     pool, _ = cell['generator'].make_pool(cell['traffic'], cell['config'],
                                           seed)
@@ -392,7 +390,7 @@ def test_toy_cell_without_a_share_lowers_as_it_did():
     step's call returns None in the counters' place."""
     with fluid.scope_guard(fluid.Scope()):
         exe = fluid.Executor(fluid.CPUPlace())
-        cell = toy.load_toy_cell('olmoe_s4096')
+        cell = toy_cell('olmoe_s4096')
         built = cell['builder'].build(cell['config'], cell['traffic'],
                                       train=True)
         exe.run(built['startup'])

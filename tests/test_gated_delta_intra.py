@@ -16,7 +16,8 @@ from paddle_tpu.fluid.ops_impl import linear_attention_ops as la
 from paddle_tpu.ops.kernels import gated_delta_intra as gdi
 from paddle_tpu.ops.kernels import gated_delta_scan as gds
 
-from test_qwen3_next import _grads_of, _input, plain_delta_net
+from test_gated_delta_rule import plain_delta_net
+from util import grads_of as _grads_of, input_parameter as _input
 
 BF16_ULP = 2.0 ** -8
 
@@ -37,7 +38,7 @@ def interpreted(monkeypatch):
 
 
 def op_inputs(seed, t, hk, hv, gates, dtype=jnp.float32, b=1):
-    """As test_qwen3_next.delta_inputs draws them, at heads of 128."""
+    """As test_gated_delta_rule.delta_inputs draws them, at heads of 128."""
     rng = np.random.default_rng(seed)
     d = 128
     q, k = (jnp.asarray(rng.normal(size=(b, t, hk, d)), dtype)

@@ -19,7 +19,9 @@ from paddle_tpu.ops.kernels import gated_delta_intra as gdi
 from paddle_tpu.ops.kernels import gated_delta_scan as gds
 
 from test_gated_delta_intra import op_inputs as _op_inputs
-from test_qwen3_next import _grads_of, _input, plain_delta_net
+from test_gated_delta_rule import plain_delta_net
+from util import (grads_of as _grads_of, input_parameter as _input,
+                  out_and_grads)
 from test_ssd_scan_kernel import _pallas_calls
 
 BF16_ULP = 2.0 ** -8
@@ -49,8 +51,8 @@ def chunks_of(seed, b, t, hk, hv, dtype, gates='mild'):
     the dtypes the kernel of that stage leaves them in; with `gates`
     'channel' the chunk's decay is [N, B, H, Dk]."""
     cfg = (64, 128 ** -0.5, True, 1e-6, False)
-    w, u, qg, kd, p, decay = la._stage_intra(
-        *op_inputs(seed, b, t, hk, hv, gates, dtype), cfg)
+    w, u, qg, kd, p, decay = jax.jit(lambda *a: la._stage_intra(*a, cfg))(
+        *op_inputs(seed, b, t, hk, hv, gates, dtype))
     return (w.astype(dtype), u, qg.astype(dtype), kd.astype(dtype),
             p.astype(dtype), decay)
 
@@ -84,13 +86,20 @@ def test_the_kernels_are_the_scan_and_its_transposition(rows, dtype, gates):
     assert gds._heads(h) == heads
     do = jnp.asarray(np.random.default_rng(1).normal(
         size=(b, n * c, h, d)), jnp.float32)
+
+    def pulled(fn):
+        """fn's output and `do` pulled back through it, in one compile."""
+        def both(do, *x):
+            out, pull = jax.vjp(fn, *x)
+            return out, pull(do)
+        return jax.jit(both)(do, *xs)
+
     with jax.default_matmul_precision('highest'):
-        want, pull_want = jax.vjp(lambda *x: la._scan(x, dtype, False), *xs)
-        got, pull_got = jax.vjp(
-            lambda *x: gds.gated_delta_scan(x, dtype, True), *xs)
-        g_want, g_got = pull_want(do), pull_got(do)
+        want, g_want = pulled(lambda *x: la._scan(x, dtype, False))
+        got, g_got = pulled(lambda *x: gds.gated_delta_scan(x, dtype, True))
         # the composition's own backward, in its two walks, is that vjp
-        g_walks = la._scan_bwd(xs, do, dtype, False)
+        g_walks = jax.jit(lambda do, *x: la._scan_bwd(x, do, dtype, False))(
+            do, *xs)
     tol = 1e-5 if dtype == jnp.float32 else 2 * BF16_ULP
     assert got.dtype == jnp.float32 and got.shape == do.shape
     assert float(jnp.abs(got - want).max()) <= 1e-5 * float(
@@ -121,11 +130,12 @@ def test_the_padded_tokens_change_nothing():
         return gds.gated_delta_scan(xs, jnp.float32, True)[:, :a[0].shape[1]]
 
     with jax.default_matmul_precision('highest'):
-        short, g_short = jax.value_and_grad(
-            lambda *a: jnp.sum(op(*a) * w[:, :100]), argnums=(0, 1, 2))(*args)
-        long_, g_long = jax.value_and_grad(
+        short, g_short = jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(op(*a) * w[:, :100]),
+            argnums=(0, 1, 2)))(*args)
+        long_, g_long = jax.jit(jax.value_and_grad(
             lambda *a: jnp.sum(op(*a)[:, :100] * w[:, :100]),
-            argnums=(0, 1, 2))(*longer)
+            argnums=(0, 1, 2)))(*longer)
     np.testing.assert_allclose(short, long_, rtol=1e-6)
     for a, b in zip(g_short, g_long):
         np.testing.assert_allclose(a, b[:, :100], rtol=1e-5, atol=1e-7)
@@ -152,12 +162,11 @@ def test_the_op_through_the_kernels_is_the_composition(dtype, gates,
             return la.gated_delta_rule(*a, chunk_size=64, qk_l2norm=True,
                                        kernel=kernels, scan_kernel=kernels,
                                        gate_floor=-5.0 if channel else None)
-        return lambda *a: (op(*a), jax.grad(
-            lambda *b: jnp.sum(op(*b) * weight), argnums=range(5))(*a))
+        return out_and_grads(op, args, weight)
 
     with jax.default_matmul_precision('highest'):
-        got, g_got = through(True)(*args)
-        want, g_want = through(False)(*args)
+        got, g_got = through(True)
+        want, g_want = through(False)
     tol = 1e-5 if dtype == jnp.float32 else 2 * BF16_ULP
     assert got.dtype == jnp.float32
     assert float(jnp.abs(got - want).max()) <= tol * float(
@@ -174,9 +183,7 @@ def test_the_op_through_the_kernels_is_the_composition(dtype, gates,
     if dtype != jnp.float32:
         return
     with jax.default_matmul_precision('highest'):
-        exact = plain_delta_net(*args)
-        g_exact = jax.grad(lambda *a: jnp.sum(plain_delta_net(*a) * weight),
-                           argnums=range(5))(*args)
+        exact, g_exact = out_and_grads(plain_delta_net, args, weight)
     assert float(jnp.abs(got - exact).max()) < 2e-5 * float(
         jnp.abs(exact).max())
     for name, a, b in zip('q k v g beta'.split(), g_got, g_exact):

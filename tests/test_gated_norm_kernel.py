@@ -16,7 +16,7 @@ from paddle_tpu.fluid import layers, lowering
 from paddle_tpu.fluid.ops_impl import linear_attention_ops as la
 from paddle_tpu.ops.kernels import gated_norm as gn
 
-from test_qwen3_next import _grads_of, _input
+from util import grads_of as _grads_of, input_parameter as _input
 
 BF16_ULP = 2.0 ** -8
 EPS = 1e-5

@@ -6,9 +6,9 @@ multi-token prediction module's shared embedding and head, the bias
 update after the optimizer, recompute regions, name scopes in op_name, the
 whole toy model against the benchmark's plain reference, and the
 configuration's file. Small sizes, on the CPU."""
+import functools
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -23,16 +23,14 @@ from paddle_tpu.fluid import framework, layers, unique_name
 from paddle_tpu.parallel.moe import router_topk
 from util import held_way
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
-sys.path.insert(0, os.path.join(REPO, 'tests', 'test_chipbench'))
+import decoder_toy
+from decoder_toy import REPO, check_all
 
 CELL = 'glm47flash_s8192'
 
-
-def reference_module():
-    from chipbench.harness import catalog
-    return catalog.load_module(catalog.ROOT, 'references', 'glm4_moe_lite')
+reference_module = functools.partial(decoder_toy.reference_module,
+                                     'glm4_moe_lite')
+_toy_cell = functools.partial(decoder_toy.toy_cell, CELL)
 
 
 # ---------------------------------------------------------------- the router
@@ -152,10 +150,11 @@ def run_share(held, xs, weights):
     first, n = held or (0, E)
     with fluid.scope_guard(fluid.Scope()):
         exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
+        # every parameter is set below: no start-up program (a compile a
+        # share) is run
         scope, place = fluid.global_scope(), fluid.CPUPlace()
         for i, w in enumerate(weights):
-            scope.find_var('moe_mlp_0.w_%d' % i).get_tensor().set(
+            scope.var('moe_mlp_0.w_%d' % i).get_tensor().set(
                 w[first:first + n] if i in (1, 2, 3) else w, place)
         return exe.run(main, feed={'x': xs}, fetch_list=[out, count])
 
@@ -386,15 +385,11 @@ def test_latent_attention_takes_values_of_their_own_width():
 
 # ------------------------------------------------------- regions and scopes
 
-def _toy_cell():
-    import chipbench_toy as toy
-    return toy.load_toy_cell(CELL)
-
-
 def _run_toy_program(cell, train, feed_seed=5, steps=1, strip=False,
-                     feed=None, optimized=False):
-    """Builds the toy cell's Program, runs it, returns (built, results,
-    the executor's lowered text)."""
+                     feeds=None, optimized=False):
+    """Builds the toy cell's Program, runs it `steps` times on the pool's
+    batch (or once on each of `feeds`), returns (built, results, the
+    executor's lowered text, the scope's state)."""
     from chipbench.harness import check
     config = dict(cell['config'], check={'grads': []}, amp='none')
     built = cell['builder'].build(config, cell['traffic'], train=train)
@@ -415,10 +410,10 @@ def _run_toy_program(cell, train, feed_seed=5, steps=1, strip=False,
         exe.run(built['startup'])
         fetch = [built['loss']] + [built['grads'][n]
                                    for n in sorted(built['grads'])]
-        feed = feed or pool[0]
+        feeds = feeds or [pool[0]] * steps
         out = [exe.run(built['main'], feed=feed, fetch_list=fetch)
-               for _ in range(steps)]
-        text = exe.lowered_hlo(built['main'], feed, fetch,
+               for feed in feeds]
+        text = exe.lowered_hlo(built['main'], feeds[0], fetch,
                                optimized=optimized)
         scope = fluid.global_scope()
         state = {v.name: np.asarray(scope.find_var(v.name).get_tensor())
@@ -428,12 +423,19 @@ def _run_toy_program(cell, train, feed_seed=5, steps=1, strip=False,
     return built, out, text, state
 
 
+@functools.lru_cache(maxsize=None)
+def _checked_toy_program():
+    """The toy cell's check Program (every trainable parameter's gradient)
+    on the pool's batch, run once for the tests that read it."""
+    return _run_toy_program(_toy_cell(), train=False)
+
+
 def test_recompute_regions_change_no_number_and_are_one_a_layer():
     """Six regions (five layers and the module), each a run of ops; the
     loss and every gradient are what the unmarked Program gives."""
-    cell = _toy_cell()
-    built, marked, text, _ = _run_toy_program(cell, train=False)
-    _, plain, plain_text, _ = _run_toy_program(cell, train=False, strip=True)
+    built, marked, text, _ = _checked_toy_program()
+    _, plain, plain_text, _ = _run_toy_program(_toy_cell(), train=False,
+                                               strip=True)
     ops = built['main'].global_block().ops
     marks = [op.attrs.get('recompute') for op in ops]
     runs = [m for i, m in enumerate(marks)
@@ -537,23 +539,10 @@ def test_toy_model_agrees_with_the_plain_reference_on_every_gradient():
     gradient of EVERY trainable parameter (a dense layer, four expert
     layers holding experts 4..7 of 16, the module; embedding and head
     shared); and under bf16 AMP within a stated tolerance."""
-    from chipbench.harness import check
     cell = _toy_cell()
     assert cell['builder'].experts(cell['config']) == (16, (4, 4))
-    with fluid.scope_guard(fluid.Scope()):
-        exe = fluid.Executor(fluid.CPUPlace())
-        built = cell['builder'].build(cell['config'], cell['traffic'])
-        exe.run(built['startup'])
-        block = built['main'].global_block()
-        names = [n for n in check.parameter_names(built['main'])
-                 if block.var(n).trainable]
-        entry = dict(cell['config']['checks']['float32'], grads=names,
-                     tolerance={'loss': 1e-5, 'grad': 5e-4})
-        got = check.run_check(cell, exe, fluid.global_scope(), 5, entry)
-        amp = check.run_check(
-            cell, exe, fluid.global_scope(), 5,
-            dict(cell['config']['checks']['amp'], grads=names,
-                 tolerance={'loss': 1e-3, 'grad': 0.25}))
+    names, got = check_all(cell, {'loss': 1e-5, 'grad': 5e-4})
+    _, amp = check_all(cell, {'loss': 1e-3, 'grad': 0.25}, amp='amp')
     # embedding; 9 + 3 dense; 4 x (9 + 4 + 3); module 3 + 16 + 1; head;
     # final norm (the five selection biases are no trainable parameter)
     assert len(names) == 1 + 12 + 4 * 16 + 20 + 1 + 1
@@ -583,7 +572,7 @@ def _reference_grads(cell, state, built, batch, untie):
 
 def test_shared_embedding_and_head_get_the_sum_of_both_uses():
     cell = _toy_cell()
-    built, out, _, state = _run_toy_program(cell, train=False)
+    built, out, _, state = _checked_toy_program()
     grads = dict(zip(sorted(built['grads']), out[0][1:]))
     block = built['main'].global_block()
     uses = {n: sum(n in op.input_arg_names for op in block.ops)
@@ -615,12 +604,16 @@ def test_the_modules_last_position_does_not_reach_the_loss():
     feed = {k: np.asarray(v).copy() for k, v in pool[0].items()}
     unused = min(set(range(cell['config']['model']['vocab_size']))
                  - set(feed['input_ids'].ravel()) - set(feed['labels'].ravel()))
-    rows = {}
+    moved = {}
     for position in (-1, -2):
-        moved = dict(feed, labels=feed['labels'].copy())
-        moved['labels'][:, position] = unused
-        built, out, _, _ = _run_toy_program(cell, train=False, feed=moved)
-        grads = dict(zip(sorted(built['grads']), out[0][1:]))
+        moved[position] = dict(feed, labels=feed['labels'].copy())
+        moved[position]['labels'][:, position] = unused
+    # one Program, run on both feeds
+    built, out, _, _ = _run_toy_program(cell, train=False,
+                                        feeds=list(moved.values()))
+    rows = {}
+    for position, got in zip(moved, out):
+        grads = dict(zip(sorted(built['grads']), got[1:]))
         rows[position] = np.asarray(grads['glm_tok_emb'])[unused]
     assert not rows[-1].any()
     assert np.abs(rows[-2]).max() > 0

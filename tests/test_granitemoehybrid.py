@@ -8,9 +8,9 @@ reference; the reference's walk with its weights on the host against
 `jax.grad` of the same function in one piece; name scopes, regions,
 counters, the configuration's file against the catalog's row, its FLOPs
 and its readers. Small sizes, on the CPU."""
+import functools
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -22,9 +22,8 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu import obs
 from paddle_tpu.fluid import framework, unique_name
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
-sys.path.insert(0, os.path.join(REPO, 'tests', 'test_chipbench'))
+import decoder_toy
+from decoder_toy import REPO, build_toy, check_all
 
 CELL = 'granite4hmicro_s8192'
 # the issue's small size: 3 mamba + 1 attention, vocabulary 256
@@ -32,36 +31,9 @@ SMALL = dict(layer_types=['mamba', 'mamba', 'mamba', 'attention'],
              num_hidden_layers=4, vocab_size=256)
 
 
-def reference_module():
-    from chipbench.harness import catalog
-    return catalog.load_module(catalog.ROOT, 'references',
-                               'granitemoehybrid')
-
-
-def _toy_cell(**model):
-    """The toy cell; `model` overrides keys of its model."""
-    import chipbench_toy as toy
-    cell = toy.load_toy_cell(CELL)
-    if model:
-        cell = dict(cell, config=dict(
-            cell['config'], model=dict(cell['config']['model'], **model)))
-    return cell
-
-
-def _check_all(cell, tolerance, seed=5, amp=None):
-    """harness/check.py's comparison of the toy cell's Program with the
-    plain reference on EVERY trainable parameter."""
-    from chipbench.harness import check
-    with fluid.scope_guard(fluid.Scope()):
-        exe = fluid.Executor(fluid.CPUPlace())
-        built = cell['builder'].build(cell['config'], cell['traffic'])
-        exe.run(built['startup'])
-        names = [v.name for v in built['main'].list_vars()
-                 if isinstance(v, framework.Parameter) and v.trainable]
-        entry = dict(cell['config']['checks'][amp or 'float32'],
-                     grads=names, tolerance=tolerance)
-        return names, check.run_check(cell, exe, fluid.global_scope(), seed,
-                                      entry)
+reference_module = functools.partial(decoder_toy.reference_module,
+                                     'granitemoehybrid')
+_toy_cell = functools.partial(decoder_toy.toy_cell, CELL)
 
 
 # trainable parameters a layer: the mixer's norm and parameters, the
@@ -81,12 +53,12 @@ def test_toy_model_agrees_with_the_plain_reference_on_every_gradient():
     cell = _toy_cell(**SMALL)
     assert cell['builder'].kinds(cell['config']['model']) == \
         SMALL['layer_types']
-    names, got = _check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
+    names, got = check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
     assert len(names) == 1 + sum(_PER_KIND[k]
                                  for k in SMALL['layer_types']) + 1
     assert set(got['grad_rel']) == set(names)
     assert got['passed'], got
-    _, amp = _check_all(cell, {'loss': 1e-3, 'grad': 0.25}, amp='amp')
+    _, amp = check_all(cell, {'loss': 1e-3, 'grad': 0.25}, amp='amp')
     assert amp['passed'], amp
 
 
@@ -182,7 +154,7 @@ def test_a_moved_rule_fails_the_comparison(rule):
     reference = reference_module()
     _MOVED[rule](reference)
     cell = dict(_toy_cell(**SMALL), reference=reference)
-    _, got = _check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
+    _, got = check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
     assert not got['passed']
     assert max(got['grad_rel'].values()) > 1e-3
 
@@ -194,14 +166,14 @@ def test_the_tied_tables_gradient_is_the_sum_of_its_two_uses():
     without the head's part misses by about as much as it holds."""
     cell = _toy_cell(**SMALL)
     uses = obs.counter('model.shared_param_uses').value
-    names, got = _check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
+    names, got = check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
     assert obs.counter('model.shared_param_uses').value - uses >= 1
     assert names[0] == 'granite_tok_emb'
     assert names.count('granite_tok_emb') == 1
     assert got['grad_rel']['granite_tok_emb'] < 1e-5
     reference = reference_module()
     _untied_head(reference)
-    _, cut = _check_all(dict(cell, reference=reference),
+    _, cut = check_all(dict(cell, reference=reference),
                         {'loss': 1e-5, 'grad': 1e-5})
     assert cut['loss_rel'] < 1e-5
     assert cut['grad_rel']['granite_tok_emb'] > 0.1
@@ -219,13 +191,10 @@ def test_the_walk_with_host_weights_is_the_gradient_of_the_whole():
     ref = reference_module()
     cell = _toy_cell(**SMALL)
     config = cell['config']
-    with fluid.scope_guard(fluid.Scope()):
-        exe = fluid.Executor(fluid.CPUPlace())
-        built = cell['builder'].build(config, cell['traffic'])
-        exe.run(built['startup'])
-        params, tree = cell['builder'].reference_params(
-            config, built['main'], lambda n: np.asarray(
-                fluid.global_scope().find_var(n).get_tensor()))
+    scope, built = decoder_toy.started(cell)
+    params, tree = cell['builder'].reference_params(
+        config, built['main'],
+        lambda n: np.asarray(scope.find_var(n).get_tensor()))
     assert all(isinstance(v, np.ndarray) for v in params.values())
     pool, _ = cell['generator'].make_pool(dict(cell['traffic'], pool=1),
                                           config, 9)
@@ -248,12 +217,6 @@ def test_the_walk_with_host_weights_is_the_gradient_of_the_whole():
     assert again[1]['tok_emb'] is grads['tok_emb']
 
 
-def _build_toy(cell, train):
-    config = dict(cell['config'], check={'grads': []}, amp='none')
-    return config, cell['builder'].build(config, cell['traffic'],
-                                         train=train)
-
-
 def test_layers_are_mixer_and_feed_forward_scopes_regions_and_counters():
     """Ten layers off `layer_types`, each a mixer AND a gated feed-forward
     behind a norm each in ONE recompute region; the Mamba-2 mixers are
@@ -273,7 +236,7 @@ def test_layers_are_mixer_and_feed_forward_scopes_regions_and_counters():
               for k in ('mamba', 'attention')}
     conv = obs.counter('conv1d.lowered', taps=4, act='silu',
                        bias='true').value
-    config, built = _build_toy(cell, train=True)
+    config, built = build_toy(cell, train=True)
     assert obs.counter('granite.layers', kind='mamba').value \
         - before['mamba'] == 9
     assert obs.counter('granite.layers', kind='attention').value \
@@ -317,14 +280,7 @@ def test_layers_are_mixer_and_feed_forward_scopes_regions_and_counters():
     regions = {op.attrs.get('recompute') for op in ops
                if op.attrs.get('recompute') is not None}
     assert len(regions) == 10
-    pool, _ = cell['generator'].make_pool(dict(cell['traffic'], pool=1),
-                                          config, 5)
-    with fluid.scope_guard(fluid.Scope()):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(built['startup'])
-        exe.run(built['main'], feed=pool[0], fetch_list=[built['loss']])
-        text = exe.lowered_hlo(built['main'], pool[0], [built['loss']],
-                               optimized=True)
+    text = decoder_toy.one_step_hlo(cell, config, built)
     window = catalog.load_module(catalog.ROOT, 'layers', 'name_scope_window')
     mamba = window.op_scopes_under(text, 'mamba_mixer')
     attn = window.op_scopes_under(text, 'attention_mixer')

@@ -7,9 +7,9 @@ the four shares adding up to the uncut reference's layer; the toy model
 against the plain reference on every gradient (and a moved rule FAILING
 the comparison); name scopes, regions, counters, the configuration's file,
 its FLOPs and its readers. Small sizes, on the CPU."""
+import functools
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -22,31 +22,20 @@ from paddle_tpu import obs
 from paddle_tpu.fluid import framework, layers, unique_name
 from util import held_way
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
-sys.path.insert(0, os.path.join(REPO, 'tests', 'test_chipbench'))
+import decoder_toy
+from decoder_toy import REPO, build_toy, check_all
 
 CELL = 'lfm2_s16384'
 
 
-def reference_module():
-    from chipbench.harness import catalog
-    return catalog.load_module(catalog.ROOT, 'references', 'lfm2_moe')
-
-
-def _toy_cell(**model):
-    """The toy cell; `model` overrides keys of its model."""
-    import chipbench_toy as toy
-    cell = toy.load_toy_cell(CELL)
-    if model:
-        cell = dict(cell, config=dict(
-            cell['config'], model=dict(cell['config']['model'], **model)))
-    return cell
+reference_module = functools.partial(decoder_toy.reference_module,
+                                     'lfm2_moe')
+_toy_cell = functools.partial(decoder_toy.toy_cell, CELL)
 
 
 def _set(scope, name, value):
-    scope.find_var(name).get_tensor().set(np.asarray(value, 'float32'),
-                                          fluid.CPUPlace())
+    scope.var(name).get_tensor().set(np.asarray(value, 'float32'),
+                                     fluid.CPUPlace())
 
 
 def _get(scope, name):
@@ -331,7 +320,8 @@ def run_share(held, xs, weights):
     first, n = held or (0, E)
     with fluid.scope_guard(fluid.Scope()):
         exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
+        # every parameter is set below: no start-up program (a compile a
+        # share) is run
         scope = fluid.global_scope()
         _set(scope, 'px', xs)
         for i, w in enumerate(weights):
@@ -446,22 +436,6 @@ def test_a_quarter_held_on_either_path(way, monkeypatch):
 
 # ------------------------------------------------------------------ the model
 
-def _check_all(cell, tolerance, seed=5, amp=None):
-    """harness/check.py's comparison of the toy cell's Program with the
-    plain reference on EVERY trainable parameter."""
-    from chipbench.harness import check
-    with fluid.scope_guard(fluid.Scope()):
-        exe = fluid.Executor(fluid.CPUPlace())
-        built = cell['builder'].build(cell['config'], cell['traffic'])
-        exe.run(built['startup'])
-        names = [v.name for v in built['main'].list_vars()
-                 if isinstance(v, framework.Parameter) and v.trainable]
-        entry = dict(cell['config']['checks'][amp or 'float32'],
-                     grads=names, tolerance=tolerance)
-        return names, check.run_check(cell, exe, fluid.global_scope(), seed,
-                                      entry)
-
-
 def test_toy_model_agrees_with_the_plain_reference_on_every_gradient():
     """models/lfm2_moe.py through the Executor against
     chipbench/references/lfm2_moe.py in float32 to 1e-5: the loss and the
@@ -475,13 +449,13 @@ def test_toy_model_agrees_with_the_plain_reference_on_every_gradient():
     assert cell['builder'].experts(cell['config']) == (16, (4, 4))
     run, dense_end = cell['builder'].stretch(cell['config']['model'])
     assert (list(run), dense_end) == ([1, 2, 3, 4, 5], 2)
-    names, got = _check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
+    names, got = check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
     # the embedding; layer 1 (2 norms, 3 + 3); layer 2 (2 norms, 6 + 4);
     # layers 3 to 5 (2 norms, 3 + 4); the final norm; NO head of its own
     assert len(names) == 1 + 8 + 12 + 3 * 9 + 1
     assert set(got['grad_rel']) == set(names)
     assert got['passed'], got
-    _, amp = _check_all(cell, {'loss': 1e-3, 'grad': 0.25}, amp='amp')
+    _, amp = check_all(cell, {'loss': 1e-3, 'grad': 0.25}, amp='amp')
     assert amp['passed'], amp
 
 
@@ -571,15 +545,9 @@ def test_a_moved_rule_fails_the_comparison(rule):
     reference = reference_module()
     _MOVED[rule](reference)
     cell = dict(_toy_cell(), reference=reference)
-    _, got = _check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
+    _, got = check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
     assert not got['passed']
     assert max(got['grad_rel'].values()) > 1e-3
-
-
-def _build_toy(cell, train):
-    config = dict(cell['config'], check={'grads': []}, amp='none')
-    return config, cell['builder'].build(config, cell['traffic'],
-                                         train=train)
 
 
 def test_layers_scopes_regions_and_counters():
@@ -600,7 +568,7 @@ def test_layers_scopes_regions_and_counters():
               obs.counter('moe.bias_updates').value,
               obs.counter('shortconv.mixers').value,
               obs.counter('shortconv.tokens').value)
-    config, built = _build_toy(cell, train=True)
+    config, built = build_toy(cell, train=True)
     assert obs.counter('shortconv.mixers').value - before[3] == 4
     assert obs.counter('moe.bias_updates').value - before[2] == 4
     ops = built['main'].global_block().ops
@@ -639,14 +607,7 @@ def test_layers_scopes_regions_and_counters():
     regions = {op.attrs.get('recompute') for op in ops
                if op.attrs.get('recompute') is not None}
     assert len(regions) == 5
-    pool, _ = cell['generator'].make_pool(dict(cell['traffic'], pool=1),
-                                          config, 5)
-    with fluid.scope_guard(fluid.Scope()):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(built['startup'])
-        exe.run(built['main'], feed=pool[0], fetch_list=[built['loss']])
-        text = exe.lowered_hlo(built['main'], pool[0], [built['loss']],
-                               optimized=True)
+    text = decoder_toy.one_step_hlo(cell, config, built)
     assert obs.counter('moe.lowered', **moe).value - before[0] >= 4
     assert obs.counter('conv1d.lowered', **conv).value - before[1] >= 4
     assert obs.counter('shortconv.tokens').value > before[4]
@@ -714,23 +675,10 @@ def test_small_preset_trains_and_moves_its_biases():
 
 def test_the_builders_rate_climbs_linearly_to_the_configurations_peak():
     cell = _toy_cell()
-    config = cell['config']
-    opt = config['optimizer']
+    opt = cell['config']['optimizer']
     assert (opt['learning_rate'], opt['warmup_steps']) == (4e-4, 2000)
-    with fluid.scope_guard(fluid.Scope()):
-        built = cell['builder'].build(config, cell['traffic'])
-        rate, = {op.input('LearningRate')[0]
-                 for op in built['main'].global_block().ops
-                 if op.type == 'adam'}
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(built['startup'])
-        pool, _ = cell['generator'].make_pool(cell['traffic'], config, 3)
-        got = [float(np.asarray(exe.run(
-            built['main'], feed=pool[0],
-            fetch_list=[built['loss'], rate])[1]).reshape(-1)[0])
-            for _ in range(3)]
-        exe.close()
-    np.testing.assert_allclose(got, [4e-4 * n / 2000 for n in (1, 2, 3)],
+    np.testing.assert_allclose(decoder_toy.rates_of_training(cell, 3),
+                               [4e-4 * n / 2000 for n in (1, 2, 3)],
                                rtol=1e-5)
 
 

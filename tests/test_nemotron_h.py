@@ -6,10 +6,10 @@ paths; the 16 shares of an expert part adding up to the uncut reference's;
 the toy model against the plain reference on every gradient (and a moved
 rule FAILING the comparison); name scopes, regions, counters, the
 configuration's file, its FLOPs and its readers. Small sizes, on the CPU."""
+import functools
 import json
 import os
 import re
-import sys
 
 import numpy as np
 import pytest
@@ -23,26 +23,15 @@ from paddle_tpu.fluid import framework, layers, unique_name
 from paddle_tpu.fluid.ops_impl import linear_attention_ops as la
 from util import held_way
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
-sys.path.insert(0, os.path.join(REPO, 'tests', 'test_chipbench'))
+import decoder_toy
+from decoder_toy import REPO, build_toy, check_all
 
 CELL = 'nemotron3nano_s8192'
 
 
-def reference_module():
-    from chipbench.harness import catalog
-    return catalog.load_module(catalog.ROOT, 'references', 'nemotron_h')
-
-
-def _toy_cell(**model):
-    """The toy cell; `model` overrides keys of its model."""
-    import chipbench_toy as toy
-    cell = toy.load_toy_cell(CELL)
-    if model:
-        cell = dict(cell, config=dict(
-            cell['config'], model=dict(cell['config']['model'], **model)))
-    return cell
+reference_module = functools.partial(decoder_toy.reference_module,
+                                     'nemotron_h')
+_toy_cell = functools.partial(decoder_toy.toy_cell, CELL)
 
 
 # ------------------------------------------------------------------ the scan
@@ -101,10 +90,11 @@ def test_ssd_scan_is_the_recurrence(case, amp):
         y = _recurrence(*v)
         return jnp.sum(y * w), y
 
-    (_, y), got = jax.value_and_grad(op, argnums=range(6), has_aux=True)(
-        *args)
-    (_, want_y), want = jax.value_and_grad(plain, argnums=range(6),
-                                           has_aux=True)(*args)
+    # one compile a function, not an op-by-op walk
+    (_, y), got = jax.jit(jax.value_and_grad(
+        op, argnums=range(6), has_aux=True))(*args)
+    (_, want_y), want = jax.jit(jax.value_and_grad(
+        plain, argnums=range(6), has_aux=True))(*args)
     assert y.dtype == jnp.float32 and y.shape == args[0].shape
     tol = 2.0 ** -6 if amp else 2e-5
     scale = float(jnp.abs(want_y).max())
@@ -288,11 +278,12 @@ def run_share(held, xs, weights):
     first, n = held or (0, E)
     with fluid.scope_guard(fluid.Scope()):
         exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
+        # every parameter is set below: no start-up program (a compile a
+        # share) is run
         scope, place = fluid.global_scope(), fluid.CPUPlace()
-        scope.find_var('px').get_tensor().set(xs, place)
+        scope.var('px').get_tensor().set(xs, place)
         for i, w in enumerate(weights):
-            scope.find_var('moe_mlp_0.w_%d' % i).get_tensor().set(
+            scope.var('moe_mlp_0.w_%d' % i).get_tensor().set(
                 w[first:first + n] if i in (1, 2) else w, place)
         return exe.run(main, fetch_list=[out, count])
 
@@ -440,22 +431,6 @@ def test_relu2_is_an_activation_of_the_whole_layer_too():
 
 # ------------------------------------------------------------------ the model
 
-def _check_all(cell, tolerance, seed=5, amp=None):
-    """harness/check.py's comparison of the toy cell's Program with the
-    plain reference on EVERY trainable parameter."""
-    from chipbench.harness import check
-    with fluid.scope_guard(fluid.Scope()):
-        exe = fluid.Executor(fluid.CPUPlace())
-        built = cell['builder'].build(cell['config'], cell['traffic'])
-        exe.run(built['startup'])
-        names = [v.name for v in built['main'].list_vars()
-                 if isinstance(v, framework.Parameter) and v.trainable]
-        entry = dict(cell['config']['checks'][amp or 'float32'],
-                     grads=names, tolerance=tolerance)
-        return names, check.run_check(cell, exe, fluid.global_scope(), seed,
-                                      entry)
-
-
 # trainable parameters a block after its norm (models/nemotron_h.py)
 _PER_KIND = {'M': 8, '*': 4, 'E': 5}
 
@@ -472,11 +447,11 @@ def test_toy_model_agrees_with_the_plain_reference_on_every_gradient():
     cell = _toy_cell()
     assert cell['builder'].experts(cell['config']) == (16, (4, 4))
     assert cell['builder'].pattern(cell['config']['model']) == 'MEMEM*EME'
-    names, got = _check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
+    names, got = check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
     assert len(names) == 1 + sum(1 + _PER_KIND[k] for k in 'MEMEM*EME') + 2
     assert set(got['grad_rel']) == set(names)
     assert got['passed'], got
-    _, amp = _check_all(cell, {'loss': 1e-3, 'grad': 0.25}, amp='amp')
+    _, amp = check_all(cell, {'loss': 1e-3, 'grad': 0.25}, amp='amp')
     assert amp['passed'], amp
 
 
@@ -536,15 +511,9 @@ def test_a_moved_rule_fails_the_comparison(rule):
     reference = reference_module()
     _MOVED[rule](reference)
     cell = dict(_toy_cell(), reference=reference)
-    _, got = _check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
+    _, got = check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
     assert not got['passed']
     assert max(got['grad_rel'].values()) > 1e-3
-
-
-def _build_toy(cell, train):
-    config = dict(cell['config'], check={'grads': []}, amp='none')
-    return config, cell['builder'].build(config, cell['traffic'],
-                                         train=train)
 
 
 def test_blocks_are_one_part_each_scopes_regions_and_counters():
@@ -562,7 +531,7 @@ def test_blocks_are_one_part_each_scopes_regions_and_counters():
               obs.counter('conv1d.lowered', taps=4, act='silu',
                           bias='true').value,
               obs.counter('moe.bias_updates').value)
-    config, built = _build_toy(cell, train=True)
+    config, built = build_toy(cell, train=True)
     assert obs.counter('moe.lowered', **moe).value - before[0] == 4
     assert obs.counter('conv1d.lowered', taps=4, act='silu',
                           bias='true').value - before[1] == 4
@@ -596,14 +565,7 @@ def test_blocks_are_one_part_each_scopes_regions_and_counters():
     regions = {op.attrs.get('recompute') for op in ops
                if op.attrs.get('recompute') is not None}
     assert len(regions) == 9
-    pool, _ = cell['generator'].make_pool(dict(cell['traffic'], pool=1),
-                                          config, 5)
-    with fluid.scope_guard(fluid.Scope()):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(built['startup'])
-        exe.run(built['main'], feed=pool[0], fetch_list=[built['loss']])
-        text = exe.lowered_hlo(built['main'], pool[0], [built['loss']],
-                               optimized=True)
+    text = decoder_toy.one_step_hlo(cell, config, built)
     window = catalog.load_module(catalog.ROOT, 'layers', 'name_scope_window')
     mamba = window.op_scopes_under(text, 'mamba_mixer')
     attn = window.op_scopes_under(text, 'attention_mixer')
@@ -672,23 +634,10 @@ def test_small_preset_trains_and_moves_its_biases():
 
 def test_the_builders_rate_climbs_linearly_to_the_configurations_peak():
     cell = _toy_cell()
-    config = cell['config']
-    opt = config['optimizer']
+    opt = cell['config']['optimizer']
     assert (opt['learning_rate'], opt['warmup_steps']) == (4e-4, 2000)
-    with fluid.scope_guard(fluid.Scope()):
-        built = cell['builder'].build(config, cell['traffic'])
-        rate, = {op.input('LearningRate')[0]
-                 for op in built['main'].global_block().ops
-                 if op.type == 'adam'}
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(built['startup'])
-        pool, _ = cell['generator'].make_pool(cell['traffic'], config, 3)
-        got = [float(np.asarray(exe.run(
-            built['main'], feed=pool[0],
-            fetch_list=[built['loss'], rate])[1]).reshape(-1)[0])
-            for _ in range(3)]
-        exe.close()
-    np.testing.assert_allclose(got, [4e-4 * n / 2000 for n in (1, 2, 3)],
+    np.testing.assert_allclose(decoder_toy.rates_of_training(cell, 3),
+                               [4e-4 * n / 2000 for n in (1, 2, 3)],
                                rtol=1e-5)
 
 

@@ -4,7 +4,6 @@ the benchmark's plain reference, what AMP casts, the typed refusal
 on a mesh, and the configuration's file. Small sizes, on the CPU."""
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -16,8 +15,8 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu import obs
 from paddle_tpu.fluid import framework, layers, unique_name
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
+from decoder_toy import REPO, check_all, reference_module, toy_cell
+from util import input_parameter as _input
 
 N, D, E, H = 48, 16, 8, 12
 
@@ -135,14 +134,6 @@ def test_dropless_equals_fixed_capacity_where_nothing_overflows():
                                rtol=1e-6)
 
 
-def _input(name, value):
-    """A Program input whose gradient append_backward returns: a parameter
-    initialised to `value`."""
-    return layers.create_parameter(
-        list(value.shape), 'float32', name=name,
-        default_initializer=fluid.initializer.NumpyArrayInitializer(value))
-
-
 def _grads_of(build, feed, wrt):
     """Runs a one-op Program forward and backward; returns (out, grads)."""
     main, startup = framework.Program(), framework.Program()
@@ -210,27 +201,36 @@ def test_rotary_embedding_forward_and_gradient():
                                x[..., :4] ** 2 + x[..., 4:] ** 2, rtol=1e-4)
 
 
-def test_toy_model_agrees_with_the_plain_reference_on_every_gradient(
-        tmp_path):
+def test_toy_model_agrees_with_the_plain_reference_on_every_gradient():
     """models/olmoe.py through the Executor against
     chipbench/references/olmoe.py in float32: the loss and the gradient of
     EVERY parameter (two layers, so the mean over layers of the router loss
     is held too)."""
-    sys.path.insert(0, os.path.join(REPO, 'tests', 'test_chipbench'))
-    import chipbench_toy as toy
-    from chipbench.harness import check
-    cell = toy.load_toy_cell('olmoe_s4096')
-    with fluid.scope_guard(fluid.Scope()):
-        exe = fluid.Executor(fluid.CPUPlace())
-        built = cell['builder'].build(cell['config'], cell['traffic'])
-        exe.run(built['startup'])
-        names = check.parameter_names(built['main'])
-        entry = dict(cell['config']['checks']['float32'], grads=names,
-                     tolerance={'loss': 1e-5, 'grad': 2e-4})
-        got = check.run_check(cell, exe, fluid.global_scope(), 5, entry)
+    names, got = check_all(toy_cell('olmoe_s4096'),
+                           {'loss': 1e-5, 'grad': 2e-4})
     assert len(names) == 1 + 2 * 12 + 2 and set(got['grad_rel']) == set(names)
     assert got['passed'], got
     assert max(got['grad_rel'].values()) < 2e-4
+
+
+def test_a_second_comparison_compiles_no_program_and_reads_the_same():
+    """tests/decoder_toy.py: the Program's side of one (cell, entry, seed)
+    is lowered, compiled and run once; `run_check` gives the next verdict
+    on the recorded fetches, and a reference with a rule moved still fails
+    it."""
+    cell = toy_cell('olmoe_s4096')
+    _, first = check_all(cell, {'loss': 1e-5, 'grad': 2e-4})
+    misses = obs.counter('executor.cache.misses').value
+    names, again = check_all(cell, {'loss': 1e-5, 'grad': 2e-4})
+    assert obs.counter('executor.cache.misses').value == misses
+    assert again['passed'] and again['grad_rel'] == first['grad_rel']
+    assert again['loss'] == first['loss']
+    moved = reference_module('olmoe')
+    moved.rotary = lambda x, theta: x
+    _, got = check_all(dict(cell, reference=moved),
+                       {'loss': 1e-5, 'grad': 2e-4})
+    assert obs.counter('executor.cache.misses').value == misses
+    assert not got['passed'] and max(got['grad_rel'].values()) > 1e-2
 
 
 def test_amp_leaves_the_router_in_float32_and_counts_what_it_lowers():

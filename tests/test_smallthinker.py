@@ -6,10 +6,10 @@ the comparison), an expert layer's eight SHARES adding up to the uncut
 reference's layer, the two name scopes, the regions, the counters, the
 configuration's file, its FLOPs and its readers. Small sizes, on the
 CPU."""
+import functools
 import json
 import os
 import re
-import sys
 
 import numpy as np
 import pytest
@@ -22,27 +22,15 @@ from paddle_tpu import obs
 from paddle_tpu.fluid import framework, layers, unique_name
 from util import held_way
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
-sys.path.insert(0, os.path.join(REPO, 'tests', 'test_chipbench'))
+import decoder_toy
+from decoder_toy import REPO, build_toy, check_all
 
 CELL = 'smallthinker_s16384'
 
 
-def reference_module():
-    from chipbench.harness import catalog
-    return catalog.load_module(catalog.ROOT, 'references', 'smallthinker')
-
-
-def _toy_cell(**model):
-    """The toy cell; `model` overrides keys of its model (the window,
-    the layouts)."""
-    import chipbench_toy as toy
-    cell = toy.load_toy_cell(CELL)
-    if model:
-        cell = dict(cell, config=dict(
-            cell['config'], model=dict(cell['config']['model'], **model)))
-    return cell
+reference_module = functools.partial(decoder_toy.reference_module,
+                                     'smallthinker')
+_toy_cell = functools.partial(decoder_toy.toy_cell, CELL)
 
 
 # ------------------------------------------------------- the router's input
@@ -80,14 +68,15 @@ def run_layer(held, own, feeds, weights, same=False):
     first, n = held or (0, E)
     with fluid.scope_guard(fluid.Scope()):
         exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
+        # every parameter is set below: no start-up program (a compile a
+        # layer) is run
         scope, place = fluid.global_scope(), fluid.CPUPlace()
         for i, w in enumerate(weights):
-            scope.find_var('moe_mlp_0.w_%d' % i).get_tensor().set(
+            scope.var('moe_mlp_0.w_%d' % i).get_tensor().set(
                 w[first:first + n] if i else w, place)
-        scope.find_var('px').get_tensor().set(feeds['x'], place)
+        scope.var('px').get_tensor().set(feeds['x'], place)
         if 'pr' in grads:
-            scope.find_var('pr').get_tensor().set(feeds['r'], place)
+            scope.var('pr').get_tensor().set(feeds['r'], place)
         names = sorted(grads)
         got = exe.run(main, feed={'w': feeds['w']},
                       fetch_list=outs + [grads[k] for k in names])
@@ -332,21 +321,6 @@ def test_the_toy_cells_expert_layers_share_a_body_across_their_regions():
 
 # ------------------------------------------------------------------ the model
 
-def _check_all(cell, tolerance, seed=5, amp=None):
-    """harness/check.py's comparison of the toy cell's Program with the
-    plain reference on EVERY trainable parameter."""
-    from chipbench.harness import check
-    with fluid.scope_guard(fluid.Scope()):
-        exe = fluid.Executor(fluid.CPUPlace())
-        built = cell['builder'].build(cell['config'], cell['traffic'])
-        exe.run(built['startup'])
-        names = check.parameter_names(built['main'])
-        entry = dict(cell['config']['checks'][amp or 'float32'],
-                     grads=names, tolerance=tolerance)
-        return names, check.run_check(cell, exe, fluid.global_scope(), seed,
-                                      entry)
-
-
 # a layer's parameters in creation order (models/smallthinker.py)
 _PER_LAYER = 10
 
@@ -361,11 +335,11 @@ def test_toy_model_agrees_with_the_plain_reference_on_every_gradient():
     tolerance."""
     cell = _toy_cell(sliding_window_size=5)
     assert cell['builder'].experts(cell['config']) == (16, (4, 4))
-    names, got = _check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
+    names, got = check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
     assert len(names) == 1 + 4 * _PER_LAYER + 2
     assert set(got['grad_rel']) == set(names)
     assert got['passed'], got
-    _, amp = _check_all(cell, {'loss': 1e-3, 'grad': 0.25}, amp='amp')
+    _, amp = check_all(cell, {'loss': 1e-3, 'grad': 0.25}, amp='amp')
     assert amp['passed'], amp
 
 
@@ -411,7 +385,7 @@ def test_a_moved_rule_fails_the_comparison(rule):
     reference = reference_module()
     _MOVED[rule](reference)
     cell = dict(_toy_cell(sliding_window_size=5), reference=reference)
-    _, got = _check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
+    _, got = check_all(cell, {'loss': 1e-5, 'grad': 1e-5})
     assert not got['passed']
     worst = max(got['grad_rel'].values())
     assert worst > 1e-3, worst
@@ -420,17 +394,6 @@ def test_a_moved_rule_fails_the_comparison(rule):
         # two tensors differ by the mixer's output beside an embedding of
         # unit variance: 0.09 here, 0.6 with the embedding at 0.02)
         assert got['grad_rel']['moe_mlp_3.w_0'] > 0.05
-
-
-def _build_toy(cell, train):
-    from chipbench.harness import check
-    config = dict(cell['config'], check={'grads': []}, amp='none')
-    built = cell['builder'].build(config, cell['traffic'], train=train)
-    if not train:
-        built = cell['builder'].build(
-            dict(config, check={'grads': check.parameter_names(
-                built['main'])}), cell['traffic'], train=False)
-    return config, built
 
 
 def test_layers_differ_by_kind_scopes_regions_and_counters():
@@ -442,7 +405,7 @@ def test_layers_differ_by_kind_scopes_regions_and_counters():
     cell = _toy_cell()
     before = obs.counter('moe.lowered', path='grouped', held='4of16',
                          dispatch='index', router='own').value
-    config, built = _build_toy(cell, train=True)
+    config, built = build_toy(cell, train=True)
     assert obs.counter('moe.lowered', path='grouped', held='4of16',
                        dispatch='index', router='own').value - before == 4
     ops = built['main'].global_block().ops
@@ -464,14 +427,7 @@ def test_layers_differ_by_kind_scopes_regions_and_counters():
     regions = {op.attrs.get('recompute') for op in ops
                if op.attrs.get('recompute') is not None}
     assert len(regions) == 4
-    pool, _ = cell['generator'].make_pool(dict(cell['traffic'], pool=1),
-                                          config, 5)
-    with fluid.scope_guard(fluid.Scope()):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(built['startup'])
-        exe.run(built['main'], feed=pool[0], fetch_list=[built['loss']])
-        text = exe.lowered_hlo(built['main'], pool[0], [built['loss']],
-                               optimized=True)
+    text = decoder_toy.one_step_hlo(cell, config, built)
     window = catalog.load_module(catalog.ROOT, 'layers', 'name_scope_window')
     under_w = window.op_scopes_under(text, 'window_attention')
     under_g = window.op_scopes_under(text, 'global_attention')
@@ -523,27 +479,14 @@ def test_the_builders_rate_climbs_linearly_to_the_configurations_peak():
     steps); the token embedding starts at `embedding_initializer_range`
     and every other matrix at `initializer_range`."""
     cell = _toy_cell()
-    config = cell['config']
-    opt = config['optimizer']
+    opt = cell['config']['optimizer']
     assert (opt['learning_rate'], opt['warmup_steps']) == (4e-4, 2000)
-    with fluid.scope_guard(fluid.Scope()):
-        built = cell['builder'].build(config, cell['traffic'])
-        rate, = {op.input('LearningRate')[0]
-                 for op in built['main'].global_block().ops
-                 if op.type == 'adam'}
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(built['startup'])
-        scope = fluid.global_scope()
-        std = {n: float(np.std(np.asarray(scope.find_var(n).get_tensor())))
-               for n in ('embedding_0.w_0', 'fc_0.w_0')}
-        pool, _ = cell['generator'].make_pool(cell['traffic'], config, 3)
-        got = [float(np.asarray(exe.run(
-            built['main'], feed=pool[0],
-            fetch_list=[built['loss'], rate])[1]).reshape(-1)[0])
-            for _ in range(3)]
-        exe.close()
-    np.testing.assert_allclose(got, [4e-4 * n / 2000 for n in (1, 2, 3)],
+    np.testing.assert_allclose(decoder_toy.rates_of_training(cell, 3),
+                               [4e-4 * n / 2000 for n in (1, 2, 3)],
                                rtol=1e-5)
+    scope, _ = decoder_toy.started(cell)
+    std = {n: float(np.std(np.asarray(scope.find_var(n).get_tensor())))
+           for n in ('embedding_0.w_0', 'fc_0.w_0')}
     assert std['embedding_0.w_0'] == pytest.approx(1.0, rel=0.05)
     assert std['fc_0.w_0'] == pytest.approx(0.02, rel=0.05)
 

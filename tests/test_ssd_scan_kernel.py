@@ -21,7 +21,8 @@ from paddle_tpu.fluid.ops_impl import linear_attention_ops as la
 from paddle_tpu.ops.kernels import ssd_scan as sk
 
 from test_nemotron_h import _recurrence, _scan_inputs
-from test_qwen3_next import _grads_of, _input
+from util import (grads_of as _grads_of, input_parameter as _input,
+                  out_and_grads)
 
 
 @pytest.fixture
@@ -44,7 +45,7 @@ def interpreted(monkeypatch):
 # so two grid steps of eight (four in float32) read the same B and C and
 # hand dB and dC on in parts
 _SCANS = {
-    'ragged_two_rows': (2, 300, 4, 64, 2, 128, 128),
+    'ragged_two_rows': (2, 172, 4, 64, 2, 128, 128),
     'a_head_a_group': (1, 200, 2, 128, 2, 128, 128),
     'whole_chunks_one_group': (1, 256, 4, 64, 1, 128, 128),
     'chunk_256_ragged': (1, 300, 4, 64, 2, 128, 256),
@@ -59,16 +60,6 @@ def _op(kernel, amp, chunk=128):
         return la.ssd_scan(x, dt, a, b, c, d, chunk_size=chunk,
                            kernel=kernel)
     return op
-
-
-def _value_and_grads(fn, args, w):
-    def loss(*v):
-        y = fn(*v)
-        return jnp.sum(y * w), y
-
-    (_, y), grads = jax.value_and_grad(loss, argnums=range(len(args)),
-                                       has_aux=True)(*args)
-    return y, grads
 
 
 # every case both ways and with and without the skip at a chunk of 128;
@@ -93,9 +84,9 @@ def test_the_kernels_are_the_composition_and_the_recurrence(
     w = jnp.asarray(np.random.default_rng(9).normal(size=args[0].shape),
                     jnp.float32)
     with jax.default_matmul_precision('highest'):
-        y, got = _value_and_grads(_op(True, amp, chunk), args, w)
-        near_y, near = _value_and_grads(_op(False, amp, chunk), args, w)
-        want_y, want = _value_and_grads(
+        y, got = out_and_grads(_op(True, amp, chunk), args, w)
+        near_y, near = out_and_grads(_op(False, amp, chunk), args, w)
+        want_y, want = out_and_grads(
             _recurrence if skip else lambda *v: _recurrence(
                 *v, jnp.zeros_like(v[2])), args, w)
     assert y.dtype == jnp.float32 and y.shape == args[0].shape
@@ -279,7 +270,7 @@ def test_the_rule_chooses_on_platform_and_shape(platform, monkeypatch,
     assert after[took] - before[took] == \
         obs.counter('ssd.lowered', **label).value - lowered >= 1
     assert after[other] == before[other]
-    want_y, want = _value_and_grads(_recurrence, args, jnp.asarray(w))
+    want_y, want = out_and_grads(_recurrence, args, jnp.asarray(w))
     assert np.abs(got - want_y).max() <= 2e-5 * np.abs(want_y).max()
     for name, a, b in zip(names, grads, want):
         assert np.linalg.norm(a - b) <= 1e-4 * np.linalg.norm(b), name
