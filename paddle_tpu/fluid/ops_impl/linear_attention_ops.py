@@ -59,13 +59,18 @@ running sums is rounded as they are: at g near -5 G reaches -320 a chunk
 and a factor is off by 3e-5, which is what a float32 comparison reads on
 the decay's own parameters. The per-channel form has its own composition
 (`_intra_channel`), its own `gdn_intra` kernels (a key head a value head),
-which read the op's OWN operands cut into chunks and take q and k's l2
-norms, q's scale, the rounding to the matmuls' dtype and G's running sum
-in VMEM, forward and backward, so that XLA prepares nothing for them and
-finishes no cotangent behind them (ISSUE 56; a grid step of 8 heads in
-bf16 asks 6.21 MiB of scoped VMEM forward and 9.43 backward, 4.58 and 9.65
-at 4 heads in float32, where the per-head kernel asks 4.62 and 6.76:
-compiled for a described v5e, PR 56) and the same `gdn_scan` kernels, which scale S's rows by the chunk's [Dk] decay (a
+which read the op's OWN operands and take q and k's l2 norms, q's scale,
+the rounding to the matmuls' dtype, g's floor and G's running sum in
+VMEM, forward and backward, so that XLA prepares nothing for them and
+finishes no cotangent behind them (ISSUE 56), WHERE THE OP HOLDS THEM
+(ISSUE 58): q, k, v and g viewed [B, T, H x D], which is how the layer's
+projections leave them, the chunks cut by the kernels' index maps and the
+four large cotangents written the same way, so that neither `_to_chunks`
+nor `_from_chunks` runs on that path and the backward keeps q, k, v and g
+in that view (a grid step of 8 heads in bf16 asks 6.50 MiB of scoped VMEM
+forward and 9.79 backward, 4.58 and 9.66 at 4 heads in float32, where the
+per-head kernel asks 4.62 and 6.76: compiled for a described v5e, PR 58)
+and the same `gdn_scan` kernels, which scale S's rows by the chunk's [Dk] decay (a
 head's row of lanes turned to a column in VMEM) where they multiply by a
 scalar (2.52, 2.74 and 5.74 MiB for the three walks in bf16, 12.99 the
 reverse walk in float32): all within Mosaic's default of 16 MiB, no call
@@ -201,8 +206,11 @@ per trace,
 `gdn.intra{way=kernel|composed}` and `gdn.scan{way=kernel|composed}`
 beside it (which way each stage went), `gdn.prologue{where=kernel|xla}`
 (where q and k's norm and g's running sum are taken: in the per-channel
-kernels, or by XLA on every other path), `gdn.tokens` the B x T of the
-traced shape,
+kernels, or by XLA on every other path), `gdn.chunks{where=kernel|xla}`
+(who cuts q, k, v and g into chunks and joins their cotangents: the
+per-channel kernels' index maps on the arrays the op holds, or
+`_to_chunks` and `_from_chunks`, transposing copies of XLA's, on every
+other path), `gdn.tokens` the B x T of the traced shape,
 `ssd.lowered{chunk=, heads=, groups=}` and `ssd.tokens` likewise and
 `ssd.way{way=kernel|composed}` beside them,
 `conv1d.lowered{taps=K, act=silu|none}` (and the labels `bias=true` and
@@ -412,19 +420,21 @@ def _stage_intra(q, k, v, g, beta, cfg):
     into chunks (the padding tokens change nothing: k = 0, beta = 0,
     g = 0), then stage `gdn_intra`: the kernel where `cfg` says so, else
     `_intra`. The per-head kernel reads what this prepares (q and k
-    normalised and rounded, G summed: passes of XLA's over each array).
-    The per-channel kernels read the op's OWN operands cut into chunks and
-    do the rest in VMEM (the norm, the scale, the rounding to the matmuls'
-    dtype, G's running sum, and in the backward their pull-backs), so that
-    XLA's part of that path is `_to_chunks` and its transpose."""
+    normalised and rounded, G summed, all cut into chunks: passes of XLA's
+    over each array). The per-channel kernels read the op's OWN operands
+    where they lie, [B, T, H, D], and do the rest in VMEM (the chunks by
+    their index maps, g's floor where `cfg` carries it, the norm, the
+    scale, the rounding to the matmuls' dtype, G's running sum, and in
+    the backward their pull-backs), so that XLA's part of that path is
+    beta's layout (and one padding copy an operand where T is no whole
+    number of chunks)."""
     chunk, scale, l2norm, eps, kernel = cfg[:5]
     dtype = v.dtype
-    if kernel and g.ndim == 4:      # a decay a channel: the raw operands
-        q, k, v, g, beta = (
-            _to_chunks(x, chunk) for x in (
-                q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32)))
-        return intra_kernel.gated_delta_intra(
-            q, k, v, g, beta, False, norm=(l2norm, eps, scale))
+    if kernel and g.ndim == 4:      # a decay a channel: the raw operands,
+        # where they lie (the kernels' index maps cut the chunks)
+        return intra_kernel.gated_delta_intra_tokens(
+            q, k, v, g, beta, False, norm=(l2norm, eps, scale),
+            floor=cfg[6] if len(cfg) > 6 else None)
     qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
     if l2norm:
         qf, kf = (x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
@@ -495,7 +505,14 @@ def _chunked(q, k, v, g, beta, cfg):
 
 
 def _chunked_fwd(q, k, v, g, beta, cfg):
-    return _chunked(q, k, v, g, beta, cfg), (q, k, v, g, beta)
+    res = (q, k, v, g)
+    if cfg[4] and g.ndim == 4:
+        # kept as the per-channel kernels read them, [B, T, H x D], which
+        # is how the projections left them: a [B, T, H, D] array behind
+        # the backward's barrier is tiled by (H, D) on the TPU, a copy to
+        # make and one more to read by token
+        res = tuple(x.reshape(x.shape[:2] + (-1,)) for x in res)
+    return _chunked(q, k, v, g, beta, cfg), res + (beta,)
 
 
 def _recompute_after(res, g):
@@ -508,6 +525,8 @@ def _recompute_after(res, g):
 
 def _chunked_bwd(cfg, res, do):
     (q, k, v, g, beta), do = _recompute_after(res, do)
+    if q.ndim == 3:                 # kept [B, T, H x D]
+        q, k, v, g = (x.reshape(beta.shape + (-1,)) for x in (q, k, v, g))
     with jax.named_scope('gdn_intra'):
         xs, pull = jax.vjp(
             lambda *a: _stage_intra(*a, cfg), q, k, v, g, beta)
@@ -552,6 +571,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk_size=64, scale=None,
     dk = q.shape[3]
     scale = dk ** -0.5 if scale is None else float(scale)
     chunk = _chunk_of(chunk_size, q.shape[1])
+    floor = None        # what stage `gdn_intra` has still to hold g to
     if g.ndim == 4:
         reach = (_channel_block(chunk) + 1) // 2     # from a block's middle
         if gate_floor is None or not \
@@ -562,12 +582,16 @@ def gated_delta_rule(q, k, v, g, beta, chunk_size=64, scale=None,
                 'exponentiated at once), got %r'
                 % (reach, -_MAX_EXPONENT, chunk, gate_floor))
         # held to its floor; a g AT the floor (a saturated gate) keeps its
-        # whole gradient, which `jnp.maximum` would halve
+        # whole gradient, which `jnp.maximum` would halve. The per-channel
+        # kernels hold it as they load it (a pass over g less each way)
         g = g.astype(jnp.float32)
-        g = jnp.where(g < float(gate_floor), float(gate_floor), g)
+        if kernel:
+            floor = float(gate_floor)
+        else:
+            g = jnp.where(g < float(gate_floor), float(gate_floor), g)
     return _chunked(q, k, v, g, beta,
                     (chunk, scale, bool(qk_l2norm), float(l2norm_eps),
-                     bool(kernel), bool(scan_kernel)))
+                     bool(kernel), bool(scan_kernel), floor))
 
 
 @register('gated_delta_rule')
@@ -590,9 +614,13 @@ def _gated_delta_rule(ins, attrs, ctx):
     obs.counter('gdn.intra',                                 # trace time
                 way='kernel' if kernel else 'composed').inc()
     # where q and k's norm and g's running sum are taken: in the
-    # per-channel kernels' VMEM, or by XLA ahead of the stage
-    obs.counter('gdn.prologue',                              # trace time
-                where='kernel' if kernel and channel else 'xla').inc()
+    # per-channel kernels' VMEM, or by XLA ahead of the stage; and who cuts
+    # the tokens' operands into chunks: those kernels' index maps, where
+    # the op holds them, or `_to_chunks` (copies of XLA's). One answer
+    # today; the per-head kernels may take the second before the first
+    where = 'kernel' if kernel and channel else 'xla'
+    obs.counter('gdn.prologue', where=where).inc()           # trace time
+    obs.counter('gdn.chunks', where=where).inc()             # trace time
     # stage `gdn_scan`: the Pallas kernels read what that kernel hands over
     scan = kernel and delta_scan.usable(
         cut, q.shape[3], v.shape[3], v.shape[2], v.dtype)

@@ -43,12 +43,25 @@ What the two kernel pairs read, by the rank of g. With a decay a HEAD
 normalised, q scaled, both rounded to `dtype`, G summed. With a decay a
 CHANNEL (`_fwd_kernel_channel`, `_bwd_kernel_channel`; G is [C, Dk] and D
 stays inside the products, linear_attention_ops `_intra_channel`) the
-kernels read the OP's own operands cut into chunks (ISSUE 56: on a
-[8192, 32, 128] array each pass XLA makes to prepare an operand is 0.2 to
-0.4 GB, and they were 29 GB a step): q and k as the op holds them, g held
-to its floor and NOT summed, beta. In VMEM, a head at a time: the l2 norm
-in float32 (`_unit`), q's scale, the rounding to `dtype` where
-`_stage_intra` rounds on the other paths, and G as the product of the
+kernels read the OP's own operands (ISSUE 56: on a [8192, 32, 128] array
+each pass XLA makes to prepare an operand is 0.2 to 0.4 GB, and they were
+29 GB a step) WHERE THE OP HOLDS THEM (ISSUE 58: `_call_channel`): q, k, v
+and g viewed [B, T, H x D], which is how a layer's projections and
+convolutions leave them, a block (1, 64, heads x 128) at (row, chunk,
+step of heads) whose lanes the bodies slice by head (`_head`: whole lane
+tiles). The DMA's strided read is what `_to_chunks` did as a transposing
+copy through HBM, and the backward writes dq, dk, dv and dg through the
+same blocks, so no chunked copy of a tokens' operand exists (they were
+19.2 GB a step; the kernels alone run as before, 2.44 ms forward and 5.24
+backward a layer of `ling3flash_s8192` in bf16: my chip run, PR 58). q and
+k come as the op holds them, g NOT summed and, where `floor` says so, not
+yet held to its floor (the bodies hold it as they load it and hand back
+no gradient where the raw g lay under it), beta, the one operand XLA
+still lays out by chunk ([B, T, H] float32). What the scan reads (W, U,
+Qg, Kd, P, T, G's last row) stays [N x B, H, C, .]. In VMEM, a head at
+a time: the l2 norm in float32 (`_unit`), q's scale, the rounding to
+`dtype` where `_stage_intra` rounds on the other paths, and G as the
+product of the
 lower triangle of ones with g at float32 precision (`_ones_below`: the
 ones are exact in bf16 and the MXU adds in float32, so each of the 64 sums
 is rounded about once; a second product with the triangle transposed turns
@@ -71,7 +84,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ['gated_delta_intra', 'usable', 'HEADS']
+__all__ = ['gated_delta_intra', 'gated_delta_intra_tokens', 'usable',
+           'HEADS']
 
 # heads a grid step at 2-byte operands (tools/bench_gated_delta_intra.py
 # --sweep; docs/perf.md has the rows); 4-byte operands take half
@@ -329,30 +343,47 @@ def _ones_below(c):
     return (row >= col).astype(jnp.float32)
 
 
-def _channel_chunks(q_ref, k_ref, v_ref, g_ref, beta_ref, dtype, norm):
-    """`_chunks` for a decay a channel, from the OP's operands: q, k
-    [C, Dk] not normalised, g [C, Dk] float32 held to its floor and not
-    summed, beta [1, C]; a key head a value head. `norm` (qk_l2norm, eps,
-    q's scale). q and k are normalised in float32, q scaled, both rounded
-    to `dtype` where `_stage_intra` rounds them on the other paths and
-    widened again; G is the running sum of g's rows as a product with the
+def _head(ref, h, heads):
+    """Where head h of a grid step's `heads` lies in a block
+    (1, C, heads x D) of an array the op holds, [B, T, H x D]: whole lane
+    tiles of the chunk's rows, so that the slice moves nothing (ssd_scan.py
+    takes a head's lanes so)."""
+    d = ref.shape[2] // heads
+    return (0, slice(None), slice(h * d, (h + 1) * d))
+
+
+def _channel_chunks(q_ref, k_ref, v_ref, g_ref, beta_ref, dtype, norm,
+                    floor):
+    """`_chunks` for a decay a channel, from the OP's operands where the op
+    holds them: a head's q, k [C, Dk] not normalised, v [C, Dv] and g
+    [C, Dk] float32 not summed are the head's lanes of a (1, C, heads x D)
+    block (`_head`), beta [1, C]; a key head a value head. `norm`
+    (qk_l2norm, eps, q's scale); `floor`: the bound g is held to here
+    (None: the caller has held it). q and k are normalised in float32, q
+    scaled, both rounded to `dtype` where `_stage_intra` rounds them on
+    the other paths and widened again;
+    G is the running sum of g's rows as a product with the
     triangle of ones at float32 precision (the MXU takes g in three bf16
     pieces, the ones are exact and the accumulator is float32: each sum
     is rounded about once, where a chain of 64 adds rounds 63 times)."""
     l2norm, eps, scale = norm
-    c = q_ref.shape[2]
+    c, n = q_ref.shape[1], beta_ref.shape[1]
     row, col = _iotas(c)
     eye = row == col
     ones = _ones_below(c)
     heads = []
-    for h in range(v_ref.shape[1]):
-        qn, q_inv = _unit(q_ref[0, h], l2norm, eps, scale)
-        kn, k_inv = _unit(k_ref[0, h], l2norm, eps)
+    for h in range(n):
+        qn, q_inv = _unit(q_ref[_head(q_ref, h, n)], l2norm, eps, scale)
+        kn, k_inv = _unit(k_ref[_head(k_ref, h, n)], l2norm, eps)
         qf, kf = (x.astype(dtype).astype(jnp.float32) for x in (qn, kn))
-        g = _dot(ones, g_ref[0, h], 'nn', jnp.float32)        # G
+        g = g_ref[_head(g_ref, h, n)]
+        if floor is not None:       # held to its floor as it is loaded
+            g = jnp.where(g < floor, floor, g)
+        g = _dot(ones, g, 'nn', jnp.float32)                  # G
         kk, qk, rows, kept = _channel_scores(qf, kf, g, dtype)
         heads.append(dict(
-            qf=qf, kf=kf, vf=v_ref[0, h].astype(jnp.float32), g=g, kk=kk,
+            qf=qf, kf=kf,
+            vf=v_ref[_head(v_ref, h, n)].astype(jnp.float32), g=g, kk=kk,
             qk=qk, rows=rows, kept=kept, a0=jnp.where(row > col, kk, 0.0),
             beta=_column(beta_ref[0, h], eye), e_g=jnp.exp(g),
             e_last=jnp.exp(g[c - 1:c] - g), q_inv=q_inv, k_inv=k_inv))
@@ -360,11 +391,12 @@ def _channel_chunks(q_ref, k_ref, v_ref, g_ref, beta_ref, dtype, norm):
 
 
 def _fwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, beta_ref, w_ref, u_ref,
-                        qg_ref, kd_ref, p_ref, last_ref, *rest, dtype, norm):
-    c = q_ref.shape[2]
+                        qg_ref, kd_ref, p_ref, last_ref, *rest, dtype, norm,
+                        floor):
+    c = q_ref.shape[1]
     row, col = _iotas(c)
     heads = _channel_chunks(q_ref, k_ref, v_ref, g_ref, beta_ref, dtype,
-                            norm)
+                            norm, floor)
     solved = _solve([x['a0'] * x['beta'] for x in heads])
     for h, (x, t) in enumerate(zip(heads, solved)):
         kf = x['kf']
@@ -384,15 +416,17 @@ def _fwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, beta_ref, w_ref, u_ref,
 
 def _bwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, dw_ref,
                         du_ref, dqg_ref, dkd_ref, dp_ref, dlast_ref, dq_ref,
-                        dk_ref, dv_ref, dg_ref, dbeta_ref, *, dtype, norm):
+                        dk_ref, dv_ref, dg_ref, dbeta_ref, *, dtype, norm,
+                        floor):
     f32 = jnp.float32
-    c, dk = q_ref.shape[2:]
+    c, n = q_ref.shape[1], beta_ref.shape[1]
+    dk = q_ref.shape[2] // n
     row, col = _iotas(c)
     eye, strict = row == col, row > col
     rowi = lax.broadcasted_iota(jnp.int32, (c, dk), 0)
     ones = _ones_below(c)
     heads = _channel_chunks(q_ref, k_ref, v_ref, g_ref, beta_ref, dtype,
-                            norm)
+                            norm, floor)
     for h, x in enumerate(heads):
         x['t'] = t_ref[0, h]
         x['g_w'], x['g_u'] = dw_ref[0, h], du_ref[0, h]
@@ -414,7 +448,7 @@ def _bwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, dw_ref,
             dqg_ref, dkd_ref, dp_ref))
         d_vb = _dot(t, x['g_u'], 'tn', dtype)
         d_kb = _dot(t, x['g_w'], 'tn', dtype)
-        dv_ref[0, h] = (d_vb * beta).astype(dtype)
+        dv_ref[_head(dv_ref, h, n)] = (d_vb * beta).astype(dtype)
         d_beta = jnp.sum(d_a * x['a0'], axis=1, keepdims=True) \
             + jnp.sum(d_vb * vf, axis=1, keepdims=True) \
             + jnp.sum(d_kb * kf * e_g, axis=1, keepdims=True)
@@ -453,14 +487,20 @@ def _bwd_kernel_channel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, dw_ref,
             d_start = d_start - jnp.sum(
                 d_rows[lo:lo + _BLOCK], axis=0, keepdims=True)
             d_g = d_g + jnp.where(rowi == lo + _MIDDLE, d_start, 0.0)
-        # g's: the sum of G's from each row to the chunk's last
-        dg_ref[0, h] = _dot(ones, d_g, 'tn', f32)
-        # q's and k's as the op holds them: through the scale and the norm
-        # in float32 (the rounding to `dtype` passes a cotangent as it is)
-        dq_ref[0, h] = _unit_pull(dq + d_qe * rows, q_ref[0, h], x['q_inv'],
-                                  norm[2]).astype(dq_ref.dtype)
-        dk_ref[0, h] = _unit_pull(dkey + d_ke * rows, k_ref[0, h],
-                                  x['k_inv']).astype(dk_ref.dtype)
+        # g's: the sum of G's from each row to the chunk's last; none
+        # where the raw g lay UNDER its floor (one AT it keeps the whole)
+        d_g = _dot(ones, d_g, 'tn', f32)
+        if floor is not None:
+            d_g = jnp.where(g_ref[_head(g_ref, h, n)] < floor, 0.0, d_g)
+        dg_ref[_head(dg_ref, h, n)] = d_g
+        # q's and k's as the op holds them and where: through the scale and
+        # the norm in float32 (the rounding to `dtype` passes a cotangent
+        # as it is)
+        at = _head(q_ref, h, n)
+        dq_ref[at] = _unit_pull(dq + d_qe * rows, q_ref[at], x['q_inv'],
+                                norm[2]).astype(dq_ref.dtype)
+        dk_ref[at] = _unit_pull(dkey + d_ke * rows, k_ref[at],
+                                x['k_inv']).astype(dk_ref.dtype)
 
 
 def _heads(hv, rep, dtype):
@@ -491,6 +531,32 @@ def _call(kernel, ins, outs, heads, interpret):
         out_shape=outs, interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel')))(*ins)
+
+
+def _call_channel(kernel, ins, outs, heads, interpret):
+    """One grid step a row, a chunk and `heads` heads, for the per-channel
+    kernels. An array of rank 3 is the OP's, [B, T, H x D], T whole chunks:
+    its block is the chunk's tokens by the step's heads' lanes, which the
+    index map cuts out where they lie (the DMA's strided read or write;
+    no chunked copy is made of it). One of rank 4 is the scan's or beta's,
+    [N x B, H, ., .], chunk n of row b at n x B + b."""
+    bsz = ins[0].shape[0]
+    rows, h, _, c = ins[4].shape                # beta [N x B, H, 1, C]
+
+    def specs(shapes):
+        return [pl.BlockSpec((1, c, heads * s[2] // h),
+                             lambda b, n, j: (b, n, j)) if len(s) == 3 else
+                pl.BlockSpec((1, heads) + s[2:],
+                             lambda b, n, j: (n * bsz + b, j, 0, 0))
+                for s in shapes]
+
+    return pl.pallas_call(
+        kernel, grid=(bsz, rows // bsz, h // heads),
+        in_specs=specs([a.shape for a in ins]),
+        out_specs=specs([o.shape for o in outs]),
+        out_shape=outs, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'parallel', 'parallel')))(*ins)
 
 
 def _flat(x):
@@ -546,57 +612,63 @@ def _intra_bwd(interpret, heads, res, g):
 _intra.defvjp(_intra_fwd, _intra_bwd)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=('interpret', 'heads', 'solved', 'norm'))
-def _forward_channel(q, k, v, g, beta, *, interpret, heads, solved, norm):
-    """`_forward` for a decay a channel, from the op's operands: q, k as
-    the op holds them, g [rows, H, C, Dk] float32 not summed, beta
-    [rows, H, 1, C] float32. Beside W, U, Qg, Kd, P (and T) it writes G's
-    last row [rows, H, 1, Dk] float32, the log of the chunk's decay."""
+@functools.partial(jax.jit, static_argnames=('interpret', 'heads', 'solved',
+                                             'norm', 'floor'))
+def _forward_channel(q, k, v, g, beta, *, interpret, heads, solved, norm,
+                     floor):
+    """`_forward` for a decay a channel, from the op's operands where the
+    op holds them: q, k [B, T, H x Dk] and v [B, T, H x Dv] as they are, g
+    [B, T, H x Dk] float32 not summed, T whole chunks, beta
+    [rows, H, 1, C] float32, rows = N x B. Beside W, U, Qg, Kd, P (and T),
+    [rows, H, C, .] for the scan, it writes G's last row [rows, H, 1, Dk]
+    float32, the log of the chunk's decay."""
     dtype = v.dtype
     like = jax.ShapeDtypeStruct
-    scores = v.shape[:3] + (v.shape[2],)
-    outs = [like(q.shape, dtype), like(v.shape, jnp.float32),
-            like(q.shape, dtype), like(q.shape, dtype), like(scores, dtype),
-            like(q.shape[:2] + (1,) + q.shape[3:], jnp.float32)]
+    rows, h, _, c = beta.shape
+    keys, scores = (rows, h, c, q.shape[2] // h), (rows, h, c, c)
+    outs = [like(keys, dtype), like((rows, h, c, v.shape[2] // h),
+                                    jnp.float32),
+            like(keys, dtype), like(keys, dtype), like(scores, dtype),
+            like((rows, h, 1, keys[3]), jnp.float32)]
     if solved:
         outs.append(like(scores, jnp.float32))
-    return tuple(_call(
-        functools.partial(_fwd_kernel_channel, dtype=dtype, norm=norm),
+    return tuple(_call_channel(
+        functools.partial(_fwd_kernel_channel, dtype=dtype, norm=norm,
+                          floor=floor),
         (q, k, v, g, beta), outs, heads, interpret))
 
 
-@functools.partial(jax.jit, static_argnames=('interpret', 'heads', 'norm'))
-def _backward_channel(res, cts, *, interpret, heads, norm):
+@functools.partial(jax.jit, static_argnames=('interpret', 'heads', 'norm',
+                                             'floor'))
+def _backward_channel(res, cts, *, interpret, heads, norm, floor):
     outs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in res[:5]]
-    return tuple(_call(
+    return tuple(_call_channel(
         functools.partial(_bwd_kernel_channel, dtype=res[2].dtype,
-                          norm=norm),
+                          norm=norm, floor=floor),
         tuple(res) + tuple(cts), outs, heads, interpret))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _intra_channel(q, k, v, g, beta, interpret, heads, norm):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _intra_channel(q, k, v, g, beta, interpret, heads, norm, floor):
     return _forward_channel(q, k, v, g, beta, interpret=interpret,
-                            heads=heads, solved=False, norm=norm)
+                            heads=heads, solved=False, norm=norm, floor=floor)
 
 
-def _intra_channel_fwd(q, k, v, g, beta, interpret, heads, norm):
+def _intra_channel_fwd(q, k, v, g, beta, interpret, heads, norm, floor):
     outs = _forward_channel(q, k, v, g, beta, interpret=interpret,
-                            heads=heads, solved=True, norm=norm)
+                            heads=heads, solved=True, norm=norm, floor=floor)
     return outs[:6], (q, k, v, g, beta, outs[6])
 
 
-def _intra_channel_bwd(interpret, heads, norm, res, cts):
+def _intra_channel_bwd(interpret, heads, norm, floor, res, cts):
     return _backward_channel(res, cts, interpret=interpret, heads=heads,
-                             norm=norm)
+                             norm=norm, floor=floor)
 
 
 _intra_channel.defvjp(_intra_channel_fwd, _intra_channel_bwd)
 
 
-def gated_delta_intra(q, k, v, g_sum, beta, interpret, heads=None,
-                      norm=None):
+def gated_delta_intra(q, k, v, g_sum, beta, interpret, heads=None):
     """q, k [N, B, Hk, C, Dk] (normalised, q scaled), v [N, B, Hv, C, Dv]
     in the matmuls' dtype, g_sum, beta [N, B, Hv, C] float32, g_sum the
     running sum of g inside each chunk. Hk divides Hv and key head h
@@ -607,26 +679,47 @@ def gated_delta_intra(q, k, v, g_sum, beta, interpret, heads=None,
     repeated key heads, W, Qg, Kd, P in the matmuls' dtype and U in
     float32. Differentiable in all five. `heads` overrides the value
     heads a grid step takes (the sweep's door: a multiple of Hv / Hk that
-    divides Hv).
-
-    A decay a CHANNEL (`g_sum` of rank 5, [N, B, Hv, C, Dk], Hk = Hv) takes
-    the OP's operands: `g_sum` is then g itself, held to its floor and NOT
-    summed, q and k are not normalised and `norm` (qk_l2norm, eps, q's
-    scale; None: no norm, a scale of 1) says what the kernels do to them
-    in VMEM before they round them to the matmuls' dtype. The chunk's
-    decay is [N, B, Hv, Dk], and the five gradients are those of the raw
-    q, k, v, g and beta."""
+    divides Hv). A decay a channel is `gated_delta_intra_tokens`."""
     lead, hv = q.shape[:2], v.shape[2]
     heads = heads or _heads(hv, hv // q.shape[2], v.dtype)
-    if g_sum.ndim == 5:
-        l2norm, eps, scale = norm or (False, 0.0, 1.0)
-        outs = _intra_channel(
-            _flat(q), _flat(k), _flat(v), _flat(g_sum.astype(jnp.float32)),
-            _flat(beta.astype(jnp.float32)[..., None, :]), interpret, heads,
-            (bool(l2norm), float(eps), float(scale)))
-        outs = tuple(o.reshape(lead + o.shape[1:]) for o in outs)
-        return outs[:5] + (jnp.exp(outs[5][..., 0, :]),)
     gb = jnp.stack([g_sum, beta], axis=-2).astype(jnp.float32)
     outs = _intra(_flat(q), _flat(k), _flat(v), _flat(gb), interpret, heads)
     return tuple(o.reshape(lead + o.shape[1:]) for o in outs) \
         + (jnp.exp(g_sum[..., -1]),)
+
+
+def gated_delta_intra_tokens(q, k, v, g, beta, interpret, heads=None,
+                             norm=None, floor=None):
+    """The stage with a decay a CHANNEL, from the OP's operands where the
+    op holds them: q, k [B, T, H, Dk] NOT normalised, v [B, T, H, Dv] in
+    the matmuls' dtype, g [B, T, H, Dk] NOT summed, beta [B, T, H]; a key
+    head a value head. `norm` (qk_l2norm, eps, q's scale; None: no norm, a
+    scale of 1) says what the kernels do to q and k in VMEM before they
+    round them to the matmuls' dtype; `floor` the bound the kernels hold g
+    to as they load it (None: the caller has), handing back no gradient
+    where the raw g lay under it and the whole of it AT it. The kernels'
+    index maps cut the chunks of 64 out of the [B, T, H x D] views, so a T
+    of whole chunks is read in place and the four large cotangents are
+    written in place; any other T is padded first (tokens that change
+    nothing: zeros), one copy an array. Only beta ([B, T, H] float32) is
+    laid out by chunk in XLA. Returns (W, U, Qg, Kd, P, decay of the
+    chunk [N, B, H, Dk]) as `gated_delta_intra`, [N, B, H, C, .] for the
+    scan; differentiable in the five raw operands."""
+    bsz, t, h = beta.shape
+    pad = -t % _CHUNK
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    n = (t + pad) // _CHUNK
+    l2norm, eps, scale = norm or (False, 0.0, 1.0)
+    outs = _intra_channel(
+        *(x.reshape(bsz, t + pad, -1)
+          for x in (q, k, v, g.astype(jnp.float32))),
+        beta.astype(jnp.float32).reshape(bsz, n, _CHUNK, h).transpose(
+            1, 0, 3, 2).reshape(n * bsz, h, 1, _CHUNK),
+        interpret, heads or _heads(h, 1, v.dtype),
+        (bool(l2norm), float(eps), float(scale)),
+        None if floor is None else float(floor))
+    outs = tuple(o.reshape((n, bsz) + o.shape[1:]) for o in outs)
+    return outs[:5] + (jnp.exp(outs[5][..., 0, :]),)

@@ -197,25 +197,31 @@ def test_gated_delta_intra_compiles_for_v5e(one_chip, dtype, gate):
     Mosaic slices no iota and broadcasts no [1, 1] both ways, which the
     interpreter lets pass (AOT, PR 34). `channel`: ling3flash_s8192's,
     a decay a channel and a key head a value head, from the op's own
-    operands (g [.., 64, 128] not summed, q and k not normalised: the l2
+    operands WHERE THE OP HOLDS THEM (ISSUE 58: one row of 8192 tokens,
+    [1, 8192, 32, 128]; a block (1, 64, heads x 128) whose lanes the
+    bodies slice by head; g not summed, q and k not normalised: the l2
     norm, q's scale and the running sum in VMEM, forward and backward; the
     sixth output G's last row and its cotangent the backward's operand),
     within Mosaic's default 16 MiB with no limit stated."""
-    from paddle_tpu.ops.kernels.gated_delta_intra import gated_delta_intra
+    from paddle_tpu.ops.kernels import gated_delta_intra as kernel
     dt = jnp.dtype(dtype)
     channel = gate == 'channel'
-    keys = jax.ShapeDtypeStruct((128, 1, 32 if channel else 16, 64, 128), dt,
-                                sharding=one_chip)
-    x = jax.ShapeDtypeStruct((128, 1, 32, 64, 128), dt, sharding=one_chip)
-    gate = jax.ShapeDtypeStruct((128, 1, 32, 64), jnp.float32,
-                                sharding=one_chip)
-    g_sum = jax.ShapeDtypeStruct((128, 1, 32, 64, 128), jnp.float32,
-                                 sharding=one_chip) if channel else gate
-    norm = dict(norm=(True, 1e-6, 128 ** -0.5)) if channel else {}
+    like = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    if channel:
+        keys = x = like((1, 8192, 32, 128), dt)
+        g_sum = like((1, 8192, 32, 128), jnp.float32)
+        gate = like((1, 8192, 32), jnp.float32)
+        stage = functools.partial(kernel.gated_delta_intra_tokens,
+                                  norm=(True, 1e-6, 128 ** -0.5))
+    else:
+        keys = like((128, 1, 16, 64, 128), dt)
+        x = like((128, 1, 32, 64, 128), dt)
+        g_sum = gate = like((128, 1, 32, 64), jnp.float32)
+        stage = kernel.gated_delta_intra
 
     def loss(q, k, v, g_sum, beta):
         return sum(jnp.sum(o.astype(jnp.float32)) for o in
-                   gated_delta_intra(q, k, v, g_sum, beta, False, **norm))
+                   stage(q, k, v, g_sum, beta, False))
 
     compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
         keys, keys, x, g_sum, gate).compile()
@@ -227,13 +233,19 @@ def test_gated_delta_intra_compiles_for_v5e(one_chip, dtype, gate):
     assert len(used) == 2 and max(used) < 12 * 2 ** 20, used
 
 
-def _delta_rule_vjp_account(one_chip, dtype, channel):
+def _delta_rule_vjp_rows(one_chip, dtype, channel):
     """`jax.vjp` of the whole op at a layer's shape (one row of 8192
     tokens, 32 value heads of 128; both stages as their kernels), compiled
     under the scope a Program gives it, and what
     tools/hlo_scope_bytes.py counts of XLA's own instructions there:
-    (their bytes, how many are a `reduce-window`, how many an `rsqrt`).
-    The Mosaic calls' lines stay out: their bodies are serialized there."""
+    (a row an instruction that moves bytes, the scope's marks). The
+    Mosaic calls' lines stay out: their bodies are serialized there. With
+    a decay a channel q, k, v and g come and their cotangents go as the
+    model's projections and convolutions hold them, [1, 8192, 32 x 128],
+    through the model's `reshape` (an op of its own, outside the scope):
+    on the TPU a [.., 32, 128] array is tiled by (32, 128) and one
+    [.., 4096] by (tokens, 4096), so the view is where the operands
+    are."""
     import importlib.util
     from paddle_tpu.fluid.ops_impl import linear_attention_ops as la
     spec = importlib.util.spec_from_file_location(
@@ -243,24 +255,39 @@ def _delta_rule_vjp_account(one_chip, dtype, channel):
     spec.loader.exec_module(tool)
     dt = jnp.dtype(dtype)
     like = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    keys = like((1, 8192, 32 if channel else 16, 128), dt)
-    v = like((1, 8192, 32, 128), dt)
+    keys = like((1, 8192, 32 * 128) if channel else (1, 8192, 16, 128), dt)
+    v = like((1, 8192, 32 * 128) if channel else (1, 8192, 32, 128), dt)
     beta = like((1, 8192, 32), jnp.float32)
-    g = like((1, 8192, 32, 128), jnp.float32) if channel else beta
+    g = like((1, 8192, 32 * 128), jnp.float32) if channel else beta
     out = like((1, 8192, 32, 128), jnp.float32)
 
     def both(do, *a):
+        by_head = a if not channel else tuple(
+            x.reshape(x.shape[:2] + (32, 128)) for x in a[:4]) + a[4:]
         with jax.named_scope('gated_delta_rule_0'):
             o, pull = jax.vjp(lambda *a: la.gated_delta_rule(
                 *a, chunk_size=64, qk_l2norm=True, kernel=True,
-                scan_kernel=True, gate_floor=-5.0 if channel else None), *a)
-            return (o,) + pull(do)
+                scan_kernel=True, gate_floor=-5.0 if channel else None),
+                *by_head)
+            cts = pull(do)
+        return (o,) + tuple(c.reshape(x.shape) for c, x in zip(cts, a))
 
     text = jax.jit(both).lower(out, keys, keys, v, g, beta).compile() \
         .as_text()
     # gdn_intra forward, forward again with T and backward; three walks
-    assert len(_mosaic_calls(text)) == 6
-    rows, marks = tool.account(text, r'gated_delta_rule_\d+')
+    calls = _mosaic_calls(text)
+    assert len(calls) == 6
+    # none states a limit, and all are under Mosaic's default
+    assert all('"scoped_memory_configs":[]' in l for l in calls)
+    assert max(_scoped_vmem(calls)) < (12 if dtype == 'bfloat16'
+                                       else 14) * 2 ** 20
+    return tool.account(text, r'gated_delta_rule_\d+')
+
+
+def _delta_rule_vjp_account(one_chip, dtype, channel):
+    """(XLA's bytes in the op's scope, how many of its instructions are a
+    `reduce-window`, how many an `rsqrt`)."""
+    rows, marks = _delta_rule_vjp_rows(one_chip, dtype, channel)
     return sum(r[3] for r in rows), marks['reduce_window'], marks['rsqrt']
 
 
@@ -269,14 +296,31 @@ def test_per_channel_delta_rule_leaves_xla_no_sum_and_no_norm(one_chip,
                                                               dtype):
     """ling3flash_s8192's op, forward and backward: the per-channel
     kernels take g's running sum and q and k's l2 norms in VMEM, so XLA's
-    part holds no `reduce-window` (jnp.cumsum on the TPU) and no `rsqrt`,
-    and what it moves is the chunks' copies: 2.71 GB an op in bf16 and
-    3.92 in float32 where the parent's passes over [8192, 32, 128] arrays
-    made it 6.23 and 7.97 (AOT, PR 56; ISSUE 56 counted 29 GB a step of
-    such passes by hand)."""
+    part holds no `reduce-window` (jnp.cumsum on the TPU) and no `rsqrt`
+    (PR 56: 6.23 GB an op in bf16 and 7.97 in float32 -> 2.71 and 3.92,
+    the chunks' copies), and since ISSUE 58, whose kernels' index maps cut
+    the chunks out of the arrays the op holds and hold g to its floor,
+    no copy either: beta's layout, the chunk decay's `exp` and its
+    cotangent, 0.03 GB an op either way (AOT, PR 58)."""
     moved, sums, norms = _delta_rule_vjp_account(one_chip, dtype, True)
     assert sums == 0 and norms == 0
-    assert moved < (3.4e9 if dtype == 'bfloat16' else 4.8e9), moved
+    assert moved < 1.0e9, moved
+
+
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+def test_per_channel_delta_rule_leaves_xla_no_chunk_copy(one_chip, dtype):
+    """The same op's scope holds no instruction of XLA's that reads or
+    writes an array of heads of 128 (a `[.., 64, 128]` chunked operand, a
+    `[.., 32, 128]` or `[.., 4096]` one): no `transpose`, `copy`,
+    `reshape` or select of q, k, v, g or of a cotangent is left between
+    the op's neighbours and its six Mosaic calls, whose scoped VMEM is
+    under 12 MiB in bf16 (14 in float32: the reverse walk's) with no limit
+    stated. What is left is beta's and the chunk decay's, [.., 32] and
+    [128, 1, 32, 128]."""
+    rows, _ = _delta_rule_vjp_rows(one_chip, dtype, True)
+    assert rows and not [r for r in rows if r[3] > 2 ** 24], rows
+    assert not [r for r in rows if r[1] in ('transpose', 'copy')
+                and r[3] > 2 ** 22], rows
 
 
 def test_per_head_delta_rule_still_sums_and_norms_in_xla(one_chip):

@@ -3,7 +3,12 @@ the kernel bodies in the interpreter against the composition they replace
 on the TPU (`linear_attention_ops._intra` and `jax.vjp` of it), the whole
 op through the kernel against the token-by-token recurrence, and the
 rule's choice between the two. Heads of 128, which the kernel asks for;
-short rows and few heads keep the interpreter cheap. On the CPU."""
+short rows and few heads keep the interpreter cheap. On the CPU.
+With a decay a channel the kernels enter through the TOKEN layout (ISSUE
+58): q, k, v and g [B, T, H, D] as the op holds them, the chunks cut by
+the kernels' index maps, against `_intra_channel` behind `_to_chunks`."""
+import re
+
 import numpy as np
 import pytest
 
@@ -32,6 +37,11 @@ def interpreted(monkeypatch):
         gdi, 'gated_delta_intra',
         lambda q, k, v, g_sum, beta, interpret, heads=None, **kw: real(
             q, k, v, g_sum, beta, True, heads, **kw))
+    tokens = gdi.gated_delta_intra_tokens
+    monkeypatch.setattr(
+        gdi, 'gated_delta_intra_tokens',
+        lambda q, k, v, g, beta, interpret, heads=None, **kw: tokens(
+            q, k, v, g, beta, True, heads, **kw))
     monkeypatch.setattr(
         gds, 'gated_delta_scan',
         lambda xs, dtype, interpret: scan(xs, dtype, True))
@@ -73,7 +83,7 @@ def _stage(kernel, dtype, l2norm=True):
     kernel reads those inputs cut into chunks and nothing else (the norm,
     the scale and G's running sum are its own), the composition stands
     behind `_stage_intra`'s prologue in XLA."""
-    cfg = (64, 128 ** -0.5, l2norm, 1e-6, kernel)
+    cfg = (64, 128 ** -0.5, l2norm, 1e-6, kernel)       # no floor to hold
 
     def stage(*args):
         w, u, qg, kd, p, decay = la._stage_intra(*args, cfg)
@@ -142,6 +152,102 @@ def test_kernel_is_the_composition(rows, dtype, gates, interpreted):
         hk = hv
     _compare_stages(op_inputs(len(rows) + len(gates), t, hk, hv, gates,
                               dtype), dtype)
+
+
+# (rows, T, heads) of a decay a channel through the token layout (ISSUE
+# 58): two rows of two chunks (chunk n of row b is the scan's n x B + b);
+# 32 heads, the cell's, four grid steps of eight (eight of four in
+# float32); 6 heads, one step of six (two of three in float32: blocks of
+# 768 and 384 lanes); 2 heads, fewer than a step takes.
+# The draw is named: the float32 comparison holds every element to its OWN
+# 1e-5, and one seed in four (at 32 heads 4 and 8 of 1 to 8) has 2 to 6 of
+# W's 262144 elements, of 1e-23 in rows of 1e-20, off the composition by up
+# to 1.8 times that
+LAYOUTS = {'two_rows': (2, 128, 2, 8), 'heads_32': (1, 64, 32, 2),
+           'heads_6': (1, 64, 6, 8), 'padded_two_rows': (2, 100, 2, 15)}
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize('layout', list(LAYOUTS))
+def test_channel_kernel_cuts_the_chunks_where_the_op_holds_them(
+        layout, dtype, interpreted):
+    """W, U, Qg, Kd, P, the chunk's decay and the five cotangents of the
+    per-channel kernels on [B, T, H, D] operands against `_intra_channel`
+    behind `_to_chunks`, at the tolerances of the chunked cases: the row
+    and chunk index maps, the lanes of every head of a grid step, and a T
+    that is no whole number of chunks (padded first, then read in
+    place)."""
+    dtype = jnp.dtype(dtype)
+    b, t, h, seed = LAYOUTS[layout]
+    _compare_stages(op_inputs(seed, t, h, h, 'channel', dtype, b=b), dtype)
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+def test_a_padded_row_is_the_row_padded_by_hand(dtype):
+    """T = 100: the entry pads to 128 and the kernels read in place. The
+    same numbers, bit for bit, as 128 tokens of which the last 28 are
+    zeros (k = 0, beta = 0, g = 0), forward and backward; the padded rows
+    are finite (0 * rsqrt(eps), exp(0)) and hand nothing back."""
+    dtype = jnp.dtype(dtype)
+    args = op_inputs(23, 100, 2, 2, 'channel', dtype)
+    padded = tuple(jnp.pad(x, [(0, 0), (0, 28)] + [(0, 0)] * (x.ndim - 2))
+                   for x in args)
+    norm = (True, 1e-6, 128 ** -0.5)
+
+    def stage(*a):
+        return gdi.gated_delta_intra_tokens(*a, True, norm=norm, floor=-5.0)
+
+    with jax.default_matmul_precision('highest'):
+        got, pull_got = jax.vjp(stage, *args)
+        want, pull_want = jax.vjp(stage, *padded)
+        cts = tuple(jnp.asarray(np.random.default_rng(i).normal(
+            size=o.shape), o.dtype) for i, o in enumerate(want))
+        g_got, g_want = pull_got(cts), pull_want(cts)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and np.all(np.isfinite(
+            np.asarray(a, np.float32)))
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    for a, b, x in zip(g_got, g_want, args):
+        assert a.shape == x.shape and a.dtype == x.dtype
+        b = np.asarray(b, np.float32)
+        np.testing.assert_array_equal(np.asarray(a, np.float32), b[:, :100])
+        assert np.all(np.isfinite(b[:, 100:]))
+    # the padding tokens write nothing: k = 0 and beta = 0
+    w, u, qg, kd = (np.asarray(x, np.float32)[-1, :, :, 36:] for x in got[:4])
+    assert not w.any() and not u.any() and not qg.any() and not kd.any()
+
+
+def test_the_kernels_hold_g_to_its_floor(interpreted):
+    """g as a producer that does not keep its bound might leave it: a
+    third of it UNDER the floor of -5. The per-channel kernels hold it as
+    they load it, so the op is what XLA's select ahead of the composition
+    makes it, values and all five gradients; g's is exactly 0 under the
+    floor and whole at it."""
+    q, k, v, g, beta = op_inputs(29, 100, 2, 2, 'channel')
+    under = np.random.default_rng(4).uniform(size=g.shape) < 0.33
+    g = jnp.where(under, g - 5.0, g)
+    g = g.at[0, :, 0, :7].set(-5.0)             # and some AT it
+    weight = jnp.asarray(np.random.default_rng(2).normal(size=v.shape),
+                         jnp.float32)
+
+    def loss(kernel):
+        return lambda *a: jnp.sum(la.gated_delta_rule(
+            *a, chunk_size=64, qk_l2norm=True, kernel=kernel,
+            gate_floor=-5.0) * weight)
+
+    with jax.default_matmul_precision('highest'):
+        got = jax.value_and_grad(loss(True), argnums=range(5))(
+            q, k, v, g, beta)
+        want = jax.value_and_grad(loss(False), argnums=range(5))(
+            q, k, v, g, beta)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for name, a, b in zip('q k v g beta'.split(), got[1], want[1]):
+        err = float(jnp.linalg.norm(a - b))
+        assert err < 3e-4 * float(jnp.linalg.norm(b)) + 1e-7, (name, err)
+    dg = np.asarray(got[1][3])
+    assert not dg[np.asarray(g) < -5.0].any()
+    assert dg[0, :, 0, :7].any()
 
 
 def _scaled(args, rng, low, high):
@@ -293,6 +399,38 @@ def test_the_rule_chooses_on_platform_and_shape(platform, monkeypatch,
     assert after['composed'] > before['composed']
 
 
+def _counted_where(counter, platform, gates, where, tokens, monkeypatch):
+    """One op through the Executor with the platform reported as
+    `platform`: `counter{where=}` rises once an op a trace beside
+    `gdn.intra{way=}`, the other label stays, and the values are the
+    recurrence's. Returns the compiled module's text."""
+    init = lowering.Ctx.__init__
+    monkeypatch.setattr(
+        lowering.Ctx, '__init__',
+        lambda self, *a, **kw: init(self, *a, **dict(kw, platform=platform)))
+    channel = gates == 'channel'
+    args = op_inputs(17, tokens, 2 if channel else 1, 2, gates)
+    names = ['q', 'k', 'v', 'g', 'beta']
+    w = np.random.default_rng(3).normal(size=args[2].shape).astype('float32')
+
+    def count():
+        return {x: obs.counter(counter, where=x).value
+                for x in ('kernel', 'xla')}, sum(_ways().values())
+
+    before, ops_before = count()
+    got, _, text = _grads_of(lambda: layers.gated_delta_rule(
+        *(_input(n, a) for n, a in zip(names, args)), chunk_size=64,
+        qk_l2norm=True, gate_floor=-5.0 if channel else None),
+        {'w': w}, names, optimized=True)
+    after, ops_after = count()
+    other = 'xla' if where == 'kernel' else 'kernel'
+    assert after[where] - before[where] == ops_after - ops_before >= 1
+    assert after[other] == before[other]
+    np.testing.assert_allclose(got, plain_delta_net(*args), rtol=1e-4,
+                               atol=1e-5)
+    return text
+
+
 @pytest.mark.parametrize('platform,gates,where', [
     ('tpu', 'channel', 'kernel'), ('tpu', 'mild', 'xla'),
     ('cpu', 'channel', 'xla')])
@@ -301,30 +439,26 @@ def test_the_prologue_is_counted_where_it_is_taken(platform, gates, where,
     """`gdn.prologue{where=}` once an op a trace beside `gdn.intra{way=}`:
     `kernel` where the per-channel kernels take the raw operands (rank-4 g
     on the TPU), `xla` with a decay a head and on every other platform."""
-    init = lowering.Ctx.__init__
-    monkeypatch.setattr(
-        lowering.Ctx, '__init__',
-        lambda self, *a, **kw: init(self, *a, **dict(kw, platform=platform)))
-    channel = gates == 'channel'
-    args = op_inputs(17, 100, 2 if channel else 1, 2, gates)
-    names = ['q', 'k', 'v', 'g', 'beta']
-    w = np.random.default_rng(3).normal(size=args[2].shape).astype('float32')
+    _counted_where('gdn.prologue', platform, gates, where, 100, monkeypatch)
 
-    def count():
-        return {x: obs.counter('gdn.prologue', where=x).value
-                for x in ('kernel', 'xla')}, sum(_ways().values())
 
-    before, ops_before = count()
-    got, _, _ = _grads_of(lambda: layers.gated_delta_rule(
-        *(_input(n, a) for n, a in zip(names, args)), chunk_size=64,
-        qk_l2norm=True, gate_floor=-5.0 if channel else None),
-        {'w': w}, names)
-    after, ops_after = count()
-    other = 'xla' if where == 'kernel' else 'kernel'
-    assert after[where] - before[where] == ops_after - ops_before >= 1
-    assert after[other] == before[other]
-    np.testing.assert_allclose(got, plain_delta_net(*args), rtol=1e-4,
-                               atol=1e-5)
+@pytest.mark.parametrize('platform,gates,where', [
+    ('tpu', 'channel', 'kernel'), ('tpu', 'mild', 'xla'),
+    ('cpu', 'channel', 'xla'), ('cpu', 'mild', 'xla')])
+def test_the_chunks_are_counted_where_they_are_cut(platform, gates, where,
+                                                   monkeypatch, interpreted):
+    """`gdn.chunks{where=}` likewise: `kernel` where the per-channel
+    kernels' index maps cut the chunks out of the arrays the op holds
+    (rank-4 g on the TPU; the compiled module then holds no transposed
+    array of heads of 128 under the op's `gdn_intra`: beta's,
+    [.., 64, H], is XLA's), `xla` where `_to_chunks` does: a decay a
+    head, and every other platform."""
+    text = _counted_where('gdn.chunks', platform, gates, where, 128,
+                          monkeypatch)
+    moved = [l for l in text.splitlines() if 'gated_delta_rule_' in l
+             and 'gdn_intra' in l and re.search(
+                 r',128\]\S* transpose\(', l.split('metadata')[0])]
+    assert bool(moved) == (where == 'xla'), moved[:2]
 
 
 def test_every_heads_a_grid_step_gives_the_same_chunks():

@@ -36,6 +36,11 @@ def interpreted(monkeypatch):
         gdi, 'gated_delta_intra',
         lambda q, k, v, g_sum, beta, interpret, heads=None, **kw: intra(
             q, k, v, g_sum, beta, True, heads, **kw))
+    tokens = gdi.gated_delta_intra_tokens
+    monkeypatch.setattr(
+        gdi, 'gated_delta_intra_tokens',
+        lambda q, k, v, g, beta, interpret, heads=None, **kw: tokens(
+            q, k, v, g, beta, True, heads, **kw))
     monkeypatch.setattr(
         gds, 'gated_delta_scan',
         lambda xs, dtype, interpret: scan(xs, dtype, True))

@@ -18,12 +18,16 @@ then pulled back):
 and the largest difference between the two, over each output's and each
 gradient's largest value. `--sweep` instead times the kernel's two calls
 over the heads a grid step takes. `--gate channel` times the form with a
-decay a CHANNEL (`ling3flash_s8192`: g [.., 64, 128] in (-5, 0), a key head
-a value head) from the OP's operands on both sides, so that "alone" holds
-what ISSUE 56 moved: q and k not normalised and g not summed, cut into
-chunks; `composed` is `_stage_intra`'s prologue in XLA (two l2 norms, q's
-scale, the rounding to the matmuls' dtype) and `_intra_channel` with its
-`cumsum`, `kernel` the per-channel kernels, which do all of it in VMEM.
+decay a CHANNEL (`ling3flash_s8192`: g in (-5, 0), a key head a value
+head) from the OP's operands on both sides, WHERE THE OP HOLDS THEM: q, k,
+v and g arrive as a layer's projections leave them, [1, T, H x D], are
+viewed [1, T, H, D] as the model's reshape does, not normalised and not
+summed, and the five gradients are taken there, so that "alone" holds what
+ISSUEs 56 and 58 moved. `composed` is all of `_stage_intra` off the TPU's
+path (the floor's select, two l2 norms, q's scale, the rounding to the
+matmuls' dtype and `_to_chunks` in XLA, then `_intra_channel` with its
+`cumsum`; its pull-back the chunks' transposes), `kernel` the per-channel
+kernels, which do all of it in VMEM on blocks their index maps cut.
 Prints one JSON line a measurement.
 Exits non-zero off the chip: a time from the CPU is no device number.
 """
@@ -55,8 +59,9 @@ def _time(fn, args, iters):
 def _inputs(args, dtype):
     """A layer's chunked operands as the op hands them over: k of unit
     length, q scaled, gates as a trained layer's (a decay of a few
-    percent a token). With a decay a channel: q and k as a projection
-    leaves them (no norm taken, no scale), g within its floor."""
+    percent a token). With a decay a channel: the op's own, q, k, v, g
+    [1, T, H x D] and beta [1, T, H] as the projections leave them (no
+    norm taken, no scale, no chunks), g within its floor."""
     rng = np.random.default_rng(0)
     shape = (args.chunks, 1, args.heads, 64)
     keys = (args.chunks, 1, args.key_heads, 64, args.d)
@@ -68,14 +73,16 @@ def _inputs(args, dtype):
     k = unit(rng.normal(size=keys))
     v = rng.normal(size=shape + (args.d,))
     g = -rng.uniform(0.0, 0.1, size=shape)
+    beta = rng.uniform(0.0, 1.0, size=shape)
     if getattr(args, 'gate', 'head') == 'channel':
-        q, k = (rng.normal(size=keys) for _ in range(2))
+        tokens = (1, 64 * args.chunks, args.heads * args.d)
+        q, k, v = (rng.normal(size=tokens) for _ in range(3))
         # a decay a channel within its floor of -5: a slow decay in a
         # quarter of the channels, near the floor in the rest
-        g = -rng.uniform(0.0, 0.1, size=shape + (args.d,))
+        g = -rng.uniform(0.0, 0.1, size=tokens)
         g = np.where(rng.uniform(size=g.shape) < 0.75,
                      -rng.uniform(4.0, 5.0, size=g.shape), g)
-    beta = rng.uniform(0.0, 1.0, size=shape)
+        beta = rng.uniform(0.0, 1.0, size=tokens[:2] + (args.heads,))
     return tuple(jnp.asarray(x, dtype) for x in (q, k, v)) \
         + tuple(jnp.asarray(x, jnp.float32) for x in (g, beta))
 
@@ -111,16 +118,17 @@ def main(argv=None):
 
     rep = args.heads // args.key_heads
 
-    norm = (True, 1e-6, args.d ** -0.5)
+    norm, floor = (True, 1e-6, args.d ** -0.5), -5.0
+
+    def by_head(*xs):       # the model's reshape of a projection
+        return (x.reshape(x.shape[:2] + (args.heads, args.d)) for x in xs)
 
     def composed(q, k, v, g, beta):
-        if channel:         # `_stage_intra`'s prologue, on chunks
-            qf, kf = (x.astype(jnp.float32) for x in (q, k))
-            qf, kf = (x * jax.lax.rsqrt(
-                jnp.sum(x * x, -1, keepdims=True) + norm[1])
-                for x in (qf, kf))
-            w, u, qg, kd, p_, decay = la._intra_channel(
-                (qf * norm[2]).astype(dtype), kf.astype(dtype), v, g, beta)
+        if channel:         # all of `_stage_intra`, the select before it
+            q, k, v, g = by_head(q, k, v, g)
+            w, u, qg, kd, p_, decay = la._stage_intra(
+                q, k, v, jnp.where(g < floor, floor, g), beta,
+                (64, norm[2], norm[0], norm[1], False))
         else:
             w, u, qg, kd, p_, decay = la._intra(
                 jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2), v,
@@ -130,8 +138,9 @@ def main(argv=None):
 
     def kernel(heads):
         if channel:
-            return lambda q, k, v, g, beta: gdi.gated_delta_intra(
-                q, k, v, g, beta, False, heads, norm=norm)
+            return lambda q, k, v, g, beta: gdi.gated_delta_intra_tokens(
+                *by_head(q, k, v, g), beta, False, heads, norm=norm,
+                floor=floor)
         return lambda q, k, v, g, beta: gdi.gated_delta_intra(
             q, k, v, jnp.cumsum(g, axis=-1), beta, False, heads)
 

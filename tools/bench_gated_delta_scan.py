@@ -70,10 +70,12 @@ def main(argv=None):
     dtype = jnp.dtype(args.dtype)
     q, k, v, g, beta = _inputs(args, dtype)
     # what stage `gdn_intra` hands over; with a decay a channel its
-    # kernel takes the op's own operands (no norm taken, g not summed)
+    # kernel takes the op's own operands where the op holds them (no norm
+    # taken, g not summed, no chunks cut)
     xs = jax.jit(
-        (lambda *a: gdi.gated_delta_intra(
-            *a, False, norm=(True, 1e-6, args.d ** -0.5)))
+        (lambda *a: gdi.gated_delta_intra_tokens(
+            *(x.reshape(x.shape[:2] + (args.heads, args.d)) for x in a[:4]),
+            a[4], False, norm=(True, 1e-6, args.d ** -0.5)))
         if args.gate == 'channel' else
         (lambda *a: gdi.gated_delta_intra(
             *a[:3], jnp.cumsum(a[3], axis=-1), a[4], False)))(
