@@ -33,7 +33,7 @@ __all__ = [
     'random_crop', 'mean_iou', 'relu', 'log', 'crop', 'rank_loss', 'prelu',
     'flatten', 'sequence_mask', 'stack', 'fused_attention', 'rms_norm',
     'rotary_embedding', 'gated_delta_rule', 'causal_conv1d',
-    'gated_rms_norm', 'ssd_scan',
+    'gated_rms_norm', 'ssd_scan', 'chunk_softmax_pool',
 ]
 
 
@@ -659,21 +659,28 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
     return helper.append_activation(layer_norm_out)
 
 
-def rms_norm(input, epsilon=1e-05, param_attr=None, name=None):
+def rms_norm(input, epsilon=1e-05, param_attr=None, name=None,
+             unit_offset=False):
     """RMS norm over the last axis: ``scale * x * rsqrt(mean(x^2) +
     epsilon)`` with a learned `scale` of the last axis' width (initialised
     to 1), no mean subtraction and no shift. One Program op; statistics in
-    float32 under AMP too. TPU extension (the reference predates it)."""
+    float32 under AMP too. ``unit_offset=True`` stores the weight as its
+    offset from one (EvaByte's `norm_add_unit_offset`): ``(1 + w) * x *
+    rsqrt(...)``, `w` initialised to 0. TPU extension (the reference
+    predates it)."""
     helper = LayerHelper('rms_norm', **locals())
     dtype = helper.input_dtype()
-    scale = helper.create_parameter(attr=helper.param_attr,
-                                    shape=[int(input.shape[-1])],
-                                    dtype=dtype,
-                                    default_initializer=Constant(1.0))
+    scale = helper.create_parameter(
+        attr=helper.param_attr, shape=[int(input.shape[-1])], dtype=dtype,
+        default_initializer=Constant(0.0 if unit_offset else 1.0))
     out = helper.create_variable_for_type_inference(dtype)
+    # the mode is written only where it departs from the op as it was
+    attrs = {'epsilon': float(epsilon)}
+    if unit_offset:
+        attrs['unit_offset'] = True
     helper.append_op(type='rms_norm', inputs={'X': [input],
                                               'Scale': [scale]},
-                     outputs={'Y': [out]}, attrs={'epsilon': float(epsilon)})
+                     outputs={'Y': [out]}, attrs=attrs)
     return out
 
 
@@ -1071,7 +1078,8 @@ def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None):
 
 
 def fused_attention(q, k, v, key_bias=None, causal=False, scale=None,
-                    name=None, window=None):
+                    name=None, window=None, aligned_window=None,
+                    summary=None, summary_every=None):
     """Whole-attention fused op: softmax(q k^T * scale + bias) v in ONE op.
 
     q/k/v: [B, H, T, D]; k and v may have FEWER heads than q (grouped
@@ -1088,10 +1096,50 @@ def fused_attention(q, k, v, key_bias=None, causal=False, scale=None,
     XLA chain with the same mask. Replaces the reference's
     matmul->softmax->matmul op sequence (nets.py
     scaled_dot_product_attention).
+
+    ``aligned_window`` W (causal only, in place of ``window``) cuts the row
+    into ALIGNED windows of W positions, which do not slide: query t sees
+    the keys of its own window up to t. ``summary=(kbar, vbar)``, both
+    [B, H_kv, T / summary_every, D] (layers.chunk_softmax_pool makes them),
+    adds one summary key and value for every ``summary_every`` consecutive
+    positions: a query also sees every summary whose positions lie in a
+    window BEFORE its own, and its output is ONE softmax over both sets
+    (EVA, arXiv:2302.04542, as EvaByte sizes it). On TPU the exact part is
+    the causal kernels over rows of W, the summary part the same kernels on
+    a staircase grid that holds only admitted blocks, merged by their
+    log-sum-exp; neither set's scores reach HBM.
     """
     # refused here as the kernels would refuse it at the lowering
     from ...ops.flash_attention import _window_of
     _window_of(window, causal, int(q.shape[2]))
+    if aligned_window is None:
+        if summary is not None or summary_every is not None:
+            raise ValueError('fused_attention: summaries belong to aligned '
+                             'windows; give aligned_window')
+    else:
+        t = int(q.shape[2])
+        if not causal or window is not None or key_bias is not None \
+                or int(k.shape[2]) != t:
+            raise ValueError(
+                'fused_attention: aligned_window is causal self-attention '
+                'without a sliding window or a key bias')
+        if int(aligned_window) < 1 or t % int(aligned_window):
+            raise ValueError('fused_attention: aligned windows of %r do not '
+                             'divide a row of %d' % (aligned_window, t))
+        if (summary is None) != (summary_every is None):
+            raise ValueError('fused_attention: summary=(kbar, vbar) and '
+                             'summary_every come together')
+        if summary is not None and (
+                int(summary_every) < 1
+                or int(aligned_window) % int(summary_every)
+                or any(int(s.shape[2]) * int(summary_every) != t
+                       or int(s.shape[1]) != int(k.shape[1])
+                       for s in summary)):
+            raise ValueError(
+                'fused_attention: one summary every %r positions of a row '
+                'of %d in windows of %d, a head a key head: got %r'
+                % (summary_every, t, aligned_window,
+                   [tuple(s.shape) for s in summary]))
     h_q, h_kv = int(q.shape[1]), int(k.shape[1])
     if h_kv != int(v.shape[1]) or h_kv <= 0 or h_q % h_kv:
         raise ValueError('fused_attention: %d query heads over %d key and '
@@ -1105,9 +1153,53 @@ def fused_attention(q, k, v, key_bias=None, causal=False, scale=None,
              'scale': float(scale) if scale is not None else -1.0}
     if window is not None:
         attrs['window'] = int(window)
+    if aligned_window is not None:
+        attrs['aligned_window'] = int(aligned_window)
+    if summary is not None:
+        inputs['SummaryK'], inputs['SummaryV'] = [summary[0]], [summary[1]]
+        attrs['summary_every'] = int(summary_every)
     helper.append_op(type='flash_attention', inputs=inputs,
                      outputs={'Out': [out]}, attrs=attrs)
     return out
+
+
+def chunk_softmax_pool(k, v, mu, phi, chunk=16, scale=None, name=None):
+    """One learned SUMMARY key and value for every ``chunk`` consecutive
+    positions of the heads ``k``, ``v`` [B, H, T, D] (EVA's pooled key and
+    control-variate value, arXiv:2302.04542 section 4, with EvaByte's two
+    learned vectors a head, ``mu`` and ``phi`` [H, D], in place of the
+    paper's sampled feature), in ONE op:
+
+        a_m = softmax over the chunk of (mu . k_m)            kbar = sum a_m k_m
+        b_m = softmax over the chunk of (scale * phi . k_m)   vbar = sum b_m v_m
+
+    Both softmaxes read the KEYS; ``scale`` (default D^-0.5) is on phi's
+    logits alone. Returns ``(kbar, vbar)``, [B, H, T / chunk, D] each, for
+    ``fused_attention(summary=(kbar, vbar), summary_every=chunk)``.
+    Logits, weights and sums in float32 under AMP too; the backward keeps
+    k, v and the two [B, H, T] weight arrays (reshape, softmax,
+    elementwise_mul and reduce_sum would keep eight arrays of k's size).
+    TPU extension (the reference predates it)."""
+    t, h, d = int(k.shape[2]), int(k.shape[1]), int(k.shape[3])
+    chunk = int(chunk)
+    if chunk < 1 or t % chunk or tuple(k.shape[:3]) != tuple(v.shape[:3]):
+        raise ValueError('chunk_softmax_pool: chunks of %r over keys %r and '
+                         'values %r' % (chunk, tuple(k.shape),
+                                        tuple(v.shape)))
+    for vec in (mu, phi):
+        if tuple(int(n) for n in vec.shape) != (h, d):
+            raise ValueError('chunk_softmax_pool: a learned vector a head, '
+                             '[%d, %d], got %r' % (h, d, tuple(vec.shape)))
+    helper = LayerHelper('chunk_softmax_pool', **locals())
+    kbar = helper.create_variable_for_type_inference(dtype=k.dtype)
+    vbar = helper.create_variable_for_type_inference(dtype=v.dtype)
+    helper.append_op(
+        type='chunk_softmax_pool',
+        inputs={'K': [k], 'V': [v], 'Mu': [mu], 'Phi': [phi]},
+        outputs={'KBar': [kbar], 'VBar': [vbar]},
+        attrs={'chunk': chunk,
+               'scale': float(scale) if scale is not None else -1.0})
+    return kbar, vbar
 
 
 def latent_attention(input, size, num_heads, q_lora_rank, kv_lora_rank,

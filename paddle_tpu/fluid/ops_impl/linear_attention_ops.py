@@ -929,3 +929,90 @@ def _gated_rms_norm(ins, attrs, ctx):
         cfg += (attrs['gate_act'],)
     y = gated_rms_norm(x, gate, data_of(ins['Scale'][0]), cfg, kernel)
     return {'Y': y.astype(x.dtype)}
+
+
+# ---------------------------------------------------------------------------
+# chunk_softmax_pool: one learned summary key and value a chunk (EVA)
+# ---------------------------------------------------------------------------
+
+def _pool_weights(k, vec, chunk, scale):
+    """softmax over each chunk's positions of scale * (vec . k): k
+    [B, H, T, D], vec [H, D] -> [B, H, T / chunk, chunk] float32. The dot
+    is a product and a sum in float32, never a matmul: the chip would
+    take a matmul's float32 operands in bf16 passes."""
+    b, h, t, _ = k.shape
+    logits = jnp.sum(k.astype(jnp.float32)
+                     * vec.astype(jnp.float32)[None, :, None, :], axis=-1)
+    return jax.nn.softmax(
+        (logits * scale).reshape(b, h, t // chunk, chunk), axis=-1)
+
+
+def _chunks(x, chunk):
+    b, h, t, d = x.shape
+    return x.astype(jnp.float32).reshape(b, h, t // chunk, chunk, d)
+
+
+def _pool_forward(k, v, mu, phi, chunk, scale):
+    a = _pool_weights(k, mu, chunk, 1.0)
+    b = _pool_weights(k, phi, chunk, scale)
+    kbar = jnp.sum(_chunks(k, chunk) * a[..., None], axis=3)
+    vbar = jnp.sum(_chunks(v, chunk) * b[..., None], axis=3)
+    return (kbar.astype(k.dtype), vbar.astype(v.dtype)), (k, v, mu, phi, a, b)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def chunk_softmax_pool(k, v, mu, phi, chunk, scale):
+    """One summary key and one summary value for every `chunk` consecutive
+    positions, per head, each a softmax-weighted sum whose logits come
+    from the KEYS against a learned vector (EVA, arXiv:2302.04542, with
+    EvaByte's two learned vectors in place of the sampled feature):
+
+        a_m = softmax over the chunk of (mu . k_m)            kbar = sum a_m k_m
+        b_m = softmax over the chunk of (scale * phi . k_m)   vbar = sum b_m v_m
+
+    k, v [B, H, T, D] (v may be [.., Dv]), mu, phi [H, D], T a multiple
+    of `chunk`; returns kbar [B, H, T / chunk, D] and vbar [.., Dv] in k's
+    and v's dtype. Logits, weights and sums are float32. The backward
+    keeps k, v and the two weight arrays ([B, H, T] float32 each) and
+    nothing else of the forward."""
+    return _pool_forward(k, v, mu, phi, chunk, scale)[0]
+
+
+def _pool_bwd(chunk, scale, res, cot):
+    k, v, mu, phi, a, b = res
+    dkbar, dvbar = (c.astype(jnp.float32)[:, :, :, None, :] for c in cot)
+    kc, vc = _chunks(k, chunk), _chunks(v, chunk)
+
+    def through_softmax(w, dw):
+        return w * (dw - jnp.sum(w * dw, axis=-1, keepdims=True))
+
+    dla = through_softmax(a, jnp.sum(kc * dkbar, axis=-1))[..., None]
+    dlb = through_softmax(b, jnp.sum(vc * dvbar, axis=-1))[..., None] * scale
+    muf, phif = (x.astype(jnp.float32)[None, :, None, None, :]
+                 for x in (mu, phi))
+    dk = a[..., None] * dkbar + dla * muf + dlb * phif
+    dv = b[..., None] * dvbar
+    dmu, dphi = (jnp.sum(d * kc, axis=(0, 2, 3)) for d in (dla, dlb))
+    return (dk.reshape(k.shape).astype(k.dtype),
+            dv.reshape(v.shape).astype(v.dtype),
+            dmu.astype(mu.dtype), dphi.astype(phi.dtype))
+
+
+chunk_softmax_pool.defvjp(
+    lambda k, v, mu, phi, chunk, scale: _pool_forward(k, v, mu, phi, chunk,
+                                                      scale),
+    _pool_bwd)
+
+
+@register('chunk_softmax_pool')
+def _chunk_softmax_pool(ins, attrs, ctx):
+    k, v, mu, phi = (data_of(ins[s][0]) for s in ('K', 'V', 'Mu', 'Phi'))
+    chunk = int(attrs['chunk'])
+    obs.counter('chunk_pool.lowered', chunk=chunk).inc()     # trace time
+    # the keys and values as the attention call beside it reads them
+    kc, vc = amp_cast(ctx, k, v)
+    scale = attrs.get('scale', -1.0)
+    scale = k.shape[-1] ** -0.5 if scale is None or scale < 0 \
+        else float(scale)
+    kbar, vbar = chunk_softmax_pool(kc, vc, mu, phi, chunk, scale)
+    return {'KBar': kbar.astype(k.dtype), 'VBar': vbar.astype(v.dtype)}

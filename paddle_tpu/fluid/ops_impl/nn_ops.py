@@ -245,13 +245,17 @@ def _rms_norm(ins, attrs, ctx):
     """y = scale * x * rsqrt(mean(x^2) + epsilon) over the last axis (Zhang
     and Sennrich 2019; no reference counterpart). The statistics are taken
     in float32 whatever the input's dtype, under AMP too; the result has
-    the input's dtype."""
+    the input's dtype. With the attribute `unit_offset` the parameter is
+    the scale's offset from one: y = (1 + scale) * x * rsqrt(...)."""
     x = data_of(ins['X'][0])
     obs.counter('rms_norm.lowered').inc()          # trace time
     xf = x.astype(jnp.float32)
     inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
                     + attrs.get('epsilon', 1e-5))
-    y = xf * inv * data_of(ins['Scale'][0]).astype(jnp.float32)
+    scale = data_of(ins['Scale'][0]).astype(jnp.float32)
+    if attrs.get('unit_offset', False):
+        scale = 1.0 + scale
+    y = xf * inv * scale
     return {'Y': like(ins['X'][0], y.astype(x.dtype))}
 
 
@@ -643,6 +647,38 @@ def _im2sequence(ins, attrs, ctx):
     return {'Out': SeqValue(seq, lengths)}
 
 
+def _aligned_attention(ctx, q, k, v, summary, attrs, scale):
+    """`flash_attention` with the attribute `aligned_window`: causal
+    attention exact inside aligned windows, and over the summaries of the
+    windows before a query's own where the op has them (`SummaryK`,
+    `SummaryV`, one every `summary_every` positions), under one softmax.
+    On the TPU the two geometries of the flash kernels and their merge
+    (ops/flash_attention.py flash_attention_summary) where the staircase
+    has tiles for the sizes; elsewhere the XLA chain over the same masks,
+    dense."""
+    from ...ops.flash_attention import (
+        flash_attention_summary, reference_attention_summary,
+        summary_blocks)
+    window = int(attrs['aligned_window'])
+    every = attrs.get('summary_every')
+    if getattr(ctx, 'mesh', None) is not None:
+        raise ValueError(
+            'flash_attention: aligned windows (aligned_window=%d) on a mesh '
+            'are not built: the per-shard call and the sp bodies know the '
+            'causal edge and the sliding window alone' % window)
+    kbar, vbar = amp_cast(ctx, *summary) if summary else (None, None)
+    kernel = ctx.platform == 'tpu' and (
+        not summary or summary_blocks(window, int(every)) is not None)
+    obs.counter('flash.aligned', way='kernel' if kernel else 'xla',
+                summaries='true' if summary else 'false').inc()  # trace time
+    if kernel:
+        return flash_attention_summary(
+            q, k, v, kbar, vbar, window=window, every=every, sm_scale=scale,
+            interpret=False)
+    return reference_attention_summary(
+        q, k, v, kbar, vbar, window=window, every=every, sm_scale=scale)
+
+
 @register('flash_attention')
 def _flash_attention(ins, attrs, ctx):
     """Fused attention: pallas flash kernel on TPU, XLA chain elsewhere.
@@ -661,6 +697,9 @@ def _flash_attention(ins, attrs, ctx):
     causal = bool(attrs.get('causal', False))
     window = attrs.get('window')
     window = None if window is None else int(window)
+    aligned = attrs.get('aligned_window')
+    summary = [data_of(ins[s][0]) for s in ('SummaryK', 'SummaryV')] \
+        if ins.get('SummaryK') else None
     if k.shape[1] != q.shape[1]:
         # grouped key-value heads: key-value head h serves the query heads
         # h * group and following. A repeat, whose transpose sums a group's
@@ -669,7 +708,12 @@ def _flash_attention(ins, attrs, ctx):
         obs.counter('flash.grouped', q_heads=q.shape[1],
                     kv_heads=k.shape[1], head_dim=q.shape[3]).inc()
         k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+        if summary:
+            summary = [jnp.repeat(t, group, axis=1) for t in summary]
     q, k, v = amp_cast(ctx, q, k, v)
+    if aligned is not None:
+        return {'Out': _aligned_attention(ctx, q, k, v, summary, attrs,
+                                          scale)}
     # off the TPU the sp bodies run their kernels interpreted and the
     # plain op takes the XLA chain
     interpret = ctx.pallas_interpret

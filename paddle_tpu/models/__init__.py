@@ -9,7 +9,7 @@ model_list = ['fit_a_line', 'mnist', 'vgg', 'resnet',
               'stacked_dynamic_lstm', 'machine_translation', 'transformer',
               'deepfm', 'word2vec', 'se_resnext', 'understand_sentiment',
               'label_semantic_roles', 'recommender_system', 'olmoe',
-              'qwen3_next', 'granitemoehybrid', 'bailing_hybrid']
+              'qwen3_next', 'granitemoehybrid', 'bailing_hybrid', 'evabyte']
 
 
 def get_model_module(name):

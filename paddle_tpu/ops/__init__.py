@@ -8,8 +8,10 @@ optimizers) that the lowering rules dispatch into behind the
 per-kernel `PADDLE_TPU_KERNELS` knob (docs/perf.md#kernel-layer).
 """
 from .flash_attention import flash_attention, flash_attention_lse, \
-    flash_attention_sharded, reference_attention
+    flash_attention_sharded, flash_attention_summary, merge_lse, \
+    reference_attention, reference_attention_summary
 from . import kernels
 
 __all__ = ['flash_attention', 'flash_attention_lse',
-           'flash_attention_sharded', 'reference_attention', 'kernels']
+           'flash_attention_sharded', 'flash_attention_summary', 'merge_lse',
+           'reference_attention', 'reference_attention_summary', 'kernels']

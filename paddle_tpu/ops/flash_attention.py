@@ -218,6 +218,31 @@ def _tri_maps_kv(n, nb=None):
             np.concatenate(jj).astype(np.int32))
 
 
+def _stair_maps(nq, qpw, spw):
+    """Row-major enumeration of a STAIRCASE: q-block i, of window i // qpw
+    (qpw q-blocks a window), sees the summary blocks 0 .. (i // qpw + 1) *
+    spw - 1 (spw summary blocks a window): whole blocks admitted or left
+    out, so no tile is masked and none above the stairs is in the grid.
+    The queries handed in start at the row's SECOND window and the
+    summaries end before its last (flash_attention_summary), so every
+    q-block and every summary block has a pair."""
+    import numpy as np
+    seen = (np.arange(nq) // qpw + 1) * spw
+    i = np.repeat(np.arange(nq), seen)
+    j = np.concatenate([np.arange(n) for n in seen])
+    return i.astype(np.int32), j.astype(np.int32)
+
+
+def _stair_maps_kv(nq, qpw, spw):
+    """The same pairs ordered for the dk/dv kernel: summary block j outer,
+    the q-blocks that see it, (j // spw) * qpw .. nq - 1, inner."""
+    import numpy as np
+    first = (np.arange(nq // qpw * spw) // spw) * qpw
+    i = np.concatenate([np.arange(f, nq) for f in first])
+    j = np.repeat(np.arange(len(first)), nq - first)
+    return i.astype(np.int32), j.astype(np.int32)
+
+
 # ---------------------------------------------------------------------------
 # forward kernel body + rectangular/triangular wrappers
 # ---------------------------------------------------------------------------
@@ -286,6 +311,24 @@ def _fwd_kernel_tri(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref,
               i, j, _row_start(i, j, nb), j == i,
               scale=scale, causal=True, block_q=block_q, block_k=block_k,
               window=window)
+
+
+def _stair_row_end(i, qpw, spw):
+    """The last summary block q-block i visits: the last of the window
+    before its own (the queries handed in start at the second window)."""
+    return (i // qpw + 1) * spw - 1
+
+
+def _fwd_kernel_stair(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref,
+                      o_ref, lse_ref, m_s, l_s, acc_s, *,
+                      scale, block_q, block_k, qpw, spw):
+    t = pl.program_id(2)
+    i, j = im_ref[t], jm_ref[t]
+    # a q-block's row runs from summary block 0; nothing inside a tile is
+    # masked
+    _fwd_body(q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref, m_s, l_s, acc_s,
+              i, j, j == 0, j == _stair_row_end(i, qpw, spw),
+              scale=scale, causal=False, block_q=block_q, block_k=block_k)
 
 
 # ---------------------------------------------------------------------------
@@ -413,6 +456,30 @@ def _bwd_dkv_kernel_tri(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
                   dk_ref, dv_ref, dk_s, dv_s, i, j, i == j, i == last,
                   scale=scale, causal=True,
                   block_q=block_q, block_k=block_k, window=window)
+
+
+def _bwd_dq_kernel_stair(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
+                         lse_ref, delta_ref, dq_ref, dq_s, *,
+                         scale, block_q, block_k, qpw, spw):
+    t = pl.program_id(2)
+    i, j = im_ref[t], jm_ref[t]
+    _bwd_dq_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
+                 dq_ref, dq_s, i, j, j == 0, j == _stair_row_end(i, qpw, spw),
+                 scale=scale, causal=False, block_q=block_q, block_k=block_k)
+
+
+def _bwd_dkv_kernel_stair(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref,
+                          do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+                          dk_s, dv_s, *, scale, block_q, block_k, nq, qpw,
+                          spw):
+    t = pl.program_id(2)
+    i, j = im_ref[t], jm_ref[t]
+    # summary block j's q-blocks run from the first of the window after
+    # its own to the row's last (_stair_maps_kv order)
+    _bwd_dkv_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
+                  dk_ref, dv_ref, dk_s, dv_s, i, j, i == (j // spw) * qpw,
+                  i == nq - 1, scale=scale, causal=False,
+                  block_q=block_q, block_k=block_k)
 
 
 def _bwd_head_kernel(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
@@ -835,18 +902,24 @@ def _flash_lse_fwd(q, k, v, kb, causal, window, scale, bq, bk, schedule,
     return (o, lse[..., 0]), (q, k, v, kb, o, lse)
 
 
-def _flash_lse_bwd(causal, window, scale, bq, bk, schedule, interpret, res,
-                   cot):
-    """Backward with an lse cotangent, sharing the kernels unchanged:
+def _folded_delta(do, o, dlse):
+    """delta = sum(do * o) - dlse, lane-broadcast as the kernels read it:
     lse = logsumexp(S) gives dS|lse = P * dlse, and the kernels compute
     dS = P * (dP - delta), so folding delta' = delta - dlse routes the lse
     gradient through the same pallas calls, whatever the schedule (the
     FlashAttention D-trick extended one term)."""
-    do, dlse = cot
-    q, k, v, kb, o, lse = res
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = delta - dlse.astype(jnp.float32)
-    delta = jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
+    return jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
+
+
+def _flash_lse_bwd(causal, window, scale, bq, bk, schedule, interpret, res,
+                   cot):
+    """Backward with an lse cotangent, sharing the kernels unchanged
+    (_folded_delta)."""
+    do, dlse = cot
+    q, k, v, kb, o, lse = res
+    delta = _folded_delta(do, o, dlse)
     dq, dk, dv = _bwd_call(q, k, v, kb, do, lse, delta, causal, scale,
                            bq, bk, schedule, interpret, window)
     # kb is a mask constant (see module docstring): zero cotangent
@@ -854,6 +927,130 @@ def _flash_lse_bwd(causal, window, scale, bq, bk, schedule, interpret, res,
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
+# The two staircase calls are jitted functions of their own: a trace tells
+# their Mosaic events from the aligned part's by the function they were
+# called in (chipbench/harness/scopes.py callee_of), as jax's megablox
+# kernels are told apart.
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
+def staircase_fwd(q, k, v, kb, scale, bq, bk, qpw, spw, interpret):
+    """The forward over the staircase grid (_stair_maps): grid (B, H,
+    pairs), the forward body as it is, unmasked."""
+    B, H, Tq, D = q.shape
+    Dv = v.shape[3]
+    im, jm = _stair_maps(Tq // bq, qpw, spw)
+    qrow, kcol, kbias, stats, orow, vcol = _tri_specs(bq, bk, D, Dv)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel_stair, scale=scale, block_q=bq,
+                          block_k=bk, qpw=qpw, spw=spw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, H, len(im)),
+            in_specs=[qrow, kcol, vcol, kbias],
+            out_specs=[orow, stats],
+            scratch_shapes=[
+                pltpu.VMEM((bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, Dv), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, Tq, Dv), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Tq, LANES), jnp.float32),
+        ],
+        interpret=interpret,
+    )(jnp.asarray(im), jnp.asarray(jm), q, k, v, kb)
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12))
+def staircase_bwd(q, k, v, kb, do, lse, delta, scale, bq, bk, qpw, spw,
+                  interpret):
+    """The backward over the same pairs in two passes: dq over a q-row's
+    summary blocks, dk/dv over a summary block's q-blocks
+    (_stair_maps_kv order), as _bwd_call_tri walks its triangle."""
+    B, H, Tq, D = q.shape
+    Dv = v.shape[3]
+    nq = Tq // bq
+    qrow, kcol, kbias, stats, orow, vcol = _tri_specs(bq, bk, D, Dv)
+    bwd_in_specs = [qrow, kcol, vcol, kbias, orow, stats, stats]
+    im, jm = _stair_maps(nq, qpw, spw)
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel_stair, scale=scale, block_q=bq,
+                          block_k=bk, qpw=qpw, spw=spw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, H, len(im)),
+            in_specs=bwd_in_specs,
+            out_specs=qrow,
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+    )(jnp.asarray(im), jnp.asarray(jm), q, k, v, kb, do, lse, delta)
+    im2, jm2 = _stair_maps_kv(nq, qpw, spw)
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel_stair, scale=scale, block_q=bq,
+                          block_k=bk, nq=nq, qpw=qpw, spw=spw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, H, len(im2)),
+            in_specs=bwd_in_specs,
+            out_specs=[kcol, vcol],
+            scratch_shapes=[
+                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, Dv), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        interpret=interpret,
+    )(jnp.asarray(im2), jnp.asarray(jm2), q, k, v, kb, do, lse, delta)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _stair_lse(q, k, v, kb, scale, bq, bk, qpw, spw, interpret):
+    o, lse = staircase_fwd(q, k, v, kb, scale, bq, bk, qpw, spw, interpret)
+    return o, lse[..., 0]
+
+
+def _stair_lse_fwd(q, k, v, kb, scale, bq, bk, qpw, spw, interpret):
+    o, lse = staircase_fwd(q, k, v, kb, scale, bq, bk, qpw, spw, interpret)
+    # kept by a recompute region's policy under the names _flash_lse_fwd
+    # gives its own
+    o, lse = checkpoint_name(o, 'flash_out'), checkpoint_name(lse, 'flash_lse')
+    return (o, lse[..., 0]), (q, k, v, kb, o, lse)
+
+
+def _stair_lse_bwd(scale, bq, bk, qpw, spw, interpret, res, cot):
+    """_flash_lse_bwd's arithmetic over the staircase's pairs."""
+    do, dlse = cot
+    q, k, v, kb, o, lse = res
+    dq, dk, dv = staircase_bwd(q, k, v, kb, do, lse,
+                               _folded_delta(do, o, dlse), scale, bq, bk,
+                               qpw, spw, interpret)
+    return dq, dk, dv, jnp.zeros_like(kb)
+
+
+_stair_lse.defvjp(_stair_lse_fwd, _stair_lse_bwd)
+
+
+def merge_lse(o, lse, o_s, lse_s):
+    """Two partial attentions over DISJOINT key sets as one softmax over
+    their union, from each part's output and log-sum-exp:
+        lse' = logaddexp(lse, lse_s)
+        o'   = o * e^(lse - lse') + o_s * e^(lse_s - lse')
+    in float32. Ring attention merges its ring steps so
+    (parallel/ring_attention.py), flash_attention_summary its exact and
+    its summary part. Differentiable through all four."""
+    lse_new = jnp.logaddexp(lse, lse_s)
+    w = jnp.exp(lse - lse_new)[..., None]
+    w_s = jnp.exp(lse_s - lse_new)[..., None]
+    return (o.astype(jnp.float32) * w + o_s.astype(jnp.float32) * w_s,
+            lse_new)
 
 
 # Tile defaults from the tools/tune_flash.py sweep of PR 24 on a v5e (bf16,
@@ -1057,6 +1254,142 @@ def flash_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,
                                block_k=block_k, window=window,
                                interpret=interpret)
     return o
+
+
+# The staircase's tiles: q-blocks of 512 (the causal table's) inside a
+# window, summary blocks of the largest of these that divides a window's
+# summaries (2048 / 16 = 128 at EvaByte's sizes: one lane tile of keys).
+_STAIR_BQ = 512
+_STAIR_BK = (512, 256, 128)
+
+
+def summary_blocks(window, every):
+    """(block_q, block_k) the staircase kernels take for aligned windows
+    of `window` positions summarised once every `every`, or None where
+    Mosaic's tiling does not take them (the XLA chain then, as off the
+    TPU)."""
+    per = window // every
+    bk = next((b for b in _STAIR_BK if per % b == 0), None)
+    if window % _STAIR_BQ or bk is None:
+        return None
+    return _STAIR_BQ, bk
+
+
+def _summary_shapes(q, kbar, window, every):
+    T, window = q.shape[2], int(window)
+    if window < 1 or T % window:
+        raise ValueError('flash attention: aligned windows of %r do not '
+                         'divide a row of %d' % (window, T))
+    if kbar is None:
+        return T // window, None
+    every = int(every)
+    if every < 1 or window % every or kbar.shape[2] * every != T:
+        raise ValueError(
+            'flash attention: one summary every %r positions, %d summaries '
+            'for a row of %d in windows of %d: the summaries divide a '
+            'window and cover the row' % (every, kbar.shape[2], T, window))
+    return T // window, window // every
+
+
+def flash_attention_summary(q, k, v, kbar=None, vbar=None, *, window,
+                            every=None, sm_scale=None, block_q=None,
+                            block_k=None, interpret):
+    """Causal attention that is EXACT inside aligned windows and sees
+    what lies before a query's window through SUMMARIES, under one
+    softmax (EVA, Zheng et al. 2023, arXiv:2302.04542, as EvaByte sizes
+    it). q, k, v [B, H, T, D] (v may be [.., Dv]); the row is cut into
+    aligned windows of `window` positions; kbar [B, H, T / every, D] and
+    vbar [B, H, T / every, Dv] hold one summary key and value for every
+    `every` consecutive positions. Query t of window w sees
+
+        the keys m of window w with m <= t                     (exact)
+        the summaries n whose positions lie in a window before w
+
+    and its output is one softmax over both sets. Two geometries of the
+    flash kernels and a merge:
+
+      aligned    the causal kernels over rows of `window`: the operands
+                 viewed [B, H x T / window, window, D], which costs
+                 nothing, so the triangular grid and the one-pass
+                 backward run as for any causal call of that length;
+      staircase  the forward, dq and dk/dv kernels unmasked on a grid of
+                 the (q-block, summary-block) pairs whose summaries lie in
+                 an earlier window (_stair_maps): the queries from the
+                 second window on against the summaries before the last;
+      merge_lse  joins the two (o, lse) pairs in float32.
+
+    Neither set's scores reach HBM. Without kbar and vbar (or in a row of
+    one window) the exact part is the answer. `block_q`/`block_k` are the
+    STAIRCASE's tiles (summary_blocks() by default); the aligned part
+    takes the causal table's. Counters `flash.forward{geometry=aligned|
+    staircase}` a call beside `flash.lowered` and `flash.backward` of
+    the aligned part. Returns [B, H, T, Dv] in q's dtype."""
+    B, H, T, D = q.shape
+    windows, per = _summary_shapes(q, kbar, window, every)
+    window = int(window)
+    if sm_scale is None:
+        sm_scale = D ** -0.5
+
+    def rows(x):
+        return x.reshape(B, H * windows, window, x.shape[3])
+
+    obs.counter('flash.forward', geometry='aligned').inc()     # trace time
+    o_e, lse_e = flash_attention_lse(rows(q), rows(k), rows(v), causal=True,
+                                     sm_scale=sm_scale, interpret=interpret)
+    o_e, lse_e = o_e.reshape(B, H, T, -1), lse_e.reshape(B, H, T)
+    if kbar is None or windows == 1:
+        return o_e
+    if block_q is None or block_k is None:
+        blocks = summary_blocks(window, every)
+        if blocks is None:
+            raise ValueError('flash attention: no staircase tiles for '
+                             'windows of %d summarised every %d; pass '
+                             'block_q and block_k' % (window, every))
+        block_q, block_k = blocks
+    if window % block_q or per % block_k:
+        raise ValueError('flash attention: staircase tiles %d x %d do not '
+                         'divide a window of %d positions and %d summaries'
+                         % (block_q, block_k, window, per))
+    operands = jnp.result_type(q, kbar, vbar)
+    # the first window sees no summary and the last window's summaries
+    # are seen by nobody: neither is handed to the kernels
+    seen = kbar.shape[2] - per
+    q_s, kbar, vbar = (x.astype(operands) for x in (
+        q[:, :, window:], kbar[:, :, :seen], vbar[:, :, :seen]))
+    obs.counter('flash.forward', geometry='staircase').inc()   # trace time
+    o_s, lse_s = _stair_lse(
+        q_s, kbar, vbar, jnp.zeros((B, 1, seen), jnp.float32),
+        float(sm_scale), int(block_q), int(block_k), window // block_q,
+        per // block_k, bool(interpret))
+    o_t, _ = merge_lse(o_e[:, :, window:], lse_e[:, :, window:], o_s, lse_s)
+    return jnp.concatenate([o_e[:, :, :window], o_t.astype(q.dtype)], axis=2)
+
+
+def reference_attention_summary(q, k, v, kbar=None, vbar=None, *, window,
+                                every=None, sm_scale=None):
+    """flash_attention_summary in plain XLA with the masks written out
+    densely: ONE softmax over [T + T / every] scores a query (the
+    fallback off the TPU, and the tests' oracle)."""
+    B, H, T, D = q.shape
+    _summary_shapes(q, kbar, window, every)
+    if sm_scale is None:
+        sm_scale = D ** -0.5
+    qf = q.astype(jnp.float32)
+    pos = jnp.arange(T)
+    seen = (pos[:, None] >= pos[None, :]) & (
+        pos[:, None] // window == pos[None, :] // window)
+    keys, values = k, v
+    if kbar is not None:
+        first = jnp.arange(kbar.shape[2]) * every      # a summary's first
+        seen = jnp.concatenate(
+            [seen, pos[:, None] // window > first[None, :] // window], axis=1)
+        keys = jnp.concatenate([k, kbar.astype(k.dtype)], axis=2)
+        values = jnp.concatenate([v, vbar.astype(v.dtype)], axis=2)
+    s = jnp.einsum('bhqd,bhkd->bhqk', qf, keys.astype(jnp.float32)) \
+        * sm_scale
+    p = jax.nn.softmax(jnp.where(seen, s, NEG_BIG), axis=-1)
+    return jnp.einsum('bhqk,bhkd->bhqd', p,
+                      values.astype(jnp.float32)).astype(q.dtype)
 
 
 def flash_attention_sharded(mesh, q, k, v, key_bias=None, causal=False,

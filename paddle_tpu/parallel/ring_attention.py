@@ -104,6 +104,7 @@ def _ring_attention_flash(q, k, v, axis_name, key_bias, causal, sm_scale,
 
     Each ring step computes (o_s, lse_s) = flash(q_local, kv_shard); steps
     merge with the standard partial-softmax combine
+    (ops/flash_attention.py merge_lse)
         lse' = logaddexp(lse, lse_s)
         o'   = o * e^{lse-lse'} + o_s * e^{lse_s-lse'}
     which is exact (the union of key shards IS full attention). Causality
@@ -113,7 +114,7 @@ def _ring_attention_flash(q, k, v, axis_name, key_bias, causal, sm_scale,
     causal mask is always position-correct. Gradients flow through both
     kernel outputs (ops.flash_attention._flash_lse_bwd) and the combine.
     """
-    from ..ops.flash_attention import flash_attention_lse
+    from ..ops.flash_attention import flash_attention_lse, merge_lse
 
     n = lax.psum(1, axis_name)
     idx = lax.axis_index(axis_name)
@@ -126,12 +127,6 @@ def _ring_attention_flash(q, k, v, axis_name, key_bias, causal, sm_scale,
     lse = jnp.full((B, H, Tl), -1e30, jnp.float32)
     kc, vc, kbc = k, v, key_bias
     perm = [(i, (i + 1) % n) for i in range(n)]
-
-    def merge(o, lse, o_s, lse_s):
-        lse_new = jnp.logaddexp(lse, lse_s)
-        w = jnp.exp(lse - lse_new)[..., None]
-        w_s = jnp.exp(lse_s - lse_new)[..., None]
-        return o * w + o_s.astype(jnp.float32) * w_s, lse_new
 
     for s in range(int(n)):
         src = (idx - s) % n           # whose kv shard we currently hold
@@ -157,7 +152,7 @@ def _ring_attention_flash(q, k, v, axis_name, key_bias, causal, sm_scale,
             o_s, lse_s = flash_attention_lse(q, kc, vc, key_bias=kbc,
                                              causal=False, sm_scale=sm_scale,
                                              interpret=interpret)
-        o, lse = merge(o, lse, o_s, lse_s)
+        o, lse = merge_lse(o, lse, o_s, lse_s)
         if s != n - 1:   # the last shard needs no further rotation
             kc = lax.ppermute(kc, axis_name, perm)
             vc = lax.ppermute(vc, axis_name, perm)

@@ -89,8 +89,8 @@ def _ssd(x):
 # that lowering.amp_cast cast, each built the way a model does. Six give
 # their result back in the dtype they were fed; attention gives it in the
 # dtype of its operands, and the output projection that follows takes it
-# so. (causal_conv1d and gated_rms_norm call amp_cast too, for what their
-# backward keeps, and multiply nothing on the MXU.)
+# so. (causal_conv1d, gated_rms_norm and chunk_softmax_pool call amp_cast
+# too, for what their backward keeps, and multiply nothing on the MXU.)
 _MXU_OPS = {
     'mul': ((4, 8), lambda x: fluid.layers.fc(input=x, size=16), 'float32'),
     'matmul': ((4, 8), lambda x: fluid.layers.matmul(x, x, transpose_y=True),

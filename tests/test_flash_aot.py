@@ -678,3 +678,58 @@ def test_an_eighth_held_compiles_both_paths_for_v5e(
     # the compact path sorts its tokens x k keys as ONE operand (a result
     # that is an array, not argsort's pair)
     assert re.search(r'= s32\[%d\]\S* sort\(' % (tokens * k), text)
+
+
+# EvaByte's attention (PR 59): exact inside aligned windows of 2048 and,
+# beyond them, over one summary a chunk of 16. The staircase's tiles are
+# 512 queries x 128 summaries (a window's summaries are ONE lane tile of
+# keys), far inside Mosaic's default scope; the aligned part is a causal
+# call over rows of 2048 (four 512-tiles, the one-pass `head` backward at
+# its stated limit). Mosaic calls of a forward and backward: the aligned
+# part's 2 and the staircase's 3 (forward, dq, dk/dv).
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+@pytest.mark.parametrize('seq', [8192, 32768],
+                         ids=['s8192_512_summaries', 's32768_2048_summaries'])
+def test_staircase_and_aligned_windows_compile_for_v5e(one_chip, dtype, seq):
+    """`flash_attention_summary` forward and backward at (1, 32, 8192,
+    128) against 512 summaries (evabyte_s8192) and at the published row
+    of 32768 against 2048, in the cell's bf16 and in its float32 check's
+    arithmetic (traced under jax's highest matmul precision, as that
+    check is), with the staircase's grids holding only admitted blocks."""
+    from paddle_tpu.ops import flash_attention_summary
+    from paddle_tpu.ops.flash_attention import (_stair_maps, _stair_maps_kv,
+                                                summary_blocks)
+    window, every = 2048, 16
+    assert summary_blocks(window, every) == (512, 128)
+    dt = jnp.dtype(dtype)
+    x = jax.ShapeDtypeStruct((1, 32, seq, 128), dt, sharding=one_chip)
+    s = jax.ShapeDtypeStruct((1, 32, seq // every, 128), dt,
+                             sharding=one_chip)
+
+    def loss(q, k, v, kbar, vbar):
+        o = flash_attention_summary(q, k, v, kbar, vbar, window=window,
+                                    every=every, interpret=False)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    before = _schedules()
+    with jax.default_matmul_precision(
+            'highest' if dtype == 'float32' else 'default'):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            x, x, x, s, s).compile()
+    after = _schedules()
+    assert after['head'] - before['head'] == 1         # the aligned part
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 5
+    # no array of a head's scores: [.., 8192, 8192] or [.., 8192, 512]
+    assert not re.search(r'\[(?:\d+,)*%d,(?:%d|%d)\]' % (
+        seq, seq, seq // every), text)
+    # the staircase visits the admitted (q-block, summary-block) pairs and
+    # no other: windows - 1 windows of queries, window w seeing w windows
+    # of summaries
+    windows = seq // window
+    nq = (windows - 1) * 4
+    pairs = sum(w * 4 for w in range(1, windows))
+    for maps in (_stair_maps(nq, 4, 1), _stair_maps_kv(nq, 4, 1)):
+        got = set(zip(maps[0].tolist(), maps[1].tolist()))
+        assert len(got) == len(maps[0]) == pairs
+        assert got == {(i, j) for i in range(nq) for j in range(i // 4 + 1)}

@@ -594,6 +594,11 @@ _SWEEP = {
                     n_group=4, topk_group=2, expert_width=16,
                     experts_held=(2, 4)),
         feeds_idx=4, stack=True),
+    'evabyte': dict(
+        kwargs=dict(batch_size=2, seq_len=32, vocab_size=64, n_layer=1,
+                    hidden=32, n_head=2, d_head=16, mlp_width=64,
+                    chunk_size=4, window_size=16, n_pred_heads=4),
+        feeds_idx=4, stack=True),
 }
 
 

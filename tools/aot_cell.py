@@ -3,7 +3,7 @@ no chip, and prints what the compiler says of it: PERF.md's batch rule
 (section 4) reads `memory_analysis()` here.
 
     JAX_PLATFORMS=cpu python tools/aot_cell.py --workload olmoe_s4096
-        [--batch N] [--hlo FILE] [--check NAME]
+        [--batch N] [--seq T] [--hlo FILE] [--check NAME]
 
 Builds the cell's Program at its published widths, runs the start-up
 program on the host (the step's arguments need shapes, not values), lowers
@@ -35,6 +35,8 @@ def main(argv=None):
     p = argparse.ArgumentParser(description=__doc__.split('\n\n')[0])
     p.add_argument('--workload', required=True)
     p.add_argument('--batch', type=int)
+    p.add_argument('--seq', type=int,
+                   help="the traffic's row length in place of the cell's")
     p.add_argument('--hlo', help='write the optimized HLO text here')
     p.add_argument('--check', help="compile this entry of `checks` instead")
     args = p.parse_args(argv)
@@ -52,6 +54,8 @@ def main(argv=None):
     config, traffic = cell['config'], dict(cell['traffic'])
     if args.batch:
         traffic['batch'] = args.batch
+    if args.seq:
+        traffic['seq'] = args.seq
     precision = None
     if args.check:
         # as harness/check.py run_check builds it
