@@ -48,6 +48,30 @@ BAND of blocks a window touches, and the mask gets its second edge. Where
 the triangular grid does not apply (or the backward is one tile) a window
 is the mask alone.
 
+What is masked where. On the rectangular grid and in the one-tile backward
+every pair of a causal call COMPUTES its mask (_mask_causal: two iotas, a
+subtraction, one or two compares, a select on the score tile). On the
+triangular grid and its band no pair does: because the tiles are square,
+every pair on one block diagonal d = i - j has the same mask, and only the
+diagonal itself and the one or two diagonals a window's far edge crosses
+hold a position the mask removes (_mask_diffs; 32 of the 528 pairs of
+16384 positions in 512-blocks, 56 of the 252 under a window of 4096). So
+the masks are two to four additive float32 tiles, 0 where a key is seen
+and NEG_BIG where not, filled into VMEM scratch once a head
+(_fill_mask_tiles), and every pair adds the tile of its KIND, tile 0 being
+zeros: s = dot * scale + tile[kind]. The kind is scalar arithmetic on the
+prefetched block coordinates and picks an operand; every pair runs the
+same instructions, so nothing is predicated (the strategy note above
+_tri_maps). An interior pair's scores are what a computed mask left to
+the bit; a masked position reads s + NEG_BIG and not NEG_BIG, and exp of
+either minus any live row's maximum is exactly 0 in float32, so p, l, the
+accumulators and every gradient are unchanged. The staircase grid admits
+or leaves out whole blocks and masks nothing. A key bias is an operand
+only where there is one to add: a call on the triangle or the band
+without a `key_bias` and without padded keys hands in none and its bodies
+add none, and the staircase never has one. `flash.tiles_masked{grid=}`
+counts, beside `flash.tiles{grid=}`, the pairs that still pay for a mask.
+
 What is float32 and what follows the input. The q, k, v and do tiles go
 into the MXU in the dtype of their refs (bf16 under AMP, float32 in a
 Program without it), and p and ds are cast to that dtype only as operands
@@ -66,8 +90,11 @@ block step waited for (PERF.md, PR 24). The counters
 `flash.backward{passes=one, span=tile|head}` / `{passes=two}` count
 attention calls per lowering; `flash.tiles{grid=}` adds up the (q-block,
 k-block) pairs a head that a call's grids visit (forward + dq + dk/dv;
-forward + the one pass under 'head'), so a lowering says off the chip
-whether the band was taken, what it spared, and which backward ran.
+forward + the one pass under 'head') and `flash.tiles_masked{grid=}` those
+of them that add a mask tile which is not zeros (triangle, band) or
+compute a mask (every pair of a causal call elsewhere), so a lowering says
+off the chip whether the band was taken, what it spared, which backward
+ran, and what share of the pairs still pays for a mask.
 
 `interpret` is the CALLER's decision, never read off the process's
 default backend: the op lowering passes interpret=False on a TPU place
@@ -163,12 +190,24 @@ def _lanes(x, n):
 #                  else changes: still one visit a pair, still no
 #                  predication; the accumulators start at a row's (a
 #                  column's) first pair IN THE BAND and end at its last.
-#                  The pairs at the band's two edges hold positions the
-#                  mask removes (_mask_causal knows both edges). 16384
-#                  positions in 512-blocks under a window of 4096: 252 of
-#                  the triangle's 528 pairs a head. A windowed call
+#                  16384 positions in 512-blocks under a window of 4096:
+#                  252 of the triangle's 528 pairs a head. A windowed call
 #                  outside _use_tri's conditions takes the rectangular
 #                  grid with the same mask and skips nothing.
+#   the mask       — on the rectangular grid every causal pair computes it
+#                  (_mask_causal). On the triangle and the band only the
+#                  pairs ON the diagonal and on the diagonals a window's
+#                  far edge crosses hold a position it removes
+#                  (_mask_diffs), and all pairs of one diagonal share one
+#                  mask; so the kernels hold the masks as additive tiles
+#                  in VMEM scratch (_fill_mask_tiles, once a head) and
+#                  EVERY pair adds the tile of its kind (_pair_kind), zeros
+#                  for the interior. A conditional round the mask, taken
+#                  by those pairs only, cost a quarter (docs/perf.md, PR
+#                  42): choosing an operand costs nothing of the kind,
+#                  because all pairs still run one instruction stream.
+#                  `flash.tiles_masked{grid=}` counts the pairs whose tile
+#                  is not zeros.
 # ---------------------------------------------------------------------------
 
 
@@ -218,6 +257,63 @@ def _tri_maps_kv(n, nb=None):
             np.concatenate(jj).astype(np.int32))
 
 
+def _mask_diffs(window, bk, n):
+    """The block diagonals d = i - j of the triangle of n blocks (or its
+    band) whose pairs hold a position the mask removes: the diagonal
+    itself (keys after their query, and under a window shorter than a
+    block the keys too far back as well) and, under a window, the one or
+    two diagonals its far edge crosses: floor(window / bk) up to
+    ceil((window - 1) / bk), the band's lower edge. They are the same
+    one where the window is a whole number of blocks or one key more
+    (4096 under 512-blocks: 0 and 8), two where it ends inside a block.
+    Every pair of one diagonal has the SAME mask, because the tiles are
+    square, and every other pair of the grid has none."""
+    if window is None:
+        return (0,)
+    return (0,) + tuple(range(max(1, window // bk),
+                              min(-(-(window - 1) // bk), n - 1) + 1))
+
+
+def _masked_pairs(n, diffs):
+    """How many pairs of the triangle of n blocks, or of its band, lie on
+    the masked diagonals `diffs`: n - d on diagonal d."""
+    return sum(n - d for d in diffs)
+
+
+def _pair_kind(d, diffs):
+    """Which mask tile a pair on block diagonal d = i - j takes: 1 + its
+    place in `diffs`, or 0, the tile of zeros, for a pair that holds
+    nothing to mask. Scalar arithmetic on the pair's prefetched
+    coordinates (or numpy's, in the tests); it chooses an OPERAND of the
+    body's one add, never an instruction."""
+    kind = 0
+    for n, masked in enumerate(diffs):
+        kind = jnp.where(d == masked, n + 1, kind)
+    return kind
+
+
+def _mask_tile(tiles, i, j):
+    """The tile pair (i, j) adds to its scores, of `tiles` = (the scratch
+    _fill_mask_tiles filled, its diagonals)."""
+    tiles_s, diffs = tiles
+    return tiles_s[_pair_kind(i - j, diffs)]
+
+
+def _fill_mask_tiles(tiles_s, t, diffs, block, q_axis, window):
+    """At a head's first pair, the mask of every kind of pair as an
+    additive float32 tile in VMEM scratch: tile 0 zeros, tile 1 + n
+    _mask_causal's own arithmetic for a pair on diagonal diffs[n] (0
+    where a position is seen, NEG_BIG where not), queries along q_axis.
+    Two to four tiles serve the whole call; no pair computes a mask."""
+    @pl.when(t == 0)
+    def _fill():
+        zeros = jnp.zeros(tiles_s.shape[1:], tiles_s.dtype)
+        tiles_s[0] = zeros
+        for n, d in enumerate(diffs):
+            tiles_s[n + 1] = _mask_causal(zeros, d * block, 0, q_axis,
+                                          window)
+
+
 def _stair_maps(nq, qpw, spw):
     """Row-major enumeration of a STAIRCASE: q-block i, of window i // qpw
     (qpw q-blocks a window), sees the summary blocks 0 .. (i // qpw + 1) *
@@ -249,7 +345,10 @@ def _stair_maps_kv(nq, qpw, spw):
 
 def _fwd_body(q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref,
               m_s, l_s, acc_s, i, j, is_first, is_last, *,
-              scale, causal, block_q, block_k, window=None):
+              scale, causal, block_q, block_k, window=None, tiles=None):
+    """One pair of the forward. `tiles` (the triangular grid and its
+    band): the mask is the tile of the pair's kind, added; without them a
+    causal pair computes its mask. kb_ref None: no key bias to add."""
     @pl.when(is_first)
     def _init():
         m_s[:] = jnp.full_like(m_s, -1e30)
@@ -261,8 +360,11 @@ def _fwd_body(q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref,
         kb = k_ref[0, 0]                                       # [bk, D]
         vb = v_ref[0, 0]
         s = _dot(q, kb, _NT) * scale
-        s = s + kb_ref[0]
-        if causal:
+        if kb_ref is not None:
+            s = s + kb_ref[0]
+        if tiles is not None:
+            s = s + _mask_tile(tiles, i, j)
+        elif causal:
             s = _mask_causal(s, i * block_q, j * block_k, 0, window)
         m_prev = m_s[:]                                        # [bq, LANES]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -301,16 +403,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref,
 
 
 def _fwd_kernel_tri(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref,
-                    o_ref, lse_ref, m_s, l_s, acc_s, *,
-                    scale, block_q, block_k, window=None, nb=None):
+                    o_ref, lse_ref, m_s, l_s, acc_s, tiles_s, *,
+                    scale, block_q, block_k, diffs, window=None, nb=None):
     t = pl.program_id(2)
     i, j = im_ref[t], jm_ref[t]
+    _fill_mask_tiles(tiles_s, t, diffs, block_q, 0, window)
     # j == 0 (in a band: the row's first block in it) starts row i;
     # j == i is the diagonal block, last for row i
     _fwd_body(q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref, m_s, l_s, acc_s,
               i, j, _row_start(i, j, nb), j == i,
               scale=scale, causal=True, block_q=block_q, block_k=block_k,
-              window=window)
+              tiles=(tiles_s, diffs))
 
 
 def _stair_row_end(i, qpw, spw):
@@ -319,14 +422,14 @@ def _stair_row_end(i, qpw, spw):
     return (i // qpw + 1) * spw - 1
 
 
-def _fwd_kernel_stair(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref,
+def _fwd_kernel_stair(im_ref, jm_ref, q_ref, k_ref, v_ref,
                       o_ref, lse_ref, m_s, l_s, acc_s, *,
                       scale, block_q, block_k, qpw, spw):
     t = pl.program_id(2)
     i, j = im_ref[t], jm_ref[t]
     # a q-block's row runs from summary block 0; nothing inside a tile is
-    # masked
-    _fwd_body(q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref, m_s, l_s, acc_s,
+    # masked and no summary has a bias
+    _fwd_body(q_ref, k_ref, v_ref, None, o_ref, lse_ref, m_s, l_s, acc_s,
               i, j, j == 0, j == _stair_row_end(i, qpw, spw),
               scale=scale, causal=False, block_q=block_q, block_k=block_k)
 
@@ -337,7 +440,7 @@ def _fwd_kernel_stair(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref,
 
 def _bwd_dq_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
                  dq_ref, dq_s, i, j, is_first, is_last, *,
-                 scale, causal, block_q, block_k, window=None):
+                 scale, causal, block_q, block_k, window=None, tiles=None):
     @pl.when(is_first)
     def _init():
         dq_s[:] = jnp.zeros_like(dq_s)
@@ -350,8 +453,11 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
         lse = _lanes(lse_ref[0, 0], block_k)                   # [bq, bk]
         delta = _lanes(delta_ref[0, 0], block_k)
         s = _dot(q, kb, _NT) * scale
-        s = s + kb_ref[0]
-        if causal:
+        if kb_ref is not None:
+            s = s + kb_ref[0]
+        if tiles is not None:
+            s = s + _mask_tile(tiles, i, j)
+        elif causal:
             s = _mask_causal(s, i * block_q, j * block_k, 0, window)
         p = jnp.exp(s - lse)
         dp = _dot(do, vb, _NT)
@@ -377,22 +483,25 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dq_kernel_tri(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
-                       lse_ref, delta_ref, dq_ref, dq_s, *,
-                       scale, block_q, block_k, window=None, nb=None):
+                       lse_ref, delta_ref, dq_ref, dq_s, tiles_s, *,
+                       scale, block_q, block_k, diffs, window=None, nb=None):
     t = pl.program_id(2)
     i, j = im_ref[t], jm_ref[t]
+    _fill_mask_tiles(tiles_s, t, diffs, block_q, 0, window)
     _bwd_dq_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
                  dq_ref, dq_s, i, j, _row_start(i, j, nb), j == i,
                  scale=scale, causal=True, block_q=block_q, block_k=block_k,
-                 window=window)
+                 tiles=(tiles_s, diffs))
 
 
 def _bwd_dkv_pair(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
                   dk_s, dv_s, i, j, *, scale, causal, block_q, block_k,
-                  window=None):
+                  window=None, tiles=None):
     """One (q-block i, k-block j) pair's s^T, p^T, dp^T and ds^T, added
     into the dk and dv accumulators. Returns the k tile and ds^T as a
-    dot's operand, which is all that dq needs besides."""
+    dot's operand, which is all that dq needs besides. `tiles` and a
+    kb_ref of None as in _fwd_body, the tiles transposed as the scores
+    are."""
     # the scores TRANSPOSED, [bk, bq]: both accumulators then take
     # their p^T and ds^T as computed, and no [bq, bk] tile is turned
     # round. What it costs is the three small vectors below.
@@ -402,9 +511,12 @@ def _bwd_dkv_pair(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
     dob = do_ref[0, 0]
     lse_b = lse_ref[0, 0].T[:1]                                # [1, bq]
     delta_b = delta_ref[0, 0].T[:1]
-    kb = jnp.broadcast_to(kb_ref[0], (LANES, block_k)).T[:, :1]
-    st = _dot(k, qb, _NT) * scale + kb                         # [bk, bq]
-    if causal:
+    st = _dot(k, qb, _NT) * scale                              # [bk, bq]
+    if kb_ref is not None:
+        st = st + jnp.broadcast_to(kb_ref[0], (LANES, block_k)).T[:, :1]
+    if tiles is not None:
+        st = st + _mask_tile(tiles, i, j)
+    elif causal:
         st = _mask_causal(st, i * block_q, j * block_k, 1, window)
     pt = jnp.exp(st - lse_b)
     dv_s[:] = dv_s[:] + _dot(pt.astype(dob.dtype), dob, _NN)
@@ -416,7 +528,7 @@ def _bwd_dkv_pair(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
                   dk_ref, dv_ref, dk_s, dv_s, i, j, is_first, is_last, *,
-                  scale, causal, block_q, block_k, window=None):
+                  scale, causal, block_q, block_k, window=None, tiles=None):
     @pl.when(is_first)
     def _init():
         dk_s[:] = jnp.zeros_like(dk_s)
@@ -424,7 +536,8 @@ def _bwd_dkv_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
 
     _bwd_dkv_pair(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
                   dk_s, dv_s, i, j, scale=scale, causal=causal,
-                  block_q=block_q, block_k=block_k, window=window)
+                  block_q=block_q, block_k=block_k, window=window,
+                  tiles=tiles)
 
     @pl.when(is_last)
     def _finish():
@@ -444,10 +557,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dkv_kernel_tri(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
-                        lse_ref, delta_ref, dk_ref, dv_ref, dk_s, dv_s, *,
-                        scale, block_q, block_k, nq, window=None, nb=None):
+                        lse_ref, delta_ref, dk_ref, dv_ref, dk_s, dv_s,
+                        tiles_s, *, scale, block_q, block_k, nq, diffs,
+                        window=None, nb=None):
     t = pl.program_id(2)
     i, j = im_ref[t], jm_ref[t]
+    _fill_mask_tiles(tiles_s, t, diffs, block_q, 1, window)
     # contributing q-blocks for k-block j run i = j..nq-1 (tri_maps_kv
     # order): the accumulator starts at the diagonal and ends at the last
     # q-block, in a band of nb at the last q-block that still sees j
@@ -455,20 +570,20 @@ def _bwd_dkv_kernel_tri(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
     _bwd_dkv_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
                   dk_ref, dv_ref, dk_s, dv_s, i, j, i == j, i == last,
                   scale=scale, causal=True,
-                  block_q=block_q, block_k=block_k, window=window)
+                  block_q=block_q, block_k=block_k, tiles=(tiles_s, diffs))
 
 
-def _bwd_dq_kernel_stair(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
+def _bwd_dq_kernel_stair(im_ref, jm_ref, q_ref, k_ref, v_ref, do_ref,
                          lse_ref, delta_ref, dq_ref, dq_s, *,
                          scale, block_q, block_k, qpw, spw):
     t = pl.program_id(2)
     i, j = im_ref[t], jm_ref[t]
-    _bwd_dq_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
+    _bwd_dq_body(q_ref, k_ref, v_ref, None, do_ref, lse_ref, delta_ref,
                  dq_ref, dq_s, i, j, j == 0, j == _stair_row_end(i, qpw, spw),
                  scale=scale, causal=False, block_q=block_q, block_k=block_k)
 
 
-def _bwd_dkv_kernel_stair(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref,
+def _bwd_dkv_kernel_stair(im_ref, jm_ref, q_ref, k_ref, v_ref,
                           do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
                           dk_s, dv_s, *, scale, block_q, block_k, nq, qpw,
                           spw):
@@ -476,7 +591,7 @@ def _bwd_dkv_kernel_stair(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref,
     i, j = im_ref[t], jm_ref[t]
     # summary block j's q-blocks run from the first of the window after
     # its own to the row's last (_stair_maps_kv order)
-    _bwd_dkv_body(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref, delta_ref,
+    _bwd_dkv_body(q_ref, k_ref, v_ref, None, do_ref, lse_ref, delta_ref,
                   dk_ref, dv_ref, dk_s, dv_s, i, j, i == (j // spw) * qpw,
                   i == nq - 1, scale=scale, causal=False,
                   block_q=block_q, block_k=block_k)
@@ -484,8 +599,8 @@ def _bwd_dkv_kernel_stair(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref,
 
 def _bwd_head_kernel(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
                      lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-                     dq_s, dk_s, dv_s, *, scale, block_q, block_k, nq,
-                     window=None, nb=None):
+                     dq_s, dk_s, dv_s, tiles_s, *, scale, block_q, block_k,
+                     nq, diffs, window=None, nb=None):
     """The whole backward of a causal head of MORE than one tile in one
     pass over its triangle or band, the pairs in _tri_maps_kv order: a
     pair's s^T, p^T, dp^T and ds^T are computed once (_bwd_dkv_pair) and
@@ -505,6 +620,7 @@ def _bwd_head_kernel(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
     t = pl.program_id(2)
     i, j = im_ref[t], jm_ref[t]
     last = nq - 1 if nb is None else jnp.minimum(j + nb, nq - 1)
+    _fill_mask_tiles(tiles_s, t, diffs, block_q, 1, window)
 
     @pl.when(i == j)
     def _init():
@@ -515,7 +631,7 @@ def _bwd_head_kernel(im_ref, jm_ref, q_ref, k_ref, v_ref, kb_ref, do_ref,
     k, dsc = _bwd_dkv_pair(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
                            delta_ref, dk_s, dv_s, i, j, scale=scale,
                            causal=True, block_q=block_q, block_k=block_k,
-                           window=window)
+                           tiles=(tiles_s, diffs))
     kt = k.astype(jnp.float32).T.astype(k.dtype)               # [D, bk]
     dq_s[i] = dq_s[i] + _dot(kt, dsc, _NN).T
 
@@ -619,6 +735,26 @@ def _tri_specs(bq, bk, D, Dv):
     return qrow, kcol, kbias, stats, row(Dv), col(Dv)
 
 
+def _bias_args(kernel, kb, kbias, **static):
+    """(kernel(**static), in_specs, operands) of a triangular or band
+    call's key bias. With no bias (kb None) the call has no such operand,
+    and the kernel's kb_ref, the sixth ref of every such kernel, is None:
+    the bodies then add none. The kernel keeps its name."""
+    if kb is not None:
+        return functools.partial(kernel, **static), [kbias], [kb]
+
+    @functools.wraps(kernel)
+    def without(*refs, **static):
+        return kernel(*refs[:5], None, *refs[5:], **static)
+    return functools.partial(without, **static), [], []
+
+
+def _mask_tiles(diffs, bq, bk):
+    """The VMEM scratch of a triangular or band call's mask tiles: the
+    tile of zeros and one a masked diagonal (_fill_mask_tiles)."""
+    return pltpu.VMEM((len(diffs) + 1, bq, bk), jnp.float32)
+
+
 def _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret, window=None):
     B, H, Tq, D = q.shape
     Tk, Dv = k.shape[2], v.shape[3]
@@ -633,23 +769,24 @@ def _fwd_call(q, k, v, kb, causal, scale, bq, bk, interpret, window=None):
     ]
     if _use_tri(causal, Tq, Tk, bq, bk):
         nb = _band(window, bk, Tq // bq)
+        diffs = _mask_diffs(window, bk, Tq // bq)
         im, jm = _tri_maps(Tq // bq, nb)
         qrow, kcol, kbias, stats, orow, vcol = _tri_specs(bq, bk, D, Dv)
-        kern = functools.partial(_fwd_kernel_tri, scale=scale,
-                                 block_q=bq, block_k=bk, window=window,
-                                 nb=nb)
+        kern, kb_spec, kb_arg = _bias_args(
+            _fwd_kernel_tri, kb, kbias, scale=scale, block_q=bq, block_k=bk,
+            diffs=diffs, window=window, nb=nb)
         return pl.pallas_call(
             kern,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(B, H, len(im)),
-                in_specs=[qrow, kcol, vcol, kbias],
+                in_specs=[qrow, kcol, vcol] + kb_spec,
                 out_specs=[orow, stats],
-                scratch_shapes=scratch_shapes,
+                scratch_shapes=scratch_shapes + [_mask_tiles(diffs, bq, bk)],
             ),
             out_shape=out_shape,
             interpret=interpret,
-        )(jnp.asarray(im), jnp.asarray(jm), q, k, v, kb)
+        )(jnp.asarray(im), jnp.asarray(jm), q, k, v, *kb_arg)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                              block_q=bq, block_k=bk, window=window)
     return pl.pallas_call(
@@ -681,27 +818,32 @@ def _bwd_call_tri(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret,
     Dv = v.shape[3]
     nq = Tq // bq
     nb = _band(window, bk, nq)
+    diffs = _mask_diffs(window, bk, nq)
     qrow, kcol, kbias, stats, orow, vcol = _tri_specs(bq, bk, D, Dv)
-    bwd_in_specs = [qrow, kcol, vcol, kbias, orow, stats, stats]
     im, jm = _tri_maps(nq, nb)
+    kern, kb_spec, kb_arg = _bias_args(
+        _bwd_dq_kernel_tri, kb, kbias, scale=scale, block_q=bq, block_k=bk,
+        diffs=diffs, window=window, nb=nb)
+    bwd_in_specs = [qrow, kcol, vcol] + kb_spec + [orow, stats, stats]
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel_tri, scale=scale,
-                          block_q=bq, block_k=bk, window=window, nb=nb),
+        kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, H, len(im)),
             in_specs=bwd_in_specs,
             out_specs=qrow,
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
+                            _mask_tiles(diffs, bq, bk)],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(jnp.asarray(im), jnp.asarray(jm), q, k, v, kb, do, lse, delta)
+    )(jnp.asarray(im), jnp.asarray(jm), q, k, v, *kb_arg, do, lse, delta)
     im2, jm2 = _tri_maps_kv(nq, nb)
+    kern, _, _ = _bias_args(
+        _bwd_dkv_kernel_tri, kb, kbias, scale=scale, block_q=bq, block_k=bk,
+        nq=nq, diffs=diffs, window=window, nb=nb)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel_tri, scale=scale,
-                          block_q=bq, block_k=bk, nq=nq, window=window,
-                          nb=nb),
+        kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, H, len(im2)),
@@ -710,6 +852,7 @@ def _bwd_call_tri(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret,
             scratch_shapes=[
                 pltpu.VMEM((bk, D), jnp.float32),
                 pltpu.VMEM((bk, Dv), jnp.float32),
+                _mask_tiles(diffs, bk, bq),
             ],
         ),
         out_shape=[
@@ -717,7 +860,7 @@ def _bwd_call_tri(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
-    )(jnp.asarray(im2), jnp.asarray(jm2), q, k, v, kb, do, lse, delta)
+    )(jnp.asarray(im2), jnp.asarray(jm2), q, k, v, *kb_arg, do, lse, delta)
     return dq, dk, dv
 
 
@@ -726,8 +869,10 @@ def _head_vmem_limit(T, D, bq, bk, itemsize):
     what it holds (a head's dq in float32 and the two buffers of its
     output block, a grid step's blocks twice over: q, do, k, v in, dk, dv
     out, lse and delta, the bias in its sublane tile; the dk and dv
-    accumulators) plus Mosaic's default scope for the body's score tiles,
-    as the two kernels have it at the same tiles. A head narrower than a
+    accumulators; the mask's tiles, counted as the four a window that
+    ends inside a block takes) plus Mosaic's default scope for the body's
+    score tiles, as the two kernels have it at the same tiles. A head
+    narrower than a
     lane tile takes a whole one in VMEM: D = 64 is counted as 128 (float32
     operands of D = 64 over 16384 positions, traced under highest
     precision, were refused by 1.75 MiB at the narrow count; compiled for
@@ -737,7 +882,8 @@ def _head_vmem_limit(T, D, bq, bk, itemsize):
     dq = T * D * (4 + 2 * itemsize)
     blocks = 2 * ((2 * bq + 4 * bk) * D * itemsize + 2 * bq * LANES * 4
                   + 8 * bk * 4)
-    return dq + blocks + 2 * bk * D * 4 + _MOSAIC_SCOPE_BYTES
+    return (dq + blocks + 2 * bk * D * 4 + 4 * bq * bk * 4
+            + _MOSAIC_SCOPE_BYTES)
 
 
 def _bwd_call_head(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret,
@@ -750,22 +896,26 @@ def _bwd_call_head(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret,
     Dv = v.shape[3]
     nq = Tq // bq
     nb = _band(window, bk, nq)
+    diffs = _mask_diffs(window, bk, nq)
     qrow, kcol, kbias, stats, orow, vcol = _tri_specs(bq, bk, D, Dv)
     head = pl.BlockSpec((1, 1, nq, bq, D),
                         lambda b, h, t, im, jm: (b, h, 0, 0, 0))
     im, jm = _tri_maps_kv(nq, nb)
+    kern, kb_spec, kb_arg = _bias_args(
+        _bwd_head_kernel, kb, kbias, scale=scale, block_q=bq, block_k=bk,
+        nq=nq, diffs=diffs, window=window, nb=nb)
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_head_kernel, scale=scale, block_q=bq,
-                          block_k=bk, nq=nq, window=window, nb=nb),
+        kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, H, len(im)),
-            in_specs=[qrow, kcol, vcol, kbias, orow, stats, stats],
+            in_specs=[qrow, kcol, vcol] + kb_spec + [orow, stats, stats],
             out_specs=[head, kcol, vcol],
             scratch_shapes=[
                 pltpu.VMEM((nq, bq, D), jnp.float32),
                 pltpu.VMEM((bk, D), jnp.float32),
                 pltpu.VMEM((bk, Dv), jnp.float32),
+                _mask_tiles(diffs, bk, bq),
             ],
         ),
         out_shape=[
@@ -777,7 +927,7 @@ def _bwd_call_head(q, k, v, kb, do, lse, delta, scale, bq, bk, interpret,
             vmem_limit_bytes=_head_vmem_limit(Tq, D, bq, bk,
                                               q.dtype.itemsize)),
         interpret=interpret,
-    )(jnp.asarray(im), jnp.asarray(jm), q, k, v, kb, do, lse, delta)
+    )(jnp.asarray(im), jnp.asarray(jm), q, k, v, *kb_arg, do, lse, delta)
     return dq.reshape(q.shape), dk, dv
 
 
@@ -787,6 +937,9 @@ def _bwd_call_fused(q, k, v, kb, do, lse, delta, causal, scale, sub_q,
     for dq, dk and dv (_bwd_fused_kernel)."""
     B, H, Tq, D = q.shape
     Tk, Dv = k.shape[2], v.shape[3]
+    if kb is None:
+        # a forward on the triangular grid that took no bias
+        kb = jnp.zeros((B, 1, Tk), jnp.float32)
     qrow = pl.BlockSpec((1, 1, Tq, D), lambda b, h: (b, h, 0, 0))
     kcol = pl.BlockSpec((1, 1, Tk, D), lambda b, h: (b, h, 0, 0))
     orow, vcol = qrow, kcol
@@ -923,7 +1076,7 @@ def _flash_lse_bwd(causal, window, scale, bq, bk, schedule, interpret, res,
     dq, dk, dv = _bwd_call(q, k, v, kb, do, lse, delta, causal, scale,
                            bq, bk, schedule, interpret, window)
     # kb is a mask constant (see module docstring): zero cotangent
-    return dq, dk, dv, jnp.zeros_like(kb)
+    return dq, dk, dv, None if kb is None else jnp.zeros_like(kb)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -933,21 +1086,21 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 # their Mosaic events from the aligned part's by the function they were
 # called in (chipbench/harness/scopes.py callee_of), as jax's megablox
 # kernels are told apart.
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
-def staircase_fwd(q, k, v, kb, scale, bq, bk, qpw, spw, interpret):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+def staircase_fwd(q, k, v, scale, bq, bk, qpw, spw, interpret):
     """The forward over the staircase grid (_stair_maps): grid (B, H,
-    pairs), the forward body as it is, unmasked."""
+    pairs), the forward body as it is, unmasked and with no bias."""
     B, H, Tq, D = q.shape
     Dv = v.shape[3]
     im, jm = _stair_maps(Tq // bq, qpw, spw)
-    qrow, kcol, kbias, stats, orow, vcol = _tri_specs(bq, bk, D, Dv)
+    qrow, kcol, _, stats, orow, vcol = _tri_specs(bq, bk, D, Dv)
     return pl.pallas_call(
         functools.partial(_fwd_kernel_stair, scale=scale, block_q=bq,
                           block_k=bk, qpw=qpw, spw=spw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, H, len(im)),
-            in_specs=[qrow, kcol, vcol, kbias],
+            in_specs=[qrow, kcol, vcol],
             out_specs=[orow, stats],
             scratch_shapes=[
                 pltpu.VMEM((bq, LANES), jnp.float32),
@@ -960,11 +1113,11 @@ def staircase_fwd(q, k, v, kb, scale, bq, bk, qpw, spw, interpret):
             jax.ShapeDtypeStruct((B, H, Tq, LANES), jnp.float32),
         ],
         interpret=interpret,
-    )(jnp.asarray(im), jnp.asarray(jm), q, k, v, kb)
+    )(jnp.asarray(im), jnp.asarray(jm), q, k, v)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12))
-def staircase_bwd(q, k, v, kb, do, lse, delta, scale, bq, bk, qpw, spw,
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+def staircase_bwd(q, k, v, do, lse, delta, scale, bq, bk, qpw, spw,
                   interpret):
     """The backward over the same pairs in two passes: dq over a q-row's
     summary blocks, dk/dv over a summary block's q-blocks
@@ -972,8 +1125,8 @@ def staircase_bwd(q, k, v, kb, do, lse, delta, scale, bq, bk, qpw, spw,
     B, H, Tq, D = q.shape
     Dv = v.shape[3]
     nq = Tq // bq
-    qrow, kcol, kbias, stats, orow, vcol = _tri_specs(bq, bk, D, Dv)
-    bwd_in_specs = [qrow, kcol, vcol, kbias, orow, stats, stats]
+    qrow, kcol, _, stats, orow, vcol = _tri_specs(bq, bk, D, Dv)
+    bwd_in_specs = [qrow, kcol, vcol, orow, stats, stats]
     im, jm = _stair_maps(nq, qpw, spw)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel_stair, scale=scale, block_q=bq,
@@ -987,7 +1140,7 @@ def staircase_bwd(q, k, v, kb, do, lse, delta, scale, bq, bk, qpw, spw,
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(jnp.asarray(im), jnp.asarray(jm), q, k, v, kb, do, lse, delta)
+    )(jnp.asarray(im), jnp.asarray(jm), q, k, v, do, lse, delta)
     im2, jm2 = _stair_maps_kv(nq, qpw, spw)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel_stair, scale=scale, block_q=bq,
@@ -1007,32 +1160,30 @@ def staircase_bwd(q, k, v, kb, do, lse, delta, scale, bq, bk, qpw, spw,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
-    )(jnp.asarray(im2), jnp.asarray(jm2), q, k, v, kb, do, lse, delta)
+    )(jnp.asarray(im2), jnp.asarray(jm2), q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _stair_lse(q, k, v, kb, scale, bq, bk, qpw, spw, interpret):
-    o, lse = staircase_fwd(q, k, v, kb, scale, bq, bk, qpw, spw, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _stair_lse(q, k, v, scale, bq, bk, qpw, spw, interpret):
+    o, lse = staircase_fwd(q, k, v, scale, bq, bk, qpw, spw, interpret)
     return o, lse[..., 0]
 
 
-def _stair_lse_fwd(q, k, v, kb, scale, bq, bk, qpw, spw, interpret):
-    o, lse = staircase_fwd(q, k, v, kb, scale, bq, bk, qpw, spw, interpret)
+def _stair_lse_fwd(q, k, v, scale, bq, bk, qpw, spw, interpret):
+    o, lse = staircase_fwd(q, k, v, scale, bq, bk, qpw, spw, interpret)
     # kept by a recompute region's policy under the names _flash_lse_fwd
     # gives its own
     o, lse = checkpoint_name(o, 'flash_out'), checkpoint_name(lse, 'flash_lse')
-    return (o, lse[..., 0]), (q, k, v, kb, o, lse)
+    return (o, lse[..., 0]), (q, k, v, o, lse)
 
 
 def _stair_lse_bwd(scale, bq, bk, qpw, spw, interpret, res, cot):
     """_flash_lse_bwd's arithmetic over the staircase's pairs."""
     do, dlse = cot
-    q, k, v, kb, o, lse = res
-    dq, dk, dv = staircase_bwd(q, k, v, kb, do, lse,
-                               _folded_delta(do, o, dlse), scale, bq, bk,
-                               qpw, spw, interpret)
-    return dq, dk, dv, jnp.zeros_like(kb)
+    q, k, v, o, lse = res
+    return staircase_bwd(q, k, v, do, lse, _folded_delta(do, o, dlse),
+                         scale, bq, bk, qpw, spw, interpret)
 
 
 _stair_lse.defvjp(_stair_lse_fwd, _stair_lse_bwd)
@@ -1151,11 +1302,6 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
         block_q = _default_tile(tuned_bq, Tq, row_bytes)
     if block_k is None:
         block_k = _default_tile(tuned_bk, Tk, row_bytes)
-    if key_bias is None:
-        key_bias = jnp.zeros((B, Tk), jnp.float32)
-    else:
-        key_bias = key_bias.reshape(B, Tk).astype(jnp.float32)
-    key_bias = lax.stop_gradient(key_bias)
     bq = min(block_q, _round_up(Tq, 128))
     bk = min(block_k, _round_up(Tk, 128))
     Tq_p = _round_up(Tq, bq)
@@ -1170,8 +1316,10 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
     if tri:
         nb = _band(window, bk, nq)
         grid, pairs = 'triangle' if nb is None else 'band', _tile_pairs(nq, nb)
+        masked = _masked_pairs(nq, _mask_diffs(window, bk, nq))
     else:
         grid, pairs = 'rect', nq * nk
+        masked = pairs if causal else 0
     # the backward's schedule, read off the shapes: one pass where a head's
     # scores are one tile, the forward's or (tiles not forced) the table's
     # largest for rows this wide; one pass over the triangle or band where
@@ -1179,12 +1327,12 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
     if (Tq_p, Tk_p) == (bq, bk) or (
             not forced and Tq_p <= _default_tile(whole_q, Tq, row_bytes)
             and Tk_p <= _default_tile(whole_k, Tk, row_bytes)):
-        schedule, bwd_pairs = 'tile', 1
+        schedule, bwd_pairs, bwd_masked = 'tile', 1, int(bool(causal))
     elif tri and _head_vmem_limit(
             Tq_p, D, bq, bk, operands.itemsize) <= _HEAD_VMEM_LIMIT_BYTES:
-        schedule, bwd_pairs = 'head', pairs
+        schedule, bwd_pairs, bwd_masked = 'head', pairs, masked
     else:
-        schedule, bwd_pairs = None, 2 * pairs
+        schedule, bwd_pairs, bwd_masked = None, 2 * pairs, 2 * masked
     # values narrower (or wider) than the keys say so; equal widths count
     # under the labels they always had
     obs.counter('flash.lowered', operands=operands.name, grid=grid,
@@ -1194,6 +1342,19 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
     else:
         obs.counter('flash.backward', passes='two').inc()
     obs.counter('flash.tiles', grid=grid).inc(pairs + bwd_pairs)
+    # of those, the pairs that pay for a mask: on the triangle and the band
+    # the ones on a masked diagonal (_mask_diffs), which add a tile that
+    # is not zeros; on the other grids every pair of a causal call, which
+    # computes its mask
+    obs.counter('flash.tiles_masked', grid=grid).inc(masked + bwd_masked)
+    if key_bias is not None:
+        key_bias = lax.stop_gradient(
+            key_bias.reshape(B, Tk).astype(jnp.float32))
+    elif not tri or Tk_p != Tk:
+        # the rectangular grid's bodies add a bias whatever it holds, and
+        # padded keys are removed through one; a triangle or band with
+        # neither hands in none and adds none
+        key_bias = jnp.zeros((B, Tk), jnp.float32)
     if Tq_p != Tq:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, Tq_p - Tq), (0, 0)))
     if Tk_p != Tk:
@@ -1201,9 +1362,10 @@ def _prep(q, k, v, key_bias, sm_scale, block_q, block_k, interpret,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, Tk_p - Tk), (0, 0)))
         key_bias = jnp.pad(key_bias, ((0, 0), (0, Tk_p - Tk)),
                            constant_values=NEG_BIG)
-    # (B, 1, Tk): Mosaic block shapes need the sublane dim to equal the
-    # array dim, so the bias carries an explicit singleton sublane
-    key_bias = key_bias.reshape(B, 1, Tk_p)
+    if key_bias is not None:
+        # (B, 1, Tk): Mosaic block shapes need the sublane dim to equal
+        # the array dim, so the bias carries an explicit singleton sublane
+        key_bias = key_bias.reshape(B, 1, Tk_p)
     return (q, k, v, key_bias, float(sm_scale), int(bq), int(bk),
             schedule, bool(interpret), Tq, Tq_p)
 
@@ -1358,9 +1520,8 @@ def flash_attention_summary(q, k, v, kbar=None, vbar=None, *, window,
         q[:, :, window:], kbar[:, :, :seen], vbar[:, :, :seen]))
     obs.counter('flash.forward', geometry='staircase').inc()   # trace time
     o_s, lse_s = _stair_lse(
-        q_s, kbar, vbar, jnp.zeros((B, 1, seen), jnp.float32),
-        float(sm_scale), int(block_q), int(block_k), window // block_q,
-        per // block_k, bool(interpret))
+        q_s, kbar, vbar, float(sm_scale), int(block_q), int(block_k),
+        window // block_q, per // block_k, bool(interpret))
     o_t, _ = merge_lse(o_e[:, :, window:], lse_e[:, :, window:], o_s, lse_s)
     return jnp.concatenate([o_e[:, :, :window], o_t.astype(q.dtype)], axis=2)
 
@@ -1418,19 +1579,19 @@ def flash_attention_sharded(mesh, q, k, v, key_bias=None, causal=False,
                                sm_scale=sm_scale, window=window,
                                interpret=interpret)
     from jax.sharding import PartitionSpec as P
-    if key_bias is None:
-        key_bias = jnp.zeros((B, k.shape[2]), jnp.float32)
     qkv = P(bdim, hdim, None, None)
+    bias = () if key_bias is None else (key_bias,)
 
-    def body(q, k, v, kb):
-        return flash_attention(q, k, v, key_bias=kb, causal=causal,
-                               sm_scale=sm_scale, window=window,
-                               interpret=interpret)
+    def body(q, k, v, *kb):
+        return flash_attention(q, k, v, key_bias=kb[0] if kb else None,
+                               causal=causal, sm_scale=sm_scale,
+                               window=window, interpret=interpret)
 
     # check_vma off: pallas out_shapes carry no varying-mesh-axes info
-    return jax.shard_map(body, mesh=mesh, in_specs=(qkv, qkv, qkv,
-                                                    P(bdim, None)),
-                         out_specs=qkv, check_vma=False)(q, k, v, key_bias)
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(qkv, qkv, qkv) + (P(bdim, None),) * len(bias),
+        out_specs=qkv, check_vma=False)(q, k, v, *bias)
 
 
 def reference_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,

@@ -310,10 +310,9 @@ def test_staircase_alone_with_the_log_sum_exps_cotangent():
     q, _, _, kbar, vbar = _qkv(4)
     window, per, bq, bk = 64, 8, 32, 4
     q_s, kb, vb = q[:, :, window:], kbar[:, :, :-per], vbar[:, :, :-per]
-    zeros = jnp.zeros((q.shape[0], 1, kb.shape[2]), jnp.float32)
 
     def flash(q_s, kb, vb):
-        return _stair_lse(q_s, kb, vb, zeros, 0.25, bq, bk, window // bq,
+        return _stair_lse(q_s, kb, vb, 0.25, bq, bk, window // bq,
                           per // bk, True)
 
     def dense(q_s, kb, vb):

@@ -543,7 +543,13 @@ _CELL_CALLS = {
     'lfm2': ((1, 32, 16384, 64), None, 'triangle', 528),
     # the same heads over 16 tiles of a row of 8192 (PR 53)
     'granite4hmicro': ((1, 32, 8192, 64), None, 'triangle', 136),
+    # a window of four tiles over 16: rows of 1, 2, 3, 4 and twelve of 5
+    'trinitymini_window': ((1, 32, 8192, 128), 2048, 'band', 70),
 }
+# of a grid's tile pairs, those that add a mask tile which is not zeros
+# (PR 60): the diagonal's, and under a window of whole tiles the band's
+# lower edge's (32 + 24, 16 + 12)
+_MASKED_PAIRS = {252: 56, 528: 32, 136: 16, 36: 8, 70: 28}
 
 
 @pytest.mark.parametrize('dtype,precision', [
@@ -569,8 +575,8 @@ def test_head_backward_compiles_for_the_cells(one_chip, call, dtype,
         pairs = {136: 528}[pairs]
     x = jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    def tiles():
-        return {g: obs.counter('flash.tiles', grid=g).value
+    def tiles(name='flash.tiles'):
+        return {g: obs.counter(name, grid=g).value
                 for g in ('band', 'triangle', 'rect')}
 
     def loss(q, k, v):
@@ -578,7 +584,7 @@ def test_head_backward_compiles_for_the_cells(one_chip, call, dtype,
                                 interpret=False)
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
-    before, was = tiles(), _schedules()
+    before, was, masked = tiles(), _schedules(), tiles('flash.tiles_masked')
     with (jax.default_matmul_precision(precision) if precision
           else contextlib.nullcontext()):
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
@@ -589,6 +595,46 @@ def test_head_backward_compiles_for_the_cells(one_chip, call, dtype,
         'tile': 0, 'head': 1, 'two': 0}
     assert {g: after[g] - before[g] for g in after} == {
         g: 2 * pairs * (g == grid) for g in after}
+    after = tiles('flash.tiles_masked')
+    assert {g: after[g] - masked[g] for g in after} == {
+        g: 2 * _MASKED_PAIRS[pairs] * (g == grid) for g in after}
+
+
+@pytest.mark.parametrize('dtype,precision', [
+    ('bfloat16', None), ('float32', 'highest')],
+    ids=['bf16_the_cell', 'float32_the_check'])
+@pytest.mark.parametrize('call', ['smallthinker_window', 'glm47flash',
+                                  'lfm2'])
+def test_two_passes_compile_beside_the_mask_tiles(one_chip, call, dtype,
+                                                  precision):
+    """The dq and dk/dv kernels on the triangle and the band, which a head
+    over the one pass's budget still takes and tools/tune_flash.py --parts
+    times: they state no VMEM limit, so Mosaic's default 16 MiB has to
+    hold their blocks, the body's score tiles and the mask's two or three
+    tiles (PR 60: 3 MiB at 512 x 512 under a window), with no key bias
+    handed in."""
+    import contextlib
+    import importlib
+    fa = importlib.import_module('paddle_tpu.ops.flash_attention')
+    shape, window, _, _ = _CELL_CALLS[call]
+    x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+
+    def loss(q, k, v):
+        q, k, v, kb, scale, bq, bk, _, interp, _, _ = fa._prep(
+            q, k, v, None, None, None, None, False, causal=True,
+            window=window)
+        assert kb is None
+        o, _ = fa._flash_lse(q, k, v, kb, True, window, scale, bq, bk, None,
+                             interp)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).compile()
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 3
+    assert '"scoped_memory_configs":[{' not in text
 
 
 # a held cell's layer: tokens, top k, held, routed experts, width

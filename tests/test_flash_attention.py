@@ -1132,3 +1132,213 @@ def test_fused_attention_window_op_matches_reference_and_refuses():
                                    jnp.repeat(v, 3, axis=1), causal=True,
                                    window=7)
     np.testing.assert_allclose(got, np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the mask as an added tile (PR 60): on the triangular grid and its band a
+# pair adds the tile of its KIND and computes no mask; without a key bias
+# and without padded keys the call has no bias operand
+# ---------------------------------------------------------------------------
+
+# T = 512 in 128-tiles, four a side. By window: the masked block diagonals
+# (_mask_diffs) and the grid. A window of one key is the diagonal alone
+# (its tile holds both edges); of a tile or a tile + 1 the diagonal and the
+# band's lower edge; one that ends INSIDE a tile (130, 200) crosses two
+# diagonals below the first; 400 reaches every tile (the triangle) and
+# still masks the last diagonal; 512 is no window.
+_TILE_WINDOWS = {
+    'no_window': (None, (0,), 10),
+    'one_key': (1, (0,), 4),
+    'half_a_tile': (64, (0, 1), 7),
+    'a_tile': (128, (0, 1), 7),
+    'a_tile_and_a_key': (129, (0, 1), 7),
+    'a_tile_and_two_keys': (130, (0, 1, 2), 9),
+    'ends_inside_a_tile': (200, (0, 1, 2), 9),
+    'two_tiles': (256, (0, 2), 9),
+    'every_tile': (400, (0, 3), 10),
+    'the_whole_row': (512, (0,), 10),
+}
+
+
+def _count_masked():
+    from paddle_tpu import obs
+    return {g: obs.counter('flash.tiles_masked', grid=g).value
+            for g in ('band', 'triangle', 'rect')}
+
+
+def _parent_backward(q, k, v, kb, do, lse, delta, scale, window):
+    """The pair arithmetic of the backward bodies before PR 60, written
+    out over the whole head in jnp: scale, the bias added, the mask
+    SELECTED (NEG_BIG exactly where a position is not seen), p from the
+    saved lse, p and ds cast to the operands' dtype for their dots,
+    float32 sums."""
+    dt, f32 = q.dtype, jnp.float32
+    T = q.shape[2]
+
+    def dot(eq, a, b):
+        return jnp.einsum(eq, a, b, preferred_element_type=f32,
+                          precision='highest')
+
+    s = dot('bhqd,bhkd->bhqk', q, k) * scale
+    if kb is not None:
+        s = s + kb[:, :, None, :]
+    ahead = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+    seen = ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
+    s = jnp.where(seen, s, -1e9)
+    p = jnp.exp(s - lse[..., :1])
+    dv = dot('bhqk,bhqd->bhkd', p.astype(dt), do)
+    dp = dot('bhqd,bhkd->bhqk', do, v)
+    ds = (p * (dp - delta[..., :1]) * scale).astype(dt)
+    return (dot('bhqk,bhkd->bhqd', ds, k).astype(dt),
+            dot('bhqk,bhqd->bhkd', ds, q).astype(dt), dv.astype(dt))
+
+
+@pytest.mark.parametrize('with_bias', [False, True], ids=['no_bias', 'bias'])
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+@pytest.mark.parametrize('case', sorted(_TILE_WINDOWS))
+def test_mask_tiles_equal_the_computed_mask(case, dtype, with_bias,
+                                            monkeypatch):
+    """Batch 2 (the stale-block hazard of the strategy note). Forward: o
+    and lse of the triangle or band equal the rectangular grid's TO THE
+    BIT (an interior pair adds zeros, a masked position's p is exactly 0
+    either way). Backward: the two kernels equal the rectangular grid's to
+    the bit as well, and they and the one pass over the head equal the
+    parent's pair arithmetic within the tolerances of
+    test_one_pass_equals_two_passes."""
+    fa = _fa()
+    window, diffs, pairs = _TILE_WINDOWS[case]
+    q, k, v, kb = _rand_qkv(B=2, H=2, Tq=512, Tk=512, D=16, seed=71)
+    q, k, v = [jnp.asarray(x, jnp.dtype(dtype)) for x in (q, k, v)]
+    if window == 1:
+        # a query whose one seen key the bias removes is a degenerate row
+        # (the module docstring): there the bias only shifts
+        kb = np.where(kb < 0, -2.5, 0.0).astype('float32')
+    bias = jnp.asarray(kb) if with_bias else None
+    do = jnp.asarray(np.random.RandomState(72).randn(*q.shape), q.dtype)
+
+    def run(schedules):
+        w = fa._window_of(window, True, 512)
+        qp, kp, vp, kbp, scale, bq, bk, chosen, interp, _, _ = fa._prep(
+            q, k, v, bias, None, 128, 128, True, causal=True, window=w)
+        o, lse = fa._fwd_call(qp, kp, vp, kbp, True, scale, bq, bk, interp, w)
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+        delta = jnp.broadcast_to(delta[..., None], delta.shape + (fa.LANES,))
+        args = (qp, kp, vp, kbp, do, lse, delta, True, scale, bq, bk)
+        return (kbp, chosen, o, lse, delta, scale, w,
+                [fa._bwd_call(*args, s, interp, w) for s in schedules])
+
+    masked0 = _count_masked()
+    kbp, chosen, o, lse, delta, scale, w, (head, two) = run(['head', None])
+    assert (kbp is None) == (not with_bias) and chosen == 'head'
+    nb = fa._band(w, 128, 4)
+    assert fa._mask_diffs(w, 128, 4) == diffs \
+        and fa._tile_pairs(4, nb) == pairs
+    grid = 'triangle' if nb is None else 'band'
+    assert _rose(masked0, _count_masked()) == {
+        g: 2 * sum(4 - d for d in diffs) * (g == grid) for g in masked0}
+    # the rectangular grid at EQUAL tiles, whose bodies still compute the
+    # mask of every pair (_mask_causal) and add a bias of zeros: the
+    # arithmetic every causal pair had before PR 60
+    monkeypatch.setattr(fa, '_use_tri', lambda *a: False)
+    kbr, _, o_r, lse_r, _, _, _, (two_r,) = run([None])
+    assert kbr is not None
+    assert np.array_equal(np.asarray(o, np.float32),
+                          np.asarray(o_r, np.float32))
+    assert np.array_equal(np.asarray(lse), np.asarray(lse_r))
+    for a, b, name in zip(two, two_r, ('dq', 'dk', 'dv')):
+        assert np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32)), name
+    want = _parent_backward(q, k, v, kbr, do, lse, delta, scale, w)
+    for got in (head, two):
+        for a, b, name in zip(got, want, ('dq', 'dk', 'dv')):
+            assert a.dtype == jnp.dtype(dtype)
+            assert _rel_norm(a, b) <= (BF16_EPS if dtype == 'bfloat16'
+                                       else 1e-6), name
+
+
+def test_padded_keys_keep_their_bias_on_the_triangle():
+    """A row of 450 pads to 512: no bias was given, but the padded keys
+    are removed through one, so the call keeps the operand; the result is
+    the rectangular grid's to the bit and the oracle's."""
+    fa = _fa()
+    q, k, v, _ = _rand_qkv(B=2, H=2, Tq=450, Tk=450, D=16, seed=73)
+    kbp = fa._prep(*map(jnp.asarray, (q, k, v)), None, None, 128, 128, True,
+                   causal=True)[3]
+    assert kbp.shape == (2, 1, 512) and float(kbp[0, 0, 449]) == 0.0 \
+        and float(kbp[0, 0, 450]) == fa.NEG_BIG
+    # queries AFTER the padded keys would see them but for the bias: not
+    # causal, on the grid that keeps the operand whatever it holds
+    got = ops.flash_attention(q, k, v, block_q=128, block_k=128,
+                              interpret=True)
+    want = ops.reference_attention(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    got = ops.flash_attention(q, k, v, causal=True, window=200, block_q=128,
+                              block_k=128, interpret=True)
+    want = ops.reference_attention(q, k, v, causal=True, window=200)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize('n', [1, 2, 3, 4, 5, 6])
+def test_pair_kinds_against_the_positions(n):
+    """For a triangle of n tiles a side (tiles of 4 positions) and every
+    window from one key to past the row, so every band `nb` and every
+    place a window can end in a tile: a pair's kind is 0 exactly where the
+    positions say the pair holds nothing to mask, the additive tile of
+    every other kind IS the pair's mask, in both enumerations, and
+    _masked_pairs counts them."""
+    fa = _fa()
+    tile = 4
+    pos = np.arange(n * tile)
+    ahead = pos[:, None] - pos[None, :]
+    zeros = jnp.zeros((tile, tile), jnp.float32)
+    for window in [None] + list(range(1, n * tile + 2)):
+        w = fa._window_of(window, True, n * tile)
+        seen = ahead >= 0 if w is None else (ahead >= 0) & (ahead < w)
+        blocks = seen.reshape(n, tile, n, tile).transpose(0, 2, 1, 3)
+        nb, diffs = fa._band(w, tile, n), fa._mask_diffs(w, tile, n)
+        assert len(diffs) <= 3 and diffs[0] == 0
+        tiles = [np.zeros((tile, tile), np.float32)] + [
+            np.asarray(fa._mask_causal(zeros, d * tile, 0, 0, w))
+            for d in diffs]
+        turned = [np.zeros((tile, tile), np.float32)] + [
+            np.asarray(fa._mask_causal(zeros, d * tile, 0, 1, w))
+            for d in diffs]
+        for maps in (fa._tri_maps(n, nb), fa._tri_maps_kv(n, nb)):
+            i, j = maps
+            kinds = np.asarray(fa._pair_kind(i - j, diffs))
+            for a, b, kind in zip(i, j, kinds):
+                want = np.where(blocks[a, b], 0.0, fa.NEG_BIG)
+                assert (kind == 0) == bool(blocks[a, b].all()), (window, a, b)
+                assert np.array_equal(tiles[kind], want), (window, a, b)
+                assert np.array_equal(turned[kind], want.T), (window, a, b)
+            assert np.count_nonzero(kinds) == fa._masked_pairs(n, diffs)
+
+
+@pytest.mark.parametrize('window,pairs,masked,grid', [
+    (4096, 252, 56, 'band'), (None, 528, 32, 'triangle')])
+def test_flash_tiles_masked_at_smallthinkers_sizes(window, pairs, masked,
+                                                   grid):
+    """16384 positions in 512-tiles, traced and not run: of the band's 252
+    pairs under a window of 4096 the 32 diagonal and the 24 lower-edge
+    ones add a mask, of the triangle's 528 the 32 diagonal ones, in the
+    forward and in the one pass; no bias is handed in."""
+    from paddle_tpu import obs
+    x = jax.ShapeDtypeStruct((1, 1, 16384, 128), jnp.bfloat16)
+
+    def read():
+        return (obs.counter('flash.tiles', grid=grid).value,
+                obs.counter('flash.tiles_masked', grid=grid).value)
+
+    before = read()
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: ops.flash_attention(
+        q, k, v, causal=True, window=window, interpret=True).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)))(x, x, x)
+    after = read()
+    assert (after[0] - before[0], after[1] - before[1]) == (
+        2 * pairs, 2 * masked)
+    calls = [e for e in _walk(jaxpr.jaxpr, [])
+             if e.primitive.name == 'pallas_call']
+    # forward: the two maps and q, k, v; the one pass: do, lse, delta too
+    assert [len(e.invars) for e in calls] == [5, 8]
