@@ -121,6 +121,17 @@ the label `router=own`, squared-ReLU experts the label `act=relu2`, and
 dropless experts of two matrices without biases the label `gated=false`:
 the form whose grouped matmuls are two a pass).
 
+ONCE A STEP IN A RECOMPUTE REGION. What the route stage DECIDES (the
+router's choice, the auxiliary loss's `f`, `ExpertCount`, and on a share's
+compact path the index, the add's steps and `live`: `_routed`,
+`_compact_route`) is named `region_keep`, the name a region's policy saves
+(step_artifact.py REGION_KEEP): the region's second forward reads the
+integers and runs no top-k, no count, no sort and no plan again; the
+logits, the gates, the row gather, the matmuls and the add it runs as
+before. Outside a region the names do nothing. `moe.route_kept{held=}`
+and `moe.route_kept_bytes{held=}` count what a lowering named
+(`_count_routed`).
+
 What a share's step did with its DATA leaves the device as the op's
 device counter (`_held_counter`, lowering.register_device_counter): the
 step's assignments to the held experts, one int32 an op, reduced from
@@ -135,10 +146,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ... import obs
 from ..lowering import (DeviceCounter, amp_cast, data_of, register,
                         register_device_counter, traced_once)
+from ..step_artifact import REGION_KEEP
 from ...ops.kernels import row_add
 
 _ACTS = {
@@ -404,6 +417,50 @@ def _add_kernel(ctx, cap, n, d, dtype):
     return ctx.platform == 'tpu' and row_add.usable(cap, n, d, dtype)
 
 
+def _routed(tree):
+    """What the route stage DECIDED, every array of `tree` named
+    REGION_KEEP for a recompute region's policy (step_artifact.py): the
+    region's second forward reads the integers its first made and runs
+    neither the choice, nor the counts, nor the sort, nor the plan again.
+    Nothing that takes a gradient goes through here. Outside a region the
+    name is inert."""
+    return jax.tree_util.tree_map(
+        lambda v: checkpoint_name(v, REGION_KEEP), tree)
+
+
+def _count_routed(tree, held):
+    """Trace-time counters of what a lowering named (`_routed`, and the two
+    router functions of parallel/moe.py): `moe.route_kept{held=}`, the
+    arrays of `tree`, and `moe.route_kept_bytes{held=}`, their bytes from
+    shape and dtype (`jax.eval_shape`'s do), under the label `moe.lowered`
+    carries (`held` None: none). They count the names, not what
+    a step kept: a lowering outside any region (`olmoe_s4096`,
+    `qwen3next_s8192`) counts the same and keeps nothing; that a region
+    used them is read from the step's jaxpr (tests/test_held_experts.py).
+    Apart from `recompute.kept_values`, the models' marks."""
+    leaves = jax.tree_util.tree_leaves(tree)
+    labels = {} if held is None else {'held': held}
+    obs.counter('moe.route_kept', **labels).inc(len(leaves))
+    obs.counter('moe.route_kept_bytes', **labels).inc(
+        sum(v.size * v.dtype.itemsize for v in leaves))
+
+
+def _compact_route(key, sizes, *, cap, width, biased):
+    """What the compact path decides from its keys, named (`_routed`):
+    `src` [cap], the laid-out assignments' positions among the token-major
+    keys (one packed sort of them all); `at` (`_index`; `width` as there);
+    `group` [cap], each row's expert, where there are biases; `live`, the
+    rows that some assignment fills."""
+    flat = key.reshape(-1)                             # token-major
+    src = _argsort(flat, sizes.shape[0] + 1)[:cap]
+    group = None
+    if biased:
+        # a row past `live` has no bias row: any row in bounds, masked
+        group = jnp.minimum(_rows(flat, src), sizes.shape[0] - 1)
+    return _routed((src, _index(src, key, sizes.shape[0], width), group,
+                    jnp.sum(sizes)))
+
+
 def _compact_moe(params, x, key, gate, sizes, cap, act, ctx):
     """A held share whose held assignments fit `cap` rows: they alone are
     laid out, sorted by expert, once for the whole layer, and nothing of
@@ -418,20 +475,22 @@ def _compact_moe(params, x, key, gate, sizes, cap, act, ctx):
     kernel = _add_kernel(ctx, cap, nt, x.shape[1], x.dtype)
     interpret = ctx.pallas_interpret if kernel else None
     with jax.named_scope('moe_route'):
-        flat = key.reshape(-1)                         # token-major
-        src = _argsort(flat, sizes.shape[0] + 1)[:cap]
-        at = _index(src, key, sizes.shape[0],
-                    x.shape[1] if kernel else None)
+        src, at, group, live = _compact_route(
+            key, sizes, cap=cap, width=x.shape[1] if kernel else None,
+            biased='b1' in params)
         # `keep`: a row past `live` is some absent assignment's token, and
         # the kernels' gradient of the rows is unwritten there
-        keep = _keep(jnp.sum(sizes))
-        group = None
-        if 'b1' in params:
-            group = jnp.minimum(_rows(flat, src), sizes.shape[0] - 1)
+        keep = _keep(live)
 
     # the index is kept for the backward pass, the rows are laid out again
     # there: kept, a layer's rows and the experts' hidden rows are 670 MB
-    # at 25600 rows of 2048
+    # at 25600 rows of 2048. Kept twice over: by this checkpoint, which
+    # closes over it, and, where a recompute region encloses the layer, by
+    # the region's policy under the names `_compact_route` gave it (the
+    # route stage's values are the third kind a region keeps,
+    # step_artifact.py REGION_KEEP), so the region's second forward makes
+    # no index either. The row gather, the grouped matmuls and the add
+    # still run again there, and once more here.
     @jax.checkpoint
     def rows_of(params, x, gate):
         with jax.named_scope('moe_route'):
@@ -527,14 +586,18 @@ def _held_moe(params, x, expert, gate, sizes, held, act, ctx):
         local = expert - first
         key = jnp.where((local >= 0) & (local < count), local, count)
     cap = _held_layout(nt * k, count, sizes.shape[0])
+    held_sizes = sizes[first:first + count]
     if cap is not None:
         # the layout's two adds, forward and the row gather's transpose
-        way = 'kernel' if _add_kernel(ctx, cap, nt, x.shape[1], x.dtype) \
-            else 'scatter'
-        obs.counter('moe.add', way=way).inc(2)
+        kernel = _add_kernel(ctx, cap, nt, x.shape[1], x.dtype)
+        obs.counter('moe.add', way='kernel' if kernel else 'scatter').inc(2)
+        # what the shared body's compact path names, counted a lowering
+        _count_routed(jax.eval_shape(
+            functools.partial(_compact_route, cap=cap, biased='b1' in params,
+                              width=x.shape[1] if kernel else None),
+            key, held_sizes), '%dof%d' % (count, sizes.shape[0]))
     paths = traced_once(ctx, _held_paths, cap=cap, act=act)
-    return paths(params, x, key, gate, sizes[first:first + count],
-                 _held_rows(sizes, held))
+    return paths(params, x, key, gate, held_sizes, _held_rows(sizes, held))
 
 
 def _held_paths(ctx, params, x, key, gate, sizes, rows, *, cap, act):
@@ -642,8 +705,13 @@ def _moe_mlp(ins, attrs, ctx):
             float(attrs.get('gate_scale', 1.0)),
             attrs.get('norm_eps'), n_group,
             int(attrs.get('topk_group', 1)))                   # [k, nt]
-        sizes = jnp.bincount(expert.reshape(-1), length=n_exp
-                             ).astype(jnp.int32)
+        sizes = _routed(jnp.bincount(expert.reshape(-1), length=n_exp
+                                     ).astype(jnp.int32))
+        # the rule's three: the choice and the auxiliary loss's f, which
+        # `router_topk` and `load_balancing_loss` name, and the counts
+        _count_routed(
+            (expert, jax.ShapeDtypeStruct((n_exp,), jnp.float32), sizes),
+            labels.get('held'))
     params = dict(zip(params, amp_cast(ctx, *params.values())))
     # the experts' rows in the experts' dtype (AMP cast the weights just
     # above; X stayed float32 for the router)

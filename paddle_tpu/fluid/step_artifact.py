@@ -51,10 +51,16 @@ __all__ = ['StepArtifact', 'StepResult', 'program_fingerprint',
            'aot_check', 'AOT_MANIFEST', 'AOT_CACHE_DIR']
 
 
-# What a recompute region keeps besides its inputs (`_run_region`): the
-# flash calls' outputs and row statistics, and what the model marked with
-# `fluid.recompute_keep`, every mark of every region under the ONE name
-# REGION_KEEP. ONE object for every region: jax caches the split of a
+# What a recompute region keeps besides its inputs (`_run_region`), three
+# kinds: the flash calls' outputs and row statistics; what the model marked
+# with `fluid.recompute_keep`, every mark of every region under the ONE
+# name REGION_KEEP; and, under the same name, what an expert layer's route
+# stage DECIDED (ops_impl/moe_ops.py `_routed`, parallel/moe.py: the
+# router's choice, the auxiliary loss's `f`, the counts, the compact path's
+# index and plan): integers and one [E] vector that take no gradient and
+# that the second forward would make again bit for bit, a megabyte a layer,
+# named by the rule itself, with no barrier and no count among the marks
+# (`moe.route_kept`). ONE object for every region: jax caches the split of a
 # jitted body into what is known and what is run again on the policy's
 # identity, so regions that call one shared body (lowering.traced_once,
 # the kernels' own jits) share its halves too; a policy made anew a region

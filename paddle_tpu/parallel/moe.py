@@ -29,8 +29,10 @@ uniform router — to be added to the model loss with a small weight.
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from ..fluid.step_artifact import REGION_KEEP
 from ._sp import stack_unit_params
 
 __all__ = ['moe_apply', 'stack_expert_params', 'router_topk', 'pack_topk',
@@ -107,7 +109,10 @@ def router_topk(logits, top_k, norm_topk_prob=True, scoring='softmax',
         stays = jnp.sum(jax.nn.one_hot(kept, n_group, dtype=jnp.int32),
                         axis=-2) > 0
         pick = jnp.where(jnp.repeat(stays, size, axis=-1), pick, -jnp.inf)
-    _, idx = lax.top_k(pick, top_k)                              # [nt, k]
+    # the choice is named for a recompute region (fluid/step_artifact.py
+    # REGION_KEEP): the gates read nothing else of the choosing, so a
+    # region's second forward runs no top_k. Inert outside a region.
+    idx = checkpoint_name(lax.top_k(pick, top_k)[1], REGION_KEEP)  # [nt, k]
     gate = jnp.take_along_axis(scores, idx, axis=-1)             # [nt, k]
     if top_k > 1 and norm_topk_prob:
         gate = gate / (jnp.sum(gate, axis=-1, keepdims=True)
@@ -127,7 +132,11 @@ def load_balancing_loss(logits, top_k=1):
     n_exp = logits.shape[-1]
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     _, idx = lax.top_k(logits, top_k)                            # [nt, k]
-    f = jnp.mean(jax.nn.one_hot(idx, n_exp, dtype=jnp.float32), axis=(0, 1))
+    # f takes no gradient: named as the router's choice is (`router_topk`),
+    # [E] float32 where its idx is [nt, k]
+    f = checkpoint_name(
+        jnp.mean(jax.nn.one_hot(idx, n_exp, dtype=jnp.float32), axis=(0, 1)),
+        REGION_KEEP)
     p = jnp.mean(probs, axis=0)
     return n_exp * jnp.sum(f * p)
 

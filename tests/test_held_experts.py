@@ -4,6 +4,8 @@ the slack or half the rows; absent rows cost nothing and poison nothing;
 the held rows are reached by index, once a layer, on the compact path or,
 over the layout, on the blocks; the counters of the lowering. Small sizes,
 on the CPU."""
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -575,3 +577,152 @@ def test_a_held_lowering_counts_once_under_dispatch_index():
             exe.run(main, feed={'x': xs}, fetch_list=[out])   # no new trace
             assert counts() == first
         assert [b - a for a, b in zip(before, first)] == moved
+
+
+# An expert layer in a recompute region routes ONCE a step (ISSUE 62): what
+# the route stage decides is named for the region's policy, the region's
+# second forward reads it, and no number changes.
+RT, RD = 256, 128        # tokens; a width the row-add kernel takes
+ROUTED = {
+    # way of the layout's add, attributes of the router, `top_k` a forward
+    'scatter': (False, {}, 2),                  # the router's and AuxLoss's
+    'kernel': (True, {}, 2),
+    'scatter_grouped': (False, {'scoring': 'sigmoid', 'n_group': 4,
+                                'topk_group': 2}, 4),    # two more by group
+}
+ROUTE_KEPT = ('moe.route_kept', 'moe.route_kept_bytes')
+
+
+def _strip_names(monkeypatch):
+    """The parent's lowering: no value of the route stage is named."""
+    from paddle_tpu.parallel import moe
+    for module in (moe, moe_ops):
+        monkeypatch.setattr(module, 'checkpoint_name', lambda v, name: v)
+
+
+def _held_step(case, region=True, held=(8, HELD)):
+    """A toy Program with one held `moe_mlp` between two `fc`, in a
+    recompute region or not, its add forced the `case`'s way: (loss and
+    every gradient and the expert counts on one batch, the step's jaxpr,
+    what its one trace counted under ROUTE_KEPT by the `held=` label and
+    under `recompute.kept_values`)."""
+    kernel, router, _ = ROUTED[case]
+    main, startup = framework.Program(), framework.Program()
+    main.random_seed = startup.random_seed = 3
+    with unique_name.guard(), framework.program_guard(main, startup):
+        x = layers.data(name='x', shape=[RD], dtype='float32')
+        h = layers.fc(x, RD)
+        with fluid.recompute_guard() if region else contextlib.nullcontext():
+            out, aux, count = layers.moe_mlp(
+                h, num_experts=E, hidden_size=H, act='swish', gated=True,
+                top_k=K, capacity_factor=None, bias_attr=False,
+                return_aux_loss=True, return_expert_count=True,
+                experts_held=held, **router)
+            y = layers.fc(out, RD)
+        loss = layers.mean(y) + 0.01 * aux
+        grads = fluid.backward.append_backward(loss)
+    feed = {'x': np.random.default_rng(0).normal(size=(RT, RD)
+                                                 ).astype('float32')}
+    label = {'held': '%dof%d' % (held[1], E)} if held else {}
+    counters = [obs.counter(n, **label) for n in ROUTE_KEPT] \
+        + [obs.counter('recompute.kept_values')]
+    with pytest.MonkeyPatch.context() as patch, \
+            fluid.scope_guard(fluid.Scope()):
+        patch.setattr(moe_ops, '_add_kernel', lambda *a: kernel)
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        fetch = [loss, count] + [g for _, g in grads]
+        before = [c.value for c in counters]
+        values = exe.run(main, feed=feed, fetch_list=fetch)
+        counted = [c.value - b for c, b in zip(counters, before)]
+        scope = fluid.global_scope()
+        compiled = exe.step_artifact(main, feed, fetch, scope)
+        donated, readonly = compiled.plan.split(compiled.state_dict(scope))
+        jaxpr = compiled._jitted.trace(
+            donated, readonly, {n: jnp.asarray(v) for n, v in feed.items()},
+            jax.random.key(0)).jaxpr
+    return values, jaxpr, counted
+
+
+def _primitives(jaxpr, counts=None):
+    """How often each primitive stands in `jaxpr` and in what it calls,
+    the FALSE branch of a two-way `cond` apart: of the held layer's
+    `lax.cond` that is `_held_blocks`, whose blocks sort for themselves
+    under a checkpoint of their own."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        counts[name] = counts.get(name, 0) + 1
+        subs = list(jax.core.jaxprs_in_params(eqn.params))
+        if name == 'cond' and len(subs) == 2:
+            subs = subs[1:]
+        for sub in subs:
+            _primitives(sub, counts)
+    return counts
+
+
+@pytest.mark.parametrize('case', list(ROUTED))
+def test_a_region_makes_the_choice_and_the_index_once(case, monkeypatch):
+    """In a region the step's jaxpr holds each `top_k` of the router and
+    each `sort` of the compact path ONCE, where the same Program with the
+    names stripped (the parent's lowering) holds them twice, forward and
+    the region's second forward; the loss, every gradient and the counts
+    are the stripped Program's TO THE BIT."""
+    kernel, _, top_ks = ROUTED[case]
+    sorts = 1 if kernel else 2    # the keys'; the scatter's: tokens' too
+    named, jaxpr, _ = _held_step(case)
+    found = _primitives(jaxpr)
+    assert (found['top_k'], found['sort']) == (top_ks, sorts)
+    assert found['name'] == 3 + (8 if kernel else 5)
+    _strip_names(monkeypatch)
+    plain, jaxpr, _ = _held_step(case)
+    found = _primitives(jaxpr)
+    assert (found['top_k'], found['sort']) == (2 * top_ks, 2 * sorts)
+    assert 'name' not in found
+    # the layer was on the compact path: the held rows fit the layout
+    cap = moe_ops._held_layout(RT * K, HELD, E)
+    assert 0 < plain[1][8:8 + HELD].sum() <= cap == 512
+    assert len(named) == len(plain) > 6
+    for a, b in zip(named, plain):
+        assert np.abs(b).max() > 0
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize('case', ['scatter', 'scatter_grouped'])
+def test_outside_a_region_the_names_are_inert(case, monkeypatch):
+    """No region, no `jax.checkpoint` to read a name: the step's jaxpr is
+    the stripped Program's with the `name` equations between, and so are
+    its numbers."""
+    named, jaxpr, _ = _held_step(case, region=False)
+    with_names = _primitives(jaxpr)
+    assert with_names.pop('name') == 3 + 5
+    _strip_names(monkeypatch)
+    plain, jaxpr, _ = _held_step(case, region=False)
+    assert _primitives(jaxpr) == with_names
+    for a, b in zip(named, plain):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize('case,held', [
+    ('scatter', (8, HELD)), ('kernel', (8, HELD)), ('scatter', None)])
+def test_a_lowering_counts_what_it_named_and_its_bytes(case, held):
+    """`moe.route_kept{held=}` and `moe.route_kept_bytes{held=}`, a trace
+    of the rule: the rule's three for every path (the choice [k, tokens],
+    `f` and the counts [E]), and beside them, where the layer has a
+    layout, what the compact path decides at (tokens, k, cap): `src` and
+    `token` [cap], the scalar `live`, and the scatter's `order` and
+    `token[order]` [cap] or the kernel's plan, four of tiles x held +
+    chunks steps and their number. Not the models' marks."""
+    from paddle_tpu.ops.kernels import row_add
+    _, _, (arrays, nbytes, marks) = _held_step(case, held=held)
+    rule = [RT * K, E, E]
+    cap = moe_ops._held_layout(RT * K, HELD, E)
+    t, r = row_add.tiles(RD)
+    steps = RT // t * HELD + cap // r
+    layout = [] if held is None else [cap, cap, 1] + (
+        [steps] * 4 + [1] if case == 'kernel' else [cap, cap])
+    assert arrays == len(rule + layout) == (3 if held is None
+                                            else 11 if case == 'kernel'
+                                            else 8)
+    assert nbytes == 4 * sum(rule + layout)
+    assert marks == 0

@@ -177,12 +177,20 @@ def test_the_trace_counts_the_marked_arrays_and_their_bytes(model):
 @pytest.mark.parametrize('model', sorted(UNMARKED))
 def test_a_program_without_marks_traces_no_keep(model):
     """nemotron_h builds the mixers granitemoehybrid marks and passes no
-    marker; glm4_moe_lite's regions have none either."""
+    marker; glm4_moe_lite's regions have none either. What the name keeps
+    there all the same is the third kind of kept value, an expert layer's
+    route stage (ops_impl/moe_ops.py `_routed`): integers and the [E]
+    vector `f`, no activation, and none of it counted as a mark."""
     _, eqns, counted, main, regions = _step(model)
     assert regions >= 3 and not _marked_vars(main)
     assert counted == [0, 0]
-    named = {e.params['name'] for e in eqns if e.primitive.name == 'name'}
-    assert step_artifact.REGION_KEEP not in named
+    kept = [e.outvars[0].aval for e in eqns if e.primitive.name == 'name'
+            and e.params['name'] == step_artifact.REGION_KEEP]
+    experts = [op for op in main.global_block().ops if op.type == 'moe_mlp']
+    # the choice and the counts; `f` where the model reads AuxLoss
+    assert 0 < 2 * len(experts) <= len(kept) <= 3 * len(experts)
+    # both presets route among 16 experts
+    assert all(a.dtype == 'int32' or a.shape == (16,) for a in kept)
     assert all(e.params['policy'] is step_artifact._REGION_KEEPS
                for e in eqns if e.primitive.name == 'remat2')
 
